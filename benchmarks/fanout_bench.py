@@ -68,7 +68,6 @@ def _host_hash_gbps(procs: int = 4, mb_each: int = 96) -> "float | None":
 
 from aiohttp import web  # noqa: E402
 
-from dragonfly2_tpu.pkg.hermetic import scrub_accelerator_env  # noqa: E402
 from dragonfly2_tpu.pkg.piece import Range  # noqa: E402
 
 
@@ -90,7 +89,6 @@ def _spawn(args: list[str], log_path: str,
         # Device-sink daemons: a real single-device CPU backend (the
         # jax.Array landing path the TPU sink uses, minus the chip).
         env["JAX_PLATFORMS"] = "cpu"
-        scrub_accelerator_env(env)
     logf = open(log_path, "w")
     return subprocess.Popen(
         [sys.executable, "-m", "dragonfly2_tpu.cli.main", *args],
@@ -526,8 +524,8 @@ def main() -> int:
         path = os.path.join(REPO, "BASELINE.json")
         doc = json.load(open(path))
         # Device-sink runs publish under their own key: overwriting the
-        # canonical fan-out baseline would orphan the README and
-        # config5_projection citations into it.
+        # canonical fan-out baseline would orphan the README's
+        # citations into it.
         key = ("config2_fanout_device_sink" if args.device_sink
                else "config2_fanout_warm" if args.warm_seed
                else "config2_fanout")

@@ -244,6 +244,9 @@ def _run_scheduler(args: argparse.Namespace) -> int:
 
     async def run() -> int:
         server = SchedulerServer(cfg)
+        from dragonfly2_tpu.cli.main import assert_no_jax
+
+        assert_no_jax("scheduler")
         import signal
 
         loop = asyncio.get_running_loop()

@@ -21,6 +21,14 @@ from dragonfly2_tpu.pkg.types import format_size
 log = dflog.get("cli")
 
 
+def assert_no_jax(role: str) -> None:
+    """A chip belongs to one process. The roles that hold no device sink
+    never import jax, so that they never take the chip from the one that
+    does; a lazy import that creeps in fails here, not as a hang there."""
+    if "jax" in sys.modules:
+        raise RuntimeError(f"{role} imported jax without a device sink")
+
+
 def _add_dfget(sub: argparse._SubParsersAction) -> None:
     p = sub.add_parser("dfget", help="download a file through the P2P fabric")
     p.add_argument("url", help="source URL (http/https/file/gs)")
@@ -152,6 +160,8 @@ def _run_dfget(args: argparse.Namespace) -> int:
             f"({format_size(int(rate))}/s) task={result.get('task_id', '')[:16]} "
             f"reuse={result.get('from_reuse', False)} p2p={result.get('from_p2p', False)}"
             + (f" device_verified={result.get('device_verified', False)}"
+               f" device={result.get('device_platform', '') or '-'}"
+               f"/{result.get('device_kind', '') or '-'}"
                if cfg.device else "") + "\n"
         )
         flight_info = result.get("flight") or {}
@@ -185,6 +195,15 @@ def _run_dfget(args: argparse.Namespace) -> int:
                 except Exception as e:
                     sys.stderr.write(f"dfget: cluster view unavailable: "
                                      f"{e}\n")
+        if cfg.device and not result.get("device_verified", False):
+            # The request asked for the device: a disk-only result is a
+            # failure of the request, whatever landed on disk.
+            sys.stderr.write(
+                "dfget: error: content did not land in the device sink: "
+                + (result.get("device_error")
+                   or "the daemon reported no sink error (is its "
+                      "tpu_sink enabled?)") + "\n")
+            return 1
         return 0
 
     try:
@@ -192,6 +211,8 @@ def _run_dfget(args: argparse.Namespace) -> int:
     except Exception as e:
         sys.stderr.write(f"\ndfget: error: {e}\n")
         return 1
+    finally:
+        assert_no_jax("dfget")
 
 
 def _spawn_daemon(path: Dfpath, *, device_sink: bool = False,
@@ -345,6 +366,8 @@ def _run_daemon(args: argparse.Namespace) -> int:
 
     async def run() -> int:
         daemon = Daemon(cfg)
+        if not cfg.tpu_sink.enabled:
+            assert_no_jax("daemon")
         import signal
 
         loop = asyncio.get_running_loop()

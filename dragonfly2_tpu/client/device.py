@@ -36,6 +36,9 @@ class DeviceResult:
     from_reuse: bool
     sink: object  # TaskDeviceSink
 
+    def as_words(self):
+        return self.sink.as_words()
+
     def as_bytes_array(self):
         return self.sink.as_bytes_array()
 
@@ -48,8 +51,8 @@ class DeviceResult:
     def load_safetensors(self, *, names: list[str] | None = None,
                          shardings: dict | None = None):
         """The landed content as named checkpoint tensors (the content
-        must be a safetensors file): bitcast views of the HBM buffer,
-        optionally device_put to per-tensor shardings."""
+        must be a safetensors file): typed device arrays cut from the HBM
+        buffer, optionally device_put to per-tensor shardings."""
         from dragonfly2_tpu.ops import safetensors as st
 
         return st.load_from_sink(self.sink, names=names,
@@ -113,8 +116,9 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
                               "download ended without a result")
             if not final.device_verified:
                 raise DfError(Code.ClientPieceDownloadFail,
-                              "content did not land in the device sink "
-                              "(sink cap reached or pieces misaligned)")
+                              "content did not land in the device sink: "
+                              + (final.device_error
+                                 or "no sink error was recorded"))
             task_id = final.task_id
             sink = (tm.device_sinks.take(task_id) if claim
                     else tm.device_sinks.get(task_id))
@@ -162,14 +166,14 @@ async def fetch_safetensors_header(daemon, url: str, *, tag: str = "",
     first = await download_to_device(
         daemon, url, tag=tag, application=application, header=header,
         range_header=f"0-{prefix_guess - 1}")
-    got = np.asarray(first.as_bytes_array()).tobytes()
+    prefix_u8 = first.as_bytes_array()    # at most prefix_guess bytes
+    got = np.asarray(prefix_u8).tobytes()
     if len(got) < 8:
         raise st.SafetensorsError(f"file shorter ({len(got)}B) than the "
                                   "safetensors length prefix")
     n = int.from_bytes(got[:8], "little")
     if n <= 0 or n > (1 << 27):
         raise st.SafetensorsError(f"implausible header length {n}")
-    prefix_u8 = first.as_bytes_array()
     if 8 + n > len(got):
         rest = await download_to_device(
             daemon, url, tag=tag, application=application, header=header,
@@ -187,7 +191,8 @@ async def _pull_ranges(daemon, url: str, ranges, *, tag: str = "",
                        header: dict | None = None) -> dict:
     """Pull each ``(start, end)`` byte range as its own ranged device
     task, concurrently under the daemon's shared sink admission; returns
-    ``{(start, end): u8_array}``. The single pull engine for
+    ``{(start, end): words}`` (the sink's uint32 buffer, zero-padded past
+    the range). The single pull engine for
     download_sharded and download_global — their task ids and coalesce
     behavior must never fork. A failed range CANCELS its siblings
     (orphaned pulls would keep downloading against a dead result), and
@@ -201,7 +206,7 @@ async def _pull_ranges(daemon, url: str, ranges, *, tag: str = "",
         result = await download_to_device(
             daemon, url, tag=tag, application=application, header=header,
             range_header=f"{s0}-{s1 - 1}")
-        landed[(s0, s1)] = result.as_bytes_array()
+        landed[(s0, s1)] = result.as_words()
 
     # First failure cancels the sibling pulls and re-raises plain (the
     # TaskGroup/ExceptionGroup shape needs 3.11; this runs on 3.10 too).
@@ -357,17 +362,17 @@ async def download_sharded(daemon, url: str, *,
     if plen:
         coverage.append(((0, plen), prefix_u8))
     for start, end, span_names in spans:
-        u8, base = next((u, c0) for (c0, c1), u in coverage
-                        if c0 <= start and end <= c1)
+        buf, base = next((u, c0) for (c0, c1), u in coverage
+                         if c0 <= start and end <= c1)
         # Rebase the span's tensors onto the slice: tensor_views validates
-        # and bitcasts exactly as for a full-content landing.
+        # and converts exactly as for a full-content landing.
         sub_header = {
             n: {**header_dict[n],
                 "data_offsets": [
                     data_start + header_dict[n]["data_offsets"][0] - base,
                     data_start + header_dict[n]["data_offsets"][1] - base]}
             for n in span_names}
-        out.update(st.tensor_views(u8, sub_header, 0, span_names))
+        out.update(st.tensor_views(buf, sub_header, 0, span_names))
     if shardings:  # unknown names already rejected above, pre-download
         import jax
 

@@ -87,7 +87,9 @@ class Daemon:
         device_sinks = None
         if config.tpu_sink.enabled:
             from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+            from dragonfly2_tpu.ops.compile_cache import place_compile_cache
 
+            log.info("jax compile cache", dir=place_compile_cache())
             device_sinks = DeviceSinkManager(
                 mesh_shape=config.tpu_sink.mesh_shape,
                 batch_pieces=config.tpu_sink.batch_pieces,

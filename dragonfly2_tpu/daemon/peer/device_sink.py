@@ -84,6 +84,10 @@ class TaskDeviceSink:
     def landed(self) -> set[int]:
         return self.sink.landed
 
+    @property
+    def content_length(self) -> int:
+        return self.sink.content_length
+
     def verify(self) -> None:
         try:
             self.sink.verify()
@@ -95,6 +99,9 @@ class TaskDeviceSink:
         self.verified_at = time.time()
 
     # Consumption — delegates to the HBMSink.
+
+    def as_words(self):
+        return self.sink.as_words()
 
     def as_bytes_array(self):
         return self.sink.as_bytes_array()
@@ -153,6 +160,10 @@ class DeviceSinkManager:
         # Tasks whose sink hit a device error mid-download: disk-only for
         # the rest of this attempt (cleared on discard → retry is fresh).
         self._degraded: set[str] = set()
+        # The FIRST device error that left a task disk-only, kept until
+        # the task's final progress reports it (outcome) or the task is
+        # discarded: the degrade contract stays, its silence does not.
+        self._errors: dict[str, str] = {}
         # Single worker: serializes sink mutation (HBMSink is not
         # thread-safe) and keeps device copies off the event loop.
         self._exec = ThreadPoolExecutor(
@@ -199,8 +210,13 @@ class DeviceSinkManager:
             # retry a doomed sink.
             log.warning("device landing failed; degrading to disk-only",
                         task=task_id[:16], error=str(e)[:200])
+            self._note_error(task_id, "landing", e)
             self._sinks.pop(task_id, None)
             self._degraded.add(task_id)
+
+    def _note_error(self, task_id: str, stage: str, err) -> None:
+        text = err if isinstance(err, str) else f"{type(err).__name__}: {err}"
+        self._errors.setdefault(task_id, f"{stage}: {text}"[:600])
 
     def _create(self, task_id: str, content_length: int,
                 piece_size: int) -> TaskDeviceSink | None:
@@ -237,6 +253,9 @@ class DeviceSinkManager:
             else:
                 log.warning("device sink cap reached; landing to disk only",
                             task=task_id[:16], cap=self.max_tasks)
+                self._note_error(
+                    task_id, "create",
+                    f"sink cap reached ({self.max_tasks} tasks resident)")
                 return None
         try:
             sink = TaskDeviceSink(task_id, content_length, piece_size,
@@ -247,6 +266,7 @@ class DeviceSinkManager:
             # rather than failing the whole download.
             log.warning("device sink unavailable for task",
                         task=task_id[:16], error=str(e)[:200])
+            self._note_error(task_id, "create", e)
             return None
         self._sinks[task_id] = sink
         log.info("device sink created", task=task_id[:16],
@@ -278,6 +298,7 @@ class DeviceSinkManager:
             # device-side hiccup.
             log.warning("device finalize failed; disk-only result",
                         task=task_id[:16], error=str(e)[:200])
+            self._note_error(task_id, "finalize", e)
             self._sinks.pop(task_id, None)
             return None
 
@@ -338,6 +359,19 @@ class DeviceSinkManager:
     def discard(self, task_id: str) -> None:
         self._sinks.pop(task_id, None)
         self._degraded.discard(task_id)
+        self._errors.pop(task_id, None)
+
+    def outcome(self, task_id: str, verified: bool) -> dict:
+        """What a device request's final progress says beside
+        ``device_verified``: the device that holds the bytes, or the
+        error that kept them off it."""
+        sink = self._sinks.get(task_id)
+        if verified and sink is not None:
+            self._errors.pop(task_id, None)
+            return {"device_platform": sink.sink.platform,
+                    "device_kind": sink.sink.device_kind}
+        error = self._errors.pop(task_id, "")
+        return {"device_error": error} if error else {}
 
     def gc(self) -> None:
         """Periodic TTL sweep (daemon GC hook) — unclaimed sinks must not

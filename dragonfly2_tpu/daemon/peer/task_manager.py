@@ -121,6 +121,12 @@ class FileTaskProgress:
     # True when the content also landed in a device sink and passed
     # on-device verification (device="tpu" requests).
     device_verified: bool = False
+    # Which device holds the verified bytes (jax's platform and
+    # device_kind of the sink's device), or the first device error that
+    # left a device="tpu" request with a disk-only result.
+    device_platform: str = ""
+    device_kind: str = ""
+    device_error: str = ""
 
     def to_wire(self) -> dict:
         return {
@@ -136,6 +142,9 @@ class FileTaskProgress:
             "from_reuse": self.from_reuse,
             "from_p2p": self.from_p2p,
             "device_verified": self.device_verified,
+            "device_platform": self.device_platform,
+            "device_kind": self.device_kind,
+            "device_error": self.device_error,
         }
 
 
@@ -509,7 +518,8 @@ class TaskManager:
                                        peer_id=peer_id, error=e.to_wire())
                 return
             yield self._final_progress(reused, task_id, peer_id,
-                                       from_reuse=True, device_verified=dev)
+                                       from_reuse=True, device=req.device,
+                                       device_verified=dev)
             return
 
         # 1b. Ranged request: serve the slice off the whole-content parent
@@ -567,7 +577,8 @@ class TaskManager:
                                        peer_id=peer_id, error=e.to_wire())
                 return
             yield self._final_progress(store, task_id, peer_id,
-                                       from_reuse=True, device_verified=dev)
+                                       from_reuse=True, device=req.device,
+                                       device_verified=dev)
             return
 
         store = self.storage.register_task(
@@ -653,6 +664,7 @@ class TaskManager:
                                    peer_id=peer_id, error=e.to_wire())
             return
         yield self._final_progress(store, task_id, peer_id, from_p2p=from_p2p,
+                                   device=req.device,
                                    device_verified=device_verified)
 
     # -- delta task (checkpoint-delta plane, delta/resolver.py) ------------
@@ -1075,8 +1087,13 @@ class TaskManager:
 
     def _final_progress(self, store, task_id: str, peer_id: str, *,
                         from_reuse: bool = False, from_p2p: bool = False,
+                        device: str = "",
                         device_verified: bool = False) -> FileTaskProgress:
         m = store.metadata
+        # Only the request that asked for the device reads (and clears)
+        # its landing's outcome.
+        outcome = (self.device_sinks.outcome(task_id, device_verified)
+                   if device and self.device_sinks is not None else {})
         return FileTaskProgress(
             state="done",
             task_id=task_id,
@@ -1089,6 +1106,7 @@ class TaskManager:
             from_reuse=from_reuse,
             from_p2p=from_p2p,
             device_verified=device_verified,
+            **outcome,
         )
 
     def _discard_sink(self, req: "FileTaskRequest", task_id: str) -> None:

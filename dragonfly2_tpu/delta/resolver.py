@@ -562,7 +562,7 @@ async def _assemble(tm, req, store, base_store, new_m: DeltaManifest,
              reused_mb=round(stats["reused_bytes"] / 1e6, 2),
              fetched_mb=round(stats["fetched_bytes"] / 1e6, 2),
              corrupt_base=stats["corrupt_base"])
-    yield tm._final_progress(store, task_id, peer_id,
+    yield tm._final_progress(store, task_id, peer_id, device=req.device,
                              device_verified=device_verified)
 
 

@@ -1,0 +1,32 @@
+"""Where this checkout keeps jax's persistent compilation cache.
+
+The cache directory is part of every entry's key, so it has to be the same
+path in every process and every run: a path from ``tempfile``, a pid or
+the clock never hits. Whoever deploys the daemon places the cache from
+outside with ``JAX_COMPILATION_CACHE_DIR``; without it the cache sits in
+the checkout, beside the package, in the git-ignored ``.jax_cache``.
+"""
+
+from __future__ import annotations
+
+import os
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_DIR = os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def place_compile_cache() -> str:
+    """Returns the cache directory in force. With
+    ``JAX_COMPILATION_CACHE_DIR`` set, jax has already read it and nothing
+    is set here. Otherwise the in-checkout directory is configured, and
+    every program is kept whatever its compile time: a checkpoint load
+    compiles a dozen sub-second view programs besides the assembly."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return DEFAULT_DIR

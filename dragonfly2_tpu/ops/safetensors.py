@@ -1,11 +1,12 @@
-"""Zero-extra-copy safetensors views over the device sink's landed bytes.
+"""Safetensors views over the device sink's landed bytes.
 
-The north-star payload is a sharded safetensors checkpoint
-(BASELINE.json: Llama-3-70B to every host). Once the P2P fabric lands the
-file in HBM (ops/hbm_sink.py), this module turns it into named tensors
-WITHOUT a host round trip: the 8-byte header length and the JSON header
-are fetched to host (tiny), and each tensor is a bitcast slice of the
-device-resident byte buffer.
+The north-star payload is a sharded safetensors checkpoint. Once the P2P
+fabric lands the file in HBM (ops/hbm_sink.py), this module turns it into
+named tensors WITHOUT a host round trip: the 8-byte header length and the
+JSON header are fetched to host (tiny), and each tensor is cut from the
+device-resident buffer — the sink's uint32 words, or a uint8 buffer on the
+hot-swap path — by ops/bitview.py, whose forms the TPU compiler accepts
+at checkpoint-shard sizes.
 
 Format (https://github.com/huggingface/safetensors — stable, public):
   [u64 little-endian header_len][header_len bytes of JSON][tensor data]
@@ -24,6 +25,8 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from dragonfly2_tpu.ops import bitview
 
 _DTYPES = {
     "F64": jnp.float64, "F32": jnp.float32, "F16": jnp.float16,
@@ -74,13 +77,16 @@ def header_metadata(header: dict) -> dict[str, str]:
     return dict(meta)
 
 
-def tensor_views(u8: jax.Array, header: dict, data_start: int,
-                 names: list[str] | None = None) -> dict[str, jax.Array]:
-    """Named device tensors as bitcast slices of the landed u8 buffer.
-    Slices fuse into the consuming computation — no materialized copy
-    until a tensor is actually used (or device_put to a sharding)."""
+def tensor_views(buffer: jax.Array, header: dict, data_start: int,
+                 names: list[str] | None = None, *,
+                 total: int | None = None) -> dict[str, jax.Array]:
+    """Named device tensors cut from the landed buffer: flat uint32 words
+    (``HBMSink.as_words``; pass the content length as ``total``, since the
+    words are padded to whole pieces) or flat uint8 bytes. Each tensor is
+    its own device array in its checkpoint dtype and shape."""
     out: dict[str, jax.Array] = {}
-    total = int(u8.shape[0])
+    if total is None:
+        total = int(buffer.shape[0]) * buffer.dtype.itemsize
     if not isinstance(header, dict):
         raise SafetensorsError(
             f"header must be a JSON object, got {type(header).__name__}")
@@ -123,22 +129,9 @@ def tensor_views(u8: jax.Array, header: dict, data_start: int,
             raise SafetensorsError(
                 f"{name}: data_offsets [{begin}, {end}] outside content "
                 f"({total - data_start} data bytes)")
-        raw = u8[data_start + begin: data_start + end]
+        at = data_start + begin
         canon = jax.dtypes.canonicalize_dtype(dtype)
-        if count == 0:
-            # Zero-length tensors (a 0 dim, data_offsets [s, s]) are
-            # legal safetensors; there are no bytes to bitcast (and no
-            # values for the 64-bit range checks to refuse), so build
-            # the empty view directly in the canonical dtype.
-            out[name] = jnp.zeros(
-                shape, dtype=jnp.bool_ if np.dtype(canon) == np.bool_
-                else canon)
-            continue
-        if np.dtype(canon) == np.bool_:
-            # bitcast_convert_type refuses bool targets; BOOL is one
-            # byte of 0/1 — compare instead.
-            t = (raw != 0)
-        elif canon.itemsize != itemsize:
+        if count and canon.itemsize != itemsize:
             # jax x64 disabled: 64-bit dtypes canonicalize to 32-bit.
             # Keeping the low word is exact only when the high word is
             # the sign/zero extension — float64 low words are mantissa
@@ -148,9 +141,7 @@ def tensor_views(u8: jax.Array, header: dict, data_start: int,
                 raise SafetensorsError(
                     f"{name}: F64 requires jax x64 mode "
                     "(jax.config.update('jax_enable_x64', True))")
-            pair = jax.lax.bitcast_convert_type(
-                raw.reshape(count, itemsize // canon.itemsize,
-                            canon.itemsize), canon)
+            pair = bitview.typed_view(buffer, at, canon, (count, 2))
             t = pair[:, 0]
             hi = pair[:, 1]
             signed = np.issubdtype(np.dtype(canon), np.signedinteger)
@@ -161,12 +152,12 @@ def tensor_views(u8: jax.Array, header: dict, data_start: int,
                 raise SafetensorsError(
                     f"{name}: {meta['dtype']} values exceed 32 bits; "
                     "enable jax x64 mode to load exactly")
-        elif itemsize == 1:
-            t = jax.lax.bitcast_convert_type(raw, dtype)
+            out[name] = t.reshape(shape)
         else:
-            t = jax.lax.bitcast_convert_type(
-                raw.reshape(count, itemsize), dtype)
-        out[name] = t.reshape(shape)
+            # Zero-length tensors (a 0 dim, data_offsets [s, s]) are
+            # legal safetensors and come back empty in the canonical
+            # dtype; BOOL is one byte of 0/1.
+            out[name] = bitview.typed_view(buffer, at, canon, shape)
     if names is not None:
         missing = [n for n in names if n not in out]
         if missing:
@@ -181,15 +172,16 @@ def load_from_sink(sink, *, names: list[str] | None = None,
     maps tensor name → jax.sharding.Sharding; matching tensors are
     device_put to their sharding (device-to-device over ICI on a slice),
     the rest stay on the sink's device."""
-    u8 = sink.as_bytes_array()
+    words = sink.as_words()
+    total = sink.content_length
     # Header prefix to host: 8 bytes, then exactly the header. Two tiny
     # fetches instead of guessing a prefix size.
-    n = int.from_bytes(np.asarray(u8[:8]).tobytes(), "little")
-    if 8 + n > u8.shape[0]:
+    n = int.from_bytes(bitview.host_bytes(words, 0, min(8, total)),
+                       "little")
+    if 8 + n > total:
         raise SafetensorsError("header length exceeds content")
-    head = np.asarray(u8[: 8 + n]).tobytes()
-    header, data_start = parse_header(head)
-    tensors = tensor_views(u8, header, data_start, names)
+    header, data_start = parse_header(bitview.host_bytes(words, 0, 8 + n))
+    tensors = tensor_views(words, header, data_start, names, total=total)
     if shardings:
         unknown = [n for n in shardings if n not in tensors]
         if unknown:

@@ -17,19 +17,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:     # pre-0.6 jax: same callable, experimental home
-    from jax.experimental.shard_map import shard_map
-
-# The "skip the replication/varying-manifest check" kwarg was renamed
-# check_rep → check_vma across jax versions; pass whichever this one has.
-import inspect as _inspect
-
-_NO_CHECK = ({"check_vma": False}
-             if "check_vma" in _inspect.signature(shard_map).parameters
-             else {"check_rep": False})
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from dragonfly2_tpu.ops import bitview
 
 
 def make_mesh(n_devices: int | None = None, axis_name: str = "d") -> Mesh:
@@ -58,7 +49,7 @@ def _all_gather_jit(x, *, mesh: Mesh, axis_name: str):
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=P(axis_name), out_specs=P(),
-        **_NO_CHECK,
+        check_vma=False,
     )
     def gather(shard):
         return jax.lax.all_gather(shard, axis_name, axis=0, tiled=True)
@@ -83,7 +74,7 @@ def _ring_all_gather_jit(x, *, mesh: Mesh, axis_name: str):
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=P(axis_name), out_specs=P(axis_name),
-        **_NO_CHECK,
+        check_vma=False,
     )
     def ring(shard):
         # shard: [chunk, ...] local block. Accumulate n blocks stacked on a
@@ -133,7 +124,7 @@ def _chunked_ring_all_gather_jit(x, *, mesh: Mesh, axis_name: str,
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=P(axis_name), out_specs=P(),
-        **_NO_CHECK,
+        check_vma=False,
     )
     def gather(shard):
         axis_index = jax.lax.axis_index(axis_name)
@@ -230,7 +221,4 @@ class StripedBroadcast:
 def bitcast_landed_bytes(buffer, dtype, shape):
     """Reinterpret fabric-landed uint8 HBM bytes as a checkpoint tensor
     without leaving the device (e.g. bf16 weights)."""
-    target = jnp.dtype(dtype)
-    flat = buffer[: int(np.prod(shape)) * target.itemsize]
-    return jax.lax.bitcast_convert_type(
-        flat.reshape(-1, target.itemsize), target).reshape(shape)
+    return bitview.typed_view(buffer, 0, dtype, shape)

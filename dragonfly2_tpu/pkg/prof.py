@@ -688,28 +688,3 @@ def release(obs: RuntimeObservatory) -> None:
 
 def observatory() -> "RuntimeObservatory | None":
     return _OBS
-
-
-def fallback_snapshot(top: int = 5) -> dict:
-    """Runtime snapshot for bench.py's structured device fallback: where
-    the probe attempt spent its wall time (sampler top frames), RSS, and
-    loop lag if a probe is armed. Works unarmed (frames empty)."""
-    obs = _OBS
-    proc = proc_stats()
-    out = {
-        "rss_mb": round(proc["rss_bytes"] / 1e6, 1),
-        "open_fds": proc["open_fds"],
-        "threads": proc["threads"],
-        "samples": 0,
-        "top_self": [],
-        "max_loop_lag_ms": None,
-        "gc_collections": None,
-    }
-    if obs is not None:
-        out["samples"] = obs.sampler.samples
-        out["top_self"] = obs.sampler.top_frames(top)
-        out["gc_collections"] = sum(obs.gc.collections)
-        if obs.probes:
-            out["max_loop_lag_ms"] = round(
-                max(p.max_lag_s for p in obs.probes.values()) * 1000, 2)
-    return out

@@ -1,0 +1,261 @@
+"""The served path's device programs, compiled for a described v5e.
+
+Nothing runs here: the TPU compiler that is installed beside jax compiles
+each program of the landing and view path for a v5e:2x2 that is described,
+not attached, at the sizes the chip smoke lands (1.7 GiB of content,
+32 MiB pieces in batches of 8) and at the old 4 MiB geometry. What it
+refuses here it refuses on the chip, and ``memory_analysis()`` is what the
+memory figures in ops/hbm_sink.py and PERF.md quote.
+
+All cases live in this one file and describe the topology inside a
+module-scoped fixture: only one process may load the TPU library, so the
+call must not run at import (every xdist worker imports every file).
+"""
+
+import functools
+import os
+
+import numpy as np
+import pytest
+
+MiB = 1 << 20
+CONTENT = 1700 * MiB + 4 * 12345      # about one checkpoint shard
+EMBED = (163840, 2048)                # Moonlight-16B-A3B embed_tokens, bf16
+EXPERT = (1408, 2048)                 # one routed-expert matrix, 5.5 MiB
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip; the sink's daemon turns
+    # the cache on in this process when another test starts one.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _memory(fn, *specs):
+    """(argument, output, temporary) bytes of ``fn`` compiled for the
+    described chip; raises what the chip's compiler would raise."""
+    import jax
+
+    m = jax.jit(fn).lower(*specs).compile().memory_analysis()
+    return (m.argument_size_in_bytes, m.output_size_in_bytes,
+            m.temp_size_in_bytes)
+
+
+def _batches(n: int, piece_mib: int, sharding):
+    import jax.numpy as jnp
+
+    return tuple(_spec((8, piece_mib * MiB // 4), jnp.uint32, sharding)
+                 for _ in range(n))
+
+
+# (piece MiB, batches of 8 pieces): the smoke's 1.75 GiB, and 512 MiB at
+# the geometry every earlier reading used.
+GEOMETRIES = [(32, 7), (4, 16)]
+
+
+@pytest.mark.parametrize("piece_mib,n_batches", GEOMETRIES)
+def test_assemble_checksum_is_three_times_content(one_chip, piece_mib,
+                                                  n_batches):
+    from dragonfly2_tpu.ops.hbm_sink import _assemble_checksum_jit
+
+    plan = tuple(("b", bi, 0, 8) for bi in range(n_batches))
+    content = n_batches * 8 * piece_mib * MiB
+    arg, out, temp = _memory(
+        functools.partial(_assemble_checksum_jit, plan=plan,
+                          piece_words=piece_mib * MiB // 4),
+        _batches(n_batches, piece_mib, one_chip))
+    assert arg >= content and out >= content
+    assert arg + out + temp <= 3.05 * content
+
+
+@pytest.mark.parametrize("piece_mib,n_batches", GEOMETRIES)
+def test_gather_checksum_is_five_times_content(one_chip, piece_mib,
+                                               n_batches):
+    """The fragmented-arrival path (more than 128 segments, so never at
+    the smoke's 55 pieces): 5.0x content at 4 MiB pieces, 5.1x at 32 MiB."""
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops.hbm_sink import _gather_checksum_jit
+
+    content = n_batches * 8 * piece_mib * MiB
+    arg, out, temp = _memory(
+        functools.partial(_gather_checksum_jit,
+                          piece_words=piece_mib * MiB // 4),
+        _batches(n_batches, piece_mib, one_chip),
+        _spec((n_batches * 8,), jnp.int32, one_chip))
+    assert arg + out + temp <= 5.2 * content
+
+
+def test_merge_group_of_4mib_batches(one_chip):
+    from dragonfly2_tpu.ops.hbm_sink import HBMSink, _merge_jit
+
+    group = HBMSink._MERGE_GROUP
+    arg, out, temp = _memory(_merge_jit, _batches(group, 4, one_chip))
+    assert out == group * 32 * MiB and temp <= out // 8
+
+
+def test_merge_group_of_32mib_batches_needs_the_whole_chip(one_chip):
+    """32 batches of 256 MiB are 8 GiB in and 8 GiB out. The compiler
+    passes it, since it counts the program and not its arguments, but a
+    landing at the 32 MiB geometry cannot reach its first merge on 16 GB;
+    it is past the 3x limit of the assembly long before (ROADMAP S2)."""
+    from dragonfly2_tpu.ops.hbm_sink import HBMSink, _merge_jit
+
+    arg, out, temp = _memory(
+        _merge_jit, _batches(HBMSink._MERGE_GROUP, 32, one_chip))
+    assert arg + out >= 16 * 1024 * MiB
+
+
+@pytest.mark.parametrize("piece_mib", [32, 4])
+def test_chunk_checksums_relayout_is_one_content(one_chip, piece_mib):
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops.checksum import _chunk_checksums_xla
+
+    content = 1728 * MiB
+    arg, out, temp = _memory(
+        functools.partial(_chunk_checksums_xla,
+                          piece_words=piece_mib * MiB // 4),
+        _spec((content // 4,), jnp.uint32, one_chip))
+    assert temp <= 1.05 * content
+
+
+def _words(sharding):
+    import jax.numpy as jnp
+
+    return _spec((-(-CONTENT // 4),), jnp.uint32, sharding)
+
+
+def test_byte_view_of_the_whole_content(one_chip):
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops import bitview
+
+    arg, out, temp = _memory(
+        functools.partial(bitview._words_view_jit, shift=0,
+                          dtype=jnp.dtype(jnp.uint8), shape=(CONTENT - 3,)),
+        _words(one_chip), _spec((), jnp.int32, one_chip))
+    assert out >= CONTENT - 3 and temp <= 1.05 * out
+
+
+@pytest.mark.parametrize("shape,shift,factor", [
+    (EMBED, 2, 2.05),       # 640 MiB starting 2 bytes into a word
+    (EMBED, 0, 2.05),       # the same, word-aligned
+    (EXPERT, 3, 1.0),       # 5.5 MiB at an odd byte
+])
+def test_typed_view_from_words(one_chip, shape, shift, factor):
+    """The program ``typed_view`` dispatches for a word buffer: the word offset is traced,
+    so one program serves every tensor of a shape and alignment. A
+    16-bit float is its own size again in temporaries twice over: the
+    flatten after the loop, and the last bitcast from uint16."""
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops import bitview
+
+    arg, out, temp = _memory(
+        functools.partial(bitview._words_view_jit, shift=shift,
+                          dtype=jnp.dtype(jnp.bfloat16), shape=shape),
+        _words(one_chip), _spec((), jnp.int32, one_chip))
+    assert out == 2 * np.prod(shape) and temp <= factor * out
+
+
+def test_typed_view_from_bytes(one_chip):
+    """The hot-swap path's uint8 buffer: the embedding within 2.1x."""
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops import bitview
+
+    arg, out, temp = _memory(
+        functools.partial(bitview._bytes_view_jit,
+                          dtype=jnp.dtype(jnp.bfloat16), shape=EMBED),
+        _spec((CONTENT,), jnp.uint8, one_chip),
+        _spec((), jnp.int32, one_chip))
+    assert out == 2 * np.prod(EMBED) and temp <= 2.1 * out
+
+
+def test_record_batch_view(one_chip):
+    """dataset/device_feed.py lands one record per piece, the piece being
+    the record rounded up to a word: 6800 records of 256 KiB less 3."""
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops.hbm_sink import _record_batch_jit
+
+    records, piece = 6800, 256 * 1024
+    arg, out, temp = _memory(
+        functools.partial(_record_batch_jit, count=records, piece_size=piece,
+                          record_bytes=piece - 3),
+        _spec((records * piece // 4,), jnp.uint32, one_chip))
+    assert out >= records * (piece - 3) and temp <= 1.05 * out
+
+
+@pytest.mark.parametrize("piece_mib", [32, 4])
+def test_hot_swap_gate_reads_bytes_in_place(one_chip, piece_mib):
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops.hbm_sink import _u8_checksums_jit
+
+    arg, out, temp = _memory(
+        functools.partial(_u8_checksums_jit, piece_size=piece_mib * MiB),
+        _spec((CONTENT - 3,), jnp.uint8, one_chip))
+    assert temp <= 2 * piece_mib * MiB
+
+
+def _mesh_words(topo, n_words: int):
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(topo.devices), ("d",))
+    return mesh, _spec((n_words,), jnp.uint32, NamedSharding(mesh, P("d")))
+
+
+def test_all_gather_on_four_chips(topo):
+    from dragonfly2_tpu.parallel.ici import _all_gather_jit
+
+    words = 1728 * MiB // 4
+    mesh, spec = _mesh_words(topo, words)
+    arg, out, temp = _memory(
+        functools.partial(_all_gather_jit, mesh=mesh, axis_name="d"), spec)
+    assert arg == words and out == 4 * words     # a quarter in, all out
+
+
+def test_chunked_ring_on_four_chips(topo):
+    """Per chip: its quarter in, the whole content out, and temporaries
+    that must still fit beside the landed source on device 0."""
+    from dragonfly2_tpu.parallel.ici import _chunked_ring_all_gather_jit
+
+    words = 1728 * MiB // 4
+    mesh, spec = _mesh_words(topo, words)
+    arg, out, temp = _memory(
+        functools.partial(_chunked_ring_all_gather_jit, mesh=mesh,
+                          axis_name="d", n_chunks=4), spec)
+    assert out == 4 * words
+    assert arg + out + temp + 4 * words <= 15 * 1024 * MiB

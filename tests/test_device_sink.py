@@ -17,6 +17,7 @@ import hashlib
 import random
 
 import numpy as np
+import pytest
 
 from dragonfly2_tpu.client import dfget as dfget_lib
 from dragonfly2_tpu.client import device as device_lib
@@ -223,7 +224,17 @@ def test_sink_unavailable_degrades_to_disk(run_async, tmp_path):
                 allow_source_fallback=False, timeout=60.0))
             assert r["state"] == "done"
             assert not r["device_verified"]
+            # The degrade is not silent: the first device error rides
+            # the final progress, and the client API raises it.
+            assert "sink cap reached" in r["device_error"]
+            assert r["device_platform"] == ""
             assert (tmp_path / "o").read_bytes() == CONTENT
+
+            from dragonfly2_tpu.client.device import download_to_device
+            from dragonfly2_tpu.pkg.errors import DfError
+
+            with pytest.raises(DfError, match="sink cap reached"):
+                await download_to_device(peer, url, digest=SHA)
         finally:
             for d in daemons:
                 await d.stop()
