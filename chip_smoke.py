@@ -6,14 +6,17 @@
 What is pulled: a safetensors file made from a seed inside the run —
 ``model.embed_tokens`` plus the whole first MoE layer of Moonlight-16B-A3B at
 its published widths in bf16, one tensor per expert matrix, DeepSeek-V3
-names: about 1.7 GiB in 205 tensors. Through what: a byte-counting HTTP
-origin, a scheduler and a seed-peer daemon, each a child process that never
-imports jax, and the chip-holding peer daemon embedded in THIS process (the
-only one that touches jax). What is checked: the content came over P2P with
-the origin serving it about once, every piece verified on device, every
-tensor is a TPU array of its published dtype and shape, sampled tensors are
-bit-exact against the generator, and ``dfget --device tpu`` exits 0 on a
-landing and non-zero when the sink cannot land.
+names: about 1.7 GiB in 205 tensors, and behind them two small tensors of
+random bytes that are not the model's (every bit pattern, through the
+integer views). Through what: a byte-counting HTTP origin, a scheduler and
+a seed-peer daemon, each a child process that never imports jax, and the
+chip-holding peer daemon embedded in THIS process (the only one that
+touches jax). What is checked: the content came over P2P with the origin
+serving it about once, every piece verified on device, every tensor is a
+TPU array of its published dtype and shape, sampled tensors are bit-exact
+against the generator (the weights are finite normal numbers: the chip
+canonicalises NaNs and flushes denormals in 16-bit floats), and ``dfget
+--device tpu`` exits 0 on a landing and non-zero when the sink cannot land.
 
 The last line of stdout is one JSON object; ``"ok": true`` only on a TPU.
 The phases are plain functions taking the object's widths, so the tests
@@ -71,7 +74,10 @@ MOONLIGHT = Widths(hidden=2048, vocab=163840, routed=64, shared=2,
 REDUCED = {"layers": "27 -> 1 (layer 1, the first MoE layer; layer 0 and "
                      "layers 2-26, the final norm and lm_head are left out)",
            "widths": "published, untouched",
-           "weights": f"random finite normal values, seed {SEED}"}
+           "weights": f"random finite normal values, seed {SEED}; after "
+                      "them two tensors of random BYTES (U16, U8) that are "
+                      "not the model's, so that every bit pattern passes "
+                      "through a view that is exact on the chip"}
 
 
 def tensor_table(w: Widths) -> list[tuple[str, str, tuple[int, ...]]]:
@@ -102,7 +108,18 @@ def tensor_table(w: Widths) -> list[tuple[str, str, tuple[int, ...]]]:
             (layer + prefix + "up_proj.weight", "BF16", (width, w.hidden)),
             (layer + prefix + "down_proj.weight", "BF16", (w.hidden, width)),
         ]
+    # Not the model's. The weights above are finite normal numbers, as
+    # weights are; these two carry every bit pattern, NaN payloads and
+    # denormals included, through the integer views, which are exact on
+    # the chip (see CheckpointObject.tensor_bytes). Odd counts, so that
+    # the views end off a word; they sort after the model, at the end of
+    # the file.
+    rows += [("smoke.random_bits.u16", "U16", (w.hidden * 512 + 1,)),
+             ("smoke.random_bits.u8", "U8", (w.hidden * 256 + 3,))]
     return sorted(rows)
+
+
+ITEM_BYTES = {"BF16": 2, "F32": 4, "U16": 2, "U8": 1}
 
 
 class CheckpointObject:
@@ -116,7 +133,7 @@ class CheckpointObject:
         header = {}
         at = 0
         for name, dtype, shape in self.tensors:
-            size = int(np.prod(shape)) * {"BF16": 2, "F32": 4}[dtype]
+            size = int(np.prod(shape)) * ITEM_BYTES[dtype]
             header[name] = {"dtype": dtype, "shape": list(shape),
                             "data_offsets": [at, at + size]}
             self.spans[name] = (at, at + size)
@@ -132,17 +149,20 @@ class CheckpointObject:
         self.length = self.data_start + at
 
     def tensor_bytes(self, name: str) -> bytes:
-        """Random weights that are weights: finite, normal numbers of
-        magnitude 2**-27 .. 2**5, every sign and mantissa bit random. Not
-        random bytes: one 16-bit pattern in 128 is a NaN or a denormal, no
-        checkpoint stores either, and the TPU rewrites both (NaN payloads
+        """Float tensors are random weights that are weights: finite,
+        normal numbers of magnitude 2**-27 .. 2**5, every sign and
+        mantissa bit random. Not random bytes: one 16-bit pattern in 128
+        is a NaN or a denormal, and the TPU rewrites both (NaN payloads
         to 0x7fc0, denormals to zero) in any op that produces a 16-bit
-        float, even a row slice — seen on the chip, PR 22."""
+        float, even a row slice — seen on the chip, PR 22. The integer
+        tensors are random bytes, every pattern among them."""
         index, (_, dtype, _) = next(
             (i, t) for i, t in enumerate(self.tensors) if t[0] == name)
         begin, end = self.spans[name]
-        uint, mantissa = {"BF16": (np.uint16, 7), "F32": (np.uint32, 23)}[dtype]
         raw = np.random.default_rng([self.seed, index]).bytes(end - begin)
+        if dtype not in ("BF16", "F32"):
+            return raw
+        uint, mantissa = {"BF16": (np.uint16, 7), "F32": (np.uint32, 23)}[dtype]
         bits = np.frombuffer(raw, np.dtype(uint).newbyteorder("<"))
         exponent = uint(100) + ((bits >> uint(mantissa)) & uint(0x1F))
         keep = uint(~(0xFF << mantissa) & np.iinfo(uint).max)
@@ -482,7 +502,8 @@ async def phase_a(fabric: Fabric, widths: Widths, device) -> None:
     jax.block_until_ready(list(tensors.values()))
     say(f"load_safetensors: {len(tensors)} tensors resident in "
         f"{time.monotonic() - t0:.1f}s; {device_memory(device)}")
-    dtypes = {"BF16": "bfloat16", "F32": "float32"}
+    dtypes = {"BF16": "bfloat16", "F32": "float32", "U16": "uint16",
+              "U8": "uint8"}
     require(set(tensors) == {n for n, _, _ in obj.tensors},
             "tensor names differ from the file's")
     for name, dtype, shape in obj.tensors:
@@ -502,7 +523,8 @@ async def phase_a(fabric: Fabric, widths: Widths, device) -> None:
         require(np.array_equal(got, want if rows is None else want[rows]),
                 f"{name}: bytes on the device differ from the generator's")
 
-    by_offset = sorted(obj.spans, key=lambda n: obj.spans[n][0])
+    by_offset = sorted((n for n in obj.spans if n.startswith("model.")),
+                       key=lambda n: obj.spans[n][0])
     layer = "model.layers.1.mlp.experts."
     exact("model.embed_tokens.weight", slice(0, 1))
     exact("model.embed_tokens.weight", slice(widths.vocab - 1, widths.vocab))
@@ -510,13 +532,14 @@ async def phase_a(fabric: Fabric, widths: Widths, device) -> None:
                layer + f"{widths.routed // 2}.down_proj.weight",
                layer + f"{widths.routed - 1}.up_proj.weight",
                "model.layers.1.mlp.gate.e_score_correction_bias",
-               by_offset[0], by_offset[-1]]
+               by_offset[0], by_offset[-1],
+               "smoke.random_bits.u16", "smoke.random_bits.u8"]
     for name in sampled:
         await asyncio.to_thread(exact, name)
     say(f"bit-exact: embedding rows 0 and {widths.vocab - 1}, and whole: "
-        + ", ".join(sampled) + f" (smallest offset {by_offset[0]}, "
-        f"largest {by_offset[-1]}; data starts at byte {obj.data_start}, "
-        f"{obj.data_start % 4} into a word)")
+        + ", ".join(sampled) + f" (the model's smallest offset "
+        f"{by_offset[0]}, largest {by_offset[-1]}; data starts at byte "
+        f"{obj.data_start}, {obj.data_start % 4} into a word)")
 
 
 async def phase_b(fabric: Fabric, widths: Widths, device) -> None:
@@ -694,17 +717,20 @@ async def timed(name: str, phase) -> None:
 
 
 def scratch_home() -> str:
-    """The run's DF_HOME: in the checkout, else under the temporary
-    directory — wherever the daemon's unix socket stays inside the 108
-    bytes a socket path may have."""
+    """A new directory for this run's DF_HOME, which no other run shares:
+    in the checkout, else under the temporary directory — wherever the
+    daemon's unix socket stays inside the 108 bytes a socket path may
+    have."""
     import tempfile
 
-    for parent in (HERE, tempfile.gettempdir(), "/tmp"):
-        home = os.path.join(parent, ".chip_smoke_home")
-        if len(os.path.join(home, "peer", "run", "dfdaemon.sock")) <= 100:
-            shutil.rmtree(home, ignore_errors=True)
-            return home
-    raise SmokeFailure("no directory short enough for the daemon's socket")
+    parents = (HERE, tempfile.gettempdir())
+    for parent in parents:
+        sock = os.path.join(parent, ".chip_smoke_12345678", "peer", "run",
+                            "dfdaemon.sock")
+        if len(sock) <= 100:
+            return tempfile.mkdtemp(prefix=".chip_smoke_", dir=parent)
+    raise SmokeFailure("the daemon's unix socket path would be too long "
+                       f"under any of {parents}")
 
 
 def describe(devices) -> dict:
@@ -761,9 +787,10 @@ def main(argv: list[str] | None = None) -> int:
         f"{len(obj.tensors)} tensors, {obj.length} bytes "
         f"({obj.length / 2**30:.3f} GiB); reduced: {json.dumps(REDUCED)}")
     say(native_rungs())
-    home = scratch_home()
     t0 = time.monotonic()
+    home = None
     try:
+        home = scratch_home()
         asyncio.run(run(MOONLIGHT, args.chips, home, devices))
     except Exception as e:
         say(meter.line())
@@ -771,7 +798,8 @@ def main(argv: list[str] | None = None) -> int:
                           "error": f"{type(e).__name__}: {e}"[:2000]}))
         return 1
     finally:
-        shutil.rmtree(home, ignore_errors=True)
+        if home is not None:
+            shutil.rmtree(home, ignore_errors=True)
     say(meter.line())
     say(f"cache entries after the run: {entries(cache_dir)}; "
         f"memory: {device_memory(devices[0])}; "

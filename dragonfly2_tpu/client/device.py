@@ -52,7 +52,9 @@ class DeviceResult:
                          shardings: dict | None = None):
         """The landed content as named checkpoint tensors (the content
         must be a safetensors file): typed device arrays cut from the HBM
-        buffer, optionally device_put to per-tensor shardings."""
+        buffer, optionally device_put to per-tensor shardings. On a TPU,
+        BF16/F16 denormals come back as zero and NaN payloads as the
+        canonical NaN (ops/bitview.py); every other value is exact."""
         from dragonfly2_tpu.ops import safetensors as st
 
         return st.load_from_sink(self.sink, names=names,

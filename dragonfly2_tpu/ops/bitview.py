@@ -173,10 +173,21 @@ def typed_view(buffer, byte_offset: int, dtype, shape):
                                shift=byte_offset % 4, dtype=dtype,
                                shape=shape)
     if buffer.dtype == jnp.uint8:
+        check_u8_indexable(buffer)
         return _bytes_view_jit(buffer, jnp.int32(byte_offset), dtype=dtype,
                                shape=shape)
     raise TypeError(f"landed buffer must be uint32 or uint8, "
                     f"got {buffer.dtype}")
+
+
+def check_u8_indexable(u8) -> None:
+    """The byte programs index with int32: a uint8 buffer stops at 2 GiB.
+    (The word buffer of a landing reaches 8 GiB, past what a 16 GB chip
+    can assemble.)"""
+    if u8.shape[0] >= 1 << 31:
+        raise ValueError(
+            f"uint8 device buffer of {u8.shape[0]} bytes: the byte views "
+            "and the hot-swap gate index with int32 and stop at 2 GiB")
 
 
 def host_bytes(buffer, start: int, stop: int) -> bytes:

@@ -18,15 +18,16 @@ DEFAULT_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
 def place_compile_cache() -> str:
     """Returns the cache directory in force. With
-    ``JAX_COMPILATION_CACHE_DIR`` set, jax has already read it and nothing
-    is set here. Otherwise the in-checkout directory is configured, and
-    every program is kept whatever its compile time: a checkpoint load
-    compiles a dozen sub-second view programs besides the assembly."""
+    ``JAX_COMPILATION_CACHE_DIR`` set, jax has already read it and no
+    directory is set here; otherwise the in-checkout one is. Wherever the
+    cache is, every program is kept whatever its compile time: a
+    checkpoint load compiles a dozen sub-second view programs besides the
+    assembly, and jax's default keeps only what took a second."""
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return DEFAULT_DIR

@@ -561,6 +561,7 @@ def verify_u8_against_host(u8, piece_size: int,
     naming the first mismatching piece; the flip must not happen."""
     if piece_size % 4:
         raise ValueError(f"piece size {piece_size} not 4-byte aligned")
+    bitview.check_u8_indexable(u8)
     if u8.shape[0] == 0:
         sums = xors = np.zeros((1,), np.uint32)
     else:

@@ -83,7 +83,10 @@ def tensor_views(buffer: jax.Array, header: dict, data_start: int,
     """Named device tensors cut from the landed buffer: flat uint32 words
     (``HBMSink.as_words``; pass the content length as ``total``, since the
     words are padded to whole pieces) or flat uint8 bytes. Each tensor is
-    its own device array in its checkpoint dtype and shape."""
+    its own device array in its checkpoint dtype and shape, bit-identical
+    to the file except for what a TPU does to 16-bit floats: BF16/F16
+    NaN payloads come back as the canonical NaN and denormals as zero
+    (ops/bitview.py). Integer and F32 tensors keep every pattern."""
     out: dict[str, jax.Array] = {}
     if total is None:
         total = int(buffer.shape[0]) * buffer.dtype.itemsize

@@ -167,25 +167,32 @@ def test_byte_view_of_the_whole_content(one_chip):
     assert out >= CONTENT - 3 and temp <= 1.05 * out
 
 
-@pytest.mark.parametrize("shape,shift,factor", [
-    (EMBED, 2, 2.05),       # 640 MiB starting 2 bytes into a word
-    (EMBED, 0, 2.05),       # the same, word-aligned
-    (EXPERT, 3, 1.0),       # 5.5 MiB at an odd byte
+@pytest.mark.parametrize("dtype,shape,shift,factor", [
+    ("bfloat16", EMBED, 2, 2.05),   # 640 MiB starting 2 bytes into a word
+    ("bfloat16", EMBED, 0, 2.05),   # the same, word-aligned
+    ("bfloat16", EXPERT, 3, 1.0),   # 5.5 MiB at an odd byte
+    # The chip smoke's two tensors of random bytes: odd counts, so the
+    # view ends off a word, after the loops' blocks and their remainder.
+    ("uint16", (2048 * 512 + 1,), 2, 1.05),
+    ("uint8", (2048 * 256 + 3,), 0, 1.05),
 ])
-def test_typed_view_from_words(one_chip, shape, shift, factor):
-    """The program ``typed_view`` dispatches for a word buffer: the word offset is traced,
-    so one program serves every tensor of a shape and alignment. A
-    16-bit float is its own size again in temporaries twice over: the
-    flatten after the loop, and the last bitcast from uint16."""
+def test_typed_view_from_words(one_chip, dtype, shape, shift, factor):
+    """The program ``typed_view`` dispatches for a word buffer: the word
+    offset is traced, so one program serves every tensor of a shape and
+    alignment. A 16-bit float is its own size again in temporaries twice
+    over: the flatten after the loop, and the last bitcast from uint16;
+    an integer view once."""
     import jax.numpy as jnp
 
     from dragonfly2_tpu.ops import bitview
 
+    dtype = jnp.dtype(dtype)
     arg, out, temp = _memory(
-        functools.partial(bitview._words_view_jit, shift=shift,
-                          dtype=jnp.dtype(jnp.bfloat16), shape=shape),
+        functools.partial(bitview._words_view_jit, shift=shift, dtype=dtype,
+                          shape=shape),
         _words(one_chip), _spec((), jnp.int32, one_chip))
-    assert out == 2 * np.prod(shape) and temp <= factor * out
+    size = dtype.itemsize * np.prod(shape)
+    assert size <= out < size + 4096 and temp <= factor * out
 
 
 def test_typed_view_from_bytes(one_chip):
@@ -200,6 +207,22 @@ def test_typed_view_from_bytes(one_chip):
         _spec((CONTENT,), jnp.uint8, one_chip),
         _spec((), jnp.int32, one_chip))
     assert out == 2 * np.prod(EMBED) and temp <= 2.1 * out
+
+
+def test_byte_buffers_stop_at_2_gib():
+    """The byte programs index with int32; past it they say so instead
+    of wrapping."""
+    import jax
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops import bitview
+    from dragonfly2_tpu.ops.hbm_sink import verify_u8_against_host
+
+    big = jax.ShapeDtypeStruct((1 << 31,), jnp.uint8)
+    with pytest.raises(ValueError, match="2 GiB"):
+        bitview.typed_view(big, 1 << 20, jnp.bfloat16, (4, 4))
+    with pytest.raises(ValueError, match="2 GiB"):
+        verify_u8_against_host(big, 4 * MiB, {})
 
 
 def test_record_batch_view(one_chip):

@@ -25,7 +25,7 @@ sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
 
-# About 12 MiB: three 4 MiB pieces, 205 tensors.
+# About 12 MiB: three 4 MiB pieces, 205 + 2 tensors.
 TINY = chip_smoke.Widths(hidden=256, vocab=4096, routed=64, shared=2,
                          expert=96, kv_lora=64, heads=4, nope=32, rope=16,
                          v=32)
@@ -135,10 +135,42 @@ def test_script_alone_fails(tmp_path):
     assert proc.returncode != 0 and proc.stdout == ""
 
 
+def test_scratch_home_is_made_by_the_run_and_shared_with_no_other(
+        home, monkeypatch):
+    """Two runs on one machine never share a DF_HOME, and a run removes
+    nothing it did not make: no fixed path, inside or outside the
+    checkout."""
+    monkeypatch.setattr(chip_smoke, "HERE", home)
+    monkeypatch.setattr(tempfile, "tempdir", None)
+    bystander = os.path.join(home, ".chip_smoke_home")
+    os.mkdir(bystander)
+    first, second = chip_smoke.scratch_home(), chip_smoke.scratch_home()
+    assert first != second and os.path.isdir(bystander)
+    assert {os.path.dirname(first), os.path.dirname(second)} == {home}
+
+    # A checkout too deep for the daemon's socket: under TMPDIR instead.
+    deep = os.path.join(home, "d" * 80)
+    os.mkdir(deep)
+    monkeypatch.setattr(chip_smoke, "HERE", deep)
+    monkeypatch.setenv("TMPDIR", home)
+    monkeypatch.setattr(tempfile, "tempdir", None)      # cached by the above
+    assert os.path.dirname(chip_smoke.scratch_home()) == home
+    # Neither is short enough: the run fails and says why; no /tmp literal.
+    monkeypatch.setattr(tempfile, "tempdir", None)
+    monkeypatch.setenv("TMPDIR", deep)
+    with pytest.raises(chip_smoke.SmokeFailure, match="socket path"):
+        chip_smoke.scratch_home()
+
+
 def test_object_is_one_real_shard():
     obj = chip_smoke.checkpoint(chip_smoke.MOONLIGHT)
     sizes = {n: e - b for n, (b, e) in obj.spans.items()}
-    assert len(obj.tensors) == 205
+    # 205 of the model, and the two tensors of random bytes behind them.
+    assert len(sizes) == 207
+    extra = {n: sizes.pop(n) for n in list(sizes) if n.startswith("smoke.")}
+    assert len(sizes) == 205 and sum(extra.values()) < 4 << 20
+    assert max(obj.spans[n][1] for n in sizes) == min(
+        obj.spans[n][0] for n in extra)
     assert obj.length >= 1.7 * 2**30
     assert sizes["model.embed_tokens.weight"] == 163840 * 2048 * 2
     assert max(sizes.values()) == 640 << 20
@@ -158,6 +190,8 @@ def test_compile_cache_is_placed_from_outside_or_in_the_checkout(monkeypatch):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
         assert compile_cache.place_compile_cache() == "/somewhere/else"
         assert jax.config.jax_compilation_cache_dir == before[0]
+        # Sub-second view programs are kept wherever the cache is.
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
 
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         placed = compile_cache.place_compile_cache()

@@ -534,6 +534,26 @@ def test_dfget_device_request_without_a_landing_exits_nonzero(run_async,
             assert "did not land in the device sink" in log, log[-800:]
             with open(out, "rb") as f:
                 assert hashlib.sha256(f.read()).hexdigest() == SHA
+
+            # A dfget process that imported jax (it never may: the chip
+            # belongs to the daemon with the sink) finishes its download
+            # and then says so and exits 1 — no traceback over the result.
+            out2 = str(tmp_path / "withjax.bin")
+            env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path
+                       .dirname(os.path.dirname(os.path.abspath(__file__))))
+            env.pop("XLA_FLAGS", None)
+            p = await asyncio.to_thread(
+                subprocess.run,
+                [sys.executable, "-c",
+                 "import sys, jax; from dragonfly2_tpu.cli.main import main; "
+                 "sys.exit(main(sys.argv[1:]))", "dfget", url, "-O", out2,
+                 "--work-home", fab.homes["p1"], "--no-daemon",
+                 "--digest", f"sha256:{SHA}"],
+                env=env, capture_output=True, text=True, timeout=120)
+            assert p.returncode == 1, p.stderr[-800:]
+            assert "imported jax" in p.stderr and "Traceback" not in p.stderr
+            with open(out2, "rb") as f:
+                assert hashlib.sha256(f.read()).hexdigest() == SHA
         finally:
             await fab.teardown()
             await runner.cleanup()
