@@ -16,6 +16,12 @@ otherwise stall the daemon's event loop (upload serving, RPC) for the
 duration of each copy. The async surface awaits that thread, so the
 download path still backpressures on landing.
 
+Spans: that thread stamps its steps into the task's flight ring
+(``sink_land`` > ``sink_read``, ``sink_checksum``, ``sink_stage``,
+``sink_put``; ``sink_finalize`` > the backfill's ``sink_land``s,
+``sink_assemble`` > ``sink_compile``), one event at a step's end with its
+ms — a child is a span that lies inside another, there being one thread.
+
 Lifecycle: sinks are created lazily at the first landed piece (task
 metadata — length and piece size — is unknown at request time), verified
 at completion, and held up to a TTL for the consuming process to claim
@@ -33,7 +39,7 @@ import asyncio
 import time
 from concurrent.futures import ThreadPoolExecutor
 
-from dragonfly2_tpu.pkg import dflog, metrics
+from dragonfly2_tpu.pkg import dflog, flight as flightlib, metrics
 
 log = dflog.get("peer.device_sink")
 
@@ -41,10 +47,39 @@ SINK_LANDED_BYTES = metrics.counter(
     "device_sink_landed_bytes_total", "Bytes landed into device sinks")
 SINK_VERIFY_COUNT = metrics.counter(
     "device_sink_verify_total", "Device sink verifications", ("result",))
+# The plan lottery: an assembly plan met for the first time compiles, on
+# the one landing thread, while every other task's pieces wait.
+SINK_COMPILES = metrics.counter(
+    "device_sink_compiles_total",
+    "Assemblies that compiled their program (a new segment plan)")
+SINK_COMPILE_SECONDS = metrics.counter(
+    "device_sink_compile_seconds_total",
+    "Backend-compile seconds spent inside device sink assemblies")
 
 
 class DeviceSinkError(Exception):
     pass
+
+
+class _SpanStamp:
+    """Where one sink's spans go: the task's flight ring (``flight``, set
+    by the manager before each step on the landing thread) and the compile
+    counters. An object of its own, so that the HBMSink that calls it
+    holds nothing that leads back to its owner: in a cycle, a dropped
+    sink's content-sized device buffers would wait for the cyclic
+    collector instead of going with the last reference."""
+
+    __slots__ = ("flight",)
+
+    def __init__(self):
+        self.flight: "flightlib.TaskFlight | None" = None
+
+    def __call__(self, code: int, piece: int, ms: float) -> None:
+        if code == flightlib.EV_SINK_COMPILE:
+            SINK_COMPILES.inc()
+            SINK_COMPILE_SECONDS.inc(ms / 1000.0)
+        if self.flight is not None:
+            self.flight.record(code, piece, ms)
 
 
 class TaskDeviceSink:
@@ -66,8 +101,9 @@ class TaskDeviceSink:
                 f"piece size {piece_size} not 4-byte aligned")
         aligned = piece_size + ((-piece_size) % 4)
         self.task_id = task_id
+        self.stamp = _SpanStamp()
         self.sink = HBMSink(content_length, aligned, device=device,
-                            batch_pieces=batch_pieces)
+                            batch_pieces=batch_pieces, stamp=self.stamp)
         self.created_at = time.time()
         self.verified = False
         self.verified_at = 0.0
@@ -144,6 +180,11 @@ class DeviceSinkManager:
     def __init__(self, *, mesh_shape: list[int] | None = None,
                  batch_pieces: int = 8, max_tasks: int = 4,
                  ttl: float = 600.0, device=None):
+        # jax comes in with the first sink manager, not with this module.
+        from dragonfly2_tpu.ops import hbm_sink
+
+        hbm_sink.watch_compiles()
+        self._span = hbm_sink.span
         self._admission = None
         self.claim_grace_s = 10.0   # see _create's eviction rule
         # Task ids a client pull has announced it WILL claim (set before
@@ -178,13 +219,25 @@ class DeviceSinkManager:
 
     # -- landing ----------------------------------------------------------
 
-    async def on_piece(self, task_id: str, store, rec) -> None:
+    async def on_piece(self, task_id: str, store, rec, tf=None) -> None:
         """Land one verified piece as it arrives (conductor/back-source
         on_piece hook). Creation is lazy: the first piece to arrive after
-        the task's length and piece size are known allocates the buffer."""
-        await self._run(self._land_sync, task_id, store, rec)
+        the task's length and piece size are known allocates the buffer.
+        ``tf`` is the task's flight, for the landing thread's spans."""
+        await self._run(self._land_sync, task_id, store, rec, tf)
 
-    def _land_sync(self, task_id: str, store, rec) -> None:
+    def _land_sync(self, task_id: str, store, rec, tf=None) -> None:
+        with self._span(tf and tf.record, flightlib.EV_SINK_LAND, rec.num):
+            self._land_inner(task_id, store, rec, tf)
+
+    def _land_one(self, sink: TaskDeviceSink, store, rec, tf) -> None:
+        """Read one piece back from the store and stage it."""
+        sink.stamp.flight = tf
+        with self._span(tf and tf.record, flightlib.EV_SINK_READ, rec.num):
+            data = store.read_piece(rec.num)
+        sink.land(rec.num, data, rec.digest)
+
+    def _land_inner(self, task_id: str, store, rec, tf) -> None:
         if task_id in self._degraded:
             return
         sink = self._sinks.get(task_id)
@@ -202,7 +255,7 @@ class DeviceSinkManager:
                         task=task_id[:16], piece=rec.num)
             return
         try:
-            sink.land(rec.num, store.read_piece(rec.num), rec.digest)
+            self._land_one(sink, store, rec, tf)
         except Exception as e:
             # Device trouble mid-stream (HBM OOM in the staging device_put,
             # runtime errors): degrade THIS task to disk-only — the
@@ -275,34 +328,41 @@ class DeviceSinkManager:
 
     # -- completion -------------------------------------------------------
 
-    async def finalize(self, task_id: str, store) -> TaskDeviceSink | None:
+    async def finalize(self, task_id: str, store,
+                       tf=None) -> TaskDeviceSink | None:
         """Complete the landing: backfill pieces the streaming hook missed
         (reuse path, tiny/small shortcuts, pre-metadata arrivals), then
         verify every landed piece on device. Returns None when no sink
         could be allocated (cap reached, misaligned pieces) — disk-only
-        degradation; raises DeviceSinkError on device-copy CORRUPTION."""
-        return await self._run(self._finalize_sync, task_id, store)
+        degradation; raises DeviceSinkError on device-copy CORRUPTION.
+        ``tf`` as for ``on_piece``."""
+        return await self._run(self._finalize_sync, task_id, store, tf)
 
-    def _finalize_sync(self, task_id: str, store) -> TaskDeviceSink | None:
-        if task_id in self._degraded:
-            self._degraded.discard(task_id)  # next attempt starts fresh
-            return None
-        try:
-            return self._finalize_inner(task_id, store)
-        except DeviceSinkError:
-            raise  # device-copy corruption: surfaced to the caller
-        except Exception as e:
-            # Environment failures (OOM during backfill staging, assembly
-            # dispatch errors, store read races) degrade to disk-only —
-            # the digest-verified disk result must not be discarded over a
-            # device-side hiccup.
-            log.warning("device finalize failed; disk-only result",
-                        task=task_id[:16], error=str(e)[:200])
-            self._note_error(task_id, "finalize", e)
-            self._sinks.pop(task_id, None)
-            return None
+    def _finalize_sync(self, task_id: str, store,
+                       tf=None) -> TaskDeviceSink | None:
+        # piece: how many pieces the backfill landed, counted as it goes.
+        with self._span(tf and tf.record, flightlib.EV_SINK_FINALIZE,
+                        0) as step:
+            if task_id in self._degraded:
+                self._degraded.discard(task_id)  # next attempt starts fresh
+                return None
+            try:
+                return self._finalize_inner(task_id, store, tf, step)
+            except DeviceSinkError:
+                raise  # device-copy corruption: surfaced to the caller
+            except Exception as e:
+                # Environment failures (OOM during backfill staging,
+                # assembly dispatch errors, store read races) degrade to
+                # disk-only — the digest-verified disk result must not be
+                # discarded over a device-side hiccup.
+                log.warning("device finalize failed; disk-only result",
+                            task=task_id[:16], error=str(e)[:200])
+                self._note_error(task_id, "finalize", e)
+                self._sinks.pop(task_id, None)
+                return None
 
-    def _finalize_inner(self, task_id: str, store) -> TaskDeviceSink | None:
+    def _finalize_inner(self, task_id: str, store, tf,
+                        step) -> TaskDeviceSink | None:
         m = store.metadata
         sink = self._sinks.get(task_id)
         if sink is not None and self._stale(sink, store):
@@ -317,9 +377,13 @@ class DeviceSinkManager:
             sink = self._create(task_id, m.content_length, m.piece_size)
             if sink is None:
                 return None
+        sink.stamp.flight = tf
         for rec in store.get_pieces():
             if rec.num not in sink.landed:
-                sink.land(rec.num, store.read_piece(rec.num), rec.digest)
+                with self._span(tf and tf.record, flightlib.EV_SINK_LAND,
+                                rec.num):
+                    self._land_one(sink, store, rec, tf)
+                step.piece += 1
         sink.verify()
         log.info("device sink verified", task=task_id[:16],
                  pieces=len(sink.landed))

@@ -241,6 +241,11 @@ class PieceDispatcher:
         p = self.parents.get(peer_id)
         if p is None:
             return
+        if piece_nums and self.flight is not None:
+            # What the wait for the first piece is made of: until a parent
+            # says it holds one, there is nothing to request.
+            self.flight.record(flightlib.EV_PARENT_PIECES, min(piece_nums),
+                               float(len(piece_nums)))
         p.pieces.update(piece_nums)
         if digests:
             per_parent = self.parent_digests.setdefault(peer_id, {})

@@ -249,7 +249,7 @@ class TaskManager:
                 # Land into HBM as the piece verifies — by completion the
                 # device buffer only awaits the final on-device check.
                 tf.record(flightlib.EV_HBM_START, rec.num)
-                await self.device_sinks.on_piece(task_id, st, rec)
+                await self.device_sinks.on_piece(task_id, st, rec, tf)
                 tf.record(flightlib.EV_HBM_LANDED, rec.num)
             if progress_q is not None:
                 await progress_q.on_piece(st, rec)
@@ -1266,7 +1266,10 @@ class TaskManager:
         from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkError
 
         try:
-            return await self.device_sinks.finalize(task_id, store) is not None
+            # The same flight the download stamped (a re-land finds the
+            # finished one): the landing thread's spans go beside them.
+            return await self.device_sinks.finalize(
+                task_id, store, self.flight.task(task_id)) is not None
         except DeviceSinkError as e:
             self.device_sinks.discard(task_id)
             raise DfError(Code.ClientPieceDownloadFail,

@@ -41,19 +41,95 @@ No reference analog: Dragonfly2's terminal store is the filesystem
 from __future__ import annotations
 
 import functools
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from dragonfly2_tpu.ops import bitview
 from dragonfly2_tpu.ops.checksum import (
     _chunk_checksums_xla,
     checksum_numpy,
 )
-from dragonfly2_tpu.pkg import dflog
+from dragonfly2_tpu.pkg import dflog, flight
 
 log = dflog.get("ops.hbm_sink")
+
+
+# ---------------------------------------------------------------------- #
+# Spans of the landing's host steps, and the compiles inside them
+# ---------------------------------------------------------------------- #
+
+class span:
+    """One host step of a landing. A ``df:<event name>`` annotation lies
+    around it, so a ``jax.profiler`` session over the daemon shows the step
+    beside the device's operations (outside a session an annotation is a
+    flag test). At its end ``stamp(code, piece, ms)`` is called once, if
+    there is a stamp: the flight ring's span convention, one event at the
+    end whose aux is the duration. ``piece`` may be set inside the block,
+    where it is only known then."""
+
+    __slots__ = ("stamp", "code", "piece", "_annotation", "_t0")
+
+    def __init__(self, stamp, code: int, piece: int = -1):
+        self.stamp = stamp
+        self.code = code
+        self.piece = piece
+
+    def __enter__(self) -> "span":
+        self._annotation = TraceAnnotation(
+            "df:" + flight.EVENT_NAMES[self.code])
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        ms = (time.perf_counter() - self._t0) * 1000.0
+        self._annotation.__exit__(*exc)
+        if self.stamp is not None:
+            self.stamp(self.code, self.piece, ms)
+
+
+# What THIS thread's backend compiles came to, from jax's own monitoring
+# events, which jax raises on the compiling thread: so reading it before
+# and after a call gives that call's compiles, whatever other threads
+# compile meanwhile. A persistent-cache hit raises the duration event too
+# (for the retrieval), after a cache_hits event: it is left out.
+_compiled = threading.local()
+_watching = False
+
+
+def _on_event(event: str, **_) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _compiled.hit = True
+
+
+def _on_duration(event: str, seconds: float, **_) -> None:
+    if event != "/jax/core/compile/backend_compile_duration":
+        return
+    if getattr(_compiled, "hit", False):
+        _compiled.hit = False
+        return
+    count, so_far = compiled()
+    _compiled.count, _compiled.seconds = count + 1, so_far + seconds
+
+
+def watch_compiles() -> None:
+    """Start counting compiles (once a process; jax keeps no way to take a
+    listener off again). Called where a sink manager is built."""
+    global _watching
+    if not _watching:
+        _watching = True
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def compiled() -> "tuple[int, float]":
+    """(count, seconds) of the calling thread's backend compiles so far."""
+    return getattr(_compiled, "count", 0), getattr(_compiled, "seconds", 0.0)
 
 
 # ---------------------------------------------------------------------- #
@@ -188,9 +264,12 @@ class HBMSink:
     once at consumption."""
 
     def __init__(self, content_length: int, piece_size: int, *, device=None,
-                 batch_pieces: int = 8):
+                 batch_pieces: int = 8, stamp=None):
         if piece_size % 4:
             raise ValueError("piece_size must be 4-byte aligned")
+        # ``stamp(code, piece, ms)``: where the owner keeps the spans of
+        # this sink's host steps (the task's flight ring), or None.
+        self.stamp = stamp
         self.content_length = content_length
         self.piece_size = piece_size
         self.piece_words = piece_size // 4
@@ -234,7 +313,8 @@ class HBMSink:
                 f"{self.total_pieces}-piece sink")
         if piece_num in self.landed:
             return
-        self.host_checksums[piece_num] = checksum_numpy(data)
+        with span(self.stamp, flight.EV_SINK_CHECKSUM, piece_num):
+            self.host_checksums[piece_num] = checksum_numpy(data)
         pad = (-len(data)) % 4
         if pad:
             data = data + b"\x00" * pad
@@ -257,17 +337,20 @@ class HBMSink:
         single assembly dispatch checksums everything later."""
         if not self._pending:
             return
-        pending = sorted(self._pending, key=lambda nw: nw[0])
-        self._pending.clear()
-        k = len(pending)
-        stack = np.zeros((k, self.piece_words), np.uint32)
-        slots = np.empty((k,), np.int64)
-        for i, (n, w) in enumerate(pending):
-            stack[i, : len(w)] = w  # zero pad short/tail pieces
-            slots[i] = n
+        with span(self.stamp, flight.EV_SINK_STAGE) as step:
+            pending = sorted(self._pending, key=lambda nw: nw[0])
+            self._pending.clear()
+            lowest = step.piece = pending[0][0]
+            k = len(pending)
+            stack = np.zeros((k, self.piece_words), np.uint32)
+            slots = np.empty((k,), np.int64)
+            for i, (n, w) in enumerate(pending):
+                stack[i, : len(w)] = w  # zero pad short/tail pieces
+                slots[i] = n
         # Straight from the host buffer to the sink's device: staging via
         # jnp.asarray would first place the batch on the default device.
-        batch = jax.device_put(stack, self.device)
+        with span(self.stamp, flight.EV_SINK_PUT, lowest):
+            batch = jax.device_put(stack, self.device)
         bi = len(self._batches)
         self._batches.append((slots, batch))
         for i, n in enumerate(slots):
@@ -364,15 +447,26 @@ class HBMSink:
             self._dev_sums = np.zeros((self.total_pieces,), np.uint32)
             self._dev_xors = np.zeros((self.total_pieces,), np.uint32)
             return self._assembled
-        plan = self._plan()
-        if len(plan) <= self._SEGMENT_CAP:
-            flat, sums, xors = _assemble_checksum_jit(
-                batches, plan, self.piece_words)
-        else:
-            flat, sums, xors = self._assemble_fragmented(batches)
-        self._assembled = flat
-        self._dev_sums = np.asarray(sums)
-        self._dev_xors = np.asarray(xors)
+        # From the dispatch to the checksums on the host: so it holds what
+        # is left of the wait for the staged transfers, and any compile.
+        with span(self.stamp, flight.EV_SINK_ASSEMBLE) as step:
+            plan = self._plan()
+            step.piece = len(plan)
+            count, seconds = compiled()
+            if len(plan) <= self._SEGMENT_CAP:
+                flat, sums, xors = _assemble_checksum_jit(
+                    batches, plan, self.piece_words)
+            else:
+                flat, sums, xors = self._assemble_fragmented(batches)
+            count_after, seconds_after = compiled()
+            if count_after > count and self.stamp is not None:
+                # A plan met for the first time (it is a static argument,
+                # and follows the order pieces arrived in).
+                self.stamp(flight.EV_SINK_COMPILE, len(plan),
+                           (seconds_after - seconds) * 1000.0)
+            self._assembled = flat
+            self._dev_sums = np.asarray(sums)
+            self._dev_xors = np.asarray(xors)
         self._maybe_drop_staging()
         self._bound_jit_cache()
         return self._assembled

@@ -13,7 +13,7 @@ box for the data plane:
     parent drops, quarantine, stripe reshuffles, back-to-source, HBM
     landing, upload serving);
   * ``analyze()`` folds a task's events into a phase breakdown
-    (sched_wait / dcn / ici / verify / store / stall / origin) whose
+    (sched_wait / dcn / ici / hbm / verify / store / stall / origin) whose
     segments partition the task's wall time exactly (a residual bucket
     ``other`` absorbs uninstrumented gaps), plus a per-piece waterfall;
   * the daemon serves it at ``/debug/flight[/<task_id>]`` (pkg/
@@ -33,7 +33,9 @@ no-dict property).
 from __future__ import annotations
 
 import gzip
+import itertools
 import json
+import operator
 import os
 import threading
 import time
@@ -88,6 +90,19 @@ EV_DELTA_REUSE = 25    # delta chunk copied from the local base (aux=cost_ms)
 EV_DELTA_FETCH = 26    # delta chunk pulled as a ranged task (aux=cost_ms)
 EV_LOOP_LAG = 27       # event loop wedged during this task (aux=lag_s)
 EV_GC_PAUSE = 28       # slow cyclic-GC pause during this task (aux=pause_s)
+# Spans of the device-sink landing thread: ONE event at the span's end,
+# aux = its duration in ms (start = t - aux/1000, as landed/source_landed
+# back theirs out). All stamped by the one df-device-sink thread, so a
+# span's children are the spans its interval contains.
+EV_SINK_LAND = 29      # one piece's on-thread work (piece=num)
+EV_SINK_READ = 30      # store.read_piece (piece=num)
+EV_SINK_CHECKSUM = 31  # host checksum of the piece (piece=num)
+EV_SINK_STAGE = 32     # flush: sort, zeroed stack, row copies (piece=lowest slot)
+EV_SINK_PUT = 33       # flush: the device_put call (piece=lowest slot)
+EV_SINK_ASSEMBLE = 34  # assembly dispatch -> checksums on host (piece=segments)
+EV_SINK_COMPILE = 35   # backend compile inside that assembly (piece=segments)
+EV_SINK_FINALIZE = 36  # backfill + assemble + verify (piece=pieces backfilled)
+EV_PARENT_PIECES = 37  # a parent announced pieces (piece=lowest, aux=how many)
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -104,6 +119,11 @@ EVENT_NAMES = {
     EV_TASK_DONE: "task_done", EV_TASK_FAILED: "task_failed",
     EV_DELTA_REUSE: "delta_reuse", EV_DELTA_FETCH: "delta_fetch",
     EV_LOOP_LAG: "loop_lag", EV_GC_PAUSE: "gc_pause",
+    EV_SINK_LAND: "sink_land", EV_SINK_READ: "sink_read",
+    EV_SINK_CHECKSUM: "sink_checksum", EV_SINK_STAGE: "sink_stage",
+    EV_SINK_PUT: "sink_put", EV_SINK_ASSEMBLE: "sink_assemble",
+    EV_SINK_COMPILE: "sink_compile", EV_SINK_FINALIZE: "sink_finalize",
+    EV_PARENT_PIECES: "parent_pieces",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING
@@ -111,15 +131,21 @@ EVENT_NAMES = {
 # so --explain can say the LOOP was wedged, not just "nothing happened".
 _RUNTIME_EVENTS = (EV_LOOP_LAG, EV_GC_PAUSE)
 
+# The landing thread's steps, summed into the report's ``hbm`` block.
+_SINK_STEPS = (EV_SINK_LAND, EV_SINK_READ, EV_SINK_CHECKSUM, EV_SINK_STAGE,
+               EV_SINK_PUT, EV_SINK_ASSEMBLE, EV_SINK_COMPILE,
+               EV_SINK_FINALIZE)
+
 # Canonical phase model. ``other`` (residual uninstrumented time) rides
 # alongside so the fold partitions wall time exactly.
-PHASES = ("sched_wait", "dcn", "ici", "verify", "store", "stall", "origin")
+PHASES = ("sched_wait", "dcn", "ici", "hbm", "verify", "store", "stall",
+          "origin")
 
 # Overlap priority: when two phases cover the same wall segment, the one
 # doing WORK wins (a stall that overlaps a concurrent healthy transfer
 # did not cost wall time).
-_PRIORITY = {"verify": 6, "store": 5, "ici": 4, "dcn": 3, "origin": 2,
-             "stall": 1, "sched_wait": 0}
+_PRIORITY = {"verify": 7, "store": 6, "hbm": 5, "ici": 4, "dcn": 3,
+             "origin": 2, "stall": 1, "sched_wait": 0}
 
 # A first byte later than this after the request counts the gap as stall
 # (the parent was connected but silent) instead of transfer time.
@@ -145,7 +171,7 @@ class TaskFlight:
     timeline); ``start_wall`` anchors export to wall time."""
 
     __slots__ = ("task_id", "start_wall", "_start_pc", "_cap", "_ring",
-                 "_n", "state", "note", "_end_pc", "_piece_track",
+                 "_seq", "state", "note", "_end_pc", "_piece_track",
                  "_piece_cap", "__weakref__")
 
     def __init__(self, task_id: str, capacity: int = 2048,
@@ -155,7 +181,10 @@ class TaskFlight:
         self._start_pc = time.perf_counter()
         self._cap = capacity
         self._ring: list = [None] * capacity
-        self._n = 0
+        # Slot allocator. The event loop and the device-sink thread record
+        # into one ring: next() on a count hands each caller its own index
+        # in one C call, where ``n += 1`` would lose updates.
+        self._seq = itertools.count()
         self.state = "running"
         self.note = ""
         self._end_pc = -1.0
@@ -170,8 +199,7 @@ class TaskFlight:
         allocation-light (no dict literals / kwargs expansion on this
         path — test_flight pins the bytecode)."""
         t = time.perf_counter() - self._start_pc
-        self._ring[self._n % self._cap] = (t, code, piece, aux, note)
-        self._n += 1
+        self._ring[next(self._seq) % self._cap] = (t, code, piece, aux, note)
         if piece >= 0 and code in _TRACK_SLOT:
             slot = _TRACK_SLOT[code]
             track = self._piece_track.get(piece)
@@ -189,11 +217,13 @@ class TaskFlight:
 
     @property
     def events_total(self) -> int:
-        return self._n
+        # A count cannot be read without advancing it, except through its
+        # repr, "count(n)": n slots have been handed out.
+        return int(repr(self._seq)[6:-1])
 
     @property
     def events_dropped(self) -> int:
-        return max(0, self._n - self._cap)
+        return max(0, self.events_total - self._cap)
 
     def wall_s(self) -> float:
         end = self._end_pc if self._end_pc >= 0 else (
@@ -207,11 +237,13 @@ class TaskFlight:
         return self.start_wall + (time.perf_counter() - self._start_pc)
 
     def events(self) -> list:
-        """Chronological retained events (oldest dropped on overflow)."""
-        if self._n <= self._cap:
-            return [e for e in self._ring[:self._n]]
-        head = self._n % self._cap
-        return [e for e in self._ring[head:] + self._ring[:head]]
+        """Chronological retained events (oldest dropped on overflow).
+        Two threads can take their slots in the other order than their
+        clocks, and a slot handed out may not be written yet: so by time,
+        not by slot, and empty slots left out."""
+        out = [e for e in self._ring if e is not None]
+        out.sort(key=operator.itemgetter(0))
+        return out
 
     def finish(self, state: str, note: str = "") -> None:
         self.record(EV_TASK_DONE if state == "done" else EV_TASK_FAILED,
@@ -399,7 +431,9 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
         elif code == EV_HBM_LANDED:
             t0 = open_marks.pop(("hbm", piece), None)
             if t0 is not None:
-                intervals.append((t0, t, "ici"))
+                # Host -> HBM landing (the piece's wait for the landing
+                # thread included); ``ici`` is intra-slice transfers only.
+                intervals.append((t0, t, "hbm"))
 
     # Tails: a request still open at the end of the timeline is the
     # black-box case — the piece never came back. Beyond the first-byte
@@ -421,10 +455,16 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
     truncated = len(ordered) > max_waterfall
     counts: dict = {}
     runtime: dict = {}
+    hbm: dict = {}
     for _t, code, _p, aux, _n in events:
         name = EVENT_NAMES.get(code, str(code))
         counts[name] = counts.get(name, 0) + 1
-        if code in _RUNTIME_EVENTS:
+        if code in _SINK_STEPS:
+            # Where a landing's time went, by step, whether or not it fell
+            # inside the task's wall time (finalize runs after the
+            # terminal event).
+            hbm[code] = hbm.get(code, 0.0) + aux
+        elif code in _RUNTIME_EVENTS:
             r = runtime.get(name)
             if r is None:
                 r = runtime[name] = {"count": 0, "max_s": 0.0, "total_s": 0.0}
@@ -451,6 +491,9 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
         "events_dropped": tf.events_dropped,
         "event_counts": counts,
         "runtime": runtime,
+        # "sink_read" -> "read_ms", in the order of _SINK_STEPS.
+        "hbm": {EVENT_NAMES[code][5:] + "_ms": round(hbm[code], 3)
+                for code in _SINK_STEPS if code in hbm},
         "pieces": ordered[:max_waterfall],
         "pieces_truncated": truncated,
     }
@@ -494,6 +537,10 @@ def render_waterfall(report: dict) -> str:
     for ph, v in entries:
         bar = "#" * int(round(width * v / wall))
         lines.append(f"  {ph:<10} {v:8.3f}s {100 * v / wall:5.1f}% {bar}")
+    hbm = report.get("hbm")
+    if hbm:
+        lines.append("hbm landing, ms on the landing thread: " + " ".join(
+            f"{k[:-3]}={v:.1f}" for k, v in hbm.items()))
     advisory = runtime_advisory(report)
     if advisory:
         lines.append(advisory)

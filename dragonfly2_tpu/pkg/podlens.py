@@ -288,8 +288,8 @@ class PodLens:
 # Text rendering: the per-host phase-colored lag waterfall
 # --------------------------------------------------------------------- #
 
-PHASE_CHARS = {"sched_wait": ".", "dcn": "=", "ici": "~", "verify": "v",
-               "store": "s", "stall": "!", "origin": "o"}
+PHASE_CHARS = {"sched_wait": ".", "dcn": "=", "ici": "~", "hbm": "h",
+               "verify": "v", "store": "s", "stall": "!", "origin": "o"}
 
 
 def render_timeline(report: dict, width: int = 48) -> str:

@@ -40,8 +40,7 @@ def synthetic(events, wall):
     (t, code, piece, aux, note)) — analyzer tests need exact clocks."""
     tf = flight.TaskFlight("synthetic")
     for e in events:
-        tf._ring[tf._n % tf._cap] = e
-        tf._n += 1
+        tf._ring[next(tf._seq) % tf._cap] = e
     tf.state = "done"
     tf._end_pc = wall
     return tf

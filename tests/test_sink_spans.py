@@ -1,0 +1,459 @@
+"""Spans inside the landing thread (pkg/flight ``sink_*`` events).
+
+The device sink's one worker thread stamps its steps into the task's
+flight ring: one event at a span's end, ``aux`` = its ms. These tests land
+a small object through ``DeviceSinkManager`` on the CPU backend and check
+the events' shape (which exist, what lies inside what), the fold
+(``analyze()`` books landing as ``hbm``, not ``ici``), the ring under two
+writers, and that none of it pulled jax into a daemon that holds no sink.
+"""
+
+from __future__ import annotations
+
+import gc
+import random
+import subprocess
+import sys
+import threading
+import weakref
+
+import numpy as np
+import pytest
+
+from dragonfly2_tpu.pkg import flight
+
+SINK_NAMES = ("sink_land", "sink_read", "sink_checksum", "sink_stage",
+              "sink_put", "sink_assemble", "sink_compile", "sink_finalize")
+PIECES = 10
+BATCH = 4
+# Bounds how far a span's backed-out start (stamp time - aux) may sit from
+# where the step really began: the stamp is taken a few microseconds after
+# the duration was.
+SLACK_S = 1e-3
+
+
+@pytest.fixture
+def fresh_compiles():
+    """No persistent compilation cache: whatever the sink compiles in the
+    test, it compiles, whatever an earlier run left on disk."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def make_store(tmp_path, task_id: str, piece_size: int, seed: int = 5):
+    """A completed store of PIECES pieces, the last one short."""
+    from dragonfly2_tpu.storage.local_store import (
+        LocalTaskStore,
+        TaskStoreMetadata,
+    )
+
+    length = piece_size * PIECES - piece_size // 2
+    content = bytes(random.Random(seed).randbytes(length))
+    store = LocalTaskStore(
+        str(tmp_path / task_id),
+        TaskStoreMetadata(task_id=task_id, content_length=length,
+                          piece_size=piece_size, total_piece_count=PIECES))
+    for n in range(PIECES):
+        store.write_piece(n, content[n * piece_size:(n + 1) * piece_size])
+    return store, content
+
+
+async def land_cold(mgr, store, tf, order) -> object:
+    """What task_manager's on_piece hook and _finalize_device do."""
+    task_id = store.metadata.task_id
+    records = {rec.num: rec for rec in store.get_pieces()}
+    for n in order:
+        tf.record(flight.EV_HBM_START, n)
+        await mgr.on_piece(task_id, store, records[n], tf)
+        tf.record(flight.EV_HBM_LANDED, n)
+    return await mgr.finalize(task_id, store, tf)
+
+
+def spans_of(tf) -> dict:
+    """name -> [(start, end, piece)] of the ring's sink_* spans."""
+    out: dict = {name: [] for name in SINK_NAMES}
+    for t, code, piece, aux, _ in tf.events():
+        name = flight.EVENT_NAMES[code]
+        if name in out:
+            out[name].append((t - aux / 1000.0, t, piece))
+    return out
+
+
+def inside(child, parents) -> bool:
+    return any(p[0] - SLACK_S <= child[0] and child[1] <= p[1] + SLACK_S
+               for p in parents)
+
+
+def seconds(rows) -> float:
+    return sum(e - s for s, e, _ in rows)
+
+
+def test_cold_landing_stamps_every_span_children_inside_parents(
+        run_async, tmp_path, fresh_compiles):
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+
+    async def body():
+        store, content = make_store(tmp_path, "t-cold", 64 * 1024 + 64)
+        tf = flight.TaskFlight("t-cold")
+        mgr = DeviceSinkManager(batch_pieces=BATCH)
+        try:
+            order = [3, 0, 1, 2, 7, 6, 5, 4, 9, 8]
+            sink = await land_cold(mgr, store, tf, order)
+            assert sink is not None and sink.verified
+            got = bytes(np.asarray(sink.as_bytes_array()))
+            assert got == content
+        finally:
+            mgr.close()
+        return spans_of(tf)
+
+    spans = run_async(body(), timeout=120)
+    for name in SINK_NAMES:
+        assert spans[name], f"no {name} event"
+    counts = {name: len(rows) for name, rows in spans.items()}
+    # Ten pieces, each read and checksummed once on the thread; two full
+    # batches flushed while landing and the rest in finalize; one
+    # assembly of a plan never met before; nothing left to backfill.
+    assert counts == {"sink_land": PIECES, "sink_read": PIECES,
+                      "sink_checksum": PIECES, "sink_stage": 3,
+                      "sink_put": 3, "sink_assemble": 1, "sink_compile": 1,
+                      "sink_finalize": 1}
+    assert sorted(p for _, _, p in spans["sink_land"]) == list(range(PIECES))
+    assert [p for _, _, p in spans["sink_finalize"]] == [0]
+    # A batch is named by its lowest slot.
+    assert sorted(p for _, _, p in spans["sink_stage"]) == [0, 4, 8]
+    assert sorted(p for _, _, p in spans["sink_put"]) == [0, 4, 8]
+
+    lands, final = spans["sink_land"], spans["sink_finalize"]
+    by_piece = {p: (s, e, p) for s, e, p in lands}
+    for name in ("sink_read", "sink_checksum"):
+        for row in spans[name]:
+            assert inside(row, [by_piece[row[2]]]), (name, row)
+    for name in ("sink_stage", "sink_put"):
+        for row in spans[name]:
+            assert inside(row, lands + final), (name, row)
+    assert inside(spans["sink_assemble"][0], final)
+    assert inside(spans["sink_compile"][0], spans["sink_assemble"])
+    # Children never add up to more than their parent.
+    for land in lands:
+        children = [row for name in ("sink_read", "sink_checksum",
+                                     "sink_stage", "sink_put")
+                    for row in spans[name] if inside(row, [land])]
+        assert seconds(children) <= (land[1] - land[0]) + SLACK_S
+    in_final = [row for name in ("sink_stage", "sink_put", "sink_assemble")
+                for row in spans[name] if inside(row, final)
+                and not inside(row, lands)]
+    assert in_final and seconds(in_final) <= seconds(final) + SLACK_S
+    assert seconds(spans["sink_compile"]) <= seconds(spans["sink_assemble"])
+
+
+def test_reland_backfill_stamps_the_same_steps_and_one_finalize(
+        run_async, tmp_path):
+    """A re-land: nothing streamed in, ``_finalize_inner`` backfills every
+    piece from the store through the same landing code."""
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+
+    async def body():
+        store, content = make_store(tmp_path, "t-reland", 64 * 1024)
+        tf = flight.TaskFlight("t-reland")
+        tf.finish("done")       # as the cold pull left it
+        mgr = DeviceSinkManager(batch_pieces=BATCH)
+        try:
+            sink = await mgr.finalize("t-reland", store, tf)
+            assert sink is not None and sink.verified
+            assert bytes(np.asarray(sink.as_bytes_array())) == content
+        finally:
+            mgr.close()
+        return spans_of(tf)
+
+    spans = run_async(body(), timeout=120)
+    for name in ("sink_land", "sink_read", "sink_checksum"):
+        assert [p for _, _, p in spans[name]] == list(range(PIECES)), name
+    assert len(spans["sink_stage"]) == len(spans["sink_put"]) == 3
+    assert [p for _, _, p in spans["sink_finalize"]] == [PIECES]
+    # In order: one segment per batch.
+    assert [p for _, _, p in spans["sink_assemble"]] == [3]
+    final = spans["sink_finalize"]
+    for name in SINK_NAMES[:-1]:
+        for row in spans[name]:
+            assert inside(row, final), (name, row)
+    direct = spans["sink_land"] + spans["sink_assemble"] + [
+        row for name in ("sink_stage", "sink_put") for row in spans[name]
+        if not inside(row, spans["sink_land"])]
+    assert seconds(direct) <= seconds(final) + SLACK_S
+
+
+def test_new_plan_stamps_one_compile_and_a_repeated_plan_none(
+        run_async, tmp_path, fresh_compiles):
+    from dragonfly2_tpu.daemon.peer import device_sink
+
+    async def body():
+        mgr = device_sink.DeviceSinkManager(batch_pieces=BATCH)
+        order = [0, 4, 1, 5, 2, 6, 3, 7, 9, 8]   # batches interleave
+        out = []
+        try:
+            for task_id in ("t-plan-a", "t-plan-b"):
+                # A piece size no other test lands: the first plan is new.
+                store, _ = make_store(tmp_path, task_id, 64 * 1024 + 192)
+                tf = flight.TaskFlight(task_id)
+                compiles = device_sink.SINK_COMPILES._value.get()
+                seconds_before = device_sink.SINK_COMPILE_SECONDS._value.get()
+                sink = await land_cold(mgr, store, tf, order)
+                assert sink is not None and sink.verified
+                out.append((
+                    spans_of(tf),
+                    device_sink.SINK_COMPILES._value.get() - compiles,
+                    device_sink.SINK_COMPILE_SECONDS._value.get()
+                    - seconds_before))
+        finally:
+            mgr.close()
+        return out
+
+    (first, n1, s1), (second, n2, s2) = run_async(body(), timeout=120)
+    assert len(first["sink_compile"]) == 1 and n1 == 1
+    assert s1 == pytest.approx(seconds(first["sink_compile"]), rel=1e-6)
+    # piece: the plan's segments, as on its assembly.
+    assert first["sink_compile"][0][2] == first["sink_assemble"][0][2] > 3
+    assert second["sink_compile"] == [] and (n2, s2) == (0, 0.0)
+    assert len(second["sink_assemble"]) == 1
+
+
+def test_a_dropped_sink_goes_with_its_last_reference():
+    """The stamp must not tie the HBMSink and its owner into a cycle: a
+    sink's device buffers are content-sized, and in a cycle they would stay
+    until the cyclic collector came round to an old generation (the chip
+    showed it: one more content-sized buffer on the device's peak for
+    every earlier pull)."""
+    from dragonfly2_tpu.daemon.peer.device_sink import TaskDeviceSink
+
+    piece = 64 * 1024
+    data = bytes(random.Random(9).randbytes(piece * 2))
+    tf = flight.TaskFlight("t-drop")
+    gc.collect()
+    gc.disable()
+    try:
+        sink = TaskDeviceSink("t-drop", len(data), piece, batch_pieces=BATCH)
+        sink.stamp.flight = tf
+        sink.land(0, data[:piece])
+        sink.land(1, data[piece:])
+        sink.verify()
+        assert tf.events_total >= 5   # 2 checksums, stage, put, assemble
+        gone = [weakref.ref(sink), weakref.ref(sink.sink)]
+        del sink
+        assert [ref() for ref in gone] == [None, None]
+    finally:
+        gc.enable()
+
+
+def test_analyze_books_landing_as_hbm_not_ici(run_async, tmp_path):
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+
+    async def body():
+        store, _ = make_store(tmp_path, "t-fold", 64 * 1024)
+        rec = flight.FlightRecorder()
+        tf = rec.task("t-fold")
+        mgr = DeviceSinkManager(batch_pieces=BATCH)
+        try:
+            await land_cold(mgr, store, tf, list(range(PIECES)))
+        finally:
+            mgr.close()
+        rec.finish_task("t-fold", "done")
+        return tf
+
+    tf = run_async(body(), timeout=120)
+    rep = flight.analyze(tf)
+    assert set(rep["phases"]) == set(flight.PHASES) and "hbm" in flight.PHASES
+    assert rep["phases"]["hbm"] > 0 and rep["phases"]["ici"] == 0
+    assert rep["dominant_phase"] == "hbm"
+    assert sum(rep["phases"].values()) + rep["other_s"] == pytest.approx(
+        rep["wall_s"], abs=1e-4)
+    spans = spans_of(tf)
+    block = rep["hbm"]
+    # A key per step seen (this plan may have been compiled before).
+    assert list(block) == [name[5:] + "_ms" for name in SINK_NAMES
+                           if spans[name]]
+    assert set(block) >= {"land_ms", "read_ms", "put_ms", "finalize_ms"}
+    for key, ms in block.items():
+        assert ms == pytest.approx(
+            seconds(spans["sink_" + key[:-3]]) * 1000.0, abs=0.01)
+    text = flight.render_waterfall(rep)
+    assert "hbm landing" in text and "read=" in text and "put=" in text
+    assert any(line.lstrip().startswith("hbm ") for line in text.split("\n"))
+
+
+def test_analyze_partition_with_hbm_above_ici():
+    """Hand-made clocks: landing overlaps an intra-slice transfer; the
+    landing wins the overlap, ici keeps only what landing does not cover,
+    and spans stamped after the terminal event still reach the block."""
+    tf = flight.TaskFlight("synthetic")
+    events = [
+        (0.0, flight.EV_REQUEST, 0, 0.0, "a:1"),
+        (1.0, flight.EV_LANDED, 0, 1000.0, "intra"),
+        (0.5, flight.EV_HBM_START, 7, 0.0, ""),
+        (0.9, flight.EV_SINK_READ, 7, 100.0, ""),
+        (1.4, flight.EV_SINK_LAND, 7, 600.0, ""),
+        (1.5, flight.EV_HBM_LANDED, 7, 0.0, ""),
+        (2.6, flight.EV_SINK_COMPILE, 2, 400.0, ""),
+        (2.7, flight.EV_SINK_FINALIZE, 0, 600.0, ""),
+    ]
+    for e in events:
+        tf._ring[next(tf._seq) % tf._cap] = e
+    tf.state = "done"
+    tf._end_pc = 2.0
+    rep = flight.analyze(tf)
+    assert rep["phases"]["ici"] == pytest.approx(0.5)
+    assert rep["phases"]["hbm"] == pytest.approx(1.0)
+    assert rep["other_s"] == pytest.approx(0.5)
+    assert sum(rep["phases"].values()) + rep["other_s"] == pytest.approx(2.0)
+    assert rep["hbm"] == {"land_ms": 600.0, "read_ms": 100.0,
+                          "compile_ms": 400.0, "finalize_ms": 600.0}
+    # events() is by time, whatever order the slots were taken in.
+    assert [e[0] for e in tf.events()] == sorted(e[0] for e in events)
+
+
+def test_plain_task_has_empty_hbm_block_and_digest_stays_bounded():
+    tf = flight.TaskFlight("plain")
+    tf.record(flight.EV_REQUEST, 0, 0.0, "p")
+    tf.record(flight.EV_LANDED, 0, 1.0, "cross")
+    tf.finish("done")
+    rep = flight.analyze(tf)
+    assert rep["hbm"] == {} and rep["phases"]["hbm"] == 0
+    assert "hbm landing" not in flight.render_waterfall(rep)
+    # A shard's worth of landing events on top of its transfer events.
+    big = flight.TaskFlight("shard")
+    for n in range(55):
+        big.record(flight.EV_PARENT_PIECES, n, 1.0)
+        big.record(flight.EV_REQUEST, n, 0.0, "10.0.0.1:4000")
+        big.record(flight.EV_LANDED, n, 30.0, "cross")
+        big.record(flight.EV_HBM_START, n)
+        big.record(flight.EV_SINK_READ, n, 40.0)
+        big.record(flight.EV_SINK_CHECKSUM, n, 15.0)
+        big.record(flight.EV_SINK_LAND, n, 140.0)
+        big.record(flight.EV_HBM_LANDED, n)
+    big.record(flight.EV_SINK_FINALIZE, 0, 300.0)
+    big.finish("done")
+    d = flight.digest(big)
+    assert d["bytes"] <= flight.DIGEST_MAX_BYTES
+    assert set(d["phases"]) == set(flight.PHASES)
+
+
+@pytest.mark.parametrize("capacity", [32768, 256])
+def test_two_threads_lose_no_event(capacity):
+    """The event loop and the landing thread record into one ring."""
+    per_thread = 10_000
+    tf = flight.TaskFlight("two-writers", capacity=capacity)
+    start = threading.Barrier(2)
+
+    def writer(who: int) -> None:
+        start.wait(timeout=10)
+        for i in range(per_thread):
+            tf.record(flight.EV_SINK_READ, i, float(who))
+
+    threads = [threading.Thread(target=writer, args=(who,)) for who in (0, 1)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert tf.events_total == 2 * per_thread
+    assert tf.events_dropped == max(0, 2 * per_thread - capacity)
+    events = tf.events()
+    assert len(events) == min(capacity, 2 * per_thread)
+    seen = {(int(aux), piece) for _, _, piece, aux, _ in events}
+    assert len(seen) == len(events)      # no slot written twice
+    if capacity >= 2 * per_thread:
+        assert seen == {(who, i) for who in (0, 1) for i in range(per_thread)}
+    assert [e[0] for e in events] == sorted(e[0] for e in events)
+
+
+def test_dispatcher_stamps_parent_pieces():
+    from dragonfly2_tpu.daemon.peer.piece_dispatcher import PieceDispatcher
+
+    tf = flight.TaskFlight("announce")
+    d = PieceDispatcher(flight=tf)
+    d.upsert_parent("seed-1", "10.0.0.1", 4000)
+    d.on_parent_pieces("seed-1", [])            # a keepalive: nothing held
+    d.on_parent_pieces("nobody", [1, 2])        # not a parent of ours
+    d.on_parent_pieces("seed-1", [5, 3, 4], total_piece_count=8)
+    rows = [(piece, aux) for _, code, piece, aux, _ in tf.events()
+            if code == flight.EV_PARENT_PIECES]
+    assert rows == [(3, 3.0)]
+    assert flight.EVENT_NAMES[flight.EV_PARENT_PIECES] == "parent_pieces"
+
+
+def test_dfget_device_explain_shows_the_hbm_block(run_async, tmp_path):
+    """The served path: a ``--device tpu`` pull's flight report books the
+    landing as ``hbm`` and carries the per-step block; a re-land adds its
+    backfill's spans to the same flight; ``--explain`` prints it."""
+    from dragonfly2_tpu.client import dfget as dfget_lib
+    from dragonfly2_tpu.proto.common import UrlMeta
+    from tests.test_device_sink import SHA, _start_sink_daemon
+    from tests.test_p2p_e2e import start_origin, start_scheduler
+
+    async def body():
+        origin, oport, _ = await start_origin()
+        sched = await start_scheduler()
+        peer = await _start_sink_daemon(tmp_path, "explain", sched.port())
+        try:
+            def pull():
+                return dfget_lib.download(dfget_lib.DfgetConfig(
+                    url=f"http://127.0.0.1:{oport}/blob", output="",
+                    daemon_sock=peer.config.unix_sock,
+                    meta=UrlMeta(digest=SHA), device="tpu", explain=True,
+                    allow_source_fallback=False, timeout=60.0))
+
+            r1 = await pull()
+            assert r1["device_verified"] and not r1["from_reuse"]
+            assert peer.task_manager.device_sinks.take(r1["task_id"])
+            r2 = await pull()
+            assert r2["device_verified"] and r2["from_reuse"]
+            return r1["flight"], r2["flight"]
+        finally:
+            await peer.stop()
+            await sched.stop()
+            await origin.cleanup()
+
+    cold, reland = run_async(body(), timeout=120)
+    rep = cold["report"]
+    assert rep["phases"]["hbm"] > 0 and rep["phases"]["ici"] == 0
+    assert rep["event_counts"]["sink_land"] == 3
+    assert rep["event_counts"]["sink_finalize"] == 1
+    for key in ("land_ms", "read_ms", "checksum_ms", "stage_ms", "put_ms",
+                "assemble_ms", "finalize_ms"):
+        assert rep["hbm"][key] > 0, key
+    assert "hbm landing, ms on the landing thread" in cold["text"]
+    assert cold["digest"]["phases"]["hbm"] == rep["phases"]["hbm"]
+    again = reland["report"]
+    assert again["event_counts"]["sink_land"] == 6
+    assert again["event_counts"]["sink_finalize"] == 2
+    assert again["hbm"]["read_ms"] > rep["hbm"]["read_ms"]
+
+
+def test_daemon_without_a_sink_still_imports_no_jax():
+    """The spans live beside the sink (ops/hbm_sink.py imports jax); the
+    modules every daemon imports must not reach it."""
+    code = (
+        "import sys\n"
+        "import dragonfly2_tpu.cli.main\n"
+        "import dragonfly2_tpu.daemon.daemon\n"
+        "import dragonfly2_tpu.daemon.peer.task_manager\n"
+        "import dragonfly2_tpu.daemon.peer.piece_dispatcher\n"
+        "import dragonfly2_tpu.daemon.peer.device_sink\n"
+        "import dragonfly2_tpu.pkg.flight\n"
+        "import dragonfly2_tpu.pkg.metrics_server\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+        "assert 'dragonfly2_tpu.ops.hbm_sink' not in sys.modules\n")
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
