@@ -231,11 +231,13 @@ class DeviceSinkManager:
             self._land_inner(task_id, store, rec, tf)
 
     def _land_one(self, sink: TaskDeviceSink, store, rec, tf) -> None:
-        """Read one piece back from the store and stage it."""
+        """Read one piece back from the store, straight into the row of
+        the sink's staging stack it will be put from, and stage it."""
         sink.stamp.flight = tf
+        row = sink.sink.next_row()
         with self._span(tf and tf.record, flightlib.EV_SINK_READ, rec.num):
-            data = store.read_piece(rec.num)
-        sink.land(rec.num, data, rec.digest)
+            size = store.read_piece_into(rec.num, row).size
+        sink.land(rec.num, row[:size], rec.digest)
 
     def _land_inner(self, task_id: str, store, rec, tf) -> None:
         if task_id in self._degraded:

@@ -13,12 +13,31 @@ dynamic-update-slice copies the whole buffer per flush, O(buffer) per
 flush and quadratic over a download. This design does zero buffer
 mutation during arrival:
 
-  * ``land_piece`` stages to a host batch; ``flush`` moves the batch to
-    the sink's device. Nothing is dispatched per flush.
+  * ``land_piece`` stages a piece as one row of a host stack of
+    ``batch_pieces`` rows; ``flush`` moves the stack to the sink's device
+    and puts its rows into slot order there. The content is not touched
+    again until consumption.
   * consumption assembles all batches into the flat uint32 content ONCE
     with a fused slice+concatenate jit that also folds the per-piece
     (sum32, xor32) checksums from the same staged copy (identical
     verification semantics to a verify-on-land kernel).
+
+Host staging: the sink owns its stacks, and they are reused. A stack comes
+from a process-wide free list (``pkg/bufpool``, pool ``hbm_stage``), so
+its pages have been touched before: a fresh 32 MiB buffer costs 32-41 ms
+of page faults on the chip's host, a fresh 256 MiB stack 260 ms (PERF.md
+section 5), against 35-65 ms for the transfer itself. The daemon reads a
+piece from its store straight into ``next_row()`` and hands that row to
+``land_piece``, which then checksums it where it lies; any other bytes
+are copied into the row once. Rows lie in arrival order. ``flush`` puts
+the stack and dispatches ``_reorder_jit``, a row gather by a traced
+permutation, so a staged batch is in slot order whatever the arrival
+order was and the assembly plan (a static argument: one compile each)
+depends on which pieces shared a batch, not on their order in it. The
+gather's output is a device buffer of its own, and only when it is ready
+does the stack go back to the free list: ``jax.device_put`` returns
+before the runtime has read the host buffer, and on the CPU backend an
+aligned buffer is aliased, not copied, for the device array's whole life.
 
 Rates: not measured on this round's chip. Memory, as the v5e compiler
 reports it for the assembly program (``memory_analysis()``,
@@ -54,9 +73,40 @@ from dragonfly2_tpu.ops.checksum import (
     _chunk_checksums_xla,
     checksum_numpy,
 )
-from dragonfly2_tpu.pkg import dflog, flight
+from dragonfly2_tpu.pkg import dflog, flight, metrics
+from dragonfly2_tpu.pkg.bufpool import BufferPool
+from dragonfly2_tpu.pkg.piece import PIECE_SIZE_LIMIT
 
 log = dflog.get("ops.hbm_sink")
+
+# A landing sink holds at most this many staging stacks: the one it fills
+# and the one the runtime may still be reading. A put takes 35-65 ms and
+# the next batch's reads 200-300, so the wait for the older one is a no-op
+# in practice (PERF.md section 5).
+_STACKS_PER_SINK = 2
+# The free list of staging stacks, shared by every sink of the process: a
+# second landing touches no new page. It keeps what a daemon at its
+# defaults can have in use at once: max_tasks (4) sinks mid-landing, each
+# with _STACKS_PER_SINK stacks of batch_pieces (8) pieces of the largest
+# piece size: 2 GiB. What is given back beyond that is dropped, which
+# costs the next landing its page faults and nothing else.
+_STAGING = BufferPool(_STACKS_PER_SINK * 4 * 8 * PIECE_SIZE_LIMIT,
+                      name="hbm_stage")
+SINK_ROWS = metrics.counter(
+    "device_sink_rows_total",
+    "Pieces staged for the device: read from the store into the sink's own "
+    "row (in_place) or copied there from the caller's bytes (copied)",
+    ("how",))
+_ROWS_IN_PLACE = SINK_ROWS.labels("in_place")
+_ROWS_COPIED = SINK_ROWS.labels("copied")
+
+
+def _give_back(view: memoryview) -> None:
+    """A stack back to the free list. Its bytearray, not the view: a view
+    that is released lets go of the memory, and should an array over it
+    still be alive somewhere (the runtime's, after a dispatch that
+    failed), that array must keep it."""
+    _STAGING.release(view.obj)
 
 
 # ---------------------------------------------------------------------- #
@@ -234,6 +284,22 @@ def _merge_jit(arrs: tuple):
     return jnp.concatenate(list(arrs), axis=0)
 
 
+@jax.jit
+def _reorder_jit(staged, order):
+    """A staged batch's rows, which lie in arrival order, in slot order:
+    row i of the result is row ``order[i]``. The permutation is traced, so
+    one program serves every arrival order of a batch shape. A loop of
+    row copies and not ``jnp.take``: for 8 rows of 32 MiB the v5e compiler
+    unrolls that gather into 22 MB of program, which stays on the device
+    (tests/test_chip_compile.py; the chip's ``peak_hbm_x`` showed it)."""
+    def place(i, out):
+        row = jax.lax.dynamic_slice_in_dim(staged, order[i], 1, axis=0)
+        return jax.lax.dynamic_update_slice_in_dim(out, row, i, axis=0)
+
+    return jax.lax.fori_loop(0, staged.shape[0], place,
+                             jnp.zeros_like(staged))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("count", "piece_size", "record_bytes"))
 def _record_batch_jit(flat, *, count: int, piece_size: int,
@@ -265,6 +331,15 @@ class HBMSink:
 
     def __init__(self, content_length: int, piece_size: int, *, device=None,
                  batch_pieces: int = 8, stamp=None):
+        # The stack being filled: the pool's view of it, its bytes as
+        # (batch_pieces, piece_size) rows, and the slots of the rows filled
+        # so far, in row order. Then the stacks that were put, oldest
+        # first, each with the device array whose readiness says the
+        # runtime has read it.
+        self._view: memoryview | None = None
+        self._stack: np.ndarray | None = None
+        self._rows: list[int] = []
+        self._in_flight: list[tuple[memoryview, jax.Array]] = []
         if piece_size % 4:
             raise ValueError("piece_size must be 4-byte aligned")
         # ``stamp(code, piece, ms)``: where the owner keeps the spans of
@@ -290,8 +365,8 @@ class HBMSink:
         self.host_checksums: dict[int, tuple[int, int]] = {}
         self.landed: set[int] = set()
         self.batch_pieces = batch_pieces
-        self._pending: list[tuple[int, np.ndarray]] = []
-        # Staged device batches: (slot ndarray, (k, piece_words) uint32).
+        # Staged device batches: (slot ndarray, (k, piece_words) uint32),
+        # rows in slot order.
         self._batches: list[tuple[np.ndarray, jax.Array]] = []
         self._slot_to_batch: dict[int, tuple[int, int]] = {}
         self._assembled: jax.Array | None = None
@@ -302,9 +377,61 @@ class HBMSink:
 
     # -- landing -----------------------------------------------------------
 
+    def _open_stack(self) -> None:
+        """A stack to fill: first those the runtime has read go back to
+        the free list (waiting for the oldest only if _STACKS_PER_SINK are
+        out), then one is taken from it."""
+        self._retire(keep=_STACKS_PER_SINK - 1)
+        self._view = _STAGING.acquire(self.batch_pieces * self.piece_size)
+        self._stack = np.frombuffer(self._view, np.uint8).reshape(
+            self.batch_pieces, self.piece_size)
+
+    def _retire(self, keep: int) -> None:
+        """Give back, oldest first, every stack whose put the runtime is
+        done with; wait for it while more than ``keep`` are out. A put
+        that failed raises here, and its stack goes back all the same: no
+        one reads it any more."""
+        while self._in_flight:
+            view, moved = self._in_flight[0]
+            if len(self._in_flight) <= keep and not moved.is_ready():
+                return
+            try:
+                moved.block_until_ready()
+            finally:
+                del self._in_flight[0]
+                _give_back(view)
+
+    def __del__(self):
+        # A sink dropped mid-landing (its task degraded, failed, expired)
+        # still holds stacks. Nothing can write to them any more, which is
+        # why they go back here and not where the owner forgets the sink:
+        # that may be another thread than the one landing into it.
+        if self._view is not None:
+            _give_back(self._view)
+            self._view = self._stack = None
+        while self._in_flight:
+            try:
+                self._retire(keep=0)
+            except jax.errors.JaxRuntimeError:
+                pass
+
+    def next_row(self) -> memoryview:
+        """The row the next piece will lie in, as ``piece_size`` writable
+        bytes: read the piece into it, then hand ``row[:size]`` to
+        ``land_piece``, which copies nothing then. A row taken and never
+        landed is handed out again."""
+        if self._stack is None:
+            with span(self.stamp, flight.EV_SINK_STAGE):
+                self._open_stack()
+        return memoryview(self._stack[len(self._rows)])
+
     def land_piece(self, piece_num: int, data: bytes) -> None:
-        """Stage one piece. Host checksum is recorded for later on-device
-        verification. Batched: flushes every ``batch_pieces``."""
+        """Stage one piece as the next row of the open stack, zero-padded
+        to the piece size. ``data`` is the piece's bytes: the start of the
+        row ``next_row()`` gave, already in place, or any other bytes-like
+        object, copied into the row once. The host checksum is taken from
+        the row, and recorded for the verification on the device.
+        Batched: flushes every ``batch_pieces``."""
         if piece_num < 0 or piece_num >= self.total_pieces:
             # A stray out-of-range piece must not invalidate (and on a
             # drained sink, zero out) the assembled content.
@@ -313,15 +440,29 @@ class HBMSink:
                 f"{self.total_pieces}-piece sink")
         if piece_num in self.landed:
             return
+        with span(self.stamp, flight.EV_SINK_STAGE, piece_num):
+            if self._stack is None:
+                self._open_stack()
+            row = self._stack[len(self._rows)]
+            given = np.frombuffer(data, np.uint8)
+            if given.size > row.size:
+                raise ValueError(
+                    f"piece {piece_num} of {given.size} bytes in a sink of "
+                    f"{self.piece_size}-byte pieces")
+            if given.ctypes.data == row.ctypes.data:
+                _ROWS_IN_PLACE.inc()
+            else:
+                row[:given.size] = given
+                _ROWS_COPIED.inc()
+            # The stack is reused: past the piece lies an earlier one.
+            row[given.size:] = 0
         with span(self.stamp, flight.EV_SINK_CHECKSUM, piece_num):
-            self.host_checksums[piece_num] = checksum_numpy(data)
-        pad = (-len(data)) % 4
-        if pad:
-            data = data + b"\x00" * pad
-        words = np.frombuffer(data, dtype="<u4")
-        self._pending.append((piece_num, words))
+            # Whole words, the zero padding included: nothing to copy.
+            self.host_checksums[piece_num] = checksum_numpy(
+                row[:given.size + (-given.size) % 4])
+        self._rows.append(piece_num)
         self.landed.add(piece_num)
-        if len(self._pending) >= self.batch_pieces:
+        if len(self._rows) >= self.batch_pieces:
             self.flush()
 
     # Every _MERGE_GROUP full batches consolidate into one superbatch
@@ -333,24 +474,27 @@ class HBMSink:
     _MERGE_GROUP = 32
 
     def flush(self) -> None:
-        """Move pending pieces to device as one batch. Pure staging — the
-        single assembly dispatch checksums everything later."""
-        if not self._pending:
+        """Move the open stack's filled rows to the device as one batch,
+        in slot order. Pure staging: the single assembly dispatch
+        checksums everything later. The stack itself stays out until the
+        reordered batch is ready (``_retire``)."""
+        if not self._rows:
             return
         with span(self.stamp, flight.EV_SINK_STAGE) as step:
-            pending = sorted(self._pending, key=lambda nw: nw[0])
-            self._pending.clear()
-            lowest = step.piece = pending[0][0]
-            k = len(pending)
-            stack = np.zeros((k, self.piece_words), np.uint32)
-            slots = np.empty((k,), np.int64)
-            for i, (n, w) in enumerate(pending):
-                stack[i, : len(w)] = w  # zero pad short/tail pieces
-                slots[i] = n
+            slots = np.asarray(self._rows, np.int64)
+            order = np.argsort(slots)
+            slots = slots[order]
+            lowest = step.piece = int(slots[0])
         # Straight from the host buffer to the sink's device: staging via
         # jnp.asarray would first place the batch on the default device.
         with span(self.stamp, flight.EV_SINK_PUT, lowest):
-            batch = jax.device_put(stack, self.device)
+            batch = _reorder_jit(
+                jax.device_put(self._stack[:len(slots)].view(np.uint32),
+                               self.device),
+                order.astype(np.int32))
+        self._in_flight.append((self._view, batch))
+        self._view = self._stack = None
+        self._rows = []
         bi = len(self._batches)
         self._batches.append((slots, batch))
         for i, n in enumerate(slots):
@@ -447,9 +591,13 @@ class HBMSink:
             self._dev_sums = np.zeros((self.total_pieces,), np.uint32)
             self._dev_xors = np.zeros((self.total_pieces,), np.uint32)
             return self._assembled
-        # From the dispatch to the checksums on the host: so it holds what
-        # is left of the wait for the staged transfers, and any compile.
+        # From the wait for the staged transfers to the checksums on the
+        # host, with any compile between them.
         with span(self.stamp, flight.EV_SINK_ASSEMBLE) as step:
+            # Every batch ready before the dispatch, not only after it:
+            # the last put's own device buffer is then gone when the flat
+            # content is allocated, and the peak stays staged + flat.
+            self._retire(keep=0)
             plan = self._plan()
             step.piece = len(plan)
             count, seconds = compiled()

@@ -14,8 +14,9 @@ Ownership rules (documented in docs/ZERO_COPY.md):
   - A released view must not be read again — the next acquire() will
     overwrite its bytes.
   - Consumers that must RETAIN piece bytes past the call that handed them
-    over (device sinks, caches) must copy (``bytes(view)``); everything on
-    the receive→verify→store→serve path only borrows.
+    over (caches) must copy (``bytes(view)``); everything on the
+    receive→verify→store→serve path only borrows. Device sinks own the
+    buffers the store reads into (pool ``hbm_stage``, ops/hbm_sink.py).
 
 Every pool is observable: acquire/release counts and retained bytes feed
 the shared Prometheus registry (``bufpool_acquires_total{pool=...}``,

@@ -851,8 +851,10 @@ class LocalTaskStore:
 
     def read_piece(self, num: int) -> bytes:
         """Piece bytes as a fresh ``bytes`` — the compatibility/oracle shape
-        (tests compare serve paths against it). Hot paths use
-        read_piece_into with a pooled buffer instead."""
+        (tests compare the serve paths and the device landing against it).
+        Hot paths use read_piece_into with a buffer of their own instead:
+        pooled on the serve side, a row of the staging stack in the device
+        sink."""
         rec = self.metadata.pieces.get(num)
         if rec is None:
             raise StorageError(f"piece {num} not found", Code.StoragePieceNotFound)

@@ -191,11 +191,9 @@ def test_corrupt_device_copy_fails_verification(run_async, tmp_path):
     data1 = bytes(random.Random(2).randbytes(piece))
     sink = TaskDeviceSink("t-corrupt", piece * 2, piece)
     sink.land(0, data0)
-    # Record piece 1's checksum for DIFFERENT bytes than we land.
+    sink.land(1, data1)
+    # Piece 1's recorded checksum is of DIFFERENT bytes than landed.
     sink.sink.host_checksums[1] = (0x12345678, 0x9ABCDEF0)
-    sink.sink.landed.add(1)
-    sink.sink._pending.append(
-        (1, np.frombuffer(data1, dtype="<u4")))
     with pytest.raises(DeviceSinkError, match="piece 1"):
         sink.verify()
 
@@ -1050,3 +1048,242 @@ def test_download_global_composes_with_ici_all_gather(run_async, tmp_path):
             await runner.cleanup()
 
     run_async(body(), timeout=120)
+
+
+# -- landing into the sink's own rows (ops/hbm_sink.py "Host staging") -----
+
+def _stored(tmp_path, task_id: str, piece_size: int, length: int,
+            seed: int = 5):
+    """A completed store of ``length`` random bytes."""
+    from dragonfly2_tpu.storage.local_store import (
+        LocalTaskStore,
+        TaskStoreMetadata,
+    )
+
+    content = bytes(random.Random(seed).randbytes(length))
+    pieces = -(-length // piece_size)
+    store = LocalTaskStore(
+        str(tmp_path / task_id),
+        TaskStoreMetadata(task_id=task_id, content_length=length,
+                          piece_size=piece_size, total_piece_count=pieces))
+    for n in range(pieces):
+        store.write_piece(n, content[n * piece_size:(n + 1) * piece_size])
+    return store, content
+
+
+def _counts() -> dict:
+    from dragonfly2_tpu.ops import hbm_sink
+    from dragonfly2_tpu.pkg import bufpool
+
+    rows = {how: hbm_sink.SINK_ROWS.labels(how)._value.get()
+            for how in ("in_place", "copied")}
+    stacks = {source: bufpool.BUFPOOL_ACQUIRES.labels("hbm_stage", source)
+              ._value.get() for source in ("fresh", "pooled")}
+    return {**rows, **stacks}
+
+
+def _since(before: dict) -> dict:
+    return {key: value - before[key] for key, value in _counts().items()}
+
+
+@pytest.mark.parametrize("arrival", ["in-order", "reversed", "four-streams"])
+def test_streamed_landing_is_the_stores_bytes_whatever_the_order(
+        run_async, tmp_path, arrival):
+    """The rehearsal of a shard's landing: 14 pieces in batches of 4, the
+    last one short and not a whole number of words, streamed through
+    ``on_piece`` (two of them left to the backfill) and read back."""
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops.checksum import checksum_numpy
+    from dragonfly2_tpu.pkg import flight
+    from tests.test_tpu_ops import ARRIVALS, sorted_batch_plan
+
+    order = ARRIVALS[arrival]
+    piece = 64 * 1024
+
+    async def body():
+        store, content = _stored(tmp_path, "t-" + arrival, piece,
+                                 piece * len(order) - 30_001)
+        task_id = store.metadata.task_id
+        records = {rec.num: rec for rec in store.get_pieces()}
+        tf = flight.TaskFlight(task_id)
+        mgr = DeviceSinkManager(batch_pieces=4)
+        before = _counts()
+        try:
+            for n in order[:-2]:
+                await mgr.on_piece(task_id, store, records[n], tf)
+            sink = await mgr.finalize(task_id, store, tf)
+            assert sink is not None and sink.verified
+            for n in range(len(order)):
+                assert sink.sink.host_checksums[n] == checksum_numpy(
+                    store.read_piece(n)), n
+            words = np.asarray(sink.as_words()).tobytes()
+            assert words[:len(content)] == content
+            assert not words[len(content):].strip(b"\x00")
+            assert len(words) == piece * len(order)
+        finally:
+            mgr.close()
+        segments = [p for _, code, p, _, _ in tf.events()
+                    if code == flight.EV_SINK_ASSEMBLE]
+        return _since(before), segments
+
+    moved, segments = run_async(body(), timeout=120)
+    assert (moved["in_place"], moved["copied"]) == (len(order), 0)
+    assert moved["fresh"] + moved["pooled"] == 4
+    # The plan is that of a sink that sorts its batches on the host, so no
+    # arrival order adds a segment (or a compile) to it.
+    landed = order[:-2] + sorted(order[-2:])
+    assert segments == [len(sorted_batch_plan(landed, 4, len(order)))]
+
+
+def test_two_tasks_of_different_piece_sizes_interleaved_on_one_manager(
+        run_async, tmp_path):
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+
+    async def body():
+        big, big_content = _stored(tmp_path, "t-big", 64 * 1024,
+                                   64 * 1024 * 10 - 7, seed=6)
+        small, small_content = _stored(tmp_path, "t-small", 16 * 1024 + 64,
+                                       (16 * 1024 + 64) * 10 - 9, seed=7)
+        mgr = DeviceSinkManager(batch_pieces=4)
+        before = _counts()
+        try:
+            for n in [3, 2, 1, 0, 7, 6, 5, 4, 9, 8]:
+                for store in (big, small):
+                    await mgr.on_piece(store.metadata.task_id, store,
+                                       store.metadata.pieces[n])
+            for store, content in ((big, big_content),
+                                   (small, small_content)):
+                sink = await mgr.finalize(store.metadata.task_id, store)
+                assert sink is not None and sink.verified
+                assert bytes(np.asarray(sink.as_bytes_array())) == content
+            first = _since(before)
+            # The same two again, now from what the first landings gave
+            # back: no stack is new.
+            for store in (big, small):
+                mgr.discard(store.metadata.task_id)
+                sink = await mgr.finalize(store.metadata.task_id, store)
+                assert sink is not None and sink.verified
+        finally:
+            mgr.close()
+        return first, _since(before)
+
+    first, both = run_async(body(), timeout=120)
+    assert (first["in_place"], first["copied"]) == (20, 0)
+    assert first["fresh"] + first["pooled"] == 6
+    assert both["fresh"] == first["fresh"]
+    assert both["pooled"] - first["pooled"] == 6
+
+
+@pytest.mark.parametrize("how", ["degraded", "put-failed", "discarded",
+                                 "expired", "finalize-failed"])
+def test_a_sink_dropped_mid_batch_leaks_no_staging_stack(
+        run_async, tmp_path, how, monkeypatch):
+    """Six of ten pieces landed (a batch put, a stack half full) when the
+    manager forgets the sink: the free list's leak guard reads zero."""
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops import hbm_sink
+    from dragonfly2_tpu.storage.local_store import StorageError
+
+    async def body():
+        store, _ = _stored(tmp_path, "t-" + how, 64 * 1024, 64 * 1024 * 10)
+        task_id = store.metadata.task_id
+        records = store.metadata.pieces
+        mgr = DeviceSinkManager(batch_pieces=4)
+        outstanding = hbm_sink._STAGING.stats()["outstanding"]
+        try:
+            for n in range(6):
+                await mgr.on_piece(task_id, store, records[n])
+            assert hbm_sink._STAGING.stats()["outstanding"] > outstanding
+
+            def fails(num, buf):
+                raise StorageError(f"piece {num} unreadable")
+
+            if how == "degraded":
+                monkeypatch.setattr(store, "read_piece_into", fails)
+                await mgr.on_piece(task_id, store, records[6])
+                assert "unreadable" in mgr.outcome(task_id, False)[
+                    "device_error"]
+            elif how == "put-failed":
+                def refuses(staged, order):
+                    raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+                monkeypatch.setattr(hbm_sink, "_reorder_jit", refuses)
+                for n in (6, 7):        # the second fills the batch
+                    await mgr.on_piece(task_id, store, records[n])
+                assert "out of HBM" in mgr.outcome(task_id, False)[
+                    "device_error"]
+            elif how == "discarded":
+                mgr.discard(task_id)
+            elif how == "expired":
+                mgr.ttl = 0.0
+                mgr.gc()
+            else:
+                monkeypatch.setattr(store, "read_piece_into", fails)
+                assert await mgr.finalize(task_id, store) is None
+            assert mgr.get(task_id) is None
+            return hbm_sink._STAGING.stats()["outstanding"] - outstanding
+        finally:
+            mgr.close()
+
+    assert run_async(body(), timeout=120) == 0
+
+
+def _the_benchmarks_break():
+    """``broken`` of chipbench/tests/control.py itself, compiled from its
+    source: importing the file would put chipbench/ on sys.path, where a
+    second ``tests`` package lives."""
+    import ast
+    import contextlib
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "tests", "control.py")
+    with open(path) as f:
+        module = ast.parse(f.read())
+    module.body = [node for node in module.body
+                   if isinstance(node, ast.FunctionDef)
+                   and node.name == "broken"]
+    scope = {"contextlib": contextlib}
+    exec(compile(module, path, "exec"), scope)
+    return scope["broken"]
+
+
+@pytest.mark.parametrize("how", ["flip", "zero"])
+def test_the_benchmarks_control_still_alters_what_lands(
+        run_async, tmp_path, how):
+    """The yardstick's ``correct`` rests on this hook: wrapped around
+    ``HBMSink.land_piece``, the control's altered bytes are what reaches
+    HBM, and the sink's own verification (taken after the hook) passes."""
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+
+    piece, pieces = 64 * 1024, 10
+
+    async def body():
+        store, content = _stored(tmp_path, "t-" + how, piece,
+                                 piece * pieces - piece // 2)
+        task_id = store.metadata.task_id
+        mgr = DeviceSinkManager(batch_pieces=4)
+        before = _counts()
+        try:
+            with _the_benchmarks_break()(how):
+                for n in [3, 0, 1, 2, 7, 6, 5]:
+                    await mgr.on_piece(task_id, store,
+                                       store.metadata.pieces[n])
+                sink = await mgr.finalize(task_id, store)
+            assert sink is not None and sink.verified
+            return (content, bytes(np.asarray(sink.as_bytes_array())),
+                    _since(before))
+        finally:
+            mgr.close()
+
+    content, landed, moved = run_async(body(), timeout=120)
+    want = bytearray(content)
+    if how == "flip":
+        at = (pieces // 2) * piece
+        want[at + piece // 3] ^= 0x10
+    else:
+        want[(pieces - 1) * piece:] = bytes(piece // 2)
+    assert landed == bytes(want) and landed != content
+    # The one altered piece came as foreign bytes and was copied into its
+    # row; every other one was read in place.
+    assert (moved["in_place"], moved["copied"]) == (pieces - 1, 1)

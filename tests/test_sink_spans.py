@@ -10,6 +10,7 @@ writers, and that none of it pulled jax into a daemon that holds no sink.
 
 from __future__ import annotations
 
+import dataclasses
 import gc
 import random
 import subprocess
@@ -26,22 +27,25 @@ SINK_NAMES = ("sink_land", "sink_read", "sink_checksum", "sink_stage",
               "sink_put", "sink_assemble", "sink_compile", "sink_finalize")
 PIECES = 10
 BATCH = 4
-# Bounds how far a span's backed-out start (stamp time - aux) may sit from
-# where the step really began: the stamp is taken a few microseconds after
-# the duration was.
-SLACK_S = 1e-3
+ORDER = [3, 0, 1, 2, 7, 6, 5, 4, 9, 8]     # how the cold landing's pieces arrive
+# The steps of a piece (or of a flush in finalize), which contain nothing.
+LEAVES = ("sink_read", "sink_checksum", "sink_stage", "sink_put")
 
 
 @pytest.fixture
 def fresh_compiles():
-    """No persistent compilation cache: whatever the sink compiles in the
-    test, it compiles, whatever an earlier run left on disk."""
+    """Neither a persistent compilation cache nor an assembly program in
+    memory: whatever plan the sink meets in the test it compiles, whatever
+    an earlier run left on disk or an earlier test in this worker landed."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
+
+    from dragonfly2_tpu.ops import hbm_sink
 
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    hbm_sink._assemble_checksum_jit.clear_cache()
     yield
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
@@ -86,13 +90,58 @@ def spans_of(tf) -> dict:
     return out
 
 
-def inside(child, parents) -> bool:
-    return any(p[0] - SLACK_S <= child[0] and child[1] <= p[1] + SLACK_S
-               for p in parents)
-
-
 def seconds(rows) -> float:
     return sum(e - s for s, e, _ in rows)
+
+
+@dataclasses.dataclass
+class Span:
+    name: str
+    piece: int
+    ms: float
+    children: list
+
+    def named(self, *names: str) -> list:
+        return [c for c in self.children if c.name in names]
+
+
+def tree_of(tf) -> list:
+    """The ring's sink_* spans as the landing thread nested them. One
+    thread stamps every span, at its end, so the ring's order alone says
+    what lies inside what, with no clock compared: a ``sink_land`` holds
+    the leaves stamped since the span before it, a ``sink_assemble`` the
+    compiles, and a ``sink_finalize`` what is loose by then and the
+    ``sink_land``s it counted in ``piece`` (its backfill)."""
+    top: list = []
+    for _, code, piece, aux, _ in tf.events():
+        name = flight.EVENT_NAMES[code]
+        if name not in SINK_NAMES:
+            continue
+        span = Span(name, piece, aux, [])
+        if name == "sink_finalize":
+            first = len(top)
+            while first and top[first - 1].name != "sink_finalize" and (
+                    top[first - 1].name != "sink_land"
+                    or len([c for c in top[first:]
+                            if c.name == "sink_land"]) < piece):
+                first -= 1
+            span.children, top[first:] = top[first:], []
+        elif name in ("sink_land", "sink_assemble"):
+            held = LEAVES if name == "sink_land" else ("sink_compile",)
+            first = len(top)
+            while first and top[first - 1].name in held:
+                first -= 1
+            span.children, top[first:] = top[first:], []
+        top.append(span)
+    return top
+
+
+def check_sums(spans: list) -> None:
+    """Children never add up to more than their parent: they were timed
+    one after the other inside it."""
+    for span in spans:
+        assert sum(c.ms for c in span.children) <= span.ms + 1e-6, span
+        check_sums(span.children)
 
 
 def test_cold_landing_stamps_every_span_children_inside_parents(
@@ -104,53 +153,49 @@ def test_cold_landing_stamps_every_span_children_inside_parents(
         tf = flight.TaskFlight("t-cold")
         mgr = DeviceSinkManager(batch_pieces=BATCH)
         try:
-            order = [3, 0, 1, 2, 7, 6, 5, 4, 9, 8]
-            sink = await land_cold(mgr, store, tf, order)
+            sink = await land_cold(mgr, store, tf, ORDER)
             assert sink is not None and sink.verified
             got = bytes(np.asarray(sink.as_bytes_array()))
             assert got == content
         finally:
             mgr.close()
-        return spans_of(tf)
+        return tf
 
-    spans = run_async(body(), timeout=120)
-    for name in SINK_NAMES:
-        assert spans[name], f"no {name} event"
+    tf = run_async(body(), timeout=120)
+    spans = spans_of(tf)
     counts = {name: len(rows) for name, rows in spans.items()}
-    # Ten pieces, each read and checksummed once on the thread; two full
-    # batches flushed while landing and the rest in finalize; one
-    # assembly of a plan never met before; nothing left to backfill.
+    # Ten pieces, each read, staged and checksummed once on the thread;
+    # three stacks opened; two full batches flushed while landing and the
+    # rest in finalize; one assembly of a plan never met before; nothing
+    # left to backfill.
     assert counts == {"sink_land": PIECES, "sink_read": PIECES,
-                      "sink_checksum": PIECES, "sink_stage": 3,
+                      "sink_checksum": PIECES, "sink_stage": PIECES + 3 + 3,
                       "sink_put": 3, "sink_assemble": 1, "sink_compile": 1,
                       "sink_finalize": 1}
-    assert sorted(p for _, _, p in spans["sink_land"]) == list(range(PIECES))
-    assert [p for _, _, p in spans["sink_finalize"]] == [0]
     # A batch is named by its lowest slot.
-    assert sorted(p for _, _, p in spans["sink_stage"]) == [0, 4, 8]
     assert sorted(p for _, _, p in spans["sink_put"]) == [0, 4, 8]
 
-    lands, final = spans["sink_land"], spans["sink_finalize"]
-    by_piece = {p: (s, e, p) for s, e, p in lands}
-    for name in ("sink_read", "sink_checksum"):
-        for row in spans[name]:
-            assert inside(row, [by_piece[row[2]]]), (name, row)
-    for name in ("sink_stage", "sink_put"):
-        for row in spans[name]:
-            assert inside(row, lands + final), (name, row)
-    assert inside(spans["sink_assemble"][0], final)
-    assert inside(spans["sink_compile"][0], spans["sink_assemble"])
-    # Children never add up to more than their parent.
-    for land in lands:
-        children = [row for name in ("sink_read", "sink_checksum",
-                                     "sink_stage", "sink_put")
-                    for row in spans[name] if inside(row, [land])]
-        assert seconds(children) <= (land[1] - land[0]) + SLACK_S
-    in_final = [row for name in ("sink_stage", "sink_put", "sink_assemble")
-                for row in spans[name] if inside(row, final)
-                and not inside(row, lands)]
-    assert in_final and seconds(in_final) <= seconds(final) + SLACK_S
-    assert seconds(spans["sink_compile"]) <= seconds(spans["sink_assemble"])
+    top = tree_of(tf)
+    lands, (final,) = top[:-1], top[-1:]
+    assert [span.name for span in lands] == ["sink_land"] * PIECES
+    assert [span.piece for span in lands] == ORDER
+    assert (final.name, final.piece) == ("sink_finalize", 0)
+    for at, land in enumerate(lands):
+        # Where a stack is opened (-1), the read into its row, the row's
+        # own staging, its checksum; with the batch's last piece the flush.
+        want = ([("sink_stage", -1)] if at % BATCH == 0 else []) + [
+            ("sink_read", land.piece), ("sink_stage", land.piece),
+            ("sink_checksum", land.piece)]
+        if at % BATCH == BATCH - 1:
+            lowest = min(ORDER[at - BATCH + 1:at + 1])
+            want += [("sink_stage", lowest), ("sink_put", lowest)]
+        assert [(c.name, c.piece) for c in land.children] == want, at
+    assert [(c.name, c.piece) for c in final.children[:2]] == [
+        ("sink_stage", 8), ("sink_put", 8)]
+    (assemble,) = final.children[2:]
+    assert assemble.name == "sink_assemble"
+    assert [c.name for c in assemble.children] == ["sink_compile"]
+    check_sums(top)
 
 
 def test_reland_backfill_stamps_the_same_steps_and_one_finalize(
@@ -170,23 +215,25 @@ def test_reland_backfill_stamps_the_same_steps_and_one_finalize(
             assert bytes(np.asarray(sink.as_bytes_array())) == content
         finally:
             mgr.close()
-        return spans_of(tf)
+        return tf
 
-    spans = run_async(body(), timeout=120)
+    tf = run_async(body(), timeout=120)
+    spans = spans_of(tf)
     for name in ("sink_land", "sink_read", "sink_checksum"):
         assert [p for _, _, p in spans[name]] == list(range(PIECES)), name
-    assert len(spans["sink_stage"]) == len(spans["sink_put"]) == 3
-    assert [p for _, _, p in spans["sink_finalize"]] == [PIECES]
+    assert len(spans["sink_stage"]) == PIECES + 3 + 3
+    assert len(spans["sink_put"]) == 3
     # In order: one segment per batch.
     assert [p for _, _, p in spans["sink_assemble"]] == [3]
-    final = spans["sink_finalize"]
-    for name in SINK_NAMES[:-1]:
-        for row in spans[name]:
-            assert inside(row, final), (name, row)
-    direct = spans["sink_land"] + spans["sink_assemble"] + [
-        row for name in ("sink_stage", "sink_put") for row in spans[name]
-        if not inside(row, spans["sink_land"])]
-    assert seconds(direct) <= seconds(final) + SLACK_S
+    # Everything lies in the one finalize, which counted its backfill.
+    (final,) = tree_of(tf)
+    assert (final.name, final.piece) == ("sink_finalize", PIECES)
+    assert [c.piece for c in final.named("sink_land")] == list(range(PIECES))
+    assert [c.name for c in final.children[PIECES:]] == [
+        "sink_stage", "sink_put", "sink_assemble"]
+    assert sum(len(c.children) for c in final.children) + len(
+        final.children) + 1 == sum(len(rows) for rows in spans.values())
+    check_sums([final])
 
 
 def test_new_plan_stamps_one_compile_and_a_repeated_plan_none(

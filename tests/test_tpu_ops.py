@@ -328,6 +328,212 @@ def test_hbm_sink_consolidates_batches_at_scale():
     assert np.asarray(sink.as_bytes_array()).tobytes() == content
 
 
+# -- the reused staging stacks (ops/hbm_sink.py "Host staging") ------------
+
+def four_streams(pieces: int) -> list:
+    """Arrival as a seed's four ranged streams give it: 0, 14, 28, 42, 1,
+    15, ... for 55 pieces."""
+    per = -(-pieces // 4)
+    return [s * per + i for i in range(per) for s in range(4)
+            if s * per + i < pieces]
+
+
+def sorted_batch_plan(order: list, batch: int, pieces: int) -> tuple:
+    """The assembly plan of a sink that sorts each batch by slot on the
+    host before it stacks it (what ``flush`` did before the stacks were
+    reused): the plan no arrival order may now exceed."""
+    where = {}
+    for bi in range(0, len(order), batch):
+        for row, slot in enumerate(sorted(order[bi:bi + batch])):
+            where[slot] = (bi // batch, row)
+    plan, slot = [], 0
+    while slot < pieces:
+        bi, row = where[slot]
+        run = 1
+        while where.get(slot + run) == (bi, row + run):
+            run += 1
+        plan.append(("b", bi, row, row + run))
+        slot += run
+    return tuple(plan)
+
+
+ARRIVALS = {"in-order": list(range(14)), "reversed": list(range(13, -1, -1)),
+            "four-streams": four_streams(14)}
+
+
+@pytest.fixture
+def staging(monkeypatch):
+    """A free list of the test's own, so that which stack comes back does
+    not depend on what earlier tests of this worker left in the
+    process's."""
+    from dragonfly2_tpu.ops import hbm_sink
+    from dragonfly2_tpu.pkg.bufpool import BufferPool
+
+    pool = BufferPool(1 << 30, name="hbm_stage")
+    monkeypatch.setattr(hbm_sink, "_STAGING", pool)
+    return pool
+
+
+def read_into_rows(sink, content: bytes, order) -> None:
+    """Land as the daemon does: each piece written into the sink's own
+    row, and that row handed over."""
+    piece = sink.piece_size
+    for n in order:
+        data = content[n * piece:(n + 1) * piece]
+        row = sink.next_row()
+        row[:len(data)] = data
+        sink.land_piece(n, row[:len(data)])
+
+
+@pytest.mark.parametrize("arrival", list(ARRIVALS))
+def test_rows_land_in_arrival_order_and_the_plan_is_the_sorted_one(
+        staging, arrival):
+    from dragonfly2_tpu.ops import hbm_sink
+
+    order = ARRIVALS[arrival]
+    piece, batch = 4096, 4
+    content = np.random.RandomState(21).bytes(piece * len(order) - 1001)
+    sink = HBMSink(len(content), piece, batch_pieces=batch)
+    in_place = hbm_sink._ROWS_IN_PLACE._value.get()
+    copied = hbm_sink._ROWS_COPIED._value.get()
+    read_into_rows(sink, content, order)
+    sink.flush()
+    assert sink._plan() == sorted_batch_plan(order, batch, len(order))
+    if arrival == "in-order":
+        assert sink._plan() == tuple(
+            ("b", bi, 0, min(batch, len(order) - bi * batch))
+            for bi in range(4))
+    assert sink.verify()
+    assert np.asarray(sink.as_bytes_array()).tobytes() == content
+    assert hbm_sink._ROWS_IN_PLACE._value.get() - in_place == len(order)
+    assert hbm_sink._ROWS_COPIED._value.get() == copied
+    # Every stack is back, and the landing never held more than two.
+    stats = staging.stats()
+    assert stats["outstanding"] == 0 and 1 <= stats["free_buffers"] <= 2
+
+
+def test_a_short_last_piece_in_a_dirty_stack_reads_zero_padded(staging):
+    """A reused stack is not fresh zeros: past a short, non-word-aligned
+    last piece lies what an earlier task left there."""
+    piece, batch = 4096, 4
+    dirty = HBMSink(piece * batch, piece, batch_pieces=batch)
+    for n in range(batch):
+        dirty.land_piece(n, b"\xff" * piece)
+    assert dirty.verify()
+    assert staging.stats()["free_buffers"] == 1
+    content = np.random.RandomState(22).bytes(piece * 3 + 1001)
+    def hand_over_bytes(sink, content: bytes, order) -> None:
+        for n in order:
+            sink.land_piece(n, content[n * piece:(n + 1) * piece])
+
+    for land in (read_into_rows, hand_over_bytes):
+        sink = HBMSink(len(content), piece, batch_pieces=batch)
+        land(sink, content, [3, 0, 1, 2])
+        assert sink.verify()
+        words = np.asarray(sink.as_words())
+        assert words.tobytes()[:len(content)] == content
+        assert not words.tobytes()[len(content):].strip(b"\x00")
+        assert len(words) == batch * piece // 4
+        assert sink.host_checksums[3] == checksum_numpy(content[3 * piece:])
+    assert staging.stats()["free_buffers"] == 1      # the same stack, thrice
+
+
+def test_a_second_landing_takes_its_stacks_from_the_free_list(staging):
+    from dragonfly2_tpu.pkg import bufpool
+
+    def acquires() -> tuple:
+        return tuple(bufpool.BUFPOOL_ACQUIRES.labels("hbm_stage", source)
+                     ._value.get() for source in ("fresh", "pooled"))
+
+    piece, batch, pieces = 4096, 4, 14
+    content = np.random.RandomState(23).bytes(piece * pieces)
+    counts = [acquires()]
+    for _ in range(2):
+        sink = HBMSink(len(content), piece, batch_pieces=batch)
+        read_into_rows(sink, content, four_streams(pieces))
+        assert sink.verify()
+        counts.append(acquires())
+    (f0, p0), (f1, p1), (f2, p2) = counts
+    assert 1 <= f1 - f0 <= 2 and (f1 - f0) + (p1 - p0) == 4
+    assert f2 == f1 and p2 - p1 == 4
+
+
+def test_no_staged_batch_aliases_a_stack_that_went_back(monkeypatch):
+    """On the CPU backend ``device_put`` of a 64-byte-aligned host array
+    copies nothing: the device array is the host memory, for its whole
+    life. A stack that went back to the free list while such an array
+    lived would change under it."""
+    from dragonfly2_tpu.ops import hbm_sink
+    from dragonfly2_tpu.pkg.bufpool import BufferPool
+
+    def address(buf) -> int:
+        return np.frombuffer(buf, np.uint8).__array_interface__["data"][0]
+
+    class Aligned(BufferPool):
+        """Hands out 64-byte-aligned views, and looks at every sink's
+        staged batches when one comes back."""
+
+        def acquire(self, size: int) -> memoryview:
+            view = super().acquire(size + 64)
+            skip = -address(view) % 64
+            return view[skip:skip + size]
+
+        def release(self, view) -> None:
+            lo = address(view)
+            for sink in sinks:
+                for _, staged in sink._batches:
+                    at = staged.unsafe_buffer_pointer()
+                    assert not lo <= at < lo + len(view), "aliased"
+            returned.append(lo)
+            super().release(view)
+
+    sinks, returned = [], []
+    pool = Aligned(1 << 30, name="hbm_stage")
+    monkeypatch.setattr(hbm_sink, "_STAGING", pool)
+    probe = pool.acquire(4 * 4096)
+    words = np.frombuffer(probe, np.uint32).reshape(4, -1)
+    if jax.device_put(words).unsafe_buffer_pointer() != address(probe):
+        pytest.skip("this backend copies on device_put: nothing to alias")
+    pool.release(probe)
+    returned.clear()
+
+    piece, batch, pieces = 4096, 4, 22
+    content = np.random.RandomState(24).bytes(piece * pieces - 3)
+    sink = HBMSink(len(content), piece, batch_pieces=batch)
+    sinks.append(sink)
+    read_into_rows(sink, content, four_streams(pieces))
+    # Six stacks were filled from two buffers: some went back mid-landing,
+    # with earlier batches still staged on the device.
+    assert len(returned) >= 4 and len(set(returned)) <= 2
+    assert sink.verify()
+    assert np.asarray(sink.as_bytes_array()).tobytes() == content
+    assert pool.stats()["outstanding"] == 0
+
+
+def test_a_sink_dropped_mid_batch_gives_its_stacks_back(staging):
+    piece, batch = 4096, 4
+    content = np.random.RandomState(25).bytes(piece * 14)
+    sink = HBMSink(len(content), piece, batch_pieces=batch)
+    read_into_rows(sink, content, range(6))     # one stack put, one open
+    sink.next_row()                             # and a row handed out
+    held = staging.stats()["outstanding"]
+    assert held in (1, 2)           # the put stack may be back already
+    del sink
+    assert staging.stats()["outstanding"] == 0
+    assert staging.stats()["free_buffers"] == held
+    with pytest.raises(ValueError, match="4-byte aligned"):
+        HBMSink(10, 6)                          # nothing taken, nothing owed
+    assert staging.stats()["outstanding"] == 0
+
+
+def test_a_piece_larger_than_the_sinks_pieces_is_refused(staging):
+    sink = HBMSink(4096 * 2, 4096)
+    with pytest.raises(ValueError, match="4097 bytes"):
+        sink.land_piece(0, bytes(4097))
+    sink.land_piece(0, bytes(4096))
+    assert sink.landed == {0}
+
+
 class TestMultihostAssembly:
     """parallel/multihost.py on the virtual 8-device mesh: the seam from
     per-host fabric landings to one pod-global jax.Array (single-process
