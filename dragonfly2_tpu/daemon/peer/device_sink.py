@@ -21,6 +21,9 @@ Spans: that thread stamps its steps into the task's flight ring
 ``sink_put``; ``sink_finalize`` > the backfill's ``sink_land``s,
 ``sink_assemble`` > ``sink_compile``), one event at a step's end with its
 ms — a child is a span that lies inside another, there being one thread.
+Before them each job stamps ``sink_wait``, the time it stood queued for
+the thread: a re-land is ONE job (``_finalize_sync``), so several tasks
+landing at once wait for each other's whole landings there.
 
 Lifecycle: sinks are created lazily at the first landed piece (task
 metadata — length and piece size — is unknown at request time), verified
@@ -55,6 +58,12 @@ SINK_COMPILES = metrics.counter(
 SINK_COMPILE_SECONDS = metrics.counter(
     "device_sink_compile_seconds_total",
     "Backend-compile seconds spent inside device sink assemblies")
+SINK_WAIT_SECONDS = metrics.counter(
+    "device_sink_wait_seconds_total",
+    "Seconds jobs stood queued for the one landing thread")
+SINKS_LANDING = metrics.gauge(
+    "device_sink_landing",
+    "Device sinks created and not yet verified or dropped")
 
 
 class DeviceSinkError(Exception):
@@ -71,13 +80,15 @@ class _SpanStamp:
 
     __slots__ = ("flight",)
 
-    def __init__(self):
-        self.flight: "flightlib.TaskFlight | None" = None
+    def __init__(self, flight: "flightlib.TaskFlight | None" = None):
+        self.flight = flight
 
     def __call__(self, code: int, piece: int, ms: float) -> None:
         if code == flightlib.EV_SINK_COMPILE:
             SINK_COMPILES.inc()
             SINK_COMPILE_SECONDS.inc(ms / 1000.0)
+        elif code == flightlib.EV_SINK_WAIT:
+            SINK_WAIT_SECONDS.inc(ms / 1000.0)
         if self.flight is not None:
             self.flight.record(code, piece, ms)
 
@@ -107,6 +118,9 @@ class TaskDeviceSink:
         self.created_at = time.time()
         self.verified = False
         self.verified_at = 0.0
+        # Counted in device_sink_landing: set by the manager that holds
+        # it, cleared once, when it verifies or is dropped.
+        self.landing = False
         # Host-side piece digests at land time: lets a later finalize
         # detect that the store's content changed under a resident sink.
         self.piece_digests: dict[int, str] = {}
@@ -213,9 +227,31 @@ class DeviceSinkManager:
     def close(self) -> None:
         self._exec.shutdown(wait=False, cancel_futures=True)
 
-    async def _run(self, fn, *args):
+    async def _run(self, tf, piece: int, fn, *args):
+        """One job for the landing thread. As it starts there it stamps
+        ``sink_wait``: how long it stood queued since this call."""
+        submitted = time.perf_counter()
+
+        def job():
+            _SpanStamp(tf)(flightlib.EV_SINK_WAIT, piece,
+                           (time.perf_counter() - submitted) * 1000.0)
+            return fn(*args)
+
         return await asyncio.get_running_loop().run_in_executor(
-            self._exec, fn, *args)
+            self._exec, job)
+
+    @staticmethod
+    def _settle(sink: TaskDeviceSink) -> None:
+        """The sink is no longer landing: verified, or dropped before."""
+        if sink.landing:
+            sink.landing = False
+            SINKS_LANDING.dec()
+
+    def _drop(self, task_id: str) -> TaskDeviceSink | None:
+        sink = self._sinks.pop(task_id, None)
+        if sink is not None:
+            self._settle(sink)
+        return sink
 
     # -- landing ----------------------------------------------------------
 
@@ -224,7 +260,8 @@ class DeviceSinkManager:
         on_piece hook). Creation is lazy: the first piece to arrive after
         the task's length and piece size are known allocates the buffer.
         ``tf`` is the task's flight, for the landing thread's spans."""
-        await self._run(self._land_sync, task_id, store, rec, tf)
+        await self._run(tf, rec.num, self._land_sync, task_id, store, rec,
+                        tf)
 
     def _land_sync(self, task_id: str, store, rec, tf=None) -> None:
         with self._span(tf and tf.record, flightlib.EV_SINK_LAND, rec.num):
@@ -266,7 +303,7 @@ class DeviceSinkManager:
             log.warning("device landing failed; degrading to disk-only",
                         task=task_id[:16], error=str(e)[:200])
             self._note_error(task_id, "landing", e)
-            self._sinks.pop(task_id, None)
+            self._drop(task_id)
             self._degraded.add(task_id)
 
     def _note_error(self, task_id: str, stage: str, err) -> None:
@@ -324,6 +361,8 @@ class DeviceSinkManager:
             self._note_error(task_id, "create", e)
             return None
         self._sinks[task_id] = sink
+        sink.landing = True
+        SINKS_LANDING.inc()
         log.info("device sink created", task=task_id[:16],
                  bytes=content_length)
         return sink
@@ -338,7 +377,8 @@ class DeviceSinkManager:
         could be allocated (cap reached, misaligned pieces) — disk-only
         degradation; raises DeviceSinkError on device-copy CORRUPTION.
         ``tf`` as for ``on_piece``."""
-        return await self._run(self._finalize_sync, task_id, store, tf)
+        return await self._run(tf, 0, self._finalize_sync, task_id, store,
+                               tf)
 
     def _finalize_sync(self, task_id: str, store,
                        tf=None) -> TaskDeviceSink | None:
@@ -360,7 +400,7 @@ class DeviceSinkManager:
                 log.warning("device finalize failed; disk-only result",
                             task=task_id[:16], error=str(e)[:200])
                 self._note_error(task_id, "finalize", e)
-                self._sinks.pop(task_id, None)
+                self._drop(task_id)
                 return None
 
     def _finalize_inner(self, task_id: str, store, tf,
@@ -373,7 +413,7 @@ class DeviceSinkManager:
             # retry): a mixed buffer must never verify. Rebuild.
             log.warning("device sink stale vs store; rebuilding",
                         task=task_id[:16])
-            del self._sinks[task_id]
+            self._drop(task_id)
             sink = None
         if sink is None:
             sink = self._create(task_id, m.content_length, m.piece_size)
@@ -387,6 +427,7 @@ class DeviceSinkManager:
                     self._land_one(sink, store, rec, tf)
                 step.piece += 1
         sink.verify()
+        self._settle(sink)
         log.info("device sink verified", task=task_id[:16],
                  pieces=len(sink.landed))
         return sink
@@ -420,10 +461,10 @@ class DeviceSinkManager:
 
     def take(self, task_id: str) -> TaskDeviceSink | None:
         """Claim the sink (caller owns the buffer; manager forgets it)."""
-        return self._sinks.pop(task_id, None)
+        return self._drop(task_id)
 
     def discard(self, task_id: str) -> None:
-        self._sinks.pop(task_id, None)
+        self._drop(task_id)
         self._degraded.discard(task_id)
         self._errors.pop(task_id, None)
 
@@ -449,7 +490,7 @@ class DeviceSinkManager:
         for tid in [t for t, s in self._sinks.items()
                     if now - s.created_at > self.ttl]:
             log.info("device sink expired", task=tid[:16])
-            del self._sinks[tid]
+            self._drop(tid)
 
     def default_mesh(self):
         """Mesh over LOCAL devices per TPUSinkOption.mesh_shape (or all

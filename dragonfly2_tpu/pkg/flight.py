@@ -103,6 +103,11 @@ EV_SINK_ASSEMBLE = 34  # assembly dispatch -> checksums on host (piece=segments)
 EV_SINK_COMPILE = 35   # backend compile inside that assembly (piece=segments)
 EV_SINK_FINALIZE = 36  # backfill + assemble + verify (piece=pieces backfilled)
 EV_PARENT_PIECES = 37  # a parent announced pieces (piece=lowest, aux=how many)
+# A job's wait for the landing thread, stamped as the job starts there:
+# submission on the event loop -> start on the thread. A sibling of the
+# sink_land / sink_finalize that follows it, not a child: it ends where
+# that one begins.
+EV_SINK_WAIT = 38      # (piece=num for on_piece, 0 for finalize)
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -123,7 +128,7 @@ EVENT_NAMES = {
     EV_SINK_CHECKSUM: "sink_checksum", EV_SINK_STAGE: "sink_stage",
     EV_SINK_PUT: "sink_put", EV_SINK_ASSEMBLE: "sink_assemble",
     EV_SINK_COMPILE: "sink_compile", EV_SINK_FINALIZE: "sink_finalize",
-    EV_PARENT_PIECES: "parent_pieces",
+    EV_PARENT_PIECES: "parent_pieces", EV_SINK_WAIT: "sink_wait",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING
@@ -131,10 +136,11 @@ EVENT_NAMES = {
 # so --explain can say the LOOP was wedged, not just "nothing happened".
 _RUNTIME_EVENTS = (EV_LOOP_LAG, EV_GC_PAUSE)
 
-# The landing thread's steps, summed into the report's ``hbm`` block.
+# The landing thread's steps, and last the jobs' wait for it, summed into
+# the report's ``hbm`` block.
 _SINK_STEPS = (EV_SINK_LAND, EV_SINK_READ, EV_SINK_CHECKSUM, EV_SINK_STAGE,
                EV_SINK_PUT, EV_SINK_ASSEMBLE, EV_SINK_COMPILE,
-               EV_SINK_FINALIZE)
+               EV_SINK_FINALIZE, EV_SINK_WAIT)
 
 # Canonical phase model. ``other`` (residual uninstrumented time) rides
 # alongside so the fold partitions wall time exactly.
@@ -539,8 +545,9 @@ def render_waterfall(report: dict) -> str:
         lines.append(f"  {ph:<10} {v:8.3f}s {100 * v / wall:5.1f}% {bar}")
     hbm = report.get("hbm")
     if hbm:
-        lines.append("hbm landing, ms on the landing thread: " + " ".join(
-            f"{k[:-3]}={v:.1f}" for k, v in hbm.items()))
+        lines.append("hbm landing, ms on the landing thread (wait: queued "
+                     "for it): " + " ".join(
+                         f"{k[:-3]}={v:.1f}" for k, v in hbm.items()))
     advisory = runtime_advisory(report)
     if advisory:
         lines.append(advisory)

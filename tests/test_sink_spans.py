@@ -322,7 +322,10 @@ def test_analyze_books_landing_as_hbm_not_ici(run_async, tmp_path):
         rep["wall_s"], abs=1e-4)
     spans = spans_of(tf)
     block = rep["hbm"]
-    # A key per step seen (this plan may have been compiled before).
+    # The jobs' waits for the thread come last (tests/test_tar_landing.py
+    # has them); before them a key per step seen (this plan may have been
+    # compiled before).
+    assert block.pop("wait_ms") >= 0
     assert list(block) == [name[5:] + "_ms" for name in SINK_NAMES
                            if spans[name]]
     assert set(block) >= {"land_ms", "read_ms", "put_ms", "finalize_ms"}
