@@ -39,6 +39,17 @@ does the stack go back to the free list: ``jax.device_put`` returns
 before the runtime has read the host buffer, and on the CPU backend an
 aligned buffer is aliased, not copied, for the device array's whole life.
 
+Host passes: a piece is passed over twice on the host, by the read into
+its row (the daemon's, ``daemon/peer/device_sink.py``) and by the checksum
+of that row (``land_piece``). Both are plain memory traffic that lets go
+of the GIL, so a piece of at least two ``_CHUNK_FLOOR``s has each pass cut
+into word-aligned chunks (``cuts``), views of the row itself, which a few
+helper threads run side by side (``side_by_side``) while the thread that
+lands the piece waits for all of them. The helpers hold no sink state and
+stamp nothing; the piece's checksum is the fold of its chunks' and is
+``checksum_numpy`` of the row, bit for bit. Smaller pieces are handled
+whole where they are, with no hand-over.
+
 Rates: not measured on this round's chip. Memory, as the v5e compiler
 reports it for the assembly program (``memory_analysis()``,
 tests/test_chip_compile.py): staged batches (argument) + flat content
@@ -60,8 +71,10 @@ No reference analog: Dragonfly2's terminal store is the filesystem
 from __future__ import annotations
 
 import functools
+import operator
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor, wait
 
 import jax
 import jax.numpy as jnp
@@ -80,9 +93,12 @@ from dragonfly2_tpu.pkg.piece import PIECE_SIZE_LIMIT
 log = dflog.get("ops.hbm_sink")
 
 # A landing sink holds at most this many staging stacks: the one it fills
-# and the one the runtime may still be reading. A put takes 35-65 ms and
-# the next batch's reads 200-300, so the wait for the older one is a no-op
-# in practice (PERF.md section 5).
+# and the one the runtime may still be reading. A put takes 35-65 ms and its
+# reorder 9.6; since PR 28 the next batch of 8 x 32 MiB is read and
+# checksummed in about 60 ms (200-300 before), so the wait for the older
+# stack is no longer far off. On the chip it was still not met
+# (land_stage_ms 3.6 a re-land, as before; PERF.md section 5): a host pass
+# that gets faster again meets the link here first.
 _STACKS_PER_SINK = 2
 # The free list of staging stacks, shared by every sink of the process: a
 # second landing touches no new page. It keeps what a daemon at its
@@ -99,6 +115,73 @@ SINK_ROWS = metrics.counter(
     ("how",))
 _ROWS_IN_PLACE = SINK_ROWS.labels("in_place")
 _ROWS_COPIED = SINK_ROWS.labels("copied")
+SINK_PIECES = metrics.counter(
+    "device_sink_pieces_total",
+    "Pieces landed, by how their host passes (read-back, checksum) ran: cut "
+    "into chunks over the helper threads (split) or on the landing thread "
+    "alone (whole)",
+    ("how",))
+_PIECES_SPLIT = SINK_PIECES.labels("split")
+_PIECES_WHOLE = SINK_PIECES.labels("whole")
+
+# A host pass over a piece is cut into at most _HELPERS chunks of about
+# _CHUNK_FLOOR bytes or more each; under two floors it is not cut. Fixed
+# from a re-land on the chip's 13-core host (PERF.md section 6, PR 28): at
+# 32 MiB pieces 4 helpers land in 0.45 s what one thread lands in 1.16, 2 in
+# 0.75 and 8 in 0.39; at 8 MiB pieces 4 chunks of 2 MiB land a shard in 0.105
+# s against 0.178 whole, 2 of 4 MiB in 0.13, and with 8 of 1 MiB the checksum
+# is slower than whole: a hand-over costs what 1 MiB of either pass does.
+_CHUNK_FLOOR = 2 << 20
+_HELPERS = 4
+# A chunk is whole pages of the store's file (a piece begins on one), hence
+# whole words of the row.
+_CHUNK_ALIGN = 4096
+# The helper threads, one pool for the process as _STAGING is one free
+# list: a thread starts when a chunk is handed over and none is idle, so a
+# process that splits no piece has none.
+_POOL = ThreadPoolExecutor(max_workers=_HELPERS,
+                           thread_name_prefix="df-sink-helper")
+
+
+def cuts(size: int) -> "list[tuple[int, int]]":
+    """The ``(start, stop)`` byte ranges a pass over ``size`` bytes is cut
+    into: as many as there are helpers, as long as each holds a floor, and
+    one, the whole, under two floors."""
+    parts = min(_HELPERS, size // _CHUNK_FLOOR)
+    if parts < 2:
+        return [(0, size)]
+    step = -(-size // parts)
+    step += (-step) % _CHUNK_ALIGN
+    return [(at, min(at + step, size)) for at in range(0, size, step)]
+
+
+def side_by_side(fn, ranges) -> list:
+    """``fn(start, stop)`` of every range, on the helper threads at once;
+    the results in the ranges' order. Returns, or raises the first
+    failure, only when EVERY call has come back: a range is a view of a
+    buffer that its owner may give away the moment this returns."""
+    futures = [_POOL.submit(fn, start, stop) for start, stop in ranges]
+    wait(futures)
+    try:
+        return [f.result() for f in futures]
+    finally:
+        # A failure holds this frame (its traceback) and is held by its
+        # future: with the futures still in the frame that is a cycle, and
+        # the failed landing's sink, three frames up, would keep its stacks
+        # and its HBM until the cyclic collector came by.
+        del futures
+
+
+def checksum_row(row: np.ndarray, ranges) -> "tuple[int, int]":
+    """``checksum_numpy(row)`` of a row of whole words, taken range by
+    range (``cuts`` of its size) where there are several: sum32 is the
+    ranges' sums mod 2^32 and xor32 the xor of their xors, so the cut
+    changes no bit."""
+    if len(ranges) < 2:
+        return checksum_numpy(row)
+    parts = side_by_side(lambda a, b: checksum_numpy(row[a:b]), ranges)
+    return (sum(s for s, _ in parts) & 0xFFFFFFFF,
+            functools.reduce(operator.xor, (x for _, x in parts)))
 
 
 def _give_back(view: memoryview) -> None:
@@ -117,17 +200,20 @@ class span:
     """One host step of a landing. A ``df:<event name>`` annotation lies
     around it, so a ``jax.profiler`` session over the daemon shows the step
     beside the device's operations (outside a session an annotation is a
-    flag test). At its end ``stamp(code, piece, ms)`` is called once, if
+    flag test). At its end ``stamp(code, piece, ms, note)`` is called once, if
     there is a stamp: the flight ring's span convention, one event at the
     end whose aux is the duration. ``piece`` may be set inside the block,
-    where it is only known then."""
+    where it is only known then, and so may ``note``, the event's text
+    (``sink_read`` / ``sink_checksum``: into how many chunks the pass was
+    cut, nothing where it was not)."""
 
-    __slots__ = ("stamp", "code", "piece", "_annotation", "_t0")
+    __slots__ = ("stamp", "code", "piece", "note", "_annotation", "_t0")
 
     def __init__(self, stamp, code: int, piece: int = -1):
         self.stamp = stamp
         self.code = code
         self.piece = piece
+        self.note = ""
 
     def __enter__(self) -> "span":
         self._annotation = TraceAnnotation(
@@ -140,7 +226,7 @@ class span:
         ms = (time.perf_counter() - self._t0) * 1000.0
         self._annotation.__exit__(*exc)
         if self.stamp is not None:
-            self.stamp(self.code, self.piece, ms)
+            self.stamp(self.code, self.piece, ms, self.note)
 
 
 # What THIS thread's backend compiles came to, from jax's own monitoring
@@ -342,7 +428,7 @@ class HBMSink:
         self._in_flight: list[tuple[memoryview, jax.Array]] = []
         if piece_size % 4:
             raise ValueError("piece_size must be 4-byte aligned")
-        # ``stamp(code, piece, ms)``: where the owner keeps the spans of
+        # ``stamp(code, piece, ms, note)``: where the owner keeps the spans of
         # this sink's host steps (the task's flight ring), or None.
         self.stamp = stamp
         self.content_length = content_length
@@ -456,10 +542,16 @@ class HBMSink:
                 _ROWS_COPIED.inc()
             # The stack is reused: past the piece lies an earlier one.
             row[given.size:] = 0
-        with span(self.stamp, flight.EV_SINK_CHECKSUM, piece_num):
+        with span(self.stamp, flight.EV_SINK_CHECKSUM, piece_num) as step:
             # Whole words, the zero padding included: nothing to copy.
-            self.host_checksums[piece_num] = checksum_numpy(
-                row[:given.size + (-given.size) % 4])
+            words = row[:given.size + (-given.size) % 4]
+            ranges = cuts(words.size)
+            if len(ranges) > 1:
+                step.note = str(len(ranges))
+                _PIECES_SPLIT.inc()
+            else:
+                _PIECES_WHOLE.inc()
+            self.host_checksums[piece_num] = checksum_row(words, ranges)
         self._rows.append(piece_num)
         self.landed.add(piece_num)
         if len(self._rows) >= self.batch_pieces:

@@ -1287,3 +1287,247 @@ def test_the_benchmarks_control_still_alters_what_lands(
     # The one altered piece came as foreign bytes and was copied into its
     # row; every other one was read in place.
     assert (moved["in_place"], moved["copied"]) == (pieces - 1, 1)
+
+
+# -- a piece's host passes cut into chunks (ops/hbm_sink.py "Host passes") --
+
+def _floor() -> int:
+    from dragonfly2_tpu.ops import hbm_sink
+
+    return hbm_sink._CHUNK_FLOOR
+
+
+def _even_ranges(size: int, parts: int) -> list:
+    """``size`` bytes in ``parts`` word-aligned ranges (fewer where there
+    are not that many words)."""
+    step = max(4, -(-size // parts))
+    step += (-step) % 4
+    return [(at, min(at + step, size))
+            for at in range(0, size, step)] or [(0, size)]
+
+
+# Piece sizes by name, each from the chunk floor: around one floor, around
+# the two floors from which a pass is cut, and a last piece that is not
+# whole words.
+SIZES = {"0": lambda floor: 0, "1": lambda floor: 1, "3": lambda floor: 3,
+         "4": lambda floor: 4, "floor-1": lambda floor: floor - 1,
+         "floor": lambda floor: floor, "floor+1": lambda floor: floor + 1,
+         "2*floor": lambda floor: 2 * floor,
+         "2*floor+3": lambda floor: 2 * floor + 3,
+         "short-last-piece": lambda floor: 3 * floor - 70_001}
+
+
+@pytest.mark.parametrize("name", list(SIZES))
+def test_a_rows_checksum_is_checksum_numpy_however_it_is_cut(name):
+    """The fold of the chunks' checksums against the plain oracle over the
+    piece's bytes: in 1-8 even cuts and in the cut the sink would make, in
+    a reused row whose tail still holds an earlier piece."""
+    from dragonfly2_tpu.ops import hbm_sink
+    from dragonfly2_tpu.ops.checksum import checksum_numpy
+
+    size = SIZES[name](_floor())
+    rng = np.random.default_rng(size)
+    data = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+    # The row as land_piece leaves it: an earlier piece everywhere, this
+    # one over its start, zeros to the end.
+    row = rng.integers(1, 256, 4 * _floor(), dtype=np.uint8)
+    row[:size] = np.frombuffer(data, np.uint8)
+    row[size:] = 0
+    words = row[:size + (-size) % 4]
+    want = checksum_numpy(data)
+    assert hbm_sink.checksum_row(words, hbm_sink.cuts(words.size)) == want
+    for parts in range(1, 9):
+        ranges = _even_ranges(words.size, parts)
+        assert len(ranges) == min(parts, max(1, words.size // 4)), parts
+        assert hbm_sink.checksum_row(words, ranges) == want, parts
+    # The cut itself: whole under two floors, else a floor or more a
+    # chunk, at most the helpers' number, the row covered once.
+    ranges = hbm_sink.cuts(words.size)
+    assert [a for a, _ in ranges] == [0] + [b for _, b in ranges[:-1]]
+    assert ranges[-1][1] == words.size
+    assert len(ranges) == (1 if words.size < 2 * _floor() else
+                           min(hbm_sink._HELPERS, words.size // _floor()))
+    assert all(a % 4 == 0 for a, _ in ranges)
+
+
+@pytest.fixture
+def own_helpers(monkeypatch):
+    """A pool of this test's own in place of the process's, so that the
+    threads it started can be told: none before the first hand-over."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from dragonfly2_tpu.ops import hbm_sink
+
+    pool = ThreadPoolExecutor(max_workers=hbm_sink._HELPERS,
+                              thread_name_prefix="df-sink-helper")
+    monkeypatch.setattr(hbm_sink, "_POOL", pool)
+    yield pool
+    pool.shutdown()
+
+
+def _pieces_counted() -> dict:
+    from dragonfly2_tpu.ops import hbm_sink
+
+    return {how: hbm_sink.SINK_PIECES.labels(how)._value.get()
+            for how in ("split", "whole")}
+
+
+@pytest.mark.parametrize("passes", ["split", "whole"])
+def test_a_piece_of_two_floors_is_split_and_a_smaller_one_is_not(
+        run_async, tmp_path, monkeypatch, own_helpers, passes):
+    """Ten pieces of 64 KiB, the last short and not whole words, re-landed:
+    with the floor at 16 KiB (patched: there is no option) every pass runs
+    in chunks on the helpers; at the real floor none does and no helper
+    thread exists. Either way the words are the store's bytes."""
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops import hbm_sink
+    from dragonfly2_tpu.pkg import flight
+
+    piece, pieces = 64 * 1024, 10
+    if passes == "split":
+        monkeypatch.setattr(hbm_sink, "_CHUNK_FLOOR", 16 * 1024)
+
+    async def body():
+        store, content = _stored(tmp_path, "t-" + passes, piece,
+                                 piece * pieces - 30_001)
+        tf = flight.TaskFlight(store.metadata.task_id)
+        mgr = DeviceSinkManager(batch_pieces=4)
+        before, counted = _counts(), _pieces_counted()
+        try:
+            sink = await mgr.finalize(store.metadata.task_id, store, tf)
+            assert sink is not None and sink.verified
+            words = np.asarray(sink.as_words()).tobytes()
+            assert words[:len(content)] == b"".join(
+                store.read_piece(n) for n in range(pieces)) == content
+            assert not words[len(content):].strip(b"\x00")
+        finally:
+            mgr.close()
+        return (tf, _since(before),
+                {how: n - counted[how]
+                 for how, n in _pieces_counted().items()})
+
+    tf, moved, counted = run_async(body(), timeout=120)
+    assert (moved["in_place"], moved["copied"]) == (pieces, 0)
+    notes = {name: [note for _, code, _, _, note in tf.events()
+                    if flight.EVENT_NAMES[code] == name]
+             for name in ("sink_read", "sink_checksum")}
+    helpers = [t.name for t in own_helpers._threads]
+    if passes == "split":
+        assert counted == {"split": pieces, "whole": 0}
+        # 64 KiB in four chunks; the last piece's 35,535 bytes in two.
+        assert notes == {"sink_read": ["4"] * 9 + ["2"],
+                         "sink_checksum": ["4"] * 9 + ["2"]}
+        assert helpers and all(
+            name.startswith("df-sink-helper") for name in helpers)
+    else:
+        assert counted == {"split": 0, "whole": pieces}
+        assert notes == {"sink_read": [""] * pieces,
+                         "sink_checksum": [""] * pieces}
+        assert helpers == []
+
+
+@pytest.mark.parametrize("how", ["short-read", "os-error"])
+@pytest.mark.parametrize("path", ["on-piece", "finalize"])
+def test_a_chunk_that_fails_degrades_the_task_once_every_chunk_is_back(
+        run_async, tmp_path, monkeypatch, own_helpers, how, path):
+    """Piece 5's second chunk fails at once while its last is still being
+    read (made slow here): the task goes disk-only as for any unreadable
+    piece, and its stacks reach the free list only after the slow chunk
+    is done."""
+    import os
+    import time
+
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops import hbm_sink
+
+    piece, chunk = 64 * 1024, 16 * 1024
+    monkeypatch.setattr(hbm_sink, "_CHUNK_FLOOR", chunk)
+    log: list[str] = []
+    sound_preadv, sound_give = os.preadv, hbm_sink._give_back
+
+    def preadv(fd, buffers, offset):
+        if offset == 5 * piece + chunk:
+            if how == "os-error":
+                raise OSError(5, "Input/output error")
+            return 0                            # the file ends here
+        if offset == 5 * piece + 3 * chunk:
+            time.sleep(0.3)
+            got = sound_preadv(fd, buffers, offset)
+            log.append("slow chunk done")
+            return got
+        return sound_preadv(fd, buffers, offset)
+
+    def give_back(view):
+        log.append("stack given back")
+        sound_give(view)
+
+    async def body():
+        store, _ = _stored(tmp_path, f"t-{how}-{path}", piece, piece * 10)
+        task_id = store.metadata.task_id
+        records = store.metadata.pieces
+        mgr = DeviceSinkManager(batch_pieces=4)
+        outstanding = hbm_sink._STAGING.stats()["outstanding"]
+        try:
+            for n in range(5):
+                await mgr.on_piece(task_id, store, records[n])
+            monkeypatch.setattr(os, "preadv", preadv)
+            monkeypatch.setattr(hbm_sink, "_give_back", give_back)
+            if path == "on-piece":
+                await mgr.on_piece(task_id, store, records[5])
+            else:
+                assert await mgr.finalize(task_id, store) is None
+            assert mgr.get(task_id) is None
+            error = mgr.outcome(task_id, False)["device_error"]
+            return error, hbm_sink._STAGING.stats()[
+                "outstanding"] - outstanding
+        finally:
+            mgr.close()
+
+    error, leaked = run_async(body(), timeout=120)
+    assert ("short read" if how == "short-read"
+            else "Input/output error") in error
+    assert leaked == 0
+    assert log[0] == "slow chunk done" and "stack given back" in log[1:]
+
+
+def test_two_landing_threads_share_the_helpers(run_async, tmp_path,
+                                               monkeypatch):
+    """Two managers in one process (two landing threads) hand chunks to
+    the one pool at once, under a switch interval that interleaves the
+    threads far more than the default: each sink's words are its own
+    store's bytes, and every piece was split."""
+    import asyncio
+    import sys
+
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops import hbm_sink
+
+    piece = 64 * 1024
+    monkeypatch.setattr(hbm_sink, "_CHUNK_FLOOR", 8 * 1024)
+
+    async def body():
+        stored = [_stored(tmp_path, f"t-shared-{i}", piece,
+                          piece * 24 - 1_001 * (i + 1), seed=20 + i)
+                  for i in range(3)]
+        managers = [DeviceSinkManager(batch_pieces=4) for _ in stored]
+        counted = _pieces_counted()
+        try:
+            sinks = await asyncio.gather(*(
+                mgr.finalize(store.metadata.task_id, store)
+                for mgr, (store, _) in zip(managers, stored)))
+            for sink, (_, content) in zip(sinks, stored):
+                assert sink is not None and sink.verified
+                assert bytes(np.asarray(sink.as_bytes_array())) == content
+        finally:
+            for mgr in managers:
+                mgr.close()
+        return {how: n - counted[how]
+                for how, n in _pieces_counted().items()}
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        counted = run_async(body(), timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert counted == {"split": 3 * 24, "whole": 0}

@@ -51,6 +51,43 @@ def fresh_compiles():
     compilation_cache.reset_cache()
 
 
+@pytest.fixture(params=["whole", "split"])
+def passes(request, monkeypatch):
+    """How a piece's read and checksum run: on the landing thread alone, as
+    pieces of this size do, or, with the chunk floor patched down to a
+    quarter of a piece, in chunks on the helper threads."""
+    if request.param == "split":
+        from dragonfly2_tpu.ops import hbm_sink
+
+        monkeypatch.setattr(hbm_sink, "_CHUNK_FLOOR", 16 * 1024)
+    return request.param
+
+
+class WatchedFlight(flight.TaskFlight):
+    """A flight that also keeps which thread stamped each sink_* event."""
+
+    def __init__(self, task_id: str):
+        super().__init__(task_id)
+        self.stampers: list = []
+
+    def record(self, code, piece=-1, aux=0.0, note=""):
+        if flight.EVENT_NAMES[code].startswith("sink_"):
+            self.stampers.append(threading.current_thread().name)
+        super().record(code, piece, aux, note)
+
+
+def check_passes(tf, passes: str) -> None:
+    """One ``sink_read`` and one ``sink_checksum`` a piece whether or not
+    the pass ran in chunks (the counts are checked beside this): both say
+    into how many, and the landing thread stamped them like every other
+    span, the helpers nothing."""
+    notes = {note for _, code, _, _, note in tf.events()
+             if flight.EVENT_NAMES[code] in ("sink_read", "sink_checksum")}
+    assert notes == ({"4", "2"} if passes == "split" else {""})
+    assert tf.stampers and all(
+        name.startswith("df-device-sink") for name in tf.stampers)
+
+
 def make_store(tmp_path, task_id: str, piece_size: int, seed: int = 5):
     """A completed store of PIECES pieces, the last one short."""
     from dragonfly2_tpu.storage.local_store import (
@@ -145,12 +182,12 @@ def check_sums(spans: list) -> None:
 
 
 def test_cold_landing_stamps_every_span_children_inside_parents(
-        run_async, tmp_path, fresh_compiles):
+        run_async, tmp_path, fresh_compiles, passes):
     from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
 
     async def body():
         store, content = make_store(tmp_path, "t-cold", 64 * 1024 + 64)
-        tf = flight.TaskFlight("t-cold")
+        tf = WatchedFlight("t-cold")
         mgr = DeviceSinkManager(batch_pieces=BATCH)
         try:
             sink = await land_cold(mgr, store, tf, ORDER)
@@ -162,6 +199,7 @@ def test_cold_landing_stamps_every_span_children_inside_parents(
         return tf
 
     tf = run_async(body(), timeout=120)
+    check_passes(tf, passes)
     spans = spans_of(tf)
     counts = {name: len(rows) for name, rows in spans.items()}
     # Ten pieces, each read, staged and checksummed once on the thread;
@@ -199,14 +237,14 @@ def test_cold_landing_stamps_every_span_children_inside_parents(
 
 
 def test_reland_backfill_stamps_the_same_steps_and_one_finalize(
-        run_async, tmp_path):
+        run_async, tmp_path, passes):
     """A re-land: nothing streamed in, ``_finalize_inner`` backfills every
     piece from the store through the same landing code."""
     from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
 
     async def body():
         store, content = make_store(tmp_path, "t-reland", 64 * 1024)
-        tf = flight.TaskFlight("t-reland")
+        tf = WatchedFlight("t-reland")
         tf.finish("done")       # as the cold pull left it
         mgr = DeviceSinkManager(batch_pieces=BATCH)
         try:
@@ -218,6 +256,7 @@ def test_reland_backfill_stamps_the_same_steps_and_one_finalize(
         return tf
 
     tf = run_async(body(), timeout=120)
+    check_passes(tf, passes)
     spans = spans_of(tf)
     for name in ("sink_land", "sink_read", "sink_checksum"):
         assert [p for _, _, p in spans[name]] == list(range(PIECES)), name
