@@ -4,7 +4,7 @@
 
 PYTHON ?= python
 
-.PHONY: native test tier1 bench-ingest bench-delta clean-native
+.PHONY: native test tier1 clean-native
 
 # Build (or rebuild) the native library. Degrades, never errors: on a box
 # without a C++ toolchain build.py prints a one-line skip reason and
@@ -15,12 +15,6 @@ native:
 # The tier-1 suite (what CI gates on).
 test tier1:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/ -q -m 'not slow'
-
-bench-ingest:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/ingest_micro.py
-
-bench-delta:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/delta_bench.py
 
 clean-native:
 	$(PYTHON) -c "from dragonfly2_tpu.native import build; build.clean()"

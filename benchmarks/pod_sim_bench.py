@@ -19,11 +19,11 @@ contributes:
                          demonstrably drain a pod-scale run
 
 Usage: python benchmarks/pod_sim_bench.py [--hosts 1024] [--churn]
-       [--churn-waves 3] [--publish]
+       [--churn-waves 3]
 Reference yardstick: the evaluator's IDC/location affinity
 (evaluator_base.go:41-45) becomes slice/pod ICI affinity here; the churn
-test (tests/test_scheduler_churn.py) covers correctness, this measures
-scale behavior and publishes numbers. ``--churn-waves N`` kills N
+test (tests/test_scheduler_churn.py) covers correctness, this drives
+scale behavior and prints counts. ``--churn-waves N`` kills N
 different slices at staggered times (sustained churn), each followed by
 its own straggler wave into the killed slice.
 """
@@ -135,16 +135,12 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
     # well above any single peer's in-run idle gap.
     cfg.gc.peer_ttl = cfg.gc.task_ttl = cfg.gc.host_ttl = max(
         gc_ttl_s, arrival_window_s + 60 * piece_latency_s)
-    # ``fleet=False`` is the paired control for fleet_bench's observatory
-    # overhead measurement (config9_fleet); ``podlens`` likewise toggles
-    # the SCHEDULER-side pod-lens/SLO machinery for podlens_bench
-    # (config10_podlens). ``ship_digests`` makes every peer record a real
-    # flight ring, digest it and attach it to download_finished (plus a
-    # clock sample) — the paired bench ships digests on BOTH sides so the
-    # pair isolates the scheduler's ingest+SLO cost (the component that
-    # must scale with host count; the daemon-side build cost is a
-    # per-task constant podlens_bench measures separately). Defaults to
-    # ``podlens`` so a lone podlens=True run exercises the whole path.
+    # ``fleet=False`` runs without the fleet observatory's per-event hooks;
+    # ``podlens`` toggles the SCHEDULER-side pod-lens/SLO machinery.
+    # ``ship_digests`` makes every peer record a real flight ring, digest
+    # it and attach it to download_finished (plus a clock sample).
+    # Defaults to ``podlens`` so a lone podlens=True run exercises the
+    # whole path.
     cfg.fleet.enabled = fleet
     cfg.podlens.enabled = cfg.podlens.slo_enabled = podlens
     if ship_digests is None:
@@ -360,8 +356,7 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
             if ship_digests:
                 # The daemon-side half of the pod lens, for real: a
                 # bounded flight ring stamped per piece, digested and
-                # shipped on the terminal message (its build cost is part
-                # of the measured pair).
+                # shipped on the terminal message.
                 tf = flight_mod.TaskFlight(body["task_id"])
                 tf.record(flight_mod.EV_REGISTER)
                 tf.record(flight_mod.EV_SCHEDULED, -1, 0.0, "normal_task")
@@ -426,12 +421,12 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
                               "digest": "", "download_cost_ms": 2,
                               "dst_peer_id": ""}
                 if report_batch <= 1:
-                    # Classic config5 wire: one report per piece.
+                    # Classic wire: one report per piece.
                     await put(stream, {"type": "piece_finished",
                                        "piece": wire_piece})
                     continue
-                # Coalesced wire (what real daemons send — conductor
-                # flushes report batches; fleet_bench measures this path).
+                # Coalesced wire (what real daemons send: the conductor
+                # flushes report batches).
                 pending.append(wire_piece)
                 if len(pending) >= report_batch:
                     await put(stream, batch_wire(pending))
@@ -469,9 +464,9 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
 
     gc.collect()
     gc.freeze()
-    # ``prof=True`` is prof_bench's paired treatment arm: the full runtime
-    # observatory (sampler thread + loop-lag probe + GC callbacks) armed
-    # for the storm, so its CPU cost lands inside the cpu_s window below.
+    # ``prof=True`` arms the full runtime observatory (sampler thread +
+    # loop-lag probe + GC callbacks) for the storm, so its CPU cost lands
+    # inside the cpu_s window below.
     prof_obs = prof_probe = None
     prof_stats = None
     if prof:
@@ -567,8 +562,7 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
     wall = time.perf_counter() - t0
     # Scheduler CPU for the storm itself — read BEFORE the TTL sweep and
     # the fleet-stats export below (resident_bytes is a deliberate deep
-    # walk; booking it into cpu_s would poison fleet_bench's paired
-    # per-event overhead comparison).
+    # walk, not part of the storm).
     cpu_s = time.process_time() - cpu0
     rss_peak = _resource.getrusage(_resource.RUSAGE_SELF).ru_maxrss / 1024
 
@@ -854,7 +848,6 @@ def main() -> int:
     ap.add_argument("--packed-wire", action="store_true",
                     help="send coalesced reports in the packed columnar "
                          "form (proto/reportcodec) + resume bitmaps")
-    ap.add_argument("--publish", action="store_true")
     args = ap.parse_args()
 
     def _arrival_window(n_hosts: int) -> float:
@@ -902,29 +895,13 @@ def main() -> int:
         # Restart runs assert BEHAVIOR only: the in-process crash window
         # (synchronous snapshot restore + the whole fleet re-registering
         # at once) IS a loop stall by design — max_loop_lag measures the
-        # deliberate outage, not a scheduler pathology. The numbers still
-        # publish for tracking.
+        # deliberate outage, not a scheduler pathology.
         (check_churn_behavior if args.churn else check_behavior)(result)
         check_restart_behavior(result)
     else:
         (check_churn if args.churn else check)(result)
     if pair is not None:
         check_scale_pair(result, pair)
-
-    if args.publish:
-        path = os.path.join(REPO, "BASELINE.json")
-        doc = json.load(open(path))
-        key = "config5_pod_sim_churn" if args.churn else "config5_pod_sim"
-        if args.hosts >= 16384:
-            key += "_16k"
-        elif args.hosts >= 4096:
-            key += "_4k"
-        elif args.hosts >= 1024:
-            key += "_1024"
-        doc.setdefault("published", {})[key] = result
-        with open(path, "w") as f:
-            json.dump(doc, f, indent=2)
-            f.write("\n")
     return 0
 
 

@@ -1,9 +1,9 @@
 """Striped slice broadcast sim: paired striped/unstriped fan-out numbers.
 
 The north-star claim — stripe the DCN pull 1/S per host, let ICI finish
-the copy — needs link-level accounting to measure, and the real-process
-bench (fanout_bench --stripe) runs everything over one loopback NIC where
-DCN and ICI are indistinguishable. This bench drives the REAL data-plane
+the copy — needs link-level accounting to measure, and real processes on
+one machine run everything over one loopback NIC where DCN and ICI are
+indistinguishable. This bench drives the REAL data-plane
 components (daemon/peer/piece_dispatcher.PieceDispatcher in stripe mode,
 scheduler/scheduling/stripe.plan_stripe) through a deterministic
 discrete-event simulation with modeled links:
@@ -20,8 +20,7 @@ plan differs. Reported per mode: per-host DCN bytes, aggregate GB/s
 reproducible results.
 
 Usage: python benchmarks/stripe_sim_bench.py [--slices 2]
-       [--hosts-per-slice 4] [--pieces 64] [--piece-mb 8] [--publish]
-Publishes BASELINE.json["published"]["config6_stripe_sim"].
+       [--hosts-per-slice 4] [--pieces 64] [--piece-mb 8]
 """
 
 from __future__ import annotations
@@ -232,7 +231,6 @@ def main() -> int:
     ap.add_argument("--hosts-per-slice", type=int, default=4)
     ap.add_argument("--pieces", type=int, default=64)
     ap.add_argument("--piece-mb", type=int, default=8)
-    ap.add_argument("--publish", action="store_true")
     args = ap.parse_args()
 
     result = run_paired(n_slices=args.slices,
@@ -241,14 +239,6 @@ def main() -> int:
                         piece_size=args.piece_mb << 20)
     check(result)
     print(json.dumps(result))
-
-    if args.publish:
-        path = os.path.join(REPO, "BASELINE.json")
-        doc = json.load(open(path))
-        doc.setdefault("published", {})["config6_stripe_sim"] = result
-        with open(path, "w") as f:
-            json.dump(doc, f, indent=2)
-            f.write("\n")
     return 0
 
 

@@ -327,8 +327,7 @@ class Daemon:
             recorder.wall_offset = self.config.clock_offset_s
         if self.config.prof.enabled:
             # Runtime observatory: always-on sampler + loop-lag probe +
-            # GC observatory (pkg/prof; paired cost published as
-            # config12_prof). Slow ticks/pauses stamp typed events into
+            # GC observatory (pkg/prof). Slow ticks/pauses stamp typed events into
             # every running flight; the probe feeds a daemon-side
             # loop_lag SLO engine at /debug/slo.
             from dataclasses import replace as _dc_replace

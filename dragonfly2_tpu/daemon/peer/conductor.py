@@ -67,15 +67,14 @@ ANNOUNCE_RECONNECT_COUNT = metrics.counter(
     "Mid-download announce-stream recovery attempts", ("result",))
 # The striped-broadcast yardstick: P2P piece bytes split by parent
 # locality — intra rides the ICI fabric, cross is real DCN traffic,
-# unlabeled means either end lacked TPU coordinates. fanout_bench --stripe
-# scrapes this per daemon for the per-host-DCN-bytes acceptance bound.
+# unlabeled means either end lacked TPU coordinates.
 PIECE_BYTES = metrics.counter(
     "peer_piece_bytes_total",
     "P2P piece bytes downloaded, by parent ICI locality",
     ("locality",))
 # Announce-wire weight: serialized msgpack bytes this daemon exchanged
 # with the scheduler over announce streams. The packed-report encoding
-# exists to shrink ``sent`` — ingest_wire_bench publishes the ratio.
+# exists to shrink ``sent``.
 ANNOUNCE_BYTES = metrics.counter(
     "peer_announce_bytes_total",
     "Serialized announce-stream traffic with the scheduler, by direction "

@@ -266,10 +266,9 @@ class UploadManager:
                 flightlib.EV_UPLOAD_SERVE,
                 int(piece_num) if piece_num is not None else -1,
                 float(length))
-            # sendfile the byte range straight from the page cache — the
-            # hot single-core cost in profiles was pread into Python bytes
-            # plus the user→kernel copy in sendmsg (benchmarks/fanout_bench
-            # --profile showed the serving side dominated by exactly that).
+            # sendfile the byte range straight from the page cache: no
+            # pread into Python bytes and no user→kernel copy in sendmsg
+            # on the serving side.
             # Pin + slot transfer to the response (released after the send).
             # content_total keeps Content-Range honest while the store is
             # still mid-download (in-progress pieces serve the same way).

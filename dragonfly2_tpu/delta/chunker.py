@@ -26,7 +26,7 @@ selected the way pkg/digest picks crc32c implementations:
   python  — per-byte rolling hash (correctness fallback)
 
 ``chunker_backend()`` reports the selection; DF_CHUNKER_BACKEND forces
-one ladder rung (benchmarks pin numpy to measure the native speedup).
+one ladder rung (tests pin one to compare cut points across rungs).
 min/max/forced-cut selection (``_emit``) is shared by all backends, so a
 backend can only ever change speed, never cut points.
 """

@@ -1,6 +1,6 @@
-"""TPU compute ops: HBM piece sink + on-device checksums (JAX/Pallas)."""
+"""TPU compute ops: HBM piece sink + on-device checksums (JAX)."""
 
-from dragonfly2_tpu.ops.checksum import chunk_checksums, checksum_numpy
+from dragonfly2_tpu.ops.checksum import checksum_numpy
 from dragonfly2_tpu.ops.hbm_sink import HBMSink
 
-__all__ = ["HBMSink", "chunk_checksums", "checksum_numpy"]
+__all__ = ["HBMSink", "checksum_numpy"]

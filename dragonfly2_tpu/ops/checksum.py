@@ -9,8 +9,8 @@ these exact bytes land in HBM?" at HBM bandwidth.
 Definition over a piece p of 4-byte words w_i (uint8 little-endian padded):
   sum32  = Σ w_i  mod 2^32
   xor32  = ⊕ w_i
-Both are order-independent per word lane, so host (numpy) and device (XLA /
-Pallas) agree bit-for-bit.
+Both are order-independent per word lane, so host (numpy) and device (XLA)
+agree bit-for-bit.
 """
 
 from __future__ import annotations
@@ -58,190 +58,3 @@ def _chunk_checksums_xla(words, piece_words: int):
     xors = jax.lax.reduce(w, jnp.int32(0), jax.lax.bitwise_xor, axes)
     return (jax.lax.bitcast_convert_type(sums, jnp.uint32),
             jax.lax.bitcast_convert_type(xors, jnp.uint32))
-
-
-def _pallas_available() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("piece_words",))
-def _chunk_checksums_pallas(words, piece_words: int):
-    """Pallas kernel: one grid step per piece; the piece's words stream
-    HBM→VMEM once and reduce on the VPU. int32 ops (TPU has no uint32
-    vector unit type); bit patterns match uint32 exactly."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_pieces = words.shape[0] // piece_words
-    LANES = 128
-    PB = 8                      # pieces per block: (8, 128) output tiles
-    rows = piece_words // LANES
-    RC = min(rows, 512)         # row chunk: 8×512×128×4B = 2 MiB in VMEM
-    assert rows % RC == 0
-
-    def _xor_fold(x, axis_len):
-        # Halving tree over axis 1 (log2 VPU ops; lax.reduce with xor has
-        # no Pallas lowering).
-        r = axis_len
-        while r > 1:
-            half = r // 2
-            folded = x[:, :half, :] ^ x[:, half : 2 * half, :]
-            if r % 2:
-                folded = folded.at[:, 0, :].set(folded[:, 0, :] ^ x[:, r - 1, :])
-            x = folded
-            r = half
-        return x[:, 0, :]
-
-    def kernel(w_ref, sum_ref, xor_ref):
-        j = pl.program_id(1)
-        w = w_ref[...]  # (PB, RC, LANES) int32
-        part_x = _xor_fold(w, RC)
-        # int32 accumulation wraps mod 2^32 — same bit pattern as the
-        # uint32 checksum definition.
-        part_s = jnp.sum(w, axis=1, dtype=jnp.int32)
-
-        @pl.when(j == 0)
-        def _init():
-            sum_ref[...] = part_s
-            xor_ref[...] = part_x
-
-        @pl.when(j != 0)
-        def _accum():
-            sum_ref[...] = sum_ref[...] + part_s
-            xor_ref[...] = xor_ref[...] ^ part_x
-
-    sums, xors = pl.pallas_call(
-        kernel,
-        grid=(n_pieces // PB, rows // RC),
-        in_specs=[pl.BlockSpec((PB, RC, LANES), lambda i, j: (i, j, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=[
-            pl.BlockSpec((PB, LANES), lambda i, j: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((PB, LANES), lambda i, j: (i, 0), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pieces, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((n_pieces, LANES), jnp.int32),
-        ],
-    )(jax.lax.bitcast_convert_type(words, jnp.int32).reshape(n_pieces, rows, LANES))
-    sums = jnp.sum(sums, axis=1, dtype=jnp.int32)
-    xors = jax.lax.reduce(xors, jnp.int32(0), jax.lax.bitwise_xor, (1,))
-    return (jax.lax.bitcast_convert_type(sums, jnp.uint32),
-            jax.lax.bitcast_convert_type(xors, jnp.uint32))
-
-
-@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("piece_words",))
-def _land_checksum_pallas(buffer, pieces, slots, piece_words: int):
-    """Single-pass land+verify kernel: each grid step streams one piece
-    block HBM→VMEM, writes it into the task buffer at its slot (the buffer
-    is input/output-aliased, so untouched slots keep their bytes — no
-    read-modify-write pass) and folds the piece's (sum32, xor32) on the VPU
-    while the data is resident. One read + one write of the batch, total.
-
-    buffer: uint32[(n_slots*piece_words,)] (donated)
-    pieces: uint32[(k, piece_words)]   slots: int32[(k,)] (scalar-prefetched)
-    Returns (buffer, sums[k], xors[k]).
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    k = pieces.shape[0]
-    LANES = 128
-    rows = piece_words // LANES
-    RC = min(rows, 512)
-    assert rows % RC == 0
-    n_slots = buffer.shape[0] // piece_words
-
-    def _xor_fold(x, axis_len):
-        r = axis_len
-        while r > 1:
-            half = r // 2
-            folded = x[:, :half, :] ^ x[:, half: 2 * half, :]
-            if r % 2:
-                folded = folded.at[:, 0, :].set(folded[:, 0, :] ^ x[:, r - 1, :])
-            x = folded
-            r = half
-        return x[:, 0, :]
-
-    def kernel(slots_ref, piece_ref, _buf_ref, out_ref, sum_ref, xor_ref):
-        j = pl.program_id(1)
-        w = piece_ref[...]              # (1, RC, LANES) int32
-        out_ref[...] = w
-        # Accumulators are (1, 8, LANES) blocks (TPU tiling needs 8
-        # sublanes); the live value sits in sublane row 0 (concatenate, not
-        # .at[].set — scatter has no Pallas TPU lowering).
-        zeros7 = jnp.zeros((1, 7, LANES), jnp.int32)
-        part_s = jnp.concatenate(
-            [jnp.sum(w, axis=1, dtype=jnp.int32)[:, None, :], zeros7], axis=1)
-        part_x = jnp.concatenate(
-            [_xor_fold(w, RC)[:, None, :], zeros7], axis=1)
-
-        @pl.when(j == 0)
-        def _init():
-            sum_ref[...] = part_s
-            xor_ref[...] = part_x
-
-        @pl.when(j != 0)
-        def _accum():
-            sum_ref[...] = sum_ref[...] + part_s
-            xor_ref[...] = xor_ref[...] ^ part_x
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(k, rows // RC),
-        in_specs=[
-            pl.BlockSpec((1, RC, LANES), lambda i, j, s: (i, j, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # aliased buffer (unread)
-        ],
-        out_specs=[
-            pl.BlockSpec((1, RC, LANES), lambda i, j, s: (s[i], j, 0)),
-            pl.BlockSpec((1, 8, LANES), lambda i, j, s: (i, 0, 0)),
-            pl.BlockSpec((1, 8, LANES), lambda i, j, s: (i, 0, 0)),
-        ],
-    )
-    out_buf, sums, xors = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_slots, rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((k, 8, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((k, 8, LANES), jnp.int32),
-        ],
-        input_output_aliases={2: 0},   # buffer (after slots, pieces) → out
-    )(slots,
-      jax.lax.bitcast_convert_type(pieces, jnp.int32).reshape(k, rows, LANES),
-      jax.lax.bitcast_convert_type(buffer, jnp.int32).reshape(n_slots, rows, LANES))
-    sums = jnp.sum(sums[:, 0, :], axis=1, dtype=jnp.int32)
-    xors = jax.lax.reduce(xors[:, 0, :], jnp.int32(0), jax.lax.bitwise_xor, (1,))
-    return (jax.lax.bitcast_convert_type(out_buf.reshape(-1), jnp.uint32),
-            jax.lax.bitcast_convert_type(sums, jnp.uint32),
-            jax.lax.bitcast_convert_type(xors, jnp.uint32))
-
-
-def chunk_checksums(words, piece_words: int, *, use_pallas: bool | None = None):
-    """(sum32[n], xor32[n]) per piece on the current backend.
-
-    ``words``: uint32 device array, length = n_pieces * piece_words.
-    ``piece_words`` must be a multiple of 128 for the Pallas path; falls
-    back to the XLA reduction otherwise (identical results).
-    """
-    n_pieces = words.shape[0] // piece_words
-    explicit = use_pallas is not None
-    if use_pallas is None:
-        # Default to XLA: with int32 arithmetic it reduces at memory
-        # bandwidth, while the Pallas grid pipeline caps at ~20-90 GB/s on
-        # v5e (measured round 3). The kernel stays available explicitly.
-        use_pallas = False
-    if use_pallas and not (_pallas_available() and piece_words % 128 == 0
-                           and n_pieces % 8 == 0):
-        # use_pallas is only ever truthy when passed explicitly.
-        raise ValueError(
-            "pallas checksum kernel needs a TPU backend, piece_words "
-            "% 128 == 0 and n_pieces % 8 == 0")
-    if use_pallas:
-        try:
-            return _chunk_checksums_pallas(words, piece_words)
-        except Exception:
-            if explicit:
-                raise  # the caller demanded the kernel; surface its failure
-    return _chunk_checksums_xla(words, piece_words)

@@ -6,12 +6,11 @@ them into device-resident batches, verifies on-device checksums against
 host-side values, and exposes the result as a JAX array (bitcast to the
 checkpoint dtype) or a mesh-sharded array for the slice.
 
-Architecture: **land-by-append + one-shot assembly**. Earlier designs
-scattered each piece batch into one flat preallocated buffer (Pallas
-scatter kernel or XLA dynamic-update-slice); a donated
-dynamic-update-slice copies the whole buffer per flush, O(buffer) per
-flush and quadratic over a download. This design does zero buffer
-mutation during arrival:
+Architecture: **land-by-append + one-shot assembly**. Scattering each
+piece batch into one flat preallocated buffer would copy the whole buffer
+per flush (a donated dynamic-update-slice does): O(buffer) per flush and
+quadratic over a download. This design does zero buffer mutation during
+arrival:
 
   * ``land_piece`` stages a piece as one row of a host stack of
     ``batch_pieces`` rows; ``flush`` moves the stack to the sink's device
@@ -19,8 +18,7 @@ mutation during arrival:
     again until consumption.
   * consumption assembles all batches into the flat uint32 content ONCE
     with a fused slice+concatenate jit that also folds the per-piece
-    (sum32, xor32) checksums from the same staged copy (identical
-    verification semantics to a verify-on-land kernel).
+    (sum32, xor32) checksums from the same staged copy.
 
 Host staging: the sink owns its stacks, and they are reused. A stack comes
 from a process-wide free list (``pkg/bufpool``, pool ``hbm_stage``), so
@@ -266,63 +264,6 @@ def watch_compiles() -> None:
 def compiled() -> "tuple[int, float]":
     """(count, seconds) of the calling thread's backend compiles so far."""
     return getattr(_compiled, "count", 0), getattr(_compiled, "seconds", 0.0)
-
-
-# ---------------------------------------------------------------------- #
-# Fused scatter+checksum op (kept for single-dispatch batch landing into
-# an existing flat buffer — kernel comparisons and callers that need
-# in-place semantics; the production sink and __graft_entry__ use the
-# assemble+checksum path below; see ops/checksum.py kernels).
-# ---------------------------------------------------------------------- #
-
-@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("piece_words",))
-def _land_and_checksum_xla(buffer, pieces, offsets, piece_words: int):
-    def body(i, buf):
-        return jax.lax.dynamic_update_slice(buf, pieces[i], (offsets[i],))
-
-    buffer = jax.lax.fori_loop(0, pieces.shape[0], body, buffer)
-    sums, xors = _chunk_checksums_xla(pieces.reshape(-1), piece_words)
-    return buffer, sums, xors
-
-
-_PALLAS_LAND_OK: dict[int, bool] = {}
-
-
-def _pallas_land_usable(piece_words: int) -> bool:
-    if (jax.default_backend() != "tpu" or piece_words % 128 != 0
-            or (piece_words // 128) % min(piece_words // 128, 512) != 0):
-        return False
-    ok = _PALLAS_LAND_OK.get(piece_words)
-    if ok is None:
-        from dragonfly2_tpu.ops.checksum import _land_checksum_pallas
-
-        try:
-            probe_buf = jnp.zeros((piece_words,), jnp.uint32)
-            probe_piece = jnp.zeros((1, piece_words), jnp.uint32)
-            jax.block_until_ready(_land_checksum_pallas(
-                probe_buf, probe_piece, jnp.zeros((1,), jnp.int32), piece_words))
-            ok = True
-        except Exception as e:
-            log.warning("pallas land+checksum kernel unavailable; "
-                        "using XLA fallback", piece_words=piece_words,
-                        error=str(e)[:200])
-            ok = False
-        _PALLAS_LAND_OK[piece_words] = ok
-    return ok
-
-
-def land_and_checksum(buffer, pieces, offsets, piece_words: int):
-    """Scatter a batch into a flat task buffer and return the landed
-    pieces' (sum32, xor32) — one device dispatch, in-place on TPU via the
-    Pallas kernel (aliased buffer), XLA fallback elsewhere. NOTE: for
-    high-throughput landing prefer the HBMSink append+assemble path; this
-    op exists for in-place single-dispatch semantics."""
-    if _pallas_land_usable(piece_words):
-        from dragonfly2_tpu.ops.checksum import _land_checksum_pallas
-
-        return _land_checksum_pallas(buffer, pieces,
-                                     offsets // piece_words, piece_words)
-    return _land_and_checksum_xla(buffer, pieces, offsets, piece_words)
 
 
 # ---------------------------------------------------------------------- #

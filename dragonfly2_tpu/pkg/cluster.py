@@ -27,8 +27,7 @@ A missing or malformed frame must never stall keepalives: every ingest
 path is fail-open (the ``ingest_tenant_burn`` discipline), and a
 scheduler on an older wire that ships no frames keeps full liveness
 semantics — the cluster view marks it ``no_data`` rather than inventing
-zeros. benchmarks/cluster_bench.py publishes the paired frame-build +
-ingest overhead as BASELINE ``config15_cluster`` (<= 3% budget).
+zeros.
 """
 
 from __future__ import annotations

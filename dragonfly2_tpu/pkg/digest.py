@@ -9,12 +9,11 @@ the TPU-sidecar path — with backend selection in strict preference order
   1. ``native``  — the C++ engine's SIMD kernel (dragonfly2_tpu/native,
      hardware CRC32C instructions); accepts any buffer zero-copy and
      releases the GIL for the call.
-  2. ``google-crc32c`` — the C extension's SIMD kernel; ~2x the native
-     kernel on ``bytes`` but its converter only takes read-only bytes, so
-     writable pooled views pay one bounded slice-copy.
-  3. ``python`` — table-driven pure Python (correctness backstop only:
-     ~3 orders of magnitude slower; the hash-fallback round in
-     benchmarks/ingest_micro.py keeps the gap honest).
+  2. ``google-crc32c`` — the C extension's SIMD kernel; its converter
+     only takes read-only bytes, so writable pooled views pay one bounded
+     slice-copy.
+  3. ``python`` — table-driven pure Python, one table lookup a byte
+     (correctness backstop only).
 
 Large buffers hash in bounded slices (``_CRC_SLICE``) so no single C call
 holds memory/GIL attention for tens of MB, and the per-slice copies of

@@ -36,9 +36,6 @@ Hot-path contract: the per-piece feed (``note_pieces``) does one clock
 read, a handful of list index increments and per-host EWMA float math —
 no per-event dicts, no scans. Scans (gauge sampling, straggler
 recompute) run at bucket/TTL cadence or serve time only.
-benchmarks/fleet_bench.py publishes the paired on/off overhead
-(``config9_fleet``: per-event overhead <= 3%, resident bytes flat in
-host count).
 """
 
 from __future__ import annotations
@@ -127,7 +124,7 @@ def _decision_child(kind: str):
 
 def _deep_bytes(obj, _seen=None) -> int:
     """Recursive getsizeof over the containers the observatory owns —
-    the resident-bytes bound fleet_bench publishes. Cycles guarded."""
+    what ``resident_bytes()`` reports. Cycles guarded."""
     if _seen is None:
         _seen = set()
     oid = id(obj)
@@ -695,8 +692,7 @@ class FleetObservatory:
                    timings: "dict | None" = None) -> None:
         """Single piece-report feed — the scheduler's per-event hot path
         (``piece_finished``). Deliberately INLINED (no sub-calls beyond
-        one clock read and the rare rotate/evict): fleet_bench pins the
-        paired overhead of exactly this path."""
+        one clock read and the rare rotate/evict)."""
         s = self.series
         now = s._clock()
         b = int(now / s.bucket_s)

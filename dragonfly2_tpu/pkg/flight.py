@@ -576,7 +576,7 @@ def render_waterfall(report: dict) -> str:
 
 # Hard byte budget for one shipped digest (serialized JSON). The daemon
 # attaches one per task to its terminal announce message, so the bound is
-# per TASK, not per piece — podlens_bench publishes the measured maximum.
+# per TASK, not per piece (tests/test_podlens.py holds a digest to it).
 DIGEST_MAX_BYTES = 16384
 
 # Compact piece row order inside a digest (arrays, not dicts — at 64

@@ -139,10 +139,8 @@ class PodLens:
     ``/debug/flight`` and come back whole via an on-demand
     ``Daemon.FlightReport`` pull). The reduction is stored as one
     msgpack bytes object per host: a live dict per digest would hand
-    every cyclic-GC pass the whole store to rescan, and podlens_bench
-    caught exactly that as a systematic scheduler CPU tax. Ingest cost
-    is ~10 us/task (config10_podlens pins it); reads (timelines, rare)
-    decode on demand."""
+    every cyclic-GC pass the whole store to rescan. Reads (timelines,
+    rare) decode on demand."""
 
     # Digest keys the merge consumes — everything else is dropped at
     # ingest (the reduction that keeps the store and the GC honest).

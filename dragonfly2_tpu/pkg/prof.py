@@ -6,9 +6,7 @@ RUNTIME itself — yet the scheduler's one real CPU regression so far
 (cyclic GC rescanning live digest dicts) was only caught by accident in
 a bench. This module is the missing process-level layer, the Python
 analog of the reference's per-binary pprof endpoints
-(cmd/dependency/dependency.go --pprof-port): always on, bounded, and
-cheap enough to leave armed in production (prof_bench publishes the
-paired cost as ``config12_prof``; budget <= 3%).
+(cmd/dependency/dependency.go --pprof-port): always on and bounded.
 
 Three instruments, one ``RuntimeObservatory``:
 
@@ -117,8 +115,8 @@ CTX_SWITCHES = metrics.gauge(
 @dataclass
 class ProfConfig:
     """Runtime-observatory knobs, shared by daemon and scheduler config
-    (``prof:`` block). Always on by default — the bench-published budget
-    is what makes that safe; ``enabled=False`` removes every hook."""
+    (``prof:`` block). Always on by default and bounded by the caps
+    below; ``enabled=False`` removes every hook."""
 
     enabled: bool = True
     hz: float = 19.0              # sampler passes per second

@@ -36,8 +36,7 @@ transition into breach, not one per scrape).
 
 Hot-path contract: ``note_completion`` is one ring append plus a
 rate-limited (default 1 s) evaluation; reads evaluate at most once per
-call. podlens_bench publishes the paired on/off cost together with the
-digest shipping (``config10_podlens``).
+call.
 """
 
 from __future__ import annotations
@@ -162,7 +161,7 @@ class SLOEngine:
 
     # Continuous means "every few seconds", not "every completion": the
     # windows are 5 m / 1 h, so a 5 s tick loses nothing while keeping
-    # the engine invisible on the ingest path (podlens_bench pairs it).
+    # the engine off the ingest path.
     def __init__(self, specs=DEFAULT_SLOS, *, series=None, probes=None,
                  max_completions: int = 4096,
                  min_eval_interval_s: float = 5.0,

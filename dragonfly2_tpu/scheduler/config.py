@@ -71,8 +71,7 @@ class SchedulingConfig:
 class FleetConfig:
     """Fleet observatory bounds (pkg/fleet): the continuous scheduler-side
     cluster view. All structures are preallocated/bounded — these knobs
-    size them; ``enabled=False`` removes the per-event hooks entirely
-    (fleet_bench publishes the paired on/off overhead)."""
+    size them; ``enabled=False`` removes the per-event hooks entirely."""
 
     enabled: bool = True
     bucket_s: float = 5.0          # time-series bucket width
@@ -95,8 +94,7 @@ class PodLensConfig:
     """Pod lens (pkg/podlens) + SLO engine (pkg/slo) bounds: the merged
     cross-host timeline store, the per-host clock estimator, and the
     continuous burn-rate evaluation. All bounded; ``enabled=False``
-    removes the digest-ingest hooks entirely (podlens_bench publishes
-    the paired on/off overhead as ``config10_podlens``)."""
+    removes the digest-ingest hooks entirely."""
 
     enabled: bool = True
     slo_enabled: bool = True

@@ -25,7 +25,7 @@ Backend ladder, selected once per process (DF_RING_BACKEND pins a rung):
   threads  — a small worker pool issues the existing preadv/pwritev
              calls concurrently (boxes without the native library).
   serial   — the plain per-span loop (forced via DF_RING_BACKEND=serial/
-             off; also the benchmarks' ring-off arm).
+             off).
 
 Every backend produces byte-identical results and the same failure
 shapes: EOF inside a requested span raises ShortReadError (the store

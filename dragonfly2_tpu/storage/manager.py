@@ -191,8 +191,8 @@ class StorageManager:
                 continue
             # Idle stores drop their data-file fd (reopened lazily on the
             # next read): without this, a long-lived daemon holds one fd
-            # per task it has EVER served until the TTL delete — the soak
-            # tool (benchmarks/soak.py) measures exactly this drift. The
+            # per task it has EVER served until the TTL delete
+            # (tests/test_storage.py::test_gc_closes_idle_store_fds). The
             # native upload server is unaffected: it opens per request.
             idle_close = self.opt.fd_idle_close or self.opt.gc_interval
             if now - m.last_access > idle_close:

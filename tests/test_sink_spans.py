@@ -10,12 +10,17 @@ writers, and that none of it pulled jax into a daemon that holds no sink.
 
 from __future__ import annotations
 
+import asyncio
 import dataclasses
 import gc
+import importlib.util
+import os
 import random
 import subprocess
 import sys
 import threading
+import time
+import types
 import weakref
 
 import numpy as np
@@ -273,6 +278,93 @@ def test_reland_backfill_stamps_the_same_steps_and_one_finalize(
     assert sum(len(c.children) for c in final.children) + len(
         final.children) + 1 == sum(len(rows) for rows in spans.values())
     check_sums([final])
+
+
+# -- the program <-> benchmark contract ------------------------------------
+# chipbench/layers/<metric>.py finds the program's flight events by name and
+# reads nothing where a name is gone; the ledger then shows a null. These
+# are the readers whose events only the device sink stamps.
+
+SINK_READERS = ("land_read_ms", "land_checksum_ms", "land_stage_ms",
+                "land_put_ms", "finalize_ms", "plan_compile_ms",
+                "land_wait_ms", "land_thread_util_pct")
+
+
+def load_reader(monkeypatch, metric: str):
+    """``read`` of chipbench/layers/<metric>.py, loaded from its source
+    under the names it imports (``layers``, ``reduce_trace``), which leave
+    ``sys.modules`` again with the test: chipbench/ itself never lands on
+    ``sys.path``, where a second ``tests`` package lives."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench")
+
+    def load(name: str, *path: str):
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(bench, *path))
+        module = importlib.util.module_from_spec(spec)
+        monkeypatch.setitem(sys.modules, name, module)
+        spec.loader.exec_module(module)
+        return module
+
+    package = types.ModuleType("layers")
+    monkeypatch.setitem(sys.modules, "layers", package)
+    load("reduce_trace", "reduce_trace.py")
+    package.sink_events = load("layers.sink_events", "layers",
+                               "sink_events.py")
+    return load("layers." + metric, "layers", metric + ".py").read
+
+
+@pytest.fixture(scope="module")
+def window_of_two(tmp_path_factory):
+    """What the harness hands a reader after a window of two operations on
+    one task, a streamed landing and a re-land: ``run.ops``, each with its
+    ``t0``, ``t1`` and the task's flight events between them as the
+    ``(t, name, piece, aux)`` rows of ``harness._read_flight``."""
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+
+    async def body():
+        store, _ = make_store(tmp_path_factory.mktemp("contract"),
+                              "t-contract", 64 * 1024)
+        tf = flight.TaskFlight("t-contract")
+        mgr = DeviceSinkManager(batch_pieces=BATCH)
+        spans = []
+        try:
+            for land in (lambda: land_cold(mgr, store, tf, ORDER),
+                         lambda: mgr.finalize("t-contract", store, tf)):
+                t0 = time.perf_counter()
+                sink = await land()
+                assert sink is not None and sink.verified
+                spans.append((t0, time.perf_counter()))
+                # As the client does: the words are its own now, and the
+                # next landing of the task starts from the store.
+                assert mgr.take("t-contract") is sink
+        finally:
+            mgr.close()
+        return tf, spans
+
+    tf, spans = asyncio.run(asyncio.wait_for(body(), 120))
+    start = time.perf_counter() - (flight.anchored_wall() - tf.start_wall)
+    run = types.SimpleNamespace(ops=[
+        types.SimpleNamespace(
+            t0=t0, t1=t1,
+            flight=[(start + t, flight.EVENT_NAMES.get(code, str(code)),
+                     piece, aux)
+                    for t, code, piece, aux, _ in tf.events()
+                    if t0 <= start + t <= t1])
+        for t0, t1 in spans])
+    for op in run.ops:      # each landing's events fell to its own operation
+        assert {name for _, name, _, _ in op.flight} >= {
+            "sink_wait", "sink_finalize", "sink_read"}
+    return run
+
+
+@pytest.mark.parametrize("metric", SINK_READERS)
+def test_the_benchmarks_reader_finds_its_events(monkeypatch, window_of_two,
+                                                metric):
+    value = load_reader(monkeypatch, metric)(window_of_two)
+    assert isinstance(value, float) and value >= 0.0, (metric, value)
+    if metric != "plan_compile_ms":     # 0.0 where no plan was new
+        assert value > 0.0, metric
 
 
 def test_new_plan_stamps_one_compile_and_a_repeated_plan_none(

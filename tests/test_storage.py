@@ -228,7 +228,7 @@ def test_concurrent_writes_and_reads_threadsafe(tmp_path):
 def test_gc_closes_idle_store_fds(tmp_path):
     """Idle (but un-expired) stores drop their data-file fd at GC time and
     reopen lazily — a long-lived daemon must not hold one fd per task it
-    ever served (benchmarks/soak.py measures the drift)."""
+    ever served."""
     import time as _time
 
     from dragonfly2_tpu.storage.manager import StorageManager, StorageOption

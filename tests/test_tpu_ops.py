@@ -6,7 +6,7 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from dragonfly2_tpu.ops.checksum import checksum_numpy, chunk_checksums  # noqa: E402
+from dragonfly2_tpu.ops.checksum import _chunk_checksums_xla, checksum_numpy  # noqa: E402
 from dragonfly2_tpu.ops.hbm_sink import HBMSink  # noqa: E402
 from dragonfly2_tpu.parallel.ici import (  # noqa: E402
     StripedBroadcast,
@@ -35,12 +35,15 @@ class TestChecksum:
         b = checksum_numpy(b"hello world!" + b"\x00" * 8)
         assert a == b
 
-    def test_device_matches_numpy(self):
+    # The kernel's two reshape branches: whole (sublane, lane) tiles of 128
+    # words, and a piece that is not a multiple of 128.
+    @pytest.mark.parametrize("piece_words", [256, 200])
+    def test_device_matches_numpy(self, piece_words):
         rng = np.random.RandomState(0)
-        piece_words = 256
         n = 4
-        data = rng.randint(0, 2**31, size=(n * piece_words,)).astype(np.uint32)
-        sums, xors = chunk_checksums(jnp.asarray(data), piece_words)
+        data = rng.randint(0, 2**32, size=(n * piece_words,),
+                           dtype=np.uint64).astype(np.uint32)
+        sums, xors = _chunk_checksums_xla(jnp.asarray(data), piece_words)
         for i in range(n):
             piece = data[i * piece_words : (i + 1) * piece_words].tobytes()
             want_s, want_x = checksum_numpy(piece)
@@ -207,33 +210,6 @@ def test_graft_entry_dryrun_multichip():
     import __graft_entry__
 
     __graft_entry__.dryrun_multichip(8)
-
-
-def test_land_and_checksum_verify_on_land():
-    """Fused sink step: scatter + checksums OF THE LANDED BATCH (verify-on-
-    land); partial batches leave other slots untouched."""
-    import numpy as np
-    import jax.numpy as jnp
-
-    from dragonfly2_tpu.ops.checksum import checksum_numpy
-    from dragonfly2_tpu.ops.hbm_sink import land_and_checksum
-
-    pw = 1024
-    n_slots = 8
-    rng = np.random.RandomState(3)
-    pieces_np = rng.randint(0, 2**31, size=(2, pw)).astype(np.uint32)
-    offsets = jnp.asarray(np.array([3 * pw, 6 * pw], np.int32))
-    base = np.arange(n_slots * pw, dtype=np.uint32)
-    buf, sums, xors = land_and_checksum(
-        jnp.asarray(base.copy()), jnp.asarray(pieces_np), offsets, pw)
-    out = np.asarray(buf)
-    assert np.array_equal(out[3 * pw:4 * pw], pieces_np[0])
-    assert np.array_equal(out[6 * pw:7 * pw], pieces_np[1])
-    assert np.array_equal(out[:3 * pw], base[:3 * pw])  # untouched slots
-    for i in range(2):
-        want_s, want_x = checksum_numpy(pieces_np[i].tobytes())
-        assert int(np.asarray(sums)[i]) == want_s
-        assert int(np.asarray(xors)[i]) == want_x
 
 
 def test_hbm_sink_contiguous_runs(tmp_path):
