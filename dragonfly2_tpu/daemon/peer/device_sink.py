@@ -58,11 +58,12 @@ SINK_LANDED_BYTES = metrics.counter(
     "device_sink_landed_bytes_total", "Bytes landed into device sinks")
 SINK_VERIFY_COUNT = metrics.counter(
     "device_sink_verify_total", "Device sink verifications", ("result",))
-# The plan lottery: an assembly plan met for the first time compiles, on
-# the one landing thread, while every other task's pieces wait.
+# An assembly of a geometry met for the first time compiles, on the one
+# landing thread, while every other task's pieces wait: once per object
+# geometry, whatever order the pieces arrived in.
 SINK_COMPILES = metrics.counter(
     "device_sink_compiles_total",
-    "Assemblies that compiled their program (a new segment plan)")
+    "Assemblies that compiled their program (a new geometry)")
 SINK_COMPILE_SECONDS = metrics.counter(
     "device_sink_compile_seconds_total",
     "Backend-compile seconds spent inside device sink assemblies")

@@ -14,11 +14,18 @@ arrival:
 
   * ``land_piece`` stages a piece as one row of a host stack of
     ``batch_pieces`` rows; ``flush`` moves the stack to the sink's device
-    and puts its rows into slot order there. The content is not touched
-    again until consumption.
+    as it lies, and keeps beside it which slot each row belongs to. The
+    content is not touched again until consumption.
   * consumption assembles all batches into the flat uint32 content ONCE
-    with a fused slice+concatenate jit that also folds the per-piece
-    (sum32, xor32) checksums from the same staged copy.
+    with one jit that copies every staged row to its slot and folds the
+    per-piece (sum32, xor32) checksums from the same staged copy. Where
+    each row belongs is an ARGUMENT of that program (an int32 array), so
+    the program is one for a geometry (the batches' shapes, the count of
+    pieces) and no arrival order compiles anything: a complete 55-piece
+    sink is always batches of 8,8,8,8,8,8,7, whatever reached the thread
+    first. (A placement that is part of the program costs a compile of
+    0.8-3.5 s on the landing thread for almost every cold pull: PERF.md
+    section 6.)
 
 Host staging: the sink owns its stacks, and they are reused. A stack comes
 from a process-wide free list (``pkg/bufpool``, pool ``hbm_stage``), so
@@ -27,15 +34,12 @@ of page faults on the chip's host, a fresh 256 MiB stack 260 ms (PERF.md
 section 5), against 35-65 ms for the transfer itself. The daemon reads a
 piece from its store straight into ``next_row()`` and hands that row to
 ``land_piece``, which then checksums it where it lies; any other bytes
-are copied into the row once. Rows lie in arrival order. ``flush`` puts
-the stack and dispatches ``_reorder_jit``, a row gather by a traced
-permutation, so a staged batch is in slot order whatever the arrival
-order was and the assembly plan (a static argument: one compile each)
-depends on which pieces shared a batch, not on their order in it. The
-gather's output is a device buffer of its own, and only when it is ready
-does the stack go back to the free list: ``jax.device_put`` returns
-before the runtime has read the host buffer, and on the CPU backend an
-aligned buffer is aliased, not copied, for the device array's whole life.
+are copied into the row once. Rows lie in arrival order, on the host and
+on the device. ``flush`` puts the stack (``_put``), and only when the
+device array is ready does the stack go back to the free list:
+``jax.device_put`` returns before the runtime has read the host buffer.
+On the CPU backend an aligned buffer is aliased, not copied, for the
+device array's whole life, so there ``_put`` copies the rows first.
 
 Host passes: a piece is passed over twice on the host, by the read into
 its row (the daemon's, ``daemon/peer/device_sink.py``) and by the checksum
@@ -48,15 +52,15 @@ stamp nothing; the piece's checksum is the fold of its chunks' and is
 ``checksum_numpy`` of the row, bit for bit. Smaller pieces are handled
 whole where they are, with no hand-over.
 
-Rates: not measured on this round's chip. Memory, as the v5e compiler
-reports it for the assembly program (``memory_analysis()``,
-tests/test_chip_compile.py): staged batches (argument) + flat content
-(output) + a content-sized temporary for the checksum reshape = **3x
-content** while the program runs, **5x** on the fragmented-arrival gather
-path (4x was read at 2 GiB of 4 MiB pieces). So one chip's share above
-roughly 5 GiB (3 GiB fragmented) cannot land on a 16 GB v5e. Staging
-batches are dropped after a verified complete assembly, which leaves 1x
-resident.
+Memory, as the v5e compiler reports it for the assembly program
+(``memory_analysis()``, tests/test_chip_compile.py): staged batches
+(argument) + flat content (output) and under 2 MiB beside them = **2x
+content** while the program runs (a staged piece is whole (8, 128) tiles,
+``_piece_shape``, so neither the copy nor the checksum nor the flat view
+needs a relayout), and the chip's ``peak_hbm_x`` reads 2.007. So one
+chip's share above roughly 7 GiB cannot land on a 16 GB v5e.
+Staging batches are dropped after a verified complete assembly, which
+leaves 1x resident. Rates: PERF.md section 5.
 
 Consumers read the flat word buffer through ops/bitview.py: a byte or
 16-bit view made with a plain ``bitcast_convert_type`` is padded 32-128x
@@ -91,12 +95,12 @@ from dragonfly2_tpu.pkg.piece import PIECE_SIZE_LIMIT
 log = dflog.get("ops.hbm_sink")
 
 # A landing sink holds at most this many staging stacks: the one it fills
-# and the one the runtime may still be reading. A put takes 35-65 ms and its
-# reorder 9.6; since PR 28 the next batch of 8 x 32 MiB is read and
-# checksummed in about 60 ms (200-300 before), so the wait for the older
-# stack is no longer far off. On the chip it was still not met
-# (land_stage_ms 3.6 a re-land, as before; PERF.md section 5): a host pass
-# that gets faster again meets the link here first.
+# and the one the runtime may still be reading. A put takes 30-65 ms; since
+# PR 28 the next batch of 8 x 32 MiB is read and checksummed in about 60 ms
+# (200-300 before), so the wait for the older stack is no longer far off.
+# On the chip it was still not met (land_stage_ms 3.6 a re-land, as before;
+# PERF.md section 5): a host pass that gets faster again meets the link
+# here first.
 _STACKS_PER_SINK = 2
 # The free list of staging stacks, shared by every sink of the process: a
 # second landing touches no new page. It keeps what a daemon at its
@@ -121,6 +125,14 @@ SINK_PIECES = metrics.counter(
     ("how",))
 _PIECES_SPLIT = SINK_PIECES.labels("split")
 _PIECES_WHOLE = SINK_PIECES.labels("whole")
+SINK_ASSEMBLIES = metrics.counter(
+    "device_sink_assemblies_total",
+    "Assembly dispatches, by whether the landing thread compiled the "
+    "program for them (compiled: a geometry this process had not assembled "
+    "before, and the persistent cache did not hold) or not (cached)",
+    ("how",))
+_ASSEMBLIES_COMPILED = SINK_ASSEMBLIES.labels("compiled")
+_ASSEMBLIES_CACHED = SINK_ASSEMBLIES.labels("cached")
 
 # A host pass over a piece is cut into at most _HELPERS chunks of about
 # _CHUNK_FLOOR bytes or more each; under two floors it is not cut. Fixed
@@ -267,41 +279,62 @@ def compiled() -> "tuple[int, float]":
 
 
 # ---------------------------------------------------------------------- #
-# Assembly: slices of staged batches → the flat content + per-piece
-# checksums, in ONE fused jit dispatch. The checksums reduce the INPUT
-# segments, from the same staged copy the concatenate reads. Rates: not
-# measured on this round's chip; memory: see the module docstring.
+# Assembly: every staged row to its slot of the flat content + per-piece
+# checksums, in ONE dispatch of a program that depends on shapes alone.
+# Rates and memory: see the module docstring.
 # ---------------------------------------------------------------------- #
 
-@functools.partial(jax.jit, static_argnames=("plan", "piece_words"))
-def _assemble_checksum_jit(batches: tuple, plan: tuple, piece_words: int):
-    """Assemble AND checksum in one dispatch. plan: tuple of
-    ("b", batch_idx, row_start, row_stop) — rows of a staged batch, in
-    slot order — or ("z", n_words) zero filler for not-landed slots.
-    Returns (flat, sums, xors) with sums/xors indexed by slot (zero
-    fillers contribute zero checksums — pad-neutral by definition).
-    Verify-on-land semantics: the checksums fold from the same staged
-    device copy the flat buffer is assembled from."""
-    parts = []
-    checks = []
-    for op in plan:
-        if op[0] == "b":
-            _, bi, r0, r1 = op
-            seg = batches[bi][r0:r1].reshape(-1)
-            parts.append(seg)
-            checks.append(_chunk_checksums_xla(seg, piece_words))
-        else:
-            parts.append(jnp.zeros((op[1],), jnp.uint32))
-            z = op[1] // piece_words
-            checks.append((jnp.zeros((z,), jnp.uint32),
-                           jnp.zeros((z,), jnp.uint32)))
-    flat = (jax.lax.concatenate(parts, 0) if len(parts) > 1 else parts[0])
-    if len(checks) > 1:
-        sums = jnp.concatenate([c[0] for c in checks])
-        xors = jnp.concatenate([c[1] for c in checks])
-    else:
-        sums, xors = checks[0]
-    return flat, sums, xors
+# A staged piece is ``piece_words / _LANES`` rows of ``_LANES`` words where
+# that makes whole tiles: the TPU tiles an array's last two dimensions
+# (8 x 128), so a piece is then one linear run of tiles, which a copy reads
+# and writes as such, and the flat view of all pieces is the same memory. As
+# one row of a ``(k, piece_words)`` batch a piece is one sublane of every
+# tile, and a copy of it reads eight times its size: 112 ms for 55 pieces of
+# 32 MiB against 12.3 (PERF.md section 6, PR 30). That shape is left to the
+# pieces that are no whole tiles (records of a few KB), which as rows of
+# tiles would be padded to the next eight.
+_LANES = 128
+_TILE = 8 * _LANES
+
+
+def _piece_shape(piece_words: int) -> tuple:
+    if piece_words % _TILE:
+        return (piece_words,)
+    return (piece_words // _LANES, _LANES)
+
+
+@functools.partial(jax.jit, static_argnames=("total_pieces",))
+def _assemble_checksum_jit(batches: tuple, slots, total_pieces: int):
+    """Assemble AND checksum in one dispatch. ``batches``: the staged
+    batches, ``(k, *piece shape)`` each, rows in whatever order they
+    arrived; ``slots``: int32, for every row of every batch in turn the
+    slot it belongs to, TRACED, so the program is one for a geometry (the
+    batches' shapes and ``total_pieces``) whatever the order was. Returns
+    (flat, sums, xors): row ``i`` at ``slots[i] * piece_words`` of the flat
+    content, zeros in the slots that no row names, and sums/xors indexed
+    by slot (zero there too: pad-neutral by definition). Verify-on-land
+    semantics: a row's checksums fold from the same staged device copy
+    that is placed."""
+    piece_words = batches[0][0].size
+    flat = jnp.zeros((total_pieces, *batches[0].shape[1:]), jnp.uint32)
+    sums = xors = jnp.zeros((total_pieces,), jnp.uint32)
+    first = 0
+    for batch in batches:
+        # One loop a batch, not one copy a row: the program grows with the
+        # operands (which _maybe_consolidate bounds), not with the pieces.
+        def place(i, out, batch=batch, first=first):
+            flat, sums, xors = out
+            slot = slots[first + i]
+            piece = jax.lax.dynamic_slice_in_dim(batch, i, 1, axis=0)
+            s, x = _chunk_checksums_xla(piece.reshape(-1), piece_words)
+            return (jax.lax.dynamic_update_slice_in_dim(flat, piece, slot, 0),
+                    jax.lax.dynamic_update_slice_in_dim(sums, s, slot, 0),
+                    jax.lax.dynamic_update_slice_in_dim(xors, x, slot, 0))
+
+        flat, sums, xors = jax.lax.fori_loop(0, batch.shape[0], place,
+                                             (flat, sums, xors))
+        first += batch.shape[0]
+    return flat.reshape(-1), sums, xors
 
 
 @jax.jit
@@ -311,20 +344,16 @@ def _merge_jit(arrs: tuple):
     return jnp.concatenate(list(arrs), axis=0)
 
 
-@jax.jit
-def _reorder_jit(staged, order):
-    """A staged batch's rows, which lie in arrival order, in slot order:
-    row i of the result is row ``order[i]``. The permutation is traced, so
-    one program serves every arrival order of a batch shape. A loop of
-    row copies and not ``jnp.take``: for 8 rows of 32 MiB the v5e compiler
-    unrolls that gather into 22 MB of program, which stays on the device
-    (tests/test_chip_compile.py; the chip's ``peak_hbm_x`` showed it)."""
-    def place(i, out):
-        row = jax.lax.dynamic_slice_in_dim(staged, order[i], 1, axis=0)
-        return jax.lax.dynamic_update_slice_in_dim(out, row, i, axis=0)
-
-    return jax.lax.fori_loop(0, staged.shape[0], place,
-                             jnp.zeros_like(staged))
+def _put(rows: np.ndarray, device) -> jax.Array:
+    """A stack's filled rows on ``device``, straight from the host buffer
+    (staging via jnp.asarray would first place them on the default
+    device), as a buffer of the device's own. Returns before the runtime
+    has read the rows: ready is when it has. The CPU backend copies
+    nothing from an aligned host buffer, the array IS the stack for its
+    whole life, so there the rows are copied first."""
+    if device.platform == "cpu":
+        rows = rows.copy()
+    return jax.device_put(rows, device)
 
 
 @functools.partial(jax.jit,
@@ -333,23 +362,6 @@ def _record_batch_jit(flat, *, count: int, piece_size: int,
                       record_bytes: int):
     u8 = bitview.typed_view(flat, 0, jnp.uint8, (count, piece_size))
     return u8[:, :record_bytes]
-
-
-@functools.partial(jax.jit, static_argnames=("piece_words",))
-def _gather_checksum_jit(batches: tuple, perm, piece_words: int):
-    """Fragmented-arrival fallback: stack the staged batches, reorder the
-    piece rows by a TRACED permutation (missing slots point at a zero
-    row), and checksum. The graph depends only on batch shapes — no
-    per-plan retrace — at the cost of one extra read+write over the fused
-    segment path; used when the segment plan would unroll too many
-    concatenate operands."""
-    stacked = (jnp.concatenate(list(batches), axis=0) if len(batches) > 1
-               else batches[0])
-    zero = jnp.zeros((1, stacked.shape[1]), stacked.dtype)
-    stacked = jnp.concatenate([stacked, zero], axis=0)
-    flat = jnp.take(stacked, perm, axis=0).reshape(-1)
-    sums, xors = _chunk_checksums_xla(flat, piece_words)
-    return flat, sums, xors
 
 
 class HBMSink:
@@ -392,10 +404,9 @@ class HBMSink:
         self.host_checksums: dict[int, tuple[int, int]] = {}
         self.landed: set[int] = set()
         self.batch_pieces = batch_pieces
-        # Staged device batches: (slot ndarray, (k, piece_words) uint32),
-        # rows in slot order.
+        # Staged device batches: (the rows' slots, (k, *piece shape)
+        # uint32), rows in the order they arrived.
         self._batches: list[tuple[np.ndarray, jax.Array]] = []
-        self._slot_to_batch: dict[int, tuple[int, int]] = {}
         self._assembled: jax.Array | None = None
         # Device checksums by slot, produced by the assembly dispatch.
         self._dev_sums: np.ndarray | None = None
@@ -508,38 +519,31 @@ class HBMSink:
 
     def flush(self) -> None:
         """Move the open stack's filled rows to the device as one batch,
-        in slot order. Pure staging: the single assembly dispatch
-        checksums everything later. The stack itself stays out until the
-        reordered batch is ready (``_retire``)."""
+        as they lie: which slot each belongs to goes with the batch. Pure
+        staging: the single assembly dispatch places and checksums
+        everything later. The stack itself stays out until the runtime
+        has read it (``_retire``)."""
         if not self._rows:
             return
         with span(self.stamp, flight.EV_SINK_STAGE) as step:
-            slots = np.asarray(self._rows, np.int64)
-            order = np.argsort(slots)
-            slots = slots[order]
-            lowest = step.piece = int(slots[0])
-        # Straight from the host buffer to the sink's device: staging via
-        # jnp.asarray would first place the batch on the default device.
+            slots = np.asarray(self._rows, np.int32)
+            lowest = step.piece = int(slots.min())
         with span(self.stamp, flight.EV_SINK_PUT, lowest):
-            batch = _reorder_jit(
-                jax.device_put(self._stack[:len(slots)].view(np.uint32),
-                               self.device),
-                order.astype(np.int32))
+            rows = self._stack[:len(slots)].view(np.uint32).reshape(
+                len(slots), *_piece_shape(self.piece_words))
+            batch = _put(rows, self.device)
         self._in_flight.append((self._view, batch))
         self._view = self._stack = None
         self._rows = []
-        bi = len(self._batches)
         self._batches.append((slots, batch))
-        for i, n in enumerate(slots):
-            self._slot_to_batch[int(n)] = (bi, i)
         self._maybe_consolidate()
         self._assembled = None
         self._dev_sums = self._dev_xors = None
 
     def _maybe_consolidate(self) -> None:
         """Merge the trailing _MERGE_GROUP equal-shaped batches into one
-        superbatch. Only ever merges ORIGINAL full batches (all shapes
-        (batch_pieces, piece_words)), so the concat jit compiles once."""
+        superbatch. Only ever merges ORIGINAL full batches (all of
+        batch_pieces pieces), so the concat jit compiles once."""
         group = self._MERGE_GROUP
         if len(self._batches) < group:
             return
@@ -549,11 +553,6 @@ class HBMSink:
         merged_arr = _merge_jit(tuple(arr for _, arr in tail))
         merged_slots = np.concatenate([s for s, _ in tail])
         self._batches = self._batches[:-group] + [(merged_slots, merged_arr)]
-        # Rebuild the slot map (indices after the merge point shifted).
-        self._slot_to_batch = {
-            int(n): (bi, i)
-            for bi, (slots, _) in enumerate(self._batches)
-            for i, n in enumerate(slots)}
 
     def complete(self) -> bool:
         return len(self.landed) >= self.total_pieces
@@ -581,39 +580,10 @@ class HBMSink:
 
     # -- assembly / consumption --------------------------------------------
 
-    def _plan(self) -> tuple:
-        plan: list[tuple] = []
-        slot = 0
-        while slot < self.total_pieces:
-            loc = self._slot_to_batch.get(slot)
-            if loc is None:
-                run = 1
-                while (slot + run < self.total_pieces
-                       and slot + run not in self._slot_to_batch):
-                    run += 1
-                plan.append(("z", run * self.piece_words))
-                slot += run
-            else:
-                bi, row = loc
-                run = 1
-                while True:
-                    nxt = self._slot_to_batch.get(slot + run)
-                    if nxt != (bi, row + run):
-                        break
-                    run += 1
-                plan.append(("b", bi, row, row + run))
-                slot += run
-        return tuple(plan)
-
-    # Above this many slot-order segments, the fused plan would unroll an
-    # O(segments) concat graph and retrace per arrival order — switch to
-    # the traced-permutation gather (fixed graph, one extra pass).
-    _SEGMENT_CAP = 128
-
     def _assemble(self) -> jax.Array:
         """Materialize the flat uint32 content + per-slot checksums: ONE
-        fused dispatch (read once, write once — the input-side checksum
-        reduction fuses with the concatenate's read)."""
+        dispatch, of a program that the sink's geometry names and the
+        arrival order does not."""
         self.flush()
         if self._assembled is not None:
             return self._assembled
@@ -631,20 +601,21 @@ class HBMSink:
             # the last put's own device buffer is then gone when the flat
             # content is allocated, and the peak stays staged + flat.
             self._retire(keep=0)
-            plan = self._plan()
-            step.piece = len(plan)
+            step.piece = len(batches)
             count, seconds = compiled()
-            if len(plan) <= self._SEGMENT_CAP:
-                flat, sums, xors = _assemble_checksum_jit(
-                    batches, plan, self.piece_words)
-            else:
-                flat, sums, xors = self._assemble_fragmented(batches)
+            flat, sums, xors = _assemble_checksum_jit(
+                batches, np.concatenate([s for s, _ in self._batches]),
+                self.total_pieces)
             count_after, seconds_after = compiled()
-            if count_after > count and self.stamp is not None:
-                # A plan met for the first time (it is a static argument,
-                # and follows the order pieces arrived in).
-                self.stamp(flight.EV_SINK_COMPILE, len(plan),
-                           (seconds_after - seconds) * 1000.0)
+            if count_after > count:
+                # A geometry (the batches' shapes, the count of pieces)
+                # that this process assembles for the first time.
+                _ASSEMBLIES_COMPILED.inc()
+                if self.stamp is not None:
+                    self.stamp(flight.EV_SINK_COMPILE, len(batches),
+                               (seconds_after - seconds) * 1000.0)
+            else:
+                _ASSEMBLIES_CACHED.inc()
             self._assembled = flat
             self._dev_sums = np.asarray(sums)
             self._dev_xors = np.asarray(xors)
@@ -652,25 +623,10 @@ class HBMSink:
         self._bound_jit_cache()
         return self._assembled
 
-    def _assemble_fragmented(self, batches: tuple):
-        """Badly scrambled arrival: slot→row permutation as a traced array
-        (missing slots → the appended zero row)."""
-        row_offset = []
-        off = 0
-        for slots, b in self._batches:
-            row_offset.append(off)
-            off += b.shape[0]
-        zero_row = off
-        perm = np.full((self.total_pieces,), zero_row, np.int32)
-        for slot, (bi, row) in self._slot_to_batch.items():
-            perm[slot] = row_offset[bi] + row
-        return _gather_checksum_jit(
-            batches, jax.device_put(perm, self.device), self.piece_words)
-
     @staticmethod
     def _bound_jit_cache() -> None:
-        """Every task's segment plan is a distinct static argument; a
-        long-lived daemon must not accumulate compiled executables without
+        """Every geometry a daemon lands is a program of its own; a
+        long-lived one must not accumulate compiled executables without
         bound."""
         try:
             if _assemble_checksum_jit._cache_size() > 64:
@@ -684,7 +640,6 @@ class HBMSink:
             # footprint. landed/checksum bookkeeping stays; re-landing a
             # piece is a no-op via `landed`.
             self._batches = []
-            self._slot_to_batch = {}
 
     def as_words(self):
         """The landed content as the flat device uint32 buffer it was

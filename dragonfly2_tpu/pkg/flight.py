@@ -99,8 +99,8 @@ EV_SINK_READ = 30      # store.read_piece (piece=num)
 EV_SINK_CHECKSUM = 31  # host checksum of the piece (piece=num)
 EV_SINK_STAGE = 32     # flush: sort, zeroed stack, row copies (piece=lowest slot)
 EV_SINK_PUT = 33       # flush: the device_put call (piece=lowest slot)
-EV_SINK_ASSEMBLE = 34  # assembly dispatch -> checksums on host (piece=segments)
-EV_SINK_COMPILE = 35   # backend compile inside that assembly (piece=segments)
+EV_SINK_ASSEMBLE = 34  # assembly dispatch -> checksums on host (piece=batches)
+EV_SINK_COMPILE = 35   # backend compile inside that assembly (piece=batches)
 EV_SINK_FINALIZE = 36  # backfill + assemble + verify (piece=pieces backfilled)
 EV_PARENT_PIECES = 37  # a parent announced pieces (piece=lowest, aux=how many)
 # A job's wait for the landing thread, stamped as the job starts there:

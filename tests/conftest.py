@@ -41,3 +41,24 @@ def run_async():
         return asyncio.run(_with_timeout())
 
     return _run
+
+
+@pytest.fixture
+def fresh_compiles():
+    """Neither a persistent compilation cache nor an assembly program in
+    memory, and the thread's compiles counted: whatever geometry a sink
+    meets in the test it compiles, once, whatever an earlier run left on
+    disk or an earlier test in this worker landed. Yields ``ops.hbm_sink``."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from dragonfly2_tpu.ops import hbm_sink
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    hbm_sink._assemble_checksum_jit.clear_cache()
+    hbm_sink.watch_compiles()
+    yield hbm_sink
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()

@@ -70,72 +70,80 @@ def _memory(fn, *specs):
             m.temp_size_in_bytes)
 
 
-def _batches(n: int, piece_mib: int, sharding):
+def _batches(n: int, piece_mib: int, sharding, last: int = 8):
+    """``n`` staged batches of 8 pieces (the last of ``last``), in the
+    shape the sink stages them in."""
     import jax.numpy as jnp
 
-    return tuple(_spec((8, piece_mib * MiB // 4), jnp.uint32, sharding)
-                 for _ in range(n))
+    from dragonfly2_tpu.ops.hbm_sink import _piece_shape
+
+    shape = _piece_shape(piece_mib * MiB // 4)
+    return tuple(_spec((8 if i < n - 1 else last, *shape), jnp.uint32,
+                       sharding) for i in range(n))
 
 
-# (piece MiB, batches of 8 pieces): the smoke's 1.75 GiB, and 512 MiB at
-# the geometry every earlier reading used.
-GEOMETRIES = [(32, 7), (4, 16)]
+def _assembly(one_chip, piece_mib: int, n_batches: int, last: int = 8,
+              missing: int = 0):
+    """The assembly program of ``n_batches`` staged batches in a sink of
+    ``missing`` more pieces than were staged, compiled for the described
+    chip; with it the content's bytes."""
+    import jax
+    import jax.numpy as jnp
 
-
-@pytest.mark.parametrize("piece_mib,n_batches", GEOMETRIES)
-def test_assemble_checksum_is_three_times_content(one_chip, piece_mib,
-                                                  n_batches):
     from dragonfly2_tpu.ops.hbm_sink import _assemble_checksum_jit
 
-    plan = tuple(("b", bi, 0, 8) for bi in range(n_batches))
-    content = n_batches * 8 * piece_mib * MiB
-    arg, out, temp = _memory(
-        functools.partial(_assemble_checksum_jit, plan=plan,
-                          piece_words=piece_mib * MiB // 4),
-        _batches(n_batches, piece_mib, one_chip))
-    assert arg >= content and out >= content
-    assert arg + out + temp <= 3.05 * content
+    rows = (n_batches - 1) * 8 + last
+    compiled = jax.jit(
+        functools.partial(_assemble_checksum_jit,
+                          total_pieces=rows + missing)).lower(
+        _batches(n_batches, piece_mib, one_chip, last),
+        _spec((rows,), jnp.int32, one_chip)).compile()
+    return compiled, (rows + missing) * piece_mib * MiB
 
 
-@pytest.mark.parametrize("piece_mib,n_batches", GEOMETRIES)
-def test_gather_checksum_is_five_times_content(one_chip, piece_mib,
-                                               n_batches):
-    """The fragmented-arrival path (more than 128 segments, so never at
-    the smoke's 55 pieces): 5.0x content at 4 MiB pieces, 5.1x at 32 MiB."""
-    import jax.numpy as jnp
-
-    from dragonfly2_tpu.ops.hbm_sink import _gather_checksum_jit
-
-    content = n_batches * 8 * piece_mib * MiB
-    arg, out, temp = _memory(
-        functools.partial(_gather_checksum_jit,
-                          piece_words=piece_mib * MiB // 4),
-        _batches(n_batches, piece_mib, one_chip),
-        _spec((n_batches * 8,), jnp.int32, one_chip))
-    assert arg + out + temp <= 5.2 * content
+# (piece MiB, batches, pieces in the last one): the smoke's 55 pieces of
+# 32 MiB, the tar shards' 30 of 8 MiB, and 512 MiB at the geometry every
+# earlier reading used.
+GEOMETRIES = [(32, 7, 7), (8, 4, 6), (4, 16, 8)]
 
 
-@pytest.mark.parametrize("rows", [8, 7])
-def test_reorder_of_a_32mib_batch_is_one_batch_more(one_chip, rows):
-    """The row gather behind every put (a full batch, and the shard's last
-    one of 7): a batch in, a batch out, next to nothing beside them, so
-    0.14x of the shard's content for a moment during landing."""
-    import jax.numpy as jnp
+@pytest.mark.parametrize("piece_mib,n_batches,last", GEOMETRIES)
+def test_assemble_checksum_is_twice_content(one_chip, piece_mib, n_batches,
+                                            last):
+    """Staged batches in, flat content out, nothing beside them: a staged
+    piece is whole tiles, so the copy needs no relayout and the flat view
+    of the placed pieces is the same memory (3x before the arrival order
+    became an argument: a content-sized temporary for the checksums'
+    reshape)."""
+    compiled, content = _assembly(one_chip, piece_mib, n_batches, last)
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes >= content
+    assert m.output_size_in_bytes >= content
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes) <= 2.05 * content
+    assert m.temp_size_in_bytes <= 4 * MiB
 
-    from dragonfly2_tpu.ops.hbm_sink import _reorder_jit
 
-    import jax
+@pytest.mark.parametrize("piece_mib,n_batches,last", GEOMETRIES)
+def test_assembly_program_grows_with_its_operands_not_its_pieces(
+        one_chip, piece_mib, n_batches, last):
+    """The program itself lives in HBM for the life of the process, one
+    for each geometry: a loop a batch, about 150 KB each (one copy a row,
+    unrolled, was 3.7 MB at 55 pieces; ``jnp.take`` of 8 rows of 32 MiB
+    compiled to 22 MB)."""
+    compiled, _ = _assembly(one_chip, piece_mib, n_batches, last)
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    assert code <= 192 * 1024 * n_batches + 256 * 1024
 
-    batch = 8 * 32 * MiB                # 7 rows are tiled as 8
-    m = jax.jit(_reorder_jit).lower(
-        _spec((rows, 32 * MiB // 4), jnp.uint32, one_chip),
-        _spec((rows,), jnp.int32, one_chip)).compile().memory_analysis()
-    assert m.output_size_in_bytes == batch
-    assert m.argument_size_in_bytes <= batch + 4096
-    assert m.temp_size_in_bytes <= MiB
-    # The program itself lives in HBM for the life of the process, one
-    # for each batch shape: ``jnp.take`` compiled to 22 MB here.
-    assert m.generated_code_size_in_bytes <= MiB
+
+def test_a_partial_sink_assembles_in_the_same_two_contents(one_chip):
+    """30 of 55 pieces staged (a ``as_words()`` mid-landing): the output
+    is the whole content, zeros where nothing landed, and still nothing
+    beside arguments and output."""
+    compiled, content = _assembly(one_chip, 32, 4, last=6, missing=25)
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes >= content
+    assert m.temp_size_in_bytes <= 4 * MiB
 
 
 def test_merge_group_of_4mib_batches(one_chip):

@@ -1095,7 +1095,7 @@ def test_streamed_landing_is_the_stores_bytes_whatever_the_order(
     from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
     from dragonfly2_tpu.ops.checksum import checksum_numpy
     from dragonfly2_tpu.pkg import flight
-    from tests.test_tpu_ops import ARRIVALS, sorted_batch_plan
+    from tests.test_tpu_ops import ARRIVALS
 
     order = ARRIVALS[arrival]
     piece = 64 * 1024
@@ -1122,17 +1122,16 @@ def test_streamed_landing_is_the_stores_bytes_whatever_the_order(
             assert len(words) == piece * len(order)
         finally:
             mgr.close()
-        segments = [p for _, code, p, _, _ in tf.events()
+        operands = [p for _, code, p, _, _ in tf.events()
                     if code == flight.EV_SINK_ASSEMBLE]
-        return _since(before), segments
+        return _since(before), operands
 
-    moved, segments = run_async(body(), timeout=120)
+    moved, operands = run_async(body(), timeout=120)
     assert (moved["in_place"], moved["copied"]) == (len(order), 0)
     assert moved["fresh"] + moved["pooled"] == 4
-    # The plan is that of a sink that sorts its batches on the host, so no
-    # arrival order adds a segment (or a compile) to it.
-    landed = order[:-2] + sorted(order[-2:])
-    assert segments == [len(sorted_batch_plan(landed, 4, len(order)))]
+    # One assembly over the four staged batches, whatever the order: it
+    # adds no operand (and no compile) to the program.
+    assert operands == [4]
 
 
 def test_two_tasks_of_different_piece_sizes_interleaved_on_one_manager(
@@ -1204,10 +1203,10 @@ def test_a_sink_dropped_mid_batch_leaks_no_staging_stack(
                 assert "unreadable" in mgr.outcome(task_id, False)[
                     "device_error"]
             elif how == "put-failed":
-                def refuses(staged, order):
+                def refuses(rows, device):
                     raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
 
-                monkeypatch.setattr(hbm_sink, "_reorder_jit", refuses)
+                monkeypatch.setattr(hbm_sink, "_put", refuses)
                 for n in (6, 7):        # the second fills the batch
                     await mgr.on_piece(task_id, store, records[n])
                 assert "out of HBM" in mgr.outcome(task_id, False)[

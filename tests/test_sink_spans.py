@@ -37,25 +37,6 @@ ORDER = [3, 0, 1, 2, 7, 6, 5, 4, 9, 8]     # how the cold landing's pieces arriv
 LEAVES = ("sink_read", "sink_checksum", "sink_stage", "sink_put")
 
 
-@pytest.fixture
-def fresh_compiles():
-    """Neither a persistent compilation cache nor an assembly program in
-    memory: whatever plan the sink meets in the test it compiles, whatever
-    an earlier run left on disk or an earlier test in this worker landed."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-
-    from dragonfly2_tpu.ops import hbm_sink
-
-    before = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    hbm_sink._assemble_checksum_jit.clear_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", before)
-    compilation_cache.reset_cache()
-
-
 @pytest.fixture(params=["whole", "split"])
 def passes(request, monkeypatch):
     """How a piece's read and checksum run: on the landing thread alone, as
@@ -209,8 +190,8 @@ def test_cold_landing_stamps_every_span_children_inside_parents(
     counts = {name: len(rows) for name, rows in spans.items()}
     # Ten pieces, each read, staged and checksummed once on the thread;
     # three stacks opened; two full batches flushed while landing and the
-    # rest in finalize; one assembly of a plan never met before; nothing
-    # left to backfill.
+    # rest in finalize; one assembly of a geometry never met before;
+    # nothing left to backfill.
     assert counts == {"sink_land": PIECES, "sink_read": PIECES,
                       "sink_checksum": PIECES, "sink_stage": PIECES + 3 + 3,
                       "sink_put": 3, "sink_assemble": 1, "sink_compile": 1,
@@ -267,7 +248,7 @@ def test_reland_backfill_stamps_the_same_steps_and_one_finalize(
         assert [p for _, _, p in spans[name]] == list(range(PIECES)), name
     assert len(spans["sink_stage"]) == PIECES + 3 + 3
     assert len(spans["sink_put"]) == 3
-    # In order: one segment per batch.
+    # piece: the staged batches the assembly read.
     assert [p for _, _, p in spans["sink_assemble"]] == [3]
     # Everything lies in the one finalize, which counted its backfill.
     (final,) = tree_of(tf)
@@ -367,39 +348,51 @@ def test_the_benchmarks_reader_finds_its_events(monkeypatch, window_of_two,
         assert value > 0.0, metric
 
 
-def test_new_plan_stamps_one_compile_and_a_repeated_plan_none(
+def test_a_new_geometry_stamps_one_compile_and_another_order_of_it_none(
         run_async, tmp_path, fresh_compiles):
     from dragonfly2_tpu.daemon.peer import device_sink
+    from dragonfly2_tpu.ops import hbm_sink
+
+    def assemblies() -> dict:
+        return {how: hbm_sink.SINK_ASSEMBLIES.labels(how)._value.get()
+                for how in ("compiled", "cached")}
 
     async def body():
         mgr = device_sink.DeviceSinkManager(batch_pieces=BATCH)
-        order = [0, 4, 1, 5, 2, 6, 3, 7, 9, 8]   # batches interleave
+        # Two orders whose batches hold different slots at different rows.
+        orders = {"t-order-a": [0, 4, 1, 5, 2, 6, 3, 7, 9, 8],
+                  "t-order-b": [9, 2, 7, 0, 5, 8, 3, 6, 1, 4]}
         out = []
         try:
-            for task_id in ("t-plan-a", "t-plan-b"):
-                # A piece size no other test lands: the first plan is new.
+            for task_id, order in orders.items():
+                # A piece size no other test lands: the geometry is new.
                 store, _ = make_store(tmp_path, task_id, 64 * 1024 + 192)
                 tf = flight.TaskFlight(task_id)
                 compiles = device_sink.SINK_COMPILES._value.get()
                 seconds_before = device_sink.SINK_COMPILE_SECONDS._value.get()
+                how = assemblies()
                 sink = await land_cold(mgr, store, tf, order)
                 assert sink is not None and sink.verified
                 out.append((
                     spans_of(tf),
                     device_sink.SINK_COMPILES._value.get() - compiles,
                     device_sink.SINK_COMPILE_SECONDS._value.get()
-                    - seconds_before))
+                    - seconds_before,
+                    {k: v - how[k] for k, v in assemblies().items()}))
         finally:
             mgr.close()
         return out
 
-    (first, n1, s1), (second, n2, s2) = run_async(body(), timeout=120)
+    (first, n1, s1, how1), (second, n2, s2, how2) = run_async(
+        body(), timeout=120)
     assert len(first["sink_compile"]) == 1 and n1 == 1
     assert s1 == pytest.approx(seconds(first["sink_compile"]), rel=1e-6)
-    # piece: the plan's segments, as on its assembly.
-    assert first["sink_compile"][0][2] == first["sink_assemble"][0][2] > 3
+    assert how1 == {"compiled": 1, "cached": 0}
+    # piece: the staged batches, as on its assembly.
+    assert first["sink_compile"][0][2] == first["sink_assemble"][0][2] == 3
     assert second["sink_compile"] == [] and (n2, s2) == (0, 0.0)
     assert len(second["sink_assemble"]) == 1
+    assert how2 == {"compiled": 0, "cached": 1}
 
 
 def test_a_dropped_sink_goes_with_its_last_reference():

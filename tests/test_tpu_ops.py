@@ -249,17 +249,16 @@ def test_hbm_sink_rejects_out_of_range_piece():
         sink.land_piece(-1, b"\x00" * 1024)
 
 
-def test_hbm_sink_fragmented_gather_path():
-    """Badly scrambled arrival falls back to the traced-permutation
-    gather (fixed graph) — content and verification must stay exact."""
+def test_hbm_sink_one_piece_batches_scrambled():
+    """Every piece a batch of its own, in a scrambled order (64 operands
+    of one row each): content and verification stay exact."""
     rng = np.random.RandomState(9)
     piece = 512
     total_pieces = 64
     content = rng.bytes(piece * total_pieces - 123)  # tail piece
     sink = HBMSink(len(content), piece, batch_pieces=1)
-    sink._SEGMENT_CAP = 4          # force the gather path
     nums = list(range(total_pieces))
-    rng.shuffle(nums)              # every piece its own batch, scrambled
+    rng.shuffle(nums)
     for n in nums:
         sink.land_piece(n, content[n * piece:(n + 1) * piece])
     assert sink.complete()
@@ -267,22 +266,28 @@ def test_hbm_sink_fragmented_gather_path():
     assert np.asarray(sink.as_bytes_array()).tobytes() == content
 
 
-def test_hbm_sink_gather_path_with_missing_pieces():
-    """The gather fallback zero-fills not-landed slots."""
+@pytest.mark.parametrize("batch_pieces", [1, 4])
+def test_hbm_sink_missing_slots_read_zeros(batch_pieces):
+    """Slots that no staged row names are zeros in the content, and their
+    device checksums are zero (pad-neutral), whatever the batches were."""
     rng = np.random.RandomState(10)
     piece = 512
     content = rng.bytes(piece * 16)
-    sink = HBMSink(len(content), piece, batch_pieces=1)
-    sink._SEGMENT_CAP = 2
-    for n in (0, 3, 5, 11, 2, 9):
+    sink = HBMSink(len(content), piece, batch_pieces=batch_pieces)
+    landed = (0, 3, 5, 11, 2, 9)
+    for n in landed:
         sink.land_piece(n, content[n * piece:(n + 1) * piece])
     out = np.asarray(sink.as_bytes_array()).tobytes()
     for n in range(16):
         got = out[n * piece:(n + 1) * piece]
-        if n in (0, 3, 5, 11, 2, 9):
+        if n in landed:
             assert got == content[n * piece:(n + 1) * piece], n
+            assert (int(sink._dev_sums[n]), int(sink._dev_xors[n])
+                    ) == sink.host_checksums[n]
         else:
             assert got == b"\x00" * piece, n
+            assert (int(sink._dev_sums[n]), int(sink._dev_xors[n])) == (0, 0)
+    assert sink.verify() and not sink.complete()
 
 
 def test_hbm_sink_consolidates_batches_at_scale():
@@ -314,25 +319,6 @@ def four_streams(pieces: int) -> list:
             if s * per + i < pieces]
 
 
-def sorted_batch_plan(order: list, batch: int, pieces: int) -> tuple:
-    """The assembly plan of a sink that sorts each batch by slot on the
-    host before it stacks it (what ``flush`` did before the stacks were
-    reused): the plan no arrival order may now exceed."""
-    where = {}
-    for bi in range(0, len(order), batch):
-        for row, slot in enumerate(sorted(order[bi:bi + batch])):
-            where[slot] = (bi // batch, row)
-    plan, slot = [], 0
-    while slot < pieces:
-        bi, row = where[slot]
-        run = 1
-        while where.get(slot + run) == (bi, row + run):
-            run += 1
-        plan.append(("b", bi, row, row + run))
-        slot += run
-    return tuple(plan)
-
-
 ARRIVALS = {"in-order": list(range(14)), "reversed": list(range(13, -1, -1)),
             "four-streams": four_streams(14)}
 
@@ -361,31 +347,93 @@ def read_into_rows(sink, content: bytes, order) -> None:
         sink.land_piece(n, row[:len(data)])
 
 
-@pytest.mark.parametrize("arrival", list(ARRIVALS))
-def test_rows_land_in_arrival_order_and_the_plan_is_the_sorted_one(
-        staging, arrival):
+def verified_landing(content: bytes, piece: int, batch: int, order) -> tuple:
+    """Land ``order`` as the daemon does and verify; the sink, and how
+    many programs the assembly compiled on this thread."""
     from dragonfly2_tpu.ops import hbm_sink
 
+    sink = HBMSink(len(content), piece, batch_pieces=batch)
+    read_into_rows(sink, content, order)
+    sink.flush()
+    # The rows lie as they arrived, on the host and on the device.
+    assert [int(n) for slots, _ in sink._batches for n in slots] == list(order)
+    before, _ = hbm_sink.compiled()
+    assert sink.verify()
+    return sink, hbm_sink.compiled()[0] - before
+
+
+@pytest.mark.parametrize("arrival", list(ARRIVALS))
+def test_rows_land_in_arrival_order_and_every_order_is_one_program(
+        staging, fresh_compiles, arrival):
+    hbm_sink = fresh_compiles
     order = ARRIVALS[arrival]
     piece, batch = 4096, 4
     content = np.random.RandomState(21).bytes(piece * len(order) - 1001)
-    sink = HBMSink(len(content), piece, batch_pieces=batch)
     in_place = hbm_sink._ROWS_IN_PLACE._value.get()
     copied = hbm_sink._ROWS_COPIED._value.get()
-    read_into_rows(sink, content, order)
-    sink.flush()
-    assert sink._plan() == sorted_batch_plan(order, batch, len(order))
-    if arrival == "in-order":
-        assert sink._plan() == tuple(
-            ("b", bi, 0, min(batch, len(order) - bi * batch))
-            for bi in range(4))
-    assert sink.verify()
+    assemblies = {how: hbm_sink.SINK_ASSEMBLIES.labels(how)._value.get()
+                  for how in ("compiled", "cached")}
+    sink, compiles = verified_landing(content, piece, batch, order)
     assert np.asarray(sink.as_bytes_array()).tobytes() == content
     assert hbm_sink._ROWS_IN_PLACE._value.get() - in_place == len(order)
     assert hbm_sink._ROWS_COPIED._value.get() == copied
     # Every stack is back, and the landing never held more than two.
     stats = staging.stats()
     assert stats["outstanding"] == 0 and 1 <= stats["free_buffers"] <= 2
+    # The other orders of the same geometry run the program this one
+    # compiled: the order is its argument.
+    for other in ARRIVALS.values():
+        again, more = verified_landing(content, piece, batch, other)
+        assert np.asarray(again.as_bytes_array()).tobytes() == content
+        compiles += more
+    assert compiles == 1
+    assert hbm_sink._assemble_checksum_jit._cache_size() == 1
+    moved = {how: hbm_sink.SINK_ASSEMBLIES.labels(how)._value.get() - was
+             for how, was in assemblies.items()}
+    assert moved == {"compiled": 1, "cached": len(ARRIVALS)}
+
+
+RANDOM_ORDERS = 20
+
+
+def test_twenty_random_orders_of_one_geometry_are_one_program(
+        staging, fresh_compiles):
+    """55 pieces in batches of 8 (8,8,8,8,8,8,7, a shard's geometry at a
+    toy piece size), the last piece short: one compile in all, content
+    bit-exact and verified for every order."""
+    hbm_sink = fresh_compiles
+    piece, batch, pieces = 1024, 8, 55
+    content = np.random.RandomState(26).bytes(piece * pieces - 333)
+    compiles = 0
+    for seed in range(RANDOM_ORDERS):
+        order = list(np.random.RandomState(seed).permutation(pieces))
+        sink, more = verified_landing(content, piece, batch, order)
+        compiles += more
+        words = np.asarray(sink.as_words()).tobytes()
+        assert words[:len(content)] == content, seed
+        assert not words[len(content):].strip(b"\x00")
+        assert sink.host_checksums[pieces - 1] == checksum_numpy(
+            content[(pieces - 1) * piece:])
+    assert compiles == 1
+    assert hbm_sink._assemble_checksum_jit._cache_size() == 1
+
+
+@pytest.mark.parametrize("arrival", list(ARRIVALS))
+def test_a_flipped_bit_in_a_staged_row_names_its_piece(staging, arrival):
+    """The checksums fold from the staged device copy: a row that changed
+    on the device after its host checksum was taken fails verification
+    under the name of the slot it belongs to, wherever it lies."""
+    order = ARRIVALS[arrival]
+    piece, batch = 4096, 4
+    content = np.random.RandomState(27).bytes(piece * len(order))
+    sink = HBMSink(len(content), piece, batch_pieces=batch)
+    read_into_rows(sink, content, order)
+    sink.flush()
+    slots, staged = sink._batches[1]
+    bad = staged.at[2].set(staged[2] ^ jnp.uint32(1 << 7))
+    sink._batches[1] = (slots, bad)
+    with pytest.raises(ValueError, match=f"piece {int(slots[2])} corrupt"):
+        sink.verify()
 
 
 def test_a_short_last_piece_in_a_dirty_stack_reads_zero_padded(staging):
