@@ -67,11 +67,23 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
                              range_header: str = "",
                              dtype=None, shape=None,
                              mesh=None, axis_name: str = "d",
+                             placement: str = "sharded",
                              claim: bool = True):
     """Download ``url`` through the embedded daemon's P2P machinery and
     land it in the device sink. Returns a jax.Array when ``dtype``+
     ``shape`` (bitcast tensor) or ``mesh`` (sharded uint32 words) is
     given, else a DeviceResult exposing the sink.
+
+    ``mesh`` with ``placement="replicated"``: the content whole on every
+    chip of the mesh (the host's local devices on one axis). The landing is
+    what it always is, up to the verified words on the sink's device; then
+    the copies travel chip to chip, and every chip's per-piece checksums of
+    its own copy must equal the host's before this returns. The result is a
+    DeviceResult whose ``as_words()`` is one replicated array and whose
+    ``load_safetensors()`` gives every tensor on every chip. A chip whose
+    copy differs fails the call (DfError, counted in
+    ``device_sink_chip_verify_total``). A mesh of the sink's device alone is
+    the plain landing.
 
     ``claim``: take ownership of the sink (the manager forgets it — HBM is
     released when the caller drops the arrays). With ``claim=False`` the
@@ -89,6 +101,10 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
     if tm.device_sinks is None:
         raise DfError(Code.BadRequest,
                       "daemon has no device sink (set tpu_sink.enabled)")
+    if placement not in ("sharded", "replicated"):
+        raise DfError(Code.BadRequest,
+                      f"placement {placement!r}: sharded or replicated")
+    replicated = mesh is not None and placement == "replicated"
     rng = Range.normalize_header(range_header) if range_header else ""
     req = FileTaskRequest(
         url=url, output="",
@@ -138,13 +154,23 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
         tm.device_sinks.unprotect(expected_id)
     if sink is None:
         raise DfError(Code.UnknownError, "device sink vanished after verify")
+    if replicated:
+        from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkError
+
+        try:
+            await tm.device_sinks.replicate(sink, mesh, axis_name,
+                                            tm.flight.task(task_id))
+        except DeviceSinkError as e:
+            tm.device_sinks.discard(task_id)
+            raise DfError(Code.ClientPieceDownloadFail,
+                          f"device sink verification failed: {e}")
     result = DeviceResult(task_id=task_id,
                           content_length=final.content_length,
                           from_p2p=final.from_p2p,
                           from_reuse=final.from_reuse, sink=sink)
     if dtype is not None and shape is not None:
         return result.as_tensor(dtype, shape)
-    if mesh is not None:
+    if mesh is not None and not replicated:
         return result.shard_to_mesh(mesh, axis_name)
     return result
 

@@ -31,7 +31,11 @@ that stamps: the helpers stamp nothing, so ``sink_read`` and
 ``sink_checksum`` are the wall time of a pass however many ran it.
 Before them each job stamps ``sink_wait``, the time it stood queued for
 the thread: a re-land is ONE job (``_finalize_sync``), so several tasks
-landing at once wait for each other's whole landings there.
+landing at once wait for each other's whole landings there. A landing that
+the caller wants whole on every chip of a mesh (``replicate``) is one more
+job of the same thread after the verified one: ``sink_replicate`` (the
+fan-out dispatched -> every chip's copy ready) and ``sink_verify_chips``
+(each chip's checksums of its own copy dispatched -> all compared).
 
 Lifecycle: sinks are created lazily at the first landed piece (task
 metadata — length and piece size — is unknown at request time), verified
@@ -70,6 +74,15 @@ SINK_COMPILE_SECONDS = metrics.counter(
 SINK_WAIT_SECONDS = metrics.counter(
     "device_sink_wait_seconds_total",
     "Seconds jobs stood queued for the one landing thread")
+SINK_REPLICATED_BYTES = metrics.counter(
+    "device_sink_replicated_bytes_total",
+    "Bytes received over the fan-out by devices other than the landing "
+    "device (a landing placed whole on every chip of a mesh)")
+SINK_CHIP_VERIFY_COUNT = metrics.counter(
+    "device_sink_chip_verify_total",
+    "Placements whole-on-every-chip by what the per-chip verification found: "
+    "every device's copy equal to the host's checksums, or one that differs",
+    ("result",))
 SINKS_LANDING = metrics.gauge(
     "device_sink_landing",
     "Device sinks created and not yet verified or dropped")
@@ -171,6 +184,23 @@ class TaskDeviceSink:
 
     def shard_to_mesh(self, mesh, axis_name: str = "d"):
         return self.sink.shard_to_mesh(mesh, axis_name)
+
+    def replicate(self, mesh, axis_name: str = "d") -> None:
+        """Whole on every chip of ``mesh``, each copy verified on the chip
+        that holds it (``HBMSink.replicate``): ``as_words()`` and every
+        view cut from it are replicated from here on. A copy that differs
+        raises DeviceSinkError naming the chip, and is counted."""
+        if not self.verified:
+            raise DeviceSinkError(
+                f"replicate on unverified sink {self.task_id[:16]}")
+        try:
+            received = self.sink.replicate(mesh, axis_name)
+        except ValueError as e:
+            SINK_CHIP_VERIFY_COUNT.labels("corrupt").inc()
+            raise DeviceSinkError(str(e)) from e
+        if received:
+            SINK_REPLICATED_BYTES.inc(received * 4 * self.sink.padded_words)
+            SINK_CHIP_VERIFY_COUNT.labels("ok").inc()
 
     def ici_broadcast(self, mesh, axis_name: str = "d", n_chunks: int = 4):
         """Striped-broadcast consumption: replicate the landed content to
@@ -459,6 +489,22 @@ class DeviceSinkManager:
         log.info("device sink verified", task=task_id[:16],
                  pieces=len(sink.landed))
         return sink
+
+    async def replicate(self, sink: TaskDeviceSink, mesh,
+                        axis_name: str = "d", tf=None) -> None:
+        """Place a verified sink whole on every chip of ``mesh`` and verify
+        every copy (``TaskDeviceSink.replicate``), as one more job of the
+        landing thread: the sink is touched by no other thread, and the
+        two spans are stamped where every ``sink_*`` span is. Raises
+        DeviceSinkError where a chip's copy differs."""
+        await self._run(tf, 0, self._replicate_sync, sink, mesh, axis_name,
+                        tf)
+
+    @staticmethod
+    def _replicate_sync(sink: TaskDeviceSink, mesh, axis_name: str,
+                        tf) -> None:
+        sink.stamp.flight = tf
+        sink.replicate(mesh, axis_name)
 
     @staticmethod
     def _stale(sink: TaskDeviceSink, store) -> bool:

@@ -684,13 +684,29 @@ class HBMSink:
         buf = self._assemble()
         n = mesh.shape[axis_name]
         per = (self.padded_words + n - 1) // n
-        if per * n != self.padded_words:
-            # Pad UP to a shard multiple — truncating would silently drop
-            # tail content bytes.
-            buf = jnp.pad(buf, (0, per * n - self.padded_words))
-        # device_put on a device array → XLA moves shards device-to-device
-        # (ICI on a TPU slice), no host staging.
-        return jax.device_put(buf, NamedSharding(mesh, P(axis_name)))
+        sharding = NamedSharding(mesh, P(axis_name))
+        if per * n == self.padded_words:
+            # device_put on a device array: XLA moves shards device-to-device
+            # (ICI on a TPU slice), no host staging.
+            return jax.device_put(buf, sharding)
+        # The words do not divide by the mesh (a single-piece task, whose
+        # piece is the content): pad UP to a shard multiple, truncating
+        # would silently drop tail content bytes. Only the last shard is
+        # padded, and the shards go out one after the other, so the landing
+        # device holds one shard beside the content, not a padded second
+        # copy of it for at most n - 1 words.
+        shards = []
+        for device, (index,) in sharding.addressable_devices_indices_map(
+                (per * n,)).items():
+            start = index.start or 0
+            shard = jax.lax.slice(
+                buf, (start,), (min(start + per, self.padded_words),))
+            if shard.shape[0] < per:
+                shard = jnp.pad(shard, (0, per - shard.shape[0]))
+            shards.append(jax.block_until_ready(
+                jax.device_put(shard, device)))
+        return jax.make_array_from_single_device_arrays(
+            (per * n,), sharding, shards)
 
     def ring_replicate(self, mesh, axis_name: str = "d", n_chunks: int = 4):
         """The ICI leg of the striped broadcast: spread the landed content
@@ -703,6 +719,88 @@ class HBMSink:
         return chunked_ring_all_gather(
             mesh, self.shard_to_mesh(mesh, axis_name),
             axis_name=axis_name, n_chunks=n_chunks)
+
+    def replicate(self, mesh, axis_name: str = "d") -> int:
+        """Place the verified content whole on every device of ``mesh``
+        and verify every copy where it lies: from here on ``as_words()``
+        is ONE array, replicated over the mesh, and every typed view cut
+        from it lies on every device too. The copies travel device to
+        device, never through the host again: one shard to each device
+        (``shard_to_mesh``), then XLA's all-gather
+        (``parallel/ici.all_gather_shards``; ICI on a TPU host), the fastest
+        of the ways measured on four chips that keeps the landing device at
+        or under three contents while it runs: the content, the shards cut
+        from it, the result (PERF.md section 6, PR 31). Then each device
+        computes the per-piece (sum32, xor32) of its own copy
+        (``_chip_checksums_jit``: one program over the mesh, every device
+        reading what it holds), and all must equal the host's. Raises ValueError naming the first device and
+        piece that differ; the content stays where it was then. Returns
+        how many devices received a copy. A mesh that is the landing
+        device alone changes nothing and costs nothing."""
+        from dragonfly2_tpu.parallel.ici import all_gather_shards
+
+        if not self._verified:
+            raise ValueError("replicate before the landing was verified")
+        if mesh.shape[axis_name] != mesh.devices.size:
+            raise ValueError(
+                f"replicate over axis {axis_name!r} of a mesh of shape "
+                f"{dict(mesh.shape)}: the devices lie on one axis")
+        devices = list(mesh.devices.flat)
+        flat = self._assemble()
+        if devices == [self.device] or flat.devices() == set(devices):
+            return 0    # the plain landing; or placed, and verified, before
+        received = len(set(devices) - {self.device})
+        with span(self.stamp, flight.EV_SINK_REPLICATE, received):
+            words = all_gather_shards(
+                mesh, self.shard_to_mesh(mesh, axis_name), axis_name)
+            if words.shape[0] != self.padded_words:
+                words = words[:self.padded_words]   # shard_to_mesh's pad
+            words = jax.block_until_ready(words)
+        with span(self.stamp, flight.EV_SINK_VERIFY_CHIPS, len(devices)):
+            # Row i is what device i computed from the copy it holds.
+            have = np.asarray(_chip_checksums_jit(
+                words, mesh=mesh, axis_name=axis_name,
+                piece_words=self.piece_words))
+            nums = sorted(self.host_checksums)
+            want = np.array([self.host_checksums[n] for n in nums],
+                            np.uint32)
+            differ = np.argwhere((have[:, nums] != want).any(axis=2))
+            if differ.size:
+                chip, k = (int(v) for v in differ[0])
+                got = have[chip, nums[k]]
+                raise ValueError(
+                    f"piece {nums[k]} corrupt on {devices[chip]} after the "
+                    f"fan-out: sum {int(got[0]):#x}!={int(want[k, 0]):#x} "
+                    f"xor {int(got[1]):#x}!={int(want[k, 1]):#x}")
+        # The landing device's single copy goes with its last reference.
+        self._assembled = words
+        return received
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("mesh", "axis_name", "piece_words"))
+def _chip_checksums_jit(words, *, mesh, axis_name: str, piece_words: int):
+    """Per-piece (sum32, xor32) of EVERY device's copy of a replicated word
+    buffer, each computed by the device that holds the copy: uint32
+    ``(devices, pieces, 2)``, row i on device i. A piece at a time, so a
+    device's temporaries are a piece and not a second content."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    pieces = words.shape[0] // piece_words
+
+    @functools.partial(shard_map, mesh=mesh, in_specs=P(),
+                       out_specs=P(axis_name), check_vma=False)
+    def each(copy):
+        def one(i):
+            piece = jax.lax.dynamic_slice(copy, (i * piece_words,),
+                                          (piece_words,))
+            s, x = _chunk_checksums_xla(piece, piece_words)
+            return jnp.stack([s[0], x[0]])
+
+        return jax.lax.map(one, jnp.arange(pieces, dtype=jnp.int32))[None]
+
+    return each(words)
 
 
 # ---------------------------------------------------------------------- #

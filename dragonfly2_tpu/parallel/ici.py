@@ -59,7 +59,11 @@ def _all_gather_jit(x, *, mesh: Mesh, axis_name: str):
 
 def all_gather_shards(mesh: Mesh, sharded, axis_name: str = "d"):
     """Every device ends with the full content (one-shot XLA all-gather —
-    on TPU this lowers to the bidirectional ICI ring)."""
+    on TPU this lowers to the bidirectional ICI ring). The way to every
+    chip that a landing placed "whole on every chip" takes
+    (``HBMSink.replicate``): of this module's ways and the runtime's own
+    ``device_put`` to a replicated sharding it was the fastest on four chips
+    of a v5e at a checkpoint shard's size (PERF.md section 6, PR 31)."""
     return _all_gather_jit(sharded, mesh=mesh, axis_name=axis_name)
 
 

@@ -108,6 +108,10 @@ EV_PARENT_PIECES = 37  # a parent announced pieces (piece=lowest, aux=how many)
 # sink_land / sink_finalize that follows it, not a child: it ends where
 # that one begins.
 EV_SINK_WAIT = 38      # (piece=num for on_piece, 0 for finalize)
+# A landing placed whole on every chip of a mesh (the same thread, after the
+# verified flat is on the landing chip): the copies travel chip to chip.
+EV_SINK_REPLICATE = 39     # fan-out dispatched -> every chip's copy ready (piece=other chips)
+EV_SINK_VERIFY_CHIPS = 40  # per-chip checksums dispatched -> all compared (piece=chips)
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -129,6 +133,8 @@ EVENT_NAMES = {
     EV_SINK_PUT: "sink_put", EV_SINK_ASSEMBLE: "sink_assemble",
     EV_SINK_COMPILE: "sink_compile", EV_SINK_FINALIZE: "sink_finalize",
     EV_PARENT_PIECES: "parent_pieces", EV_SINK_WAIT: "sink_wait",
+    EV_SINK_REPLICATE: "sink_replicate",
+    EV_SINK_VERIFY_CHIPS: "sink_verify_chips",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING
@@ -136,11 +142,16 @@ EVENT_NAMES = {
 # so --explain can say the LOOP was wedged, not just "nothing happened".
 _RUNTIME_EVENTS = (EV_LOOP_LAG, EV_GC_PAUSE)
 
-# The landing thread's steps, and last the jobs' wait for it, summed into
-# the report's ``hbm`` block.
+# The landing thread's steps, then the fan-out over the mesh and the
+# verification on every chip, and last the jobs' wait for the thread, summed
+# into the report's ``hbm`` block.
 _SINK_STEPS = (EV_SINK_LAND, EV_SINK_READ, EV_SINK_CHECKSUM, EV_SINK_STAGE,
                EV_SINK_PUT, EV_SINK_ASSEMBLE, EV_SINK_COMPILE,
-               EV_SINK_FINALIZE, EV_SINK_WAIT)
+               EV_SINK_FINALIZE, EV_SINK_REPLICATE, EV_SINK_VERIFY_CHIPS,
+               EV_SINK_WAIT)
+# Chip-to-chip work of a landing: booked under ``ici`` beside the
+# intra-slice piece transfers.
+_ICI_STEPS = (EV_SINK_REPLICATE, EV_SINK_VERIFY_CHIPS)
 
 # Canonical phase model. ``other`` (residual uninstrumented time) rides
 # alongside so the fold partitions wall time exactly.
@@ -438,8 +449,13 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
             t0 = open_marks.pop(("hbm", piece), None)
             if t0 is not None:
                 # Host -> HBM landing (the piece's wait for the landing
-                # thread included); ``ici`` is intra-slice transfers only.
+                # thread included); ``ici`` is chip-to-chip traffic only.
                 intervals.append((t0, t, "hbm"))
+        elif code in _ICI_STEPS:
+            # One event at the span's end, aux = its ms. Like every
+            # interval it counts where it falls inside the task's wall
+            # time; the ``hbm`` block below holds its ms wherever it fell.
+            intervals.append((max(0.0, t - aux / 1000.0), t, "ici"))
 
     # Tails: a request still open at the end of the timeline is the
     # black-box case — the piece never came back. Beyond the first-byte

@@ -313,3 +313,47 @@ def test_chunked_ring_on_four_chips(topo):
                           axis_name="d", n_chunks=4), spec)
     assert out == 4 * words
     assert arg + out + temp + 4 * words <= 15 * 1024 * MiB
+
+
+def test_per_chip_checksums_of_a_replicated_content_need_no_temporary(topo):
+    """The verification after the fan-out: every chip reads the copy it
+    holds, a piece at a time; per chip the content in, 8 bytes a piece out
+    and no content-sized temporary."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dragonfly2_tpu.ops.hbm_sink import _chip_checksums_jit
+
+    pieces, piece_words = 55, 32 * MiB // 4
+    mesh = Mesh(np.array(topo.devices), ("d",))
+    spec = _spec((pieces * piece_words,), jnp.uint32,
+                 NamedSharding(mesh, P()))
+    arg, out, temp = _memory(
+        functools.partial(_chip_checksums_jit, mesh=mesh, axis_name="d",
+                          piece_words=piece_words), spec)
+    assert arg == 4 * pieces * piece_words
+    assert out <= 4096 and temp <= 4 * MiB     # a tile for 55 x 2 words
+
+
+@pytest.mark.parametrize("dtype,shape", [("bfloat16", EMBED),
+                                         ("bfloat16", EXPERT)])
+def test_typed_view_of_words_that_lie_on_every_chip(topo, dtype, shape):
+    """A view cut from replicated words is replicated: the same tensor on
+    every chip, at the temporaries the one-chip view has."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dragonfly2_tpu.ops import bitview
+
+    mesh = Mesh(np.array(topo.devices), ("d",))
+    everywhere = NamedSharding(mesh, P())
+    compiled = jax.jit(functools.partial(
+        bitview._words_view_jit, shift=2, dtype=dtype, shape=shape)).lower(
+        _spec((CONTENT // 4,), jnp.uint32, everywhere),
+        _spec((), jnp.int32, everywhere)).compile()
+    assert compiled.output_shardings.is_fully_replicated
+    m = compiled.memory_analysis()
+    nbytes = 2 * int(np.prod(shape))
+    assert m.output_size_in_bytes == nbytes
+    assert m.temp_size_in_bytes <= 2.05 * nbytes + MiB
