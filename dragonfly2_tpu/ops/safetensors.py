@@ -6,7 +6,8 @@ named tensors WITHOUT a host round trip: the 8-byte header length and the
 JSON header are fetched to host (tiny), and each tensor is cut from the
 device-resident buffer — the sink's uint32 words, or a uint8 buffer on the
 hot-swap path — by ops/bitview.py, whose forms the TPU compiler accepts
-at checkpoint-shard sizes.
+at checkpoint-shard sizes, the tensors of one dtype and shape by one
+dispatch.
 
 Format (https://github.com/huggingface/safetensors — stable, public):
   [u64 little-endian header_len][header_len bytes of JSON][tensor data]
@@ -21,6 +22,7 @@ what a checkpoint is.
 from __future__ import annotations
 
 import json
+import math
 
 import jax
 import jax.numpy as jnp
@@ -77,22 +79,46 @@ def header_metadata(header: dict) -> dict[str, str]:
     return dict(meta)
 
 
+def _low_words(buffer, name: str, file_dtype: str, at: int, canon, shape):
+    """A 64-bit integer tensor with jax x64 disabled, where 64-bit dtypes
+    canonicalize to 32-bit. Keeping the low word is exact only when the
+    high word is the sign/zero extension; values beyond 32 bits are
+    checked on device rather than silently truncated. One tensor a call:
+    the check needs its answer on the host."""
+    pair = bitview.typed_view(buffer, at, canon, (math.prod(shape), 2))
+    low, hi = pair[:, 0], pair[:, 1]
+    signed = np.issubdtype(np.dtype(canon), np.signedinteger)
+    expect_hi = (jnp.where(low < 0, jnp.asarray(-1, canon),
+                           jnp.asarray(0, canon))
+                 if signed else jnp.zeros_like(hi))
+    if bool(jnp.any(hi != expect_hi)):
+        raise SafetensorsError(
+            f"{name}: {file_dtype} values exceed 32 bits; "
+            "enable jax x64 mode to load exactly")
+    return low.reshape(shape)
+
+
 def tensor_views(buffer: jax.Array, header: dict, data_start: int,
                  names: list[str] | None = None, *,
                  total: int | None = None) -> dict[str, jax.Array]:
     """Named device tensors cut from the landed buffer: flat uint32 words
     (``HBMSink.as_words``; pass the content length as ``total``, since the
-    words are padded to whole pieces) or flat uint8 bytes. Each tensor is
+    words are padded to whole pieces) or flat uint8 bytes. The whole
+    header is validated before anything is dispatched; then the tensors
+    of one dtype and shape are cut together, a tuple of arrays a dispatch
+    (``bitview.typed_views``). The result is in header order, each tensor
     its own device array in its checkpoint dtype and shape, bit-identical
     to the file except for what a TPU does to 16-bit floats: BF16/F16
     NaN payloads come back as the canonical NaN and denormals as zero
     (ops/bitview.py). Integer and F32 tensors keep every pattern."""
-    out: dict[str, jax.Array] = {}
     if total is None:
         total = int(buffer.shape[0]) * buffer.dtype.itemsize
     if not isinstance(header, dict):
         raise SafetensorsError(
             f"header must be a JSON object, got {type(header).__name__}")
+    # name -> (byte offset, canonical dtype, shape, the file's dtype where
+    # it is wider than the canonical one, else None), in header order.
+    plan: dict[str, tuple] = {}
     for name, meta in header.items():
         if name == "__metadata__":
             continue
@@ -132,40 +158,33 @@ def tensor_views(buffer: jax.Array, header: dict, data_start: int,
             raise SafetensorsError(
                 f"{name}: data_offsets [{begin}, {end}] outside content "
                 f"({total - data_start} data bytes)")
-        at = data_start + begin
+        # Zero-length tensors (a 0 dim, data_offsets [s, s]) are legal
+        # safetensors and come back empty in the canonical dtype.
         canon = jax.dtypes.canonicalize_dtype(dtype)
-        if count and canon.itemsize != itemsize:
-            # jax x64 disabled: 64-bit dtypes canonicalize to 32-bit.
-            # Keeping the low word is exact only when the high word is
-            # the sign/zero extension — float64 low words are mantissa
-            # garbage (refuse), and integer values beyond 32 bits are
-            # checked on device rather than silently truncated.
-            if meta["dtype"] == "F64":
-                raise SafetensorsError(
-                    f"{name}: F64 requires jax x64 mode "
-                    "(jax.config.update('jax_enable_x64', True))")
-            pair = bitview.typed_view(buffer, at, canon, (count, 2))
-            t = pair[:, 0]
-            hi = pair[:, 1]
-            signed = np.issubdtype(np.dtype(canon), np.signedinteger)
-            expect_hi = (jnp.where(t < 0, jnp.asarray(-1, canon),
-                                   jnp.asarray(0, canon))
-                         if signed else jnp.zeros_like(hi))
-            if bool(jnp.any(hi != expect_hi)):
-                raise SafetensorsError(
-                    f"{name}: {meta['dtype']} values exceed 32 bits; "
-                    "enable jax x64 mode to load exactly")
-            out[name] = t.reshape(shape)
-        else:
-            # Zero-length tensors (a 0 dim, data_offsets [s, s]) are
-            # legal safetensors and come back empty in the canonical
-            # dtype; BOOL is one byte of 0/1.
-            out[name] = bitview.typed_view(buffer, at, canon, shape)
+        wide = count and canon.itemsize != itemsize
+        if wide and meta["dtype"] == "F64":
+            # float64 low words are mantissa garbage: refuse.
+            raise SafetensorsError(
+                f"{name}: F64 requires jax x64 mode "
+                "(jax.config.update('jax_enable_x64', True))")
+        plan[name] = (data_start + begin, canon, shape,
+                      meta["dtype"] if wide else None)
     if names is not None:
-        missing = [n for n in names if n not in out]
+        missing = [n for n in names if n not in plan]
         if missing:
             raise SafetensorsError(
                 f"tensors not in checkpoint: {missing}")
+    out: dict[str, jax.Array] = dict.fromkeys(plan)
+    groups: dict[tuple, list[str]] = {}
+    for name, (at, canon, shape, wide) in plan.items():
+        if wide:
+            out[name] = _low_words(buffer, name, wide, at, canon, shape)
+        else:
+            groups.setdefault((canon, shape), []).append(name)
+    for (canon, shape), members in groups.items():
+        views = bitview.typed_views(
+            buffer, [plan[name][0] for name in members], canon, shape)
+        out.update(zip(members, views))
     return out
 
 

@@ -186,15 +186,22 @@ def _words(sharding):
     return _spec((-(-CONTENT // 4),), jnp.uint32, sharding)
 
 
+def _starts(n: int, sharding):
+    """The offsets of ``n`` views, the one vector a view program takes."""
+    import jax.numpy as jnp
+
+    return _spec((n,), jnp.int32, sharding)
+
+
 def test_byte_view_of_the_whole_content(one_chip):
     import jax.numpy as jnp
 
     from dragonfly2_tpu.ops import bitview
 
     arg, out, temp = _memory(
-        functools.partial(bitview._words_view_jit, shift=0,
+        functools.partial(bitview._views_jit, shift=0,
                           dtype=jnp.dtype(jnp.uint8), shape=(CONTENT - 3,)),
-        _words(one_chip), _spec((), jnp.int32, one_chip))
+        _words(one_chip), _starts(1, one_chip))
     assert out >= CONTENT - 3 and temp <= 1.05 * out
 
 
@@ -209,7 +216,7 @@ def test_byte_view_of_the_whole_content(one_chip):
 ])
 def test_typed_view_from_words(one_chip, dtype, shape, shift, factor):
     """The program ``typed_view`` dispatches for a word buffer: the word
-    offset is traced, so one program serves every tensor of a shape and
+    offsets are traced, so one program serves every tensor of a shape and
     alignment. A 16-bit float is its own size again in temporaries twice
     over: the flatten after the loop, and the last bitcast from uint16;
     an integer view once."""
@@ -219,11 +226,51 @@ def test_typed_view_from_words(one_chip, dtype, shape, shift, factor):
 
     dtype = jnp.dtype(dtype)
     arg, out, temp = _memory(
-        functools.partial(bitview._words_view_jit, shift=shift, dtype=dtype,
+        functools.partial(bitview._views_jit, shift=shift, dtype=dtype,
                           shape=shape),
-        _words(one_chip), _spec((), jnp.int32, one_chip))
+        _words(one_chip), _starts(1, one_chip))
     size = dtype.itemsize * np.prod(shape)
     assert size <= out < size + 4096 and temp <= factor * out
+
+
+def _group_memory(sharding, n: int):
+    """(output, temporary) bytes per device, and the compiled program, of
+    the view program for ``n`` routed-expert matrices of the benchmark's
+    shard: bf16 ``EXPERT``, starting 2 bytes into a word."""
+    import jax
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops import bitview
+
+    compiled = jax.jit(functools.partial(
+        bitview._views_jit, shift=2, dtype=jnp.dtype(jnp.bfloat16),
+        shape=EXPERT)).lower(_words(sharding), _starts(n, sharding)).compile()
+    m = compiled.memory_analysis()
+    return m.output_size_in_bytes, m.temp_size_in_bytes, compiled
+
+
+@pytest.mark.parametrize("where", ["one_chip", "every_chip"])
+def test_a_group_of_views_is_its_members_and_no_more(topo, one_chip, where):
+    """One dispatch for ``_GROUP_CAP`` expert matrices, on one chip and
+    on words that lie on every chip: the outputs are the members', the
+    temporaries at most the cap times the single view's (129,024 bytes),
+    nothing of the size of the content or of the group."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dragonfly2_tpu.ops import bitview
+
+    sharding = one_chip
+    if where == "every_chip":
+        sharding = NamedSharding(Mesh(np.array(topo.devices), ("d",)), P())
+    cap = bitview._GROUP_CAP
+    single_out, single_temp, _ = _group_memory(sharding, 1)
+    out, temp, compiled = _group_memory(sharding, cap)
+    nbytes = 2 * int(np.prod(EXPERT))
+    assert single_out == nbytes and single_temp <= MiB // 4
+    assert cap * nbytes <= out <= cap * (nbytes + 64)    # + the tuple's table
+    assert temp <= cap * single_temp
+    if where == "every_chip":
+        assert all(s.is_fully_replicated for s in compiled.output_shardings)
 
 
 def test_typed_view_from_bytes(one_chip):
@@ -233,10 +280,9 @@ def test_typed_view_from_bytes(one_chip):
     from dragonfly2_tpu.ops import bitview
 
     arg, out, temp = _memory(
-        functools.partial(bitview._bytes_view_jit,
+        functools.partial(bitview._views_jit, shift=0,
                           dtype=jnp.dtype(jnp.bfloat16), shape=EMBED),
-        _spec((CONTENT,), jnp.uint8, one_chip),
-        _spec((), jnp.int32, one_chip))
+        _spec((CONTENT,), jnp.uint8, one_chip), _starts(1, one_chip))
     assert out == 2 * np.prod(EMBED) and temp <= 2.1 * out
 
 
@@ -349,10 +395,10 @@ def test_typed_view_of_words_that_lie_on_every_chip(topo, dtype, shape):
     mesh = Mesh(np.array(topo.devices), ("d",))
     everywhere = NamedSharding(mesh, P())
     compiled = jax.jit(functools.partial(
-        bitview._words_view_jit, shift=2, dtype=dtype, shape=shape)).lower(
+        bitview._views_jit, shift=2, dtype=dtype, shape=shape)).lower(
         _spec((CONTENT // 4,), jnp.uint32, everywhere),
-        _spec((), jnp.int32, everywhere)).compile()
-    assert compiled.output_shardings.is_fully_replicated
+        _starts(1, everywhere)).compile()
+    assert all(s.is_fully_replicated for s in compiled.output_shardings)
     m = compiled.memory_analysis()
     nbytes = 2 * int(np.prod(shape))
     assert m.output_size_in_bytes == nbytes

@@ -320,6 +320,38 @@ def test_every_way_to_every_chip_gives_the_same_bytes(way):
         assert np.array_equal(copies[device][:words.size], words), device
 
 
+# -- (d2) a dispatch carries many views, on every chip ----------------------
+
+@pytest.mark.parametrize("pad", [0, 1, 2, 3])
+def test_grouped_views_of_words_on_every_chip_equal_the_reference(pad):
+    """Over words that lie on every chip a group of views is one program
+    on every chip: cap + 1 members at two alignments, singles, a BOOL and
+    a zero-length tensor, every one whole on every chip and equal to
+    ``np.frombuffer``, for the dispatches a single chip's load costs."""
+    from dragonfly2_tpu.ops import bitview, safetensors as st
+    from tests.test_safetensors import (_expected_dispatches,
+                                        _views_counted, grouped_object)
+
+    content, want = grouped_object(bitview._GROUP_CAP + 1, pad, seed=40 + pad)
+    sink = _landed(content, 4096)
+    mesh = mesh_of(4)
+    assert sink.replicate(mesh) == 3
+    was = _views_counted()
+    got = st.load_from_sink(sink)
+    now = _views_counted()
+    assert (now[0] - was[0], now[1] - was[1]) == (
+        _expected_dispatches(content), len(want))
+    assert list(got) == list(want)
+    devices = set(mesh.devices.flat)
+    for name, reference in want.items():
+        copies = held_by(got[name])
+        assert set(copies) == devices, name
+        for device, copy in copies.items():
+            assert copy.dtype == reference.dtype, (name, device)
+            assert copy.shape == reference.shape, (name, device)
+            assert np.array_equal(bits(copy), bits(reference)), (name, device)
+
+
 # -- (e) spans and counters ---------------------------------------------------
 
 def test_the_fan_out_and_the_per_chip_verification_are_stamped_once_inside(

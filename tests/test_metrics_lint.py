@@ -55,8 +55,9 @@ METRIC_MODULES = (
 # The documented component vocabulary (docs/OBSERVABILITY.md "Metric
 # families"). Adding a component means documenting it there first.
 COMPONENTS = ("bufpool", "chaos", "dataset", "delta", "device_sink",
-              "fleet", "manager", "objectstorage", "peer", "proxy", "qos",
-              "runtime", "scheduler", "storage", "tracing", "upload")
+              "device_views", "fleet", "manager", "objectstorage", "peer",
+              "proxy", "qos", "runtime", "scheduler", "storage", "tracing",
+              "upload")
 
 # Histogram families must name their unit; counters use _total; gauges
 # may end in a unit but never _total. "pieces" is a unit here: batch-size

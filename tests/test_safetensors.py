@@ -330,3 +330,210 @@ def test_pod_global_shardings_from_preheated_sink(checkpoint):
     # a consumer jit under the same mesh uses it directly
     out = jax.jit(lambda x: x.sum())(arr)
     np.testing.assert_allclose(float(out), float(ref.sum()), rtol=1e-4)
+
+
+# -- a dispatch carries many views (ops/bitview.typed_views) -----------------
+
+_NUMPY = {"F32": np.float32, "U16": np.uint16, "I16": np.int16,
+          "U8": np.uint8, "I8": np.int8, "BOOL": np.bool_, "I32": np.int32}
+
+
+def _views_counted() -> tuple[float, float]:
+    from dragonfly2_tpu.ops import bitview
+
+    return (bitview.VIEWS_DISPATCHES._value.get(),
+            bitview.VIEWS_TENSORS._value.get())
+
+
+def grouped_object(members: int, pad: int, seed: int = 5):
+    """A safetensors object whose header mixes one group of ``members``
+    I16 tensors of one shape with singles, a BOOL, a zero-length tensor
+    and an odd U8 in the middle of the group (so the group's members lie
+    at two alignments), its data starting ``pad`` bytes into a word:
+    (content, {name: what ``np.frombuffer`` reads}) in header order."""
+    rng = np.random.default_rng(seed)
+    table = [("a.single", "F32", (3, 5))]
+    for i in range(members):
+        table.append((f"g.{i}", "I16", (7, 9)))
+        if i == members // 2:
+            table += [("m.odd", "U8", (11,)), ("m.bool", "BOOL", (6,)),
+                      ("m.empty", "F32", (0, 4)), ("m.pair", "U16", (2, 3))]
+        if i % 5 == 0:
+            table.append((f"h.{i}", "I8", (5,)))
+    table.append(("z.last", "I32", (4,)))
+    header, blobs, want, at = {}, [], {}, 0
+    for name, dtype, shape in table:
+        kind = np.dtype(_NUMPY[dtype])
+        size = int(np.prod(shape)) * kind.itemsize
+        raw = rng.integers(0, 2 if dtype == "BOOL" else 256, size,
+                           dtype=np.uint8).tobytes()
+        header[name] = {"dtype": dtype, "shape": list(shape),
+                        "data_offsets": [at, at + size]}
+        want[name] = np.frombuffer(raw, kind).reshape(shape)
+        blobs.append(raw)
+        at += size
+    text = json.dumps(header).encode()
+    text += b" " * ((pad - (8 + len(text))) % 4)
+    return struct.pack("<Q", len(text)) + text + b"".join(blobs), want
+
+
+def _expected_dispatches(content: bytes, names=None) -> int:
+    """One dispatch for each (alignment, dtype, shape) and cap's worth."""
+    from dragonfly2_tpu.ops import bitview
+
+    header, data_start = st.parse_header(content)
+    groups: dict[tuple, int] = {}
+    for name, meta in header.items():
+        if names is None or name in names:
+            key = ((data_start + meta["data_offsets"][0]) % 4,
+                   meta["dtype"], tuple(meta["shape"]))
+            groups[key] = groups.get(key, 0) + 1
+    return sum(-(-n // bitview._GROUP_CAP) for n in groups.values())
+
+
+def _assert_equal_to_frombuffer(loaded: dict, want: dict) -> None:
+    assert list(loaded) == list(want)                   # header order
+    for name, ref in want.items():
+        got = np.asarray(loaded[name])
+        assert got.dtype == ref.dtype and got.shape == ref.shape, name
+        assert got.tobytes() == ref.tobytes(), name     # bit for bit
+
+
+def _group_sizes():
+    from dragonfly2_tpu.ops import bitview
+
+    cap = bitview._GROUP_CAP
+    return [cap, cap + 1, 2 * cap + 1]
+
+
+@pytest.mark.parametrize("pad", [0, 1, 2, 3])
+@pytest.mark.parametrize("members", _group_sizes())
+def test_grouped_views_equal_frombuffer_at_every_alignment(members, pad):
+    content, want = grouped_object(members, pad)
+    sink = _land(content, piece=1024)
+    was = _views_counted()
+    loaded = st.load_from_sink(sink)
+    now = _views_counted()
+    _assert_equal_to_frombuffer(loaded, want)
+    assert len({id(t) for t in loaded.values()}) == len(want)
+    assert now[1] - was[1] == len(want)
+    assert now[0] - was[0] == _expected_dispatches(content)
+
+
+@pytest.mark.parametrize("members", _group_sizes())
+def test_grouped_views_from_a_byte_buffer(members):
+    """The hot-swap plane's uint8 buffer goes the same way; it knows no
+    alignment, so a group is cut whole."""
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops import bitview
+
+    content, want = grouped_object(members, 3)
+    header, data_start = st.parse_header(content)
+    was = _views_counted()
+    loaded = st.tensor_views(jnp.asarray(np.frombuffer(content, np.uint8)),
+                             header, data_start)
+    now = _views_counted()
+    _assert_equal_to_frombuffer(loaded, want)
+    kinds = {(m["dtype"], tuple(m["shape"])) for m in header.values()}
+    assert now[0] - was[0] == len(kinds) - 1 + -(-members
+                                                 // bitview._GROUP_CAP)
+
+
+def test_names_pick_part_of_a_group_in_header_order():
+    from dragonfly2_tpu.ops import bitview
+
+    cap = bitview._GROUP_CAP
+    content, want = grouped_object(cap + 1, 2)
+    sink = _land(content, piece=1024)
+    names = ["z.last", f"g.{cap}", "m.bool", "g.1", "g.0", "m.empty"]
+    was = _views_counted()
+    loaded = st.load_from_sink(sink, names=names)
+    now = _views_counted()
+    _assert_equal_to_frombuffer(
+        loaded, {n: want[n] for n in want if n in names})
+    assert (now[0] - was[0], now[1] - was[1]) == (
+        _expected_dispatches(content, names), len(names))
+
+
+_GOOD = '"g.0": {"dtype": "I16", "shape": [2], "data_offsets": [0, 4]}, ' \
+        '"g.1": {"dtype": "I16", "shape": [2], "data_offsets": [4, 8]}, '
+
+
+@pytest.mark.parametrize("bad,match", [
+    ('"t": "not-an-object"', "must be an object"),
+    ('"t": {"dtype": "F8", "shape": [1], "data_offsets": [8, 9]}',
+     "unsupported dtype"),
+    ('"t": {"dtype": "F32", "data_offsets": [8, 12]}', "bad shape"),
+    ('"t": {"dtype": "F32", "shape": [true], "data_offsets": [8, 12]}',
+     "bad shape"),
+    ('"t": {"dtype": "F32", "shape": [1], "data_offsets": [8.0, 12]}',
+     "bad data_offsets"),
+    ('"t": {"dtype": "F32", "shape": [1], "data_offsets": [8]}',
+     "bad data_offsets"),
+    ('"t": {"dtype": "F32", "shape": [2], "data_offsets": [8, 12]}',
+     "data span"),
+    ('"t": {"dtype": "F32", "shape": [1], "data_offsets": [-4, 0]}',
+     "outside content"),
+    ('"t": {"dtype": "F32", "shape": [1], "data_offsets": [4096, 4100]}',
+     "outside content"),
+    ('"t": {"dtype": "F64", "shape": [1], "data_offsets": [8, 16]}', "x64"),
+])
+def test_a_malformed_entry_raises_before_anything_is_dispatched(bad, match):
+    """The good entries stand before the bad one in the header: nothing
+    of them is cut either."""
+    text = ("{" + _GOOD + bad + "}").encode()
+    content = struct.pack("<Q", len(text)) + text + b"\x01" * 64
+    sink = _land(content, piece=256)
+    words = sink.as_words()
+    header, data_start = st.parse_header(content)
+    was = _views_counted()
+    with pytest.raises(st.SafetensorsError, match=match):
+        st.tensor_views(words, header, data_start, total=len(content))
+    with pytest.raises(st.SafetensorsError, match="not in checkpoint"):
+        st.tensor_views(words, header, data_start, ["g.0", "absent"],
+                        total=len(content))
+    assert _views_counted() == was
+
+
+def test_the_benchmark_shards_207_tensors_are_a_dispatch_a_group():
+    """207 entries of ``moonlight-shard-1p7g``'s geometry: its tensor
+    table at widths small enough for a test and, as the published ones,
+    such that no two of the model's other matrices share a shape. 128
+    gate/up and 64 down matrices in groups of the cap, 13 programs of one
+    or two tensors. A count, not a time."""
+    import importlib.util
+    import os
+
+    from dragonfly2_tpu.ops import bitview
+
+    # Loaded from its source: chipbench/ itself never lands on sys.path,
+    # where a second ``tests`` package lives.
+    spec = importlib.util.spec_from_file_location(
+        "safetensors_shard", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "chipbench", "objects", "safetensors_shard.py"))
+    shard = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(shard)
+    config = {
+        "hidden_size": 128, "num_attention_heads": 2, "qk_nope_head_dim": 16,
+        "qk_rope_head_dim": 8, "v_head_dim": 8, "kv_lora_rank": 24,
+        "n_routed_experts": 64, "moe_intermediate_size": 40,
+        "n_shared_experts": 2, "vocab_size": 96,
+        "object": {"probe_u16_items": 1025, "probe_u8_items": 515}}
+    obj = shard.Objects(config, seed=11)
+    content = b"".join(bytes(s) for s in obj.segments())
+    assert len(obj.tensors) == 207 and len(content) == obj.length
+    sink = _land(content, piece=256 * 1024)
+    was = _views_counted()
+    loaded = st.load_from_sink(sink)
+    now = _views_counted()
+    cap = bitview._GROUP_CAP
+    assert now[1] - was[1] == 207
+    assert now[0] - was[0] == -(-128 // cap) + -(-64 // cap) + 13
+    assert list(loaded) == [name for name, _, _ in obj.tensors]
+    for name, rows in obj.sample(np.random.default_rng(3), True):
+        got = np.asarray(loaded[name] if rows is None else loaded[name][rows])
+        assert np.array_equal(
+            got.view(np.uint8).reshape(got.shape[0], -1),
+            obj.expected(name, rows)), name
