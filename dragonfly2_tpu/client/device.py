@@ -17,13 +17,25 @@ No reference analog: Dragonfly2's dfget terminates at the filesystem
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+import time
+from dataclasses import dataclass, field
 
-from dragonfly2_tpu.pkg import dflog
+from dragonfly2_tpu.pkg import dflog, flight as flightlib, metrics
 from dragonfly2_tpu.pkg.errors import Code, DfError
 from dragonfly2_tpu.proto.common import UrlMeta
 
 log = dflog.get("client.device")
+
+SHARDED_TASKS = metrics.counter(
+    "device_sharded_tasks_total",
+    "Spans of sharded pulls (download_sharded) by how they were served: "
+    "pulled as a ranged task of their own, or cut from the surplus of the "
+    "header's ranged task", ("how",))
+SHARDED_BYTES = metrics.counter(
+    "device_sharded_bytes_total",
+    "Bytes of sharded pulls: of the selected tensors, of what rode along "
+    "in the gaps of coalesced spans, and of the selected tensors that the "
+    "header's ranged task already held", ("kind",))
 
 
 @dataclass
@@ -59,6 +71,48 @@ class DeviceResult:
 
         return st.load_from_sink(self.sink, names=names,
                                  shardings=shardings)
+
+
+@dataclass
+class RangedTask:
+    """One ranged task of a sharded pull: bytes [start, end) of the object
+    as the fabric landed them, and the tensors that were cut from it."""
+
+    start: int
+    end: int
+    task_id: str
+    content_length: int
+    from_p2p: bool
+    from_reuse: bool
+    names: list[str] = field(default_factory=list)
+
+
+class ShardedTensors(dict):
+    """What ``download_sharded`` returns: name -> device array, a plain
+    dict to every caller, and in ``tasks`` every ranged task the pull made,
+    the header's first."""
+
+    def __init__(self, tensors=(), tasks=()):
+        super().__init__(tensors)
+        self.tasks: list[RangedTask] = list(tasks)
+
+
+@dataclass
+class HeaderPrefix:
+    """The first bytes of a checkpoint as ``fetch_safetensors_header``
+    landed them: ``nbytes`` content bytes in the sink's ``words`` (uint32,
+    zero-padded), and the ranged task (two, for a header longer than the
+    guess) that brought them."""
+
+    words: object
+    nbytes: int
+    tasks: list[RangedTask]
+
+
+def _ranged_task(start: int, result: "DeviceResult") -> RangedTask:
+    return RangedTask(start, start + result.content_length, result.task_id,
+                      result.content_length, result.from_p2p,
+                      result.from_reuse)
 
 
 async def download_to_device(daemon, url: str, *, digest: str = "",
@@ -123,7 +177,13 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
     try:
         for attempt in range(2):
             final = None
+            asked = time.perf_counter()
             async with tm.device_sinks.admit():
+                # The flight begins with the task, so the wait lies before
+                # its first event and inside no phase of its wall time.
+                tm.flight.task(expected_id).record(
+                    flightlib.EV_ADMIT_WAIT, -1,
+                    (time.perf_counter() - asked) * 1000.0)
                 async for progress in tm.start_file_task(req):
                     if progress.state == "failed":
                         raise DfError.from_wire(progress.error or {})
@@ -184,34 +244,34 @@ async def fetch_safetensors_header(daemon, url: str, *, tag: str = "",
     a second exact pull covers the rare huge header). Ranged tasks are
     byte-identical pod-wide, so a 256-host pod fetching the same header
     costs ~one origin touch and ONE fabric round trip per host instead
-    of two. Returns ``(header_dict, data_start_abs, prefix_u8)`` —
-    the landed guess bytes, whose surplus beyond the header is real
-    tensor data callers carve spans from."""
-    import numpy as np
-
+    of two. Returns ``(header_dict, data_start_abs, prefix)``: the landed
+    guess as a ``HeaderPrefix``, whose surplus beyond the header is real
+    tensor data that callers carve tensors from. The guess stays what the
+    sink landed, uint32 words on the device; only the words that cover
+    the length prefix and the header come to the host."""
+    from dragonfly2_tpu.ops import bitview
     from dragonfly2_tpu.ops import safetensors as st
 
     first = await download_to_device(
         daemon, url, tag=tag, application=application, header=header,
         range_header=f"0-{prefix_guess - 1}")
-    prefix_u8 = first.as_bytes_array()    # at most prefix_guess bytes
-    got = np.asarray(prefix_u8).tobytes()
-    if len(got) < 8:
-        raise st.SafetensorsError(f"file shorter ({len(got)}B) than the "
+    words, plen = first.as_words(), first.content_length
+    tasks = [_ranged_task(0, first)]
+    if plen < 8:
+        raise st.SafetensorsError(f"file shorter ({plen}B) than the "
                                   "safetensors length prefix")
-    n = int.from_bytes(got[:8], "little")
+    n = int.from_bytes(bitview.host_bytes(words, 0, 8), "little")
     if n <= 0 or n > (1 << 27):
         raise st.SafetensorsError(f"implausible header length {n}")
-    if 8 + n > len(got):
+    got = bitview.host_bytes(words, 0, min(8 + n, plen))
+    if 8 + n > plen:
         rest = await download_to_device(
             daemon, url, tag=tag, application=application, header=header,
-            range_header=f"{len(got)}-{8 + n - 1}")
-        got += np.asarray(rest.as_bytes_array()).tobytes()
+            range_header=f"{plen}-{8 + n - 1}")
+        tasks.append(_ranged_task(plen, rest))
+        got += bitview.host_bytes(rest.as_words(), 0, rest.content_length)
     header_dict, _ = st.parse_header(got[:8 + n])
-    # The guess surplus beyond the header is REAL tensor data already in
-    # HBM: callers carve spans inside it instead of re-pulling (see
-    # download_sharded/download_global).
-    return header_dict, 8 + n, prefix_u8
+    return header_dict, 8 + n, HeaderPrefix(words, plen, tasks)
 
 
 async def _pull_ranges(daemon, url: str, ranges, *, tag: str = "",
@@ -219,8 +279,9 @@ async def _pull_ranges(daemon, url: str, ranges, *, tag: str = "",
                        header: dict | None = None) -> dict:
     """Pull each ``(start, end)`` byte range as its own ranged device
     task, concurrently under the daemon's shared sink admission; returns
-    ``{(start, end): words}`` (the sink's uint32 buffer, zero-padded past
-    the range). The single pull engine for
+    ``{(start, end): (words, task)}``: the sink's uint32 buffer, zero-padded
+    past the range, and the ``RangedTask`` that brought it. The single pull
+    engine for
     download_sharded and download_global — their task ids and coalesce
     behavior must never fork. A failed range CANCELS its siblings
     (orphaned pulls would keep downloading against a dead result), and
@@ -234,7 +295,7 @@ async def _pull_ranges(daemon, url: str, ranges, *, tag: str = "",
         result = await download_to_device(
             daemon, url, tag=tag, application=application, header=header,
             range_header=f"{s0}-{s1 - 1}")
-        landed[(s0, s1)] = result.as_words()
+        landed[(s0, s1)] = (result.as_words(), _ranged_task(s0, result))
 
     # First failure cancels the sibling pulls and re-raises plain (the
     # TaskGroup/ExceptionGroup shape needs 3.11; this runs on 3.10 too).
@@ -247,6 +308,21 @@ async def _pull_ranges(daemon, url: str, ranges, *, tag: str = "",
         await asyncio.gather(*tasks, return_exceptions=True)
         raise
     return landed
+
+
+def _carve(words, base: int, nbytes: int, header_dict: dict,
+           data_start: int, names: list[str]) -> dict:
+    """The ``names`` tensors cut from ``words``, which hold ``nbytes``
+    content bytes from byte ``base`` of the object on: ``tensor_views``
+    validates and converts exactly as for a full-content landing, over
+    entries whose offsets are rebased onto the slice."""
+    from dragonfly2_tpu.ops import safetensors as st
+
+    sub = {n: {**header_dict[n], "data_offsets": [
+        data_start + header_dict[n]["data_offsets"][0] - base,
+        data_start + header_dict[n]["data_offsets"][1] - base]}
+        for n in names}
+    return st.tensor_views(words, sub, 0, names, total=nbytes)
 
 
 def coalesce_spans(spans) -> list[tuple[int, int]]:
@@ -301,20 +377,30 @@ async def download_sharded(daemon, url: str, *,
                            prefix_guess: int = 256 << 10):
     """Pull ONLY this host's tensors of a safetensors checkpoint through
     the fabric, landing straight in HBM: the sharded-pod pattern where a
-    host needs its pipeline stage / expert shard, not all 140 GB.
+    host needs its pipeline stage / expert shard and not the whole file
+    (an expert-parallel rank of four needs 29 % of a Moonlight checkpoint
+    file: its 16 of each layer's 64 experts and what is not routed).
 
     Every host in the same shard group issues byte-identical ranged tasks
     (same task ids), so the fabric dedupes origin traffic per RANGE, not
     per object — with 16 pipeline stages, origin serves ~1/16th of the
     checkpoint once per stage group instead of the whole file per host.
-    No reference analog: Dragonfly2 has no notion of partial-object
-    device placement (dfget terminates at the filesystem, whole-file).
+    Upstream Dragonfly2 has ranged tasks (``dfget --range``) and ends each
+    in a file; the plan over a checkpoint's header and the landing in HBM
+    are this build's.
 
     ``names``: explicit tensor list, or ``selector(name, meta) -> bool``
     over header entries. ``shardings``: tensor name → jax Sharding,
     applied via device_put after landing. Adjacent selected spans closer
     than ``coalesce_gap`` bytes merge into one ranged task (fewer tasks;
-    the gap bytes ride along).
+    the gap bytes ride along). A tensor that lies whole inside the
+    header's ranged task (``prefix_guess`` bytes) is cut from it and not
+    pulled again.
+
+    Returns a ``ShardedTensors``: a dict of name → device array in header
+    order, whose ``tasks`` name every ranged task of the pull (the header's
+    first) with its byte range, task id, content length, ``from_p2p``,
+    ``from_reuse`` and the tensors cut from it.
 
     Ranged landings verify by the per-piece digest chain (announced by
     serving parents, anchored at the range seed's self-hash); a
@@ -322,10 +408,11 @@ async def download_sharded(daemon, url: str, *,
     """
     from dragonfly2_tpu.ops import safetensors as st
 
-    header_dict, data_start, prefix_u8 = await fetch_safetensors_header(
+    called = time.perf_counter()
+    header_dict, data_start, prefix = await fetch_safetensors_header(
         daemon, url, tag=tag, application=application, header=header,
         prefix_guess=prefix_guess)
-    plen = int(prefix_u8.shape[0])
+    plen = prefix.nbytes
 
     picked: list[tuple[int, int, str]] = []
     for name, meta in header_dict.items():
@@ -351,62 +438,74 @@ async def download_sharded(daemon, url: str, *,
             raise st.SafetensorsError(
                 f"shardings reference tensors not loaded: {unknown}")
 
-    out: dict = {}
-    # Zero-element tensors (legal: a 0 dim, data_offsets [s, s]) carry no
-    # bytes — synthesize them instead of building an inverted range.
-    nonempty = []
-    for start, end, name in picked:
-        if end > start:
-            nonempty.append((start, end, name))
-            continue
-        import jax.numpy as jnp
-
-        sub = {name: {**header_dict[name], "data_offsets": [0, 0]}}
-        out.update(st.tensor_views(jnp.zeros((0,), dtype="uint8"),
-                                   sub, 0, [name]))
-    if not nonempty and not out:
-        return {}
-
-    nonempty.sort()
+    # Three kinds of tensor: without bytes (legal: a 0 dim, data_offsets
+    # [s, s]; synthesized below), whole inside the header's landing (cut
+    # from it for free), and the rest, whose spans coalesce into ranged
+    # tasks. A tensor that straddles the landing's end is pulled whole:
+    # splitting it would need a carve from two sources.
+    empty: list[str] = []
+    in_prefix: list[str] = []
     spans: list[list] = []  # [start, end, [names...]]
-    for start, end, name in nonempty:
-        if spans and start - spans[-1][1] <= coalesce_gap:
+    selected = held = gap = 0
+    for start, end, name in sorted(picked):
+        selected += end - start
+        if end == start:
+            empty.append(name)
+        elif end <= plen:
+            in_prefix.append(name)
+            held += end - start
+        elif spans and start - spans[-1][1] <= coalesce_gap:
+            gap += max(0, start - spans[-1][1])
             spans[-1][1] = max(spans[-1][1], end)
             spans[-1][2].append(name)
         else:
             spans.append([start, end, [name]])
+    head = prefix.tasks[0]
+    head.names = in_prefix
+    SHARDED_TASKS.labels("pulled").inc(len(spans))
+    if in_prefix:
+        SHARDED_TASKS.labels("prefix").inc()
+    SHARDED_BYTES.labels("selected").inc(selected)
+    SHARDED_BYTES.labels("gap").inc(gap)
+    SHARDED_BYTES.labels("prefix").inc(held)
+    tf = daemon.task_manager.flight.task(head.task_id)
+    tf.record(flightlib.EV_SHARD_PLAN, len(spans),
+              (time.perf_counter() - called) * 1000.0)
 
     # Independent spans pull concurrently (scattered shards — e.g. MoE
     # expert weights — are max-of-spans, not sum-of-spans), bounded by
-    # the daemon's shared sink admission inside _pull_ranges. Spans that
-    # the header-guess landing already covers carve from it for free.
-    # (A span straddling plen re-pulls its prefix-covered head — bounded
-    # by prefix_guess per span; splitting would need two-source carves.)
-    landed = await _pull_ranges(daemon, url,
-                                [(s, e) for s, e, _ in spans if e > plen],
+    # the daemon's shared sink admission inside _pull_ranges.
+    landed = await _pull_ranges(daemon, url, [(s, e) for s, e, _ in spans],
                                 tag=tag, application=application,
                                 header=header)
-    coverage = list(landed.items())
-    if plen:
-        coverage.append(((0, plen), prefix_u8))
+    viewing = time.perf_counter()
+    cut: dict = {}
+    if empty:
+        import jax.numpy as jnp
+
+        sub = {name: {**header_dict[name], "data_offsets": [0, 0]}
+               for name in empty}
+        cut.update(st.tensor_views(jnp.zeros((0,), dtype="uint32"),
+                                   sub, 0, empty))
+    if in_prefix:
+        cut.update(_carve(prefix.words, 0, plen, header_dict, data_start,
+                          in_prefix))
+    tasks = list(prefix.tasks)
     for start, end, span_names in spans:
-        buf, base = next((u, c0) for (c0, c1), u in coverage
-                         if c0 <= start and end <= c1)
-        # Rebase the span's tensors onto the slice: tensor_views validates
-        # and converts exactly as for a full-content landing.
-        sub_header = {
-            n: {**header_dict[n],
-                "data_offsets": [
-                    data_start + header_dict[n]["data_offsets"][0] - base,
-                    data_start + header_dict[n]["data_offsets"][1] - base]}
-            for n in span_names}
-        out.update(st.tensor_views(buf, sub_header, 0, span_names))
+        words, task = landed.pop((start, end))
+        task.names = span_names
+        tasks.append(task)
+        cut.update(_carve(words, start, task.content_length, header_dict,
+                          data_start, span_names))
+    tf.record(flightlib.EV_SHARD_VIEWS, len(cut),
+              (time.perf_counter() - viewing) * 1000.0)
     if shardings:  # unknown names already rejected above, pre-download
         import jax
 
         for name, sharding in shardings.items():
-            out[name] = jax.device_put(out[name], sharding)
-    return out
+            cut[name] = jax.device_put(cut[name], sharding)
+    return ShardedTensors(((name, cut[name]) for _, _, name in picked),
+                          tasks)
 
 
 async def download_global(daemon, url: str,
@@ -438,10 +537,10 @@ async def download_global(daemon, url: str,
 
     from dragonfly2_tpu.ops import safetensors as st
 
-    header_dict, data_start, prefix_u8 = await fetch_safetensors_header(
+    header_dict, data_start, prefix = await fetch_safetensors_header(
         daemon, url, tag=tag, application=application, header=header,
         prefix_guess=prefix_guess)
-    plen = int(prefix_u8.shape[0])
+    plen = prefix.nbytes
 
     missing = [n for n in shardings if n not in header_dict]
     if missing:
@@ -506,11 +605,11 @@ async def download_global(daemon, url: str,
 
     # Ranges the header-guess landing already covers carve from it free.
     pull_list = [m for m in merged if m[1] > plen]
-    landed = await _pull_ranges(daemon, url, pull_list,
-                                tag=tag, application=application,
-                                header=header)
+    pulled = await _pull_ranges(daemon, url, pull_list, tag=tag,
+                                application=application, header=header)
+    landed = {span: words for span, (words, _) in pulled.items()}
     if plen:
-        landed[(0, plen)] = prefix_u8
+        landed[(0, plen)] = prefix.words
     coverage = pull_list + ([(0, plen)] if plen else [])
 
     def super_range(a: int, b: int) -> tuple[int, int]:
@@ -526,20 +625,22 @@ async def download_global(daemon, url: str,
             # dtypes as SafetensorsError, never a bare KeyError).
             sub = {name: {**meta, "shape": list(shard_shape),
                           "data_offsets": [0, 0]}}
-            shard = st.tensor_views(jax.numpy.zeros((0,), dtype="uint8"),
+            shard = st.tensor_views(jax.numpy.zeros((0,), dtype="uint32"),
                                     sub, 0, [name])[name]
         elif idx is not None:
             # Fallback: the whole tensor landed; carve the (possibly
             # non-contiguous) shard on device.
             s0, s1 = super_range(a, b)
             sub = {name: {**meta, "data_offsets": [a - s0, b - s0]}}
-            shard = st.tensor_views(landed[(s0, s1)], sub, 0, [name])[name]
+            shard = st.tensor_views(landed[(s0, s1)], sub, 0, [name],
+                                    total=s1 - s0)[name]
             shard = shard[idx]
         else:
             s0, s1 = super_range(a, b)
             sub = {name: {**meta, "shape": list(shard_shape),
                           "data_offsets": [a - s0, b - s0]}}
-            shard = st.tensor_views(landed[(s0, s1)], sub, 0, [name])[name]
+            shard = st.tensor_views(landed[(s0, s1)], sub, 0, [name],
+                                    total=s1 - s0)[name]
         by_name.setdefault(name, []).append(jax.device_put(shard, dev))
     for name, sharding in shardings.items():
         shape = tuple(header_dict[name].get("shape") or ())

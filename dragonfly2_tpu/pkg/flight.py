@@ -112,6 +112,13 @@ EV_SINK_WAIT = 38      # (piece=num for on_piece, 0 for finalize)
 # verified flat is on the landing chip): the copies travel chip to chip.
 EV_SINK_REPLICATE = 39     # fan-out dispatched -> every chip's copy ready (piece=other chips)
 EV_SINK_VERIFY_CHIPS = 40  # per-chip checksums dispatched -> all compared (piece=chips)
+# The client API's own steps (client/device.py), stamped on the event loop:
+# ONE event at the step's end, aux = its ms, as the sink_* spans. The first on
+# the flight of the task that waited; the other two on the flight of a sharded
+# pull's header task, which stands for the whole call.
+EV_ADMIT_WAIT = 41     # a device pull's wait at device_sinks.admit(), stamped as the task starts
+EV_SHARD_PLAN = 42     # download_sharded called -> header landed, parsed, spans planned (piece=ranged tasks planned)
+EV_SHARD_VIEWS = 43    # the typed views of every span dispatched (piece=tensors returned)
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -135,6 +142,8 @@ EVENT_NAMES = {
     EV_PARENT_PIECES: "parent_pieces", EV_SINK_WAIT: "sink_wait",
     EV_SINK_REPLICATE: "sink_replicate",
     EV_SINK_VERIFY_CHIPS: "sink_verify_chips",
+    EV_ADMIT_WAIT: "admit_wait", EV_SHARD_PLAN: "shard_plan",
+    EV_SHARD_VIEWS: "shard_views",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING
@@ -149,6 +158,8 @@ _SINK_STEPS = (EV_SINK_LAND, EV_SINK_READ, EV_SINK_CHECKSUM, EV_SINK_STAGE,
                EV_SINK_PUT, EV_SINK_ASSEMBLE, EV_SINK_COMPILE,
                EV_SINK_FINALIZE, EV_SINK_REPLICATE, EV_SINK_VERIFY_CHIPS,
                EV_SINK_WAIT)
+# The client API's steps, summed into the report's ``client`` block.
+_CLIENT_STEPS = (EV_ADMIT_WAIT, EV_SHARD_PLAN, EV_SHARD_VIEWS)
 # Chip-to-chip work of a landing: booked under ``ici`` beside the
 # intra-slice piece transfers.
 _ICI_STEPS = (EV_SINK_REPLICATE, EV_SINK_VERIFY_CHIPS)
@@ -478,6 +489,7 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
     counts: dict = {}
     runtime: dict = {}
     hbm: dict = {}
+    client: dict = {}
     for _t, code, _p, aux, _n in events:
         name = EVENT_NAMES.get(code, str(code))
         counts[name] = counts.get(name, 0) + 1
@@ -486,6 +498,10 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
             # inside the task's wall time (finalize runs after the
             # terminal event).
             hbm[code] = hbm.get(code, 0.0) + aux
+        elif code in _CLIENT_STEPS:
+            # The admission wait ends where the task's wall time begins,
+            # and a sharded pull's plan and views span its other tasks.
+            client[code] = client.get(code, 0.0) + aux
         elif code in _RUNTIME_EVENTS:
             r = runtime.get(name)
             if r is None:
@@ -516,6 +532,8 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
         # "sink_read" -> "read_ms", in the order of _SINK_STEPS.
         "hbm": {EVENT_NAMES[code][5:] + "_ms": round(hbm[code], 3)
                 for code in _SINK_STEPS if code in hbm},
+        "client": {EVENT_NAMES[code] + "_ms": round(client[code], 3)
+                   for code in _CLIENT_STEPS if code in client},
         "pieces": ordered[:max_waterfall],
         "pieces_truncated": truncated,
     }
@@ -564,6 +582,12 @@ def render_waterfall(report: dict) -> str:
         lines.append("hbm landing, ms on the landing thread (wait: queued "
                      "for it): " + " ".join(
                          f"{k[:-3]}={v:.1f}" for k, v in hbm.items()))
+    client = report.get("client")
+    if client:
+        lines.append("client api, ms (admit_wait: queued for a sink slot "
+                     "before the task; shard_*: the sharded pull this task "
+                     "heads): " + " ".join(
+                         f"{k[:-3]}={v:.1f}" for k, v in client.items()))
     advisory = runtime_advisory(report)
     if advisory:
         lines.append(advisory)

@@ -37,6 +37,7 @@ METRIC_MODULES = (
     "dragonfly2_tpu.daemon.peer.conductor",
     "dragonfly2_tpu.daemon.peer.task_manager",
     "dragonfly2_tpu.daemon.peer.device_sink",
+    "dragonfly2_tpu.client.device",
     "dragonfly2_tpu.scheduler.service",
     "dragonfly2_tpu.manager.client",
     "dragonfly2_tpu.proto.reportcodec",
@@ -54,10 +55,10 @@ METRIC_MODULES = (
 
 # The documented component vocabulary (docs/OBSERVABILITY.md "Metric
 # families"). Adding a component means documenting it there first.
-COMPONENTS = ("bufpool", "chaos", "dataset", "delta", "device_sink",
-              "device_views", "fleet", "manager", "objectstorage", "peer",
-              "proxy", "qos", "runtime", "scheduler", "storage", "tracing",
-              "upload")
+COMPONENTS = ("bufpool", "chaos", "dataset", "delta", "device_sharded",
+              "device_sink", "device_views", "fleet", "manager",
+              "objectstorage", "peer", "proxy", "qos", "runtime", "scheduler",
+              "storage", "tracing", "upload")
 
 # Histogram families must name their unit; counters use _total; gauges
 # may end in a unit but never _total. "pieces" is a unit here: batch-size
