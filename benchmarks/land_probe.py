@@ -1,0 +1,262 @@
+"""What a landed piece costs the host: a probe of the pass that reads a
+piece from the store into its staging row and checksums it
+(``ops/hbm_sink.py`` "Host passes"), as a re-land through
+``DeviceSinkManager.finalize`` alone: no scheduler, no transfer, no views.
+
+    chiprun --chips 1 -- python3 benchmarks/land_probe.py
+    JAX_PLATFORMS=cpu python3 benchmarks/land_probe.py \\
+        --objects 20000003:4194304                          # the rehearsal
+
+For each object (the benchmark's two geometries, the Moonlight shard's 55
+pieces of 32 MiB and a LAION tar's 30 of 8 MiB, and 60 pieces of 16 MiB
+between them; random bytes, written to a store of the probe's own and so
+in the page cache) and each arrangement,
+the median and the range over ``--repeats`` re-lands, after one that is not
+counted, of ``sink_finalize`` and of the summed ``sink_read`` and
+``sink_checksum`` of the flight, in ms:
+
+  two-pass widening   the tree before PR 35: every chunk read, the landing
+                      thread waiting; then every chunk checksummed, waiting
+                      again; sum32 as ``np.sum(dtype=uint64) & 0xFFFFFFFF``
+  two-pass wrapping   the same two passes, sum32 as a wrapping uint32 sum
+  fused wrapping      the tree as it is: a helper reads its chunk and
+                      checksums it before it returns
+  fused blocked N     the same, the two reductions over blocks of N KiB in
+                      turn, so that a block is read from memory once
+  fused wrapping, h/f the tree's pass with h helpers (1: no hand-over) and
+                      a chunk floor of f KiB
+
+The first two are rebuilt here from the tree's own parts and patched over
+``hbm_sink.read_checksummed`` / ``checksum_numpy``; every arrangement's
+host checksums must equal the first's, and the device verifies each
+landing. The table goes to stdout and to ``chiprun_out/land_probe.json``;
+PERF.md section 5 ("The passes, alone") holds the reading that
+``_HELPERS`` and ``_CHUNK_FLOOR`` rest on.
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import json
+import os
+import shutil
+import statistics
+import sys
+import tempfile
+import time
+from concurrent.futures import ThreadPoolExecutor
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(HERE)
+sys.path.insert(0, REPO)
+
+OBJECTS = ("1843431563:33554432", "1000000007:16777216",
+           "250000384:8388608")
+BLOCKS_KIB = (256, 512, 1024, 2048)
+OTHERS = ("1:2048", "2:2048", "4:2048", "6:2048", "12:2048", "8:1024",
+          "8:4096")
+
+
+def _store(root: str, name: str, length: int, piece_size: int):
+    """A completed store of ``length`` random bytes in ``piece_size``
+    pieces."""
+    import numpy as np
+
+    from dragonfly2_tpu.storage.local_store import (
+        LocalTaskStore,
+        TaskStoreMetadata,
+    )
+
+    pieces = -(-length // piece_size)
+    store = LocalTaskStore(
+        os.path.join(root, name),
+        TaskStoreMetadata(task_id=name, content_length=length,
+                          piece_size=piece_size, total_piece_count=pieces))
+    rng = np.random.default_rng(length)
+    for n in range(pieces):
+        size = min(piece_size, length - n * piece_size)
+        store.write_piece(n, rng.integers(0, 256, size, dtype=np.uint8).data)
+    return store
+
+
+def _widening(data) -> "tuple[int, int]":
+    """``checksum_numpy`` as it was before PR 35."""
+    import numpy as np
+
+    from dragonfly2_tpu.ops.checksum import _pad_to_words
+
+    words = _pad_to_words(data)
+    return (int(np.sum(words, dtype=np.uint64) & 0xFFFFFFFF),
+            int(np.bitwise_xor.reduce(words, initial=np.uint32(0))))
+
+
+def _blocked(block_bytes: int):
+    """``checksum_numpy`` with both reductions over one block at a time."""
+    import numpy as np
+
+    from dragonfly2_tpu.ops.checksum import _pad_to_words
+
+    step = block_bytes // 4
+
+    def checksum(data) -> "tuple[int, int]":
+        words = _pad_to_words(data)
+        s = x = 0
+        for at in range(0, words.size, step):
+            block = words[at:at + step]
+            s += int(np.add.reduce(block, dtype=np.uint32))
+            x ^= int(np.bitwise_xor.reduce(block))
+        return s & 0xFFFFFFFF, x
+
+    return checksum
+
+
+def _two_passes(row, size: int, read_into):
+    """``read_checksummed`` as two passes with a wait after each: PR 28's
+    ``_land_one`` and ``land_piece``, from the tree's own parts."""
+    from dragonfly2_tpu.ops import hbm_sink
+
+    ranges = hbm_sink.cuts(size)
+    t0 = time.perf_counter()
+    if len(ranges) > 1:
+        hbm_sink.side_by_side(lambda a, b: read_into(row, a, b), ranges)
+    else:
+        read_into(row, 0, size)
+    read_s = time.perf_counter() - t0
+    padded = size + (-size) % 4
+    row[size:padded] = 0
+    checksum = hbm_sink.checksum_row(row[:padded], hbm_sink.cuts(padded))
+    return checksum, read_s, len(ranges)
+
+
+def _counted() -> dict:
+    from dragonfly2_tpu.ops import hbm_sink
+
+    return {**{k: hbm_sink.SINK_PASSES.labels(k)._value.get()
+               for k in ("fused", "checksum")},
+            **{k: hbm_sink.SINK_PIECES.labels(k)._value.get()
+               for k in ("split", "whole")}}
+
+
+def _arrangements(helpers: int, floor: int, blocks_kib, others) -> list:
+    """(name, helpers, chunk floor, read_checksummed or None for the
+    tree's, checksum_numpy or None for the tree's)."""
+    rows = [("two-pass widening", helpers, floor, _two_passes, _widening),
+            ("two-pass wrapping", helpers, floor, _two_passes, None),
+            ("fused wrapping", helpers, floor, None, None)]
+    rows += [(f"fused blocked {kib}", helpers, floor, None,
+              _blocked(kib << 10)) for kib in blocks_kib]
+    for other in others:
+        h, kib = (int(v) for v in other.split(":"))
+        rows.append((f"fused wrapping, {h}/{kib}", h, kib << 10, None, None))
+    return rows
+
+
+async def _reland(mgr, store, repeats: int) -> dict:
+    from dragonfly2_tpu.pkg import flight
+
+    task_id = store.metadata.task_id
+    runs, checksums = [], None
+    counted = {}
+    for i in range(repeats + 1):
+        before = _counted()
+        tf = flight.TaskFlight(task_id)
+        sink = await mgr.finalize(task_id, store, tf)
+        if sink is None or not sink.verified:
+            raise RuntimeError(
+                f"{task_id}: no verified landing: "
+                f"{mgr.outcome(task_id, False)}")
+        checksums = dict(sink.sink.host_checksums)
+        counted = {k: n - before[k] for k, n in _counted().items()}
+        mgr.discard(task_id)
+        del sink
+        ms = {"sink_finalize": 0.0, "sink_read": 0.0, "sink_checksum": 0.0}
+        for _, code, _, aux, _ in tf.events():
+            name = flight.EVENT_NAMES[code]
+            if name in ms:
+                ms[name] += aux
+        if i:                               # the first is the warm-up
+            runs.append(ms)
+    out = {"counted": counted, "checksums": checksums}
+    for key, name in (("finalize_ms", "sink_finalize"),
+                      ("read_ms", "sink_read"),
+                      ("checksum_ms", "sink_checksum")):
+        values = sorted(r[name] for r in runs)
+        out[key] = statistics.median(values)
+        out[key + "_range"] = [values[0], values[-1]]
+    return out
+
+
+def main(argv: list[str] | None = None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--objects", nargs="*", default=list(OBJECTS),
+                        help="length:piece_size, bytes")
+    parser.add_argument("--repeats", type=int, default=3)
+    parser.add_argument("--blocks-kib", type=int, nargs="*",
+                        default=list(BLOCKS_KIB))
+    parser.add_argument("--others", nargs="*", default=list(OTHERS),
+                        help="helpers:floor_KiB of further fused passes")
+    args = parser.parse_args(argv)
+
+    import jax
+
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops import hbm_sink
+
+    device = jax.devices()[0]
+    tree = (hbm_sink._HELPERS, hbm_sink._CHUNK_FLOOR, hbm_sink._POOL,
+            hbm_sink.read_checksummed, hbm_sink.checksum_numpy)
+    root = tempfile.mkdtemp(prefix=".land_probe_", dir=REPO)
+    rows = []
+    try:
+        for spec in args.objects:
+            length, piece_size = (int(v) for v in spec.split(":"))
+            t0 = time.perf_counter()
+            store = _store(root, f"probe-{length}-{piece_size}", length,
+                           piece_size)
+            print(f"[land_probe] {spec}: {len(store.metadata.pieces)} pieces "
+                  f"stored in {time.perf_counter() - t0:.1f} s", flush=True)
+            first = None
+            for name, h, floor, fused, checksum in _arrangements(
+                    tree[0], tree[1], args.blocks_kib, args.others):
+                pool = ThreadPoolExecutor(
+                    max_workers=h, thread_name_prefix="df-sink-helper")
+                hbm_sink._HELPERS, hbm_sink._CHUNK_FLOOR = h, floor
+                hbm_sink._POOL = pool
+                hbm_sink.read_checksummed = fused or tree[3]
+                hbm_sink.checksum_numpy = checksum or tree[4]
+                mgr = DeviceSinkManager()
+                try:
+                    row = asyncio.run(_reland(mgr, store, args.repeats))
+                finally:
+                    mgr.close()
+                    pool.shutdown()
+                checksums = row.pop("checksums")
+                first = first or checksums
+                row.update(object=spec, arrangement=name, helpers=h,
+                           floor=floor, pieces=len(checksums),
+                           device=device.device_kind,
+                           same_bits=checksums == first)
+                rows.append(row)
+                print(f"[land_probe] {spec} {name}: " + json.dumps(row),
+                      flush=True)
+            store.destroy()
+    finally:
+        (hbm_sink._HELPERS, hbm_sink._CHUNK_FLOOR, hbm_sink._POOL,
+         hbm_sink.read_checksummed, hbm_sink.checksum_numpy) = tree
+        shutil.rmtree(root, ignore_errors=True)
+    out = os.path.join(REPO, "chiprun_out")
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "land_probe.json"), "w") as f:
+        json.dump(rows, f, indent=1)
+    print(f"{'object':>22} {'arrangement':>24} {'finalize':>9} {'read':>8} "
+          f"{'checksum':>8}  ms, median of {args.repeats}")
+    for r in rows:
+        print(f"{r['object']:>22} {r['arrangement']:>24} "
+              f"{r['finalize_ms']:9.1f} {r['read_ms']:8.1f} "
+              f"{r['checksum_ms']:8.1f}")
+    return 0 if all(r["same_bits"] for r in rows) else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

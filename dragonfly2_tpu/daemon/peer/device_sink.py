@@ -16,11 +16,12 @@ otherwise stall the daemon's event loop (upload serving, RPC) for the
 duration of each copy. The async surface awaits that thread, so the
 download path still backpressures on landing. The thread has helpers
 (``ops/hbm_sink.py``: ``df-sink-helper``, one small pool a process) for
-the two host passes over a piece of two chunk floors and more: they read
-the chunks of the piece into the chunks of its row (``_land_one``) and
-checksum them (``HBMSink.land_piece``) side by side, touch no sink and no
-manager state, and the landing thread waits for every one of them before
-it goes on, a failed chunk's error in hand or not.
+the host pass over a piece of two chunk floors and more
+(``HBMSink.read_piece``): each reads a chunk of the piece from the store
+into the same chunk of the piece's row and checksums it there before it
+returns; they touch no sink and no manager state, and the landing thread
+waits for every one of them before it goes on, a failed chunk's error in
+hand or not.
 
 Spans: that thread stamps its steps into the task's flight ring
 (``sink_land`` > ``sink_read``, ``sink_checksum``, ``sink_stage``,
@@ -28,7 +29,8 @@ Spans: that thread stamps its steps into the task's flight ring
 ``sink_assemble`` > ``sink_compile``), one event at a step's end with its
 ms — a child is a span that lies inside another, there being one thread
 that stamps: the helpers stamp nothing, so ``sink_read`` and
-``sink_checksum`` are the wall time of a pass however many ran it.
+``sink_checksum`` are the two parts of the one pass's wall time (its
+longest read, and the rest) however many ran it.
 Before them each job stamps ``sink_wait``, the time it stood queued for
 the thread: a re-land is ONE job (``_finalize_sync``), so several tasks
 landing at once wait for each other's whole landings there. A landing that
@@ -309,30 +311,20 @@ class DeviceSinkManager:
 
     def _land_one(self, sink: TaskDeviceSink, store, rec, tf) -> None:
         """Read one piece back from the store, straight into the row of
-        the sink's staging stack it will be put from, and stage it."""
-        from dragonfly2_tpu.ops.hbm_sink import cuts, side_by_side
-
+        the sink's staging stack it will be put from, checksummed in the
+        same pass, and stage it."""
         sink.stamp.flight = tf
-        row = sink.sink.next_row()
-        with self._span(tf and tf.record, flightlib.EV_SINK_READ,
-                        rec.num) as step:
-            stored = store.metadata.pieces.get(rec.num)
-            ranges = cuts(stored.size) if stored is not None else ()
-            if len(ranges) < 2:
-                size = store.read_piece_into(rec.num, row).size
-            else:
-                # Each helper reads its range of the piece into the same
-                # range of the row; a failure in one is raised when all
-                # are back, so none can still write into a stack that the
-                # degraded sink has given up.
-                step.note = str(len(ranges))
-                side_by_side(
-                    lambda a, b: store.read_into(stored.offset + a, b - a,
-                                                 row, at=a),
-                    ranges)
-                store.touch()
-                size = stored.size
-        sink.land(rec.num, row[:size], rec.digest)
+        stored = store.piece(rec.num)
+        # A range of the piece into the same range of its row. Where
+        # helpers read the ranges, a failure in one is raised when all are
+        # back, so none can still write into a stack that the degraded
+        # sink has given up.
+        data = sink.sink.read_piece(
+            rec.num, stored.size,
+            lambda row, start, stop: store.read_into(
+                stored.offset + start, stop - start, row, at=start))
+        store.touch()
+        sink.land(rec.num, data, rec.digest)
 
     def _land_inner(self, task_id: str, store, rec, tf) -> None:
         if task_id in self._degraded:

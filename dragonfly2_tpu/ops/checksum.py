@@ -30,10 +30,14 @@ def _pad_to_words(data: bytes) -> np.ndarray:
 
 
 def checksum_numpy(data: bytes) -> tuple[int, int]:
-    """Host-side reference: (sum32, xor32)."""
+    """Host-side reference: (sum32, xor32). The sum is taken at the word's
+    own width: a uint32 reduction wraps mod 2^32, which IS sum32's
+    definition, and it runs at the xor's speed, where a sum widened to
+    uint64 goes through numpy's buffered cast at a third of it (PERF.md
+    section 5, "The passes, alone")."""
     words = _pad_to_words(data)
-    s = int(np.sum(words, dtype=np.uint64) & 0xFFFFFFFF)
-    x = int(np.bitwise_xor.reduce(words, initial=np.uint32(0)))
+    s = int(np.add.reduce(words, dtype=np.uint32))
+    x = int(np.bitwise_xor.reduce(words))
     return s, x
 
 

@@ -31,26 +31,33 @@ Host staging: the sink owns its stacks, and they are reused. A stack comes
 from a process-wide free list (``pkg/bufpool``, pool ``hbm_stage``), so
 its pages have been touched before: a fresh 32 MiB buffer costs 32-41 ms
 of page faults on the chip's host, a fresh 256 MiB stack 260 ms (PERF.md
-section 5), against 35-65 ms for the transfer itself. The daemon reads a
-piece from its store straight into ``next_row()`` and hands that row to
-``land_piece``, which then checksums it where it lies; any other bytes
-are copied into the row once. Rows lie in arrival order, on the host and
-on the device. ``flush`` puts the stack (``_put``), and only when the
-device array is ready does the stack go back to the free list:
+section 5), against 35-65 ms for the transfer itself. The daemon's piece
+is read from its store straight into the next row and checksummed there
+in the same pass (``read_piece``), and that row is handed to
+``land_piece``, which copies nothing then; any other bytes are copied into
+the row once and checksummed where they lie. Rows lie in arrival order, on
+the host and on the device. ``flush`` puts the stack (``_put``), and only
+when the device array is ready does the stack go back to the free list:
 ``jax.device_put`` returns before the runtime has read the host buffer.
 On the CPU backend an aligned buffer is aliased, not copied, for the
 device array's whole life, so there ``_put`` copies the rows first.
 
-Host passes: a piece is passed over twice on the host, by the read into
-its row (the daemon's, ``daemon/peer/device_sink.py``) and by the checksum
-of that row (``land_piece``). Both are plain memory traffic that lets go
-of the GIL, so a piece of at least two ``_CHUNK_FLOOR``s has each pass cut
-into word-aligned chunks (``cuts``), views of the row itself, which a few
-helper threads run side by side (``side_by_side``) while the thread that
-lands the piece waits for all of them. The helpers hold no sink state and
-stamp nothing; the piece's checksum is the fold of its chunks' and is
-``checksum_numpy`` of the row, bit for bit. Smaller pieces are handled
-whole where they are, with no hand-over.
+Host passes: a piece the sink reads itself costs the host ONE pass
+(``read_piece`` -> ``read_checksummed``): its bytes are read from the
+store into its row and checksummed there while they are still in the
+cache. Both halves are plain memory traffic that lets go of the GIL, so a
+piece of at least two ``_CHUNK_FLOOR``s is cut into page-aligned chunks
+(``cuts``), views of the row itself, and each of a few helper threads
+reads its chunk and checksums it before it returns (``side_by_side``),
+while the thread that lands the piece waits once for all of them. The
+helpers hold no sink state and stamp nothing; the piece's checksum is the
+fold of its chunks' and is ``checksum_numpy`` of the padded row, bit for
+bit. Smaller pieces run the same read-then-checksum where they are, with
+no hand-over. Bytes a caller brings to ``land_piece`` (a record of
+``DeviceFeed``, a delta chunk, the benchmark's controls) were read by
+someone else: they are copied into the row and cost a checksum pass of
+their own there (``checksum_row``), cut the same way. Either way the host
+checksums exactly the bytes it hands to ``device_put``.
 
 Memory, as the v5e compiler reports it for the assembly program
 (``memory_analysis()``, tests/test_chip_compile.py): staged batches
@@ -119,12 +126,21 @@ _ROWS_IN_PLACE = SINK_ROWS.labels("in_place")
 _ROWS_COPIED = SINK_ROWS.labels("copied")
 SINK_PIECES = metrics.counter(
     "device_sink_pieces_total",
-    "Pieces landed, by how their host passes (read-back, checksum) ran: cut "
+    "Pieces landed, by how the host pass that checksummed them ran: cut "
     "into chunks over the helper threads (split) or on the landing thread "
     "alone (whole)",
     ("how",))
 _PIECES_SPLIT = SINK_PIECES.labels("split")
 _PIECES_WHOLE = SINK_PIECES.labels("whole")
+SINK_PASSES = metrics.counter(
+    "device_sink_host_passes_total",
+    "Host passes over pieces' bytes: a piece read from the store into its "
+    "row and checksummed in one hand-over (fused), or a checksum alone, of "
+    "bytes a caller brought (checksum). Over device_sink_pieces_total: 1.0 "
+    "where every piece is the sink's own read",
+    ("kind",))
+_PASSES_FUSED = SINK_PASSES.labels("fused")
+_PASSES_CHECKSUM = SINK_PASSES.labels("checksum")
 SINK_ASSEMBLIES = metrics.counter(
     "device_sink_assemblies_total",
     "Assembly dispatches, by whether the landing thread compiled the "
@@ -134,15 +150,19 @@ SINK_ASSEMBLIES = metrics.counter(
 _ASSEMBLIES_COMPILED = SINK_ASSEMBLIES.labels("compiled")
 _ASSEMBLIES_CACHED = SINK_ASSEMBLIES.labels("cached")
 
-# A host pass over a piece is cut into at most _HELPERS chunks of about
+# The host pass over a piece is cut into at most _HELPERS chunks of about
 # _CHUNK_FLOOR bytes or more each; under two floors it is not cut. Fixed
-# from a re-land on the chip's 13-core host (PERF.md section 6, PR 28): at
-# 32 MiB pieces 4 helpers land in 0.45 s what one thread lands in 1.16, 2 in
-# 0.75 and 8 in 0.39; at 8 MiB pieces 4 chunks of 2 MiB land a shard in 0.105
-# s against 0.178 whole, 2 of 4 MiB in 0.13, and with 8 of 1 MiB the checksum
-# is slower than whole: a hand-over costs what 1 MiB of either pass does.
+# from a re-land on the chip's 13-core host (benchmarks/land_probe.py;
+# PERF.md section 5, "The passes, alone", PR 35; finalize ms, medians of 3).
+# 55 pieces of 32 MiB: 4 helpers 357-371, 6 323, **8 294-295**, 12 279; one
+# thread 988; and end to end 8 land a shard a quarter faster than 4 with
+# nothing lost in a cold pull beside the wire. 30 pieces of 8 MiB: 4 chunks
+# of 2 MiB 75-78 against 157 whole and 104 in 2; 8 chunks of 1 MiB 77 with
+# the checksum half again as long, and at 32 MiB a floor of 1 MiB or of 4
+# changes nothing while one of 4 MiB takes the 8 MiB pieces to 104: a
+# hand-over still costs what 1 MiB of the pass does.
 _CHUNK_FLOOR = 2 << 20
-_HELPERS = 4
+_HELPERS = 8
 # A chunk is whole pages of the store's file (a piece begins on one), hence
 # whole words of the row.
 _CHUNK_ALIGN = 4096
@@ -182,16 +202,45 @@ def side_by_side(fn, ranges) -> list:
         del futures
 
 
+def _fold(parts) -> "tuple[int, int]":
+    """The checksum of a row from its ranges' ``(sum32, xor32, ...)``:
+    sum32 is the ranges' sums mod 2^32 and xor32 the xor of their xors, so
+    a cut changes no bit."""
+    return (sum(p[0] for p in parts) & 0xFFFFFFFF,
+            functools.reduce(operator.xor, (p[1] for p in parts)))
+
+
 def checksum_row(row: np.ndarray, ranges) -> "tuple[int, int]":
     """``checksum_numpy(row)`` of a row of whole words, taken range by
-    range (``cuts`` of its size) where there are several: sum32 is the
-    ranges' sums mod 2^32 and xor32 the xor of their xors, so the cut
-    changes no bit."""
+    range (``cuts`` of its size) where there are several."""
     if len(ranges) < 2:
         return checksum_numpy(row)
-    parts = side_by_side(lambda a, b: checksum_numpy(row[a:b]), ranges)
-    return (sum(s for s, _ in parts) & 0xFFFFFFFF,
-            functools.reduce(operator.xor, (x for _, x in parts)))
+    return _fold(side_by_side(lambda a, b: checksum_numpy(row[a:b]), ranges))
+
+
+def read_checksummed(row: np.ndarray, size: int,
+                     read_into) -> "tuple[tuple[int, int], float, int]":
+    """A piece of ``size`` bytes read into the start of ``row`` and
+    checksummed there in ONE pass: ``read_into(row, start, stop)`` fills
+    ``row[start:stop]`` with the piece's bytes ``[start, stop)``, and
+    whoever read a range (``cuts(size)``; a helper each where there are
+    several) checksums it before it returns, the last one up to the whole
+    word, over padding zeroed here. Returns ``checksum_numpy`` of the
+    padded piece, the seconds the longest read took, and the number of
+    ranges. Past the padding the row is as it was."""
+    padded = size + (-size) % 4
+    row[size:padded] = 0
+
+    def one(start: int, stop: int) -> "tuple[int, int, float]":
+        t0 = time.perf_counter()
+        read_into(row, start, stop)
+        read_s = time.perf_counter() - t0
+        s, x = checksum_numpy(row[start:padded if stop == size else stop])
+        return s, x, read_s
+
+    ranges = cuts(size)
+    parts = side_by_side(one, ranges) if len(ranges) > 1 else [one(0, size)]
+    return _fold(parts), max(p[2] for p in parts), len(ranges)
 
 
 def _give_back(view: memoryview) -> None:
@@ -214,8 +263,8 @@ class span:
     there is a stamp: the flight ring's span convention, one event at the
     end whose aux is the duration. ``piece`` may be set inside the block,
     where it is only known then, and so may ``note``, the event's text
-    (``sink_read`` / ``sink_checksum``: into how many chunks the pass was
-    cut, nothing where it was not)."""
+    (``sink_checksum``: into how many chunks the pass was cut, nothing
+    where it was not)."""
 
     __slots__ = ("stamp", "code", "piece", "note", "_annotation", "_t0")
 
@@ -402,6 +451,9 @@ class HBMSink:
         self.platform = self.device.platform
         self.device_kind = self.device.device_kind
         self.host_checksums: dict[int, tuple[int, int]] = {}
+        # What ``read_piece`` left for the ``land_piece`` that follows it:
+        # (piece, size, checksum, chunks) of the row it filled.
+        self._read: tuple | None = None
         self.landed: set[int] = set()
         self.batch_pieces = batch_pieces
         # Staged device batches: (the rows' slots, (k, *piece shape)
@@ -455,7 +507,7 @@ class HBMSink:
 
     def next_row(self) -> memoryview:
         """The row the next piece will lie in, as ``piece_size`` writable
-        bytes: read the piece into it, then hand ``row[:size]`` to
+        bytes: write the piece into it, then hand ``row[:size]`` to
         ``land_piece``, which copies nothing then. A row taken and never
         landed is handed out again."""
         if self._stack is None:
@@ -463,19 +515,51 @@ class HBMSink:
                 self._open_stack()
         return memoryview(self._stack[len(self._rows)])
 
+    def read_piece(self, piece_num: int, size: int, read_into) -> memoryview:
+        """Piece ``piece_num`` of ``size`` bytes read from the caller's
+        store into the next row and checksummed there, in one pass over
+        its bytes (``read_checksummed``, which says what ``read_into`` is).
+        Returns the piece in its row, for ``land_piece``: handed exactly
+        that, it copies nothing and takes this pass's checksum. Stamps the
+        pass as ``sink_read``, the longest read in it, and
+        ``sink_checksum``, the rest of its wall time, each with the number
+        of chunks as its note where the piece was cut."""
+        view = self.next_row()
+        row = np.frombuffer(view, np.uint8)
+        if size > row.size:
+            raise ValueError(
+                f"piece {piece_num} of {size} bytes in a sink of "
+                f"{self.piece_size}-byte pieces")
+        t0 = time.perf_counter()
+        with TraceAnnotation("df:sink_read_checksum"):
+            checksum, read_s, chunks = read_checksummed(row, size, read_into)
+        pass_s = time.perf_counter() - t0
+        _PASSES_FUSED.inc()
+        if self.stamp is not None:
+            note = str(chunks) if chunks > 1 else ""
+            self.stamp(flight.EV_SINK_READ, piece_num, read_s * 1000.0, note)
+            self.stamp(flight.EV_SINK_CHECKSUM, piece_num,
+                       (pass_s - read_s) * 1000.0, note)
+        self._read = (piece_num, size, checksum, chunks)
+        return view[:size]
+
     def land_piece(self, piece_num: int, data: bytes) -> None:
         """Stage one piece as the next row of the open stack, zero-padded
-        to the piece size. ``data`` is the piece's bytes: the start of the
-        row ``next_row()`` gave, already in place, or any other bytes-like
-        object, copied into the row once. The host checksum is taken from
-        the row, and recorded for the verification on the device.
-        Batched: flushes every ``batch_pieces``."""
+        to the piece size. ``data`` is the piece's bytes: what ``read_piece``
+        returned (or the start of the row ``next_row()`` gave), already in
+        place, or any other bytes-like object, copied into the row once.
+        The host checksum recorded for the verification on the device is
+        the one ``read_piece`` took of this row with these bytes in it;
+        after anything else it is taken from the row here. Batched:
+        flushes every ``batch_pieces``."""
         if piece_num < 0 or piece_num >= self.total_pieces:
             # A stray out-of-range piece must not invalidate (and on a
             # drained sink, zero out) the assembled content.
             raise ValueError(
                 f"piece {piece_num} out of range for "
                 f"{self.total_pieces}-piece sink")
+        # Whatever is landed now, no later piece may take this reading.
+        read, self._read = self._read, None
         if piece_num in self.landed:
             return
         with span(self.stamp, flight.EV_SINK_STAGE, piece_num):
@@ -487,23 +571,30 @@ class HBMSink:
                 raise ValueError(
                     f"piece {piece_num} of {given.size} bytes in a sink of "
                     f"{self.piece_size}-byte pieces")
-            if given.ctypes.data == row.ctypes.data:
+            in_place = given.ctypes.data == row.ctypes.data
+            if in_place:
                 _ROWS_IN_PLACE.inc()
             else:
                 row[:given.size] = given
                 _ROWS_COPIED.inc()
             # The stack is reused: past the piece lies an earlier one.
             row[given.size:] = 0
-        with span(self.stamp, flight.EV_SINK_CHECKSUM, piece_num) as step:
-            # Whole words, the zero padding included: nothing to copy.
-            words = row[:given.size + (-given.size) % 4]
-            ranges = cuts(words.size)
-            if len(ranges) > 1:
-                step.note = str(len(ranges))
-                _PIECES_SPLIT.inc()
-            else:
-                _PIECES_WHOLE.inc()
-            self.host_checksums[piece_num] = checksum_row(words, ranges)
+        if in_place and read is not None and read[:2] == (piece_num,
+                                                          given.size):
+            checksum, chunks = read[2:]
+        else:
+            with span(self.stamp, flight.EV_SINK_CHECKSUM,
+                      piece_num) as step:
+                # Whole words, the zero padding included: nothing to copy.
+                words = row[:given.size + (-given.size) % 4]
+                ranges = cuts(words.size)
+                chunks = len(ranges)
+                if chunks > 1:
+                    step.note = str(chunks)
+                checksum = checksum_row(words, ranges)
+            _PASSES_CHECKSUM.inc()
+        (_PIECES_SPLIT if chunks > 1 else _PIECES_WHOLE).inc()
+        self.host_checksums[piece_num] = checksum
         self._rows.append(piece_num)
         self.landed.add(piece_num)
         if len(self._rows) >= self.batch_pieces:

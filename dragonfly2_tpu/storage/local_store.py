@@ -840,12 +840,19 @@ class LocalTaskStore:
         self.touch()
         return total
 
-    def read_piece_into(self, num: int, buf) -> PieceRecord:
-        """Read piece ``num``'s bytes into ``buf`` (pooled or caller-owned);
-        returns the piece record (size says how much of ``buf`` is valid)."""
+    def piece(self, num: int) -> PieceRecord:
+        """Piece ``num``'s record (where it lies in the data file, its
+        size): what a reader with a way of its own to cut the read needs
+        beside ``read_into``."""
         rec = self.metadata.pieces.get(num)
         if rec is None:
             raise StorageError(f"piece {num} not found", Code.StoragePieceNotFound)
+        return rec
+
+    def read_piece_into(self, num: int, buf) -> PieceRecord:
+        """Read piece ``num``'s bytes into ``buf`` (pooled or caller-owned);
+        returns the piece record (size says how much of ``buf`` is valid)."""
+        rec = self.piece(num)
         self.read_spans_into(((rec.offset, rec.size),), buf)
         return rec
 
@@ -855,9 +862,7 @@ class LocalTaskStore:
         Hot paths use read_piece_into with a buffer of their own instead:
         pooled on the serve side, a row of the staging stack in the device
         sink."""
-        rec = self.metadata.pieces.get(num)
-        if rec is None:
-            raise StorageError(f"piece {num} not found", Code.StoragePieceNotFound)
+        rec = self.piece(num)
         out = bytearray(rec.size)
         self.read_spans_into(((rec.offset, rec.size),), out)
         return bytes(out)

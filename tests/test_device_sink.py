@@ -1194,11 +1194,11 @@ def test_a_sink_dropped_mid_batch_leaks_no_staging_stack(
                 await mgr.on_piece(task_id, store, records[n])
             assert hbm_sink._STAGING.stats()["outstanding"] > outstanding
 
-            def fails(num, buf):
-                raise StorageError(f"piece {num} unreadable")
+            def fails(offset, length, buf, at=0):
+                raise StorageError(f"piece at {offset} unreadable")
 
             if how == "degraded":
-                monkeypatch.setattr(store, "read_piece_into", fails)
+                monkeypatch.setattr(store, "read_into", fails)
                 await mgr.on_piece(task_id, store, records[6])
                 assert "unreadable" in mgr.outcome(task_id, False)[
                     "device_error"]
@@ -1217,7 +1217,7 @@ def test_a_sink_dropped_mid_batch_leaks_no_staging_stack(
                 mgr.ttl = 0.0
                 mgr.gc()
             else:
-                monkeypatch.setattr(store, "read_piece_into", fails)
+                monkeypatch.setattr(store, "read_into", fails)
                 assert await mgr.finalize(task_id, store) is None
             assert mgr.get(task_id) is None
             return hbm_sink._STAGING.stats()["outstanding"] - outstanding
@@ -1423,6 +1423,115 @@ def test_a_piece_of_two_floors_is_split_and_a_smaller_one_is_not(
         assert notes == {"sink_read": [""] * pieces,
                          "sink_checksum": [""] * pieces}
         assert helpers == []
+
+
+@pytest.mark.parametrize("path", ["re-land", "streamed"])
+def test_a_split_piece_costs_the_helpers_one_hand_over_a_chunk(
+        run_async, tmp_path, monkeypatch, own_helpers, path):
+    """Ten pieces of 64 KiB under a floor of 16 KiB, the last of 35,535
+    bytes: nine pieces in four chunks and one in two are 38 submits to the
+    pool, not 76, and every piece is one fused pass: host passes over
+    pieces reads 1.0."""
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops import hbm_sink
+    from tests.test_tpu_ops import _passes as _passes_counted
+
+    piece, pieces = 64 * 1024, 10
+    monkeypatch.setattr(hbm_sink, "_CHUNK_FLOOR", 16 * 1024)
+    submits = []
+    submit = own_helpers.submit
+
+    def counted_submit(fn, *args):
+        submits.append(args)
+        return submit(fn, *args)
+
+    monkeypatch.setattr(own_helpers, "submit", counted_submit)
+
+    async def body():
+        store, content = _stored(tmp_path, "t-" + path, piece,
+                                 piece * pieces - 30_001)
+        task_id = store.metadata.task_id
+        mgr = DeviceSinkManager(batch_pieces=4)
+        passes, counted = _passes_counted(), _pieces_counted()
+        try:
+            if path == "streamed":
+                for n in [4, 9, 0, 1, 2, 3, 8, 7]:
+                    await mgr.on_piece(task_id, store,
+                                       store.metadata.pieces[n])
+            sink = await mgr.finalize(task_id, store)
+            assert sink is not None and sink.verified
+            assert bytes(np.asarray(sink.as_bytes_array())) == content
+        finally:
+            mgr.close()
+        return ({k: n - passes[k] for k, n in _passes_counted().items()},
+                {k: n - counted[k] for k, n in _pieces_counted().items()})
+
+    passes, counted = run_async(body(), timeout=120)
+    assert len(submits) == 9 * 4 + 2
+    assert passes == {"fused": pieces, "checksum": 0}
+    assert passes["fused"] / sum(counted.values()) == 1.0
+    assert counted == {"split": pieces, "whole": 0}
+
+
+@pytest.mark.parametrize("passes", ["split", "whole"])
+def test_a_pieces_two_events_sum_to_the_pass(run_async, tmp_path,
+                                             monkeypatch, passes):
+    """Under a clock that ticks a ms a reading: each piece stamps ONE
+    ``sink_read`` and ONE ``sink_checksum`` on the landing thread, whose
+    ``aux`` sum to the span the test puts around the pass, to the two
+    readings ``read_piece`` makes outside it; ``sink_read`` is a reading
+    inside the pass (the longest read), and the note is the chunks."""
+    import itertools
+    import types
+
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops import hbm_sink
+    from dragonfly2_tpu.pkg import flight
+
+    piece, pieces = 64 * 1024, 6
+    if passes == "split":
+        monkeypatch.setattr(hbm_sink, "_CHUNK_FLOOR", 16 * 1024)
+    ticks = itertools.count()
+    clock = types.SimpleNamespace(perf_counter=lambda: next(ticks) / 1000.0)
+    monkeypatch.setattr(hbm_sink, "time", clock)
+    sound, around = hbm_sink.read_checksummed, []
+
+    def timed(row, size, read_into):
+        t0 = clock.perf_counter()
+        try:
+            return sound(row, size, read_into)
+        finally:
+            around.append((clock.perf_counter() - t0) * 1000.0)
+
+    monkeypatch.setattr(hbm_sink, "read_checksummed", timed)
+
+    async def body():
+        store, _ = _stored(tmp_path, "t-ev-" + passes, piece,
+                           piece * pieces - 30_001)
+        tf = flight.TaskFlight(store.metadata.task_id)
+        mgr = DeviceSinkManager(batch_pieces=4)
+        try:
+            sink = await mgr.finalize(store.metadata.task_id, store, tf)
+            assert sink is not None and sink.verified
+        finally:
+            mgr.close()
+        return tf
+
+    tf = run_async(body(), timeout=120)
+    events = {name: [(p, aux, note) for _, code, p, aux, note in tf.events()
+                     if flight.EVENT_NAMES[code] == name]
+              for name in ("sink_read", "sink_checksum")}
+    assert [p for p, _, _ in events["sink_read"]] == list(range(pieces))
+    assert [p for p, _, _ in events["sink_checksum"]] == list(range(pieces))
+    assert len(around) == pieces
+    for (_, read, note), (_, rest, same), span in zip(
+            events["sink_read"], events["sink_checksum"], around):
+        assert span <= read + rest <= span + 2.0 + 1e-6
+        assert 1.0 - 1e-6 <= read <= span and rest > 0
+        assert note == same
+    notes = [note for _, _, note in events["sink_read"]]
+    assert notes == (["4"] * 5 + ["2"] if passes == "split"
+                     else [""] * pieces)
 
 
 @pytest.mark.parametrize("how", ["short-read", "os-error"])

@@ -205,11 +205,12 @@ def test_cold_landing_stamps_every_span_children_inside_parents(
     assert [span.piece for span in lands] == ORDER
     assert (final.name, final.piece) == ("sink_finalize", 0)
     for at, land in enumerate(lands):
-        # Where a stack is opened (-1), the read into its row, the row's
-        # own staging, its checksum; with the batch's last piece the flush.
+        # Where a stack is opened (-1), the one pass that read the piece
+        # into its row and checksummed it, stamped as its two parts, the
+        # row's own staging; with the batch's last piece the flush.
         want = ([("sink_stage", -1)] if at % BATCH == 0 else []) + [
-            ("sink_read", land.piece), ("sink_stage", land.piece),
-            ("sink_checksum", land.piece)]
+            ("sink_read", land.piece), ("sink_checksum", land.piece),
+            ("sink_stage", land.piece)]
         if at % BATCH == BATCH - 1:
             lowest = min(ORDER[at - BATCH + 1:at + 1])
             want += [("sink_stage", lowest), ("sink_put", lowest)]

@@ -35,6 +35,35 @@ class TestChecksum:
         b = checksum_numpy(b"hello world!" + b"\x00" * 8)
         assert a == b
 
+    # Lengths in bytes by name: around a word, a word short of and over
+    # the sizes a pass is cut at (a cache-sized block, the chunk floor), a
+    # sum that passes 2^32 four million times, and many random bits.
+    LENGTHS = {
+        "0": 0, "1": 1, "3": 3, "4": 4, "5": 5,
+        **{f"{kib}KiB{step:+d}": (kib << 10) + step
+           for kib in (256, 1024, 2048) for step in (-4, 0, 4)},
+        "all-ones": (1 << 24) + 12, "64MiB-random": 64 << 20,
+    }
+
+    @pytest.mark.parametrize("name", list(LENGTHS))
+    def test_numpy_reference_is_the_uint64_definition(self, name):
+        """sum32 added at the word's own width (it wraps) against the
+        definition written out: every word widened to 64 bits, summed,
+        taken mod 2^32; xor32 against the fold of the same words."""
+        length = self.LENGTHS[name]
+        if name == "all-ones":
+            data = b"\xff" * length
+        else:
+            data = np.random.default_rng(length).integers(
+                0, 256, length, dtype=np.uint8).tobytes()
+        wide = np.frombuffer(data + bytes(-length % 4), "<u4").astype(
+            np.uint64)
+        want = (int(wide.sum() % (1 << 32)),
+                int(np.bitwise_xor.reduce(wide)) if wide.size else 0)
+        if name == "all-ones":
+            assert int(wide.sum()) >> 32 > 4_000_000
+        assert checksum_numpy(data) == want
+
     # The kernel's two reshape branches: whole (sublane, lane) tiles of 128
     # words, and a piece that is not a multiple of 128.
     @pytest.mark.parametrize("piece_words", [256, 200])
@@ -460,6 +489,108 @@ def test_a_short_last_piece_in_a_dirty_stack_reads_zero_padded(staging):
         assert len(words) == batch * piece // 4
         assert sink.host_checksums[3] == checksum_numpy(content[3 * piece:])
     assert staging.stats()["free_buffers"] == 1      # the same stack, thrice
+
+
+def _passes() -> dict:
+    from dragonfly2_tpu.ops import hbm_sink
+
+    return {kind: hbm_sink.SINK_PASSES.labels(kind)._value.get()
+            for kind in ("fused", "checksum")}
+
+
+def _reads(content: bytes, piece: int, n: int):
+    """``read_into`` of piece ``n`` of ``content``, as a store would give
+    it, and the piece's size."""
+    data = content[n * piece:(n + 1) * piece]
+
+    def read_into(row, start, stop):
+        row[start:stop] = np.frombuffer(data[start:stop], np.uint8)
+
+    return read_into, len(data)
+
+
+# A sink of 64 KiB pieces under a chunk floor of 16 KiB: the sizes of a last
+# piece that ``cuts`` makes 1, 2, 3 and 4 ranges of, whole words and not,
+# one of them ending a byte past a cut and one a byte before.
+LAST_PIECES = {"whole-4-cuts": 64 * 1024, "4-cuts-odd": 64 * 1024 - 3,
+               "3-cuts-odd": 48 * 1024 + 1, "a-byte-before-a-cut": 36863,
+               "a-byte-past-a-cut": 36865, "2-cuts": 35_535,
+               "1-cut-odd": 30_001, "1-byte": 1}
+
+
+@pytest.mark.parametrize("name", list(LAST_PIECES))
+def test_the_fused_pass_checksums_the_padded_row_however_it_is_cut(
+        staging, monkeypatch, name):
+    """``read_piece`` in a reused stack (an earlier task's 0xff
+    everywhere): what it leaves for ``land_piece`` is ``checksum_numpy`` of
+    the piece padded to whole words, the pad zeroed before the last range
+    was summed; the device agrees, and the helpers were handed one pass."""
+    from dragonfly2_tpu.ops import hbm_sink
+
+    monkeypatch.setattr(hbm_sink, "_CHUNK_FLOOR", 16 * 1024)
+    piece, batch = 64 * 1024, 2
+    dirty = HBMSink(piece * batch, piece, batch_pieces=batch)
+    for n in range(batch):
+        dirty.land_piece(n, b"\xff" * piece)
+    assert dirty.verify()
+    last = LAST_PIECES[name]
+    content = np.random.RandomState(last % 1000).bytes(piece + last)
+    sink = HBMSink(len(content), piece, batch_pieces=batch)
+    before = _passes()
+    for n in (1, 0):
+        read_into, size = _reads(content, piece, n)
+        data = sink.read_piece(n, size, read_into)
+        assert len(data) == size
+        sink.land_piece(n, data)
+    assert staging.stats()["free_buffers"] == 0      # the dirty stack, again
+    assert sink.host_checksums[1] == checksum_numpy(content[piece:])
+    assert sink.host_checksums[0] == checksum_numpy(content[:piece])
+    assert len(hbm_sink.cuts(last)) == min(4, max(1, last // (16 * 1024)))
+    after = _passes()
+    assert (after["fused"] - before["fused"],
+            after["checksum"] - before["checksum"]) == (2, 0)
+    assert sink.verify()
+    words = np.asarray(sink.as_words()).tobytes()
+    assert words[:len(content)] == content
+    assert not words[len(content):].strip(b"\x00")
+
+
+@pytest.mark.parametrize("how", ["another-piece", "the-same-piece-altered",
+                                 "the-same-row-shorter"])
+def test_bytes_that_are_not_the_read_are_checksummed_in_the_row(staging, how):
+    """After ``read_piece`` of piece 3, ``land_piece`` is handed something
+    else: another piece's bytes, the benchmark's control (piece 3's bytes
+    with a bit flipped, a copy), or less of the row. The host checksum is
+    taken from the row as it is put, never the reading left for piece 3."""
+    piece, batch = 4096, 4
+    content = np.random.RandomState(31).bytes(piece * 6)
+    sink = HBMSink(len(content), piece, batch_pieces=batch)
+    read_into, size = _reads(content, piece, 3)
+    data = sink.read_piece(3, size, read_into)
+    before, reading = _passes(), checksum_numpy(bytes(data))
+    if how == "another-piece":
+        num, given = 5, content[5 * piece:]
+    elif how == "the-same-piece-altered":
+        altered = bytearray(data)
+        altered[len(altered) // 3] ^= 0x10
+        num, given = 3, bytes(altered)
+    else:
+        num, given = 3, data[:size - 8]
+    sink.land_piece(num, given)
+    assert sink.host_checksums == {num: checksum_numpy(bytes(given))}
+    assert sink.host_checksums[num] != reading
+    assert _passes()["checksum"] - before["checksum"] == 1
+    # And the reading is gone: the same row again is a pass of its own.
+    read_into, size = _reads(content, piece, 0)
+    row = sink.next_row()
+    read_into(np.frombuffer(row, np.uint8), 0, size)
+    sink.land_piece(0, row[:size])
+    assert sink.host_checksums[0] == checksum_numpy(content[:piece])
+    assert _passes()["checksum"] - before["checksum"] == 2
+    sink.flush()
+    assert sink.verify()
+    landed = np.asarray(sink.as_words()).tobytes()
+    assert landed[num * piece:num * piece + len(given)] == bytes(given)
 
 
 def test_a_second_landing_takes_its_stacks_from_the_free_list(staging):
