@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import asyncio
 import os
+import time
 from collections import deque
 
 import msgpack
@@ -62,6 +63,13 @@ PARENT_QUARANTINE_COUNT = metrics.counter(
     "peer_parent_quarantine_total",
     "Parents entering the daemon-wide quarantine, by tipping reason",
     ("reason",))
+# Seconds P2P children stood in _await_certification, by how the wait
+# ended: beside peer_completion_rehash_total{skipped|hashed} it says what the
+# skips cost (certified) and what the misses wasted (timeout).
+CERT_WAIT_SECONDS = metrics.counter(
+    "peer_task_cert_wait_seconds_total",
+    "Seconds spent waiting for a certifying parent's done at completion",
+    ("result",))
 ANNOUNCE_RECONNECT_COUNT = metrics.counter(
     "peer_announce_reconnects_total",
     "Mid-download announce-stream recovery attempts", ("result",))
@@ -530,28 +538,51 @@ class PeerTaskConductor:
         if not LocalTaskStore.completion_digest_applies(
                 self.meta.get("digest", ""), self.content_range is not None):
             return False  # no completion re-hash would run: nothing to save
+        t0 = time.perf_counter()
+        how, tried = await self._wait_certified()
+        waited = time.perf_counter() - t0
+        # One span for the whole stay, whichever way it ended: between the
+        # last piece and task_done nothing else of a cold pull is stamped.
+        self.flight.record(flightlib.EV_CERT_WAIT, tried, waited * 1000.0,
+                           how)
+        CERT_WAIT_SECONDS.labels(how).inc(waited)
+        return how == "certified"
+
+    async def _wait_certified(self) -> "tuple[str, int]":
+        """The wait itself: how it ended (``certified``, ``timeout``,
+        ``no_certifier``: no parent left whose done could still come,
+        ``unverifiable``: a piece landed without a verified-against digest)
+        and how many digest maps were tried on the way."""
         content = self.store.metadata.content_length
         if content <= 0:
-            return False
+            return "no_certifier", 0
         if not self.store.pieces_verified_against_digests():
             # Some piece landed without a verified-against digest: no
             # certified map can ever engage the skip — waiting is futile.
-            return False
+            return "unverifiable", 0
         loop = asyncio.get_running_loop()
         deadline = loop.time() + self._cert_wait_bound(content)
         disp = self.dispatcher
+        how, tried = "no_certifier", 0
         while disp.pending_certifiers():
             remaining = deadline - loop.time()
             if remaining <= 0:
+                how = "timeout"
                 break  # deadline-edge done still gets the final attempt
             disp.certified_event.clear()
-            if self.store.apply_certification(disp.certified_digest_maps()):
-                return True
+            maps = disp.certified_digest_maps()
+            tried += len(maps)
+            if self.store.apply_certification(maps):
+                return "certified", tried
             try:
                 await asyncio.wait_for(disp.certified_event.wait(), remaining)
             except asyncio.TimeoutError:
+                how = "timeout"
                 break
-        return self.store.apply_certification(disp.certified_digest_maps())
+        maps = disp.certified_digest_maps()
+        if self.store.apply_certification(maps):
+            how = "certified"
+        return how, tried + len(maps)
 
     @staticmethod
     def _cert_wait_bound(content_length: int) -> float:

@@ -200,7 +200,28 @@ class PieceDispatcher:
         gate passed (seed: full-digest validation; intermediate peer: its
         own certified chain)."""
         self.done_parents.add(peer_id)
+        if self.flight is not None:
+            p = self.parents.get(peer_id)
+            self.flight.record(flightlib.EV_PARENT_DONE,
+                               len(p.pieces) if p is not None else -1)
         self.certified_event.set()
+
+    def note_parent_spans(self, spans) -> None:
+        """A parent's own spans as its sync stream carried them
+        (``[[name, ms, piece], ...]``): each becomes ONE event of this
+        task's flight, stamped as it arrives, ``aux`` the parent's ms. The
+        field comes from another process: a name this side does not know or
+        an entry of another shape is passed over."""
+        if self.flight is None or not isinstance(spans, (list, tuple)):
+            return
+        for span in spans:
+            try:
+                name, ms, piece = span
+                code = flightlib.PARENT_SPANS.get(name)
+                if code is not None:
+                    self.flight.record(code, int(piece), float(ms))
+            except (TypeError, ValueError):
+                continue
 
     def certified_digest_maps(self) -> "list[dict[int, str]]":
         """EVERY done parent's non-empty digest map. Provenance matters:

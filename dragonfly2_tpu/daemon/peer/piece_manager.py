@@ -143,6 +143,17 @@ class PieceManager:
                 f"source download incomplete: {len(store.metadata.pieces)}/"
                 f"{store.metadata.total_piece_count} pieces", Code.BackToSourceAborted)
 
+    @staticmethod
+    def _stamp_first_byte(store: LocalTaskStore, piece: int, issued: float,
+                          note: str = "") -> None:
+        """One origin request's wait for its first body byte, on the flight
+        of the task that pulls: ``issued`` is ``time.monotonic()`` as the
+        request went out (connect included), ``piece`` the first piece the
+        request covers."""
+        flightlib.for_task(store.metadata.task_id).record(
+            flightlib.EV_SOURCE_FIRST_BYTE, piece,
+            (time.monotonic() - issued) * 1000.0, note)
+
     # -- native-engine span fetch (no Python byte handling) ----------------
 
     @staticmethod
@@ -181,6 +192,7 @@ class PieceManager:
             return False
         host, port, head = plan
         m = store.metadata
+        issued = time.monotonic()
         try:
             h = await native_connect(nb, host, port, 60000)
         except nb.NativeHttpError:
@@ -234,9 +246,16 @@ class PieceManager:
                     # Resume overlap: the bytes still arrive on this stream;
                     # drain without touching the already-verified piece.
                     await ncall(nb.http_read_to_file, h, -1, 0, take)
+                    crc = None
+                else:
+                    crc = await ncall(nb.http_read_to_file, h, dup_fd,
+                                      num * m.piece_size, take)
+                if num == first:
+                    # The engine hands back whole pieces: the first one's
+                    # arrival stands in for the first body byte.
+                    self._stamp_first_byte(store, first, issued, "native")
+                if crc is None:
                     continue
-                crc = await ncall(nb.http_read_to_file, h, dup_fd,
-                                  num * m.piece_size, take)
                 # Off-loop: record_piece's batched metadata save serializes
                 # the whole piece map — a loop stall if run inline.
                 cost_ms = int((time.monotonic() - t0) * 1000)
@@ -278,6 +297,7 @@ class PieceManager:
                     known_length, on_piece, limiter,
                     ranged=content_range is not None)):
             return
+        issued = time.monotonic()
         resp = await client.download(req)
         piece_size = store.metadata.piece_size
         num = 0
@@ -304,6 +324,8 @@ class PieceManager:
         try:
             try:
                 async for chunk in body:
+                    if not total:
+                        self._stamp_first_byte(store, 0, issued)
                     total += len(chunk)
                     cv = memoryview(chunk)
                     while len(cv):
@@ -394,6 +416,7 @@ class PieceManager:
                                              byte_len, on_piece, limiter,
                                              ranged=True):
                 return
+            issued = time.monotonic()
             resp = await client.download(req)
             if resp.status != 206:
                 await resp.close()
@@ -415,6 +438,8 @@ class PieceManager:
             try:
                 try:
                     async for chunk in body:
+                        if not got:
+                            self._stamp_first_byte(store, first, issued)
                         got += len(chunk)
                         cv = memoryview(chunk)
                         while len(cv):

@@ -8,7 +8,11 @@ the scheduler can blocklist them.
 Wire (drpc "Peer.SyncPieceTasks"):
   open_body: {task_id, src_peer_id (requester), dst_peer_id (parent)}
   parent → child: {pieces: [nums], total_piece_count, content_length,
-                   piece_size, done}
+                   piece_size, done, spans?}
+    spans (optional, absent when empty): [[name, ms, piece], ...], the
+    spans only the parent can measure that its flight closed since its last
+    message on this stream (pkg/flight SpanRelay): "source_first_byte",
+    "verified". The child stamps each on its own flight as parent_<name>.
   child → parent: {interested: true}   (keep-alive / request-more)
 """
 
@@ -111,6 +115,8 @@ class PieceTaskSynchronizer:
                     continue
                 if msg is None:
                     break
+                if msg.get("spans"):
+                    self.dispatcher.note_parent_spans(msg["spans"])
                 self.dispatcher.on_parent_pieces(
                     parent_peer_id,
                     msg.get("pieces") or [],

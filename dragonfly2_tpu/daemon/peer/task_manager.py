@@ -1219,9 +1219,16 @@ class TaskManager:
         else:
             COMPLETION_REHASH.labels("hashed").inc()
             tf = self.flight.task(store.metadata.task_id)
-            tf.record(flightlib.EV_VERIFY_START)
+            # Where the prefix hasher stands as the last piece has landed:
+            # what is still to hash is the whole of the wait below.
+            hashed = store.digest_frontier()
+            t0 = time.perf_counter()
+            tf.record(flightlib.EV_VERIFY_START, hashed, float(
+                max(0, store.metadata.total_piece_count - hashed)))
             await asyncio.to_thread(store.validate_digest, req.meta.digest)
-            tf.record(flightlib.EV_VERIFIED)
+            how, read_back = store.digest_pass
+            tf.record(flightlib.EV_VERIFIED, read_back,
+                      (time.perf_counter() - t0) * 1000.0, how)
         store.metadata.digest = req.meta.digest
 
     async def _finalize_device_for_seed(self, req: "FileTaskRequest",

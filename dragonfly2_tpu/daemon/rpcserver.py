@@ -250,10 +250,23 @@ class DaemonRpcServer:
             while await stream.recv() is not None:
                 pass
 
+        # What only this side can measure of the task while it runs here
+        # (its origin's first byte, its whole-object verify) rides the
+        # messages as an optional ``spans``; a task that was complete before
+        # the child came has kept nobody waiting and sends none.
+        tf = self.task_manager.flight.get(task_id) if running else None
+        relay = flightlib.SpanRelay(tf) if tf is not None else None
+
+        def with_spans(msg: dict) -> dict:
+            spans = relay.take() if relay is not None else None
+            if spans:
+                msg["spans"] = spans
+            return msg
+
         drainer = asyncio.ensure_future(drain_keepalives())
         try:
             if snapshot is not None:
-                await stream.send(snapshot)
+                await stream.send(with_spans(snapshot))
                 if snapshot["done"]:
                     return
             while True:
@@ -261,14 +274,14 @@ class DaemonRpcServer:
                 if event.failed:
                     raise DfError(Code.ClientPieceDownloadFail,
                                   "parent download failed")
-                await stream.send({
+                await stream.send(with_spans({
                     "pieces": event.piece_nums,
                     "total_piece_count": event.total_piece_count,
                     "content_length": event.content_length,
                     "piece_size": event.piece_size,
                     "done": event.done,
                     "digests": event.digests,
-                })
+                }))
                 if event.done:
                     return
         finally:

@@ -74,8 +74,8 @@ EV_LANDED = 9          # piece verified+recorded (aux=cost_ms, note=locality)
 EV_FAILED = 10         # piece attempt failed (note=typed reason)
 EV_STORE_START = 11    # store write handed to the executor
 EV_STORED = 12         # store write committed
-EV_VERIFY_START = 13   # completion whole-content re-hash started
-EV_VERIFIED = 14       # completion re-hash done
+EV_VERIFY_START = 13   # completion whole-content digest started (piece=pieces the prefix hasher had hashed, aux=pieces still to hash)
+EV_VERIFIED = 14       # ... done (aux=ms since verify_start, piece=pieces read back from the store, note=prefix|rehash)
 EV_PARENT_DROP = 15    # dispatcher dropped a parent (note=peer id)
 EV_QUARANTINE = 16     # parent entered quarantine (note=endpoint|reason)
 EV_STRIPE = 17         # stripe plan applied/cleared (aux=slice_size)
@@ -95,9 +95,12 @@ EV_GC_PAUSE = 28       # slow cyclic-GC pause during this task (aux=pause_s)
 # back theirs out). All stamped by the one df-device-sink thread, so a
 # span's children are the spans its interval contains.
 EV_SINK_LAND = 29      # one piece's on-thread work (piece=num)
-EV_SINK_READ = 30      # store.read_piece (piece=num)
-EV_SINK_CHECKSUM = 31  # host checksum of the piece (piece=num)
-EV_SINK_STAGE = 32     # flush: sort, zeroed stack, row copies (piece=lowest slot)
+# A piece the sink reads itself is ONE host pass (HBMSink.read_piece: each
+# helper reads its chunk from the store into the row and checksums it before
+# it returns); the two events are that pass's two shares, stamped together.
+EV_SINK_READ = 30      # the longest store read inside the pass (piece=num, note=chunks)
+EV_SINK_CHECKSUM = 31  # the pass less that read; or a checksum pass over bytes a caller brought (piece=num)
+EV_SINK_STAGE = 32     # what is left of staging: taking a stack, copying foreign bytes, zeroing a short tail, the batch's order (piece=num, lowest slot, or -1)
 EV_SINK_PUT = 33       # flush: the device_put call (piece=lowest slot)
 EV_SINK_ASSEMBLE = 34  # assembly dispatch -> checksums on host (piece=batches)
 EV_SINK_COMPILE = 35   # backend compile inside that assembly (piece=batches)
@@ -119,6 +122,18 @@ EV_SINK_VERIFY_CHIPS = 40  # per-chip checksums dispatched -> all compared (piec
 EV_ADMIT_WAIT = 41     # a device pull's wait at device_sinks.admit(), stamped as the task starts
 EV_SHARD_PLAN = 42     # download_sharded called -> header landed, parsed, spans planned (piece=ranged tasks planned)
 EV_SHARD_VIEWS = 43    # the typed views of every span dispatched (piece=tensors returned)
+# The completion path and the source client: ONE event at the span's end,
+# aux = its ms, as above. source_first_byte is on the flight of whichever
+# task pulls from the origin (a seed's, a peer's own back-to-source);
+# cert_wait and parent_done on a P2P child's. The parent_* pair are a
+# PARENT's spans, carried by its sync stream (``spans``, see SpanRelay) and
+# stamped on the child's flight as they arrive: durations on the parent's
+# clock, not intervals of the child's.
+EV_SOURCE_FIRST_BYTE = 44  # an origin request's first body byte (piece=first piece it covers, note="native": its first piece)
+EV_CERT_WAIT = 45      # conductor._await_certification returned (piece=digest maps tried, note=how it ended)
+EV_PARENT_DONE = 46    # a parent's sync stream said done: a point (piece=pieces that parent holds)
+EV_PARENT_SOURCE_FIRST_BYTE = 47  # the parent's source_first_byte (piece=the parent's first piece)
+EV_PARENT_VERIFIED = 48  # the parent's verify_start -> verified (piece=pieces its hasher had still to hash)
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -144,6 +159,10 @@ EVENT_NAMES = {
     EV_SINK_VERIFY_CHIPS: "sink_verify_chips",
     EV_ADMIT_WAIT: "admit_wait", EV_SHARD_PLAN: "shard_plan",
     EV_SHARD_VIEWS: "shard_views",
+    EV_SOURCE_FIRST_BYTE: "source_first_byte", EV_CERT_WAIT: "cert_wait",
+    EV_PARENT_DONE: "parent_done",
+    EV_PARENT_SOURCE_FIRST_BYTE: "parent_source_first_byte",
+    EV_PARENT_VERIFIED: "parent_verified",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING
@@ -163,6 +182,10 @@ _CLIENT_STEPS = (EV_ADMIT_WAIT, EV_SHARD_PLAN, EV_SHARD_VIEWS)
 # Chip-to-chip work of a landing: booked under ``ici`` beside the
 # intra-slice piece transfers.
 _ICI_STEPS = (EV_SINK_REPLICATE, EV_SINK_VERIFY_CHIPS)
+# A parent's spans as its sync stream names them -> the event a child
+# stamps for each (SpanRelay sends, PieceDispatcher.note_parent_spans stamps).
+PARENT_SPANS = {"source_first_byte": EV_PARENT_SOURCE_FIRST_BYTE,
+                "verified": EV_PARENT_VERIFIED}
 
 # Canonical phase model. ``other`` (residual uninstrumented time) rides
 # alongside so the fold partitions wall time exactly.
@@ -273,6 +296,19 @@ class TaskFlight:
         out.sort(key=operator.itemgetter(0))
         return out
 
+    def tail(self, seen: int) -> "tuple[list, int]":
+        """The events in the slots handed out since ``seen`` (an earlier
+        call's second value; 0: every retained one), in slot order, and the
+        count to pass next time. A slot another thread has taken and not
+        written yet is empty or, once the ring has wrapped, still holds the
+        event of a lap ago: the caller tells those by their time."""
+        total = self.events_total
+        ring, cap = self._ring, self._cap
+        out = [e for e in (ring[i % cap]
+                           for i in range(max(seen, total - cap), total))
+               if e is not None]
+        return out, total
+
     def finish(self, state: str, note: str = "") -> None:
         self.record(EV_TASK_DONE if state == "done" else EV_TASK_FAILED,
                     -1, 0.0, note)
@@ -302,6 +338,41 @@ class TaskFlight:
             out["dcn_ms"] = int(max(0.0, total - stall - store))
             out["stall_ms"] = int(stall)
         return out or None
+
+
+class SpanRelay:
+    """One sync stream's cursor over the PARENT's ring for a task: the two
+    spans only the parent can measure, each handed out once, for the next
+    message to the child (``spans``: ``[[name, ms, piece], ...]``).
+    ``source_first_byte`` goes as it is; ``verified`` goes with its
+    ``verify_start``'s pieces still to hash as its piece. Costs one pass over
+    the events recorded since the last message. Both names are stamped on the
+    event loop that also sends the messages, so they are written before they
+    are looked for and their times only grow."""
+
+    __slots__ = ("_tf", "_seen", "_t", "_behind")
+
+    def __init__(self, tf: TaskFlight):
+        self._tf = tf
+        self._seen = 0
+        self._t = -1.0       # newest time met so far: older is a lap ago
+        self._behind = -1
+
+    def take(self) -> list:
+        events, self._seen = self._tf.tail(self._seen)
+        floor, out = self._t, []
+        for t, code, piece, aux, _note in events:
+            if t <= floor:
+                continue
+            if t > self._t:
+                self._t = t
+            if code == EV_SOURCE_FIRST_BYTE:
+                out.append(["source_first_byte", round(aux, 3), piece])
+            elif code == EV_VERIFY_START:
+                self._behind = int(aux)
+            elif code == EV_VERIFIED:
+                out.append(["verified", round(aux, 3), self._behind])
+        return out
 
 
 # --------------------------------------------------------------------- #
@@ -448,6 +519,14 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
             t0 = open_marks.pop(("store", piece), None)
             if t0 is not None:
                 intervals.append((t0, t, "store"))
+        elif code == EV_SOURCE_FIRST_BYTE:
+            # The request's wait for the origin, before any piece of it
+            # could land.
+            intervals.append((max(0.0, t - aux / 1000.0), t, "origin"))
+        elif code == EV_CERT_WAIT:
+            # The wait for a verify: the parent's, whose done certifies
+            # this task's pieces (or, at its bound, nobody's).
+            intervals.append((max(0.0, t - aux / 1000.0), t, "verify"))
         elif code == EV_VERIFY_START:
             open_marks["verify"] = t
         elif code == EV_VERIFIED:
@@ -490,9 +569,22 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
     runtime: dict = {}
     hbm: dict = {}
     client: dict = {}
-    for _t, code, _p, aux, _n in events:
+    parent: dict = {}
+    for _t, code, piece, aux, note in events:
         name = EVENT_NAMES.get(code, str(code))
         counts[name] = counts.get(name, 0) + 1
+        if code == EV_CERT_WAIT:
+            parent["cert_wait_ms"] = round(
+                parent.get("cert_wait_ms", 0.0) + aux, 3)
+            parent["cert_wait"] = note
+        elif code == EV_PARENT_VERIFIED:
+            parent["verified_ms"] = round(aux, 3)
+            parent["hash_behind"] = piece
+        elif code == EV_PARENT_DONE:
+            parent["pieces"] = piece
+        elif code == EV_PARENT_SOURCE_FIRST_BYTE:
+            # The earliest: the wait before the parent's first piece.
+            parent.setdefault("source_first_byte_ms", round(aux, 3))
         if code in _SINK_STEPS:
             # Where a landing's time went, by step, whether or not it fell
             # inside the task's wall time (finalize runs after the
@@ -534,6 +626,9 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
                 for code in _SINK_STEPS if code in hbm},
         "client": {EVENT_NAMES[code] + "_ms": round(client[code], 3)
                    for code in _CLIENT_STEPS if code in client},
+        # A P2P child's completion: its wait for a certifying parent, and
+        # that parent's own spans as its sync stream carried them.
+        "parent": parent,
         "pieces": ordered[:max_waterfall],
         "pieces_truncated": truncated,
     }
@@ -588,6 +683,21 @@ def render_waterfall(report: dict) -> str:
                      "before the task; shard_*: the sharded pull this task "
                      "heads): " + " ".join(
                          f"{k[:-3]}={v:.1f}" for k, v in client.items()))
+    parent = report.get("parent") or {}
+    parts = []
+    if "cert_wait_ms" in parent:
+        parts.append(f"cert_wait={parent['cert_wait_ms']:.1f} ms "
+                     f"({parent['cert_wait']})")
+    if "verified_ms" in parent:
+        of = f" of {parent['pieces']}" if "pieces" in parent else ""
+        parts.append(f"seed verify {parent['verified_ms']:.1f} ms, "
+                     f"{parent['hash_behind']}{of} pieces behind")
+    if "source_first_byte_ms" in parent:
+        parts.append("origin first byte "
+                     f"{parent['source_first_byte_ms']:.1f} ms")
+    if parts:
+        lines.append("completion, the parent's spans on its own clock: "
+                     + "; ".join(parts))
     advisory = runtime_advisory(report)
     if advisory:
         lines.append(advisory)

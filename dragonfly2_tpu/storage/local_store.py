@@ -173,7 +173,9 @@ class _PrefixHasher:
         # arrives — observer raised mid-commit — only stalls us briefly).
         self._reserved: int | None = None
         self._reserved_at = 0.0
-        self.disk_reads = 0   # pieces the background thread pread (telemetry)
+        # Pieces the background thread read back from the store: what the
+        # frontier could not take from memory (``verified``'s piece).
+        self.disk_reads = 0
         self._thread = threading.Thread(
             target=self._run, daemon=True,
             name=f"df-prefix-hash-{store.metadata.task_id[:12]}")
@@ -354,6 +356,9 @@ class LocalTaskStore:
         # Optional background contiguous-prefix hasher (back-source tasks
         # with a known content digest — see _PrefixHasher).
         self._prefix_hasher: _PrefixHasher | None = None
+        # How the last validate_digest got its digest, for the flight's
+        # ``verified``: ("prefix" | "rehash", pieces it read from the store).
+        self.digest_pass: tuple[str, int] = ("", 0)
 
     # -- pinning: GC must not reclaim a store mid-download/upload ----------
 
@@ -684,6 +689,12 @@ class LocalTaskStore:
         except (ValueError, StorageError, OSError):
             return
 
+    def digest_frontier(self) -> int:
+        """Pieces the prefix hasher has hashed by now; 0 without one (the
+        whole object is then still to hash)."""
+        ph = self._prefix_hasher
+        return ph._next if ph is not None else 0
+
     @staticmethod
     def completion_digest_applies(digest: str, ranged: bool) -> bool:
         """Would the completion-time whole-content digest decision run at
@@ -943,6 +954,7 @@ class LocalTaskStore:
             prefix_hex = ph.finish(
                 timeout=max(60.0, cl / (50 << 20)) if cl > 0 else 60.0)
             if prefix_hex is not None:
+                self.digest_pass = ("prefix", ph.disk_reads)
                 actual = f"{algorithm}:{prefix_hex}"
                 if want and actual != want:
                     raise StorageError(
@@ -967,6 +979,7 @@ class LocalTaskStore:
                     remaining -= take
         finally:
             _READ_BUFFERS.release(mv)
+        self.digest_pass = ("rehash", len(self.metadata.pieces))
         actual = f"{algorithm}:{h.hexdigest()}"
         if want and actual != want:
             raise StorageError(f"content digest mismatch: want {want}, got {actual}",

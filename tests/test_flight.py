@@ -220,6 +220,165 @@ class TestAnalyzer:
 
 
 # --------------------------------------------------------------------- #
+# The completion path: cert_wait, the parent's spans, the sync stream's relay
+# --------------------------------------------------------------------- #
+
+COMPLETION_EVENTS = {
+    flight.EV_SOURCE_FIRST_BYTE: "source_first_byte",
+    flight.EV_CERT_WAIT: "cert_wait",
+    flight.EV_PARENT_DONE: "parent_done",
+    flight.EV_PARENT_SOURCE_FIRST_BYTE: "parent_source_first_byte",
+    flight.EV_PARENT_VERIFIED: "parent_verified",
+}
+
+# A cold pull as the peer sees it: scheduled, the seed's first announcement
+# after 4 s, two pieces, then 1.8 s in _await_certification for the seed's
+# done, which carries the seed's verify.
+COLD_PULL = [
+    (0.00, flight.EV_REGISTER, -1, 0.0, ""),
+    (0.01, flight.EV_SCHEDULED, -1, 0.0, "normal"),
+    (4.00, flight.EV_PARENT_SOURCE_FIRST_BYTE, 0, 3950.0, ""),
+    (4.00, flight.EV_PARENT_SOURCE_FIRST_BYTE, 14, 3990.0, ""),
+    (4.00, flight.EV_PARENT_PIECES, 0, 1.0, ""),
+    (4.01, flight.EV_REQUEST, 0, 0.0, "s:1"),
+    (4.50, flight.EV_LANDED, 0, 490.0, "cross"),
+    (4.50, flight.EV_REQUEST, 1, 0.0, "s:1"),
+    (5.00, flight.EV_LANDED, 1, 500.0, "cross"),
+    (6.79, flight.EV_PARENT_VERIFIED, 41, 1750.0, ""),
+    (6.79, flight.EV_PARENT_DONE, 55, 0.0, ""),
+    (6.80, flight.EV_CERT_WAIT, 1, 1800.0, "certified"),
+]
+
+
+class TestCompletionSpans:
+    @pytest.mark.parametrize("code,name", sorted(COMPLETION_EVENTS.items()))
+    def test_names_round_trip(self, code, name):
+        assert flight.EVENT_NAMES[code] == name
+        assert [c for c, n in flight.EVENT_NAMES.items() if n == name] == [code]
+        tf = flight.TaskFlight("names")
+        tf.record(code, 3, 12.5, "x")
+        (_, got, piece, aux, note), = tf.events()
+        assert (got, piece, aux, note) == (code, 3, 12.5, "x")
+
+    def test_parent_spans_name_the_child_events(self):
+        assert flight.PARENT_SPANS == {
+            "source_first_byte": flight.EV_PARENT_SOURCE_FIRST_BYTE,
+            "verified": flight.EV_PARENT_VERIFIED}
+        for name, code in flight.PARENT_SPANS.items():
+            assert flight.EVENT_NAMES[code] == "parent_" + name
+
+    def test_cert_wait_is_booked_as_verify_and_leaves_other(self):
+        without = flight.analyze(synthetic(COLD_PULL[:-1], wall=6.81))
+        with_it = flight.analyze(synthetic(COLD_PULL, wall=6.81))
+        assert without["phases"]["verify"] == 0.0
+        assert with_it["phases"]["verify"] == pytest.approx(1.8)
+        assert without["other_s"] - with_it["other_s"] == pytest.approx(1.8)
+        for rep in (without, with_it):
+            assert sum(rep["phases"].values()) + rep["other_s"] == \
+                pytest.approx(rep["wall_s"])
+        # The parent's spans are durations on ITS clock: no phase of this
+        # task moves by them.
+        bare = [e for e in COLD_PULL if e[1] not in COMPLETION_EVENTS]
+        assert flight.analyze(synthetic(bare, wall=6.81))["phases"] == \
+            without["phases"]
+
+    def test_own_first_byte_wait_is_origin_time(self):
+        rep = flight.analyze(synthetic([
+            (0.0, flight.EV_BACK_SOURCE, -1, 0.0, ""),
+            (2.0, flight.EV_SOURCE_FIRST_BYTE, 0, 1990.0, ""),
+            (2.5, flight.EV_SOURCE_LANDED, 0, 500.0, ""),
+        ], wall=2.5))
+        assert rep["phases"]["origin"] == pytest.approx(2.49)
+        assert rep["other_s"] == pytest.approx(0.01)
+        assert rep["parent"] == {}
+
+    def test_parent_block_and_the_explain_line(self):
+        rep = flight.analyze(synthetic(COLD_PULL, wall=6.81))
+        assert rep["parent"] == {
+            "cert_wait_ms": 1800.0, "cert_wait": "certified",
+            "verified_ms": 1750.0, "hash_behind": 41, "pieces": 55,
+            "source_first_byte_ms": 3950.0}
+        assert rep["event_counts"]["parent_source_first_byte"] == 2
+        text = flight.render_waterfall(rep)
+        assert ("cert_wait=1800.0 ms (certified); seed verify 1750.0 ms, "
+                "41 of 55 pieces behind; origin first byte 3950.0 ms") in text
+        # A pull that ends at its bound has no parent span to show.
+        timed_out = [e for e in COLD_PULL[:-3]] + [
+            (8.0, flight.EV_CERT_WAIT, 0, 3000.0, "timeout")]
+        text = flight.render_waterfall(
+            flight.analyze(synthetic(timed_out, wall=8.0)))
+        assert "cert_wait=3000.0 ms (timeout); origin first byte" in text
+        assert "seed verify" not in text
+
+    def test_a_report_without_the_events_is_unchanged(self):
+        bare = [e for e in COLD_PULL if e[1] not in COMPLETION_EVENTS]
+        rep = flight.analyze(synthetic(bare, wall=6.81))
+        assert rep["parent"] == {}
+        assert rep["other_s"] == pytest.approx(6.81 - 0.01 - 0.99)
+        text = flight.render_waterfall(rep)
+        assert "cert_wait" not in text and "completion" not in text
+        # A parent that only said done (a task with no digest to certify)
+        # adds no line either.
+        done_only = bare + [(5.0, flight.EV_PARENT_DONE, 55, 0.0, "")]
+        rep = flight.analyze(synthetic(done_only, wall=6.81))
+        assert rep["parent"] == {"pieces": 55}
+        assert flight.render_waterfall(rep) == text
+
+
+class TestSpanRelay:
+    def test_a_span_is_sent_once(self):
+        tf = flight.TaskFlight("seed")
+        relay = flight.SpanRelay(tf)
+        assert relay.take() == []
+        tf.record(flight.EV_BACK_SOURCE)
+        tf.record(flight.EV_SOURCE_FIRST_BYTE, 0, 40.0, "native")
+        tf.record(flight.EV_SOURCE_FIRST_BYTE, 14, 55.5)
+        tf.record(flight.EV_SOURCE_LANDED, 0, 45.0)
+        assert relay.take() == [["source_first_byte", 40.0, 0],
+                                ["source_first_byte", 55.5, 14]]
+        tf.record(flight.EV_SOURCE_LANDED, 1, 45.0)
+        assert relay.take() == []            # the next message carries none
+        tf.record(flight.EV_VERIFY_START, 14, 41.0)
+        assert relay.take() == []            # a verify still open is no span
+        tf.record(flight.EV_VERIFIED, 40, 1750.25, "prefix")
+        assert relay.take() == [["verified", 1750.25, 41]]
+        assert relay.take() == []
+
+    def test_a_stream_opened_late_gets_what_closed_before_it(self):
+        tf = flight.TaskFlight("seed")
+        tf.record(flight.EV_SOURCE_FIRST_BYTE, 0, 40.0)
+        first, late = flight.SpanRelay(tf), flight.SpanRelay(tf)
+        assert first.take() == [["source_first_byte", 40.0, 0]]
+        assert late.take() == [["source_first_byte", 40.0, 0]]
+
+    def test_a_wrapped_ring_resends_nothing(self):
+        """A slot another thread has taken and not yet written still holds
+        the event of a lap ago: the relay tells it by its time."""
+        tf = flight.TaskFlight("seed", capacity=8)
+        relay = flight.SpanRelay(tf)
+        tf.record(flight.EV_SOURCE_FIRST_BYTE, 0, 40.0)
+        assert len(relay.take()) == 1
+        for n in range(7):
+            tf.record(flight.EV_SOURCE_LANDED, n, 1.0)
+        assert relay.take() == []
+        next(tf._seq)     # slot 0 handed out again, the old event still in it
+        assert relay.take() == []
+        assert flight.SpanRelay(tf).take() == [["source_first_byte", 40.0, 0]]
+
+    def test_tail_is_by_slot_and_bounded_by_the_ring(self):
+        tf = flight.TaskFlight("t", capacity=4)
+        for n in range(3):
+            tf.record(flight.EV_LANDED, n, 1.0)
+        events, seen = tf.tail(0)
+        assert [e[2] for e in events] == [0, 1, 2] and seen == 3
+        assert tf.tail(seen) == ([], 3)
+        for n in range(3, 9):
+            tf.record(flight.EV_LANDED, n, 1.0)
+        events, seen = tf.tail(seen)
+        assert [e[2] for e in events] == [5, 6, 7, 8] and seen == 9
+
+
+# --------------------------------------------------------------------- #
 # Post-mortem bundles
 # --------------------------------------------------------------------- #
 

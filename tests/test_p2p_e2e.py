@@ -598,6 +598,51 @@ class TestAwaitCertification:
 
         run_async(body(), timeout=10)
 
+    @pytest.mark.parametrize("ending", ["certified", "timeout",
+                                        "no_certifier", "unverifiable",
+                                        "digest_does_not_apply"])
+    def test_every_ending_stamps_one_cert_wait(self, run_async, ending):
+        """However the stay in _await_certification ends it leaves ONE
+        ``cert_wait`` on the task's flight (aux = its ms, piece = digest
+        maps tried, note = the ending) and its seconds on the counter; a
+        task whose completion digest does not apply leaves nothing."""
+        from dragonfly2_tpu.daemon.peer.conductor import CERT_WAIT_SECONDS
+        from dragonfly2_tpu.pkg import flight
+
+        async def body():
+            applies = ending != "digest_does_not_apply"
+            c = _await_cert_conductor(
+                1 << 20, {"digest": "sha256:x"} if applies else {},
+                pieces_verified=ending != "unverifiable")
+            c.flight = c.dispatcher.flight = flight.TaskFlight("cert-wait")
+            if ending != "no_certifier":
+                c.dispatcher.upsert_parent("seed", "10.0.0.1", 1)
+            if ending == "certified":
+                c.dispatcher.on_parent_pieces(
+                    "seed", [0], digests={0: "crc32c:0000000a"})
+                c.dispatcher.note_parent_done("seed")
+            before = {how: CERT_WAIT_SECONDS.labels(how)._value.get()
+                      for how in ("certified", "timeout", "no_certifier",
+                                  "unverifiable")}
+            assert await c._await_certification() is (ending == "certified")
+            stamped = [(piece, aux, note)
+                       for _, code, piece, aux, note in c.flight.events()
+                       if code == flight.EV_CERT_WAIT]
+            moved = {how: CERT_WAIT_SECONDS.labels(how)._value.get() - was
+                     for how, was in before.items()}
+            if not applies:
+                assert stamped == [] and not any(moved.values())
+                return
+            (tried, ms, note), = stamped
+            assert note == ending
+            assert tried == (1 if ending == "certified" else 0)
+            # 1 MiB: the bound is 52 ms, and only the timeout sits it out.
+            assert (40.0 <= ms < 1500.0) if ending == "timeout" else ms < 40.0
+            assert moved.pop(ending) == pytest.approx(ms / 1000.0)
+            assert not any(moved.values())
+
+        run_async(body(), timeout=10)
+
 
 def test_ranged_task_seed_trigger_fetches_the_slice(run_async, tmp_path):
     """A ranged dfget through a scheduler with a live seed: the triggered
@@ -783,3 +828,201 @@ def test_warm_pull_skips_whole_content_rehash(run_async, tmp_path, monkeypatch):
             await origin.cleanup()
 
     run_async(body(), timeout=120)
+
+
+# --------------------------------------------------------------------- #
+# The parent's spans on the sync stream (pkg/flight SpanRelay -> the
+# child's parent_source_first_byte / parent_verified)
+# --------------------------------------------------------------------- #
+
+NEW_EVENTS = ("source_first_byte", "cert_wait", "parent_done",
+              "parent_source_first_byte", "parent_verified")
+
+
+def _named(tf, *names):
+    """(piece, aux, note) of the flight's events of these names."""
+    from dragonfly2_tpu.pkg import flight
+
+    return [(piece, aux, note) for _, code, piece, aux, note in tf.events()
+            if flight.EVENT_NAMES[code] in names]
+
+
+@pytest.mark.parametrize("wire", ["relayed", "parent_sends_none",
+                                  "child_ignores_the_field"])
+def test_cold_pull_carries_the_seeds_spans(run_async, tmp_path, monkeypatch,
+                                           wire):
+    """A child trailing a seed that is still pulling from the origin: each
+    origin request's first byte and the seed's whole-object verify reach
+    the child's flight ONCE each, on the announcement of the piece and on
+    ``done``; the tail of the pull is booked as ``verify``. A parent that
+    sends no ``spans`` and a child that never reads the field pull just as
+    well. Afterwards a re-land on the child, and a pull by a second child
+    from the seed now complete, stamp no parent span."""
+    import time as _time
+
+    from dragonfly2_tpu.daemon.peer.conductor import PeerTaskConductor
+    from dragonfly2_tpu.daemon.peer.piece_dispatcher import PieceDispatcher
+    from dragonfly2_tpu.pkg import flight
+    from dragonfly2_tpu.storage.local_store import LocalTaskStore
+
+    real = LocalTaskStore.validate_digest
+
+    def slow_seed(self, expected=""):
+        if "/seed/" in self.dir:
+            _time.sleep(0.05)     # the child's last piece lands first
+        return real(self, expected)
+
+    monkeypatch.setattr(LocalTaskStore, "validate_digest", slow_seed)
+    monkeypatch.setattr(PeerTaskConductor, "_cert_wait_bound",
+                        staticmethod(lambda content_length: 5.0))
+    if wire == "parent_sends_none":
+        monkeypatch.setattr(flight.SpanRelay, "take", lambda self: [])
+    elif wire == "child_ignores_the_field":
+        monkeypatch.setattr(PieceDispatcher, "note_parent_spans",
+                            lambda self, spans: None)
+
+    async def body():
+        origin, oport, stats = await start_origin()
+        sched = await start_scheduler()
+        url = f"http://127.0.0.1:{oport}/blob"
+        daemons = []
+        try:
+            daemons.append(seed := await start_daemon(
+                tmp_path, "seed", sched.port(), seed=True))
+            daemons.append(p1 := await start_daemon(
+                tmp_path, "p1", sched.port()))
+            daemons.append(p2 := await start_daemon(
+                tmp_path, "p2", sched.port()))
+            # The seed keeps the process's recorder (its source client
+            # stamps there); each child gets a ring of its own.
+            p1.task_manager.flight = flight.FlightRecorder()
+            p2.task_manager.flight = flight.FlightRecorder()
+            seed_task = asyncio.ensure_future(
+                dfget_via(seed, url, str(tmp_path / "s.bin")))
+            for _ in range(500):
+                if any(s.metadata.pieces for s in seed.storage.tasks()):
+                    break
+                await asyncio.sleep(0.01)
+            r1 = await dfget_via(p1, url, str(tmp_path / "c.bin"))
+            assert (await seed_task)["state"] == "done"
+            assert r1["state"] == "done", r1
+            assert open(tmp_path / "c.bin", "rb").read() == CONTENT
+            task_id = r1["task_id"]
+
+            seed_tf = flight.get(task_id)
+            child_tf = p1.task_manager.flight.get(task_id)
+            sent = _named(seed_tf, "source_first_byte")
+            assert sent and all(piece == 0 and ms > 0 for piece, ms, _ in sent)
+            (frontier, behind, _), = _named(seed_tf, "verify_start")
+            total = len(seed.storage.try_get(task_id).metadata.pieces)
+            assert 0 <= frontier <= total and behind == total - frontier
+            (read_back, verify_ms, how), = _named(seed_tf, "verified")
+            assert how == "prefix" and verify_ms >= 50.0
+            assert 0 <= read_back <= total
+            assert not _named(seed_tf, "cert_wait", "parent_done",
+                              "parent_source_first_byte", "parent_verified")
+
+            (tried, waited_ms, note), = _named(child_tf, "cert_wait")
+            assert note == "certified" and tried >= 1
+            assert _named(child_tf, "parent_done") == [(total, 0.0, "")]
+            got = _named(child_tf, "parent_source_first_byte",
+                         "parent_verified")
+            report = flight.analyze(child_tf)
+            text = flight.render_waterfall(report)
+            if wire == "relayed":
+                # Each of the seed's spans once, with the seed's own ms.
+                assert sorted(got) == sorted(
+                    [(piece, round(ms, 3), "") for piece, ms, _ in sent]
+                    + [(int(behind), round(verify_ms, 3), "")])
+                assert report["parent"]["verified_ms"] == \
+                    round(verify_ms, 3)
+                assert f"seed verify {round(verify_ms, 3):.1f} ms" in text
+                assert "origin first byte" in text
+            else:
+                assert got == []
+                assert "seed verify" not in text
+            # (the report rounds seconds to the microsecond)
+            assert report["phases"]["verify"] >= waited_ms / 1000.0 - 1e-5
+            assert report["parent"]["cert_wait_ms"] == round(waited_ms, 3)
+            assert (f"cert_wait={round(waited_ms, 3):.1f} ms (certified)"
+                    in text)
+
+            # A re-land on the child: served from its store, nothing new.
+            counts = flight.analyze(child_tf)["event_counts"]
+            r = await dfget_via(p1, url, str(tmp_path / "again.bin"))
+            assert r["state"] == "done" and r["from_reuse"], r
+            after = flight.analyze(child_tf)["event_counts"]
+            assert {n: after.get(n, 0) for n in NEW_EVENTS} == \
+                {n: counts.get(n, 0) for n in NEW_EVENTS}
+
+            # A second child, the seed complete: its wait ends at once on
+            # the snapshot's done, and a parent nobody waited for sends
+            # no span.
+            r2 = await dfget_via(p2, url, str(tmp_path / "late.bin"))
+            assert r2["state"] == "done", r2
+            late_tf = p2.task_manager.flight.get(task_id)
+            (_, _, note), = _named(late_tf, "cert_wait")
+            assert note == "certified"
+            assert not _named(late_tf, "parent_source_first_byte",
+                              "parent_verified")
+        finally:
+            for d in daemons:
+                await d.stop()
+            await sched.stop()
+            await origin.cleanup()
+
+    run_async(body(), timeout=120)
+
+
+def test_sync_stream_tolerates_absent_unknown_and_malformed_spans(run_async):
+    """The child's side alone, against a parent that speaks the wire by
+    hand: a message with ``spans``, one without the field, one with a field
+    and span names this child has never heard of and entries of other
+    shapes. Every well-formed span of a known name becomes ONE event; the
+    rest is passed over and the stream lives on."""
+    from dragonfly2_tpu.daemon.peer.piece_dispatcher import PieceDispatcher
+    from dragonfly2_tpu.daemon.peer.synchronizer import PieceTaskSynchronizer
+    from dragonfly2_tpu.pkg import flight
+    from dragonfly2_tpu.pkg.types import NetAddr
+    from dragonfly2_tpu.rpc import Server
+
+    async def body():
+        base = {"total_piece_count": 3, "content_length": 3 << 20,
+                "piece_size": 1 << 20, "digests": {}}
+
+        async def handler(stream, ctx):
+            await stream.send({**base, "pieces": [0], "done": False,
+                               "spans": [["source_first_byte", 12.5, 0]]})
+            await stream.send({**base, "pieces": [1], "done": False})
+            await stream.send({
+                **base, "pieces": [2], "done": True, "sparks": {"x": 1},
+                "spans": [["verified", 80.25, 2], ["hashed", 1.0, 0],
+                          ["verified", "soon", 0], 7, ["verified"], None,
+                          [["verified"], 1.0, 0]]})
+
+        server = Server("test.parent.spans")
+        server.register_stream("Peer.SyncPieceTasks", handler)
+        await server.serve(NetAddr.tcp("127.0.0.1", 0))
+        try:
+            tf = flight.TaskFlight("t-spans")
+            dispatcher = PieceDispatcher(flight=tf)
+            sync = PieceTaskSynchronizer("t-spans", "child", dispatcher)
+            dispatcher.upsert_parent("parent-1", "127.0.0.1", 9000)
+            task = asyncio.ensure_future(
+                sync._sync_one("parent-1", "127.0.0.1", server.port()))
+            sync._tasks["parent-1"] = task
+            await asyncio.wait_for(task, 10)
+            p = dispatcher.parents["parent-1"]
+            assert p.pieces == {0, 1, 2} and not p.blocked
+            assert "parent-1" in dispatcher.done_parents
+            assert [(flight.EVENT_NAMES[code], piece, aux)
+                    for _, code, piece, aux, _ in tf.events()
+                    if code != flight.EV_PARENT_PIECES] == [
+                ("parent_source_first_byte", 0, 12.5),
+                ("parent_verified", 2, 80.25),
+                ("parent_done", 3, 0.0)]
+            await sync.close()
+        finally:
+            await server.close()
+
+    run_async(body(), timeout=30)

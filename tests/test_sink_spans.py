@@ -293,6 +293,8 @@ def load_reader(monkeypatch, metric: str):
     load("reduce_trace", "reduce_trace.py")
     package.sink_events = load("layers.sink_events", "layers",
                                "sink_events.py")
+    package.ranged_events = load("layers.ranged_events", "layers",
+                                 "ranged_events.py")
     return load("layers." + metric, "layers", metric + ".py").read
 
 
@@ -632,3 +634,82 @@ def test_daemon_without_a_sink_still_imports_no_jax():
     done = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, timeout=120)
     assert done.returncode == 0, done.stderr[-2000:]
+
+
+# -- the completion path's readers (PR 36) ----------------------------------
+# cert_wait / parent_* come from the conductor and the sync stream, not from
+# the sink; their readers are held to synthetic flights as the sink's are.
+
+COMPLETION_READERS = ("cert_wait_ms", "seed_verify_ms", "seed_hash_behind_pct",
+                      "origin_first_byte_ms")
+
+
+def _op(events, nbytes=55 << 20, piece_bytes=1 << 20, ranged=None):
+    """An operation as a driver leaves it: ``flight`` is (t, name, piece,
+    aux) by time; ``ranged`` one ``{"flight": ...}`` a task where the
+    operation was a sharded pull."""
+    op = types.SimpleNamespace(flight=sorted(events), nbytes=nbytes,
+                               piece_bytes=piece_bytes)
+    if ranged is not None:
+        op.ranged = [{"flight": sorted(f)} for f in ranged]
+        op.flight = sorted(e for f in ranged for e in f)
+    return op
+
+
+def _cold(first_byte, verify, behind, waited):
+    """One task's flight: four origin groups' first bytes, the last of
+    them stamped first, then the seed's verify on ``done``."""
+    return [(1.0, "scheduled", -1, 0.0),
+            (4.99, "parent_source_first_byte", 41, first_byte + 30.0),
+            (5.0, "parent_source_first_byte", 0, first_byte),
+            (5.1, "parent_pieces", 0, 1.0),
+            (7.9, "parent_verified", behind, verify),
+            (7.9, "parent_done", 55, 0.0),
+            (8.0, "cert_wait", 1, waited),
+            (8.0, "task_done", -1, 0.0)]
+
+
+@pytest.mark.parametrize("metric,value", [
+    ("cert_wait_ms", 1800.0),              # median of 1700, 1800, 1900
+    ("seed_verify_ms", 1750.0),
+    ("seed_hash_behind_pct", 100.0 * 41 / 55),
+    # The earliest event of the task, not the smallest wait.
+    ("origin_first_byte_ms", 4030.0),
+])
+def test_completion_readers_over_one_task_operations(monkeypatch, metric,
+                                                     value):
+    run = types.SimpleNamespace(ops=[
+        _op(_cold(3900.0, 1650.0, 38, 1700.0)),
+        _op(_cold(4000.0, 1750.0, 41, 1800.0)),
+        _op(_cold(4100.0, 1850.0, 44, 1900.0)),
+        _op([(1.0, "scheduled", -1, 0.0)]),       # stamped none: left out
+    ])
+    assert load_reader(monkeypatch, metric)(run) == pytest.approx(value)
+
+
+def test_origin_first_byte_sums_a_ranged_operations_tasks(monkeypatch):
+    """A sharded pull: the earliest first byte of each of its tasks,
+    summed; a task that met a seed already complete carries none."""
+    tasks = [[(1.0, "parent_source_first_byte", 0, 12.0)],
+             [(2.0, "parent_source_first_byte", 7, 610.0),
+              (2.1, "parent_source_first_byte", 0, 400.0)],
+             [(3.0, "parent_pieces", 0, 1.0)]]
+    read = load_reader(monkeypatch, "origin_first_byte_ms")
+    run = types.SimpleNamespace(ops=[_op([], ranged=tasks)])
+    assert read(run) == pytest.approx(12.0 + 610.0)
+    # The merged flight is not what is read: the tasks stay apart.
+    assert len(run.ops[0].flight) == 4
+
+
+@pytest.mark.parametrize("metric", COMPLETION_READERS)
+def test_completion_readers_read_nothing_without_their_events(monkeypatch,
+                                                              metric):
+    """A re-land, a task with no digest, a program older than the events:
+    None, and the result line leaves the metric out."""
+    read = load_reader(monkeypatch, metric)
+    bare = [(1.0, "scheduled", -1, 0.0), (1.5, "parent_pieces", 0, 1.0),
+            (2.0, "hbm_landed", 0, 0.0), (2.1, "task_done", -1, 0.0)]
+    assert read(types.SimpleNamespace(ops=[])) is None
+    assert read(types.SimpleNamespace(ops=[_op(bare), _op([])])) is None
+    assert read(types.SimpleNamespace(
+        ops=[_op([], ranged=[bare, []])])) is None
