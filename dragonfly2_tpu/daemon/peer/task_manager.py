@@ -1226,9 +1226,16 @@ class TaskManager:
             tf.record(flightlib.EV_VERIFY_START, hashed, float(
                 max(0, store.metadata.total_piece_count - hashed)))
             await asyncio.to_thread(store.validate_digest, req.meta.digest)
-            how, read_back = store.digest_pass
-            tf.record(flightlib.EV_VERIFIED, read_back,
-                      (time.perf_counter() - t0) * 1000.0, how)
+            how, read_back, (ready, waited) = store.digest_pass
+            ms = (time.perf_counter() - t0) * 1000.0
+            tf.record(flightlib.EV_VERIFIED, read_back, ms, how)
+            # ready / (ready + waited) near 1: sha256 was the wait's limit;
+            # near 0: the read-back was (store_digest_chunks_total).
+            log.info("content digest verified",
+                     task_id=store.metadata.task_id[:16], how=how,
+                     ms=round(ms, 1), hashed_before=hashed,
+                     read_back=read_back, chunks_ready=ready,
+                     chunks_waited=waited)
         store.metadata.digest = req.meta.digest
 
     async def _finalize_device_for_seed(self, req: "FileTaskRequest",

@@ -19,6 +19,7 @@ from dataclasses import asdict, dataclass, field
 from itertools import accumulate
 
 from dragonfly2_tpu.pkg import digest as pkgdigest
+from dragonfly2_tpu.pkg import metrics
 from dragonfly2_tpu.pkg.bufpool import BufferPool
 from dragonfly2_tpu.pkg.errors import Code, StorageError
 from dragonfly2_tpu.pkg.piece import compute_piece_count
@@ -47,6 +48,20 @@ def release_read_buffer(view) -> None:
 
 def read_buffer_stats() -> dict:
     return _READ_BUFFERS.stats()
+
+
+def _preadv_exact(fd: int, view: memoryview, offset: int) -> None:
+    """Fill ``view`` with the file's bytes from ``offset``; a short read
+    (EOF inside the span) raises StorageError."""
+    got = 0
+    while got < len(view):
+        n = os.preadv(fd, [view[got:]], offset + got)
+        if n <= 0:
+            raise StorageError(
+                f"short read at offset {offset + got}: "
+                f"{got}/{len(view)} bytes (EOF)")
+        got += n
+
 
 _NATIVE = None
 _NATIVE_PROBED = False
@@ -129,6 +144,166 @@ class TaskStoreMetadata:
         )
 
 
+# The completion digest's ring (see _ReadAhead): chunks of _CHUNK bytes, at
+# most _RING_DEPTH of them out of the pool at once (being filled, filled, or
+# under the hash), filled by _RING_READERS threads. Constants from
+# benchmarks/digest_probe.py on the chip's host (PERF.md section 5), not
+# settings.
+_CHUNK = 4 << 20
+_RING_DEPTH = 4
+_RING_READERS = 1
+
+DIGEST_CHUNKS = metrics.counter(
+    "store_digest_chunks_total",
+    "Completion-digest chunks the hashing thread found read ahead (ready) "
+    "against chunks it had to wait for (waited)", ("how",))
+_CHUNKS_READY = DIGEST_CHUNKS.labels("ready")
+_CHUNKS_WAITED = DIGEST_CHUNKS.labels("waited")
+
+
+class _ReadAhead:
+    """The one pipelined read+hash loop of the completion digest: the
+    thread that hashes never reads.
+
+    ``_RING_READERS`` threads (``df-prefix-read-*``) walk the spans that
+    ``next_span`` hands out, cut them into ``_CHUNK`` requests, and fill
+    pooled buffers with ``os.preadv`` from a private O_RDONLY fd (the
+    store's own may be GC-closed mid-life); ``hash_into``, on the caller's
+    thread, takes the filled buffers in request order, updates the hasher,
+    and hands each back to the pool. Both calls release the GIL, so chunk
+    k+1 is copied while chunk k is hashed. No ``mmap``: a file truncated
+    under a mapping is a SIGBUS, where a short ``preadv`` is an error that
+    ``err`` carries.
+
+    ``next_span`` is called under ``cv`` (the owner's own condition, so its
+    frontier and the ring change together) and returns ``(offset, size,
+    tag)``, or None when the next span is not there yet, or raises
+    StopIteration when there will be no more. ``close`` is idempotent and
+    does not block: filled buffers go back to the pool at once, a buffer in
+    a thread's hands when that thread next looks, the fd with the last
+    reader out."""
+
+    def __init__(self, path: str, cv: threading.Condition, next_span,
+                 name: str):
+        self._cv = cv
+        self._next_span = next_span
+        self._fd = os.open(path, os.O_RDONLY)
+        self._cut: tuple[int, int, object] | None = None  # span being cut
+        self._issued = 0     # chunk requests taken by a reader
+        self._hashed = 0     # chunks hashed and back in the pool
+        self._filled: dict[int, tuple] = {}   # request -> (view, size, tag)
+        self._readers = _RING_READERS
+        self.closed = False
+        self.err: str | None = None
+        # Chunks hash_into found filled when it asked / had to wait for.
+        self.ready = 0
+        self.waited = 0
+        self.threads = [
+            threading.Thread(target=self._read, daemon=True,
+                             name=f"df-prefix-read-{name}")
+            for _ in range(_RING_READERS)]
+        for t in self.threads:
+            t.start()
+
+    def close(self) -> None:
+        with self._cv:
+            self.closed = True
+            for view, _, _ in self._filled.values():
+                _READ_BUFFERS.release(view)
+            self._filled.clear()
+            self._cv.notify_all()
+
+    def fail(self, why: str) -> None:
+        with self._cv:
+            if self.err is None:
+                self.err = why
+            self.close()
+
+    def _read(self) -> None:
+        cv = self._cv
+        try:
+            while True:
+                with cv:
+                    while True:
+                        if self.closed:
+                            return
+                        if self._issued - self._hashed < _RING_DEPTH:
+                            if self._cut is None:
+                                self._cut = self._next_span()
+                            if self._cut is not None:
+                                break
+                        # Timed: total_piece_count can be set by
+                        # update_task without a piece commit notifying.
+                        cv.wait(timeout=1.0)
+                    off, left, tag = self._cut
+                    take = min(left, _CHUNK)
+                    if left > take:
+                        self._cut = (off + take, left - take, tag)
+                        tag = None   # only a span's last chunk carries it
+                    else:
+                        self._cut = None
+                    request = self._issued
+                    self._issued += 1
+                view = _READ_BUFFERS.acquire(take)
+                try:
+                    _preadv_exact(self._fd, view[:take], off)
+                except BaseException:
+                    _READ_BUFFERS.release(view)
+                    raise
+                with cv:
+                    if self.closed:
+                        _READ_BUFFERS.release(view)
+                        return
+                    self._filled[request] = (view, take, tag)
+                    cv.notify_all()
+        except StopIteration:
+            pass
+        except Exception as e:  # noqa: BLE001 - carried by err; caller re-hashes
+            self.fail(str(e))
+        finally:
+            with cv:
+                self._readers -= 1
+                if not self._readers:
+                    os.close(self._fd)
+                cv.notify_all()
+
+    def hash_into(self, h, span_done=None) -> None:
+        """The hashing side, on the caller's thread: returns when every
+        span has been hashed or the ring is closed (``closed`` / ``err``
+        say which; it does not raise). ``span_done(tag)`` is called under
+        ``cv`` as a span's last chunk has been hashed."""
+        cv = self._cv
+        try:
+            while True:
+                with cv:
+                    filled = self._filled.pop(self._hashed, None)
+                    ready = filled is not None
+                    while filled is None:
+                        if self.closed or (not self._readers
+                                           and self._issued == self._hashed):
+                            return
+                        cv.wait(timeout=1.0)
+                        filled = self._filled.pop(self._hashed, None)
+                if ready:
+                    self.ready += 1
+                    _CHUNKS_READY.inc()
+                else:
+                    self.waited += 1
+                    _CHUNKS_WAITED.inc()
+                view, size, tag = filled
+                try:
+                    h.update(view[:size])   # GIL released for >2 KiB
+                finally:
+                    _READ_BUFFERS.release(view)
+                with cv:
+                    self._hashed += 1
+                    if tag is not None and span_done is not None:
+                        span_done(tag)
+                    cv.notify_all()
+        except Exception as e:  # noqa: BLE001 - carried by err; caller re-hashes
+            self.fail(str(e))
+
+
 class _PrefixHasher:
     """Background contiguous-prefix hasher: overlaps the completion-time
     whole-content digest with the download itself.
@@ -141,78 +316,91 @@ class _PrefixHasher:
     serial tail into overlap. P2P children keep the certification skip and
     never start one of these.
 
-    Owns a private O_RDONLY fd (the store's fd may be GC-closed mid-life).
-    Only committed pieces are read — commitment is the store's byte-
-    finality point. Any anomaly (re-recorded piece below the frontier,
-    short read, fd error) poisons the hasher; ``finish`` then returns None
-    and the caller falls back to the normal full re-hash, so this is an
-    optimization that can only be bypassed, never wrong.
+    The background side is two roles (``_ReadAhead``). The READER claims
+    the committed piece at the read frontier ``_read_next`` — commitment is
+    the store's byte-finality point — and copies it into the ring; the
+    HASHER (``df-prefix-hash-*``) takes the ring's chunks in order and
+    advances the hash frontier ``_next`` as a piece's last chunk is done.
+    Pieces in ``[_next, _read_next)`` are the reader's: copied, or being
+    copied, outside the lock. Any anomaly (a piece re-recorded at or behind
+    the READ frontier, a short read, an fd error) poisons the hasher;
+    ``finish`` then returns None and the caller falls back to the full
+    re-hash, so this is an optimization that can only be bypassed, never
+    wrong.
 
     Zero-copy feed: when the committing writer still holds the piece's
     bytes in memory (the Python receive paths), it hands them to ``feed``
-    right after the commit and the frontier advances WITHOUT re-reading
+    right after the commit and both frontiers advance WITHOUT re-reading
     landed bytes from disk — the hash runs in the writer's worker thread,
-    over memory it owns for the duration of the call. The background
-    thread only ever preads pieces that never came through memory
-    (native-engine landings, out-of-order arrivals)."""
+    over memory it owns for the duration of the call. Only a piece the
+    reader has not claimed is fed (``_next == _read_next``: the ring is
+    empty), and the reader claims none while a feed runs, so no piece is
+    hashed twice or skipped. The reader only ever preads pieces that never
+    came through memory (native-engine landings, out-of-order arrivals)."""
 
     def __init__(self, store: "LocalTaskStore", algorithm: str):
         self.store = store
         self.algorithm = algorithm
         self._h = pkgdigest.new_hasher(algorithm)
-        self._next = 0
-        self._err: str | None = None
+        self._next = 0         # hash frontier: pieces [0, _next) are in _h
+        self._read_next = 0    # read frontier: the reader's next claim
         self._cv = threading.Condition()
-        self._stop = False
-        # Frontier claim: exactly one hasher (a feed() caller or the
-        # background thread) may advance _next at a time.
-        self._busy = False
+        # A feed() is hashing piece _next outside the lock.
+        self._feeding = False
         # Commit→feed handshake: a commit that WILL be followed by a feed
-        # of the frontier piece reserves it so the background thread does
-        # not race in and pread it first (stamped so a feed that never
-        # arrives — observer raised mid-commit — only stalls us briefly).
+        # of the frontier piece reserves it so the reader does not race in
+        # and pread it first (stamped so a feed that never arrives —
+        # observer raised mid-commit — only stalls us briefly).
         self._reserved: int | None = None
         self._reserved_at = 0.0
-        # Pieces the background thread read back from the store: what the
-        # frontier could not take from memory (``verified``'s piece).
+        # Pieces the reader read back from the store: what the frontier
+        # could not take from memory (``verified``'s piece).
         self.disk_reads = 0
+        # Chunks (ready, waited) of the ring inside finish(): the tail.
+        self.tail_chunks = (0, 0)
+        name = store.metadata.task_id[:12]
+        self._ring = _ReadAhead(store._data_path, self._cv, self._claim, name)
         self._thread = threading.Thread(
-            target=self._run, daemon=True,
-            name=f"df-prefix-hash-{store.metadata.task_id[:12]}")
+            target=self._ring.hash_into, args=(self._h, self._piece_hashed),
+            daemon=True, name=f"df-prefix-hash-{name}")
         self._thread.start()
 
     # Called from _commit_piece_record (under the store's _meta_lock; lock
-    # order store._meta_lock → self._cv, and _run never takes _meta_lock).
+    # order store._meta_lock → self._cv, and no thread of ours takes
+    # _meta_lock).
     def piece_recorded(self, num: int, replaced: bool,
                        will_feed: bool = False) -> None:
         with self._cv:
-            # <=, not <: _next is also the piece currently being hashed
-            # OUTSIDE the lock — a re-record there would hash a torn mix
-            # of old and new bytes without this poison.
-            if replaced and num <= self._next:
-                self._err = f"piece {num} re-recorded at/behind the frontier"
-                self._stop = True
-            if (will_feed and not self._stop and not self._busy
-                    and num == self._next):
+            # At or behind the READ frontier: bytes of every piece below it
+            # may already be copied, and <=, not <, because a feed() may be
+            # hashing piece _read_next OUTSIDE the lock — a re-record there
+            # would hash a torn mix of old and new bytes without this
+            # poison.
+            if replaced and num <= self._read_next:
+                self._ring.fail(
+                    f"piece {num} re-recorded at/behind the read frontier")
+            if (will_feed and not self._ring.closed and not self._feeding
+                    and num == self._next == self._read_next):
                 self._reserved = num
                 self._reserved_at = time.monotonic()
                 return   # no notify: the imminent feed() advances instead
-            self._cv.notify()
+            self._cv.notify_all()
 
     def feed(self, num: int, chunks) -> None:
         """Advance the frontier with in-memory bytes (one buffer or a list
         of buffers, in order). Called by the committing writer AFTER
         ``piece_recorded``, outside the store's _meta_lock, while it still
-        owns the buffers. No-op unless ``num`` is exactly the unclaimed
-        frontier — anything else stays the background thread's job."""
+        owns the buffers. No-op unless ``num`` is exactly the frontier and
+        the reader has not claimed it — anything else stays the reader's
+        job."""
         with self._cv:
             if self._reserved == num:
                 self._reserved = None
-            if (self._err is not None or self._stop or self._busy
-                    or num != self._next):
-                self._cv.notify()
+            if (self._ring.closed or self._feeding
+                    or not num == self._next == self._read_next):
+                self._cv.notify_all()
                 return
-            self._busy = True
+            self._feeding = True
         try:
             if isinstance(chunks, (bytes, bytearray, memoryview)):
                 chunks = (chunks,)
@@ -220,93 +408,52 @@ class _PrefixHasher:
                 self._h.update(c)   # GIL released for >2 KiB
         except Exception as e:  # noqa: BLE001 - poisons; caller re-hashes
             with self._cv:
-                self._err = str(e)
-                self._busy = False
-                self._cv.notify()
+                self._feeding = False
+                self._ring.fail(str(e))
             return
         with self._cv:
-            self._busy = False
+            self._feeding = False
             self._next += 1
-            self._cv.notify()
+            self._read_next += 1
+            self._cv.notify_all()
 
     def stop(self) -> None:
-        with self._cv:
-            self._stop = True
-            self._cv.notify()
+        self._ring.close()
 
-    def _run(self) -> None:
-        try:
-            fd = os.open(self.store._data_path, os.O_RDONLY)
-        except OSError as e:
-            with self._cv:
-                self._err = str(e)
-                self._cv.notify()
-            return
-        try:
-            while True:
-                with self._cv:
-                    while True:
-                        if self._stop:
-                            return
-                        m = self.store.metadata
-                        rec = m.pieces.get(self._next)
-                        if rec is not None and not self._busy:
-                            if self._reserved != self._next:
-                                break
-                            # A feed() is imminent for this piece; only
-                            # reclaim a reservation whose feed never came
-                            # (commit-path exception between record and
-                            # feed — rare, and the cost is one pread).
-                            if time.monotonic() - self._reserved_at > 1.0:
-                                self._reserved = None
-                                break
-                        if (rec is None and m.total_piece_count >= 0
-                                and self._next >= m.total_piece_count):
-                            return  # drained
-                        # Timed wait: total_piece_count can be set by
-                        # update_task without a piece commit notifying.
-                        self._cv.wait(timeout=1.0)
-                    self._busy = True
-                try:
-                    remaining, off = rec.size, rec.offset
-                    self.disk_reads += 1
-                    mv = _READ_BUFFERS.acquire(min(remaining, 4 << 20))
-                    try:
-                        while remaining > 0:
-                            take = min(len(mv), remaining)
-                            n = os.preadv(fd, [mv[:take]], off)
-                            if n <= 0:
-                                raise OSError(f"short read at piece {rec.num}")
-                            self._h.update(mv[:n])  # GIL released for >2 KiB
-                            off += n
-                            remaining -= n
-                    finally:
-                        _READ_BUFFERS.release(mv)
-                except BaseException:
-                    with self._cv:
-                        self._busy = False
-                    raise
-                with self._cv:
-                    self._busy = False
-                    self._next += 1
-                    self._cv.notify()
-        except Exception as e:  # noqa: BLE001 - poisons; caller re-hashes
-            with self._cv:
-                self._err = str(e)
-                self._cv.notify()
-        finally:
-            try:
-                os.close(fd)
-            except OSError:
-                pass
+    def _claim(self):
+        """The reader's ``next_span``, under ``_cv``: the committed piece
+        at the read frontier, unless a feed holds or is about to take it."""
+        m = self.store.metadata
+        rec = m.pieces.get(self._read_next)
+        if rec is None:
+            if 0 <= m.total_piece_count <= self._read_next:
+                raise StopIteration   # drained
+            return None
+        if self._feeding:
+            return None
+        if self._reserved == self._read_next:
+            # A feed() is imminent for this piece; only reclaim a
+            # reservation whose feed never came (commit-path exception
+            # between record and feed — rare, and the cost is one pread).
+            if time.monotonic() - self._reserved_at <= 1.0:
+                return None
+            self._reserved = None
+        self._read_next += 1
+        self.disk_reads += 1
+        return rec.offset, rec.size, rec.num
+
+    def _piece_hashed(self, num: int) -> None:
+        self._next = num + 1
 
     def finish(self, timeout: float = 60.0) -> str | None:
         """Wait for the frontier to drain; hex digest, or None on any
         error/timeout (caller falls back to the full re-hash)."""
         deadline = time.monotonic() + timeout
+        ring = self._ring
+        ready, waited = ring.ready, ring.waited
         with self._cv:
             while True:
-                if self._err is not None or self._stop:
+                if ring.closed:
                     return None
                 total = self.store.metadata.total_piece_count
                 if total >= 0 and self._next >= total:
@@ -315,7 +462,9 @@ class _PrefixHasher:
                 if left <= 0 or not self._cv.wait(timeout=min(left, 2.0)):
                     if time.monotonic() >= deadline:
                         return None
-        self._thread.join(timeout=5.0)
+        for t in (*ring.threads, self._thread):
+            t.join(timeout=5.0)
+        self.tail_chunks = (ring.ready - ready, ring.waited - waited)
         return self._h.hexdigest()
 
 
@@ -357,8 +506,9 @@ class LocalTaskStore:
         # with a known content digest — see _PrefixHasher).
         self._prefix_hasher: _PrefixHasher | None = None
         # How the last validate_digest got its digest, for the flight's
-        # ``verified``: ("prefix" | "rehash", pieces it read from the store).
-        self.digest_pass: tuple[str, int] = ("", 0)
+        # ``verified``: ("prefix" | "rehash", pieces it read from the store,
+        # the ring's chunks (ready, waited) while validate_digest ran).
+        self.digest_pass: tuple[str, int, tuple[int, int]] = ("", 0, (0, 0))
 
     # -- pinning: GC must not reclaim a store mid-download/upload ----------
 
@@ -805,15 +955,7 @@ class LocalTaskStore:
         if at + length > len(mv):
             raise StorageError(
                 f"read buffer too small: need {at + length}, have {len(mv)}")
-        fd = self._ensure_fd()
-        got = 0
-        while got < length:
-            n = os.preadv(fd, [mv[at + got:at + length]], offset + got)
-            if n <= 0:
-                raise StorageError(
-                    f"short read at offset {offset + got}: "
-                    f"{got}/{length} bytes (EOF)")
-            got += n
+        _preadv_exact(self._ensure_fd(), mv[at:at + length], offset)
 
     def read_spans_into(self, spans, buf) -> int:
         """Pack the byte spans ``[(offset, length), ...]`` back to back into
@@ -954,36 +1096,36 @@ class LocalTaskStore:
             prefix_hex = ph.finish(
                 timeout=max(60.0, cl / (50 << 20)) if cl > 0 else 60.0)
             if prefix_hex is not None:
-                self.digest_pass = ("prefix", ph.disk_reads)
-                actual = f"{algorithm}:{prefix_hex}"
-                if want and actual != want:
-                    raise StorageError(
-                        f"content digest mismatch: want {want}, got {actual}",
-                        Code.ClientPieceDownloadFail)
-                return actual
+                self.digest_pass = ("prefix", ph.disk_reads, ph.tail_chunks)
+                return self._checked_digest(want, f"{algorithm}:{prefix_hex}")
             # Poisoned/timed-out hasher: fall through to the full re-hash
-            # — and stop the thread so a merely-lagging hasher does not
+            # — and stop its threads so a merely-lagging hasher does not
             # keep pread'ing in parallel with the re-hash below.
             ph.stop()
+        # The full re-hash: the same read-ahead, over every recorded piece
+        # in order, this thread hashing.
         h = pkgdigest.new_hasher(algorithm)
-        mv = _READ_BUFFERS.acquire(4 << 20)
+        recs = [self.metadata.pieces[n] for n in sorted(self.metadata.pieces)]
+        self._ensure_fd()   # the ring opens the data file by path
+        ring = _ReadAhead(
+            self._data_path, threading.Condition(),
+            ((r.offset, r.size, r.num) for r in recs).__next__,
+            self.metadata.task_id[:12])
         try:
-            for n in sorted(self.metadata.pieces):
-                rec = self.metadata.pieces[n]
-                remaining, off = rec.size, rec.offset
-                while remaining > 0:
-                    take = min(len(mv), remaining)
-                    self.read_into(off, take, mv)
-                    h.update(mv[:take])
-                    off += take
-                    remaining -= take
+            ring.hash_into(h)
         finally:
-            _READ_BUFFERS.release(mv)
-        self.digest_pass = ("rehash", len(self.metadata.pieces))
-        actual = f"{algorithm}:{h.hexdigest()}"
+            ring.close()
+        if ring.err is not None:
+            raise StorageError(ring.err)
+        self.digest_pass = ("rehash", len(recs), (ring.ready, ring.waited))
+        return self._checked_digest(want, f"{algorithm}:{h.hexdigest()}")
+
+    @staticmethod
+    def _checked_digest(want: str, actual: str) -> str:
         if want and actual != want:
-            raise StorageError(f"content digest mismatch: want {want}, got {actual}",
-                               Code.ClientPieceDownloadFail)
+            raise StorageError(
+                f"content digest mismatch: want {want}, got {actual}",
+                Code.ClientPieceDownloadFail)
         return actual
 
     def reverify_pieces(self, threads: int = 0) -> list[int]:

@@ -47,6 +47,7 @@ METRIC_MODULES = (
     "dragonfly2_tpu.delta.manifest",
     "dragonfly2_tpu.delta.resolver",
     "dragonfly2_tpu.storage.io_ring",
+    "dragonfly2_tpu.storage.local_store",
     "dragonfly2_tpu.dataset.loader",
     "dragonfly2_tpu.dataset.shard_reader",
     "dragonfly2_tpu.dataset.tar_index",
@@ -58,7 +59,7 @@ METRIC_MODULES = (
 COMPONENTS = ("bufpool", "chaos", "dataset", "delta", "device_sharded",
               "device_sink", "device_views", "fleet", "manager",
               "objectstorage", "peer", "proxy", "qos", "runtime", "scheduler",
-              "storage", "tracing", "upload")
+              "storage", "store", "tracing", "upload")
 
 # Histogram families must name their unit; counters use _total; gauges
 # may end in a unit but never _total. "pieces" is a unit here: batch-size

@@ -387,3 +387,237 @@ class TestPrefixHasher:
                                meta("t-ph4", piece_size=4, content_length=8))
         store.start_prefix_hasher("whirlpool999:beef")
         assert store._prefix_hasher is None
+
+
+class _GatedHasher:
+    """sha256 whose ``update`` stands at a gate: holds the hashing thread
+    so that the read frontier runs ahead of the hash frontier."""
+
+    def __init__(self, gate):
+        import hashlib
+
+        self._h = hashlib.sha256()
+        self._gate = gate
+
+    def update(self, data):
+        assert self._gate.wait(timeout=10)
+        self._h.update(data)
+
+    def hexdigest(self):
+        return self._h.hexdigest()
+
+
+def _wait_for(cond, what, timeout=10.0):
+    import time
+
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out: {what}"
+        time.sleep(0.005)
+
+
+def _prefix_threads():
+    import threading
+
+    return [t for t in threading.enumerate() if t.name.startswith("df-prefix-")]
+
+
+class TestDigestReadAhead:
+    """The read-ahead in front of the prefix hasher (PR 37): a reader fills
+    a ring of pooled chunks, the hashing thread never reads, and the
+    digest is ``hashlib``'s over the content in piece order in every
+    interleaving of commits, feeds and reads."""
+
+    CHUNK = 16 * 1024
+
+    @pytest.fixture(autouse=True)
+    def small_chunks(self, monkeypatch):
+        from dragonfly2_tpu.storage import local_store
+
+        # Pieces of a few chunks each without gigabytes of content.
+        monkeypatch.setattr(local_store, "_CHUNK", self.CHUNK)
+
+    def _filled(self, tmp_path, name, pieces, piece=40 * 1024 + 17, tail=1234,
+                seed=0):
+        import hashlib
+        import random
+
+        content = bytes(random.Random(seed).randbytes(
+            (pieces - 1) * piece + tail))
+        store = LocalTaskStore(str(tmp_path / name),
+                               meta(f"t-{name}", piece_size=piece,
+                                    content_length=len(content)))
+        return store, content, "sha256:" + hashlib.sha256(content).hexdigest()
+
+    @staticmethod
+    def _land(store, content, n, fed):
+        """Commit piece ``n``: through memory (``write_piece``: the Python
+        receive paths, which feed) or as the native engine does (bytes
+        straight into the file, then ``record_piece``: read back)."""
+        piece = store.metadata.piece_size
+        data = content[n * piece:(n + 1) * piece]
+        if fed:
+            store.write_piece(n, data)
+        else:
+            os.pwrite(store.data_fd(), data, n * piece)
+            store.record_piece(n, len(data), crc=0)
+
+    @pytest.mark.parametrize("readers", [1, 2])
+    @pytest.mark.parametrize("pieces", [1, 2, 55])
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_digest_is_hashlibs_in_any_order_and_mix(
+            self, tmp_path, monkeypatch, seed, pieces, readers):
+        import random
+
+        from dragonfly2_tpu.storage import local_store
+
+        monkeypatch.setattr(local_store, "_RING_READERS", readers)
+        rnd = random.Random(seed * 100 + pieces)
+        store, content, want = self._filled(
+            tmp_path, "mix", pieces, tail=rnd.randrange(1, 40 * 1024),
+            seed=seed)
+        store.start_prefix_hasher(want)
+        ph = store._prefix_hasher
+        order = list(range(pieces))
+        rnd.shuffle(order)
+        fed = 0
+        for n in order:
+            by_memory = rnd.random() < 0.5
+            fed += by_memory
+            self._land(store, content, n, by_memory)
+        assert store.validate_digest(want) == want
+        how, read_back, (ready, waited) = store.digest_pass
+        assert how == "prefix"
+        # Every piece went into the digest once: read back, or fed.
+        assert read_back == ph.disk_reads and pieces - fed <= read_back <= pieces
+        assert not _prefix_threads()
+
+    def test_concurrent_commits_under_a_short_switch_interval(self, tmp_path):
+        import sys
+        import threading
+
+        store, content, want = self._filled(tmp_path, "stress", 96)
+        store.start_prefix_hasher(want)
+        workers = 2 * (os.cpu_count() or 4)
+        kept = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [
+                threading.Thread(target=lambda w=w: [
+                    self._land(store, content, n, fed=(n + w) % 2 == 0)
+                    for n in range(w, 96, workers)])
+                for w in range(workers)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=30)
+                assert not t.is_alive()
+        finally:
+            sys.setswitchinterval(kept)
+        assert store.validate_digest(want) == want
+        assert store.digest_pass[0] == "prefix"
+
+    @pytest.mark.parametrize("offset,how", [(-1, "rehash"), (0, "rehash"),
+                                            (1, "prefix")])
+    def test_rerecord_at_or_behind_the_read_frontier_poisons(
+            self, tmp_path, monkeypatch, offset, how):
+        import threading
+
+        from dragonfly2_tpu.storage import local_store
+
+        gate = threading.Event()
+        monkeypatch.setattr(local_store.pkgdigest, "new_hasher",
+                            lambda algorithm: _GatedHasher(gate))
+        store, content, want = self._filled(tmp_path, f"poison{offset + 1}", 8,
+                                            piece=self.CHUNK, tail=self.CHUNK)
+        store.start_prefix_hasher(want)
+        ph = store._prefix_hasher
+        for n in range(8):
+            self._land(store, content, n, fed=False)
+        # The hasher stands at piece 0 (a chunk a piece); the reader has
+        # copied a ring's worth ahead of it.
+        _wait_for(lambda: ph._read_next == local_store._RING_DEPTH,
+                  "reader a ring ahead of the hasher")
+        assert ph._next == 0
+        again = ph._read_next + offset
+        assert again > ph._next + 1   # the hash frontier's rule let it pass
+        self._land(store, content, again, fed=False)
+        assert ph._ring.closed == (how == "rehash")
+        gate.set()
+        assert store.validate_digest(want) == want
+        assert store.digest_pass[0] == how
+        _wait_for(lambda: not _prefix_threads(), "threads gone")
+
+    @pytest.mark.parametrize("repaired", [True, False])
+    def test_short_data_file_poisons_and_falls_back(self, tmp_path, repaired):
+        store, content, want = self._filled(tmp_path, f"short{repaired}", 4)
+        for n in range(4):
+            self._land(store, content, n, fed=False)
+        os.truncate(store.data_path, len(content) // 2)
+        store.start_prefix_hasher(want)
+        ph = store._prefix_hasher
+        _wait_for(lambda: ph._ring.closed, "short read poisons")
+        assert "short read" in ph._ring.err
+        if repaired:
+            os.pwrite(store.data_fd(), content, 0)
+            assert store.validate_digest(want) == want
+            assert store.digest_pass[:2] == ("rehash", 4)
+        else:
+            with pytest.raises(StorageError, match="short read"):
+                store.validate_digest(want)
+        _wait_for(lambda: not _prefix_threads(), "threads gone")
+
+    @pytest.mark.parametrize("recorded", [(0, 1, 2, 3), (0, 1, 3), (2,), ()])
+    def test_full_rehash_gives_the_digest_it_gave(self, tmp_path, recorded):
+        """``validate_digest`` without a prefix hasher: sha256 over the
+        RECORDED pieces in piece order (gaps and all), as the serial loop
+        gave it."""
+        import hashlib
+
+        store, content, _ = self._filled(tmp_path, f"re{len(recorded)}", 4)
+        piece = store.metadata.piece_size
+        for n in reversed(recorded):
+            self._land(store, content, n, fed=n % 2 == 0)
+        want = "sha256:" + hashlib.sha256(b"".join(
+            content[n * piece:(n + 1) * piece] for n in recorded)).hexdigest()
+        assert store.validate_digest() == want
+        how, pieces, (ready, waited) = store.digest_pass
+        assert (how, pieces) == ("rehash", len(recorded))
+        assert ready + waited == sum(
+            -(-len(content[n * piece:(n + 1) * piece]) // self.CHUNK)
+            for n in recorded)
+        assert not _prefix_threads()
+
+    @pytest.mark.parametrize("ending", ["stop", "mark_invalid", "destroy",
+                                        "finish"])
+    def test_endings_leave_no_thread_and_no_buffer_out(
+            self, tmp_path, monkeypatch, ending):
+        import threading
+
+        from dragonfly2_tpu.storage import local_store
+
+        gate = threading.Event()
+        monkeypatch.setattr(local_store.pkgdigest, "new_hasher",
+                            lambda algorithm: _GatedHasher(gate))
+
+        def out():
+            return local_store.read_buffer_stats()["outstanding"]
+
+        before = out()
+        store, content, want = self._filled(tmp_path, ending, 12)
+        store.start_prefix_hasher(want)
+        ph = store._prefix_hasher
+        for n in range(12):
+            self._land(store, content, n, fed=False)
+        # A full ring: every buffer the hasher may hold is out of the pool.
+        _wait_for(lambda: out() - before == local_store._RING_DEPTH,
+                  "ring full")
+        if ending == "finish":
+            gate.set()
+            assert "sha256:" + ph.finish() == want
+        else:
+            ph.stop() if ending == "stop" else getattr(store, ending)()
+            assert ph._ring.closed and ph.finish(timeout=0.1) is None
+            gate.set()
+        _wait_for(lambda: not _prefix_threads(), "threads gone")
+        assert out() == before

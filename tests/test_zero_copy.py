@@ -225,3 +225,54 @@ def test_buffer_pool_recycles_and_refuses_double_release():
     big = pool.acquire(2 << 20)
     pool.release(big)
     assert pool.stats()["retained_bytes"] <= 1 << 20
+
+
+@pytest.mark.parametrize("through", ["prefix", "rehash"])
+def test_completion_digest_reads_only_on_the_reader_threads(
+        tmp_path, monkeypatch, through):
+    """The thread that hashes never reads (PR 37): every ``preadv`` of the
+    completion digest is a ``df-prefix-read-*`` thread's, whether the
+    digest comes from the prefix hasher or from the full re-hash, and
+    ``disk_reads`` and ``store_digest_chunks_total`` account for every
+    chunk read."""
+    import threading
+
+    from dragonfly2_tpu.storage import local_store
+
+    chunk = 64 * 1024
+    monkeypatch.setattr(local_store, "_CHUNK", chunk)
+    store = _store(tmp_path, f"readers-{through}")
+    pieces = -(-len(CONTENT) // PIECE)
+    store.update_task(content_length=len(CONTENT))
+    want = "sha256:" + hashlib.sha256(CONTENT).hexdigest()
+    # Native-engine landings: bytes straight into the file, no memory feed.
+    for n in range(pieces):
+        data = CONTENT[n * PIECE:(n + 1) * PIECE]
+        os.pwrite(store.data_fd(), data, n * PIECE)
+        store.record_piece(n, len(data), crc=0)
+    readers: list[str] = []
+    real_preadv = os.preadv
+
+    def preadv(fd, bufs, off):
+        readers.append(threading.current_thread().name)
+        return real_preadv(fd, bufs, off)
+
+    def counted():
+        return sum(local_store.DIGEST_CHUNKS.labels(how)._value.get()
+                   for how in ("ready", "waited"))
+
+    before = counted()
+    monkeypatch.setattr(os, "preadv", preadv)
+    if through == "prefix":
+        store.start_prefix_hasher(want)
+    assert store.validate_digest(want) == want
+    how, read_back, (ready, waited) = store.digest_pass
+    assert (how, read_back) == (through, pieces)
+    assert all(name.startswith("df-prefix-read-") for name in readers), \
+        set(readers)
+    chunks = sum(-(-min(PIECE, len(CONTENT) - n * PIECE) // chunk)
+                 for n in range(pieces))
+    assert len(readers) == chunks == counted() - before
+    assert ready + waited <= chunks   # the tail's, or the whole pass's
+    monkeypatch.undo()
+    store.destroy()
