@@ -147,6 +147,17 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
     the object — a distinct ranged task (P2P-deduped among peers pulling
     the SAME range). Ranged landings verify by the per-piece digest chain
     only; a whole-content ``digest`` cannot apply to a slice.
+
+    A pod-wide pull (every host of a slice asking for the same object at
+    once) is, on this path, plain P2P: each host registers for the task, the
+    scheduler hands it up to ``candidate_parent_limit`` parents, slice-mates
+    ranked before the seed (``scheduling.find_candidate_parents``), and its
+    pieces come from whichever of them holds one. This call has no way to
+    ask for the striped broadcast (each slice-mate pulling a disjoint
+    stripe across the DCN and trading the rest inside the slice): that is
+    ``FileTaskRequest.pod_broadcast``, reachable through ``Daemon.Download``
+    (``dfget --pod-broadcast``) alone, and ``stripe_min_slice_peers`` is 0,
+    so the scheduler stripes nothing unasked (ROADMAP S5).
     """
     from dragonfly2_tpu.daemon.peer.task_manager import FileTaskRequest
     from dragonfly2_tpu.pkg.piece import Range

@@ -80,6 +80,14 @@ PIECE_BYTES = metrics.counter(
     "peer_piece_bytes_total",
     "P2P piece bytes downloaded, by parent ICI locality",
     ("locality",))
+# The same bytes by what the parent is: a seed peer (its copy came from
+# the origin) or a fellow peer (its copy came over P2P too). The share
+# from peers is what a fan-out saves the seed; locality cannot say it (a
+# seed may sit inside the slice, a fellow peer outside it).
+PIECE_BYTES_BY_PARENT = metrics.counter(
+    "peer_piece_bytes_by_parent_total",
+    "P2P piece bytes downloaded, by the parent's kind (seed | peer)",
+    ("parent",))
 # Announce-wire weight: serialized msgpack bytes this daemon exchanged
 # with the scheduler over announce streams. The packed-report encoding
 # exists to shrink ``sent``.
@@ -177,6 +185,11 @@ class PeerTaskConductor:
         # snapshots locality_bytes for benches/tests.
         self.own_slice = (host_info or {}).get("tpu_slice", "") or ""
         self.locality_bytes = {"intra": 0, "cross": 0, "unlabeled": 0}
+        # Bytes by where they came from, and the parents that served any:
+        # stamped once, as ``task_sources``, when the conductor ends.
+        self.source_bytes = {"seed": 0, "peer": 0, "origin": 0}
+        self._parents_used: set[str] = set()
+        self._sources_stamped = False
         self._stream = None
         self._reschedules = 0
         self._from_p2p = False
@@ -363,6 +376,8 @@ class PeerTaskConductor:
                     expected_digest=piece.digest, cost_ms=cost_ms)
                 self.flight.record(flightlib.EV_LANDED, piece.piece_num,
                                    float(cost_ms))
+                self._note_source_bytes(bool(host.get("type", 0)),
+                                        parent.get("id", ""), size)
                 await self._report_piece(rec, parent_id=parent.get("id", ""))
                 if self.on_piece is not None:
                     await self.on_piece(self.store, rec)
@@ -430,6 +445,10 @@ class PeerTaskConductor:
             if self.on_piece is not None:
                 await self.on_piece(store, rec)
 
+        async def on_source_piece(store: LocalTaskStore, rec) -> None:
+            self.source_bytes["origin"] += rec.size
+            await on_piece(store, rec)
+
         # A ranged slice a LOCAL parent store covers imports warm — the
         # scheduler-triggered ranged seed on a preheated host never
         # re-touches origin. This is not a back-source: it runs BEFORE
@@ -459,7 +478,7 @@ class PeerTaskConductor:
             await self.piece_manager.download_source(
                 self.store, self.url, self.meta.get("header") or {},
                 content_range=self.content_range,
-                on_piece=on_piece, limiter=self.limiter,
+                on_piece=on_source_piece, limiter=self.limiter,
             )
         await self._safe_send({
             "type": "download_finished",
@@ -668,6 +687,24 @@ class PeerTaskConductor:
         key = self._parent_locality(parent)
         self.locality_bytes[key] += size
         PIECE_BYTES.labels(key).inc(size)
+        self._note_source_bytes(parent.is_seed, parent.peer_id, size)
+
+    def _note_source_bytes(self, from_seed: bool, parent_id: str,
+                           size: int) -> None:
+        kind = "seed" if from_seed else "peer"
+        self.source_bytes[kind] += size
+        self._parents_used.add(parent_id)
+        PIECE_BYTES_BY_PARENT.labels(kind).inc(size)
+
+    def _stamp_sources(self) -> None:
+        if self._sources_stamped:
+            return
+        self._sources_stamped = True
+        b = self.source_bytes
+        self.flight.record(
+            flightlib.EV_TASK_SOURCES, len(self._parents_used),
+            float(b["peer"]),
+            f"seed={b['seed']} peer={b['peer']} origin={b['origin']}")
 
     def _apply_task_meta(self, task_wire: dict) -> None:
         cl = task_wire.get("content_length", -1)
@@ -1202,6 +1239,7 @@ class PeerTaskConductor:
 
     async def _teardown(self) -> None:
         self._announce_done = True   # recovery must not race teardown
+        self._stamp_sources()
         unwatch = getattr(self.scheduler_client, "unwatch_ring", None)
         if unwatch is not None:
             unwatch(self.task_id)

@@ -39,6 +39,10 @@ class ParentInfo:
     # stripe mode it is the ONLY class allowed to serve non-stripe pieces.
     same_slice: bool = False
     tpu_slice: str = ""
+    # The parent's host is a seed peer (host type, as the scheduler's
+    # handout names it): the conductor books its bytes apart from a
+    # fellow peer's.
+    is_seed: bool = False
     # Assignments currently in flight against this parent (tie-breaker).
     inflight: int = 0
 
@@ -154,11 +158,13 @@ class PieceDispatcher:
 
     def upsert_parent(self, peer_id: str, ip: str, upload_port: int,
                       *, same_slice: bool = False,
-                      tpu_slice: str = "") -> ParentInfo:
+                      tpu_slice: str = "",
+                      is_seed: bool = False) -> ParentInfo:
         p = self.parents.get(peer_id)
         if p is None:
             p = ParentInfo(peer_id, ip, upload_port,
-                           same_slice=same_slice, tpu_slice=tpu_slice)
+                           same_slice=same_slice, tpu_slice=tpu_slice,
+                           is_seed=is_seed)
             self.parents[peer_id] = p
             self._wakeup.set()
         else:

@@ -69,7 +69,8 @@ class PieceTaskSynchronizer:
                 peer_id, ip, upload_port,
                 same_slice=bool(self.own_slice)
                 and parent_slice == self.own_slice,
-                tpu_slice=parent_slice)
+                tpu_slice=parent_slice,
+                is_seed=bool(host.get("type", 0)))
             # Seed known pieces from the schedule response, and the
             # relayed digests into the SHARED map only (no parent
             # attribution — relayed digests have no provenance and must

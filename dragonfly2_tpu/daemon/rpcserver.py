@@ -190,16 +190,23 @@ class DaemonRpcServer:
         breakdown + per-piece waterfall, JSON plus the rendered text
         (dfget --explain prints the latter — identical to the
         /debug/flight/<task_id>?format=text rendering) plus the compact
-        digest the scheduler's pod lens merges on an on-demand pull."""
+        digest the scheduler's pod lens merges on an on-demand pull. With
+        ``raw`` true the reply also holds ``raw``: the task's named events
+        as they lie in the ring (``flight.raw``), uncapped, so a caller can
+        fold several daemons' rings on one clock."""
         task_id = (body or {}).get("task_id", "")
+        self.task_manager.flight.sync()
         tf = self.task_manager.flight.get(task_id)
         if tf is None:
             raise DfError(Code.PeerTaskNotFound,
                           f"no flight data for task {task_id}")
         report = flightlib.analyze(tf)
-        return {"report": report,
-                "text": flightlib.render_waterfall(report),
-                "digest": flightlib.digest(tf)}
+        reply = {"report": report,
+                 "text": flightlib.render_waterfall(report),
+                 "digest": flightlib.digest(tf)}
+        if (body or {}).get("raw"):
+            reply["raw"] = flightlib.raw(tf)
+        return reply
 
     async def _pod_timeline(self, body, ctx: RpcContext):
         """dfget --pod: proxy the merged cross-host timeline from the

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import asyncio
 import threading
+import time
 
 from aiohttp import web
 
@@ -109,6 +110,9 @@ class UploadManager:
         self.qos_buckets = qos_buckets
         self.concurrent_limit = concurrent_limit
         self.concurrent = 0
+        # The recorder whose rings the serving side stamps: the process's
+        # own unless the owner gives another (daemons sharing a process).
+        self.flight = flightlib.recorder()
         self._runner: web.AppRunner | None = None
         self._native_srv: int | None = None
         self._port = 0
@@ -142,6 +146,10 @@ class UploadManager:
             # exists (reloaded tasks), then stay current via observer
             # callbacks — requests never consult Python.
             self.storage.set_observer(_NativeServingIndex(nb, srv))
+            # The native server's sends reach the flight rings when a ring
+            # is read (FlightRecorder.sync), not as they happen: requests
+            # never consult Python.
+            self.flight.feeders.append(self.drain_serves)
             log.info("upload server up (native)", port=self._port)
             return self._port
         app = web.Application()
@@ -167,10 +175,29 @@ class UploadManager:
 
         return _native().upload_counters(self._native_srv)
 
+    def drain_serves(self) -> None:
+        """Stamp an ``upload_serve`` for every send the native server has
+        finished since the last call, at the time it ended, into the ring
+        of its task (a task without a ring here is skipped: the log may
+        outlive it)."""
+        if self._native_srv is None:
+            return
+        from dragonfly2_tpu.storage.local_store import _native
+
+        for task_id, piece, nbytes, end_s, send_ms, wait_ms in \
+                _native().upload_drain(self._native_srv):
+            tf = self.flight.get(task_id)
+            if tf is not None:
+                tf.record_at(end_s, flightlib.EV_UPLOAD_SERVE, piece, send_ms,
+                             flightlib.serve_note(nbytes, wait_ms))
+
     async def close(self) -> None:
         if self._native_srv is not None:
             from dragonfly2_tpu.storage.local_store import _native
 
+            feeders = self.flight.feeders
+            if self.drain_serves in feeders:
+                feeders.remove(self.drain_serves)
             srv, self._native_srv = self._native_srv, None
             # Detach + barrier BEFORE the stop frees the handle: observer
             # callbacks arrive from executor threads (piece commits), and a
@@ -213,6 +240,8 @@ class UploadManager:
         CONCURRENT_UPLOADS.inc()
         store.pin()
         released = False
+        t_asked = time.perf_counter()
+        sending = None   # (piece, bytes, perf_counter at the send's start)
 
         def release() -> None:
             nonlocal released
@@ -221,6 +250,19 @@ class UploadManager:
                 store.unpin()
                 self.concurrent -= 1
                 CONCURRENT_UPLOADS.dec()
+                if sending is not None:
+                    # Serving-side flight event: the parent's own timeline
+                    # records which pieces it handed out and how long each
+                    # send took (pod autopsies correlate a child's stall
+                    # against the parent's serve log; the union of the
+                    # sends is the parent's busy time). The wait is the
+                    # tenant bucket's and the rate limiter's.
+                    piece, nbytes, t_send = sending
+                    wait_ms = (t_send - t_asked) * 1000.0
+                    self.flight.task(task_id).record(
+                        flightlib.EV_UPLOAD_SERVE, piece,
+                        (time.perf_counter() - t_send) * 1000.0,
+                        flightlib.serve_note(nbytes, wait_ms))
 
         try:
             piece_num = request.query.get("pieceNum")
@@ -259,13 +301,8 @@ class UploadManager:
             UPLOAD_BYTES.inc(length)
             UPLOAD_REQUESTS.labels("ok").inc()
             sp.set_attr("bytes", length)
-            # Serving-side flight event: the parent's own timeline records
-            # which pieces it handed out (pod autopsies correlate a child's
-            # stall against the parent's serve log).
-            flightlib.for_task(task_id).record(
-                flightlib.EV_UPLOAD_SERVE,
-                int(piece_num) if piece_num is not None else -1,
-                float(length))
+            sending = (int(piece_num) if piece_num is not None else -1,
+                       length, time.perf_counter())
             # sendfile the byte range straight from the page cache: no
             # pread into Python bytes and no user→kernel copy in sendmsg
             # on the serving side.

@@ -127,6 +127,10 @@ _lib.df_upload_counters.argtypes = [ctypes.c_int64,
                                     ctypes.POINTER(ctypes.c_uint64)]
 _lib.df_upload_counters.restype = None
 
+_lib.df_upload_drain.argtypes = [ctypes.c_int64, ctypes.c_char_p,
+                                 ctypes.c_int64]
+_lib.df_upload_drain.restype = ctypes.c_int64
+
 _lib.df_upload_stop.argtypes = [ctypes.c_int64]
 _lib.df_upload_stop.restype = None
 
@@ -404,6 +408,23 @@ def upload_counters(handle: int) -> dict:
     return {"bytes_served": out[0], "ok": out[1], "not_found": out[2],
             "piece_missing": out[3], "throttled": out[4],
             "bad_request": out[5]}
+
+
+def upload_drain(handle: int) -> list:
+    """The sends finished since the last call, oldest first:
+    ``(task_id, piece, bytes, end_s, send_ms, wait_ms)`` each, ``end_s`` on
+    ``time.perf_counter()``'s clock (CLOCK_MONOTONIC), ``piece`` -1 for a
+    Range request."""
+    out: list = []
+    buf = ctypes.create_string_buffer(1 << 16)
+    while True:
+        n = _lib.df_upload_drain(handle, buf, len(buf))
+        if n <= 0:
+            return out
+        for line in buf.raw[:n].decode().splitlines():
+            task_id, piece, nbytes, end_ns, send_us, wait_us = line.split()
+            out.append((task_id, int(piece), int(nbytes), int(end_ns) / 1e9,
+                        int(send_us) / 1000.0, int(wait_us) / 1000.0))
 
 
 def upload_stop(handle: int) -> None:

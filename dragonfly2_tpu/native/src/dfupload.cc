@@ -37,6 +37,7 @@
 #include <sys/sendfile.h>
 #include <sys/socket.h>
 #include <sys/stat.h>
+#include <time.h>
 #include <unistd.h>
 #include <arpa/inet.h>
 
@@ -65,6 +66,31 @@ struct TaskEnt {
   std::unordered_map<uint32_t, PieceEnt> pieces;
 };
 
+// One finished send, for the daemon's flight ring: Python drains these
+// (df_upload_drain) and stamps an upload_serve event each. Times are
+// CLOCK_MONOTONIC, which is Python's time.perf_counter() on Linux.
+struct ServeRec {
+  std::string task_id;
+  int64_t piece;      // -1: a Range request
+  uint64_t bytes;
+  int64_t end_ns;     // the send's last byte written
+  int64_t send_us;    // header out -> last byte written
+  int64_t wait_us;    // the connection's wait for a worker (its first request)
+};
+
+constexpr size_t SERVE_LOG_MAX = 8192;
+
+int64_t mono_ns() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (int64_t)ts.tv_sec * 1000000000LL + ts.tv_nsec;
+}
+
+struct Pending {
+  int fd;
+  int64_t accepted_ns;
+};
+
 struct Server {
   int listen_fd = -1;
   int port = 0;
@@ -74,7 +100,7 @@ struct Server {
 
   std::mutex queue_mu;
   std::condition_variable queue_cv;
-  std::deque<int> pending;  // accepted fds awaiting a worker
+  std::deque<Pending> pending;  // accepted fds awaiting a worker
   size_t max_queue = 128;
 
   int concurrent_limit = 0;  // 0 = unlimited; over → 429
@@ -92,6 +118,10 @@ struct Server {
   std::atomic<uint64_t> piece_missing{0}; // known task, absent piece / 416
   std::atomic<uint64_t> throttled{0};
   std::atomic<uint64_t> bad_request{0};
+
+  std::mutex log_mu;
+  std::deque<ServeRec> serve_log;  // newest SERVE_LOG_MAX finished sends
+  uint64_t serve_log_dropped = 0;
 };
 
 std::mutex g_srv_mu;
@@ -183,7 +213,7 @@ bool covers_range(const TaskEnt& t, uint64_t start, uint64_t length) {
 }
 
 void handle_request(Server* srv, int fd, const std::string& head,
-                    bool* keep_alive) {
+                    bool* keep_alive, int64_t* wait_us) {
   // Request line: "GET <path> HTTP/1.1"
   size_t eol = head.find("\r\n");
   std::string line = head.substr(0, eol == std::string::npos ? head.size() : eol);
@@ -376,6 +406,7 @@ void handle_request(Server* srv, int fd, const std::string& head,
                   (unsigned long long)length, (unsigned long long)start,
                   (unsigned long long)(start + length - 1));
   }
+  int64_t send_start_ns = mono_ns();
   bool ok = send_all(fd, hdr, (size_t)hn);
   off_t off = (off_t)start;
   uint64_t left = length;
@@ -419,12 +450,22 @@ void handle_request(Server* srv, int fd, const std::string& head,
   if (ok) {
     srv->bytes_served += length;
     srv->ok++;
+    int64_t end_ns = mono_ns();
+    std::lock_guard<std::mutex> lk(srv->log_mu);
+    if (srv->serve_log.size() >= SERVE_LOG_MAX) {
+      srv->serve_log.pop_front();
+      srv->serve_log_dropped++;
+    }
+    srv->serve_log.push_back(ServeRec{task_id, piece_num, length, end_ns,
+                                      (end_ns - send_start_ns) / 1000,
+                                      *wait_us});
+    *wait_us = 0;  // the wait was this connection's first request's
   } else {
     *keep_alive = false;  // response possibly truncated: desynced stream
   }
 }
 
-void conn_loop(Server* srv, int fd) {
+void conn_loop(Server* srv, int fd, int64_t wait_us) {
   {
     std::lock_guard<std::mutex> lk(srv->conns_mu);
     if (srv->stopping.load()) { close(fd); return; }
@@ -464,7 +505,7 @@ void conn_loop(Server* srv, int fd) {
     std::string head = buf.substr(0, mark);
     buf.erase(0, mark + 4);
     bool keep = true;
-    handle_request(srv, fd, head, &keep);
+    handle_request(srv, fd, head, &keep, &wait_us);
     if (!keep) break;
     {
       // Accepted connections are waiting for a worker: yield this one
@@ -484,18 +525,18 @@ void conn_loop(Server* srv, int fd) {
 
 void worker_loop(Server* srv) {
   for (;;) {
-    int fd;
+    Pending next;
     {
       std::unique_lock<std::mutex> lk(srv->queue_mu);
       srv->queue_cv.wait(lk, [&] {
         return srv->stopping.load() || !srv->pending.empty();
       });
       if (srv->pending.empty()) return;  // stopping
-      fd = srv->pending.front();
+      next = srv->pending.front();
       srv->pending.pop_front();
     }
-    if (fd < 0) return;  // sentinel
-    conn_loop(srv, fd);
+    if (next.fd < 0) return;  // sentinel
+    conn_loop(srv, next.fd, (mono_ns() - next.accepted_ns) / 1000);
   }
 }
 
@@ -511,7 +552,7 @@ void accept_loop(Server* srv) {
       close(fd);
       continue;
     }
-    srv->pending.push_back(fd);
+    srv->pending.push_back(Pending{fd, mono_ns()});
     srv->queue_cv.notify_one();
   }
 }
@@ -616,6 +657,34 @@ void df_upload_counters(int64_t h, uint64_t* out) {
   out[5] = srv->bad_request.load();
 }
 
+// Pop finished sends, oldest first, as text lines
+// "<task_id> <piece> <bytes> <end_ns> <send_us> <wait_us>\n" into out[cap];
+// only whole lines, as many as fit. Returns the bytes written (0: none
+// left, or cap too small for the next line).
+int64_t df_upload_drain(int64_t h, char* out, int64_t cap) {
+  Server* srv = get_srv(h);
+  if (srv == nullptr) return 0;
+  std::lock_guard<std::mutex> lk(srv->log_mu);
+  int64_t used = 0;
+  while (!srv->serve_log.empty()) {
+    const ServeRec& r = srv->serve_log.front();
+    char line[512];
+    int n = snprintf(line, sizeof(line), "%s %lld %llu %lld %lld %lld\n",
+                     r.task_id.c_str(), (long long)r.piece,
+                     (unsigned long long)r.bytes, (long long)r.end_ns,
+                     (long long)r.send_us, (long long)r.wait_us);
+    if (n <= 0 || (size_t)n >= sizeof(line)) {  // an absurd task id: skip
+      srv->serve_log.pop_front();
+      continue;
+    }
+    if (used + n > cap) break;
+    memcpy(out + used, line, (size_t)n);
+    used += n;
+    srv->serve_log.pop_front();
+  }
+  return used;
+}
+
 void df_upload_stop(int64_t h) {
   Server* srv;
   {
@@ -630,7 +699,7 @@ void df_upload_stop(int64_t h) {
   close(srv->listen_fd);
   {
     std::lock_guard<std::mutex> lk(srv->queue_mu);
-    for (int fd : srv->pending) close(fd);
+    for (const Pending& p : srv->pending) close(p.fd);
     srv->pending.clear();
   }
   srv->queue_cv.notify_all();

@@ -83,7 +83,7 @@ EV_BACK_SOURCE = 18    # task demoted to origin
 EV_SOURCE_LANDED = 19  # origin piece landed (aux=cost_ms)
 EV_HBM_START = 20      # device-sink landing started
 EV_HBM_LANDED = 21     # device-sink landing done
-EV_UPLOAD_SERVE = 22   # this daemon served a piece of the task (aux=bytes)
+EV_UPLOAD_SERVE = 22   # this daemon served a piece of the task: stamped at the send's end (aux=ms of the send, piece=num or -1 for a range, note="<bytes>" or "<bytes> wait=<ms>")
 EV_TASK_DONE = 23
 EV_TASK_FAILED = 24
 EV_DELTA_REUSE = 25    # delta chunk copied from the local base (aux=cost_ms)
@@ -134,6 +134,10 @@ EV_CERT_WAIT = 45      # conductor._await_certification returned (piece=digest m
 EV_PARENT_DONE = 46    # a parent's sync stream said done: a point (piece=pieces that parent holds)
 EV_PARENT_SOURCE_FIRST_BYTE = 47  # the parent's source_first_byte (piece=the parent's first piece)
 EV_PARENT_VERIFIED = 48  # the parent's verify_start -> verified (piece=pieces its hasher had still to hash)
+# Where a task's bytes came from: ONE event as the conductor ends, whichever
+# way (piece=distinct parents that served a piece, aux=bytes from parents that
+# are not seeds, note="seed=<bytes> peer=<bytes> origin=<bytes>").
+EV_TASK_SOURCES = 49
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -163,6 +167,7 @@ EVENT_NAMES = {
     EV_PARENT_DONE: "parent_done",
     EV_PARENT_SOURCE_FIRST_BYTE: "parent_source_first_byte",
     EV_PARENT_VERIFIED: "parent_verified",
+    EV_TASK_SOURCES: "task_sources",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING
@@ -263,6 +268,14 @@ class TaskFlight:
                 # New attempt: the previous attempt's marks are stale.
                 track[1] = track[2] = track[3] = track[4] = -1.0
             track[slot] = t
+
+    def record_at(self, t_pc: float, code: int, piece: int = -1,
+                  aux: float = 0.0, note: str = "") -> None:
+        """An event that ended at ``t_pc`` on ``time.perf_counter()``, stamped
+        after the fact: what a thread outside Python measured (the native
+        upload server's sends) and Python only learns when it drains."""
+        self._ring[next(self._seq) % self._cap] = (
+            t_pc - self._start_pc, code, piece, aux, note)
 
     # -- accessors ---------------------------------------------------------
 
@@ -427,6 +440,58 @@ def _fold_phases(intervals: list, wall: float) -> "tuple[dict, float, list]":
     return phases, other, segments
 
 
+def serve_note(nbytes: int, wait_ms: float) -> str:
+    return f"{nbytes} wait={wait_ms:.1f}" if wait_ms >= 0.05 else str(nbytes)
+
+
+def parse_serve_note(note: str) -> "tuple[int, float]":
+    """An ``upload_serve`` event's note, "<bytes>" or "<bytes> wait=<ms>":
+    the bytes sent and the ms the request waited before its send began."""
+    head, _, wait = note.partition(" wait=")
+    try:
+        return int(head or 0), float(wait or 0.0)
+    except ValueError:
+        return 0, 0.0
+
+
+def parse_sources_note(note: str) -> dict:
+    """A ``task_sources`` event's note, "seed=<n> peer=<n> origin=<n>", as
+    ``{"seed_bytes": n, "peer_bytes": n, "origin_bytes": n}``."""
+    out = {"seed_bytes": 0, "peer_bytes": 0, "origin_bytes": 0}
+    for part in note.split():
+        key, _, value = part.partition("=")
+        if key + "_bytes" in out and value.isdigit():
+            out[key + "_bytes"] = int(value)
+    return out
+
+
+def _union_s(spans: list) -> float:
+    total, end = 0.0, float("-inf")
+    for s, e in sorted(spans):
+        if e > end:
+            total += e - max(s, end)
+            end = e
+    return total
+
+
+def raw(tf: TaskFlight) -> dict:
+    """The task's named events as they lie in the ring, uncapped: what
+    lets a reader outside this process fold several daemons' rings on one
+    clock (``start_wall`` is this host's anchored wall clock at the ring's
+    zero). ``digest()`` is the bounded form that ships unasked."""
+    return {
+        "task_id": tf.task_id,
+        "state": tf.state,
+        "start_wall": tf.start_wall,
+        "wall_s": round(tf.wall_s(), 6),
+        "events_total": tf.events_total,
+        "events_dropped": tf.events_dropped,
+        "events": [[round(t, 6), EVENT_NAMES.get(code, str(code)), piece,
+                    round(aux, 3), note]
+                   for t, code, piece, aux, note in tf.events()],
+    }
+
+
 def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
             max_waterfall: int = 256, max_segments: int = 256) -> dict:
     """Fold a task's event ring into the phase breakdown + per-piece
@@ -570,6 +635,9 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
     hbm: dict = {}
     client: dict = {}
     parent: dict = {}
+    sources: dict = {}
+    serves: list = []             # (start_s, end_s) of each upload_serve
+    served_bytes, serve_wait_ms = 0, 0.0
     for _t, code, piece, aux, note in events:
         name = EVENT_NAMES.get(code, str(code))
         counts[name] = counts.get(name, 0) + 1
@@ -585,6 +653,16 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
         elif code == EV_PARENT_SOURCE_FIRST_BYTE:
             # The earliest: the wait before the parent's first piece.
             parent.setdefault("source_first_byte_ms", round(aux, 3))
+        elif code == EV_UPLOAD_SERVE:
+            # Serving is no phase of this task's own download (a seed
+            # serves while and after it pulls): a block of its own.
+            serves.append((_t - aux / 1000.0, _t))
+            nbytes, wait_ms = parse_serve_note(note)
+            served_bytes += nbytes
+            serve_wait_ms += wait_ms
+        elif code == EV_TASK_SOURCES:
+            sources = parse_sources_note(note)
+            sources["parents"] = piece
         if code in _SINK_STEPS:
             # Where a landing's time went, by step, whether or not it fell
             # inside the task's wall time (finalize runs after the
@@ -629,6 +707,12 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
         # A P2P child's completion: its wait for a certifying parent, and
         # that parent's own spans as its sync stream carried them.
         "parent": parent,
+        # Where the bytes came from (a child), and what this daemon sent
+        # to others of the task (busy_ms: the union of the sends).
+        "sources": sources,
+        "upload": {"serves": len(serves), "bytes": served_bytes,
+                   "busy_ms": round(_union_s(serves) * 1000.0, 3),
+                   "wait_ms": round(serve_wait_ms, 3)} if serves else {},
         "pieces": ordered[:max_waterfall],
         "pieces_truncated": truncated,
     }
@@ -698,6 +782,18 @@ def render_waterfall(report: dict) -> str:
     if parts:
         lines.append("completion, the parent's spans on its own clock: "
                      + "; ".join(parts))
+    src = report.get("sources") or {}
+    if src:
+        lines.append(
+            f"sources, bytes: seed={src['seed_bytes']} "
+            f"peers={src['peer_bytes']} origin={src['origin_bytes']} from "
+            f"{src['parents']} parent(s)")
+    up = report.get("upload") or {}
+    if up:
+        lines.append(
+            f"upload: served {up['serves']} piece(s), {up['bytes']} bytes, "
+            f"to others of this task; sending {up['busy_ms']:.1f} ms "
+            f"(union), waited {up['wait_ms']:.1f} ms before sends")
     advisory = runtime_advisory(report)
     if advisory:
         lines.append(advisory)
@@ -836,8 +932,16 @@ class FlightRecorder:
         # task's autopsy shows what the PROCESS was doing, not just what
         # the task saw.
         self.runtime = None
+        # Callables that bring in events measured outside Python (the
+        # native upload server's serve log): sync() runs them before a
+        # ring is read for a report.
+        self.feeders: list = []
         self._tasks: "OrderedDict[str, TaskFlight]" = OrderedDict()
         self._lock = threading.Lock()
+
+    def sync(self) -> None:
+        for bring in list(self.feeders):
+            bring()
 
     def task(self, task_id: str) -> TaskFlight:
         tf = self._tasks.get(task_id)
@@ -1007,8 +1111,25 @@ class PodAggregator:
         if entry is None:
             while len(self._tasks) >= self.max_tasks:
                 self._tasks.popitem(last=False)
-            entry = self._tasks[task_id] = {"hosts": {}, "quarantine": []}
+            entry = self._tasks[task_id] = {"hosts": {}, "quarantine": [],
+                                            "fanout": {}}
         return entry
+
+    def note_fanout(self, task_id: str, what: str, host_id: str = "") -> None:
+        """The task as the scheduler saw it fan out: ``register`` /
+        ``finished`` / ``failed`` of a peer on ``host_id``, a ``handout`` of
+        parents, a ``reschedule`` asked for, a peer sent ``back_source``.
+        Counts, and the anchored wall time of the first register and the
+        last finish: a slow fan-out (many handouts, a long first-to-last)
+        can be told from one slow host."""
+        f = self._task(task_id)["fanout"]
+        f[what] = f.get(what, 0) + 1
+        now = anchored_wall()
+        if what == "register":
+            f.setdefault("first_register_at", now)
+            f.setdefault("hosts", set()).add(host_id)
+        elif what == "finished":
+            f["last_finished_at"] = now
 
     def _host(self, task_id: str, host_id: str) -> dict:
         hosts = self._task(task_id)["hosts"]
@@ -1092,4 +1213,18 @@ class PodAggregator:
             "slowest_host": slowest,
             "dominant_phase": dominant,
             "quarantine": list(entry["quarantine"]),
+            "fanout": self._fanout_report(entry["fanout"]),
         }
+
+    @staticmethod
+    def _fanout_report(f: dict) -> dict:
+        if not f:
+            return {}
+        out = {k: f.get(k, 0) for k in ("register", "handout", "reschedule",
+                                        "back_source", "finished", "failed")}
+        out["hosts"] = len(f.get("hosts", ()))
+        first, last = f.get("first_register_at"), f.get("last_finished_at")
+        out["first_register_at"] = first
+        out["register_to_last_finished_s"] = round(last - first, 6) \
+            if first is not None and last is not None else None
+        return out

@@ -225,13 +225,17 @@ class MetricsServer:
     async def _flight_task(self, request: web.Request) -> web.Response:
         """The black-box autopsy: phase breakdown folding the task's event
         ring (sums to wall time) + the per-piece waterfall. ``?format=text``
-        renders the same waterfall ``dfget --explain`` prints."""
+        renders the same waterfall ``dfget --explain`` prints; ``?raw=1``
+        answers with the ring's own events instead (``flight.raw``)."""
         if self._flight is None:
             raise web.HTTPNotFound(text="no flight recorder on this binary\n")
         task_id = request.match_info["task_id"]
+        self._flight.sync()
         tf = self._flight.get(task_id)
         if tf is None:
             raise web.HTTPNotFound(text=f"no flight data for {task_id}\n")
+        if request.query.get("raw") == "1":
+            return web.json_response(flightlib.raw(tf))
         report = flightlib.analyze(tf)
         if request.query.get("format") == "text":
             return web.Response(text=flightlib.render_waterfall(report) + "\n")

@@ -283,6 +283,12 @@ class Server:
                 pass
         except asyncio.CancelledError:
             raise
+        except ConnectionError as e:
+            # The caller closed the connection under a send or the close: a
+            # child that has every piece leaves its parents' sync streams
+            # this way. Nobody is left to answer, and no handler crashed.
+            log.debug("stream's caller went away", method=stream.method,
+                      error=str(e))
         except Exception as e:
             log.error("stream handler crashed", exc_info=True)
             try:

@@ -568,6 +568,7 @@ class SchedulerService:
 
         peer.fsm.event("register_normal")
         REGISTER_SCOPE_COUNT.labels("normal").inc()
+        self.pod_flight.note_fanout(task.id, "register", peer.host.id)
 
         # Seed peers and solo first-comers go straight to origin; everyone
         # else gets parents (back-to-source dedup: ~1 origin fetch per task).
@@ -736,6 +737,7 @@ class SchedulerService:
                     PARENT_PICK_COUNT.labels("intra").inc()
                 else:
                     PARENT_PICK_COUNT.labels("cross").inc()
+            self.pod_flight.note_fanout(task.id, "handout")
             self.scheduling.reattach_peer(peer, result.parents)
             if peer.fsm.can("download"):
                 peer.fsm.event("download")
@@ -862,6 +864,7 @@ class SchedulerService:
         if peer.fsm.can("download_back_to_source"):
             peer.fsm.event("download_back_to_source")
             task.back_to_source_peers.add(peer.id)
+            self.pod_flight.note_fanout(task.id, "back_source")
             if self.fleet is not None:
                 self.fleet.note_back_source(task.id, peer.id, peer.host.id,
                                             reason)
@@ -1179,6 +1182,7 @@ class SchedulerService:
 
     async def _handle_reschedule(self, msg: dict, task: Task, peer: Peer) -> None:
         peer.reschedule_count += 1
+        self.pod_flight.note_fanout(task.id, "reschedule")
         for pid in msg.get("blocklist") or []:
             peer.block_parents.add(pid)
         task.delete_peer_in_edges(peer.id)
@@ -1242,6 +1246,7 @@ class SchedulerService:
         # Finished peer = SUCCEEDED parent + freed upload slots on its old
         # parents: both change candidacy for waiting schedule loops.
         task.notify_parents_changed()
+        self.pod_flight.note_fanout(task.id, "finished", peer.host.id)
         log.info("peer finished", peer=peer.id[:24], task=task.id[:16])
         # Tiny tasks: pull the content off the finisher's upload server so
         # later registrants get it inlined (reference service_v1.go:1196-1210
@@ -1268,6 +1273,7 @@ class SchedulerService:
         # (a failed host is exactly the one an operator wants on the
         # picture); it books no SLO completion.
         self._note_shipped_flight(msg, task, peer)
+        self.pod_flight.note_fanout(task.id, "failed", peer.host.id)
         self._fail_peer(peer)
         # Task fails only when nothing is still making progress.
         still_running = any(

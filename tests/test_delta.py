@@ -141,7 +141,10 @@ class TestManifest:
             DeltaManifest.from_json_bytes(json.dumps(doc).encode())
 
     def test_plan_partition(self):
-        data = os.urandom(1 << 20)
+        # Seeded bytes: the share reused depends on where the four edits
+        # fall among chunks of 4-64 KiB, and over os.urandom's bytes it
+        # read 0.78-0.90, under the bound below in one run of sixty.
+        data = random.Random(10).randbytes(1 << 20)
         mutated = scattered_mutation(data)
         base = build_manifest(data, "v1", P)
         new = build_manifest(mutated, "v2", P)
