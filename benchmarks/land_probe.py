@@ -1,5 +1,5 @@
-"""What a landed piece costs the host: a probe of the pass that reads a
-piece from the store into its staging row and checksums it
+"""What a landed piece costs the host: a probe of the pass that reads
+pieces from the store into their staging rows and checksums them
 (``ops/hbm_sink.py`` "Host passes"), as a re-land through
 ``DeviceSinkManager.finalize`` alone: no scheduler, no transfer, no views.
 
@@ -12,26 +12,30 @@ pieces of 32 MiB and a LAION tar's 30 of 8 MiB, and 60 pieces of 16 MiB
 between them; random bytes, written to a store of the probe's own and so
 in the page cache) and each arrangement,
 the median and the range over ``--repeats`` re-lands, after one that is not
-counted, of ``sink_finalize`` and of the summed ``sink_read`` and
-``sink_checksum`` of the flight, in ms:
+counted, of ``sink_finalize`` and of the summed ``sink_read``,
+``sink_checksum`` and ``sink_stage`` (which holds the wait for a staging
+stack that the runtime is still reading, once the host pass outruns the
+link) of the flight, in ms:
 
-  two-pass widening   the tree before PR 35: every chunk read, the landing
-                      thread waiting; then every chunk checksummed, waiting
-                      again; sum32 as ``np.sum(dtype=uint64) & 0xFFFFFFFF``
-  two-pass wrapping   the same two passes, sum32 as a wrapping uint32 sum
-  fused wrapping      the tree as it is: a helper reads its chunk and
-                      checksums it before it returns
-  fused blocked N     the same, the two reductions over blocks of N KiB in
-                      turn, so that a block is read from memory once
-  fused wrapping, h/f the tree's pass with h helpers (1: no hand-over) and
-                      a chunk floor of f KiB
+  a pass a piece      the tree before PR 40, and a cold pull's path still:
+                      one pass and one wait a piece (``HBMSink.free_rows``
+                      patched to 1), a helper reads its chunk of the piece
+                      and checksums it before it returns
+  a pass a stack      the tree as it is: the backfill's pass takes what the
+                      open stack has free, every piece cut as it would be
+                      alone, ONE wait for all the chunks
+  a stack, whole pieces  the same with a piece a chunk (``cuts`` patched):
+                      eight hand-overs a stack where the tree makes 32-64
+  a pass a stack, h/f the tree's pass with h helpers (1: one thread takes
+                      every piece in turn) and a chunk floor of f KiB
 
-The first two are rebuilt here from the tree's own parts and patched over
-``hbm_sink.read_checksummed`` / ``checksum_numpy``; every arrangement's
-host checksums must equal the first's, and the device verifies each
-landing. The table goes to stdout and to ``chiprun_out/land_probe.json``;
-PERF.md section 5 ("The passes, alone") holds the reading that
-``_HELPERS`` and ``_CHUNK_FLOOR`` rest on.
+All but the tree's are the tree's own code under a patch; every
+arrangement's host checksums must equal the first's, and the device
+verifies each landing. The table goes to stdout and to
+``chiprun_out/land_probe.json``; PERF.md section 5 ("The passes, alone")
+holds the reading that ``_HELPERS`` and ``_CHUNK_FLOOR`` rest on (PR 35's
+rows of the two passes of before it, and of reductions in blocks, are kept
+there; their arrangements left this file with PR 40).
 """
 
 from __future__ import annotations
@@ -53,9 +57,7 @@ sys.path.insert(0, REPO)
 
 OBJECTS = ("1843431563:33554432", "1000000007:16777216",
            "250000384:8388608")
-BLOCKS_KIB = (256, 512, 1024, 2048)
-OTHERS = ("1:2048", "2:2048", "4:2048", "6:2048", "12:2048", "8:1024",
-          "8:4096")
+OTHERS = ("1:2048", "4:2048", "12:2048", "8:1024", "8:4096")
 
 
 def _store(root: str, name: str, length: int, piece_size: int):
@@ -80,75 +82,25 @@ def _store(root: str, name: str, length: int, piece_size: int):
     return store
 
 
-def _widening(data) -> "tuple[int, int]":
-    """``checksum_numpy`` as it was before PR 35."""
-    import numpy as np
-
-    from dragonfly2_tpu.ops.checksum import _pad_to_words
-
-    words = _pad_to_words(data)
-    return (int(np.sum(words, dtype=np.uint64) & 0xFFFFFFFF),
-            int(np.bitwise_xor.reduce(words, initial=np.uint32(0))))
-
-
-def _blocked(block_bytes: int):
-    """``checksum_numpy`` with both reductions over one block at a time."""
-    import numpy as np
-
-    from dragonfly2_tpu.ops.checksum import _pad_to_words
-
-    step = block_bytes // 4
-
-    def checksum(data) -> "tuple[int, int]":
-        words = _pad_to_words(data)
-        s = x = 0
-        for at in range(0, words.size, step):
-            block = words[at:at + step]
-            s += int(np.add.reduce(block, dtype=np.uint32))
-            x ^= int(np.bitwise_xor.reduce(block))
-        return s & 0xFFFFFFFF, x
-
-    return checksum
-
-
-def _two_passes(row, size: int, read_into):
-    """``read_checksummed`` as two passes with a wait after each: PR 28's
-    ``_land_one`` and ``land_piece``, from the tree's own parts."""
-    from dragonfly2_tpu.ops import hbm_sink
-
-    ranges = hbm_sink.cuts(size)
-    t0 = time.perf_counter()
-    if len(ranges) > 1:
-        hbm_sink.side_by_side(lambda a, b: read_into(row, a, b), ranges)
-    else:
-        read_into(row, 0, size)
-    read_s = time.perf_counter() - t0
-    padded = size + (-size) % 4
-    row[size:padded] = 0
-    checksum = hbm_sink.checksum_row(row[:padded], hbm_sink.cuts(padded))
-    return checksum, read_s, len(ranges)
-
-
 def _counted() -> dict:
     from dragonfly2_tpu.ops import hbm_sink
 
     return {**{k: hbm_sink.SINK_PASSES.labels(k)._value.get()
                for k in ("fused", "checksum")},
             **{k: hbm_sink.SINK_PIECES.labels(k)._value.get()
-               for k in ("split", "whole")}}
+               for k in ("batched", "split", "whole")}}
 
 
-def _arrangements(helpers: int, floor: int, blocks_kib, others) -> list:
-    """(name, helpers, chunk floor, read_checksummed or None for the
-    tree's, checksum_numpy or None for the tree's)."""
-    rows = [("two-pass widening", helpers, floor, _two_passes, _widening),
-            ("two-pass wrapping", helpers, floor, _two_passes, None),
-            ("fused wrapping", helpers, floor, None, None)]
-    rows += [(f"fused blocked {kib}", helpers, floor, None,
-              _blocked(kib << 10)) for kib in blocks_kib]
+def _arrangements(helpers: int, floor: int, others) -> list:
+    """(name, helpers, chunk floor, rows a pass may take or None for the
+    stack's, whether a piece is one chunk)."""
+    rows = [("a pass a piece", helpers, floor, 1, False),
+            ("a pass a stack", helpers, floor, None, False),
+            ("a stack, whole pieces", helpers, floor, None, True)]
     for other in others:
         h, kib = (int(v) for v in other.split(":"))
-        rows.append((f"fused wrapping, {h}/{kib}", h, kib << 10, None, None))
+        rows.append((f"a pass a stack, {h}/{kib}", h, kib << 10, None,
+                     False))
     return rows
 
 
@@ -170,7 +122,8 @@ async def _reland(mgr, store, repeats: int) -> dict:
         counted = {k: n - before[k] for k, n in _counted().items()}
         mgr.discard(task_id)
         del sink
-        ms = {"sink_finalize": 0.0, "sink_read": 0.0, "sink_checksum": 0.0}
+        ms = {"sink_finalize": 0.0, "sink_read": 0.0, "sink_checksum": 0.0,
+              "sink_stage": 0.0}
         for _, code, _, aux, _ in tf.events():
             name = flight.EVENT_NAMES[code]
             if name in ms:
@@ -180,7 +133,8 @@ async def _reland(mgr, store, repeats: int) -> dict:
     out = {"counted": counted, "checksums": checksums}
     for key, name in (("finalize_ms", "sink_finalize"),
                       ("read_ms", "sink_read"),
-                      ("checksum_ms", "sink_checksum")):
+                      ("checksum_ms", "sink_checksum"),
+                      ("stage_ms", "sink_stage")):
         values = sorted(r[name] for r in runs)
         out[key] = statistics.median(values)
         out[key + "_range"] = [values[0], values[-1]]
@@ -192,10 +146,8 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--objects", nargs="*", default=list(OBJECTS),
                         help="length:piece_size, bytes")
     parser.add_argument("--repeats", type=int, default=3)
-    parser.add_argument("--blocks-kib", type=int, nargs="*",
-                        default=list(BLOCKS_KIB))
     parser.add_argument("--others", nargs="*", default=list(OTHERS),
-                        help="helpers:floor_KiB of further fused passes")
+                        help="helpers:floor_KiB of further passes a stack")
     args = parser.parse_args(argv)
 
     import jax
@@ -205,7 +157,7 @@ def main(argv: list[str] | None = None) -> int:
 
     device = jax.devices()[0]
     tree = (hbm_sink._HELPERS, hbm_sink._CHUNK_FLOOR, hbm_sink._POOL,
-            hbm_sink.read_checksummed, hbm_sink.checksum_numpy)
+            hbm_sink.HBMSink.free_rows, hbm_sink.cuts)
     root = tempfile.mkdtemp(prefix=".land_probe_", dir=REPO)
     rows = []
     try:
@@ -217,14 +169,17 @@ def main(argv: list[str] | None = None) -> int:
             print(f"[land_probe] {spec}: {len(store.metadata.pieces)} pieces "
                   f"stored in {time.perf_counter() - t0:.1f} s", flush=True)
             first = None
-            for name, h, floor, fused, checksum in _arrangements(
-                    tree[0], tree[1], args.blocks_kib, args.others):
+            for name, h, floor, rows_a_pass, whole in _arrangements(
+                    tree[0], tree[1], args.others):
                 pool = ThreadPoolExecutor(
                     max_workers=h, thread_name_prefix="df-sink-helper")
                 hbm_sink._HELPERS, hbm_sink._CHUNK_FLOOR = h, floor
                 hbm_sink._POOL = pool
-                hbm_sink.read_checksummed = fused or tree[3]
-                hbm_sink.checksum_numpy = checksum or tree[4]
+                hbm_sink.HBMSink.free_rows = (
+                    (lambda sink, n=rows_a_pass: n) if rows_a_pass
+                    else tree[3])
+                hbm_sink.cuts = ((lambda size: [(0, size)]) if whole
+                                 else tree[4])
                 mgr = DeviceSinkManager()
                 try:
                     row = asyncio.run(_reland(mgr, store, args.repeats))
@@ -243,18 +198,18 @@ def main(argv: list[str] | None = None) -> int:
             store.destroy()
     finally:
         (hbm_sink._HELPERS, hbm_sink._CHUNK_FLOOR, hbm_sink._POOL,
-         hbm_sink.read_checksummed, hbm_sink.checksum_numpy) = tree
+         hbm_sink.HBMSink.free_rows, hbm_sink.cuts) = tree
         shutil.rmtree(root, ignore_errors=True)
     out = os.path.join(REPO, "chiprun_out")
     os.makedirs(out, exist_ok=True)
     with open(os.path.join(out, "land_probe.json"), "w") as f:
         json.dump(rows, f, indent=1)
-    print(f"{'object':>22} {'arrangement':>24} {'finalize':>9} {'read':>8} "
-          f"{'checksum':>8}  ms, median of {args.repeats}")
+    print(f"{'object':>22} {'arrangement':>28} {'finalize':>9} {'read':>8} "
+          f"{'checksum':>8} {'stage':>7}  ms, median of {args.repeats}")
     for r in rows:
-        print(f"{r['object']:>22} {r['arrangement']:>24} "
+        print(f"{r['object']:>22} {r['arrangement']:>28} "
               f"{r['finalize_ms']:9.1f} {r['read_ms']:8.1f} "
-              f"{r['checksum_ms']:8.1f}")
+              f"{r['checksum_ms']:8.1f} {r['stage_ms']:7.1f}")
     return 0 if all(r["same_bits"] for r in rows) else 1
 
 

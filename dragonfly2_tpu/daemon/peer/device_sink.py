@@ -16,11 +16,13 @@ otherwise stall the daemon's event loop (upload serving, RPC) for the
 duration of each copy. The async surface awaits that thread, so the
 download path still backpressures on landing. The thread has helpers
 (``ops/hbm_sink.py``: ``df-sink-helper``, one small pool a process) for
-the host pass over a piece of two chunk floors and more
-(``HBMSink.read_piece``): each reads a chunk of the piece from the store
-into the same chunk of the piece's row and checksums it there before it
-returns; they touch no sink and no manager state, and the landing thread
-waits for every one of them before it goes on, a failed chunk's error in
+the host pass over a group of pieces of two chunk floors and more
+(``HBMSink.read_pieces``; a group is one piece as it arrives, and in a
+finalize's backfill as many as the open staging stack has rows free): each
+takes the next chunk of the group, reads it from the store into the same
+chunk of its piece's row and checksums it there before it returns; they
+touch no sink and no manager state, and the landing thread waits ONCE a
+group for every one of them before it goes on, a failed chunk's error in
 hand or not.
 
 Spans: that thread stamps its steps into the task's flight ring
@@ -29,8 +31,10 @@ Spans: that thread stamps its steps into the task's flight ring
 ``sink_assemble`` > ``sink_compile``), one event at a step's end with its
 ms — a child is a span that lies inside another, there being one thread
 that stamps: the helpers stamp nothing, so ``sink_read`` and
-``sink_checksum`` are the two parts of the one pass's wall time (its
-longest read, and the rest) however many ran it.
+``sink_checksum`` are the two parts of the one pass's wall time (what
+the thread that read longest spent reading, and the rest) however many ran
+it. A ``sink_land`` is one pass and the staging of its pieces: a piece as
+it arrives, a group of the backfill (``piece`` the group's lowest).
 Before them each job stamps ``sink_wait``, the time it stood queued for
 the thread: a re-land is ONE job (``_finalize_sync``), so several tasks
 landing at once wait for each other's whole landings there. A landing that
@@ -309,22 +313,27 @@ class DeviceSinkManager:
         with self._span(tf and tf.record, flightlib.EV_SINK_LAND, rec.num):
             self._land_inner(task_id, store, rec, tf)
 
-    def _land_one(self, sink: TaskDeviceSink, store, rec, tf) -> None:
-        """Read one piece back from the store, straight into the row of
-        the sink's staging stack it will be put from, checksummed in the
-        same pass, and stage it."""
+    def _land(self, sink: TaskDeviceSink, store, recs, tf) -> None:
+        """Read a group of pieces back from the store, straight into the
+        rows of the sink's staging stack they will be put from,
+        checksummed in the same pass, and stage them: at most the rows the
+        open stack has free (``HBMSink.free_rows``)."""
         sink.stamp.flight = tf
-        stored = store.piece(rec.num)
-        # A range of the piece into the same range of its row. Where
-        # helpers read the ranges, a failure in one is raised when all are
-        # back, so none can still write into a stack that the degraded
-        # sink has given up.
-        data = sink.sink.read_piece(
-            rec.num, stored.size,
-            lambda row, start, stop: store.read_into(
-                stored.offset + start, stop - start, row, at=start))
+        stored = [store.piece(rec.num) for rec in recs]
+
+        # A range of a piece into the same range of its row. Where helpers
+        # read the ranges, a failure in one is raised when all are back,
+        # so none can still write into a stack that the degraded sink has
+        # given up.
+        def read_into(i: int, row, start: int, stop: int) -> None:
+            store.read_into(stored[i].offset + start, stop - start, row,
+                            at=start)
+
+        landed = sink.sink.read_pieces(
+            [(piece.num, piece.size) for piece in stored], read_into)
         store.touch()
-        sink.land(rec.num, data, rec.digest)
+        for rec, data in zip(recs, landed):
+            sink.land(rec.num, data, rec.digest)
 
     def _land_inner(self, task_id: str, store, rec, tf) -> None:
         if task_id in self._degraded:
@@ -344,7 +353,7 @@ class DeviceSinkManager:
                         task=task_id[:16], piece=rec.num)
             return
         try:
-            self._land_one(sink, store, rec, tf)
+            self._land(sink, store, [rec], tf)
         except Exception as e:
             # Device trouble mid-stream (HBM OOM in the staging device_put,
             # runtime errors): degrade THIS task to disk-only — the
@@ -470,12 +479,18 @@ class DeviceSinkManager:
             if sink is None:
                 return None
         sink.stamp.flight = tf
-        for rec in store.get_pieces():
-            if rec.num not in sink.landed:
-                with self._span(tf and tf.record, flightlib.EV_SINK_LAND,
-                                rec.num):
-                    self._land_one(sink, store, rec, tf)
-                step.piece += 1
+        # Every missing piece is in the store before the first is read and
+        # nothing orders the reads: a host pass takes as many as the open
+        # stack has rows free.
+        missing = [rec for rec in store.get_pieces()
+                   if rec.num not in sink.landed]
+        while missing:
+            group = missing[:sink.sink.free_rows()]
+            del missing[:len(group)]
+            with self._span(tf and tf.record, flightlib.EV_SINK_LAND,
+                            group[0].num):
+                self._land(sink, store, group, tf)
+            step.piece += len(group)
         sink.verify()
         self._settle(sink)
         log.info("device sink verified", task=task_id[:16],

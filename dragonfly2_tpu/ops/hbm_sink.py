@@ -33,7 +33,7 @@ its pages have been touched before: a fresh 32 MiB buffer costs 32-41 ms
 of page faults on the chip's host, a fresh 256 MiB stack 260 ms (PERF.md
 section 5), against 35-65 ms for the transfer itself. The daemon's piece
 is read from its store straight into the next row and checksummed there
-in the same pass (``read_piece``), and that row is handed to
+in the same pass (``read_pieces``), and that row is handed to
 ``land_piece``, which copies nothing then; any other bytes are copied into
 the row once and checksummed where they lie. Rows lie in arrival order, on
 the host and on the device. ``flush`` puts the stack (``_put``), and only
@@ -42,22 +42,27 @@ when the device array is ready does the stack go back to the free list:
 On the CPU backend an aligned buffer is aliased, not copied, for the
 device array's whole life, so there ``_put`` copies the rows first.
 
-Host passes: a piece the sink reads itself costs the host ONE pass
-(``read_piece`` -> ``read_checksummed``): its bytes are read from the
-store into its row and checksummed there while they are still in the
-cache. Both halves are plain memory traffic that lets go of the GIL, so a
-piece of at least two ``_CHUNK_FLOOR``s is cut into page-aligned chunks
-(``cuts``), views of the row itself, and each of a few helper threads
-reads its chunk and checksums it before it returns (``side_by_side``),
-while the thread that lands the piece waits once for all of them. The
-helpers hold no sink state and stamp nothing; the piece's checksum is the
-fold of its chunks' and is ``checksum_numpy`` of the padded row, bit for
-bit. Smaller pieces run the same read-then-checksum where they are, with
-no hand-over. Bytes a caller brings to ``land_piece`` (a record of
-``DeviceFeed``, a delta chunk, the benchmark's controls) were read by
-someone else: they are copied into the row and cost a checksum pass of
-their own there (``checksum_row``), cut the same way. Either way the host
-checksums exactly the bytes it hands to ``device_put``.
+Host passes: the pieces the sink reads itself cost the host ONE pass a
+group (``read_pieces`` -> ``read_checksummed``): a group is the pieces
+that go into the next free rows of the open stack, one as a piece arrives
+(``on_piece``), as many as the stack has rows free where every piece is in
+the store before the first is read (a re-land's backfill). Their bytes are
+read from the store into their rows and checksummed there while they are
+still in the cache. Both halves are plain memory traffic that lets go of
+the GIL, so a group of at least two ``_CHUNK_FLOOR``s becomes a work list
+of page-aligned chunks, views of the rows themselves and none across two
+pieces (``cuts`` of every piece), and each of a few helper threads takes
+the next chunk, reads it and checksums it before it returns
+(``side_by_side``), while the thread that lands the group waits ONCE for
+all of them. The helpers hold no sink state and stamp nothing; a piece's
+checksum is the fold of its chunks' and is ``checksum_numpy`` of the
+padded row, bit for bit. Smaller groups run the same read-then-checksum
+where they are, with no hand-over. Bytes a caller brings to
+``land_piece`` (a record of ``DeviceFeed``, a delta chunk, the benchmark's
+controls) were read by someone else: they are copied into the row and cost
+a checksum pass of their own there (``checksum_row``), cut the same way.
+Either way the host checksums exactly the bytes it hands to
+``device_put``.
 
 Memory, as the v5e compiler reports it for the assembly program
 (``memory_analysis()``, tests/test_chip_compile.py): staged batches
@@ -126,18 +131,20 @@ _ROWS_IN_PLACE = SINK_ROWS.labels("in_place")
 _ROWS_COPIED = SINK_ROWS.labels("copied")
 SINK_PIECES = metrics.counter(
     "device_sink_pieces_total",
-    "Pieces landed, by how the host pass that checksummed them ran: cut "
-    "into chunks over the helper threads (split) or on the landing thread "
-    "alone (whole)",
+    "Pieces landed, by how the host pass that checksummed them ran: one "
+    "pass over several pieces of a stack at once (batched), or a pass of "
+    "the piece's own, cut into chunks over the helper threads (split) or "
+    "on the landing thread alone (whole)",
     ("how",))
-_PIECES_SPLIT = SINK_PIECES.labels("split")
-_PIECES_WHOLE = SINK_PIECES.labels("whole")
+_PIECES = {how: SINK_PIECES.labels(how)
+           for how in ("batched", "split", "whole")}
 SINK_PASSES = metrics.counter(
     "device_sink_host_passes_total",
-    "Host passes over pieces' bytes: a piece read from the store into its "
-    "row and checksummed in one hand-over (fused), or a checksum alone, of "
-    "bytes a caller brought (checksum). Over device_sink_pieces_total: 1.0 "
-    "where every piece is the sink's own read",
+    "Host passes over pieces' bytes: a group of pieces read from the store "
+    "into their rows and checksummed under one wait (fused), or a checksum "
+    "alone, of one piece's bytes that a caller brought (checksum). "
+    "device_sink_pieces_total over the fused passes: pieces a pass, 1.0 "
+    "where pieces land as they arrive, the stack's rows in a re-land",
     ("kind",))
 _PASSES_FUSED = SINK_PASSES.labels("fused")
 _PASSES_CHECKSUM = SINK_PASSES.labels("checksum")
@@ -185,12 +192,14 @@ def cuts(size: int) -> "list[tuple[int, int]]":
     return [(at, min(at + step, size)) for at in range(0, size, step)]
 
 
-def side_by_side(fn, ranges) -> list:
-    """``fn(start, stop)`` of every range, on the helper threads at once;
-    the results in the ranges' order. Returns, or raises the first
-    failure, only when EVERY call has come back: a range is a view of a
-    buffer that its owner may give away the moment this returns."""
-    futures = [_POOL.submit(fn, start, stop) for start, stop in ranges]
+def side_by_side(fn, work) -> list:
+    """``fn(*args)`` of every entry of ``work`` (a range ``(start, stop)``
+    of one row, a chunk ``(row, start, stop)`` of several), on the helper
+    threads at once; the results in ``work``'s order. Returns, or raises
+    the first failure, only when EVERY call has come back: a chunk is a
+    view of a buffer that its owner may give away the moment this
+    returns."""
+    futures = [_POOL.submit(fn, *args) for args in work]
     wait(futures)
     try:
         return [f.result() for f in futures]
@@ -218,29 +227,48 @@ def checksum_row(row: np.ndarray, ranges) -> "tuple[int, int]":
     return _fold(side_by_side(lambda a, b: checksum_numpy(row[a:b]), ranges))
 
 
-def read_checksummed(row: np.ndarray, size: int,
-                     read_into) -> "tuple[tuple[int, int], float, int]":
-    """A piece of ``size`` bytes read into the start of ``row`` and
-    checksummed there in ONE pass: ``read_into(row, start, stop)`` fills
-    ``row[start:stop]`` with the piece's bytes ``[start, stop)``, and
-    whoever read a range (``cuts(size)``; a helper each where there are
-    several) checksums it before it returns, the last one up to the whole
-    word, over padding zeroed here. Returns ``checksum_numpy`` of the
-    padded piece, the seconds the longest read took, and the number of
-    ranges. Past the padding the row is as it was."""
-    padded = size + (-size) % 4
-    row[size:padded] = 0
+def read_checksummed(rows: np.ndarray, sizes,
+                     read_into) -> "tuple[list, float, int]":
+    """A group of pieces read into consecutive rows of a staging stack and
+    checksummed there in ONE pass: piece ``i`` of ``sizes[i]`` bytes into
+    the start of ``rows[i]``, where ``read_into(i, row, start, stop)``
+    fills ``row[start:stop]`` with that piece's bytes ``[start, stop)``.
+    The work list is every piece's ``cuts``, so no chunk lies across two
+    pieces; where the group holds two floors and more than one chunk, the
+    helpers take the chunks in turn and the caller waits once for all of
+    them, else the caller runs them where it is. Whoever read a chunk
+    checksums it before it returns, a piece's last one up to the whole
+    word, over padding zeroed here. Returns ``checksum_numpy`` of every
+    padded piece, the seconds of the pass that the thread which read
+    longest spent reading (a pass of one piece on idle helpers: its
+    longest chunk's read), and the number of chunks the helpers were
+    handed, 1 where the caller ran the pass itself. Past a piece's padding
+    its row is as it was."""
+    padded = [size + (-size) % 4 for size in sizes]
+    for row, size, end in zip(rows, sizes, padded):
+        row[size:end] = 0
 
-    def one(start: int, stop: int) -> "tuple[int, int, float]":
+    def one(i: int, start: int, stop: int) -> tuple:
+        row = rows[i]
         t0 = time.perf_counter()
-        read_into(row, start, stop)
+        read_into(i, row, start, stop)
         read_s = time.perf_counter() - t0
-        s, x = checksum_numpy(row[start:padded if stop == size else stop])
-        return s, x, read_s
+        s, x = checksum_numpy(row[start:padded[i] if stop == sizes[i]
+                                  else stop])
+        return i, s, x, read_s, threading.get_ident()
 
-    ranges = cuts(size)
-    parts = side_by_side(one, ranges) if len(ranges) > 1 else [one(0, size)]
-    return _fold(parts), max(p[2] for p in parts), len(ranges)
+    work = [(i, start, stop) for i, size in enumerate(sizes)
+            for start, stop in cuts(size)]
+    handed = len(work) if sum(sizes) >= 2 * _CHUNK_FLOOR else 1
+    parts = (side_by_side(one, work) if handed > 1
+             else [one(*chunk) for chunk in work])
+    of_piece: list = [[] for _ in sizes]
+    reading: dict = {}
+    for i, s, x, read_s, thread in parts:
+        of_piece[i].append((s, x))
+        reading[thread] = reading.get(thread, 0.0) + read_s
+    return ([_fold(chunks) for chunks in of_piece], max(reading.values()),
+            handed)
 
 
 def _give_back(view: memoryview) -> None:
@@ -451,9 +479,10 @@ class HBMSink:
         self.platform = self.device.platform
         self.device_kind = self.device.device_kind
         self.host_checksums: dict[int, tuple[int, int]] = {}
-        # What ``read_piece`` left for the ``land_piece`` that follows it:
-        # (piece, size, checksum, chunks) of the row it filled.
-        self._read: tuple | None = None
+        # What ``read_pieces`` left for the ``land_piece``s that follow it:
+        # (piece, size, checksum, how its pass ran) of every row it
+        # filled, the next row's first.
+        self._read: list[tuple] = []
         self.landed: set[int] = set()
         self.batch_pieces = batch_pieces
         # Staged device batches: (the rows' slots, (k, *piece shape)
@@ -515,52 +544,73 @@ class HBMSink:
                 self._open_stack()
         return memoryview(self._stack[len(self._rows)])
 
-    def read_piece(self, piece_num: int, size: int, read_into) -> memoryview:
-        """Piece ``piece_num`` of ``size`` bytes read from the caller's
-        store into the next row and checksummed there, in one pass over
-        its bytes (``read_checksummed``, which says what ``read_into`` is).
-        Returns the piece in its row, for ``land_piece``: handed exactly
-        that, it copies nothing and takes this pass's checksum. Stamps the
-        pass as ``sink_read``, the longest read in it, and
-        ``sink_checksum``, the rest of its wall time, each with the number
-        of chunks as its note where the piece was cut."""
-        view = self.next_row()
-        row = np.frombuffer(view, np.uint8)
-        if size > row.size:
+    def free_rows(self) -> int:
+        """How many pieces the next ``read_pieces`` may take: the rows the
+        open stack has left, a whole stack where none is open."""
+        return self.batch_pieces - len(self._rows)
+
+    def read_pieces(self, pieces, read_into) -> "list[memoryview]":
+        """A group of pieces, ``(piece_num, size)`` each and at most
+        ``free_rows()`` of them, read from the caller's store into the
+        next rows and checksummed there, in one pass over their bytes
+        (``read_checksummed``, which says what ``read_into`` is). Returns
+        each piece in its row, for ``land_piece``: handed exactly those,
+        in this order, it copies nothing and takes this pass's checksums.
+        Stamps the pass once, under the group's lowest piece, as
+        ``sink_read``, what the thread that read longest spent reading,
+        and ``sink_checksum``, the rest of the pass's wall time, each with
+        the number of chunks as its note where there were several."""
+        self.next_row()                 # a stack is open from here on
+        first = len(self._rows)
+        sizes = [size for _, size in pieces]
+        if not 0 < len(pieces) <= self.free_rows():
             raise ValueError(
-                f"piece {piece_num} of {size} bytes in a sink of "
+                f"a group of {len(pieces)} pieces into a stack with "
+                f"{self.free_rows()} rows free")
+        if max(sizes) > self.piece_size:
+            raise ValueError(
+                f"a piece of {max(sizes)} bytes in a sink of "
                 f"{self.piece_size}-byte pieces")
+        rows = self._stack[first:first + len(pieces)]
         t0 = time.perf_counter()
         with TraceAnnotation("df:sink_read_checksum"):
-            checksum, read_s, chunks = read_checksummed(row, size, read_into)
+            checksums, read_s, chunks = read_checksummed(rows, sizes,
+                                                         read_into)
         pass_s = time.perf_counter() - t0
         _PASSES_FUSED.inc()
         if self.stamp is not None:
+            lowest = min(num for num, _ in pieces)
             note = str(chunks) if chunks > 1 else ""
-            self.stamp(flight.EV_SINK_READ, piece_num, read_s * 1000.0, note)
-            self.stamp(flight.EV_SINK_CHECKSUM, piece_num,
+            self.stamp(flight.EV_SINK_READ, lowest, read_s * 1000.0, note)
+            self.stamp(flight.EV_SINK_CHECKSUM, lowest,
                        (pass_s - read_s) * 1000.0, note)
-        self._read = (piece_num, size, checksum, chunks)
-        return view[:size]
+        how = ("batched" if len(pieces) > 1
+               else "split" if chunks > 1 else "whole")
+        self._read = [(num, size, checksum, how)
+                      for (num, size), checksum in zip(pieces, checksums)]
+        return [memoryview(row)[:size] for row, size in zip(rows, sizes)]
 
     def land_piece(self, piece_num: int, data: bytes) -> None:
         """Stage one piece as the next row of the open stack, zero-padded
-        to the piece size. ``data`` is the piece's bytes: what ``read_piece``
-        returned (or the start of the row ``next_row()`` gave), already in
-        place, or any other bytes-like object, copied into the row once.
-        The host checksum recorded for the verification on the device is
-        the one ``read_piece`` took of this row with these bytes in it;
-        after anything else it is taken from the row here. Batched:
-        flushes every ``batch_pieces``."""
+        to the piece size. ``data`` is the piece's bytes: what
+        ``read_pieces`` returned for it (or the start of the row
+        ``next_row()`` gave), already in place, or any other bytes-like
+        object, copied into the row once. The host checksum recorded for
+        the verification on the device is the one ``read_pieces`` took of
+        this row with these bytes in it; after anything else it is taken
+        from the row here. Batched: flushes every ``batch_pieces``."""
         if piece_num < 0 or piece_num >= self.total_pieces:
             # A stray out-of-range piece must not invalidate (and on a
             # drained sink, zero out) the assembled content.
             raise ValueError(
                 f"piece {piece_num} out of range for "
                 f"{self.total_pieces}-piece sink")
-        # Whatever is landed now, no later piece may take this reading.
-        read, self._read = self._read, None
+        # The reading of the next row, if there is one. Whatever is landed
+        # now that is not its piece in its row, no later piece may take a
+        # reading.
+        read = self._read.pop(0) if self._read else None
         if piece_num in self.landed:
+            self._read.clear()
             return
         with span(self.stamp, flight.EV_SINK_STAGE, piece_num):
             if self._stack is None:
@@ -581,19 +631,20 @@ class HBMSink:
             row[given.size:] = 0
         if in_place and read is not None and read[:2] == (piece_num,
                                                           given.size):
-            checksum, chunks = read[2:]
+            checksum, how = read[2:]
         else:
+            self._read.clear()
             with span(self.stamp, flight.EV_SINK_CHECKSUM,
                       piece_num) as step:
                 # Whole words, the zero padding included: nothing to copy.
                 words = row[:given.size + (-given.size) % 4]
                 ranges = cuts(words.size)
-                chunks = len(ranges)
-                if chunks > 1:
-                    step.note = str(chunks)
+                if len(ranges) > 1:
+                    step.note = str(len(ranges))
                 checksum = checksum_row(words, ranges)
             _PASSES_CHECKSUM.inc()
-        (_PIECES_SPLIT if chunks > 1 else _PIECES_WHOLE).inc()
+            how = "split" if len(ranges) > 1 else "whole"
+        _PIECES[how].inc()
         self.host_checksums[piece_num] = checksum
         self._rows.append(piece_num)
         self.landed.add(piece_num)

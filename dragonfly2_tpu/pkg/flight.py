@@ -94,12 +94,13 @@ EV_GC_PAUSE = 28       # slow cyclic-GC pause during this task (aux=pause_s)
 # aux = its duration in ms (start = t - aux/1000, as landed/source_landed
 # back theirs out). All stamped by the one df-device-sink thread, so a
 # span's children are the spans its interval contains.
-EV_SINK_LAND = 29      # one piece's on-thread work (piece=num)
-# A piece the sink reads itself is ONE host pass (HBMSink.read_piece: each
-# helper reads its chunk from the store into the row and checksums it before
-# it returns); the two events are that pass's two shares, stamped together.
-EV_SINK_READ = 30      # the longest store read inside the pass (piece=num, note=chunks)
-EV_SINK_CHECKSUM = 31  # the pass less that read; or a checksum pass over bytes a caller brought (piece=num)
+EV_SINK_LAND = 29      # one host pass and the staging of its pieces: a piece as it arrives, a group of a finalize's backfill (piece=num, the group's lowest)
+# The pieces the sink reads itself cost ONE host pass a group
+# (HBMSink.read_pieces: each helper takes the next chunk, reads it from the
+# store into its row and checksums it before it returns); the two events are
+# that pass's two parts, stamped together, once a group.
+EV_SINK_READ = 30      # what the thread that read longest spent reading inside the pass (piece=num, the group's lowest, note=chunks)
+EV_SINK_CHECKSUM = 31  # the pass less that (same piece and note); or a checksum pass over bytes a caller brought (piece=num, note=chunks)
 EV_SINK_STAGE = 32     # what is left of staging: taking a stack, copying foreign bytes, zeroing a short tail, the batch's order (piece=num, lowest slot, or -1)
 EV_SINK_PUT = 33       # flush: the device_put call (piece=lowest slot)
 EV_SINK_ASSEMBLE = 34  # assembly dispatch -> checksums on host (piece=batches)

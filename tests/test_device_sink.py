@@ -1135,8 +1135,20 @@ def test_streamed_landing_is_the_stores_bytes_whatever_the_order(
 
 
 def test_two_tasks_of_different_piece_sizes_interleaved_on_one_manager(
-        run_async, tmp_path):
+        run_async, tmp_path, monkeypatch):
+    import jax
+
     from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops import hbm_sink
+
+    # A put that the runtime has read when it returns: whether a sink holds
+    # one stack or two at a time is then no matter of microseconds (a
+    # backfill opens its next stack right after the put), and "no stack is
+    # new" is about the free list alone.
+    put = hbm_sink._put
+    monkeypatch.setattr(
+        hbm_sink, "_put",
+        lambda rows, device: jax.block_until_ready(put(rows, device)))
 
     async def body():
         big, big_content = _stored(tmp_path, "t-big", 64 * 1024,
@@ -1368,16 +1380,19 @@ def _pieces_counted() -> dict:
     from dragonfly2_tpu.ops import hbm_sink
 
     return {how: hbm_sink.SINK_PIECES.labels(how)._value.get()
-            for how in ("split", "whole")}
+            for how in ("batched", "split", "whole")}
 
 
+@pytest.mark.parametrize("path", ["re-land", "streamed"])
 @pytest.mark.parametrize("passes", ["split", "whole"])
 def test_a_piece_of_two_floors_is_split_and_a_smaller_one_is_not(
-        run_async, tmp_path, monkeypatch, own_helpers, passes):
-    """Ten pieces of 64 KiB, the last short and not whole words, re-landed:
-    with the floor at 16 KiB (patched: there is no option) every pass runs
-    in chunks on the helpers; at the real floor none does and no helper
-    thread exists. Either way the words are the store's bytes."""
+        run_async, tmp_path, monkeypatch, own_helpers, passes, path):
+    """Ten pieces of 64 KiB, the last short and not whole words, streamed
+    piece by piece or re-landed in groups of a stack of four: with the
+    floor at 16 KiB (patched: there is no option) every pass runs in
+    chunks on the helpers; at the real floor none does and no helper
+    thread exists. Either way the words are the store's bytes, and only
+    the backfill's pieces count as ``batched``."""
     from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
     from dragonfly2_tpu.ops import hbm_sink
     from dragonfly2_tpu.pkg import flight
@@ -1387,13 +1402,18 @@ def test_a_piece_of_two_floors_is_split_and_a_smaller_one_is_not(
         monkeypatch.setattr(hbm_sink, "_CHUNK_FLOOR", 16 * 1024)
 
     async def body():
-        store, content = _stored(tmp_path, "t-" + passes, piece,
+        store, content = _stored(tmp_path, f"t-{passes}-{path}", piece,
                                  piece * pieces - 30_001)
-        tf = flight.TaskFlight(store.metadata.task_id)
+        task_id = store.metadata.task_id
+        tf = flight.TaskFlight(task_id)
         mgr = DeviceSinkManager(batch_pieces=4)
         before, counted = _counts(), _pieces_counted()
         try:
-            sink = await mgr.finalize(store.metadata.task_id, store, tf)
+            if path == "streamed":
+                for n in range(pieces):
+                    await mgr.on_piece(task_id, store,
+                                       store.metadata.pieces[n], tf)
+            sink = await mgr.finalize(task_id, store, tf)
             assert sink is not None and sink.verified
             words = np.asarray(sink.as_words()).tobytes()
             assert words[:len(content)] == b"".join(
@@ -1411,17 +1431,22 @@ def test_a_piece_of_two_floors_is_split_and_a_smaller_one_is_not(
                     if flight.EVENT_NAMES[code] == name]
              for name in ("sink_read", "sink_checksum")}
     helpers = [t.name for t in own_helpers._threads]
+    none = {"batched": 0, "split": 0, "whole": 0}
+    # 64 KiB in four chunks; the last piece's 35,535 bytes in two.
+    if path == "streamed":
+        want = ["4"] * 9 + ["2"] if passes == "split" else [""] * pieces
+        assert counted == {**none, passes: pieces}
+    else:
+        # Two stacks of four pieces, sixteen chunks each; then the last
+        # two pieces' six. Under the real floor a group of 256 KiB is
+        # handed to no one.
+        want = ["16", "16", "6"] if passes == "split" else [""] * 3
+        assert counted == {**none, "batched": pieces}
+    assert notes == {"sink_read": want, "sink_checksum": want}
     if passes == "split":
-        assert counted == {"split": pieces, "whole": 0}
-        # 64 KiB in four chunks; the last piece's 35,535 bytes in two.
-        assert notes == {"sink_read": ["4"] * 9 + ["2"],
-                         "sink_checksum": ["4"] * 9 + ["2"]}
         assert helpers and all(
             name.startswith("df-sink-helper") for name in helpers)
     else:
-        assert counted == {"split": 0, "whole": pieces}
-        assert notes == {"sink_read": [""] * pieces,
-                         "sink_checksum": [""] * pieces}
         assert helpers == []
 
 
@@ -1429,9 +1454,11 @@ def test_a_piece_of_two_floors_is_split_and_a_smaller_one_is_not(
 def test_a_split_piece_costs_the_helpers_one_hand_over_a_chunk(
         run_async, tmp_path, monkeypatch, own_helpers, path):
     """Ten pieces of 64 KiB under a floor of 16 KiB, the last of 35,535
-    bytes: nine pieces in four chunks and one in two are 38 submits to the
-    pool, not 76, and every piece is one fused pass: host passes over
-    pieces reads 1.0."""
+    bytes, into stacks of four: nine pieces in four chunks and one in two
+    are 38 submits to the pool however they are grouped (a chunk never
+    lies across two pieces). Streamed, eight of them are a pass each and
+    the backfill's two ONE pass; re-landed, three passes take four, four
+    and two. Pieces over fused passes says how many a pass took."""
     from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
     from dragonfly2_tpu.ops import hbm_sink
     from tests.test_tpu_ops import _passes as _passes_counted
@@ -1468,19 +1495,26 @@ def test_a_split_piece_costs_the_helpers_one_hand_over_a_chunk(
 
     passes, counted = run_async(body(), timeout=120)
     assert len(submits) == 9 * 4 + 2
-    assert passes == {"fused": pieces, "checksum": 0}
-    assert passes["fused"] / sum(counted.values()) == 1.0
-    assert counted == {"split": pieces, "whole": 0}
+    if path == "streamed":
+        assert passes == {"fused": 8 + 1, "checksum": 0}
+        assert counted == {"batched": 2, "split": 8, "whole": 0}
+    else:
+        assert passes == {"fused": 3, "checksum": 0}
+        assert counted == {"batched": pieces, "split": 0, "whole": 0}
+        assert sum(counted.values()) / passes["fused"] == pieces / 3
+    # Every chunk inside one piece: (row of the group, start, stop).
+    assert all(0 <= start < stop <= piece for _, start, stop in submits)
 
 
 @pytest.mark.parametrize("passes", ["split", "whole"])
 def test_a_pieces_two_events_sum_to_the_pass(run_async, tmp_path,
                                              monkeypatch, passes):
-    """Under a clock that ticks a ms a reading: each piece stamps ONE
-    ``sink_read`` and ONE ``sink_checksum`` on the landing thread, whose
-    ``aux`` sum to the span the test puts around the pass, to the two
-    readings ``read_piece`` makes outside it; ``sink_read`` is a reading
-    inside the pass (the longest read), and the note is the chunks."""
+    """Under a clock that ticks a ms a reading: each pass (six pieces into
+    stacks of four are two) stamps ONE ``sink_read`` and ONE
+    ``sink_checksum`` on the landing thread, whose ``aux`` sum to the span
+    the test puts around the pass, to the two readings ``read_pieces``
+    makes outside it; ``sink_read`` is readings inside the pass (what one
+    thread spent reading, the most), and the note is the chunks."""
     import itertools
     import types
 
@@ -1496,10 +1530,10 @@ def test_a_pieces_two_events_sum_to_the_pass(run_async, tmp_path,
     monkeypatch.setattr(hbm_sink, "time", clock)
     sound, around = hbm_sink.read_checksummed, []
 
-    def timed(row, size, read_into):
+    def timed(rows, sizes, read_into):
         t0 = clock.perf_counter()
         try:
-            return sound(row, size, read_into)
+            return sound(rows, sizes, read_into)
         finally:
             around.append((clock.perf_counter() - t0) * 1000.0)
 
@@ -1521,17 +1555,16 @@ def test_a_pieces_two_events_sum_to_the_pass(run_async, tmp_path,
     events = {name: [(p, aux, note) for _, code, p, aux, note in tf.events()
                      if flight.EVENT_NAMES[code] == name]
               for name in ("sink_read", "sink_checksum")}
-    assert [p for p, _, _ in events["sink_read"]] == list(range(pieces))
-    assert [p for p, _, _ in events["sink_checksum"]] == list(range(pieces))
-    assert len(around) == pieces
+    assert [p for p, _, _ in events["sink_read"]] == [0, 4]
+    assert [p for p, _, _ in events["sink_checksum"]] == [0, 4]
+    assert len(around) == 2
     for (_, read, note), (_, rest, same), span in zip(
             events["sink_read"], events["sink_checksum"], around):
         assert span <= read + rest <= span + 2.0 + 1e-6
         assert 1.0 - 1e-6 <= read <= span and rest > 0
         assert note == same
     notes = [note for _, _, note in events["sink_read"]]
-    assert notes == (["4"] * 5 + ["2"] if passes == "split"
-                     else [""] * pieces)
+    assert notes == (["16", "6"] if passes == "split" else ["", ""])
 
 
 @pytest.mark.parametrize("how", ["short-read", "os-error"])
@@ -1539,9 +1572,10 @@ def test_a_pieces_two_events_sum_to_the_pass(run_async, tmp_path,
 def test_a_chunk_that_fails_degrades_the_task_once_every_chunk_is_back(
         run_async, tmp_path, monkeypatch, own_helpers, how, path):
     """Piece 5's second chunk fails at once while its last is still being
-    read (made slow here): the task goes disk-only as for any unreadable
-    piece, and its stacks reach the free list only after the slow chunk
-    is done."""
+    read (made slow here), in a pass of the piece's own or in the
+    backfill's pass over pieces 5-7: the task goes disk-only as for any
+    unreadable piece, and its stacks reach the free list only after the
+    slow chunk is done."""
     import os
     import time
 
@@ -1603,7 +1637,7 @@ def test_two_landing_threads_share_the_helpers(run_async, tmp_path,
     """Two managers in one process (two landing threads) hand chunks to
     the one pool at once, under a switch interval that interleaves the
     threads far more than the default: each sink's words are its own
-    store's bytes, and every piece was split."""
+    store's bytes, and every piece's pass was a group's."""
     import asyncio
     import sys
 
@@ -1638,4 +1672,270 @@ def test_two_landing_threads_share_the_helpers(run_async, tmp_path,
         counted = run_async(body(), timeout=120)
     finally:
         sys.setswitchinterval(interval)
-    assert counted == {"split": 3 * 24, "whole": 0}
+    assert counted == {"batched": 3 * 24, "split": 0, "whole": 0}
+
+
+# -- the backfill's pass takes what the open stack has free (S1 b) ---------
+
+_P = 64 * 1024
+
+
+async def _landed(mgr, store, streamed, tf=None):
+    """``streamed`` through ``on_piece``, then the finalize; what the
+    verified sink holds, as the host's numbers."""
+    task_id = store.metadata.task_id
+    for n in streamed:
+        await mgr.on_piece(task_id, store, store.metadata.pieces[n], tf)
+    sink = await mgr.finalize(task_id, store, tf)
+    assert sink is not None and sink.verified
+    return {"checksums": dict(sink.sink.host_checksums),
+            "words": np.asarray(sink.as_words()),
+            "bytes": bytes(np.asarray(sink.as_bytes_array()))}
+
+
+# Rows shaped like the cells' pieces, a 128th of their size, under a floor of
+# 16 KiB (a 128th of the real one): a piece under the floor, of two floors
+# (two chunks), of four (a tar's 8 MiB: four) and of sixteen (a shard's
+# 32 MiB: eight).
+ROWS = {"1MiB": 8 * 1024, "4MiB": 32 * 1024, "8MiB": 64 * 1024,
+        "32MiB": 256 * 1024}
+
+
+@pytest.mark.parametrize("pieces", [1, 7, 8, 9, 30])
+@pytest.mark.parametrize("row", list(ROWS))
+def test_a_group_pass_gives_the_piece_passes_checksums_bit_for_bit(
+        run_async, tmp_path, monkeypatch, pieces, row):
+    """The same store landed twice into stacks of eight, its last piece
+    short and not whole words: every piece through ``on_piece``, a pass
+    each, and everything through the finalize's backfill, a pass a stack
+    (the last stack short unless the pieces are eight). The same host
+    checksums, which are ``checksum_numpy`` of each piece and which the
+    device verified, and the same words."""
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops import hbm_sink
+    from dragonfly2_tpu.ops.checksum import checksum_numpy
+    from tests.test_tpu_ops import _passes as passes_counted
+
+    piece = ROWS[row]
+    monkeypatch.setattr(hbm_sink, "_CHUNK_FLOOR", 16 * 1024)
+
+    async def body():
+        store, content = _stored(tmp_path, f"t-{pieces}-{row}", piece,
+                                 piece * pieces - piece // 3 - 1)
+        one_by_one, grouped = DeviceSinkManager(), DeviceSinkManager()
+        try:
+            want = await _landed(one_by_one, store, range(pieces))
+            passes, counted = passes_counted(), _pieces_counted()
+            got = await _landed(grouped, store, [])
+        finally:
+            one_by_one.close()
+            grouped.close()
+        return (content, want, got,
+                passes_counted()["fused"] - passes["fused"],
+                {k: n - counted[k] for k, n in _pieces_counted().items()})
+
+    content, want, got, fused, counted = run_async(body(), timeout=120)
+    assert got["checksums"] == want["checksums"] == {
+        n: checksum_numpy(content[n * piece:(n + 1) * piece])
+        for n in range(pieces)}
+    assert np.array_equal(got["words"], want["words"])
+    assert got["bytes"] == want["bytes"] == content
+    # 55 pieces are 7 passes and 30 are 4; one piece alone is no group.
+    assert fused == -(-pieces // 8)
+    last = pieces % 8
+    assert counted["batched"] == (pieces if last != 1 else pieces - 1)
+    assert sum(counted.values()) == pieces
+
+
+# name -> (content bytes, pieces streamed through ``on_piece`` before the
+# finalize, the notes of the passes the finalize's backfill must stamp).
+# Pieces of 64 KiB (four chunks under a floor of 16 KiB) into stacks of four.
+BACKFILLS = {
+    # 4 + 4 + 2, the last piece 35,535 bytes: two chunks, not whole words.
+    "a-short-last-piece": (10 * _P - 30_001, [], ["16", "16", "6"]),
+    "a-group-shorter-than-the-stack": (3 * _P, [], ["12"]),
+    # Rows 0 and 1 of the first stack are taken: the first group is two.
+    "a-stack-half-filled-by-streamed-pieces": (
+        10 * _P - 30_001, [7, 2], ["8", "16", "6"]),
+    # One stack put, 8 in the open one: groups of 0, 3, 6 and of 7, 9.
+    "missing-pieces-that-are-not-neighbours": (
+        10 * _P - 30_001, [1, 2, 4, 5, 8], ["12", "6"]),
+    # What the hook missed in a cold pull is often one piece: its own pass.
+    "one-piece-left-over": (10 * _P, [0, 1, 2, 3, 4, 5, 6, 8, 9], ["4"]),
+    "a-single-piece": (_P - 1_001, [], ["3"]),
+}
+
+
+def _joined(ranges) -> list:
+    """Byte ranges in order, neighbours joined."""
+    out: list = []
+    for start, stop in sorted(ranges):
+        if out and out[-1][1] == start:
+            out[-1][1] = stop
+        else:
+            out.append([start, stop])
+    return out
+
+
+@pytest.mark.parametrize("name", list(BACKFILLS))
+def test_a_backfill_takes_the_open_stacks_free_rows_a_pass(
+        run_async, tmp_path, monkeypatch, name):
+    """Whatever the streaming hook left: the backfill's groups are what the
+    open stack has free, each ONE pass whose note is its chunks; the ranges
+    it reads cover every missing piece once, each inside one piece and
+    into the same place of that piece's row; a group of one is today's
+    cut of that piece."""
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops import hbm_sink
+    from dragonfly2_tpu.pkg import flight
+
+    length, streamed, want_notes = BACKFILLS[name]
+    monkeypatch.setattr(hbm_sink, "_CHUNK_FLOOR", 16 * 1024)
+
+    async def body():
+        store, content = _stored(tmp_path, "t-" + name, _P, length)
+        task_id = store.metadata.task_id
+        reads, read_into = [], store.read_into
+
+        def logged(offset, length, buf, at=0):
+            reads.append((offset, length, at))
+            return read_into(offset, length, buf, at=at)
+
+        mgr = DeviceSinkManager(batch_pieces=4)
+        tf = flight.TaskFlight(task_id)
+        counted = _pieces_counted()
+        try:
+            for n in streamed:
+                await mgr.on_piece(task_id, store, store.metadata.pieces[n])
+            streamed_counted = _pieces_counted()
+            monkeypatch.setattr(store, "read_into", logged)
+            got = await _landed(mgr, store, [], tf)
+        finally:
+            mgr.close()
+        return (content, got, reads, tf,
+                {k: streamed_counted[k] - counted[k] for k in counted},
+                {k: n - streamed_counted[k]
+                 for k, n in _pieces_counted().items()})
+
+    content, got, reads, tf, cold, backfill = run_async(body(), timeout=120)
+    assert got["bytes"] == content
+    pieces = -(-length // _P)
+    missing = sorted(set(range(pieces)) - set(streamed))
+    # A piece that arrives is never batched; the backfill's are, unless
+    # one alone was left.
+    assert cold == {"batched": 0, "split": len(streamed), "whole": 0}
+    assert backfill == ({"batched": len(missing), "split": 0, "whole": 0}
+                        if len(missing) > 1
+                        else {"batched": 0, "split": 1, "whole": 0})
+    events = [(piece, note) for _, code, piece, _, note in tf.events()
+              if flight.EVENT_NAMES[code] == "sink_read"]
+    assert [note for _, note in events] == want_notes
+    lands = [piece for _, code, piece, _, _ in tf.events()
+             if flight.EVENT_NAMES[code] == "sink_land"]
+    assert lands == [piece for piece, _ in events]      # a group's lowest
+    assert all(at == offset % _P and offset // _P == (offset + n - 1) // _P
+               for offset, n, at in reads)
+    assert _joined((offset, offset + n) for offset, n, _ in reads) == _joined(
+        (n * _P, min((n + 1) * _P, length)) for n in missing)
+    if len(missing) == 1:
+        (only,) = missing
+        assert [(offset - only * _P, offset - only * _P + n)
+                for offset, n, _ in sorted(reads)] == hbm_sink.cuts(
+                    min(_P, length - only * _P))
+
+
+def test_a_chunk_of_a_group_that_fails_leaves_no_helper_writing(
+        run_async, tmp_path, monkeypatch):
+    """The backfill's first pass is over pieces 0-3 in sixteen chunks; the
+    chunk that begins piece 2 fails at once while the others are still
+    reading (made slow here). The finalize gives the disk-only result, and
+    no stack goes back to the free list while a helper may still write
+    into it."""
+    import threading
+    import time
+
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+    from dragonfly2_tpu.ops import hbm_sink
+    from dragonfly2_tpu.storage.local_store import StorageError
+
+    monkeypatch.setattr(hbm_sink, "_CHUNK_FLOOR", 16 * 1024)
+    lock, reading, given = threading.Lock(), [0], []
+    give_back = hbm_sink._give_back
+
+    def watched(view):
+        given.append(reading[0])
+        give_back(view)
+
+    monkeypatch.setattr(hbm_sink, "_give_back", watched)
+
+    async def body():
+        store, _ = _stored(tmp_path, "t-chunk-fails", _P, 10 * _P)
+        task_id = store.metadata.task_id
+        read_into = store.read_into
+
+        def failing(offset, length, buf, at=0):
+            if offset == 2 * _P:
+                raise StorageError("short read: the file ends here")
+            with lock:
+                reading[0] += 1
+            try:
+                time.sleep(0.05)
+                return read_into(offset, length, buf, at=at)
+            finally:
+                with lock:
+                    reading[0] -= 1
+
+        monkeypatch.setattr(store, "read_into", failing)
+        mgr = DeviceSinkManager(batch_pieces=4)
+        outstanding = hbm_sink._STAGING.stats()["outstanding"]
+        counted = _pieces_counted()
+        try:
+            assert await mgr.finalize(task_id, store) is None
+            assert mgr.get(task_id) is None
+            return (mgr.outcome(task_id, False)["device_error"],
+                    hbm_sink._STAGING.stats()["outstanding"] - outstanding,
+                    {k: n - counted[k]
+                     for k, n in _pieces_counted().items()})
+        finally:
+            mgr.close()
+
+    error, leaked, counted = run_async(body(), timeout=120)
+    assert "the file ends here" in error and error.startswith("finalize:")
+    assert leaked == 0
+    assert given and not any(given)     # no helper was reading by then
+    assert counted == {"batched": 0, "split": 0, "whole": 0}    # none landed
+
+
+def test_import_a_manager_and_a_sink_start_no_thread_and_take_no_stack():
+    """Nothing of the landing exists before the first piece is landed: the
+    modules imported, a manager and a sink created, and no thread has
+    started (the landing thread and the helpers start with their first
+    job), no staging stack is taken, and the constants are as they were."""
+    import os
+    import subprocess
+    import sys
+
+    code = """
+import threading
+import jax
+jax.devices()
+before = {t.name for t in threading.enumerate()}
+from dragonfly2_tpu.daemon.peer import device_sink
+from dragonfly2_tpu.ops import hbm_sink
+mgr = device_sink.DeviceSinkManager()
+task = device_sink.TaskDeviceSink("t", 30 * (8 << 20) - 5, 8 << 20)
+sink = hbm_sink.HBMSink(55 * (32 << 20) - 5, 32 << 20)
+assert sink.free_rows() == task.sink.free_rows() == 8
+started = {t.name for t in threading.enumerate()} - before
+stats = hbm_sink._STAGING.stats()
+print(sorted(started), len(hbm_sink._POOL._threads), len(mgr._exec._threads),
+      stats["acquires"], stats["retained_bytes"],
+      hbm_sink._HELPERS, hbm_sink._CHUNK_FLOOR, hbm_sink._STACKS_PER_SINK,
+      mgr.batch_pieces)
+mgr.close()
+"""
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split("\n")[-2] == f"[] 0 0 0 0 8 {2 << 20} 2 8"

@@ -27,6 +27,7 @@ import numpy as np
 import pytest
 
 from dragonfly2_tpu.pkg import flight
+from tests.test_device_sink import _pieces_counted as pieces_counted
 
 SINK_NAMES = ("sink_land", "sink_read", "sink_checksum", "sink_stage",
               "sink_put", "sink_assemble", "sink_compile", "sink_finalize")
@@ -62,14 +63,14 @@ class WatchedFlight(flight.TaskFlight):
         super().record(code, piece, aux, note)
 
 
-def check_passes(tf, passes: str) -> None:
-    """One ``sink_read`` and one ``sink_checksum`` a piece whether or not
-    the pass ran in chunks (the counts are checked beside this): both say
-    into how many, and the landing thread stamped them like every other
-    span, the helpers nothing."""
-    notes = {note for _, code, _, _, note in tf.events()
-             if flight.EVENT_NAMES[code] in ("sink_read", "sink_checksum")}
-    assert notes == ({"4", "2"} if passes == "split" else {""})
+def check_passes(tf, passes: str, notes: dict) -> None:
+    """One ``sink_read`` and one ``sink_checksum`` a pass whether or not
+    it ran in chunks on the helpers (the counts are checked beside this):
+    both say into how many (``notes[passes]``), and the landing thread
+    stamped them like every other span, the helpers nothing."""
+    for name in ("sink_read", "sink_checksum"):
+        assert [note for _, code, _, _, note in tf.events()
+                if flight.EVENT_NAMES[code] == name] == notes[passes], name
     assert tf.stampers and all(
         name.startswith("df-device-sink") for name in tf.stampers)
 
@@ -134,7 +135,8 @@ def tree_of(tf) -> list:
     what lies inside what, with no clock compared: a ``sink_land`` holds
     the leaves stamped since the span before it, a ``sink_assemble`` the
     compiles, and a ``sink_finalize`` what is loose by then and the
-    ``sink_land``s it counted in ``piece`` (its backfill)."""
+    ``sink_land``s of its backfill, at most the pieces it counted in
+    ``piece`` (a ``sink_land`` of a backfill is a group of them)."""
     top: list = []
     for _, code, piece, aux, _ in tf.events():
         name = flight.EVENT_NAMES[code]
@@ -175,6 +177,7 @@ def test_cold_landing_stamps_every_span_children_inside_parents(
         store, content = make_store(tmp_path, "t-cold", 64 * 1024 + 64)
         tf = WatchedFlight("t-cold")
         mgr = DeviceSinkManager(batch_pieces=BATCH)
+        counted = pieces_counted()
         try:
             sink = await land_cold(mgr, store, tf, ORDER)
             assert sink is not None and sink.verified
@@ -182,10 +185,17 @@ def test_cold_landing_stamps_every_span_children_inside_parents(
             assert got == content
         finally:
             mgr.close()
-        return tf
+        return tf, {how: n - counted[how]
+                    for how, n in pieces_counted().items()}
 
-    tf = run_async(body(), timeout=120)
-    check_passes(tf, passes)
+    tf, counted = run_async(body(), timeout=120)
+    # A pass a piece, in the order they came: 64 KiB + 64 bytes in four
+    # chunks, the short last piece (9) in two.
+    check_passes(tf, passes, {"split": ["4"] * 8 + ["2", "4"],
+                              "whole": [""] * PIECES})
+    # The hook missed nothing: no piece shared its pass.
+    assert counted == {"batched": 0, "split": 0, "whole": 0,
+                       passes: PIECES}
     spans = spans_of(tf)
     counts = {name: len(rows) for name, rows in spans.items()}
     # Ten pieces, each read, staged and checksummed once on the thread;
@@ -226,7 +236,9 @@ def test_cold_landing_stamps_every_span_children_inside_parents(
 def test_reland_backfill_stamps_the_same_steps_and_one_finalize(
         run_async, tmp_path, passes):
     """A re-land: nothing streamed in, ``_finalize_inner`` backfills every
-    piece from the store through the same landing code."""
+    piece from the store through the same landing code, a stack's free
+    rows a pass: ONE ``sink_land`` > ``sink_read``, ``sink_checksum`` a
+    group, named by its lowest piece, then its pieces' ``sink_stage``s."""
     from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
 
     async def body():
@@ -234,28 +246,50 @@ def test_reland_backfill_stamps_the_same_steps_and_one_finalize(
         tf = WatchedFlight("t-reland")
         tf.finish("done")       # as the cold pull left it
         mgr = DeviceSinkManager(batch_pieces=BATCH)
+        counted = pieces_counted()
         try:
             sink = await mgr.finalize("t-reland", store, tf)
             assert sink is not None and sink.verified
             assert bytes(np.asarray(sink.as_bytes_array())) == content
         finally:
             mgr.close()
-        return tf
+        return tf, {how: n - counted[how]
+                    for how, n in pieces_counted().items()}
 
-    tf = run_async(body(), timeout=120)
-    check_passes(tf, passes)
+    tf, counted = run_async(body(), timeout=120)
+    # Two stacks of four pieces of 64 KiB, four chunks each, then a piece
+    # and a half: four chunks and two. Under the real floor no group of
+    # these is handed to anyone.
+    check_passes(tf, passes, {"split": ["16", "16", "6"],
+                              "whole": ["", "", ""]})
+    # Every piece shared its pass with others of its stack.
+    assert counted == {"batched": PIECES, "split": 0, "whole": 0}
     spans = spans_of(tf)
+    groups = list(range(0, PIECES, BATCH))
     for name in ("sink_land", "sink_read", "sink_checksum"):
-        assert [p for _, _, p in spans[name]] == list(range(PIECES)), name
+        assert [p for _, _, p in spans[name]] == groups, name
     assert len(spans["sink_stage"]) == PIECES + 3 + 3
     assert len(spans["sink_put"]) == 3
     # piece: the staged batches the assembly read.
     assert [p for _, _, p in spans["sink_assemble"]] == [3]
-    # Everything lies in the one finalize, which counted its backfill.
+    # Everything lies in the one finalize, which counted its backfill in
+    # pieces.
     (final,) = tree_of(tf)
     assert (final.name, final.piece) == ("sink_finalize", PIECES)
-    assert [c.piece for c in final.named("sink_land")] == list(range(PIECES))
-    assert [c.name for c in final.children[PIECES:]] == [
+    lands = final.named("sink_land")
+    assert [c.piece for c in lands] == groups
+    for land in lands:
+        first, held = land.piece, min(BATCH, PIECES - land.piece)
+        want = ([("sink_stage", -1), ("sink_read", first),
+                 ("sink_checksum", first)]
+                + [("sink_stage", first + i) for i in range(held)])
+        if held == BATCH:
+            want += [("sink_stage", first), ("sink_put", first)]
+        assert [(c.name, c.piece) for c in land.children] == want, first
+        # One pair a group, inside the group's ``sink_land``.
+        assert sum(c.ms for c in land.named(
+            "sink_read", "sink_checksum")) <= land.ms + 1e-6
+    assert [c.name for c in final.children[len(groups):]] == [
         "sink_stage", "sink_put", "sink_assemble"]
     assert sum(len(c.children) for c in final.children) + len(
         final.children) + 1 == sum(len(rows) for rows in spans.values())
@@ -612,7 +646,8 @@ def test_dfget_device_explain_shows_the_hbm_block(run_async, tmp_path):
     assert "hbm landing, ms on the landing thread" in cold["text"]
     assert cold["digest"]["phases"]["hbm"] == rep["phases"]["hbm"]
     again = reland["report"]
-    assert again["event_counts"]["sink_land"] == 6
+    # The re-land's three pieces are one group of its one stack.
+    assert again["event_counts"]["sink_land"] == 3 + 1
     assert again["event_counts"]["sink_finalize"] == 2
     assert again["hbm"]["read_ms"] > rep["hbm"]["read_ms"]
 

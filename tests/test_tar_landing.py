@@ -342,3 +342,46 @@ def test_a_sink_dropped_before_it_verified_is_no_longer_landing(tmp_path):
             mgr.close()
 
     assert asyncio.run(asyncio.wait_for(body(), 120)) == (1, 0)
+
+
+# -- a re-land's host pass takes a staging stack at once (S1 b) ------------
+
+def passes_of(tf) -> dict:
+    """name -> [(piece, note)] of the flight's ``sink_land``, ``sink_read``
+    and ``sink_checksum`` events: one of each a host pass."""
+    out: dict = {"sink_land": [], "sink_read": [], "sink_checksum": []}
+    for _, code, piece, _, note in tf.events():
+        name = flight.EVENT_NAMES[code]
+        if name in out:
+            out[name].append((piece, note))
+    return out
+
+
+@pytest.mark.parametrize("i", range(TASKS))
+def test_a_reland_a_stack_a_pass_lands_what_piece_by_piece_does(
+        relanded, interleaved, i):
+    """The two scenarios' stores hold the same bytes (same seeds): every
+    round of the re-land, whose pieces went a stack a pass, holds word for
+    word what the landing of one piece a pass holds."""
+    assert relanded.contents[i] == interleaved.contents[i]
+    for r in range(ROUNDS):
+        assert np.array_equal(relanded.words[r, i], interleaved.words[0, i])
+
+
+@pytest.mark.parametrize("case", LANDINGS, ids=landing_id)
+def test_a_pass_a_stack_in_a_reland_and_a_pass_a_piece_streamed(case,
+                                                                request):
+    """Thirty pieces into stacks of eight (the default): a re-land is four
+    passes, of 8, 8, 8 and 6 pieces, each named by its lowest piece; a
+    streamed landing is thirty of one, whatever else queues at the thread.
+    Pieces of 16 KiB are under the chunk floor either way: no hand-over,
+    no note."""
+    name, r, i = case
+    out = request.getfixturevalue(name)
+    passes = passes_of(out.flights[r, i])
+    if out.jobs[r, i] == 1:
+        want = [(0, ""), (8, ""), (16, ""), (24, "")]
+    else:
+        want = [(n, "") for n in range(PIECES)]
+    for name, events in passes.items():
+        assert sorted(events) == want, name

@@ -499,11 +499,11 @@ def _passes() -> dict:
 
 
 def _reads(content: bytes, piece: int, n: int):
-    """``read_into`` of piece ``n`` of ``content``, as a store would give
-    it, and the piece's size."""
+    """``read_into`` of a run of piece ``n`` of ``content`` alone, as a
+    store would give it, and the piece's size."""
     data = content[n * piece:(n + 1) * piece]
 
-    def read_into(row, start, stop):
+    def read_into(_, row, start, stop):
         row[start:stop] = np.frombuffer(data[start:stop], np.uint8)
 
     return read_into, len(data)
@@ -521,7 +521,7 @@ LAST_PIECES = {"whole-4-cuts": 64 * 1024, "4-cuts-odd": 64 * 1024 - 3,
 @pytest.mark.parametrize("name", list(LAST_PIECES))
 def test_the_fused_pass_checksums_the_padded_row_however_it_is_cut(
         staging, monkeypatch, name):
-    """``read_piece`` in a reused stack (an earlier task's 0xff
+    """``read_pieces`` of one piece in a reused stack (an earlier task's 0xff
     everywhere): what it leaves for ``land_piece`` is ``checksum_numpy`` of
     the piece padded to whole words, the pad zeroed before the last range
     was summed; the device agrees, and the helpers were handed one pass."""
@@ -539,7 +539,7 @@ def test_the_fused_pass_checksums_the_padded_row_however_it_is_cut(
     before = _passes()
     for n in (1, 0):
         read_into, size = _reads(content, piece, n)
-        data = sink.read_piece(n, size, read_into)
+        (data,) = sink.read_pieces([(n, size)], read_into)
         assert len(data) == size
         sink.land_piece(n, data)
     assert staging.stats()["free_buffers"] == 0      # the dirty stack, again
@@ -558,7 +558,7 @@ def test_the_fused_pass_checksums_the_padded_row_however_it_is_cut(
 @pytest.mark.parametrize("how", ["another-piece", "the-same-piece-altered",
                                  "the-same-row-shorter"])
 def test_bytes_that_are_not_the_read_are_checksummed_in_the_row(staging, how):
-    """After ``read_piece`` of piece 3, ``land_piece`` is handed something
+    """After ``read_pieces`` of piece 3, ``land_piece`` is handed something
     else: another piece's bytes, the benchmark's control (piece 3's bytes
     with a bit flipped, a copy), or less of the row. The host checksum is
     taken from the row as it is put, never the reading left for piece 3."""
@@ -566,7 +566,7 @@ def test_bytes_that_are_not_the_read_are_checksummed_in_the_row(staging, how):
     content = np.random.RandomState(31).bytes(piece * 6)
     sink = HBMSink(len(content), piece, batch_pieces=batch)
     read_into, size = _reads(content, piece, 3)
-    data = sink.read_piece(3, size, read_into)
+    (data,) = sink.read_pieces([(3, size)], read_into)
     before, reading = _passes(), checksum_numpy(bytes(data))
     if how == "another-piece":
         num, given = 5, content[5 * piece:]
@@ -583,7 +583,7 @@ def test_bytes_that_are_not_the_read_are_checksummed_in_the_row(staging, how):
     # And the reading is gone: the same row again is a pass of its own.
     read_into, size = _reads(content, piece, 0)
     row = sink.next_row()
-    read_into(np.frombuffer(row, np.uint8), 0, size)
+    read_into(0, np.frombuffer(row, np.uint8), 0, size)
     sink.land_piece(0, row[:size])
     assert sink.host_checksums[0] == checksum_numpy(content[:piece])
     assert _passes()["checksum"] - before["checksum"] == 2
@@ -591,6 +591,74 @@ def test_bytes_that_are_not_the_read_are_checksummed_in_the_row(staging, how):
     assert sink.verify()
     landed = np.asarray(sink.as_words()).tobytes()
     assert landed[num * piece:num * piece + len(given)] == bytes(given)
+
+
+def _group_reads(content: bytes, piece: int, nums):
+    """``read_into`` of a group of pieces ``nums`` of ``content``, as a
+    store would give it, the ``(piece, size)`` pairs, and the ranges it
+    was asked for."""
+    asked = []
+
+    def read_into(i, row, start, stop):
+        asked.append((nums[i], start, stop))
+        at = nums[i] * piece
+        row[start:stop] = np.frombuffer(content[at + start:at + stop],
+                                        np.uint8)
+
+    sizes = [len(content[n * piece:(n + 1) * piece]) for n in nums]
+    return read_into, list(zip(nums, sizes)), asked
+
+
+@pytest.mark.parametrize("how", ["as-read", "a-landed-piece-again",
+                                 "one-altered"])
+def test_a_groups_readings_go_to_its_rows_in_order_and_to_nothing_else(
+        staging, how):
+    """``read_pieces`` of pieces 4, 1, 5 into three rows, then
+    ``land_piece`` of each: handed exactly what was returned, in that
+    order, every piece takes the pass's checksum and no other pass runs.
+    Anything else between them (a piece that has landed already, a copy
+    with a bit flipped) costs the piece at fault, and every piece after
+    it, a checksum of its row as it is put: no reading outlives a row it
+    may not describe."""
+    piece, batch = 4096, 4
+    content = np.random.RandomState(37).bytes(piece * 6 - 1_001)
+    sink = HBMSink(len(content), piece, batch_pieces=batch)
+    sink.land_piece(0, content[:piece])
+    assert sink.free_rows() == 3
+    nums = [4, 1, 5]
+    read_into, pieces, asked = _group_reads(content, piece, nums)
+    rows = sink.read_pieces(pieces, read_into)
+    assert [len(row) for row in rows] == [piece, piece, piece - 1_001]
+    # Every piece's bytes once, no range across two pieces.
+    assert sorted(asked) == [(1, 0, piece), (4, 0, piece),
+                             (5, 0, piece - 1_001)]
+    before = _passes()
+    if how == "a-landed-piece-again":
+        sink.land_piece(0, content[:piece])     # dropped, and the readings
+        again = 3
+    elif how == "one-altered":
+        altered = bytearray(rows[1])
+        altered[7] ^= 0x10
+        rows[1] = bytes(altered)
+        again = 2
+    else:
+        again = 0
+    given = {n: bytes(row) for n, row in zip(nums, rows)}
+    for n, row in zip(nums, rows):
+        sink.land_piece(n, row)
+    assert sink.host_checksums == {
+        0: checksum_numpy(content[:piece]),
+        **{n: checksum_numpy(data) for n, data in given.items()}}
+    assert _passes()["checksum"] - before["checksum"] == again
+    assert sink.free_rows() == batch            # the stack was put
+    with pytest.raises(ValueError, match="5 pieces into a stack with 4"):
+        sink.read_pieces([(n, 1) for n in range(5)], read_into)
+    with pytest.raises(ValueError, match="of 4097 bytes"):
+        sink.read_pieces([(2, piece + 1)], read_into)
+    assert sink.verify()
+    landed = np.asarray(sink.as_words()).tobytes()
+    for n, data in given.items():
+        assert landed[n * piece:n * piece + len(data)] == data, n
 
 
 def test_a_second_landing_takes_its_stacks_from_the_free_list(staging):
