@@ -85,12 +85,15 @@ class RangedTask:
     from_p2p: bool
     from_reuse: bool
     names: list[str] = field(default_factory=list)
+    # Ids of the local devices the landed words lie on, the chip they
+    # landed on first.
+    chips: tuple = ()
 
 
 class ShardedTensors(dict):
-    """What ``download_sharded`` returns: name -> device array, a plain
-    dict to every caller, and in ``tasks`` every ranged task the pull made,
-    the header's first."""
+    """What ``download_sharded`` and ``download_global`` return: name ->
+    device array, a plain dict to every caller, and in ``tasks`` every
+    ranged task the pull made, the header's first."""
 
     def __init__(self, tensors=(), tasks=()):
         super().__init__(tensors)
@@ -107,12 +110,35 @@ class HeaderPrefix:
     words: object
     nbytes: int
     tasks: list[RangedTask]
+    sink: object = None     # the guess's TaskDeviceSink, for a later fan-out
+
+
+def _chips(landed_on, words) -> tuple:
+    return (landed_on.id, *sorted(
+        d.id for d in words.devices() if d != landed_on))
 
 
 def _ranged_task(start: int, result: "DeviceResult") -> RangedTask:
     return RangedTask(start, start + result.content_length, result.task_id,
                       result.content_length, result.from_p2p,
-                      result.from_reuse)
+                      result.from_reuse,
+                      chips=_chips(result.sink.device, result.as_words()))
+
+
+def _put(array, sharding):
+    """``jax.device_put`` of a landed array to ``sharding``, the bytes that
+    thereby reach a device from another one counted
+    (``device_sink_hop_bytes_total``)."""
+    import jax
+
+    from dragonfly2_tpu.daemon.peer.device_sink import SINK_HOP_BYTES
+
+    held = array.devices()
+    out = jax.device_put(array, sharding)
+    SINK_HOP_BYTES.labels("device_put").inc(sum(
+        s.data.nbytes for s in out.addressable_shards
+        if s.device not in held))
+    return out
 
 
 async def download_to_device(daemon, url: str, *, digest: str = "",
@@ -122,6 +148,7 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
                              dtype=None, shape=None,
                              mesh=None, axis_name: str = "d",
                              placement: str = "sharded",
+                             device=None,
                              claim: bool = True):
     """Download ``url`` through the embedded daemon's P2P machinery and
     land it in the device sink. Returns a jax.Array when ``dtype``+
@@ -138,6 +165,15 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
     copy differs fails the call (DfError, counted in
     ``device_sink_chip_verify_total``). A mesh of the sink's device alone is
     the plain landing.
+
+    ``device``: the local device (a ``jax.Device`` of this process) the
+    bytes land on: its sink is created there, and staging, assembly, the
+    checksums on the device and every view cut from ``as_words()`` run
+    there. None lands where the daemon's sink manager lands, the first
+    local device. The destination rides with the request and is no part of
+    the task id: hosts that land the same range on different chips still
+    issue byte-identical tasks. With ``mesh`` and ``placement=
+    "replicated"`` it is the chip the fan-out starts from.
 
     ``claim``: take ownership of the sink (the manager forgets it — HBM is
     released when the caller drops the arrays). With ``claim=False`` the
@@ -175,7 +211,7 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
         url=url, output="",
         meta=UrlMeta(digest=digest, tag=tag, application=application,
                      header=header or {}, range=rng),
-        device="tpu",
+        device="tpu", sink_device=device,
     )
     if rng:
         req.range = Range.parse_http(rng)
@@ -192,9 +228,9 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
             async with tm.device_sinks.admit():
                 # The flight begins with the task, so the wait lies before
                 # its first event and inside no phase of its wall time.
+                admitted = time.perf_counter()
                 tm.flight.task(expected_id).record(
-                    flightlib.EV_ADMIT_WAIT, -1,
-                    (time.perf_counter() - asked) * 1000.0)
+                    flightlib.EV_ADMIT_WAIT, -1, (admitted - asked) * 1000.0)
                 async for progress in tm.start_file_task(req):
                     if progress.state == "failed":
                         raise DfError.from_wire(progress.error or {})
@@ -226,15 +262,12 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
     if sink is None:
         raise DfError(Code.UnknownError, "device sink vanished after verify")
     if replicated:
-        from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkError
-
-        try:
-            await tm.device_sinks.replicate(sink, mesh, axis_name,
-                                            tm.flight.task(task_id))
-        except DeviceSinkError as e:
-            tm.device_sinks.discard(task_id)
-            raise DfError(Code.ClientPieceDownloadFail,
-                          f"device sink verification failed: {e}")
+        await _fan_out(tm, sink, mesh, axis_name)
+    chips = _chips(sink.device, sink.as_words())
+    tm.flight.task(task_id).record(
+        flightlib.EV_DEVICE_PULL, chips[0],
+        (time.perf_counter() - admitted) * 1000.0,
+        "chips=" + ",".join(map(str, chips)) if len(chips) > 1 else "")
     result = DeviceResult(task_id=task_id,
                           content_length=final.content_length,
                           from_p2p=final.from_p2p,
@@ -244,6 +277,21 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
     if mesh is not None and not replicated:
         return result.shard_to_mesh(mesh, axis_name)
     return result
+
+
+async def _fan_out(tm, sink, mesh, axis_name: str = "d") -> None:
+    """``sink`` whole on every chip of ``mesh``, each copy verified on its
+    chip (``DeviceSinkManager.replicate``); a copy that differs fails the
+    call as a landing that fails its verification does."""
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkError
+
+    try:
+        await tm.device_sinks.replicate(sink, mesh, axis_name,
+                                        tm.flight.task(sink.task_id))
+    except DeviceSinkError as e:
+        tm.device_sinks.discard(sink.task_id)
+        raise DfError(Code.ClientPieceDownloadFail,
+                      f"device sink verification failed: {e}")
 
 
 async def fetch_safetensors_header(daemon, url: str, *, tag: str = "",
@@ -282,7 +330,15 @@ async def fetch_safetensors_header(daemon, url: str, *, tag: str = "",
         tasks.append(_ranged_task(plen, rest))
         got += bitview.host_bytes(rest.as_words(), 0, rest.content_length)
     header_dict, _ = st.parse_header(got[:8 + n])
-    return header_dict, 8 + n, HeaderPrefix(words, plen, tasks)
+    return header_dict, 8 + n, HeaderPrefix(words, plen, tasks, first.sink)
+
+
+def _mesh_of(devices):
+    """The devices on one axis ``d``, as a landing's fan-out takes them."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(list(devices)), ("d",))
 
 
 async def _pull_ranges(daemon, url: str, ranges, *, tag: str = "",
@@ -291,8 +347,12 @@ async def _pull_ranges(daemon, url: str, ranges, *, tag: str = "",
     """Pull each ``(start, end)`` byte range as its own ranged device
     task, concurrently under the daemon's shared sink admission; returns
     ``{(start, end): (words, task)}``: the sink's uint32 buffer, zero-padded
-    past the range, and the ``RangedTask`` that brought it. The single pull
-    engine for
+    past the range, and the ``RangedTask`` that brought it. A range may
+    come as ``(start, end, devices)``, the local devices that want it: it
+    lands on the first of them and, where there are more, is placed whole
+    on each and verified there (``download_to_device`` with ``device`` and a
+    replicated ``mesh``), so the words come back on exactly those devices.
+    The destination changes no task id. The single pull engine for
     download_sharded and download_global — their task ids and coalesce
     behavior must never fork. A failed range CANCELS its siblings
     (orphaned pulls would keep downloading against a dead result), and
@@ -302,15 +362,20 @@ async def _pull_ranges(daemon, url: str, ranges, *, tag: str = "",
 
     landed: dict = {}
 
-    async def pull(s0: int, s1: int) -> None:
+    async def pull(s0: int, s1: int, devices=()) -> None:
+        placed = {}
+        if devices:
+            placed["device"] = devices[0]
+        if len(devices) > 1:
+            placed.update(mesh=_mesh_of(devices), placement="replicated")
         result = await download_to_device(
             daemon, url, tag=tag, application=application, header=header,
-            range_header=f"{s0}-{s1 - 1}")
+            range_header=f"{s0}-{s1 - 1}", **placed)
         landed[(s0, s1)] = (result.as_words(), _ranged_task(s0, result))
 
     # First failure cancels the sibling pulls and re-raises plain (the
     # TaskGroup/ExceptionGroup shape needs 3.11; this runs on 3.10 too).
-    tasks = [asyncio.ensure_future(pull(s0, s1)) for s0, s1 in ranges]
+    tasks = [asyncio.ensure_future(pull(*r)) for r in ranges]
     try:
         await asyncio.gather(*tasks)
     except BaseException:
@@ -402,7 +467,10 @@ async def download_sharded(daemon, url: str, *,
 
     ``names``: explicit tensor list, or ``selector(name, meta) -> bool``
     over header entries. ``shardings``: tensor name → jax Sharding,
-    applied via device_put after landing. Adjacent selected spans closer
+    applied via device_put after landing on the daemon's one landing chip
+    (a second, chip-to-chip copy, counted in ``device_sink_hop_bytes_total``;
+    ``download_global`` lands each shard on the chip that keeps it).
+    Adjacent selected spans closer
     than ``coalesce_gap`` bytes merge into one ranged task (fewer tasks;
     the gap bytes ride along). A tensor that lies whole inside the
     header's ranged task (``prefix_guess`` bytes) is cut from it and not
@@ -511,12 +579,37 @@ async def download_sharded(daemon, url: str, *,
     tf.record(flightlib.EV_SHARD_VIEWS, len(cut),
               (time.perf_counter() - viewing) * 1000.0)
     if shardings:  # unknown names already rejected above, pre-download
-        import jax
-
         for name, sharding in shardings.items():
-            cut[name] = jax.device_put(cut[name], sharding)
+            cut[name] = _put(cut[name], sharding)
     return ShardedTensors(((name, cut[name]) for _, _, name in picked),
                           tasks)
+
+
+def coalesce_by_destination(wanted: dict) -> list[tuple]:
+    """``wanted`` maps a ``(start, end)`` span to the devices that want it;
+    returns ``[(start, end, devices)]`` sorted by offset, the devices in id
+    order: touching or overlapping spans merge into one super-range only
+    where the SAME devices want them (``coalesce_spans`` within each set of
+    devices), so no byte lands on a chip that has no use for it. The one
+    merge rule of download_global's plan — unit-testable without a
+    daemon."""
+    by_devices: dict[tuple, list] = {}
+    for span, devices in wanted.items():
+        key = tuple(sorted(devices, key=lambda d: d.id))
+        by_devices.setdefault(key, []).append(span)
+    return sorted(((s0, s1, key) for key, spans in by_devices.items()
+                   for s0, s1 in coalesce_spans(spans)),
+                  key=lambda pull: pull[:2])
+
+
+def _held_by(array, device):
+    """What ``device`` holds of ``array`` (its copy, for an array that lies
+    whole on several devices), as an array of that device alone; nothing
+    is copied."""
+    if array.devices() == {device}:
+        return array
+    return next(s.data for s in array.addressable_shards
+                if s.device == device)
 
 
 async def download_global(daemon, url: str,
@@ -526,8 +619,9 @@ async def download_global(daemon, url: str,
                           prefix_guess: int = 256 << 10):
     """Global sharded checkpoint load through the fabric: for each tensor,
     pull ONLY the byte ranges this process's devices actually hold under
-    its jax Sharding, land them as ranged device tasks, and assemble true
-    global ``jax.Array``s with ``make_array_from_single_device_arrays``.
+    its jax Sharding, land each range ON the device that keeps it, and
+    assemble true global ``jax.Array``s, each under exactly the sharding
+    asked, with ``make_array_from_single_device_arrays``.
 
     The pod pattern this completes: every host computes the same plan
     from (header x shardings); hosts holding the same shard issue
@@ -535,12 +629,35 @@ async def download_global(daemon, url: str,
     RANGE across the pod — a TP=16 row-sharded matrix costs the origin
     one copy TOTAL, each 1/16th fetched once and fanned over P2P.
 
-    Leading-axis shards (a slice on axis 0, all trailing axes full) map
-    to contiguous byte ranges and are pulled exactly; any other layout
-    falls back to pulling that tensor's full span once per host and
-    slicing on device. Adjacent shard ranges on one host coalesce into
-    single tasks. ``shardings``: tensor name -> jax.sharding.Sharding
-    (tensors not named are not loaded).
+    The plan is by DESTINATION. Leading-axis shards (a slice on axis 0,
+    all trailing axes full) map to contiguous byte ranges, and touching
+    ranges coalesce into one ranged task only where the same set of local
+    devices wants them (``coalesce_by_destination``): under four-way expert
+    parallelism a chip's experts are its own tasks, and what every chip
+    holds is a task of all four. A range that one device wants lands on
+    that device (``download_to_device(device=)``): staging, assembly, the
+    checksums on the device and the typed views all run there, and no
+    shard is copied from another chip. A range that several want is read
+    from the store and passed over by the host ONCE, lands on the first of
+    them, and reaches the others chip to chip (``placement="replicated"``
+    over a mesh of exactly those devices: the fan-out of
+    ``HBMSink.replicate``), each copy's per-piece checksums computed on the
+    chip that holds it and compared with the host's before this returns.
+    The destination is no part of a task id. Any other layout (a shard that
+    is no leading-axis slice) falls back to landing that tensor whole on
+    the first of its devices, slicing there and ``jax.device_put`` of each
+    slice (counted in ``device_sink_hop_bytes_total``), as does what lies inside
+    the header's ranged task where another chip than that task's wants it:
+    that task names no chip, and is fanned out to the chips that do.
+    ``shardings``: tensor name -> jax.sharding.Sharding (tensors not named
+    are not loaded).
+
+    Returns a ``ShardedTensors``: name -> global array in the order of
+    ``shardings``, and in ``tasks`` every ranged task (the header's first)
+    with the local devices its words lie on (``chips``). The spans
+    ``shard_plan`` and ``shard_views`` go on the header task's flight, as
+    ``download_sharded`` stamps them; every task stamps ``device_pull`` with
+    its chip.
     """
     import numpy as np
 
@@ -548,6 +665,7 @@ async def download_global(daemon, url: str,
 
     from dragonfly2_tpu.ops import safetensors as st
 
+    called = time.perf_counter()
     header_dict, data_start, prefix = await fetch_safetensors_header(
         daemon, url, tag=tag, application=application, header=header,
         prefix_guess=prefix_guess)
@@ -560,9 +678,10 @@ async def download_global(daemon, url: str,
 
     # Plan: per (tensor, local device) -> the absolute byte span it needs
     # plus how to carve the shard out of that span once landed.
-    #   (name, dev, span_start, span_end, shard_shape | None, idx | None)
+    #   (name, dev, span_start, span_end, shard_shape, idx | None)
+    # and per span the devices that want its bytes ON them.
     plan = []
-    spans_needed: set[tuple[int, int]] = set()
+    wanted: dict[tuple[int, int], set] = {}
     for name, sharding in shardings.items():
         meta = header_dict[name]
         begin, end = _validated_span(name, meta, data_start)
@@ -584,7 +703,8 @@ async def download_global(daemon, url: str,
             raise st.SafetensorsError(
                 f"{name}: sharding has no addressable devices in this "
                 "process")
-        for dev in sharding.addressable_devices:
+        devices = sorted(sharding.addressable_devices, key=lambda d: d.id)
+        for dev in devices:
             idx = idx_map[dev]
 
             def _dim(sl, size):
@@ -605,59 +725,94 @@ async def download_global(daemon, url: str,
                 r1 = shape[0] if lead.stop is None else lead.stop
                 span = (begin + r0 * row_bytes, begin + r1 * row_bytes)
                 plan.append((name, dev, span[0], span[1], shard_shape, None))
+                wants = dev
             else:
-                span = (begin, end)   # whole tensor; slice on device
+                span = (begin, end)   # whole tensor; sliced where it lands
                 plan.append((name, dev, begin, end, shard_shape, idx))
+                wants = devices[0]
             if span[1] > span[0]:
-                spans_needed.add(span)
+                wanted.setdefault(span, set()).add(wants)
 
-    # Coalesce touching spans into super-ranges → one ranged task each.
-    merged = coalesce_spans(spans_needed)
-
-    # Ranges the header-guess landing already covers carve from it free.
-    pull_list = [m for m in merged if m[1] > plen]
+    # Ranges the header-guess landing already covers carve from it free;
+    # the others coalesce, by destination, into one ranged task each.
+    pull_list = coalesce_by_destination(
+        {span: devs for span, devs in wanted.items() if span[1] > plen})
+    head = prefix.tasks[0]
+    tf = daemon.task_manager.flight.task(head.task_id)
+    tf.record(flightlib.EV_SHARD_PLAN, len(pull_list),
+              (time.perf_counter() - called) * 1000.0)
     pulled = await _pull_ranges(daemon, url, pull_list, tag=tag,
                                 application=application, header=header)
-    landed = {span: words for span, (words, _) in pulled.items()}
+    viewing = time.perf_counter()
+    tasks = list(prefix.tasks)
+    landed: dict = {}
+    for s0, s1, _ in pull_list:
+        landed[(s0, s1)], task = pulled.pop((s0, s1))
+        tasks.append(task)
+    coverage = [pull[:2] for pull in pull_list]
     if plen:
         landed[(0, plen)] = prefix.words
-    coverage = pull_list + ([(0, plen)] if plen else [])
+        coverage.append((0, plen))
+        # The header's task named no chip: where others want what lies
+        # inside it, it goes whole to them too, verified there.
+        inside = set().union(*(devs for span, devs in wanted.items()
+                               if span[1] <= plen))
+        here = prefix.sink.device
+        if inside - {here}:
+            await _fan_out(daemon.task_manager, prefix.sink, _mesh_of(
+                [here, *sorted(inside - {here}, key=lambda d: d.id)]))
+            landed[(0, plen)] = prefix.sink.as_words()
+            head.chips = _chips(here, landed[(0, plen)])
 
-    def super_range(a: int, b: int) -> tuple[int, int]:
-        return covering_span(coverage, a, b)
-
-    out: dict[str, object] = {}
+    # Carve: every distinct (tensor, byte range, shard shape) ONCE from the
+    # words of the range that covers it, the cuts of one range by one
+    # ``tensor_views`` (a dispatch a group of equal dtype and shape), on
+    # the device(s) those words lie on; a device's shard is then what it
+    # holds of the cut.
+    cuts: dict[tuple[int, int], dict] = {}
+    for name, dev, a, b, shard_shape, idx in plan:
+        if b > a:
+            s0, s1 = covering_span(coverage, a, b)
+            meta = header_dict[name]
+            cuts.setdefault((s0, s1), {})[(name, a, idx is not None)] = {
+                **meta, "data_offsets": [a - s0, b - s0],
+                "shape": meta["shape"] if idx is not None
+                else list(shard_shape)}
+    of_range = {(task.start, task.end): task for task in tasks}
+    carved: dict = {}
+    for (s0, s1), sub in cuts.items():
+        of_range[(s0, s1)].names = list(dict.fromkeys(
+            name for name, _, _ in sub))
+        carved.update(st.tensor_views(landed.pop((s0, s1)), sub, 0,
+                                      total=s1 - s0))
+    landed.clear()
     by_name: dict[str, list] = {}
     for name, dev, a, b, shard_shape, idx in plan:
-        meta = header_dict[name]
         if b <= a:
             # Zero-element shard: synthesize through the same validated
             # dtype path as real carves (tensor_views rejects unknown
             # dtypes as SafetensorsError, never a bare KeyError).
-            sub = {name: {**meta, "shape": list(shard_shape),
+            sub = {name: {**header_dict[name], "shape": list(shard_shape),
                           "data_offsets": [0, 0]}}
-            shard = st.tensor_views(jax.numpy.zeros((0,), dtype="uint32"),
-                                    sub, 0, [name])[name]
+            shard = jax.device_put(st.tensor_views(
+                jax.numpy.zeros((0,), dtype="uint32"), sub, 0, [name])[name],
+                dev)
         elif idx is not None:
-            # Fallback: the whole tensor landed; carve the (possibly
-            # non-contiguous) shard on device.
-            s0, s1 = super_range(a, b)
-            sub = {name: {**meta, "data_offsets": [a - s0, b - s0]}}
-            shard = st.tensor_views(landed[(s0, s1)], sub, 0, [name],
-                                    total=s1 - s0)[name]
-            shard = shard[idx]
+            # Fallback: the whole tensor landed on the first of its
+            # devices; the (possibly non-contiguous) shard is cut there
+            # and copied to its own.
+            shard = _put(carved[(name, a, True)][idx], dev)
         else:
-            s0, s1 = super_range(a, b)
-            sub = {name: {**meta, "shape": list(shard_shape),
-                          "data_offsets": [a - s0, b - s0]}}
-            shard = st.tensor_views(landed[(s0, s1)], sub, 0, [name],
-                                    total=s1 - s0)[name]
-        by_name.setdefault(name, []).append(jax.device_put(shard, dev))
+            shard = _held_by(carved[(name, a, False)], dev)
+        by_name.setdefault(name, []).append(shard)
+    out = {}
     for name, sharding in shardings.items():
         shape = tuple(header_dict[name].get("shape") or ())
         out[name] = jax.make_array_from_single_device_arrays(
             shape, sharding, by_name[name])
-    return out
+    tf.record(flightlib.EV_SHARD_VIEWS, len(out),
+              (time.perf_counter() - viewing) * 1000.0)
+    return ShardedTensors(out, tasks)
 
 
 # ------------------------------------------------------------------ #

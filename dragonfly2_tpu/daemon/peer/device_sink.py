@@ -65,7 +65,13 @@ from dragonfly2_tpu.pkg import dflog, flight as flightlib, metrics
 log = dflog.get("peer.device_sink")
 
 SINK_LANDED_BYTES = metrics.counter(
-    "device_sink_landed_bytes_total", "Bytes landed into device sinks")
+    "device_sink_landed_bytes_total",
+    "Bytes landed into device sinks, by the local device (jax's id) whose "
+    "sink took them", ("chip",))
+SINK_STORE_READ_BYTES = metrics.counter(
+    "device_sink_store_read_bytes_total",
+    "Bytes the landing thread and its helpers read back from the piece "
+    "store into staging rows (a re-land reads every byte it lands)")
 SINK_VERIFY_COUNT = metrics.counter(
     "device_sink_verify_total", "Device sink verifications", ("result",))
 # An assembly of a geometry met for the first time compiles, on the one
@@ -80,10 +86,14 @@ SINK_COMPILE_SECONDS = metrics.counter(
 SINK_WAIT_SECONDS = metrics.counter(
     "device_sink_wait_seconds_total",
     "Seconds jobs stood queued for the one landing thread")
-SINK_REPLICATED_BYTES = metrics.counter(
-    "device_sink_replicated_bytes_total",
-    "Bytes received over the fan-out by devices other than the landing "
-    "device (a landing placed whole on every chip of a mesh)")
+SINK_HOP_BYTES = metrics.counter(
+    "device_sink_hop_bytes_total",
+    "Bytes that reached a local device by a copy from another device and not "
+    "from the host: received over the fan-out by devices other than the "
+    "landing device (fanout: a landing placed whole on every chip of a mesh, "
+    "its padding to whole pieces included), or copied by the client API's "
+    "jax.device_put of landed tensors or shards to a sharding (device_put)",
+    ("how",))
 SINK_CHIP_VERIFY_COUNT = metrics.counter(
     "device_sink_chip_verify_total",
     "Placements whole-on-every-chip by what the per-chip verification found: "
@@ -153,11 +163,17 @@ class TaskDeviceSink:
         # Host-side piece digests at land time: lets a later finalize
         # detect that the store's content changed under a resident sink.
         self.piece_digests: dict[int, str] = {}
+        self._landed_bytes = SINK_LANDED_BYTES.labels(str(self.sink.device.id))
+
+    @property
+    def device(self):
+        """The local device the bytes land on."""
+        return self.sink.device
 
     def land(self, piece_num: int, data: bytes, digest: str = "") -> None:
         self.sink.land_piece(piece_num, data)
         self.piece_digests[piece_num] = digest
-        SINK_LANDED_BYTES.inc(len(data))
+        self._landed_bytes.inc(len(data))
 
     @property
     def landed(self) -> set[int]:
@@ -205,7 +221,8 @@ class TaskDeviceSink:
             SINK_CHIP_VERIFY_COUNT.labels("corrupt").inc()
             raise DeviceSinkError(str(e)) from e
         if received:
-            SINK_REPLICATED_BYTES.inc(received * 4 * self.sink.padded_words)
+            SINK_HOP_BYTES.labels("fanout").inc(
+                received * 4 * self.sink.padded_words)
             SINK_CHIP_VERIFY_COUNT.labels("ok").inc()
 
     def ici_broadcast(self, mesh, axis_name: str = "d", n_chunks: int = 4):
@@ -301,17 +318,20 @@ class DeviceSinkManager:
 
     # -- landing ----------------------------------------------------------
 
-    async def on_piece(self, task_id: str, store, rec, tf=None) -> None:
+    async def on_piece(self, task_id: str, store, rec, tf=None,
+                       device=None) -> None:
         """Land one verified piece as it arrives (conductor/back-source
         on_piece hook). Creation is lazy: the first piece to arrive after
-        the task's length and piece size are known allocates the buffer.
+        the task's length and piece size are known allocates the buffer,
+        on the local ``device`` the request names (none: the manager's).
         ``tf`` is the task's flight, for the landing thread's spans."""
         await self._run(tf, rec.num, self._land_sync, task_id, store, rec,
-                        tf)
+                        tf, device)
 
-    def _land_sync(self, task_id: str, store, rec, tf=None) -> None:
+    def _land_sync(self, task_id: str, store, rec, tf=None,
+                   device=None) -> None:
         with self._span(tf and tf.record, flightlib.EV_SINK_LAND, rec.num):
-            self._land_inner(task_id, store, rec, tf)
+            self._land_inner(task_id, store, rec, tf, device)
 
     def _land(self, sink: TaskDeviceSink, store, recs, tf) -> None:
         """Read a group of pieces back from the store, straight into the
@@ -331,11 +351,12 @@ class DeviceSinkManager:
 
         landed = sink.sink.read_pieces(
             [(piece.num, piece.size) for piece in stored], read_into)
+        SINK_STORE_READ_BYTES.inc(sum(piece.size for piece in stored))
         store.touch()
         for rec, data in zip(recs, landed):
             sink.land(rec.num, data, rec.digest)
 
-    def _land_inner(self, task_id: str, store, rec, tf) -> None:
+    def _land_inner(self, task_id: str, store, rec, tf, device) -> None:
         if task_id in self._degraded:
             return
         sink = self._sinks.get(task_id)
@@ -343,7 +364,8 @@ class DeviceSinkManager:
             m = store.metadata
             if m.content_length < 0 or m.piece_size <= 0:
                 return  # metadata not known yet; backfill catches it later
-            sink = self._create(task_id, m.content_length, m.piece_size)
+            sink = self._create(task_id, m.content_length, m.piece_size,
+                                device)
             if sink is None:
                 return
         if rec.num in sink.landed:
@@ -370,8 +392,13 @@ class DeviceSinkManager:
         self._errors.setdefault(task_id, f"{stage}: {text}"[:600])
 
     def _create(self, task_id: str, content_length: int,
-                piece_size: int) -> TaskDeviceSink | None:
+                piece_size: int, device=None) -> TaskDeviceSink | None:
+        """A sink for the task on ``device`` (none named: the manager's
+        own, the first local device where it has none). The cap counts the
+        sinks of every chip together: it bounds the staging stacks on the
+        host as much as the HBM."""
         self._expire()
+        device = device or self._device
         if len(self._sinks) >= self.max_tasks:
             # Residents are cached conveniences — the disk store stays
             # authoritative — so a verified, unclaimed sink yields its
@@ -397,7 +424,10 @@ class DeviceSinkManager:
                           if now - s.verified_at > self.claim_grace_s]
                          or verified)
             if evictable:
-                victim = evictable[0]
+                # A resident of the chip the new landing goes to gives its
+                # HBM where it is needed; one of another chip only a slot.
+                victim = min(evictable, key=lambda s: (
+                    device is not None and s.device != device, s.created_at))
                 log.info("evicting resident device sink for new landing",
                          evicted=victim.task_id[:16], task=task_id[:16])
                 del self._sinks[victim.task_id]
@@ -410,7 +440,7 @@ class DeviceSinkManager:
                 return None
         try:
             sink = TaskDeviceSink(task_id, content_length, piece_size,
-                                  device=self._device,
+                                  device=device,
                                   batch_pieces=self.batch_pieces)
         except Exception as e:
             # Includes device OOM (XlaRuntimeError): degrade to disk-only
@@ -423,24 +453,27 @@ class DeviceSinkManager:
         sink.landing = True
         SINKS_LANDING.inc()
         log.info("device sink created", task=task_id[:16],
-                 bytes=content_length)
+                 bytes=content_length, chip=sink.device.id)
         return sink
 
     # -- completion -------------------------------------------------------
 
-    async def finalize(self, task_id: str, store,
-                       tf=None) -> TaskDeviceSink | None:
+    async def finalize(self, task_id: str, store, tf=None,
+                       device=None) -> TaskDeviceSink | None:
         """Complete the landing: backfill pieces the streaming hook missed
         (reuse path, tiny/small shortcuts, pre-metadata arrivals), then
         verify every landed piece on device. Returns None when no sink
         could be allocated (cap reached, misaligned pieces) — disk-only
         degradation; raises DeviceSinkError on device-copy CORRUPTION.
-        ``tf`` as for ``on_piece``."""
+        ``tf`` and ``device`` as for ``on_piece``; a sink of the task that
+        lies on another chip than the one named (a resident that an earlier
+        request left there) is dropped and built again from the store on
+        the named one."""
         return await self._run(tf, 0, self._finalize_sync, task_id, store,
-                               tf)
+                               tf, device)
 
-    def _finalize_sync(self, task_id: str, store,
-                       tf=None) -> TaskDeviceSink | None:
+    def _finalize_sync(self, task_id: str, store, tf=None,
+                       device=None) -> TaskDeviceSink | None:
         # piece: how many pieces the backfill landed, counted as it goes.
         with self._span(tf and tf.record, flightlib.EV_SINK_FINALIZE,
                         0) as step:
@@ -448,7 +481,8 @@ class DeviceSinkManager:
                 self._degraded.discard(task_id)  # next attempt starts fresh
                 return None
             try:
-                return self._finalize_inner(task_id, store, tf, step)
+                return self._finalize_inner(task_id, store, tf, step,
+                                            device)
             except DeviceSinkError:
                 raise  # device-copy corruption: surfaced to the caller
             except Exception as e:
@@ -462,10 +496,15 @@ class DeviceSinkManager:
                 self._drop(task_id)
                 return None
 
-    def _finalize_inner(self, task_id: str, store, tf,
-                        step) -> TaskDeviceSink | None:
+    def _finalize_inner(self, task_id: str, store, tf, step,
+                        device=None) -> TaskDeviceSink | None:
         m = store.metadata
         sink = self._sinks.get(task_id)
+        if sink is not None and device is not None and sink.device != device:
+            log.info("device sink on another chip than asked; rebuilding",
+                     task=task_id[:16], chip=sink.device.id, asked=device.id)
+            self._drop(task_id)
+            sink = None
         if sink is not None and self._stale(sink, store):
             # The store's content changed under a resident sink (same task
             # id, new bytes — e.g. origin changed between invalidate and
@@ -475,10 +514,12 @@ class DeviceSinkManager:
             self._drop(task_id)
             sink = None
         if sink is None:
-            sink = self._create(task_id, m.content_length, m.piece_size)
+            sink = self._create(task_id, m.content_length, m.piece_size,
+                                device)
             if sink is None:
                 return None
         sink.stamp.flight = tf
+        step.note = f"chip={sink.device.id}"
         # Every missing piece is in the store before the first is read and
         # nothing orders the reads: a host pass takes as many as the open
         # stack has rows free.

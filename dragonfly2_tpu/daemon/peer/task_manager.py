@@ -56,6 +56,10 @@ class FileTaskRequest:
     # Terminal device: "" = disk only; "tpu" additionally lands verified
     # pieces into an HBM sink (daemon/peer/device_sink.py) as they arrive.
     device: str = ""
+    # Which local device the sink lies on (a jax Device of this process; a
+    # client-API request's alone, never on the wire and no part of the task
+    # id): None lands where the sink manager lands.
+    sink_device: object = None
     # Striped slice broadcast: register the task as a pod broadcast so the
     # scheduler stripes the DCN pull across same-slice hosts (1/S of the
     # bytes each; the rest fills intra-slice).
@@ -249,7 +253,8 @@ class TaskManager:
                 # Land into HBM as the piece verifies — by completion the
                 # device buffer only awaits the final on-device check.
                 tf.record(flightlib.EV_HBM_START, rec.num)
-                await self.device_sinks.on_piece(task_id, st, rec, tf)
+                await self.device_sinks.on_piece(task_id, st, rec, tf,
+                                                 req.sink_device)
                 tf.record(flightlib.EV_HBM_LANDED, rec.num)
             if progress_q is not None:
                 await progress_q.on_piece(st, rec)
@@ -1283,7 +1288,8 @@ class TaskManager:
             # The same flight the download stamped (a re-land finds the
             # finished one): the landing thread's spans go beside them.
             return await self.device_sinks.finalize(
-                task_id, store, self.flight.task(task_id)) is not None
+                task_id, store, self.flight.task(task_id),
+                req.sink_device) is not None
         except DeviceSinkError as e:
             self.device_sinks.discard(task_id)
             raise DfError(Code.ClientPieceDownloadFail,

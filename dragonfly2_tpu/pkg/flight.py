@@ -105,7 +105,7 @@ EV_SINK_STAGE = 32     # what is left of staging: taking a stack, copying foreig
 EV_SINK_PUT = 33       # flush: the device_put call (piece=lowest slot)
 EV_SINK_ASSEMBLE = 34  # assembly dispatch -> checksums on host (piece=batches)
 EV_SINK_COMPILE = 35   # backend compile inside that assembly (piece=batches)
-EV_SINK_FINALIZE = 36  # backfill + assemble + verify (piece=pieces backfilled)
+EV_SINK_FINALIZE = 36  # backfill + assemble + verify (piece=pieces backfilled, note="chip=<id>": the local device the sink lies on)
 EV_PARENT_PIECES = 37  # a parent announced pieces (piece=lowest, aux=how many)
 # A job's wait for the landing thread, stamped as the job starts there:
 # submission on the event loop -> start on the thread. A sibling of the
@@ -119,10 +119,11 @@ EV_SINK_VERIFY_CHIPS = 40  # per-chip checksums dispatched -> all compared (piec
 # The client API's own steps (client/device.py), stamped on the event loop:
 # ONE event at the step's end, aux = its ms, as the sink_* spans. The first on
 # the flight of the task that waited; the other two on the flight of a sharded
-# pull's header task, which stands for the whole call.
+# pull's header task (download_sharded's or download_global's), which stands
+# for the whole call.
 EV_ADMIT_WAIT = 41     # a device pull's wait at device_sinks.admit(), stamped as the task starts
-EV_SHARD_PLAN = 42     # download_sharded called -> header landed, parsed, spans planned (piece=ranged tasks planned)
-EV_SHARD_VIEWS = 43    # the typed views of every span dispatched (piece=tensors returned)
+EV_SHARD_PLAN = 42     # download_sharded / download_global called -> header landed, parsed, spans planned, by destination in download_global (piece=ranged tasks planned)
+EV_SHARD_VIEWS = 43    # the typed views of every span dispatched and, in download_global, the global arrays made of them (piece=tensors returned)
 # The completion path and the source client: ONE event at the span's end,
 # aux = its ms, as above. source_first_byte is on the flight of whichever
 # task pulls from the origin (a seed's, a peer's own back-to-source);
@@ -139,6 +140,12 @@ EV_PARENT_VERIFIED = 48  # the parent's verify_start -> verified (piece=pieces i
 # way (piece=distinct parents that served a piece, aux=bytes from parents that
 # are not seeds, note="seed=<bytes> peer=<bytes> origin=<bytes>").
 EV_TASK_SOURCES = 49
+# A device pull of the client API, whole: ONE event as download_to_device has
+# the verified sink in hand, placed where the caller asked (aux = ms since its
+# admission, so the task, its landing and any fan-out lie inside; piece = the
+# id of the local device the bytes landed on; note = "chips=<id>,<id>,.." where
+# the words lie on more chips than that one, the landing chip first).
+EV_DEVICE_PULL = 50
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -168,7 +175,7 @@ EVENT_NAMES = {
     EV_PARENT_DONE: "parent_done",
     EV_PARENT_SOURCE_FIRST_BYTE: "parent_source_first_byte",
     EV_PARENT_VERIFIED: "parent_verified",
-    EV_TASK_SOURCES: "task_sources",
+    EV_TASK_SOURCES: "task_sources", EV_DEVICE_PULL: "device_pull",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING

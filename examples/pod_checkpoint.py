@@ -76,8 +76,10 @@ def main() -> None:
 
     # Global sharded load through the REAL fabric: origin + scheduler +
     # sink daemon in this process, then client.device.download_global
-    # pulls only the byte ranges the mesh's devices hold and hands back
-    # global arrays directly — the production checkpoint-loading API.
+    # pulls only the byte ranges the mesh's devices hold, lands each on
+    # the device that keeps it (a range that several devices want lands
+    # once and is fanned out chip to chip), and hands back global arrays
+    # directly — the production checkpoint-loading API.
     import asyncio
 
     asyncio.run(fabric_global_load(content, ref, mesh))

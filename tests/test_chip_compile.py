@@ -136,6 +136,35 @@ def test_assembly_program_grows_with_its_operands_not_its_pieces(
     assert code <= 192 * 1024 * n_batches + 256 * 1024
 
 
+@pytest.mark.parametrize("chip", [1, 2, 3])
+@pytest.mark.parametrize("n_batches,last", [(1, 5), (2, 7)],
+                         ids=["one_expert_17mb", "a_layers_rest_62mb"])
+def test_a_sink_on_another_chip_compiles_for_that_chip(topo, chip, n_batches,
+                                                       last):
+    """A sink that ``download_global`` creates on the chip that keeps its
+    bytes (one expert's three matrices, 5 pieces of 4 MiB; what every chip
+    keeps of a layer, 15): the assembly program and a group of three views
+    compile for that chip as for the first, in the same memory."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from dragonfly2_tpu.ops import bitview
+
+    there = SingleDeviceSharding(topo.devices[chip])
+    compiled, content = _assembly(there, 4, n_batches, last)
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes >= content
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes) <= 2.05 * content + 4 * MiB
+    views = jax.jit(functools.partial(
+        bitview._views_jit, shift=2, dtype=jnp.dtype(jnp.bfloat16),
+        shape=EXPERT)).lower(
+        _spec((content // 4,), jnp.uint32, there),
+        _starts(3, there)).compile().memory_analysis()
+    assert views.output_size_in_bytes >= 3 * 2 * np.prod(EXPERT)
+
+
 def test_a_partial_sink_assembles_in_the_same_two_contents(one_chip):
     """30 of 55 pieces staged (a ``as_words()`` mid-landing): the output
     is the whole content, zeros where nothing landed, and still nothing

@@ -258,7 +258,7 @@ def test_device_corruption_fails_request_but_not_store(run_async, tmp_path):
             mgr = peer.task_manager.device_sinks
 
             # Sabotage: make every finalize report corruption.
-            async def bad_finalize(task_id, store, tf=None):
+            async def bad_finalize(task_id, store, tf=None, device=None):
                 from dragonfly2_tpu.daemon.peer.device_sink import (
                     DeviceSinkError,
                 )

@@ -358,7 +358,7 @@ def test_the_fan_out_and_the_per_chip_verification_are_stamped_once_inside(
         run_async, tmp_path):
     from dragonfly2_tpu.daemon.peer import device_sink
 
-    moved = device_sink.SINK_REPLICATED_BYTES._value
+    moved = device_sink.SINK_HOP_BYTES.labels("fanout")._value
     verified = device_sink.SINK_CHIP_VERIFY_COUNT.labels("ok")._value
     before = (moved.get(), verified.get())
     content = make_object(12, 9 * MIB + 8)
