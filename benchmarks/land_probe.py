@@ -29,6 +29,21 @@ link) of the flight, in ms:
   a pass a stack, h/f the tree's pass with h helpers (1: one thread takes
                       every piece in turn) and a chunk floor of f KiB
 
+Then, for each ``--queued`` geometry (``length:piece_size:sinks``; a LAION
+tar's 30 x 8 MiB three times, as ``tar-reland``'s three clients queue them,
+and 8 x 4 MiB sixteen times, as ``host-reland-ep4``'s small ranges), that
+many sinks of the one geometry asked of ``DeviceSinkManager.finalize`` AT
+ONCE through the sink's admission, as ``download_to_device`` asks (three hold
+a slot, the others wait there), each discarded as it ends:
+
+  N sinks queued      the round's wall time a sink, and per sink the landing
+                      thread's ms (``sink_finalize`` less ``sink_tail``: job
+                      start -> the hand-over; the whole ``sink_finalize``
+                      where the program stamps no ``sink_tail``), the tail's
+                      (``sink_tail``), ``sink_assemble`` inside it,
+                      ``sink_wait``, and how many tails had another job
+                      started beside them (``sink_tail``'s ``piece`` >= 1)
+
 All but the tree's are the tree's own code under a patch; every
 arrangement's host checksums must equal the first's, and the device
 verifies each landing. The table goes to stdout and to
@@ -58,6 +73,7 @@ sys.path.insert(0, REPO)
 OBJECTS = ("1843431563:33554432", "1000000007:16777216",
            "250000384:8388608")
 OTHERS = ("1:2048", "4:2048", "12:2048", "8:1024", "8:4096")
+QUEUED = ("250000384:8388608:3", "33554432:4194304:16")
 
 
 def _store(root: str, name: str, length: int, piece_size: int):
@@ -141,6 +157,59 @@ async def _reland(mgr, store, repeats: int) -> dict:
     return out
 
 
+async def _queued(mgr, stores, repeats: int) -> dict:
+    """``stores``' sinks landed at once, ``repeats`` rounds after one that
+    is not counted: medians over the counted rounds' sinks."""
+    from dragonfly2_tpu.pkg import flight
+
+    names = ("sink_finalize", "sink_tail", "sink_assemble", "sink_wait")
+
+    async def land(store, tf):
+        task_id = store.metadata.task_id
+        async with mgr.admit():
+            sink = await mgr.finalize(task_id, store, tf)
+            if sink is None or not sink.verified:
+                raise RuntimeError(
+                    f"{task_id}: no verified landing: "
+                    f"{mgr.outcome(task_id, False)}")
+            mgr.discard(task_id)
+
+    sinks, rounds = [], []
+    for i in range(repeats + 1):
+        flights = [flight.TaskFlight(store.metadata.task_id)
+                   for store in stores]
+        t0 = time.perf_counter()
+        await asyncio.gather(*map(land, stores, flights))
+        wall_ms = (time.perf_counter() - t0) * 1000.0
+        if not i:                           # the first is the warm-up
+            continue
+        rounds.append(wall_ms / len(stores))
+        for tf in flights:
+            ms = dict.fromkeys(names, 0.0)
+            beside = None
+            for _, code, piece, aux, _ in tf.events():
+                name = flight.EVENT_NAMES[code]
+                if name in ms:
+                    ms[name] += aux
+                if name == "sink_tail":
+                    beside = piece
+            sinks.append({**ms, "beside": beside})
+    tails = [s["beside"] for s in sinks if s["beside"] is not None]
+    return {
+        "round_ms_a_sink": statistics.median(rounds),
+        "round_ms_a_sink_range": [min(rounds), max(rounds)],
+        "thread_ms": statistics.median(
+            s["sink_finalize"] - s["sink_tail"] for s in sinks),
+        "tail_ms": (statistics.median(s["sink_tail"] for s in sinks)
+                    if tails else None),
+        "assemble_ms": statistics.median(s["sink_assemble"] for s in sinks),
+        "finalize_ms": statistics.median(s["sink_finalize"] for s in sinks),
+        "wait_ms": statistics.median(s["sink_wait"] for s in sinks),
+        "tails_beside_a_job": (sum(1 for n in tails if n) if tails
+                               else None),
+        "sinks": len(sinks)}
+
+
 def main(argv: list[str] | None = None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--objects", nargs="*", default=list(OBJECTS),
@@ -148,6 +217,8 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--repeats", type=int, default=3)
     parser.add_argument("--others", nargs="*", default=list(OTHERS),
                         help="helpers:floor_KiB of further passes a stack")
+    parser.add_argument("--queued", nargs="*", default=list(QUEUED),
+                        help="length:piece_size:sinks landed at once")
     args = parser.parse_args(argv)
 
     import jax
@@ -158,8 +229,13 @@ def main(argv: list[str] | None = None) -> int:
     device = jax.devices()[0]
     tree = (hbm_sink._HELPERS, hbm_sink._CHUNK_FLOOR, hbm_sink._POOL,
             hbm_sink.HBMSink.free_rows, hbm_sink.cuts)
+
+    def restore() -> None:
+        (hbm_sink._HELPERS, hbm_sink._CHUNK_FLOOR, hbm_sink._POOL,
+         hbm_sink.HBMSink.free_rows, hbm_sink.cuts) = tree
+
     root = tempfile.mkdtemp(prefix=".land_probe_", dir=REPO)
-    rows = []
+    rows, queued = [], []
     try:
         for spec in args.objects:
             length, piece_size = (int(v) for v in spec.split(":"))
@@ -196,20 +272,46 @@ def main(argv: list[str] | None = None) -> int:
                 print(f"[land_probe] {spec} {name}: " + json.dumps(row),
                       flush=True)
             store.destroy()
+        restore()                           # the tree as it is from here on
+        for spec in args.queued:
+            length, piece_size, count = (int(v) for v in spec.split(":"))
+            stores = [_store(root, f"queued-{length}-{piece_size}-{n}",
+                             length + 4 * n, piece_size)
+                      for n in range(count)]
+            mgr = DeviceSinkManager()
+            try:
+                row = asyncio.run(_queued(mgr, stores, args.repeats))
+            finally:
+                mgr.close()
+            row.update(geometry=spec, device=device.device_kind)
+            queued.append(row)
+            print(f"[land_probe] {spec} queued: " + json.dumps(row),
+                  flush=True)
+            for store in stores:
+                store.destroy()
     finally:
-        (hbm_sink._HELPERS, hbm_sink._CHUNK_FLOOR, hbm_sink._POOL,
-         hbm_sink.HBMSink.free_rows, hbm_sink.cuts) = tree
+        restore()
         shutil.rmtree(root, ignore_errors=True)
     out = os.path.join(REPO, "chiprun_out")
     os.makedirs(out, exist_ok=True)
     with open(os.path.join(out, "land_probe.json"), "w") as f:
-        json.dump(rows, f, indent=1)
+        json.dump({"passes": rows, "queued": queued}, f, indent=1)
     print(f"{'object':>22} {'arrangement':>28} {'finalize':>9} {'read':>8} "
           f"{'checksum':>8} {'stage':>7}  ms, median of {args.repeats}")
     for r in rows:
         print(f"{r['object']:>22} {r['arrangement']:>28} "
               f"{r['finalize_ms']:9.1f} {r['read_ms']:8.1f} "
               f"{r['checksum_ms']:8.1f} {r['stage_ms']:7.1f}")
+    print(f"{'sinks at once':>26} {'round/sink':>10} {'thread':>8} "
+          f"{'tail':>8} {'assemble':>8} {'wait':>8} {'beside':>7}  "
+          f"ms a sink, medians over {args.repeats} rounds")
+    for r in queued:
+        tail = "-" if r["tail_ms"] is None else f"{r['tail_ms']:.1f}"
+        beside = ("-" if r["tails_beside_a_job"] is None
+                  else f"{r['tails_beside_a_job']}/{r['sinks']}")
+        print(f"{r['geometry']:>26} {r['round_ms_a_sink']:10.1f} "
+              f"{r['thread_ms']:8.1f} {tail:>8} {r['assemble_ms']:8.1f} "
+              f"{r['wait_ms']:8.1f} {beside:>7}")
     return 0 if all(r["same_bits"] for r in rows) else 1
 
 

@@ -25,19 +25,36 @@ touch no sink and no manager state, and the landing thread waits ONCE a
 group for every one of them before it goes on, a failed chunk's error in
 hand or not.
 
+A finalize is cut in two where the host's work on the sink's bytes is over,
+the ``device_put`` call of its last stack: from there the landing thread
+takes the next queued job, and the TAIL (the wait for the sink's puts, the
+assembly's dispatch, the fetched checksums, their comparison with the
+host's, the bookkeeping) runs on one completer thread (``df-device-sink-tail``),
+tails one at a time in the order they were handed over, so two assemblies
+are never in flight together. A sink in its tail belongs to the tail alone:
+the landing thread waits for the tail before it would touch such a sink
+again, and a second finalize of the task joins the tail in flight.
+``finalize()`` resolves only when the tail has verified the sink. What both
+threads and the event loop share of the manager (``_sinks``, ``_degraded``,
+``_errors``, ``_tails``) changes under one lock.
+
 Spans: that thread stamps its steps into the task's flight ring
 (``sink_land`` > ``sink_read``, ``sink_checksum``, ``sink_stage``,
 ``sink_put``; ``sink_finalize`` > the backfill's ``sink_land``s,
 ``sink_assemble`` > ``sink_compile``), one event at a step's end with its
-ms — a child is a span that lies inside another, there being one thread
-that stamps: the helpers stamp nothing, so ``sink_read`` and
+ms — a child is a span that lies inside another, a task's steps being
+stamped one after the other: by the landing thread up to the hand-over, by
+the completer from there (``sink_assemble``, ``sink_compile``, then
+``sink_tail``, the hand-over -> the tail's end with ``piece`` = the jobs the
+landing thread started meanwhile, and ``sink_finalize``, job start ->
+verified as ever). The helpers stamp nothing, so ``sink_read`` and
 ``sink_checksum`` are the two parts of the one pass's wall time (what
 the thread that read longest spent reading, and the rest) however many ran
 it. A ``sink_land`` is one pass and the staging of its pieces: a piece as
 it arrives, a group of the backfill (``piece`` the group's lowest).
 Before them each job stamps ``sink_wait``, the time it stood queued for
 the thread: a re-land is ONE job (``_finalize_sync``), so several tasks
-landing at once wait for each other's whole landings there. A landing that
+landing at once wait for each other's host passes there. A landing that
 the caller wants whole on every chip of a mesh (``replicate``) is one more
 job of the same thread after the verified one: ``sink_replicate`` (the
 fan-out dispatched -> every chip's copy ready) and ``sink_verify_chips``
@@ -57,8 +74,9 @@ which is what lets other peers still fetch pieces from this host.
 from __future__ import annotations
 
 import asyncio
+import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor, wait
 
 from dragonfly2_tpu.pkg import dflog, flight as flightlib, metrics
 
@@ -74,9 +92,9 @@ SINK_STORE_READ_BYTES = metrics.counter(
     "store into staging rows (a re-land reads every byte it lands)")
 SINK_VERIFY_COUNT = metrics.counter(
     "device_sink_verify_total", "Device sink verifications", ("result",))
-# An assembly of a geometry met for the first time compiles, on the one
-# landing thread, while every other task's pieces wait: once per object
-# geometry, whatever order the pieces arrived in.
+# An assembly of a geometry met for the first time compiles, in its
+# finalize's tail, while the tails handed over behind it wait: once per
+# object geometry, whatever order the pieces arrived in.
 SINK_COMPILES = metrics.counter(
     "device_sink_compiles_total",
     "Assemblies that compiled their program (a new geometry)")
@@ -99,6 +117,12 @@ SINK_CHIP_VERIFY_COUNT = metrics.counter(
     "Placements whole-on-every-chip by what the per-chip verification found: "
     "every device's copy equal to the host's checksums, or one that differs",
     ("result",))
+SINK_TAILS = metrics.counter(
+    "device_sink_tails_total",
+    "Finalize tails (the wait for the sink's last put, the assembly's "
+    "dispatch, the fetched checksums and their comparison, off the landing "
+    "thread) by whether the landing thread started another job while the "
+    "tail ran (overlapped) or found none queued (alone)", ("how",))
 SINKS_LANDING = metrics.gauge(
     "device_sink_landing",
     "Device sinks created and not yet verified or dropped")
@@ -130,6 +154,25 @@ class _SpanStamp:
             SINK_WAIT_SECONDS.inc(ms / 1000.0)
         if self.flight is not None:
             self.flight.record(code, piece, ms, note)
+
+
+class _FinalizeSpan:
+    """A finalize's ``sink_finalize``, job start -> verified: begun on the
+    landing thread and ended by whichever thread ends the finalize, the
+    completer where there is a tail. ``piece`` counts the pieces the
+    backfill landed, ``note`` names the sink's chip."""
+
+    __slots__ = ("stamp", "t0", "piece", "note")
+
+    def __init__(self, tf):
+        self.stamp = _SpanStamp(tf)
+        self.t0 = time.perf_counter()
+        self.piece = 0
+        self.note = ""
+
+    def end(self) -> None:
+        self.stamp(flightlib.EV_SINK_FINALIZE, self.piece,
+                   (time.perf_counter() - self.t0) * 1000.0, self.note)
 
 
 class TaskDeviceSink:
@@ -286,9 +329,24 @@ class DeviceSinkManager:
         # thread-safe) and keeps device copies off the event loop.
         self._exec = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="df-device-sink")
+        # The finalizes' tails, one at a time in the order they were handed
+        # over: at most one assembly in flight, one sink mid-assembly in HBM.
+        self._completer = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="df-device-sink-tail")
+        # The tails in flight, task id -> (the sink that is the tail's alone
+        # meanwhile, the finalize's future).
+        self._tails: dict[str, tuple[TaskDeviceSink, Future]] = {}
+        # Jobs the landing thread has started: what a tail reads at its
+        # hand-over and at its end to say whether anything ran beside it.
+        self._started = 0
+        # _sinks, _degraded, _errors and _tails change under it: the
+        # landing thread, the completer and the event loop (take, discard,
+        # outcome, gc) all do.
+        self._lock = threading.RLock()
 
     def close(self) -> None:
         self._exec.shutdown(wait=False, cancel_futures=True)
+        self._completer.shutdown(wait=False, cancel_futures=True)
 
     async def _run(self, tf, piece: int, fn, *args):
         """One job for the landing thread. As it starts there it stamps
@@ -296,6 +354,7 @@ class DeviceSinkManager:
         submitted = time.perf_counter()
 
         def job():
+            self._started += 1
             _SpanStamp(tf)(flightlib.EV_SINK_WAIT, piece,
                            (time.perf_counter() - submitted) * 1000.0)
             return fn(*args)
@@ -311,10 +370,30 @@ class DeviceSinkManager:
             SINKS_LANDING.dec()
 
     def _drop(self, task_id: str) -> TaskDeviceSink | None:
-        sink = self._sinks.pop(task_id, None)
-        if sink is not None:
-            self._settle(sink)
-        return sink
+        """Forget the task's sink. One in its tail is forgotten at once all
+        the same: the tail ends on its own reference, hands the finalize
+        that waits for it what it found, and the sink's stacks and HBM go
+        with it; a later finalize of the task starts from the store and
+        joins nothing."""
+        with self._lock:
+            sink = self._sinks.pop(task_id, None)
+            if sink is not None:
+                self._settle(sink)
+                self._tail_over(task_id, sink)
+            return sink
+
+    def _tail_over(self, task_id: str, sink: TaskDeviceSink) -> None:
+        """Under the lock: the task's tail in flight, if it is ``sink``'s,
+        is no longer one to join or to wait for."""
+        if self._tails.get(task_id, (None,))[0] is sink:
+            del self._tails[task_id]
+
+    def _await_tail(self, task_id: str) -> None:
+        """On the landing thread, before it touches the task's sink: a sink
+        in its tail is the completer's alone (HBMSink is not thread-safe)."""
+        tail = self._tails.get(task_id)
+        if tail is not None:
+            wait([tail[1]])
 
     # -- landing ----------------------------------------------------------
 
@@ -359,6 +438,7 @@ class DeviceSinkManager:
     def _land_inner(self, task_id: str, store, rec, tf, device) -> None:
         if task_id in self._degraded:
             return
+        self._await_tail(task_id)
         sink = self._sinks.get(task_id)
         if sink is None:
             m = store.metadata
@@ -383,13 +463,15 @@ class DeviceSinkManager:
             # retry a doomed sink.
             log.warning("device landing failed; degrading to disk-only",
                         task=task_id[:16], error=str(e)[:200])
-            self._note_error(task_id, "landing", e)
-            self._drop(task_id)
-            self._degraded.add(task_id)
+            with self._lock:
+                self._note_error(task_id, "landing", e)
+                self._drop(task_id)
+                self._degraded.add(task_id)
 
     def _note_error(self, task_id: str, stage: str, err) -> None:
         text = err if isinstance(err, str) else f"{type(err).__name__}: {err}"
-        self._errors.setdefault(task_id, f"{stage}: {text}"[:600])
+        with self._lock:
+            self._errors.setdefault(task_id, f"{stage}: {text}"[:600])
 
     def _create(self, task_id: str, content_length: int,
                 piece_size: int, device=None) -> TaskDeviceSink | None:
@@ -397,8 +479,13 @@ class DeviceSinkManager:
         own, the first local device where it has none). The cap counts the
         sinks of every chip together: it bounds the staging stacks on the
         host as much as the HBM."""
+        with self._lock:
+            return self._create_locked(task_id, content_length, piece_size,
+                                       device or self._device)
+
+    def _create_locked(self, task_id: str, content_length: int,
+                       piece_size: int, device) -> TaskDeviceSink | None:
         self._expire()
-        device = device or self._device
         if len(self._sinks) >= self.max_tasks:
             # Residents are cached conveniences — the disk store stays
             # authoritative — so a verified, unclaimed sink yields its
@@ -468,37 +555,70 @@ class DeviceSinkManager:
         ``tf`` and ``device`` as for ``on_piece``; a sink of the task that
         lies on another chip than the one named (a resident that an earlier
         request left there) is dropped and built again from the store on
-        the named one."""
-        return await self._run(tf, 0, self._finalize_sync, task_id, store,
+        the named one. The landing thread's job ends with the last stack's
+        ``device_put``; this call returns when the tail behind it has
+        verified the sink."""
+        tail = await self._run(tf, 0, self._finalize_sync, task_id, store,
                                tf, device)
+        # Shielded: a caller that gives up must not cancel a tail that
+        # other claimers of the task wait for, nor leave a queued one unrun
+        # with its sink neither verified nor dropped.
+        return await asyncio.shield(asyncio.wrap_future(tail))
 
     def _finalize_sync(self, task_id: str, store, tf=None,
-                       device=None) -> TaskDeviceSink | None:
-        # piece: how many pieces the backfill landed, counted as it goes.
-        with self._span(tf and tf.record, flightlib.EV_SINK_FINALIZE,
-                        0) as step:
-            if task_id in self._degraded:
-                self._degraded.discard(task_id)  # next attempt starts fresh
-                return None
+                       device=None) -> Future:
+        """The landing thread's part of a finalize, up to the hand-over.
+        Returns the future of the finalize's result: its tail's, or one
+        that is over already where there is nothing to verify."""
+        step = _FinalizeSpan(tf)
+        # The profiler's view of the thread's part; the flight's
+        # sink_finalize is stamped where the finalize ends (``step.end``).
+        with self._span(None, flightlib.EV_SINK_FINALIZE):
+            tail = None
             try:
-                return self._finalize_inner(task_id, store, tf, step,
-                                            device)
-            except DeviceSinkError:
-                raise  # device-copy corruption: surfaced to the caller
+                with self._lock:
+                    degraded = task_id in self._degraded
+                    self._degraded.discard(task_id)  # next attempt starts fresh
+                if not degraded:
+                    tail = self._finalize_inner(task_id, store, tf, step,
+                                                device)
             except Exception as e:
-                # Environment failures (OOM during backfill staging,
-                # assembly dispatch errors, store read races) degrade to
-                # disk-only — the digest-verified disk result must not be
-                # discarded over a device-side hiccup.
-                log.warning("device finalize failed; disk-only result",
-                            task=task_id[:16], error=str(e)[:200])
-                self._note_error(task_id, "finalize", e)
+                self._disk_only(task_id, e)
+        if tail is None:
+            step.end()
+            tail = Future()
+            tail.set_result(None)
+        return tail
+
+    def _disk_only(self, task_id: str, err,
+                   only: TaskDeviceSink | None = None) -> None:
+        """Environment failures (OOM during backfill staging, assembly
+        dispatch errors, store read races) degrade to disk-only — the
+        digest-verified disk result must not be discarded over a
+        device-side hiccup. ``only``: the sink that failed, in its tail;
+        where the manager has forgotten it meanwhile (discarded, a retry's
+        sink in its place) there is nothing of the task's to degrade."""
+        log.warning("device finalize failed; disk-only result",
+                    task=task_id[:16], error=str(err)[:200])
+        with self._lock:
+            if only is None or self._sinks.get(task_id) is only:
+                self._note_error(task_id, "finalize", err)
                 self._drop(task_id)
-                return None
 
     def _finalize_inner(self, task_id: str, store, tf, step,
-                        device=None) -> TaskDeviceSink | None:
+                        device=None) -> Future | None:
         m = store.metadata
+        in_tail = self._tails.get(task_id)
+        if in_tail is not None:
+            sink, tail = in_tail
+            if ((device is None or sink.device == device)
+                    and not self._stale(sink, store)):
+                # Another claimer of the task, while its sink is being
+                # verified: the one verification answers both.
+                step.note = f"chip={sink.device.id}"
+                tail.add_done_callback(lambda _: step.end())
+                return tail
+            wait([tail])
         sink = self._sinks.get(task_id)
         if sink is not None and device is not None and sink.device != device:
             log.info("device sink on another chip than asked; rebuilding",
@@ -532,11 +652,49 @@ class DeviceSinkManager:
                             group[0].num):
                 self._land(sink, store, group, tf)
             step.piece += len(group)
-        sink.verify()
-        self._settle(sink)
-        log.info("device sink verified", task=task_id[:16],
-                 pieces=len(sink.landed))
-        return sink
+        # The host's work on the sink's bytes ends with the last stack's
+        # device_put: what is left waits for the device, and this thread
+        # takes the next queued job, whose first act is a host pass.
+        sink.sink.flush()
+        with self._lock:
+            tail = self._completer.submit(
+                self._tail, task_id, sink, step, time.perf_counter(),
+                self._started)
+            self._tails[task_id] = (sink, tail)
+        return tail
+
+    def _tail(self, task_id: str, sink: TaskDeviceSink, step,
+              handed: float, started: int) -> TaskDeviceSink | None:
+        """What is left of a finalize once its last stack was put, on the
+        completer: the wait for the sink's puts, the assembly's dispatch,
+        the fetched checksums and their comparison with the host's
+        (``TaskDeviceSink.verify``; a corrupt piece's DeviceSinkError goes
+        to the caller of ``finalize``), then the bookkeeping. Stamps
+        ``sink_tail`` (``aux`` = ms since the hand-over, ``piece`` = jobs
+        the landing thread started in that time: 0 says nothing ran beside
+        it) and ends the finalize's own span."""
+        try:
+            with self._span(None, flightlib.EV_SINK_TAIL):
+                try:
+                    sink.verify()
+                except DeviceSinkError:
+                    raise
+                except Exception as e:
+                    self._disk_only(task_id, e, sink)
+                    return None
+                with self._lock:
+                    self._settle(sink)
+                log.info("device sink verified", task=task_id[:16],
+                         pieces=len(sink.landed))
+                return sink
+        finally:
+            with self._lock:
+                self._tail_over(task_id, sink)
+            beside = self._started - started
+            SINK_TAILS.labels("overlapped" if beside else "alone").inc()
+            step.stamp(flightlib.EV_SINK_TAIL, beside,
+                       (time.perf_counter() - handed) * 1000.0)
+            step.end()
 
     async def replicate(self, sink: TaskDeviceSink, mesh,
                         axis_name: str = "d", tf=None) -> None:
@@ -586,20 +744,21 @@ class DeviceSinkManager:
         return self._drop(task_id)
 
     def discard(self, task_id: str) -> None:
-        self._drop(task_id)
-        self._degraded.discard(task_id)
-        self._errors.pop(task_id, None)
+        with self._lock:
+            self._drop(task_id)
+            self._degraded.discard(task_id)
+            self._errors.pop(task_id, None)
 
     def outcome(self, task_id: str, verified: bool) -> dict:
         """What a device request's final progress says beside
         ``device_verified``: the device that holds the bytes, or the
         error that kept them off it."""
-        sink = self._sinks.get(task_id)
+        with self._lock:
+            sink = self._sinks.get(task_id)
+            error = self._errors.pop(task_id, "")
         if verified and sink is not None:
-            self._errors.pop(task_id, None)
             return {"device_platform": sink.sink.platform,
                     "device_kind": sink.sink.device_kind}
-        error = self._errors.pop(task_id, "")
         return {"device_error": error} if error else {}
 
     def gc(self) -> None:
@@ -609,10 +768,11 @@ class DeviceSinkManager:
 
     def _expire(self) -> None:
         now = time.time()
-        for tid in [t for t, s in self._sinks.items()
-                    if now - s.created_at > self.ttl]:
-            log.info("device sink expired", task=tid[:16])
-            self._drop(tid)
+        with self._lock:
+            for tid in [t for t, s in self._sinks.items()
+                        if now - s.created_at > self.ttl]:
+                log.info("device sink expired", task=tid[:16])
+                self._drop(tid)
 
     def default_mesh(self):
         """Mesh over LOCAL devices per TPUSinkOption.mesh_shape (or all

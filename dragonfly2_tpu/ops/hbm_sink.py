@@ -24,8 +24,7 @@ arrival:
     pieces) and no arrival order compiles anything: a complete 55-piece
     sink is always batches of 8,8,8,8,8,8,7, whatever reached the thread
     first. (A placement that is part of the program costs a compile of
-    0.8-3.5 s on the landing thread for almost every cold pull: PERF.md
-    section 6.)
+    0.8-3.5 s for almost every cold pull: PERF.md section 6.)
 
 Host staging: the sink owns its stacks, and they are reused. A stack comes
 from a process-wide free list (``pkg/bufpool``, pool ``hbm_stage``), so
@@ -150,7 +149,7 @@ _PASSES_FUSED = SINK_PASSES.labels("fused")
 _PASSES_CHECKSUM = SINK_PASSES.labels("checksum")
 SINK_ASSEMBLIES = metrics.counter(
     "device_sink_assemblies_total",
-    "Assembly dispatches, by whether the landing thread compiled the "
+    "Assembly dispatches, by whether the dispatching thread compiled the "
     "program for them (compiled: a geometry this process had not assembled "
     "before, and the persistent cache did not hold) or not (cached)",
     ("how",))

@@ -92,8 +92,10 @@ EV_LOOP_LAG = 27       # event loop wedged during this task (aux=lag_s)
 EV_GC_PAUSE = 28       # slow cyclic-GC pause during this task (aux=pause_s)
 # Spans of the device-sink landing thread: ONE event at the span's end,
 # aux = its duration in ms (start = t - aux/1000, as landed/source_landed
-# back theirs out). All stamped by the one df-device-sink thread, so a
-# span's children are the spans its interval contains.
+# back theirs out). A task's are stamped one after the other, by the one
+# df-device-sink thread and, from a finalize's hand-over on, by the one
+# completer behind it (sink_assemble, sink_compile, sink_tail,
+# sink_finalize), so a span's children are the spans its interval contains.
 EV_SINK_LAND = 29      # one host pass and the staging of its pieces: a piece as it arrives, a group of a finalize's backfill (piece=num, the group's lowest)
 # The pieces the sink reads itself cost ONE host pass a group
 # (HBMSink.read_pieces: each helper takes the next chunk, reads it from the
@@ -103,9 +105,9 @@ EV_SINK_READ = 30      # what the thread that read longest spent reading inside 
 EV_SINK_CHECKSUM = 31  # the pass less that (same piece and note); or a checksum pass over bytes a caller brought (piece=num, note=chunks)
 EV_SINK_STAGE = 32     # what is left of staging: taking a stack, copying foreign bytes, zeroing a short tail, the batch's order (piece=num, lowest slot, or -1)
 EV_SINK_PUT = 33       # flush: the device_put call (piece=lowest slot)
-EV_SINK_ASSEMBLE = 34  # assembly dispatch -> checksums on host (piece=batches)
+EV_SINK_ASSEMBLE = 34  # the sink's puts awaited, assembly dispatch -> checksums on host (piece=batches)
 EV_SINK_COMPILE = 35   # backend compile inside that assembly (piece=batches)
-EV_SINK_FINALIZE = 36  # backfill + assemble + verify (piece=pieces backfilled, note="chip=<id>": the local device the sink lies on)
+EV_SINK_FINALIZE = 36  # backfill + assemble + verify, job start -> verified (piece=pieces backfilled, note="chip=<id>": the local device the sink lies on)
 EV_PARENT_PIECES = 37  # a parent announced pieces (piece=lowest, aux=how many)
 # A job's wait for the landing thread, stamped as the job starts there:
 # submission on the event loop -> start on the thread. A sibling of the
@@ -146,6 +148,13 @@ EV_TASK_SOURCES = 49
 # id of the local device the bytes landed on; note = "chips=<id>,<id>,.." where
 # the words lie on more chips than that one, the landing chip first).
 EV_DEVICE_PULL = 50
+# A finalize's tail, off the landing thread: ONE event as the tail ends,
+# inside its sink_finalize (aux = ms since the landing thread handed the
+# sink over with its last stack's device_put: the wait behind earlier tails,
+# the wait for the sink's puts, the assembly, the fetched checksums, the
+# comparison; piece = jobs the landing thread STARTED in that time, 0 where
+# nothing ran beside the tail).
+EV_SINK_TAIL = 51
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -176,6 +185,7 @@ EVENT_NAMES = {
     EV_PARENT_SOURCE_FIRST_BYTE: "parent_source_first_byte",
     EV_PARENT_VERIFIED: "parent_verified",
     EV_TASK_SOURCES: "task_sources", EV_DEVICE_PULL: "device_pull",
+    EV_SINK_TAIL: "sink_tail",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING
@@ -183,13 +193,13 @@ EVENT_NAMES = {
 # so --explain can say the LOOP was wedged, not just "nothing happened".
 _RUNTIME_EVENTS = (EV_LOOP_LAG, EV_GC_PAUSE)
 
-# The landing thread's steps, then the fan-out over the mesh and the
-# verification on every chip, and last the jobs' wait for the thread, summed
-# into the report's ``hbm`` block.
+# The landing thread's steps and a finalize's tail behind it, then the
+# fan-out over the mesh and the verification on every chip, and last the
+# jobs' wait for the thread, summed into the report's ``hbm`` block.
 _SINK_STEPS = (EV_SINK_LAND, EV_SINK_READ, EV_SINK_CHECKSUM, EV_SINK_STAGE,
                EV_SINK_PUT, EV_SINK_ASSEMBLE, EV_SINK_COMPILE,
-               EV_SINK_FINALIZE, EV_SINK_REPLICATE, EV_SINK_VERIFY_CHIPS,
-               EV_SINK_WAIT)
+               EV_SINK_FINALIZE, EV_SINK_TAIL, EV_SINK_REPLICATE,
+               EV_SINK_VERIFY_CHIPS, EV_SINK_WAIT)
 # The client API's steps, summed into the report's ``client`` block.
 _CLIENT_STEPS = (EV_ADMIT_WAIT, EV_SHARD_PLAN, EV_SHARD_VIEWS)
 # Chip-to-chip work of a landing: booked under ``ici`` beside the
@@ -766,8 +776,8 @@ def render_waterfall(report: dict) -> str:
         lines.append(f"  {ph:<10} {v:8.3f}s {100 * v / wall:5.1f}% {bar}")
     hbm = report.get("hbm")
     if hbm:
-        lines.append("hbm landing, ms on the landing thread (wait: queued "
-                     "for it): " + " ".join(
+        lines.append("hbm landing, ms on the landing thread (tail: the end "
+                     "of finalize off it; wait: queued for it): " + " ".join(
                          f"{k[:-3]}={v:.1f}" for k, v in hbm.items()))
     client = report.get("client")
     if client:

@@ -1939,3 +1939,286 @@ mgr.close()
         timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-2000:]
     assert out.stdout.split("\n")[-2] == f"[] 0 0 0 0 8 {2 << 20} 2 8"
+
+
+# -- a finalize's tail, off the landing thread (PR 43) ----------------------
+
+class _HeldTail:
+    """``TaskDeviceSink.verify`` patched so that a tail says when it has
+    begun and then stands still until the test lets it go: an event, no
+    clock. ``before(sink)`` runs on the completer just before the real
+    verification."""
+
+    def __init__(self, monkeypatch, before=None):
+        import threading
+
+        from dragonfly2_tpu.daemon.peer import device_sink
+
+        self.begun = threading.Event()
+        self.go = threading.Event()
+        self.calls = 0
+        real = device_sink.TaskDeviceSink.verify
+
+        def verify(sink):
+            self.calls += 1
+            self.begun.set()
+            assert self.go.wait(60), "the test never let the tail go"
+            if before is not None:
+                before(sink)
+            real(sink)
+
+        monkeypatch.setattr(device_sink.TaskDeviceSink, "verify", verify)
+
+    async def has_begun(self):
+        import asyncio
+
+        assert await asyncio.to_thread(self.begun.wait, 60), \
+            "no tail began"
+
+
+def _assemblies() -> float:
+    from dragonfly2_tpu.ops import hbm_sink
+
+    return sum(hbm_sink.SINK_ASSEMBLIES.labels(how)._value.get()
+               for how in ("compiled", "cached"))
+
+
+@pytest.mark.parametrize("ending", ["verified", "corrupt", "environment",
+                                    "discarded"])
+def test_a_tails_ending_reaches_the_caller_of_finalize(
+        run_async, tmp_path, monkeypatch, ending):
+    """The tail runs off the landing thread and ``finalize()`` still ends as
+    it did: the verified sink; DeviceSinkError for a corrupt piece; None, the
+    error noted, the sink dropped and its stacks back after an environment
+    failure; and a sink discarded mid-tail is forgotten at once, its tail
+    ending on its own reference. Whatever the ending, the manager keeps no
+    tail and counts no sink as landing afterwards."""
+    import asyncio
+    import gc
+
+    from dragonfly2_tpu.daemon.peer import device_sink
+    from dragonfly2_tpu.ops import hbm_sink
+
+    def before(sink):
+        if ending == "corrupt":
+            # Piece 1's recorded checksum is of other bytes than landed.
+            sink.sink.host_checksums[1] = (0x12345678, 0x9ABCDEF0)
+
+    def refuses(*args, **kwargs):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+    async def body():
+        store, content = _stored(tmp_path, "t-" + ending, 64 * 1024,
+                                 64 * 1024 * 10)
+        task_id = store.metadata.task_id
+        held = _HeldTail(monkeypatch, before)
+        if ending == "environment":
+            monkeypatch.setattr(hbm_sink, "_assemble_checksum_jit", refuses)
+        mgr = device_sink.DeviceSinkManager(batch_pieces=4)
+        landing = device_sink.SINKS_LANDING._value.get()
+        outstanding = hbm_sink._STAGING.stats()["outstanding"]
+        try:
+            pending = asyncio.ensure_future(mgr.finalize(task_id, store))
+            await held.has_begun()
+            # Mid-tail: the landing thread is free (a job runs), the sink
+            # still counts as landing, and its last stack is still out.
+            await mgr._run(None, 0, lambda: None)
+            assert not pending.done()
+            assert device_sink.SINKS_LANDING._value.get() == landing + 1
+            assert hbm_sink._STAGING.stats()["outstanding"] > outstanding
+            assert list(mgr._tails) == [task_id]
+            if ending == "discarded":
+                mgr.discard(task_id)
+                assert mgr.get(task_id) is None and mgr._tails == {}
+            held.go.set()
+            if ending == "corrupt":
+                with pytest.raises(device_sink.DeviceSinkError,
+                                   match="piece 1"):
+                    await pending
+                # As before: the caller of finalize discards it.
+                assert mgr.get(task_id) is not None
+                mgr.discard(task_id)
+            elif ending == "environment":
+                assert await pending is None
+                assert "out of HBM" in mgr.outcome(task_id, False)[
+                    "device_error"]
+            else:
+                sink = await pending
+                assert sink.verified
+                assert bytes(np.asarray(sink.as_bytes_array())) == content
+                assert mgr.get(task_id) is (
+                    None if ending == "discarded" else sink)
+                mgr.take(task_id)
+                del sink
+            assert mgr.get(task_id) is None and mgr._tails == {}
+            assert device_sink.SINKS_LANDING._value.get() == landing
+            del pending
+            gc.collect()
+            return hbm_sink._STAGING.stats()["outstanding"] - outstanding
+        finally:
+            held.go.set()
+            mgr.close()
+
+    assert run_async(body(), timeout=120) == 0
+
+
+def test_a_second_finalize_of_a_task_mid_tail_joins_it(
+        run_async, tmp_path, monkeypatch):
+    """Two claimers of one task: the second's finalize job finds the sink in
+    its tail and touches nothing of it; both get the one verification's
+    sink, the assembly was dispatched once, and both flights' spans end."""
+    import asyncio
+
+    from dragonfly2_tpu.daemon.peer import device_sink
+    from dragonfly2_tpu.pkg import flight
+
+    async def body():
+        store, content = _stored(tmp_path, "t-joined", 64 * 1024,
+                                 64 * 1024 * 10)
+        held = _HeldTail(monkeypatch)
+        mgr = device_sink.DeviceSinkManager(batch_pieces=4)
+        first_tf, second_tf = (flight.TaskFlight("t-joined")
+                               for _ in range(2))
+        assemblies = _assemblies()
+        try:
+            first = asyncio.ensure_future(
+                mgr.finalize("t-joined", store, first_tf))
+            await held.has_begun()
+            second = asyncio.ensure_future(
+                mgr.finalize("t-joined", store, second_tf))
+            # The landing thread takes jobs in turn: once a job submitted
+            # after the second finalize's has run, that one is over, and it
+            # neither waited for the tail nor is its caller answered yet.
+            await asyncio.wait_for(mgr._run(None, 0, lambda: None), 60)
+            assert not first.done() and not second.done()
+            assert list(mgr._tails) == ["t-joined"]
+            held.go.set()
+            sinks = await asyncio.gather(first, second)
+            assert sinks[0] is sinks[1] and sinks[0].verified
+            assert bytes(np.asarray(sinks[0].as_bytes_array())) == content
+            assert held.calls == 1 and _assemblies() - assemblies == 1
+            assert mgr._tails == {}
+        finally:
+            held.go.set()
+            mgr.close()
+        return first_tf, second_tf
+
+    first_tf, second_tf = run_async(body(), timeout=120)
+
+    def counted(tf, name):
+        return sum(flight.EVENT_NAMES[code] == name
+                   for _, code, _, _, _ in tf.events())
+
+    # The tail is the first's; the second's finalize has its own span, job
+    # start -> the same verification, and backfilled nothing.
+    assert (counted(first_tf, "sink_tail"), counted(second_tf, "sink_tail"),
+            counted(first_tf, "sink_finalize"),
+            counted(second_tf, "sink_finalize")) == (1, 0, 1, 1)
+    assert counted(second_tf, "sink_land") == 0
+
+
+def test_a_caller_that_gives_up_mid_tail_cancels_its_own_wait_alone(
+        run_async, tmp_path, monkeypatch):
+    """A finalize cancelled while its sink is in its tail: the tail runs on,
+    a second claimer that joined it gets the verified sink from the one
+    assembly, and the manager keeps no tail afterwards."""
+    import asyncio
+
+    from dragonfly2_tpu.daemon.peer import device_sink
+
+    async def body():
+        store, content = _stored(tmp_path, "t-given-up", 64 * 1024,
+                                 64 * 1024 * 10)
+        held = _HeldTail(monkeypatch)
+        mgr = device_sink.DeviceSinkManager(batch_pieces=4)
+        assemblies = _assemblies()
+        try:
+            first = asyncio.ensure_future(mgr.finalize("t-given-up", store))
+            await held.has_begun()
+            second = asyncio.ensure_future(mgr.finalize("t-given-up", store))
+            await asyncio.wait_for(mgr._run(None, 0, lambda: None), 60)
+            first.cancel()
+            with pytest.raises(asyncio.CancelledError):
+                await first
+            assert list(mgr._tails) == ["t-given-up"]
+            held.go.set()
+            sink = await second
+            assert sink.verified and mgr.get("t-given-up") is sink
+            assert bytes(np.asarray(sink.as_bytes_array())) == content
+            assert held.calls == 1 and _assemblies() - assemblies == 1
+            assert mgr._tails == {}
+        finally:
+            held.go.set()
+            mgr.close()
+
+    run_async(body(), timeout=120)
+
+
+def test_many_finalizes_at_once_never_assemble_two_at_a_time(
+        run_async, tmp_path, monkeypatch):
+    """Twelve sinks queued at the landing thread while the event loop takes
+    and discards finished ones, under a shortened switch interval: every
+    finalize ends verified with its store's bytes, the completer ran one
+    tail at a time (no two assemblies in flight), and the manager's books
+    are empty at the end."""
+    import asyncio
+    import sys
+    import threading
+
+    from dragonfly2_tpu.daemon.peer import device_sink
+    from dragonfly2_tpu.ops import hbm_sink
+
+    in_flight, most = [0], [0]
+    guard = threading.Lock()
+    real = hbm_sink._assemble_checksum_jit
+
+    def counted(*args, **kwargs):
+        with guard:
+            in_flight[0] += 1
+            most[0] = max(most[0], in_flight[0])
+        try:
+            out = real(*args, **kwargs)
+            for part in out:
+                part.block_until_ready()
+            return out
+        finally:
+            with guard:
+                in_flight[0] -= 1
+
+    monkeypatch.setattr(hbm_sink, "_assemble_checksum_jit", counted)
+
+    async def body():
+        stores = [_stored(tmp_path, f"t-many-{n}", 16 * 1024,
+                          16 * 1024 * 9 + 100 * n, seed=n)
+                  for n in range(12)]
+        mgr = device_sink.DeviceSinkManager(batch_pieces=4, max_tasks=16)
+        landing = device_sink.SINKS_LANDING._value.get()
+        tails = sum(device_sink.SINK_TAILS.labels(how)._value.get()
+                    for how in ("overlapped", "alone"))
+
+        async def land(store, content, n):
+            task_id = store.metadata.task_id
+            sink = await mgr.finalize(task_id, store)
+            assert sink is not None and sink.verified
+            assert bytes(np.asarray(sink.as_bytes_array())) == content
+            if n % 2:
+                assert mgr.take(task_id) is sink
+            else:
+                mgr.discard(task_id)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            await asyncio.wait_for(asyncio.gather(*(
+                land(store, content, n)
+                for n, (store, content) in enumerate(stores))), 100)
+        finally:
+            sys.setswitchinterval(interval)
+            mgr.close()
+        assert mgr._tails == {} and mgr._sinks == {}
+        assert device_sink.SINKS_LANDING._value.get() == landing
+        return sum(device_sink.SINK_TAILS.labels(how)._value.get()
+                   for how in ("overlapped", "alone")) - tails
+
+    assert run_async(body(), timeout=120) == 12
+    assert most[0] == 1 and in_flight[0] == 0

@@ -446,10 +446,12 @@ def test_a_new_landing_evicts_a_resident_of_its_own_chip_first(tmp_path):
     try:
         for n, device in enumerate(devices):
             store, _ = _stored(tmp_path, f"t{n}", 4096, 10000, seed=n)
-            sink = manager._finalize_sync(f"t{n}", store, None, device)
+            sink = manager._finalize_sync(f"t{n}", store, None,
+                                          device).result(60)
             assert sink.verified and sink.device == device
         store, content = _stored(tmp_path, "t3", 4096, 10000, seed=3)
-        sink = manager._finalize_sync("t3", store, None, devices[1])
+        sink = manager._finalize_sync("t3", store, None,
+                                      devices[1]).result(60)
         assert sink.device == devices[1]
         assert sorted(manager._sinks) == ["t0", "t2", "t3"]
         assert bytes(np.asarray(sink.as_bytes_array())) == content
@@ -457,8 +459,8 @@ def test_a_new_landing_evicts_a_resident_of_its_own_chip_first(tmp_path):
         manager._sinks["t3"].verified_at = 0.0
         store, _ = _stored(tmp_path, "t4", 4096, 10000, seed=4)
         manager.protect("t3")
-        assert manager._finalize_sync("t4", store, None,
-                                      devices[1]).device == devices[1]
+        assert manager._finalize_sync(
+            "t4", store, None, devices[1]).result(60).device == devices[1]
         assert sorted(manager._sinks) == ["t2", "t3", "t4"]
         assert manager.outcome("t4", True) == {
             "device_platform": "cpu",
@@ -480,11 +482,12 @@ def test_a_sink_runs_its_programs_on_the_chip_it_is_on(tmp_path, chip):
     manager = device_sink.DeviceSinkManager()
     try:
         store, content = _stored(tmp_path, "t", 4096, 3 * 4096 + 1234)
-        sink = manager._finalize_sync("t", store, None, device)
+        sink = manager._finalize_sync("t", store, None, device).result(60)
         assert sink.verified and sink.device == device
         assert sink.as_words().devices() == {device}
         assert sink.as_tensor("uint16", (100,)).devices() == {device}
         assert bytes(np.asarray(sink.as_bytes_array())) == content
-        assert manager._finalize_sync("t", store, None, None) is sink
+        assert manager._finalize_sync("t", store, None,
+                                      None).result(60) is sink
     finally:
         manager.close()

@@ -296,6 +296,179 @@ def test_reland_backfill_stamps_the_same_steps_and_one_finalize(
     check_sums([final])
 
 
+# -- a finalize's tail, off the landing thread (PR 43) ----------------------
+
+class OrderedFlight(flight.TaskFlight):
+    """A flight that tells ``seen(task, event name)`` of each event before
+    it is recorded: the order of two tasks' stamps with no clock compared."""
+
+    def __init__(self, task_id: str, seen):
+        super().__init__(task_id)
+        self.seen = seen
+
+    def record(self, code, piece=-1, aux=0.0, note=""):
+        self.seen(self.task_id, flight.EVENT_NAMES[code])
+        super().record(code, piece, aux, note)
+
+
+def tails_counted() -> dict:
+    from dragonfly2_tpu.daemon.peer import device_sink
+
+    return {how: device_sink.SINK_TAILS.labels(how)._value.get()
+            for how in ("overlapped", "alone")}
+
+
+def named(tf, name: str) -> list:
+    """(start, end, piece) of the flight's ``name`` events, on its clock."""
+    return [(t - aux / 1000.0, t, piece) for t, code, piece, aux, _
+            in tf.events() if flight.EVENT_NAMES[code] == name]
+
+
+def test_a_tail_runs_beside_the_next_sinks_host_pass(run_async, tmp_path,
+                                                     monkeypatch):
+    """Two finalizes queued at the one landing thread: the first's tail is
+    held (an event, no clock) until the second's first ``sink_land`` has
+    been stamped, which can only happen if the thread took the second job
+    while the first's sink was still being verified. The first's
+    ``sink_tail`` counts that job; its ``sink_finalize`` still covers job
+    start -> verified, and ``finalize()`` returned after it."""
+    from dragonfly2_tpu.daemon.peer import device_sink
+
+    order: list = []
+    second_lands = threading.Event()
+    verified_at_finalize: dict = {}
+
+    async def body():
+        mgr = device_sink.DeviceSinkManager(batch_pieces=BATCH)
+
+        def seen(task_id, name):
+            order.append((task_id, name))
+            if (task_id, name) == ("t-second", "sink_land"):
+                second_lands.set()
+            if name == "sink_finalize":
+                sink = mgr.get(task_id)
+                verified_at_finalize[task_id] = (
+                    sink is not None and sink.verified)
+
+        real = device_sink.TaskDeviceSink.verify
+
+        def held(self):
+            if self.task_id == "t-first":
+                assert second_lands.wait(60), \
+                    "the landing thread took no job while the tail ran"
+            real(self)
+
+        monkeypatch.setattr(device_sink.TaskDeviceSink, "verify", held)
+        stores = {name: make_store(tmp_path, name, 64 * 1024, seed)[0]
+                  for seed, name in enumerate(("t-first", "t-second"))}
+        flights = {name: OrderedFlight(name, seen) for name in stores}
+        before = tails_counted()
+        try:
+            sinks = await asyncio.gather(*(
+                mgr.finalize(name, store, flights[name])
+                for name, store in stores.items()))
+            assert all(sink is not None and sink.verified for sink in sinks)
+            assert mgr._tails == {}
+            # finalize() resolved only after its span was stamped.
+            for tf in flights.values():
+                assert len(named(tf, "sink_finalize")) == 1
+        finally:
+            mgr.close()
+        return flights, {how: n - before[how]
+                         for how, n in tails_counted().items()}
+
+    flights, counted = run_async(body(), timeout=120)
+    first, second = flights["t-first"], flights["t-second"]
+    assert order.index(("t-second", "sink_land")) < order.index(
+        ("t-first", "sink_tail"))
+    (tail,), (final,) = named(first, "sink_tail"), named(first,
+                                                         "sink_finalize")
+    assert tail[2] >= 1                 # jobs started beside the tail
+    # The tail lies inside its finalize, which ends it: job start ->
+    # verified, and the sink was verified when the span was stamped.
+    assert final[0] <= tail[0] <= tail[1] <= final[1]
+    assert order.index(("t-first", "sink_assemble")) < order.index(
+        ("t-first", "sink_tail")) < order.index(("t-first", "sink_finalize"))
+    assert verified_at_finalize == {"t-first": True, "t-second": True}
+    # The second found no successor queued: nothing ran beside its tail.
+    (alone,) = named(second, "sink_tail")
+    assert alone[2] == 0
+    assert counted == {"overlapped": 1, "alone": 1}
+    # Every span of a task is stamped by the landing thread or, from the
+    # hand-over on, by the completer: the tree of one task is as ever.
+    (tree,) = tree_of(first)
+    assert tree.name == "sink_finalize" and tree.piece == PIECES
+    assert [c.name for c in tree.children][-1] == "sink_assemble"
+
+
+def test_a_lone_sinks_tail_hides_behind_nothing(run_async, tmp_path):
+    from dragonfly2_tpu.daemon.peer.device_sink import DeviceSinkManager
+
+    async def body():
+        store, _ = make_store(tmp_path, "t-lone", 64 * 1024)
+        tf = WatchedFlight("t-lone")
+        mgr = DeviceSinkManager(batch_pieces=BATCH)
+        before = tails_counted()
+        try:
+            sink = await mgr.finalize("t-lone", store, tf)
+            assert sink is not None and sink.verified
+        finally:
+            mgr.close()
+        return tf, {how: n - before[how]
+                    for how, n in tails_counted().items()}
+
+    tf, counted = run_async(body(), timeout=120)
+    (tail,) = named(tf, "sink_tail")
+    assert tail[2] == 0 and counted == {"overlapped": 0, "alone": 1}
+    # Landing thread up to the hand-over, the completer from there.
+    assert {name.rsplit("_", 1)[0] for name in tf.stampers} == {
+        "df-device-sink", "df-device-sink-tail"}
+    rep = flight.analyze(tf)
+    assert rep["hbm"]["tail_ms"] == pytest.approx(
+        (tail[1] - tail[0]) * 1000.0, abs=0.01)
+    assert "tail=" in flight.render_waterfall(rep)
+
+
+def _tail_run(*ops):
+    """A window as the harness hands it to a reader: ``flight`` rows are
+    (t, name, piece, aux)."""
+    return types.SimpleNamespace(ops=[
+        types.SimpleNamespace(t0=t0, t1=t1, flight=sorted(events))
+        for t0, t1, events in ops])
+
+
+@pytest.mark.parametrize("name,ops,want", [
+    # One client, one sink: its own passes end where its tail begins.
+    ("one sink", [(10.0, 10.4, [(10.10, "sink_land", 0, 90.0),
+                                (10.20, "sink_land", 8, 90.0),
+                                (10.30, "sink_tail", 0, 80.0),
+                                (10.30, "sink_finalize", 16, 290.0)])], 0.0),
+    # Two tasks: the first's tail of 50 ms lies wholly inside the second's
+    # pass; the second's own tail of 50 ms behind nothing: half of each.
+    ("inside another task's pass",
+     [(20.0, 20.2, [(20.10, "sink_land", 0, 90.0),
+                    (20.16, "sink_tail", 1, 50.0)]),
+      (20.0, 20.3, [(20.20, "sink_land", 0, 95.0),
+                    (20.25, "sink_tail", 0, 50.0)])], 50.0),
+    ("wholly hidden",
+     [(30.0, 30.2, [(30.16, "sink_tail", 1, 50.0)]),
+      (30.0, 30.3, [(30.20, "sink_land", 0, 95.0)])], 100.0),
+    # Two passes of another task with a gap of 10 ms between them, under a
+    # tail of 40 ms: 30 of 40.
+    ("a gap between two passes",
+     [(40.0, 40.2, [(40.14, "sink_tail", 2, 40.0)]),
+      (40.0, 40.3, [(40.115, "sink_land", 0, 20.0),
+                    (40.145, "sink_land", 8, 20.0)])], 75.0),
+    ("no sink_tail stamped (the parent's program)",
+     [(50.0, 50.3, [(50.10, "sink_land", 0, 90.0),
+                    (50.30, "sink_finalize", 8, 290.0)])], None),
+    ("no operation", [], None),
+])
+def test_land_tail_hidden_pct_on_hand_made_runs(monkeypatch, name, ops, want):
+    value = load_reader(monkeypatch, "land_tail_hidden_pct")(_tail_run(*ops))
+    assert value == (want if want is None else pytest.approx(want)), name
+
+
 # -- the program <-> benchmark contract ------------------------------------
 # chipbench/layers/<metric>.py finds the program's flight events by name and
 # reads nothing where a name is gone; the ledger then shows a null. These
@@ -303,7 +476,8 @@ def test_reland_backfill_stamps_the_same_steps_and_one_finalize(
 
 SINK_READERS = ("land_read_ms", "land_checksum_ms", "land_stage_ms",
                 "land_put_ms", "finalize_ms", "plan_compile_ms",
-                "land_wait_ms", "land_thread_util_pct")
+                "land_wait_ms", "land_thread_util_pct",
+                "land_tail_hidden_pct")
 
 
 def load_reader(monkeypatch, metric: str):
@@ -381,7 +555,9 @@ def test_the_benchmarks_reader_finds_its_events(monkeypatch, window_of_two,
                                                 metric):
     value = load_reader(monkeypatch, metric)(window_of_two)
     assert isinstance(value, float) and value >= 0.0, (metric, value)
-    if metric != "plan_compile_ms":     # 0.0 where no plan was new
+    # 0.0 where no plan was new, and where one client lands one sink at a
+    # time: no other pass for a tail to lie behind.
+    if metric not in ("plan_compile_ms", "land_tail_hidden_pct"):
         assert value > 0.0, metric
 
 
@@ -487,6 +663,10 @@ def test_analyze_books_landing_as_hbm_not_ici(run_async, tmp_path):
     # has them); before them a key per step seen (this plan may have been
     # compiled before).
     assert block.pop("wait_ms") >= 0
+    # The finalize's tail, off the thread, lies inside its finalize.
+    assert list(block).index("tail_ms") == list(block).index(
+        "finalize_ms") + 1
+    assert 0 < block.pop("tail_ms") <= block["finalize_ms"]
     assert list(block) == [name[5:] + "_ms" for name in SINK_NAMES
                            if spans[name]]
     assert set(block) >= {"land_ms", "read_ms", "put_ms", "finalize_ms"}
