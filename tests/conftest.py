@@ -44,6 +44,16 @@ def run_async():
 
 
 @pytest.fixture
+def kernel_on_cpu(monkeypatch):
+    """``ops/bitview.py``'s rows kernel as the chip runs it, interpreted:
+    the CPU backend's serving path is the flat form, so a test says where
+    the kernel may run."""
+    from dragonfly2_tpu.ops import bitview
+
+    monkeypatch.setattr(bitview, "_KERNEL_PLATFORMS", ("tpu", "cpu"))
+
+
+@pytest.fixture
 def fresh_compiles():
     """Neither a persistent compilation cache nor an assembly program in
     memory, and the thread's compiles counted: whatever geometry a sink

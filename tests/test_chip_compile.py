@@ -20,8 +20,11 @@ import pytest
 
 MiB = 1 << 20
 CONTENT = 1700 * MiB + 4 * 12345      # about one checkpoint shard
+PIECES = 55 * 32 * MiB                # the same shard as the sink assembles
+                                      # it: whole pieces of 32 MiB
 EMBED = (163840, 2048)                # Moonlight-16B-A3B embed_tokens, bf16
 EXPERT = (1408, 2048)                 # one routed-expert matrix, 5.5 MiB
+DOWN = (2048, 1408)                   # its down_proj: rows of 5.5 word groups
 
 
 @pytest.fixture(scope="module")
@@ -209,10 +212,13 @@ def test_chunk_checksums_relayout_is_one_content(one_chip, piece_mib):
     assert temp <= 1.05 * content
 
 
-def _words(sharding):
+def _words(sharding, whole: bool = False):
+    """The landed words: the content's, or (``whole``) the whole pieces a
+    sink assembles, which the rows kernel reads as 128-word rows."""
     import jax.numpy as jnp
 
-    return _spec((-(-CONTENT // 4),), jnp.uint32, sharding)
+    return _spec((PIECES // 4 if whole else -(-CONTENT // 4),), jnp.uint32,
+                 sharding)
 
 
 def _starts(n: int, sharding):
@@ -262,27 +268,42 @@ def test_typed_view_from_words(one_chip, dtype, shape, shift, factor):
     assert size <= out < size + 4096 and temp <= factor * out
 
 
-def _group_memory(sharding, n: int):
-    """(output, temporary) bytes per device, and the compiled program, of
-    the view program for ``n`` routed-expert matrices of the benchmark's
-    shard: bf16 ``EXPERT``, starting 2 bytes into a word."""
+def _group_memory(sharding, n: int, form: str = "flat", shift: int = 2,
+                  shape=EXPERT):
+    """(output, temporary) bytes per device, the compiled program and the
+    seconds its compile took, of the view program for ``n`` bf16 tensors
+    of ``shape`` (the benchmark's routed-expert matrices), starting
+    ``shift`` bytes into a word, cut by ``form``."""
+    import time
+
     import jax
     import jax.numpy as jnp
 
     from dragonfly2_tpu.ops import bitview
 
+    how = {}
+    if form == "rows":
+        how = {"form": "rows",
+               "mesh": getattr(sharding, "mesh", None)}
+    began = time.perf_counter()
     compiled = jax.jit(functools.partial(
-        bitview._views_jit, shift=2, dtype=jnp.dtype(jnp.bfloat16),
-        shape=EXPERT)).lower(_words(sharding), _starts(n, sharding)).compile()
+        bitview._views_jit, shift=shift, dtype=jnp.dtype(jnp.bfloat16),
+        shape=shape, **how)).lower(
+        _words(sharding, whole=form == "rows"),
+        _starts(n, sharding)).compile()
+    seconds = time.perf_counter() - began
     m = compiled.memory_analysis()
-    return m.output_size_in_bytes, m.temp_size_in_bytes, compiled
+    return m.output_size_in_bytes, m.temp_size_in_bytes, compiled, seconds
 
 
+@pytest.mark.parametrize("form", ["flat", "rows"])
 @pytest.mark.parametrize("where", ["one_chip", "every_chip"])
-def test_a_group_of_views_is_its_members_and_no_more(topo, one_chip, where):
+def test_a_group_of_views_is_its_members_and_no_more(topo, one_chip, where,
+                                                     form):
     """One dispatch for ``_GROUP_CAP`` expert matrices, on one chip and
     on words that lie on every chip: the outputs are the members', the
-    temporaries at most the cap times the single view's (129,024 bytes),
+    temporaries at most the cap times the single view's (129,024 bytes
+    flat; by the rows kernel nothing but a tile for each member's start),
     nothing of the size of the content or of the group."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -292,14 +313,67 @@ def test_a_group_of_views_is_its_members_and_no_more(topo, one_chip, where):
     if where == "every_chip":
         sharding = NamedSharding(Mesh(np.array(topo.devices), ("d",)), P())
     cap = bitview._GROUP_CAP
-    single_out, single_temp, _ = _group_memory(sharding, 1)
-    out, temp, compiled = _group_memory(sharding, cap)
+    single_out, single_temp, _, _ = _group_memory(sharding, 1, form)
+    out, temp, compiled, _ = _group_memory(sharding, cap, form)
     nbytes = 2 * int(np.prod(EXPERT))
     assert single_out == nbytes and single_temp <= MiB // 4
     assert cap * nbytes <= out <= cap * (nbytes + 64)    # + the tuple's table
-    assert temp <= cap * single_temp
+    assert temp <= cap * (single_temp + 8192)     # + a member's start, a tile or two
     if where == "every_chip":
         assert all(s.is_fully_replicated for s in compiled.output_shardings)
+
+
+@pytest.mark.parametrize("shift", [0, 2])
+@pytest.mark.parametrize("shape,n", [(EMBED, 1), (EXPERT, None), (DOWN, None)],
+                         ids=["embed_tokens", "a_group_of_experts",
+                              "a_group_of_down_proj"])
+def test_a_view_is_written_once(one_chip, shape, n, shift):
+    """The rows kernel over 1.7 GiB of landed pieces: ``embed_tokens``, a
+    full group of expert matrices and one of their ``down_proj`` (rows of
+    704 words: only a PAIR of them is whole 128-word groups) at both
+    alignments a 2-byte tensor of the shard meets. The outputs are the members' and no more; what the
+    program holds beside them is under 0.1 GB for the embedding (the flat
+    form's two tensor-sized temporaries were 1.34 GB) and nothing a
+    member; a member is under 128 KiB of code (1.3 MB flat, which held
+    the cap at 8); and the group compiles within the seconds the flat
+    form's program of 8 takes here, and within a minute in any case."""
+    from dragonfly2_tpu.ops import bitview
+
+    n = n or bitview._GROUP_CAP
+    out, temp, compiled, seconds = _group_memory(one_chip, n, "rows", shift,
+                                                 shape)
+    nbytes = 2 * int(np.prod(shape))
+    assert n * nbytes <= out <= n * (nbytes + 64)
+    assert temp <= min(100 * 10 ** 6, n * MiB // 4)
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    assert code <= n * 128 * 1024 + 64 * 1024
+    _, _, _, flat_seconds = _group_memory(one_chip, 8, "flat", shift, EXPERT)
+    assert seconds <= max(flat_seconds, 2.0) * 2 and seconds <= 60
+    assert flat_seconds <= 60
+
+
+@pytest.mark.parametrize("dtype,shape,shift", [
+    ("float16", (300, 256), 3), ("int16", (4, 64, 512), 1),
+    ("uint16", (256, 8192), 2)])
+def test_the_rows_kernel_at_the_other_dtypes_and_alignments(one_chip, dtype,
+                                                            shape, shift):
+    """float16 (which the v5e's vector unit lacks: the kernel leaves it
+    unsigned and XLA makes the float), an odd byte, a last step that is
+    not whole, leading dimensions, and the widest row the kernel takes:
+    accepted, with no temporary."""
+    import jax
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops import bitview
+
+    m = jax.jit(functools.partial(
+        bitview._views_jit, shift=shift, dtype=jnp.dtype(dtype), shape=shape,
+        form="rows")).lower(
+        _words(one_chip, whole=True),
+        _starts(2, one_chip)).compile().memory_analysis()
+    nbytes = 2 * int(np.prod(shape))
+    assert 2 * nbytes <= m.output_size_in_bytes <= 2 * (nbytes + 4096)
+    assert m.temp_size_in_bytes <= MiB // 4
 
 
 def test_typed_view_from_bytes(one_chip):
@@ -410,25 +484,19 @@ def test_per_chip_checksums_of_a_replicated_content_need_no_temporary(topo):
     assert out <= 4096 and temp <= 4 * MiB     # a tile for 55 x 2 words
 
 
+@pytest.mark.parametrize("form", ["flat", "rows"])
 @pytest.mark.parametrize("dtype,shape", [("bfloat16", EMBED),
                                          ("bfloat16", EXPERT)])
-def test_typed_view_of_words_that_lie_on_every_chip(topo, dtype, shape):
+def test_typed_view_of_words_that_lie_on_every_chip(topo, dtype, shape, form):
     """A view cut from replicated words is replicated: the same tensor on
-    every chip, at the temporaries the one-chip view has."""
-    import jax
-    import jax.numpy as jnp
+    every chip, at the temporaries the one-chip view has (the rows kernel
+    runs on each chip's own copy, under ``shard_map``)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from dragonfly2_tpu.ops import bitview
-
     mesh = Mesh(np.array(topo.devices), ("d",))
-    everywhere = NamedSharding(mesh, P())
-    compiled = jax.jit(functools.partial(
-        bitview._views_jit, shift=2, dtype=dtype, shape=shape)).lower(
-        _spec((CONTENT // 4,), jnp.uint32, everywhere),
-        _starts(1, everywhere)).compile()
+    out, temp, compiled, _ = _group_memory(NamedSharding(mesh, P()), 1, form,
+                                           2, shape)
     assert all(s.is_fully_replicated for s in compiled.output_shardings)
-    m = compiled.memory_analysis()
     nbytes = 2 * int(np.prod(shape))
-    assert m.output_size_in_bytes == nbytes
-    assert m.temp_size_in_bytes <= 2.05 * nbytes + MiB
+    assert out == nbytes
+    assert temp <= (2.05 * nbytes + MiB if form == "flat" else MiB)

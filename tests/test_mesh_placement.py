@@ -323,24 +323,32 @@ def test_every_way_to_every_chip_gives_the_same_bytes(way):
 # -- (d2) a dispatch carries many views, on every chip ----------------------
 
 @pytest.mark.parametrize("pad", [0, 1, 2, 3])
-def test_grouped_views_of_words_on_every_chip_equal_the_reference(pad):
+@pytest.mark.parametrize("form", ["flat", "rows"])
+def test_grouped_views_of_words_on_every_chip_equal_the_reference(
+        kernel_on_cpu, form, pad):
     """Over words that lie on every chip a group of views is one program
     on every chip: cap + 1 members at two alignments, singles, a BOOL and
     a zero-length tensor, every one whole on every chip and equal to
-    ``np.frombuffer``, for the dispatches a single chip's load costs."""
+    ``np.frombuffer``, for the dispatches a single chip's load costs. The
+    group is of a shape the flat form cuts, and of one that the rows
+    kernel cuts on each chip's own copy of the words."""
     from dragonfly2_tpu.ops import bitview, safetensors as st
-    from tests.test_safetensors import (_expected_dispatches,
+    from tests.test_safetensors import (ROWS_SHAPE, _expected_dispatches,
                                         _views_counted, grouped_object)
 
-    content, want = grouped_object(bitview._GROUP_CAP + 1, pad, seed=40 + pad)
+    members = bitview._GROUP_CAP + 1
+    content, want = grouped_object(
+        members, pad, seed=40 + pad,
+        shape=ROWS_SHAPE if form == "rows" else (7, 9))
     sink = _landed(content, 4096)
     mesh = mesh_of(4)
     assert sink.replicate(mesh) == 3
     was = _views_counted()
     got = st.load_from_sink(sink)
     now = _views_counted()
-    assert (now[0] - was[0], now[1] - was[1]) == (
-        _expected_dispatches(content), len(want))
+    assert (now[0] - was[0], now[1] - was[1], now[2] - was[2]) == (
+        _expected_dispatches(content), len(want),
+        members if form == "rows" else 0)
     assert list(got) == list(want)
     devices = set(mesh.devices.flat)
     for name, reference in want.items():

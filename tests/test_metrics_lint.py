@@ -38,6 +38,7 @@ METRIC_MODULES = (
     "dragonfly2_tpu.daemon.peer.task_manager",
     "dragonfly2_tpu.daemon.peer.device_sink",
     "dragonfly2_tpu.client.device",
+    "dragonfly2_tpu.ops.bitview",
     "dragonfly2_tpu.scheduler.service",
     "dragonfly2_tpu.manager.client",
     "dragonfly2_tpu.proto.reportcodec",
@@ -122,6 +123,18 @@ def test_every_family_documented(all_families):
     assert not undocumented, (
         f"metric families missing from docs/OBSERVABILITY.md: "
         f"{undocumented} — every family needs a table row there")
+
+
+def test_the_views_counter_says_which_form_cut_the_tensor(all_families):
+    """``device_views_tensors_total{form}``: ``rows`` for the kernel that
+    writes a view once, ``flat`` for the general loops; both documented."""
+    by_name = {f["name"]: f for f in all_families}
+    assert by_name["device_views_tensors_total"]["labels"] == ("form",)
+    assert by_name["device_views_dispatches_total"]["labels"] == ()
+    with open(DOCS) as f:
+        row = next(line for line in f
+                   if line.startswith("| `device_views_tensors_total`"))
+    assert "| `form` |" in row and "`rows`" in row and "`flat`" in row
 
 
 def test_every_family_has_help_text(all_families):

@@ -338,29 +338,45 @@ _NUMPY = {"F32": np.float32, "U16": np.uint16, "I16": np.int16,
           "U8": np.uint8, "I8": np.int8, "BOOL": np.bool_, "I32": np.int32}
 
 
-def _views_counted() -> tuple[float, float]:
+def _views_counted() -> tuple[float, float, float]:
+    """(dispatches, tensors, of them cut by the rows kernel)."""
     from dragonfly2_tpu.ops import bitview
 
-    return (bitview.VIEWS_DISPATCHES._value.get(),
-            bitview.VIEWS_TENSORS._value.get())
+    rows, flat = (bitview.VIEWS_TENSORS.labels(form)._value.get()
+                  for form in ("rows", "flat"))
+    return bitview.VIEWS_DISPATCHES._value.get(), rows + flat, rows
 
 
-def grouped_object(members: int, pad: int, seed: int = 5):
+# The group's shape: two the rows kernel takes (rows of whole 128-word
+# groups or half of one; rows of 1.5, whose PAIRS are whole, as the benchmark's
+# ``down_proj`` rows of 1,408 items are 5.5) and four it does not: the
+# sweep's old shape (under ``_SINGLE_WORDS``), rows of 1,400 items, a 1-D
+# norm, and rows of whole groups but fewer than a step cuts.
+ROWS_SHAPE = (256, 128)
+PAIRED_SHAPE = (258, 384)
+FLAT_SHAPES = [(7, 9), (260, 1400), (2048,), (8, 256)]
+
+
+def grouped_object(members: int, pad: int, seed: int = 5, shape=(7, 9)):
     """A safetensors object whose header mixes one group of ``members``
-    I16 tensors of one shape with singles, a BOOL, a zero-length tensor
+    I16 tensors of ``shape`` with singles, a BOOL, a zero-length tensor
     and an odd U8 in the middle of the group (so the group's members lie
-    at two alignments), its data starting ``pad`` bytes into a word:
-    (content, {name: what ``np.frombuffer`` reads}) in header order."""
+    at two alignments), its data starting ``pad`` bytes into a word, and
+    a tail that keeps the last member a step's rows from the content's
+    end: (content, {name: what ``np.frombuffer`` reads}) in header order."""
     rng = np.random.default_rng(seed)
     table = [("a.single", "F32", (3, 5))]
     for i in range(members):
-        table.append((f"g.{i}", "I16", (7, 9)))
+        table.append((f"g.{i}", "I16", shape))
         if i == members // 2:
             table += [("m.odd", "U8", (11,)), ("m.bool", "BOOL", (6,)),
                       ("m.empty", "F32", (0, 4)), ("m.pair", "U16", (2, 3))]
         if i % 5 == 0:
             table.append((f"h.{i}", "I8", (5,)))
-    table.append(("z.last", "I32", (4,)))
+    # The kernel reads whole steps of 256 rows: up to 255 rows' words and
+    # 9 buffer rows behind the last member's end.
+    table += [("z.last", "I32", (4,)),
+              ("zz.tail", "U8", (5 * 1024 + 7 + 510 * shape[-1],))]
     header, blobs, want, at = {}, [], {}, 0
     for name, dtype, shape in table:
         kind = np.dtype(_NUMPY[dtype])
@@ -416,8 +432,97 @@ def test_grouped_views_equal_frombuffer_at_every_alignment(members, pad):
     now = _views_counted()
     _assert_equal_to_frombuffer(loaded, want)
     assert len({id(t) for t in loaded.values()}) == len(want)
-    assert now[1] - was[1] == len(want)
+    assert (now[1] - was[1], now[2] - was[2]) == (len(want), 0)
     assert now[0] - was[0] == _expected_dispatches(content)
+
+
+def _written_once_cases():
+    """Every group size at every alignment over rows of half a word group,
+    and a group of cap + 1 over rows of 1.5 with a last step of two."""
+    from dragonfly2_tpu.ops import bitview
+
+    return ([(members, ROWS_SHAPE, pad) for members in _group_sizes()
+             for pad in (0, 1, 2, 3)]
+            + [(bitview._GROUP_CAP + 1, PAIRED_SHAPE, pad) for pad in (1, 2)])
+
+
+@pytest.mark.parametrize("members,shape,pad", _written_once_cases(), ids=str)
+def test_grouped_views_written_once_equal_frombuffer(kernel_on_cpu, members,
+                                                     shape, pad):
+    """The same sweep over a group the rows kernel cuts: every member of
+    the group by the kernel, everything else in the object flat, and all
+    of it bit for bit what ``np.frombuffer`` reads."""
+    content, want = grouped_object(members, pad, shape=shape)
+    sink = _land(content, piece=4096)
+    was = _views_counted()
+    loaded = st.load_from_sink(sink)
+    now = _views_counted()
+    _assert_equal_to_frombuffer(loaded, want)
+    assert len({id(t) for t in loaded.values()}) == len(want)
+    assert (now[1] - was[1], now[2] - was[2]) == (len(want), members)
+    assert now[0] - was[0] == _expected_dispatches(content)
+
+
+@pytest.mark.parametrize("pad", [0, 2, 3])
+@pytest.mark.parametrize("shape", FLAT_SHAPES, ids=str)
+def test_grouped_views_of_shapes_the_kernel_leaves_stay_flat(kernel_on_cpu,
+                                                             shape, pad):
+    """Where the kernel may run, a shape it does not take is cut by the
+    flat form, and says so."""
+    from dragonfly2_tpu.ops import bitview
+
+    content, want = grouped_object(bitview._GROUP_CAP + 1, pad, shape=shape)
+    sink = _land(content, piece=4096)
+    was = _views_counted()
+    loaded = st.load_from_sink(sink)
+    now = _views_counted()
+    _assert_equal_to_frombuffer(loaded, want)
+    assert (now[1] - was[1], now[2] - was[2]) == (len(want), 0)
+    assert now[0] - was[0] == _expected_dispatches(content)
+
+
+@pytest.mark.parametrize("dtype,width,pad", [
+    ("I16", 256, 1), ("U16", 512, 2), ("F16", 256, 0), ("BF16", 1024, 2),
+    ("BF16", 128, 3), ("U16", 2048, 0), ("BF16", 1408, 2), ("I16", 384, 3)])
+def test_the_rows_kernel_at_every_dtype_it_takes(kernel_on_cpu, dtype, width,
+                                                 pad):
+    """Every 2-byte dtype of the header, rows of half a word group to eight, 5.5 among them,
+    a last step that is not whole (300 rows), and the tensor that ends at
+    the content's end, which the kernel must leave to the flat form: its
+    window would reach past the buffer. 16-bit floats are finite normal
+    values (the module's documented exception is the chip's)."""
+    import jax.numpy as jnp
+    import ml_dtypes
+
+    from dragonfly2_tpu.ops import bitview
+
+    rng = np.random.default_rng(width + pad)
+    kind = {"I16": np.int16, "U16": np.uint16, "F16": np.float16,
+            "BF16": ml_dtypes.bfloat16}[dtype]
+    raw = rng.integers(0, 1 << 16, (3, 300, width), dtype=np.uint16)
+    if dtype in ("F16", "BF16"):
+        lo, bits = (10, 5) if dtype == "F16" else (7, 8)
+        exponent = (raw >> lo) & ((1 << bits) - 1)
+        raw[exponent == 0] |= 1 << lo
+        raw[exponent == (1 << bits) - 1] &= np.uint16(~(1 << lo) & 0xFFFF)
+    data = raw.tobytes()
+    size = len(data) // 3
+    content = b"\x5a" * (4096 + pad) + data
+    content += b"\x00" * (-len(content) % 4096)        # whole tiles
+    offsets = [4096 + pad + i * size for i in range(3)]
+    words = jnp.asarray(np.frombuffer(content, "<u4"))
+    was = _views_counted()
+    views = bitview.typed_views(words, offsets, st._DTYPES[dtype], (300, width))
+    now = _views_counted()
+    # The last of the three may end too near the buffer's end: two steps
+    # of 256 rows and 9 buffer rows from its first.
+    at_end = offsets[2] // 512 + 2 * width + 9 > len(content) // 512
+    assert (now[1] - was[1], now[2] - was[2]) == (3, 3 - at_end)
+    assert now[0] - was[0] == 1 + at_end
+    for i, view in enumerate(views):
+        got = np.asarray(view)
+        assert got.dtype == kind and got.shape == (300, width)
+        assert got.tobytes() == data[i * size:(i + 1) * size], i
 
 
 @pytest.mark.parametrize("members", _group_sizes())
