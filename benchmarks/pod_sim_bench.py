@@ -97,10 +97,8 @@ def _open_body(i: int) -> dict:
 async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
                   arrival_window_s: "float | None" = None,
                   churn: bool = False, churn_waves: int = 1,
-                  gc_ttl_s: float = 1.0, fleet: bool = True,
-                  report_batch: int = 1, podlens: bool = False,
-                  ship_digests: "bool | None" = None,
-                  restart: bool = False, prof: bool = False,
+                  gc_ttl_s: float = 1.0, report_batch: int = 1,
+                  restart: bool = False,
                   packed_wire: bool = False) -> dict:
     """``churn=True`` kills whole slices mid-fan-out (their peers' streams
     drop after a few pieces, no finish) and sends straggler waves into the
@@ -135,16 +133,10 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
     # well above any single peer's in-run idle gap.
     cfg.gc.peer_ttl = cfg.gc.task_ttl = cfg.gc.host_ttl = max(
         gc_ttl_s, arrival_window_s + 60 * piece_latency_s)
-    # ``fleet=False`` runs without the fleet observatory's per-event hooks;
-    # ``podlens`` toggles the SCHEDULER-side pod-lens/SLO machinery.
-    # ``ship_digests`` makes every peer record a real flight ring, digest
-    # it and attach it to download_finished (plus a clock sample).
-    # Defaults to ``podlens`` so a lone podlens=True run exercises the
-    # whole path.
-    cfg.fleet.enabled = fleet
-    cfg.podlens.enabled = cfg.podlens.slo_enabled = podlens
-    if ship_digests is None:
-        ship_digests = podlens
+    # The fleet observatory's per-event hooks run (the default); the
+    # scheduler-side pod-lens/SLO machinery does not, and no peer ships a
+    # flight digest.
+    cfg.podlens.enabled = cfg.podlens.slo_enabled = False
     svc = SchedulerService(cfg)
     # Peers resolve the CURRENT scheduler through this box: the restart
     # swaps in the restored replacement service and bumps ``gen`` so
@@ -156,9 +148,6 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
         "resume_answers": {}, "rebuilt_piece_mismatch": 0,
         "restored_peers": 0, "restored_tasks": 0,
     }
-    digest_bytes: list[int] = []
-    if ship_digests:
-        from dragonfly2_tpu.pkg import flight as flight_mod
 
     n_slices = max(1, n_hosts // HOSTS_PER_SLICE)
     waves_n = min(churn_waves, max(1, n_slices - 2)) if churn else 0
@@ -352,14 +341,6 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
                 "content_length": N_PIECES * PIECE_SIZE,
                 "piece_size": PIECE_SIZE,
                 "total_piece_count": N_PIECES})
-            tf = None
-            if ship_digests:
-                # The daemon-side half of the pod lens, for real: a
-                # bounded flight ring stamped per piece, digested and
-                # shipped on the terminal message.
-                tf = flight_mod.TaskFlight(body["task_id"])
-                tf.record(flight_mod.EV_REGISTER)
-                tf.record(flight_mod.EV_SCHEDULED, -1, 0.0, "normal_task")
             pending: list = []
             for n in range(N_PIECES):
                 if restart and svc_box["gen"] != my_gen:
@@ -412,9 +393,6 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
                     killed_here = True
                     return
                 await asyncio.sleep(piece_latency_s * rng.uniform(0.5, 1.5))
-                if tf is not None:
-                    tf.record(flight_mod.EV_REQUEST, n, 0.0, "10.0.0.1:1")
-                    tf.record(flight_mod.EV_LANDED, n, 2.0, "cross")
                 wire_piece = {"piece_num": n,
                               "range_start": n * PIECE_SIZE,
                               "range_size": PIECE_SIZE,
@@ -438,12 +416,6 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
                 "content_length": N_PIECES * PIECE_SIZE,
                 "piece_size": PIECE_SIZE,
                 "total_piece_count": N_PIECES}
-            if tf is not None:
-                tf.finish("done")
-                now = flight_mod.anchored_wall()
-                finish_msg["flight"] = flight_mod.digest(
-                    tf, clock_samples=[(now - 0.002, now, now - 0.001)])
-                digest_bytes.append(finish_msg["flight"]["bytes"])
             await put(stream, finish_msg)
             finished.add(i)
         finally:
@@ -464,16 +436,6 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
 
     gc.collect()
     gc.freeze()
-    # ``prof=True`` arms the full runtime observatory (sampler thread +
-    # loop-lag probe + GC callbacks) for the storm, so its CPU cost lands
-    # inside the cpu_s window below.
-    prof_obs = prof_probe = None
-    prof_stats = None
-    if prof:
-        from dragonfly2_tpu.pkg import prof as proflib
-
-        prof_obs = proflib.install()
-        prof_probe = prof_obs.arm_loop("sim")
     hb = asyncio.ensure_future(heartbeat())
     t0 = time.perf_counter()
     cpu0 = time.process_time()
@@ -543,16 +505,6 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
     finally:
         hb.cancel()
         gc.unfreeze()
-        if prof_obs is not None:
-            from dragonfly2_tpu.pkg import prof as proflib
-
-            smp = prof_obs.sampler
-            prof_stats = {"samples": smp.samples, "nodes": smp.nodes,
-                          "truncated": smp.truncated,
-                          "loop_slow_ticks": prof_probe.slow_ticks}
-            prof_probe.disarm()
-            prof_obs.probes.pop(prof_probe.name, None)
-            proflib.release(prof_obs)
         if snapshot_path:
             try:
                 os.unlink(snapshot_path)
@@ -622,16 +574,6 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
             "registers": win["totals"]["registers"],
             "scorecard_hosts": len(svc.fleet.scorecards._hosts),
         }
-    podlens_stats = None
-    if ship_digests or podlens:
-        podlens_stats = {
-            "digests": len(digest_bytes),
-            "digest_max_bytes": max(digest_bytes) if digest_bytes else 0,
-            "resident_bytes":
-                svc.pod_lens.resident_bytes() if svc.pod_lens else 0,
-            "slo_completions":
-                svc.slo.completions_total if svc.slo else 0,
-        }
     return {
         "config": "pod-fanout-sim" + ("-churn" if churn else ""),
         "hosts": n_hosts,
@@ -685,12 +627,7 @@ async def run_sim(n_hosts: int, piece_latency_s: float = 0.002,
         "registry_peak": registry_sizes,
         **after_gc,
         "host_cores": os.cpu_count(),
-        "fleet_enabled": fleet,
         "fleet": fleet_stats,
-        "podlens_enabled": podlens,
-        "podlens": podlens_stats,
-        "prof_enabled": prof,
-        "prof": prof_stats,
         "restart_enabled": restart,
         "restart": {
             "rebuild_s": round(max(0.0, restart_info["rebuild_done_at"]
@@ -723,16 +660,6 @@ def latency_budget_ms(result: dict, idle_budget_ms: float) -> float:
     more than 20x p50 is a scheduler tail problem regardless of load)."""
     return max(idle_budget_ms * slowdown_factor(result),
                20.0 * result.get("schedule_p50_ms", 0.0))
-
-
-def timing_assertable(result: dict, max_slowdown: float = 3.0) -> bool:
-    """Were timing bounds meaningful for this run? Under suite-level CPU
-    contention (ambient heartbeat lag pushing the slowdown factor past
-    ~3x) even budgeted bounds measure the NEIGHBORS, not the scheduler —
-    the round-5 verdict's load-flake: the test wrapper records instead of
-    asserting there, while behavioral invariants always assert and the
-    dedicated bench (which runs alone) always asserts both."""
-    return slowdown_factor(result) <= max_slowdown
 
 
 def check_behavior(result: dict) -> None:

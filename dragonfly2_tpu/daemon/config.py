@@ -154,7 +154,6 @@ class TPUSinkOption:
     ``device="tpu"`` (dfget --device tpu)."""
 
     enabled: bool = False
-    mesh_shape: list[int] = field(default_factory=list)  # for shard_to_mesh
     batch_pieces: int = 8       # pieces staged per device dispatch
     max_tasks: int = 4          # concurrent HBM-resident tasks
 

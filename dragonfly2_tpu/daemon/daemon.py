@@ -91,7 +91,6 @@ class Daemon:
 
             log.info("jax compile cache", dir=place_compile_cache())
             device_sinks = DeviceSinkManager(
-                mesh_shape=config.tpu_sink.mesh_shape,
                 batch_pieces=config.tpu_sink.batch_pieces,
                 max_tasks=config.tpu_sink.max_tasks)
         self.task_manager = TaskManager(

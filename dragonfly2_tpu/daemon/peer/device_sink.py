@@ -268,17 +268,6 @@ class TaskDeviceSink:
                 received * 4 * self.sink.padded_words)
             SINK_CHIP_VERIFY_COUNT.labels("ok").inc()
 
-    def ici_broadcast(self, mesh, axis_name: str = "d", n_chunks: int = 4):
-        """Striped-broadcast consumption: replicate the landed content to
-        every device of the mesh via the chunked ring all-gather (ICI
-        completes the copy; the NIC is done once the stripe landed).
-        Requires a verified sink — a striped task must never expose
-        unverified bytes, on device exactly as over upload."""
-        if not self.verified:
-            raise DeviceSinkError(
-                f"ici_broadcast on unverified sink {self.task_id[:16]}")
-        return self.sink.ring_replicate(mesh, axis_name, n_chunks=n_chunks)
-
 
 class DeviceSinkManager:
     """Owns the per-task sinks a daemon is landing. Selected per request
@@ -297,8 +286,7 @@ class DeviceSinkManager:
             self._admission = asyncio.Semaphore(max(1, self.max_tasks - 1))
         return self._admission
 
-    def __init__(self, *, mesh_shape: list[int] | None = None,
-                 batch_pieces: int = 8, max_tasks: int = 4,
+    def __init__(self, *, batch_pieces: int = 8, max_tasks: int = 4,
                  ttl: float = 600.0, device=None):
         # jax comes in with the first sink manager, not with this module.
         from dragonfly2_tpu.ops import hbm_sink
@@ -312,7 +300,6 @@ class DeviceSinkManager:
         # Refcounted: concurrent claimers of one deduped task each hold
         # a reference; the first to finish must not strip the others'.
         self._protected: dict[str, int] = {}
-        self.mesh_shape = list(mesh_shape or [])
         self.batch_pieces = batch_pieces
         self.max_tasks = max_tasks
         self.ttl = ttl
@@ -773,20 +760,3 @@ class DeviceSinkManager:
                         if now - s.created_at > self.ttl]:
                 log.info("device sink expired", task=tid[:16])
                 self._drop(tid)
-
-    def default_mesh(self):
-        """Mesh over LOCAL devices per TPUSinkOption.mesh_shape (or all
-        local devices on one axis when unset) — the sink's shard_to_mesh
-        spreads over this host's chips; under jax.distributed the global
-        list would include non-addressable devices."""
-        import numpy as np
-        import jax
-        from jax.sharding import Mesh
-
-        devices = jax.local_devices()
-        if self.mesh_shape:
-            n = int(np.prod(self.mesh_shape))
-            names = tuple(f"d{i}" for i in range(len(self.mesh_shape)))
-            return Mesh(np.asarray(devices[:n]).reshape(self.mesh_shape),
-                        names)
-        return Mesh(np.asarray(devices), ("d",))

@@ -849,18 +849,6 @@ class HBMSink:
         return jax.make_array_from_single_device_arrays(
             (per * n,), sharding, shards)
 
-    def ring_replicate(self, mesh, axis_name: str = "d", n_chunks: int = 4):
-        """The ICI leg of the striped broadcast: spread the landed content
-        over the mesh (one shard per device) and complete the copy with
-        the chunked ppermute ring, so every device ends with the full
-        word buffer without any further NIC traffic. Returns the
-        replicated uint32 array (padded words; callers trim/bitcast)."""
-        from dragonfly2_tpu.parallel.ici import chunked_ring_all_gather
-
-        return chunked_ring_all_gather(
-            mesh, self.shard_to_mesh(mesh, axis_name),
-            axis_name=axis_name, n_chunks=n_chunks)
-
     def replicate(self, mesh, axis_name: str = "d") -> int:
         """Place the verified content whole on every device of ``mesh``
         and verify every copy where it lies: from here on ``as_words()``

@@ -450,20 +450,6 @@ def test_all_gather_on_four_chips(topo):
     assert arg == words and out == 4 * words     # a quarter in, all out
 
 
-def test_chunked_ring_on_four_chips(topo):
-    """Per chip: its quarter in, the whole content out, and temporaries
-    that must still fit beside the landed source on device 0."""
-    from dragonfly2_tpu.parallel.ici import _chunked_ring_all_gather_jit
-
-    words = 1728 * MiB // 4
-    mesh, spec = _mesh_words(topo, words)
-    arg, out, temp = _memory(
-        functools.partial(_chunked_ring_all_gather_jit, mesh=mesh,
-                          axis_name="d", n_chunks=4), spec)
-    assert out == 4 * words
-    assert arg + out + temp + 4 * words <= 15 * 1024 * MiB
-
-
 def test_per_chip_checksums_of_a_replicated_content_need_no_temporary(topo):
     """The verification after the fan-out: every chip reads the copy it
     holds, a piece at a time; per chip the content in, 8 bytes a piece out

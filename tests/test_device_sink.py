@@ -13,8 +13,11 @@ HBM as a second, per-task-selectable terminal.
 
 from __future__ import annotations
 
+import asyncio
 import hashlib
+import os
 import random
+import sys
 
 import numpy as np
 import pytest
@@ -31,12 +34,10 @@ CONTENT = e2e.CONTENT          # 10 MiB, 3 pieces at 4 MiB
 SHA = e2e.SHA
 
 
-async def _start_sink_daemon(tmp_path, name, scheduler_port, *, seed=False,
-                             mesh_shape=None) -> Daemon:
+async def _start_sink_daemon(tmp_path, name, scheduler_port, *,
+                             seed=False) -> Daemon:
     cfg = daemon_config(tmp_path, name, scheduler_port, seed=seed)
     cfg.tpu_sink.enabled = True
-    if mesh_shape:
-        cfg.tpu_sink.mesh_shape = mesh_shape
     d = Daemon(cfg)
     await d.start()
     return d
@@ -91,6 +92,7 @@ def test_device_result_as_tensor_and_mesh(run_async, tmp_path):
 
     async def body():
         import jax
+        from jax.sharding import Mesh
 
         origin, oport, _ = await start_origin()
         sched = await start_scheduler()
@@ -113,7 +115,7 @@ def test_device_result_as_tensor_and_mesh(run_async, tmp_path):
                 got.view(np.uint32), want.view(np.uint32))
 
             # Mesh sharding: every device holds a contiguous uint32 shard.
-            mesh = peer.task_manager.device_sinks.default_mesh()
+            mesh = Mesh(np.asarray(jax.devices()), ("d",))
             sharded = result.shard_to_mesh(mesh)
             assert len(sharded.devices()) == len(jax.devices())
             whole = np.asarray(sharded)
@@ -198,9 +200,14 @@ def test_corrupt_device_copy_fails_verification(run_async, tmp_path):
         sink.verify()
 
 
-def test_sink_unavailable_degrades_to_disk(run_async, tmp_path):
-    """Sink cap reached: the request still completes (disk verified) with
-    device_verified=False rather than failing."""
+@pytest.mark.parametrize("slots", [0, 1],
+                         ids=["no_slot", "the_one_slot_protected"])
+def test_sink_unavailable_degrades_to_disk(run_async, tmp_path, slots):
+    """Sink cap reached (no slot at all; or one, held by a landing that a
+    consumer has announced it will claim, so nothing may be evicted): the
+    request still completes (disk verified) with device_verified=False
+    rather than failing, and the command that asked for the device exits
+    nonzero and says why."""
 
     async def body():
         origin, oport, _ = await start_origin()
@@ -210,10 +217,16 @@ def test_sink_unavailable_degrades_to_disk(run_async, tmp_path):
         try:
             cfg = daemon_config(tmp_path, "capped", sched.port())
             cfg.tpu_sink.enabled = True
-            cfg.tpu_sink.max_tasks = 0          # nothing fits
+            cfg.tpu_sink.max_tasks = slots
             peer = Daemon(cfg)
             await peer.start()
             daemons.append(peer)
+            sinks = peer.task_manager.device_sinks
+            if slots:
+                held = await device_lib.download_to_device(
+                    peer, url + "?held", claim=False)
+                assert list(sinks._sinks) == [held.task_id]
+                sinks.protect(held.task_id)
 
             r = await dfget_lib.download(dfget_lib.DfgetConfig(
                 url=url, output=str(tmp_path / "o"),
@@ -233,13 +246,35 @@ def test_sink_unavailable_degrades_to_disk(run_async, tmp_path):
 
             with pytest.raises(DfError, match="sink cap reached"):
                 await download_to_device(peer, url, digest=SHA)
+
+            # The CLI as a user runs it, against this daemon's socket: a
+            # device request whose result is disk-only is a failed command.
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            env = dict(os.environ, PYTHONPATH=repo)
+            env.pop("XLA_FLAGS", None)
+            env.pop("JAX_PLATFORMS", None)
+            proc = await asyncio.create_subprocess_exec(
+                sys.executable, "-m", "dragonfly2_tpu.cli.main", "dfget",
+                url + "?cli", "-O", str(tmp_path / "o2"), "--device", "tpu",
+                "--no-daemon", "--work-home", cfg.work_home, env=env,
+                stdout=asyncio.subprocess.PIPE,
+                stderr=asyncio.subprocess.STDOUT)
+            out = (await asyncio.wait_for(proc.communicate(), 90))[0].decode()
+            assert proc.returncode == 1, out[-1500:]
+            assert "sink cap reached" in out, out[-1500:]
+            assert out.count("device_verified=False") == 1, out[-1500:]
+            assert (tmp_path / "o2").read_bytes() == CONTENT
+            if slots:
+                # Nothing was evicted for any of the three.
+                assert list(sinks._sinks) == [held.task_id]
+                sinks.unprotect(held.task_id)
         finally:
             for d in daemons:
                 await d.stop()
             await sched.stop()
             await origin.cleanup()
 
-    run_async(body(), timeout=120)
+    run_async(body(), timeout=180)
 
 
 def test_device_corruption_fails_request_but_not_store(run_async, tmp_path):

@@ -120,6 +120,13 @@ class TestManifests:
         assert cfg.download.peer_port == 65000
         assert cfg.upload.port == 65002
         assert cfg.tpu_sink.enabled is True
+        # A key this release no longer has (a field an older one read) is
+        # passed over like any unknown key: the file still loads.
+        doc = yaml.safe_load(cm["data"]["daemon.yaml"])
+        doc["tpu_sink"]["a_field_of_an_older_release"] = [2, 2]
+        old = DaemonConfig.from_dict(doc)
+        assert old.tpu_sink == cfg.tpu_sink
+        assert not hasattr(old.tpu_sink, "a_field_of_an_older_release")
         args = _container(_named(self.docs, "DaemonSet", "daemon"))["args"]
         assert args[args.index("--config") + 1] == "/etc/dragonfly/daemon.yaml"
 

@@ -280,7 +280,7 @@ def test_a_copy_altered_on_one_chip_fails_the_operation_and_names_the_chip(
             after["ok"] - before["ok"]) == (1, 0)
 
 
-# -- (d) the collective on the path and the others give the same bytes -------
+# -- (d) the collective on the path and the plain reference give the same bytes
 
 def _landed(content: bytes, piece: int):
     from dragonfly2_tpu.ops.hbm_sink import HBMSink
@@ -292,11 +292,10 @@ def _landed(content: bytes, piece: int):
     return sink
 
 
-@pytest.mark.parametrize("way", ["on_the_path", "device_put",
-                                 "chunked_ring_1", "chunked_ring_4",
-                                 "ring"])
+@pytest.mark.parametrize("way", ["on_the_path", "device_put"])
 def test_every_way_to_every_chip_gives_the_same_bytes(way):
-    from dragonfly2_tpu.parallel import ici
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     piece = 64 * 1024
     content = np.random.default_rng(9).integers(
@@ -307,13 +306,9 @@ def test_every_way_to_every_chip_gives_the_same_bytes(way):
     if way == "on_the_path":
         assert sink.replicate(mesh) == 3
         out = sink.as_words()
-    elif way == "device_put":
-        out = ici.replicate_to_mesh(mesh, sink.as_words())
-    elif way == "ring":
-        # A sharded stack: each device's block is the whole gather.
-        out = ici.ring_all_gather(mesh, sink.shard_to_mesh(mesh))
     else:
-        out = sink.ring_replicate(mesh, n_chunks=int(way[-1]))
+        # The plain reference: the runtime's own placement on every chip.
+        out = jax.device_put(sink.as_words(), NamedSharding(mesh, P()))
     copies = held_by(out)
     assert set(copies) == set(mesh.devices.flat)
     for device, words in zip(mesh.devices.flat, want):
@@ -448,3 +443,19 @@ def test_replicate_needs_a_verified_landing_and_devices_on_one_axis():
     assert sink.as_words().devices() == {sink.device}
     assert sink.replicate(mesh_of(4)) == 3
     assert sink.replicate(mesh_of(4)) == 0      # placed and verified already
+
+    # The daemon's sink refuses before the device is asked: no fan-out of a
+    # landing that nothing has verified.
+    from dragonfly2_tpu.daemon.peer.device_sink import (
+        DeviceSinkError,
+        TaskDeviceSink,
+    )
+
+    task = TaskDeviceSink("t-unverified", len(content), 4096)
+    for n in range(task.sink.total_pieces):
+        task.land(n, content[n * 4096:(n + 1) * 4096])
+    with pytest.raises(DeviceSinkError, match="unverified sink"):
+        task.replicate(mesh_of(4))
+    task.verify()
+    task.replicate(mesh_of(4))
+    assert len(task.as_words().devices()) == 4

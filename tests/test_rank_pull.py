@@ -16,9 +16,7 @@ rehearsal of the cell, as a whole run of ``chipbench/run.py``.
 from __future__ import annotations
 
 import importlib.util
-import json
 import os
-import subprocess
 import sys
 import time
 import types
@@ -380,38 +378,3 @@ def test_the_three_events_have_names_and_a_place_in_the_docs():
     for name in (*codes.values(), "device_sharded_tasks_total",
                  "device_sharded_bytes_total"):
         assert f"`{name}`" in docs, name
-
-
-# -- the cell's rehearsal, as whole runs of the benchmark ------------------
-
-def rehearse(script: str, *extra: str) -> dict:
-    # A whole run is four processes and some forty compiles. At the lowest
-    # priority, on one device and one compute thread, so that the tests of the other workers
-    # that assert on latency under load keep the cores (three runs of the
-    # whole suite with these runs at full priority failed one of them each,
-    # three without them none).
-    proc = subprocess.run(
-        ["nice", "-n", "19", sys.executable, os.path.join(BENCH, script),
-         *extra,
-         "--manifest", os.path.join(BENCH, "rehearsal",
-                                    "manifest-ranged.json"),
-         "--workload", "tiny-rank-cold", "--seed", "2147484034",
-         "--seconds", "1", "--trace", "0"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu",
-                 OMP_NUM_THREADS="1", XLA_FLAGS=(
-                     "--xla_force_host_platform_device_count=1 "
-                     "--xla_cpu_multi_thread_eigen=false "
-                     "intra_op_parallelism_threads=1")),
-        capture_output=True, text=True, timeout=600, cwd=REPO)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
-
-
-def test_the_cells_rehearsal():
-    """The controls (``control_ranged.py --break flip|stray``, ``correct``
-    false) run under chipbench/tests only: a whole run is four processes,
-    and this file shares its host with five other workers."""
-    line = rehearse("run.py")
-    assert line["failed"] == 0 and line["attempted"] >= 2
-    assert line["correct"] is True, line
-    assert line["metrics"] == {} and line["device"]["platform"] == "cpu"

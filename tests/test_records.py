@@ -81,3 +81,55 @@ def test_a_stated_rate_names_its_origin(path):
                 f"{path} states a rate without its origin (\"ledger, PR n\", "
                 f"\"my chip runs, PR n\" or a pointer at PERF.md):\n"
                 f"{block[:400]}")
+
+
+# What a document names in backticks and that looks like a file of this
+# repository: a path or a bare name that ends in one of these, with an
+# optional ``:line`` or ``:line-line`` behind it. A placeholder (``<name>``,
+# ``*``) does not match.
+NAMED_FILE = re.compile(
+    r"`((?:[\w.-]+/)*[\w.-]+\.(?:py|json|md|sh))(?::\d+(?:-\d+)?)?`")
+TOP_LEVEL = ("dragonfly2_tpu", "tests", "benchmarks", "chipbench", "docs",
+             "examples", "deploy", ".claude")
+# History, not a pointer to follow: a line that says where something was
+# "copied from" (the benchmark's own README says so of its fabric, PR 22, and
+# only a ``benchmark`` PR may reword it: ROADMAP D16).
+HISTORY = "copied from"
+
+
+@functools.cache
+def _file_names() -> frozenset:
+    names = set()
+    for top in TOP_LEVEL:
+        for _, _, files in os.walk(os.path.join(REPO, top)):
+            names.update(files)
+    names.update(f for f in os.listdir(REPO)
+                 if os.path.isfile(os.path.join(REPO, f)))
+    return frozenset(names)
+
+
+@pytest.mark.parametrize("path", ["README.md", "docs/ARCHITECTURE.md",
+                                  "docs/ZERO_COPY.md",
+                                  "docs/OBSERVABILITY.md",
+                                  "chipbench/README.md",
+                                  ".claude/skills/verify/SKILL.md"])
+def test_a_named_file_exists(path):
+    """A path is read from the root, from the package (``ops/hbm_sink.py``)
+    or from the document's own directory; a bare name is any file of the
+    tree. A path whose first directory is none of ours is not ours to
+    check."""
+    roots = (REPO, os.path.join(REPO, "dragonfly2_tpu"),
+             os.path.join(REPO, os.path.dirname(path)))
+    stale = set()
+    pointers = "\n".join(line for line in _text(path).splitlines()
+                         if HISTORY not in line)
+    for named in NAMED_FILE.findall(pointers):
+        first, slash, _ = named.partition("/")
+        if not slash:
+            if named not in _file_names():
+                stale.add(named)
+        elif any(os.path.isdir(os.path.join(root, first)) for root in roots):
+            if not any(os.path.isfile(os.path.join(root, named))
+                       for root in roots):
+                stale.add(named)
+    assert not stale, f"{path} names files that do not exist: {sorted(stale)}"

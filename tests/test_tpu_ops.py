@@ -8,16 +8,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from dragonfly2_tpu.ops.checksum import _chunk_checksums_xla, checksum_numpy  # noqa: E402
 from dragonfly2_tpu.ops.hbm_sink import HBMSink  # noqa: E402
-from dragonfly2_tpu.parallel.ici import (  # noqa: E402
-    StripedBroadcast,
-    all_gather_shards,
-    bitcast_landed_bytes,
-    chunked_ring_all_gather,
-    make_mesh,
-    replicate_to_mesh,
-    ring_all_gather,
-    scatter_shards,
-)
+from dragonfly2_tpu.parallel.ici import all_gather_shards, make_mesh  # noqa: E402
 from dragonfly2_tpu.parallel.topology import TpuTopology, detect_topology  # noqa: E402
 
 
@@ -125,74 +116,21 @@ class TestHBMSink:
         np.testing.assert_array_equal(
             np.asarray(sharded), np.frombuffer(content, "<u4"))
 
-    def test_ring_replicate(self):
-        # The striped broadcast's ICI leg: shard the landed content over
-        # the mesh, complete the copy with the chunked ppermute ring.
-        mesh = make_mesh(8)
-        content = np.random.RandomState(4).bytes(8 * 1024 + 100)  # tail pad
-        sink = HBMSink(len(content), piece_size=1024)
-        for n in range((len(content) + 1023) // 1024):
-            sink.land_piece(n, content[n * 1024:(n + 1) * 1024])
-        out = sink.ring_replicate(mesh, n_chunks=3)
-        assert out.sharding.is_fully_replicated
-        got = np.asarray(out).view("<u1")[:len(content)].tobytes()
-        assert got == content
-
 
 class TestICI:
     def test_scatter_then_all_gather(self):
+        # Fed as the path feeds it (HBMSink.replicate): the landing's own
+        # shard_to_mesh, one shard a device.
         mesh = make_mesh(8)
         data = np.arange(8 * 16, dtype=np.uint32)
-        sharded = scatter_shards(mesh, data)
+        sink = HBMSink(data.nbytes, piece_size=64)
+        for n in range(8):
+            sink.land_piece(n, data[n * 16:(n + 1) * 16].tobytes())
+        sharded = sink.shard_to_mesh(mesh)
         assert len(sharded.sharding.device_set) == 8
         full = all_gather_shards(mesh, sharded)
+        assert full.sharding.is_fully_replicated
         np.testing.assert_array_equal(np.asarray(full), data)
-
-    def test_replicate(self):
-        mesh = make_mesh(8)
-        data = np.arange(32, dtype=np.float32)
-        rep = replicate_to_mesh(mesh, data)
-        assert rep.sharding.is_fully_replicated
-
-    def test_ring_all_gather_matches(self):
-        mesh = make_mesh(8)
-        data = np.arange(8 * 8, dtype=np.uint32)
-        sharded = scatter_shards(mesh, data)
-        ringed = ring_all_gather(mesh, sharded)
-        # Every device's logical row is the full gather.
-        out = np.asarray(ringed)
-        np.testing.assert_array_equal(out.reshape(8, -1)[0], data)
-
-    def test_bitcast_landed_bytes(self):
-        vals = np.arange(16, dtype=np.float32)
-        words = jnp.asarray(np.frombuffer(vals.tobytes(), "<u1"))
-        t = bitcast_landed_bytes(words, "float32", (4, 4))
-        np.testing.assert_array_equal(np.asarray(t).reshape(-1), vals)
-
-    def test_chunked_ring_all_gather_matches_all_gather(self):
-        mesh = make_mesh(8)
-        data = np.arange(8 * 24 * 3, dtype=np.uint32).reshape(8 * 24, 3)
-        sharded = scatter_shards(mesh, data)
-        for n_chunks in (1, 3, 4, 24, 100):
-            out = chunked_ring_all_gather(mesh, sharded, n_chunks=n_chunks)
-            assert out.sharding.is_fully_replicated
-            np.testing.assert_array_equal(np.asarray(out), data)
-
-    def test_striped_broadcast_pipelines_chunks(self):
-        # The DCN/ICI overlap driver: chunks fed in landing order come
-        # back as the full content, replicated, regardless of chunk size
-        # vs mesh-size alignment.
-        mesh = make_mesh(8)
-        content = np.arange(101, dtype=np.uint32)
-        sb = StripedBroadcast(mesh, n_chunks=2)
-        for lo in range(0, 101, 17):
-            sb.feed(content[lo:lo + 17])
-        out = sb.result()
-        np.testing.assert_array_equal(np.asarray(out), content)
-
-    def test_striped_broadcast_empty_raises(self):
-        with pytest.raises(ValueError):
-            StripedBroadcast(make_mesh(8)).result()
 
 
 class TestTopology:
@@ -826,3 +764,29 @@ class TestMultihostAssembly:
         out = jax.jit(lambda x: x + 1,
                       out_shardings=NamedSharding(mesh, P()))(arr)
         np.testing.assert_array_equal(np.asarray(out), local + 1)
+
+
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(monkeypatch):
+    import os
+
+    from dragonfly2_tpu.ops import compile_cache
+
+    before = (jax.config.jax_compilation_cache_dir,
+              jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert compile_cache.place_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == before[0]
+        # Sub-second view programs are kept wherever the cache is.
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        placed = compile_cache.place_compile_cache()
+        assert placed == os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache")
+        assert placed == compile_cache.place_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == placed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          before[1])
