@@ -1179,6 +1179,7 @@ class TaskManager:
                  task=store.metadata.task_id[:16],
                  parent=parent.metadata.task_id[:16],
                  start=rng.start, length=rng.length)
+        moved_s, pieces = 0.0, 0
         try:
             with parent:  # pin: GC must not reclaim the parent mid-import
                 # ONE pooled buffer reused for every piece of the import:
@@ -1191,14 +1192,20 @@ class TaskManager:
                             continue   # resume semantics match back-source
                         off = n * piece_size
                         size = min(piece_size, rng.length - off)
+                        t0 = time.perf_counter()
                         await asyncio.to_thread(
                             parent.read_into, rng.start + off, size, buf)
                         rec = await asyncio.to_thread(
                             store.write_piece, n, buf[:size])
+                        moved_s += time.perf_counter() - t0
+                        pieces += 1
                         if on_piece is not None:
                             await on_piece(store, rec)
                 finally:
                     release_read_buffer(buf)
+                    self.flight.task(store.metadata.task_id).record(
+                        flightlib.EV_RANGE_IMPORT, pieces, moved_s * 1000.0,
+                        str(rng.length))
         except (StorageError, OSError) as e:
             log.warning("local range import failed; falling back to origin",
                         task=store.metadata.task_id[:16], error=str(e)[:200])

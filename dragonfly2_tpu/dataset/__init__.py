@@ -20,6 +20,7 @@ from dragonfly2_tpu.dataset.tar_index import (   # noqa: F401
     TarIndexError,
     TarMember,
     TruncatedShardError,
+    build_index_from_task,
     fetch_or_build_index,
     index_tar_bytes,
 )

@@ -11,20 +11,39 @@ fused assembly dispatch.
 
 On a CPU-only JAX backend (``JAX_PLATFORMS=cpu``) — or with no usable
 jax at all — the feed degrades to plain NumPy batches (``force_hbm=True``
-keeps the sink path for tests and CPU-backend verification).
+keeps the sink path for tests and CPU-backend verification). A device
+path that FAILS degrades the same way, for the rest of the feed's life;
+a feed that was told its device (``device=`` or ``force_hbm=True``) says
+so: ``dataset_device_fallbacks_total{cause}``, one error line, and
+``DeviceFeed.fell_back`` names the cause, so a caller that needs device
+batches can stop (``DeviceBatch.on_device`` is false from then on).
+
+Spans, on the ring handed in as ``flight`` (the loader's,
+``PodShardedLoader.flight``; pkg/flight.py says what each carries):
+``feed_wait`` a batch, the consumer side's wait for its samples;
+``feed_batch`` a batch, first record staged -> as_record_batch
+dispatched; and the batch's HBMSink stamps its own ``sink_*`` steps there
+with ``batch=<k>`` leading the note.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+import time
+from dataclasses import dataclass, field
 
+from dragonfly2_tpu.dataset.shard_reader import DATASET_BYTES
 from dragonfly2_tpu.pkg import dflog, metrics
+from dragonfly2_tpu.pkg import flight as flightlib
 
 log = dflog.get("dataset.device_feed")
 
 DEVICE_BATCHES = metrics.counter(
     "dataset_device_batches_total",
     "Record batches produced by the device feed", ("path",))
+DEVICE_FALLBACKS = metrics.counter(
+    "dataset_device_fallbacks_total",
+    "Device feeds that fell to NumPy batches after the device path failed, "
+    "by the exception that ended it", ("cause",))
 
 
 class DeviceFeedError(Exception):
@@ -39,6 +58,9 @@ class DeviceBatch:
     keys: list[str]
     array: object
     on_device: bool
+    # The shard each record came from (a sample's ``__shard__``): keys
+    # are unique within a shard only.
+    shards: list[str] = field(default_factory=list)
 
 
 def _hbm_available() -> bool:
@@ -62,7 +84,7 @@ class DeviceFeed:
 
     def __init__(self, ext: str, record_bytes: int, batch_size: int, *,
                  pad: bool = False, drop_last: bool = False,
-                 device=None, force_hbm: bool = False):
+                 device=None, force_hbm: bool = False, flight=None):
         if record_bytes <= 0 or batch_size <= 0:
             raise DeviceFeedError("record_bytes and batch_size must be > 0")
         self.ext = ext
@@ -72,6 +94,11 @@ class DeviceFeed:
         self.drop_last = drop_last
         self.device = device
         self.use_hbm = force_hbm or _hbm_available()
+        # The caller named its device: a fall to NumPy is then loud.
+        self.device_asked = force_hbm or device is not None
+        self.fell_back = ""     # the cause, once the device path has failed
+        self.flight = flight
+        self.batch_no = 0       # batches landed so far: the next one's number
 
     def _record(self, sample: dict) -> bytes:
         data = sample.get(self.ext)
@@ -91,50 +118,103 @@ class DeviceFeed:
             data = data + b"\0" * (self.record_bytes - len(data))
         return data
 
-    def _land_hbm(self, keys: list[str], records: list[bytes]) -> DeviceBatch:
+    def _land_hbm(self, records: list[bytes]) -> "tuple[object, str]":
         from dragonfly2_tpu.ops.hbm_sink import HBMSink
 
         padded = self.record_bytes + ((-self.record_bytes) % 4)
-        sink = HBMSink(padded * len(records), padded, device=self.device,
-                       batch_pieces=min(len(records), 64))
+        ring, lead = self.flight, f"batch={self.batch_no}"
+        sink = HBMSink(
+            padded * len(records), padded, device=self.device,
+            batch_pieces=min(len(records), 64),
+            stamp=None if ring is None else (
+                lambda code, piece, ms, note="": ring.record(
+                    code, piece, ms, f"{lead} {note}" if note else lead)))
+        t0 = time.perf_counter()
         for i, rec in enumerate(records):
             sink.land_piece(i, rec)
+        sink.flush()
+        t1 = time.perf_counter()
         sink.verify()   # on-device checksums vs host values
+        t2 = time.perf_counter()
         arr = sink.as_record_batch(len(records), self.record_bytes)
-        DEVICE_BATCHES.labels("hbm").inc()
-        return DeviceBatch(keys=keys, array=arr, on_device=True)
+        t3 = time.perf_counter()
+        DATASET_BYTES.labels("device").inc(padded * len(records))
+        return arr, (f"put={padded * len(records)} "
+                     f"stage={(t1 - t0) * 1e3:.3f} "
+                     f"verify={(t2 - t1) * 1e3:.3f} view={(t3 - t2) * 1e3:.3f}")
 
-    def _land_numpy(self, keys: list[str], records: list[bytes]) -> DeviceBatch:
+    def _land_numpy(self, records: list[bytes]):
         import numpy as np
 
-        arr = np.frombuffer(b"".join(records), dtype=np.uint8).reshape(
+        return np.frombuffer(b"".join(records), dtype=np.uint8).reshape(
             len(records), self.record_bytes)
-        DEVICE_BATCHES.labels("numpy").inc()
-        return DeviceBatch(keys=keys, array=arr, on_device=False)
 
-    def _land(self, keys: list[str], records: list[bytes]) -> DeviceBatch:
+    def _fall_back(self, e: Exception) -> None:
+        """The device path failed: host batches from here on, the input
+        pipeline must outlive a sink hiccup. Once, and loudly where the
+        caller had named its device."""
+        self.use_hbm = False
+        self.fell_back = type(e).__name__
+        DEVICE_FALLBACKS.labels(self.fell_back).inc()
+        (log.error if self.device_asked else log.warning)(
+            "HBM batch landing failed; NumPy batches for the rest of this "
+            "feed", batch=self.batch_no, cause=self.fell_back,
+            error=str(e)[:200], device_asked=self.device_asked)
+
+    def _land(self, keys: list[str], shards: list[str],
+              records: list[bytes], payload: int) -> DeviceBatch:
+        t0 = time.perf_counter()
+        arr = None
         if self.use_hbm:
             try:
-                return self._land_hbm(keys, records)
+                arr, steps = self._land_hbm(records)
             except DeviceFeedError:
                 raise
             except Exception as e:
-                # Device trouble (OOM, runtime) degrades to host batches —
-                # the input pipeline must outlive a sink hiccup.
-                log.warning("HBM batch landing failed; numpy fallback",
-                            error=str(e)[:200])
-                self.use_hbm = False
-        return self._land_numpy(keys, records)
+                self._fall_back(e)
+        path = "numpy" if arr is None else "hbm"
+        if arr is None:
+            arr, steps = self._land_numpy(records), "put=0"
+        DEVICE_BATCHES.labels(path).inc()
+        if self.flight is not None:
+            self.flight.record(
+                flightlib.EV_FEED_BATCH, self.batch_no,
+                (time.perf_counter() - t0) * 1000.0,
+                f"path={path} n={len(records)} "
+                f"payload={payload} {steps}")
+        self.batch_no += 1
+        return DeviceBatch(keys=keys, array=arr, on_device=path == "hbm",
+                           shards=shards)
 
     async def batches(self, samples):
         """Async generator: sample dicts in → DeviceBatch out."""
         keys: list[str] = []
+        shards: list[str] = []
         records: list[bytes] = []
-        async for sample in samples:
+        payload = 0         # the records' bytes before padding
+        waited = 0.0
+        it = samples.__aiter__()
+        while True:
+            t0 = time.perf_counter()
+            try:
+                sample = await it.__anext__()
+            except StopAsyncIteration:
+                break
+            waited += time.perf_counter() - t0
             keys.append(sample.get("__key__", ""))
+            shards.append(sample.get("__shard__", ""))
             records.append(self._record(sample))
+            payload += len(sample[self.ext])
             if len(records) == self.batch_size:
-                yield self._land(keys, records)
-                keys, records = [], []
+                self._stamp_wait(waited, len(records))
+                yield self._land(keys, shards, records, payload)
+                keys, shards, records = [], [], []
+                payload, waited = 0, 0.0
         if records and not self.drop_last:
-            yield self._land(keys, records)
+            self._stamp_wait(waited, len(records))
+            yield self._land(keys, shards, records, payload)
+
+    def _stamp_wait(self, waited: float, n: int) -> None:
+        if self.flight is not None:
+            self.flight.record(flightlib.EV_FEED_WAIT, self.batch_no,
+                               waited * 1000.0, str(n))

@@ -34,7 +34,11 @@ from dataclasses import dataclass
 from dragonfly2_tpu.pkg import dflog, metrics
 from dragonfly2_tpu.pkg.bufpool import BufferPool
 from dragonfly2_tpu.dataset import tar_index
-from dragonfly2_tpu.dataset.shard_reader import GatewayRangeFetcher, ShardReader
+from dragonfly2_tpu.dataset.shard_reader import (
+    DaemonRangeFetcher,
+    GatewayRangeFetcher,
+    ShardReader,
+)
 
 log = dflog.get("dataset.loader")
 
@@ -44,6 +48,12 @@ READAHEAD_DEPTH = metrics.gauge(
     "dataset_readahead_depth", "In-flight prefetched samples")
 EPOCHS = metrics.counter(
     "dataset_epochs_total", "Epoch iterations started")
+
+
+# Events the feed's ring holds: a batch of 256 stamps about 800 (a
+# feed_sample a sample, a sink_stage and a sink_checksum a record), and
+# whoever reads the ring reads it a batch at a time.
+FEED_RING = 8192
 
 
 class LoaderError(Exception):
@@ -131,18 +141,18 @@ def plan_host_epoch(samples_per_shard: list[int], opts: LoaderOptions,
 
 class PodShardedLoader:
     """Streams webdataset samples out of P2P tar shards for ONE host of a
-    pod. Construct with a Dfstore (gateway transport) or pass
-    ``fetcher_factory`` to ride an embedded daemon
-    (shard_reader.DaemonRangeFetcher). ``prepare()`` resolves every
+    pod. Construct with a Dfstore (gateway transport), or with
+    ``over_daemon`` in the process that embeds its daemon (shards named
+    by URL, read through its task manager). ``prepare()`` resolves every
     shard's index (cached P2P object or one-pass build), then
     ``epoch(n)`` yields sample dicts."""
 
     def __init__(self, store, bucket: str, shard_keys: list[str], *,
                  options: LoaderOptions | None = None,
-                 fetcher_factory=None,
+                 fetcher_factory=None, index_resolver=None,
                  coalesce_gap: int = 256 << 10,
                  index_concurrency: int = 4,
-                 pool: BufferPool | None = None):
+                 pool: BufferPool | None = None, flight=None):
         if not shard_keys:
             raise LoaderError("no shards given")
         if len(set(shard_keys)) != len(shard_keys):
@@ -153,12 +163,41 @@ class PodShardedLoader:
         self.opts = options or LoaderOptions()
         self._fetcher_factory = fetcher_factory or (
             lambda key: GatewayRangeFetcher(store, bucket, key))
+        self._index_resolver = index_resolver or (
+            lambda key: tar_index.fetch_or_build_index(store, bucket, key))
         self._coalesce_gap = coalesce_gap
         self._index_concurrency = max(1, index_concurrency)
         self.pool = pool if pool is not None else BufferPool(
             name="dataset_span")
+        # The feed-level flight ring (pkg/flight.TaskFlight) the readers
+        # stamp every sample's read on (``feed_sample``), or None. Hand the
+        # same ring to the DeviceFeed that consumes this loader.
+        self.flight = flight
         self.indexes: list[tar_index.ShardIndex] | None = None
         self.readers: list[ShardReader] | None = None
+
+    @classmethod
+    def over_daemon(cls, task_manager, urls: list[str], *, tag: str = "",
+                    **kwargs) -> "PodShardedLoader":
+        """The loader of a process that embeds its daemon (the JAX process
+        hosting its own dfdaemon): shards are URLs, ``prepare()`` streams
+        each ONCE through a whole-file task of ``task_manager`` into the
+        indexer (``tar_index.build_index_from_task``), which leaves the
+        shard whole in this host's store, and every sample read is a
+        ranged task on the same manager (``DaemonRangeFetcher``) that
+        imports its span from that store. ``tag`` is the task identity's
+        tag, the same for the shard's task and its samples'. The feed's
+        flight ring is ``loader.flight``, served with the daemon's others
+        under ``/debug/flight/dataset-feed:<tag>``."""
+        kwargs.setdefault("flight", task_manager.flight.task(
+            f"dataset-feed:{tag}", capacity=FEED_RING))
+        return cls(
+            None, "", urls,
+            fetcher_factory=lambda url: DaemonRangeFetcher(
+                task_manager, url, tag=tag),
+            index_resolver=lambda url: tar_index.build_index_from_task(
+                task_manager, url, tag=tag),
+            **kwargs)
 
     async def prepare(self) -> "PodShardedLoader":
         """Resolve all shard indexes (bounded concurrency) and build the
@@ -169,8 +208,7 @@ class PodShardedLoader:
 
         async def resolve(key: str) -> tar_index.ShardIndex:
             async with sem:
-                return await tar_index.fetch_or_build_index(
-                    self.store, self.bucket, key)
+                return await self._index_resolver(key)
 
         tasks = [asyncio.ensure_future(resolve(k)) for k in self.shard_keys]
         try:
@@ -183,7 +221,8 @@ class PodShardedLoader:
         self.readers = [
             ShardReader(self._fetcher_factory(key), idx,
                         extensions=self.opts.extensions,
-                        coalesce_gap=self._coalesce_gap, pool=self.pool)
+                        coalesce_gap=self._coalesce_gap, pool=self.pool,
+                        flight=self.flight)
             for key, idx in zip(self.shard_keys, self.indexes)]
         log.info("loader prepared", shards=len(self.shard_keys),
                  samples=sum(i.num_samples for i in self.indexes),
@@ -215,7 +254,7 @@ class PodShardedLoader:
         EPOCHS.inc()
         counts = [i.num_samples for i in self.indexes]
         plan = plan_host_epoch(counts, self.opts, epoch)
-        plan_iter = iter(plan)
+        plan_iter = iter(enumerate(plan))
         window = max(1, self.opts.readahead)
         inflight: deque[asyncio.Future] = deque()
 
@@ -224,9 +263,10 @@ class PodShardedLoader:
                 nxt = next(plan_iter, None)
                 if nxt is None:
                     break
-                si, ki = nxt
+                seq, (si, ki) = nxt
                 inflight.append(asyncio.ensure_future(
-                    self.readers[si].read_sample(self.indexes[si].samples[ki])))
+                    self.readers[si].read_sample(
+                        self.indexes[si].samples[ki], seq)))
             READAHEAD_DEPTH.set(len(inflight))
 
         try:

@@ -25,8 +25,10 @@ per-sample allocate/free churn.
 from __future__ import annotations
 
 import asyncio
+import time
 
 from dragonfly2_tpu.pkg import dflog, metrics
+from dragonfly2_tpu.pkg import flight as flightlib
 from dragonfly2_tpu.pkg.bufpool import BufferPool
 from dragonfly2_tpu.dataset.tar_index import Sample, ShardIndex
 
@@ -35,10 +37,16 @@ log = dflog.get("dataset.shard_reader")
 DATASET_BYTES = metrics.counter(
     "dataset_bytes_total",
     "Dataset plane bytes: fetched (ranged spans) vs yielded (sample "
-    "member payloads)", ("direction",))
+    "member payloads) vs device (what the feed put to the device, padding "
+    "included)", ("direction",))
 RANGE_READS = metrics.counter(
     "dataset_range_reads_total",
     "Sample span reads by outcome", ("result",))
+
+
+# Where a span's bytes came from, worst last: a sample that took several
+# spans is booked under the worst of them.
+SOURCES = ("reuse", "local", "peer", "cold", "origin")
 
 
 class ShardReadError(Exception):
@@ -66,7 +74,14 @@ class DaemonRangeFetcher:
         self.pod_broadcast = pod_broadcast
         self.stats = {"cold": 0, "reuse": 0}
 
-    async def fetch_into(self, start: int, end: int, buf: memoryview) -> None:
+    async def fetch_into(self, start: int, end: int,
+                         buf: memoryview) -> "tuple[str, float, float]":
+        """One span into ``buf`` as ONE ranged task. Returns what the feed's
+        ring books for it: where the bytes came from (``reuse``: the ranged
+        task was complete in this store; ``local``: imported from this
+        host's whole parent; ``peer``; ``origin``), the ms the task spent
+        moving them (the import's reads and writes; the pieces' transfers),
+        and the ms of the read from the task's store into ``buf``."""
         from dragonfly2_tpu.daemon.peer.task_manager import FileTaskRequest
         from dragonfly2_tpu.pkg.errors import Code, DfError
         from dragonfly2_tpu.pkg.piece import Range
@@ -97,12 +112,33 @@ class DaemonRangeFetcher:
             raise ShardReadError(
                 f"ranged task returned {store.metadata.content_length}B "
                 f"for a {n}B span of {self.url}")
+        t0 = time.perf_counter()
         with store:   # pin across the off-loop read
             # Unified read path: preadv straight into the caller's pooled
             # span buffer — no intermediate store buffer, no copy.
             await asyncio.to_thread(store.read_into, 0, n, buf)
+        read_ms = (time.perf_counter() - t0) * 1000.0
         self.stats["reuse" if final.from_reuse else "cold"] += 1
         RANGE_READS.labels("reuse" if final.from_reuse else "cold").inc()
+        if final.from_reuse:
+            return "reuse", 0.0, read_ms
+        # The task's own ring, while it is still there (a recorder keeps
+        # 128 tasks'): a slice read out of this host's whole parent store
+        # stamped range_import; a piece from a peer landed, one from the
+        # origin source_landed, each with its cost.
+        tf = self.tm.flight.get(final.task_id)
+        spent = {flightlib.EV_RANGE_IMPORT: 0.0, flightlib.EV_LANDED: 0.0,
+                 flightlib.EV_SOURCE_LANDED: 0.0}
+        imported = 0
+        for _, code, piece, aux, _ in (tf.events() if tf is not None else ()):
+            if code in spent:
+                spent[code] += aux
+                imported += code == flightlib.EV_RANGE_IMPORT and piece > 0
+        if final.from_p2p:
+            return "peer", spent[flightlib.EV_LANDED], read_ms
+        if imported:
+            return "local", spent[flightlib.EV_RANGE_IMPORT], read_ms
+        return "origin", spent[flightlib.EV_SOURCE_LANDED], read_ms
 
 
 class GatewayRangeFetcher:
@@ -115,12 +151,17 @@ class GatewayRangeFetcher:
         self.key = key
         self.stats = {"cold": 0, "reuse": 0}
 
-    async def fetch_into(self, start: int, end: int, buf: memoryview) -> None:
+    async def fetch_into(self, start: int, end: int,
+                         buf: memoryview) -> "tuple[str, float, float]":
+        """As ``DaemonRangeFetcher.fetch_into``; the gateway says only
+        whether the ranged task was reused (else ``cold``), and the bytes
+        arrive in ``buf`` as the response's body, no read of its own."""
         attrs, _ = await self.store.read_object_range(
             self.bucket, self.key, start, end, buf=buf)
         outcome = "reuse" if attrs.get("from_reuse") else "cold"
         self.stats[outcome] += 1
         RANGE_READS.labels(outcome).inc()
+        return outcome, 0.0, 0.0
 
 
 class ShardReader:
@@ -132,8 +173,11 @@ class ShardReader:
     def __init__(self, fetcher, index: ShardIndex, *,
                  extensions=None, coalesce_gap: int = 256 << 10,
                  include_headers: bool = False,
-                 pool: BufferPool | None = None):
+                 pool: BufferPool | None = None, flight=None):
         self.fetcher = fetcher
+        # The feed-level ring (pkg/flight.TaskFlight) that every sample's
+        # read stamps ``feed_sample`` on, or None.
+        self.flight = flight
         self.index = index
         self.extensions = (None if extensions is None
                            else tuple(extensions))
@@ -164,18 +208,23 @@ class ShardReader:
                 spans.append([s, e])
         return [(s, e) for s, e in spans]
 
-    async def read_sample(self, sample: Sample) -> dict:
+    async def read_sample(self, sample: Sample, seq: int = -1) -> dict:
         """Fetch one sample; returns ``{"__key__", "__shard__",
         <ext>: bytes, ...}``. Multiple spans fetch concurrently (rare —
-        coalescing usually leaves one)."""
+        coalescing usually leaves one). ``seq`` is the sample's place in
+        the caller's plan, for the feed's ring."""
+        t0 = time.perf_counter()
         spans = self.sample_spans(sample)
         bufs: dict[tuple[int, int], memoryview] = {}
+        fetched: list[tuple] = []
         try:
             for s, e in spans:
                 bufs[(s, e)] = self.pool.acquire(e - s)
 
             async def pull(s: int, e: int) -> None:
-                await self.fetcher.fetch_into(s, e, bufs[(s, e)])
+                fetched.append(
+                    await self.fetcher.fetch_into(s, e, bufs[(s, e)])
+                    or ("cold", 0.0, 0.0))
 
             if len(spans) == 1:
                 await pull(*spans[0])
@@ -188,6 +237,7 @@ class ShardReader:
                         t.cancel()
                     await asyncio.gather(*tasks, return_exceptions=True)
                     raise
+            t1 = time.perf_counter()   # the bytes are in the pooled buffers
             out: dict = {"__key__": sample.key, "__shard__": self.index.shard}
             yielded = 0
             for ext, m in self.index.members_of(sample, self.extensions):
@@ -198,9 +248,19 @@ class ShardReader:
                 lo = m.data_offset - span[0]
                 out[ext] = bytes(buf[lo:lo + m.size])
                 yielded += m.size
-            DATASET_BYTES.labels("fetched").inc(
-                sum(e - s for s, e in spans))
+            nbytes = sum(e - s for s, e in spans)
+            DATASET_BYTES.labels("fetched").inc(nbytes)
             DATASET_BYTES.labels("yielded").inc(yielded)
+            if self.flight is not None:
+                ms = (t1 - t0) * 1000.0
+                read_ms = max(f[2] for f in fetched)
+                self.flight.record_at(
+                    t1, flightlib.EV_FEED_SAMPLE, seq, ms,
+                    f"src={max((f[0] for f in fetched), key=SOURCES.index)} "
+                    f"tasks={len(spans)} bytes={nbytes} "
+                    f"task={ms - read_ms:.3f} "
+                    f"move={max(f[1] for f in fetched):.3f} "
+                    f"read={read_ms:.3f}")
             return out
         finally:
             for buf in bufs.values():

@@ -25,6 +25,7 @@ addressing is new with this layer.
 
 from __future__ import annotations
 
+import asyncio
 import json
 from dataclasses import dataclass, field
 
@@ -455,4 +456,40 @@ async def fetch_or_build_index(store, bucket: str, shard_key: str, *,
         except DfstoreError as e:
             log.warning("shard index publish failed (non-fatal)",
                         shard=shard_key, error=str(e)[:200])
+    return idx
+
+
+async def build_index_from_task(task_manager, url: str, *, tag: str = "",
+                                application: str = "",
+                                header: dict | None = None) -> ShardIndex:
+    """The one-pass build where the process embeds its daemon: the shard
+    streams through ONE whole-file task of ``task_manager`` (pieces in
+    order as they land) into the indexer, and stays whole in this host's
+    store under the task id that every ranged sample read of the same
+    ``(url, tag, application)`` names as its parent, so those reads import
+    their spans from here and leave the origin alone. No index object is
+    published: there is no bucket behind a bare URL (the gateway form,
+    ``fetch_or_build_index``, keeps the pod-wide cache)."""
+    from dragonfly2_tpu.daemon.peer.task_manager import StreamTaskRequest
+    from dragonfly2_tpu.proto.common import UrlMeta
+
+    req = StreamTaskRequest(url=url, meta=UrlMeta(
+        tag=tag, application=application, header=dict(header or {})))
+    attrs, body = await task_manager.start_stream_task(req)
+    ix = TarIndexer()
+    try:
+        async for chunk in body:
+            ix.feed(chunk)
+    finally:
+        await body.aclose()
+    idx = ix.finish(url)
+    # The last piece is out before the task is marked done: a reader that
+    # starts now must find the store complete, not a running task.
+    while task_manager.is_task_running(attrs["task_id"]):
+        await asyncio.sleep(0.005)
+    length = attrs["content_length"]
+    if length >= 0 and idx.size != length:
+        raise TruncatedShardError(
+            f"indexed {idx.size} of the task's {length} bytes of {url}")
+    INDEX_FETCHES.labels("built").inc()
     return idx

@@ -155,6 +155,32 @@ EV_DEVICE_PULL = 50
 # comparison; piece = jobs the landing thread STARTED in that time, 0 where
 # nothing ran beside the tail).
 EV_SINK_TAIL = 51
+# A ranged task's slice read out of a whole (or covering partial) parent store
+# of THIS host: ONE event as the import ends, on the ranged task's flight
+# (aux = ms of its reads and writes, piece = pieces imported, note = bytes).
+EV_RANGE_IMPORT = 52
+# The dataset plane (dataset/): the loader, its shard readers and the device
+# feed stamp ONE feed-level ring (``PodShardedLoader.flight``; a task's own
+# ring lives for 128 tasks, a sample is a task), each span ONE event at its
+# end with aux = its ms, as the sink_* spans.
+# feed_sample: a sample's read, launched by the readahead -> its spans
+# resolved -> every ranged task done -> the bytes in the pooled buffers
+# (piece = its place in the host's epoch plan; note = "src=<local|reuse|peer|
+# origin> tasks=<n> bytes=<fetched> task=<ms> move=<ms> read=<ms>": where the
+# bytes came from, the worst of its tasks; ranged tasks; span bytes; start ->
+# last task done; what a task spent moving the bytes, its range_import or its
+# pieces' transfers; the task's store -> the pooled buffer).
+EV_FEED_SAMPLE = 53
+# feed_wait: what the feed's consumer side stood waiting for samples while it
+# gathered one batch (piece = batch, aux = the summed ms, note = records).
+EV_FEED_WAIT = 54
+# feed_batch: a batch's landing, first record staged -> last flushed ->
+# verified on the device -> as_record_batch dispatched (piece = batch; note =
+# "path=<hbm|numpy> n=<records> payload=<bytes> put=<bytes> stage=<ms>
+# verify=<ms> view=<ms>"). The feed's HBMSink stamps its sink_stage,
+# sink_checksum, sink_put, sink_assemble and sink_compile on the same ring
+# with "batch=<k>" leading their note.
+EV_FEED_BATCH = 55
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -185,7 +211,9 @@ EVENT_NAMES = {
     EV_PARENT_SOURCE_FIRST_BYTE: "parent_source_first_byte",
     EV_PARENT_VERIFIED: "parent_verified",
     EV_TASK_SOURCES: "task_sources", EV_DEVICE_PULL: "device_pull",
-    EV_SINK_TAIL: "sink_tail",
+    EV_SINK_TAIL: "sink_tail", EV_RANGE_IMPORT: "range_import",
+    EV_FEED_SAMPLE: "feed_sample", EV_FEED_WAIT: "feed_wait",
+    EV_FEED_BATCH: "feed_batch",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING
@@ -961,7 +989,10 @@ class FlightRecorder:
         for bring in list(self.feeders):
             bring()
 
-    def task(self, task_id: str) -> TaskFlight:
+    def task(self, task_id: str, capacity: int = 0) -> TaskFlight:
+        """Get-or-create ``task_id``'s flight; ``capacity`` sizes a new
+        one's ring where the recorder's own would be too short (a dataset
+        feed's ring holds a batch of samples, not a task's pieces)."""
         tf = self._tasks.get(task_id)
         if tf is not None:
             return tf
@@ -971,7 +1002,7 @@ class FlightRecorder:
                 while len(self._tasks) >= self.max_tasks:
                     self._evict_one()
                 tf = self._tasks[task_id] = TaskFlight(
-                    task_id, self.capacity,
+                    task_id, capacity or self.capacity,
                     wall_offset=self.wall_offset)
         return tf
 

@@ -478,6 +478,9 @@ class LocalTaskStore:
         os.makedirs(self.dir, exist_ok=True)
         self._data_path = os.path.join(self.dir, DATA_FILE)
         self._fd: int | None = None
+        # Called with this store after it has opened its data file, from
+        # whichever thread did (StorageManager: its budget of open files).
+        self.on_open = None
         self._pins = 0
         self._unsaved_pieces = 0
         self._last_meta_save = 0.0
@@ -546,10 +549,16 @@ class LocalTaskStore:
 
     def _ensure_fd(self) -> int:
         if self._fd is None:
+            opened = False
             with self._meta_lock:
                 if self._fd is None:
                     self._fd = os.open(self._data_path,
                                        os.O_RDWR | os.O_CREAT, 0o644)
+                    opened = True
+            # Outside the lock: the manager may close OTHER stores' fds
+            # here (each under its own lock) to stay inside its budget.
+            if opened and self.on_open is not None:
+                self.on_open(self)
         return self._fd
 
     def close(self) -> None:

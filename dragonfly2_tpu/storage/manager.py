@@ -7,7 +7,9 @@ TTL+LRU disk-quota GC (:871-1068).
 
 from __future__ import annotations
 
+import collections
 import os
+import threading
 import time
 from dataclasses import dataclass
 
@@ -20,6 +22,14 @@ from dragonfly2_tpu.storage.local_store import (
 )
 
 log = dflog.get("storage")
+
+# Data files a manager holds open at once, beside those of pinned stores and
+# of stores touched within the last second: a daemon that serves thousands
+# of small tasks a minute (a dataset's samples, a ranged task each) would
+# else hold one fd a task until the idle sweep of gc(), a gc_interval or two
+# later, and run out of them first. The oldest opened go first; a closed
+# store reopens lazily.
+MAX_OPEN_FILES = 512
 
 
 @dataclass
@@ -45,6 +55,11 @@ class StorageManager:
         # can serve without consulting Python per request. piece_recorded
         # arrives from worker threads; implementations must be thread-safe.
         self.observer = None
+        # Stores in the order they opened their data file, oldest first
+        # (a store that was closed and reopened is in it again).
+        self._opened: "collections.deque[LocalTaskStore]" = \
+            collections.deque()
+        self._opened_lock = threading.Lock()
         os.makedirs(opt.data_dir, exist_ok=True)
 
     def set_observer(self, observer) -> None:
@@ -83,11 +98,31 @@ class StorageManager:
                 store.touch()
                 return store
         store = LocalTaskStore.create(self._task_dir(metadata.task_id), metadata)
+        store.on_open = self._note_open
         self._stores[metadata.task_id] = store
         if self.observer is not None:
             store.observer = self.observer
             self.observer.task_updated(store)
         return store
+
+    def _note_open(self, store: LocalTaskStore) -> None:
+        """A store opened its data file (any thread). Over the budget, the
+        few that opened theirs longest ago close them, but for those in
+        use: pinned, or touched within the last second (the GC's own rule
+        for an idle fd, at a shorter idle), which go to the back."""
+        with self._opened_lock:
+            self._opened.append(store)
+            over = len(self._opened) - MAX_OPEN_FILES
+            oldest = [self._opened.popleft() for _ in range(min(over, 8))]
+        now = time.time()
+        for old in oldest:
+            if old._fd is None:
+                continue        # closed since (gc, destroy)
+            if old.pinned or now - old.metadata.last_access < 1.0:
+                with self._opened_lock:
+                    self._opened.append(old)
+            else:
+                old.close()
 
     def get(self, task_id: str) -> LocalTaskStore:
         store = self._stores.get(task_id)
@@ -168,6 +203,7 @@ class StorageManager:
                 if store.metadata.invalid:
                     store.destroy()
                     continue
+                store.on_open = self._note_open
                 self._stores[store.metadata.task_id] = store
                 restored += 1
         if restored:

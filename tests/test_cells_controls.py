@@ -26,6 +26,9 @@ CONTROLS = [
     ("control_global.py", ("--break", "flip"), "tiny-host-reland-ep4"),
     ("control_global.py", ("--break", "copy"), "tiny-host-reland-ep4"),
     ("control_global.py", ("--break", "misplace"), "tiny-host-reland-ep4"),
+    ("control_feed.py", ("--break", "flip"), "tiny-feed-records"),
+    ("control_feed.py", ("--break", "swap"), "tiny-feed-records"),
+    ("control_feed.py", ("--break", "numpy"), "tiny-feed-records"),
 ]
 
 

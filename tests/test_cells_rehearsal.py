@@ -32,6 +32,7 @@ CELLS = {
     "tiny-rank-cold": ("manifest-ranged.json", 1),
     "tiny-shard-cold-fanout": ("manifest-fanout.json", 1),
     "tiny-host-reland-ep4": ("manifest-global.json", 4),
+    "tiny-feed-records": ("manifest-feed.json", 1),
 }
 
 # Every process of a run inherits its environment from the run, so a

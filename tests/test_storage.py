@@ -621,3 +621,41 @@ class TestDigestReadAhead:
             gate.set()
         _wait_for(lambda: not _prefix_threads(), "threads gone")
         assert out() == before
+
+
+def test_open_data_files_stay_inside_the_managers_budget(tmp_path,
+                                                         monkeypatch):
+    """A daemon that lands thousands of one-piece tasks (a dataset's samples,
+    a ranged task each) holds at most ``MAX_OPEN_FILES`` data files open,
+    beside the pinned and the just-touched: the oldest opened close theirs
+    and reopen lazily, with their bytes."""
+    import time as _time
+
+    from dragonfly2_tpu.storage import manager
+    from dragonfly2_tpu.storage.manager import StorageManager, StorageOption
+
+    monkeypatch.setattr(manager, "MAX_OPEN_FILES", 16)
+    mgr = StorageManager(StorageOption(data_dir=str(tmp_path / "d")))
+    stores = []
+    for i in range(200):
+        store = mgr.register_task(TaskStoreMetadata(
+            task_id=f"sample-{i:04d}", content_length=8, piece_size=8,
+            total_piece_count=1))
+        store.write_piece(0, b"%08d" % i)
+        # As if written more than a second ago.
+        store.metadata.last_access = _time.time() - 5
+        stores.append(store)
+    assert sum(s._fd is not None for s in stores) <= 16
+    assert stores[0]._fd is None and stores[-1]._fd is not None
+    assert stores[0].read_piece(0) == b"00000000"      # lazy reopen
+    # A pinned store and one touched within the second keep theirs.
+    with stores[1]:
+        stores[1].read_piece(0)
+        stores[2].read_piece(0)
+        stores[2].touch()
+        for s in stores[3:40]:
+            s.read_piece(0)
+            s.metadata.last_access = _time.time() - 5
+        assert stores[1]._fd is not None and stores[2]._fd is not None
+    assert sum(s._fd is not None for s in stores) <= 16 + 2
+    mgr.close()
