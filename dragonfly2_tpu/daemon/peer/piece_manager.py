@@ -82,13 +82,17 @@ class PieceManager:
         limiter = limiter or self._limiter
 
         content_length = store.metadata.content_length
+        # A ranged store that has its length has the SLICE's (a local import
+        # that failed part-way, a run before this one): not the object's
+        # total to clamp the range against.
+        slice_known = content_range is not None and content_length >= 0
         range_known: bool | None = None
         if content_length < 0:
             try:
                 content_length, range_known = await client.probe(request)
             except SourceError:
                 content_length = -1
-        if content_range is not None:
+        if content_range is not None and not slice_known:
             # Ranged task: treat the range as the content.
             total = content_length if content_length >= 0 else -1
             if total >= 0:

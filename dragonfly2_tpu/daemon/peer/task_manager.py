@@ -218,6 +218,8 @@ class TaskManager:
         # daemons share the process-wide one.
         self.flight = flight if flight is not None else flightlib.recorder()
         self._running: dict[str, _RunningTask] = {}
+        # Parents whose direct range read failed, each warned of once.
+        self._unreadable_parents: set[str] = set()
         # Last completed P2P pull's bytes per parent locality
         # (conductor.locality_bytes), keyed by task id — the striped
         # e2e/bench per-host DCN-bytes readout. Bounded: small dicts,
@@ -533,9 +535,13 @@ class TaskManager:
         # Device requests skip this (the export path is file-only; a
         # fresh ranged task below lands into the sink), and so do
         # output-less requests (gateway ranged prefetch: nothing to
-        # export — the fresh ranged task imports from the warm parent
-        # via _covering_local_parent instead). The local parent keeps
-        # serving its pieces to other peers either way.
+        # export). Their fresh ranged task imports from the warm parent
+        # only where no scheduler is configured or the conductor is
+        # demoted to back-to-source (_run_download); under a scheduler
+        # it registers and pulls its pieces like any task. A caller with
+        # a buffer of its own asks read_range_from_local_parent BEFORE
+        # it makes a task (dataset/shard_reader.py). The local parent
+        # keeps serving its pieces to other peers either way.
         if req.meta.range and req.device != "tpu" and req.output:
             covering = self._covering_local_parent(req)
             if covering is not None:
@@ -1151,6 +1157,34 @@ class TaskManager:
         if length <= 0 or not parent.covers_range(rng.start, length):
             return None
         return parent, Range(rng.start, length)
+
+    async def read_range_from_local_parent(self, req, buf) -> "int | None":
+        """The bytes of ``req``'s range straight into ``buf``, when THIS
+        store's parent covers them (the one gate above): no task is made,
+        none registered, nothing leaves this process. Returns the bytes
+        read (the range clamped to the parent's end), or None where the
+        caller must run the ranged task: no covering parent, or a parent
+        that cannot be read (truncated under its metadata, reclaimed),
+        which the task's own import and its fall to the origin then
+        handle as they did."""
+        covering = self._covering_local_parent(req)
+        if covering is None:
+            return None
+        parent, rng = covering
+        try:
+            with parent:  # pin across the off-loop read
+                await asyncio.to_thread(parent.read_into, rng.start,
+                                        rng.length, buf)
+        except (StorageError, OSError) as e:
+            parent_id = parent.metadata.task_id
+            if parent_id not in self._unreadable_parents:
+                self._unreadable_parents.add(parent_id)
+                log.warning("local range read failed; its ranges go "
+                            "through ranged tasks", parent=parent_id[:16],
+                            start=rng.start, length=rng.length,
+                            error=str(e)[:200])
+            return None
+        return rng.length
 
     async def import_range_from_local_parent(self, store, req, on_piece) -> bool:
         """Ranged back-source shortcut: when THIS daemon already holds a

@@ -20,8 +20,9 @@ seeds via SHA-512, stable across processes and machines — never
 ``hash()``, which is salted per process).
 
 Fetching is pipelined: a bounded readahead window of in-flight
-``ShardReader.read_sample`` futures (each a ranged P2P task) runs ahead
-of the consumer; yield order stays the planned order.
+``ShardReader.read_sample`` futures (each a read of this host's store
+where it holds the shard, else a ranged P2P task) runs ahead of the
+consumer; yield order stays the planned order.
 """
 
 from __future__ import annotations
@@ -183,10 +184,13 @@ class PodShardedLoader:
         hosting its own dfdaemon): shards are URLs, ``prepare()`` streams
         each ONCE through a whole-file task of ``task_manager`` into the
         indexer (``tar_index.build_index_from_task``), which leaves the
-        shard whole in this host's store, and every sample read is a
-        ranged task on the same manager (``DaemonRangeFetcher``) that
-        imports its span from that store. ``tag`` is the task identity's
-        tag, the same for the shard's task and its samples'. The feed's
+        shard whole in this host's store, and every sample read is then a
+        read of its span out of that store (``DaemonRangeFetcher`` asks
+        the same manager's parent gate first): no task, no register, no
+        other process in a sample's path. A shard that has left the store
+        since (evicted, unreadable) falls back to a ranged task a span
+        through the fabric. ``tag`` is the task identity's tag, the same
+        for the shard's task and its samples' spans. The feed's
         flight ring is ``loader.flight``, served with the daemon's others
         under ``/debug/flight/dataset-feed:<tag>``."""
         kwargs.setdefault("flight", task_manager.flight.task(

@@ -1,4 +1,5 @@
-"""Sample-addressed reads: a sample's tar byte spans → ranged P2P tasks.
+"""Sample-addressed reads: a sample's tar byte spans → a read of this
+host's store where it holds the shard, ranged P2P tasks where it does not.
 
 The point of the dataset plane: a host that needs sample ``000123`` of a
 16 GB shard must fetch the few hundred KB covering that sample's members,
@@ -6,14 +7,20 @@ not the shard. Both fetchers below resolve a byte span to a RANGED file
 task on a daemon — range is part of task identity (pkg/idgen
 task_id_v1), so every host in the pod pulling the same sample issues a
 byte-identical task and the fabric dedupes per SPAN, exactly like
-sharded checkpoint pulls (client/device.py _pull_ranges). Warm spans are
-imported from the local whole-shard parent store without touching origin
-(task_manager.import_range_from_local_parent); repeated reads ride
-completed-task reuse.
+sharded checkpoint pulls (client/device.py _pull_ranges); repeated reads
+ride completed-task reuse. A span that a parent in THIS host's store
+covers (the shard whole after ``PodShardedLoader.over_daemon``'s
+``prepare()``, or a partial parent with the span's pieces) is no task at
+all on the embedded daemon: ``DaemonRangeFetcher`` asks the task
+manager's one parent gate first (task_manager.
+read_range_from_local_parent) and the bytes are one ``preadv`` out of
+that store, with no register, no scheduler and no seed in a sample's
+path. The gateway's fetcher always makes the task.
 
 Two transports:
   * ``DaemonRangeFetcher`` — embedded daemon (the north-star JAX process
-    hosting its own dfdaemon): ranged FileTasks directly on the TaskManager.
+    hosting its own dfdaemon): this store's parent first, else ranged
+    FileTasks directly on the TaskManager.
   * ``GatewayRangeFetcher`` — over HTTP against the daemon's object
     gateway (`?ranged_task=1` GETs, daemon/objectstorage.py).
 
@@ -45,8 +52,8 @@ RANGE_READS = metrics.counter(
 
 
 # Where a span's bytes came from, worst last: a sample that took several
-# spans is booked under the worst of them.
-SOURCES = ("reuse", "local", "peer", "cold", "origin")
+# spans is booked under the worst of them. ``local`` alone ran no task.
+SOURCES = ("local", "reuse", "import", "peer", "cold", "origin")
 
 
 class ShardReadError(Exception):
@@ -54,10 +61,13 @@ class ShardReadError(Exception):
 
 
 class DaemonRangeFetcher:
-    """Ranged file tasks on an in-process daemon/TaskManager. ``url`` is
-    the shard's origin URL (e.g. backend.object_url(bucket, key)); ``tag``
-    must match whatever other consumers use (the gateway uses the bucket
-    name) so ranged tasks dedupe across surfaces."""
+    """Spans of one shard on an in-process daemon/TaskManager: read out of
+    its store where a parent of the same identity covers them, ranged file
+    tasks otherwise. ``url`` is the shard's origin URL (e.g.
+    backend.object_url(bucket, key)); ``tag`` must match whatever other
+    consumers use (the gateway uses the bucket name) so ranged tasks
+    dedupe across surfaces, and whatever pulled the shard whole, so that
+    it is found as the parent."""
 
     def __init__(self, task_manager, url: str, *, tag: str = "",
                  application: str = "", header: dict | None = None,
@@ -72,16 +82,22 @@ class DaemonRangeFetcher:
         self.application = application
         self.header = dict(header or {})
         self.pod_broadcast = pod_broadcast
-        self.stats = {"cold": 0, "reuse": 0}
+        # Span reads by outcome: ``local`` read from this store's parent
+        # with no task, ``reuse`` a ranged task already complete here,
+        # ``cold`` a ranged task run; the local hit share is ``local`` over
+        # the three.
+        self.stats = {"local": 0, "cold": 0, "reuse": 0}
 
     async def fetch_into(self, start: int, end: int,
                          buf: memoryview) -> "tuple[str, float, float]":
-        """One span into ``buf`` as ONE ranged task. Returns what the feed's
-        ring books for it: where the bytes came from (``reuse``: the ranged
-        task was complete in this store; ``local``: imported from this
-        host's whole parent; ``peer``; ``origin``), the ms the task spent
-        moving them (the import's reads and writes; the pieces' transfers),
-        and the ms of the read from the task's store into ``buf``."""
+        """One span into ``buf``: read from this host's store where a parent
+        there covers it, else fetched as ONE ranged task. Returns what the
+        feed's ring books for it: where the bytes came from (``local``:
+        this store's parent, no task; ``reuse``: the ranged task was
+        complete in this store; ``import``: a task that copied them from
+        the parent; ``peer``; ``origin``), the ms a task spent moving them
+        (the import's reads and writes; the pieces' transfers), and the ms
+        of the read from the store into ``buf``."""
         from dragonfly2_tpu.daemon.peer.task_manager import FileTaskRequest
         from dragonfly2_tpu.pkg.errors import Code, DfError
         from dragonfly2_tpu.pkg.piece import Range
@@ -95,6 +111,16 @@ class DaemonRangeFetcher:
                                            range=rng),
                               pod_broadcast=self.pod_broadcast)
         req.range = Range.parse_http(rng)
+        n = end - start
+        t0 = time.perf_counter()
+        got = await self.tm.read_range_from_local_parent(req, buf)
+        if got is not None:
+            if got != n:
+                raise ShardReadError(
+                    f"this store's parent holds {got}B of a {n}B span of "
+                    f"{self.url}")
+            self._count("local")
+            return "local", 0.0, (time.perf_counter() - t0) * 1000.0
         final = None
         async for p in self.tm.start_file_task(req):
             if p.state == "failed":
@@ -107,7 +133,6 @@ class DaemonRangeFetcher:
         if store is None:
             raise DfError(Code.StorageTaskNotFound,
                           f"ranged task {final.task_id[:16]} has no store")
-        n = end - start
         if store.metadata.content_length != n:
             raise ShardReadError(
                 f"ranged task returned {store.metadata.content_length}B "
@@ -118,14 +143,13 @@ class DaemonRangeFetcher:
             # span buffer — no intermediate store buffer, no copy.
             await asyncio.to_thread(store.read_into, 0, n, buf)
         read_ms = (time.perf_counter() - t0) * 1000.0
-        self.stats["reuse" if final.from_reuse else "cold"] += 1
-        RANGE_READS.labels("reuse" if final.from_reuse else "cold").inc()
+        self._count("reuse" if final.from_reuse else "cold")
         if final.from_reuse:
             return "reuse", 0.0, read_ms
         # The task's own ring, while it is still there (a recorder keeps
-        # 128 tasks'): a slice read out of this host's whole parent store
-        # stamped range_import; a piece from a peer landed, one from the
-        # origin source_landed, each with its cost.
+        # 128 tasks'): a slice copied out of a parent that came into this
+        # store after the gate said no stamped range_import; a piece from a
+        # peer landed, one from the origin source_landed, each with its cost.
         tf = self.tm.flight.get(final.task_id)
         spent = {flightlib.EV_RANGE_IMPORT: 0.0, flightlib.EV_LANDED: 0.0,
                  flightlib.EV_SOURCE_LANDED: 0.0}
@@ -137,8 +161,12 @@ class DaemonRangeFetcher:
         if final.from_p2p:
             return "peer", spent[flightlib.EV_LANDED], read_ms
         if imported:
-            return "local", spent[flightlib.EV_RANGE_IMPORT], read_ms
+            return "import", spent[flightlib.EV_RANGE_IMPORT], read_ms
         return "origin", spent[flightlib.EV_SOURCE_LANDED], read_ms
+
+    def _count(self, result: str) -> None:
+        self.stats[result] += 1
+        RANGE_READS.labels(result).inc()
 
 
 class GatewayRangeFetcher:
@@ -257,8 +285,8 @@ class ShardReader:
                 self.flight.record_at(
                     t1, flightlib.EV_FEED_SAMPLE, seq, ms,
                     f"src={max((f[0] for f in fetched), key=SOURCES.index)} "
-                    f"tasks={len(spans)} bytes={nbytes} "
-                    f"task={ms - read_ms:.3f} "
+                    f"tasks={sum(f[0] != 'local' for f in fetched)} "
+                    f"bytes={nbytes} task={ms - read_ms:.3f} "
                     f"move={max(f[1] for f in fetched):.3f} "
                     f"read={read_ms:.3f}")
             return out

@@ -466,10 +466,10 @@ async def build_index_from_task(task_manager, url: str, *, tag: str = "",
     streams through ONE whole-file task of ``task_manager`` (pieces in
     order as they land) into the indexer, and stays whole in this host's
     store under the task id that every ranged sample read of the same
-    ``(url, tag, application)`` names as its parent, so those reads import
-    their spans from here and leave the origin alone. No index object is
-    published: there is no bucket behind a bare URL (the gateway form,
-    ``fetch_or_build_index``, keeps the pod-wide cache)."""
+    ``(url, tag, application)`` names as its parent, so those reads take
+    their spans from here and leave the fabric and the origin alone. No
+    index object is published: there is no bucket behind a bare URL (the
+    gateway form, ``fetch_or_build_index``, keeps the pod-wide cache)."""
     from dragonfly2_tpu.daemon.peer.task_manager import StreamTaskRequest
     from dragonfly2_tpu.proto.common import UrlMeta
 

@@ -161,15 +161,17 @@ EV_SINK_TAIL = 51
 EV_RANGE_IMPORT = 52
 # The dataset plane (dataset/): the loader, its shard readers and the device
 # feed stamp ONE feed-level ring (``PodShardedLoader.flight``; a task's own
-# ring lives for 128 tasks, a sample is a task), each span ONE event at its
-# end with aux = its ms, as the sink_* spans.
+# ring lives for 128 tasks, a sample is a task or none), each span ONE event
+# at its end with aux = its ms, as the sink_* spans.
 # feed_sample: a sample's read, launched by the readahead -> its spans
-# resolved -> every ranged task done -> the bytes in the pooled buffers
-# (piece = its place in the host's epoch plan; note = "src=<local|reuse|peer|
-# origin> tasks=<n> bytes=<fetched> task=<ms> move=<ms> read=<ms>": where the
-# bytes came from, the worst of its tasks; ranged tasks; span bytes; start ->
-# last task done; what a task spent moving the bytes, its range_import or its
-# pieces' transfers; the task's store -> the pooled buffer).
+# resolved -> each read out of this store's parent or its ranged task done ->
+# the bytes in the pooled buffers (piece = its place in the host's epoch
+# plan; note = "src=<local|reuse|import|peer|cold|origin> tasks=<n>
+# bytes=<fetched> task=<ms> move=<ms> read=<ms>": where the bytes came from,
+# the worst of its spans, ``local`` this store's parent with no task; ranged
+# tasks the spans ran, 0 for a sample read from this store; span bytes; the
+# read less ``read``; what a task spent moving the bytes, its range_import or
+# its pieces' transfers, 0 with no task; the store -> the pooled buffer).
 EV_FEED_SAMPLE = 53
 # feed_wait: what the feed's consumer side stood waiting for samples while it
 # gathered one batch (piece = batch, aux = the summed ms, note = records).

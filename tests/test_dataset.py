@@ -9,12 +9,16 @@ ranges and reuse are about the actual P2P machinery, not mocks.
 from __future__ import annotations
 
 import io
+import os
+import random
 import tarfile
 
 import aiohttp
 import pytest
 
 from dragonfly2_tpu.client.dfstore import Dfstore
+from dragonfly2_tpu.daemon.peer import task_manager as task_manager_module
+from dragonfly2_tpu.daemon.peer.task_manager import FileTaskRequest
 from dragonfly2_tpu.dataset import (
     DaemonRangeFetcher,
     LoaderOptions,
@@ -26,9 +30,15 @@ from dragonfly2_tpu.dataset import (
     interleave_shards,
     plan_host_epoch,
 )
+from dragonfly2_tpu.dataset.shard_reader import RANGE_READS
 from dragonfly2_tpu.dataset.tar_index import fetch_or_build_index, index_object_key
-from dragonfly2_tpu.pkg import metrics
-from dragonfly2_tpu.pkg.testing import start_gateway_fixture
+from dragonfly2_tpu.pkg import idgen, metrics
+from dragonfly2_tpu.pkg.testing import start_gateway_fixture, start_range_origin
+from dragonfly2_tpu.proto.common import UrlMeta
+from dragonfly2_tpu.source.client import default_registry
+from dragonfly2_tpu.storage.local_store import TaskStoreMetadata
+
+from tests.test_stream_proxy import make_task_manager
 
 
 def make_shard(shard_no: int, n_samples: int, payload_base: int = 64) -> bytes:
@@ -204,9 +214,10 @@ def test_cold_read_is_ranged_and_warm_read_reuses(run_async, tmp_path):
 
 
 def test_daemon_fetcher_matches_gateway(run_async, tmp_path):
-    """The embedded-daemon fetcher (ranged FileTasks straight on the
-    TaskManager) produces identical sample bytes and dedupes with the
-    gateway's ranged tasks (same tag → same task identity)."""
+    """On a store that holds no parent of the shard, the embedded-daemon
+    fetcher (ranged FileTasks straight on the TaskManager) produces
+    identical sample bytes and dedupes with the gateway's ranged tasks
+    (same tag → same task identity)."""
 
     async def run():
         fx = await start_gateway_fixture(tmp_path)
@@ -220,9 +231,12 @@ def test_daemon_fetcher_matches_gateway(run_async, tmp_path):
                                    tag="wds"),
                 idx)
             sample = idx.samples[2]
+            assert not [t for t in fx.tm.storage.tasks()
+                        if t.metadata.content_length == len(shards[key])]
             out = await reader.read_sample(sample)
             assert out["jpg"] == expected_payload(0, 2)
-            assert reader.fetcher.stats == {"cold": 1, "reuse": 0}
+            assert reader.fetcher.stats == {"local": 0, "cold": 1,
+                                            "reuse": 0}
             n_tasks = len(fx.tm.storage.tasks())
             # Same span over the gateway: byte-identical task id → reuse,
             # no new task store.
@@ -235,6 +249,226 @@ def test_daemon_fetcher_matches_gateway(run_async, tmp_path):
             await fx.aclose()
 
     run_async(run())
+
+
+# -- a span this host's store covers is read from it, not made a task --------
+
+MB = 1 << 20
+CONTENT = random.Random(47).randbytes(9 * MB)    # pieces of 4, 4 and 1 MiB
+SPAN = (4 * MB - 700, 4 * MB + 900)              # across pieces 0 and 1
+TAG = "held"
+
+
+async def pull_whole(tm, url: str, tag: str = TAG):
+    """The shard whole in ``tm``'s store under the id that every ranged
+    read of (url, tag) names as its parent, as ``prepare()`` leaves it."""
+    async for p in tm.start_file_task(FileTaskRequest(
+            url=url, output="", meta=UrlMeta(tag=tag))):
+        assert p.state != "failed", p.error
+    return tm.storage.find_completed_task(p.task_id)
+
+
+def ranged_task_id(url: str, start: int, end: int, tag: str = TAG) -> str:
+    """The id the fabric dedupes a span's task by, reckoned beside the
+    fetcher: it must not move with where the bytes were found."""
+    return idgen.task_id_v1(url, tag=tag,
+                            range_header=f"bytes={start}-{end - 1}")
+
+
+async def fetch(tm, url: str, span=SPAN, tag: str = TAG):
+    fetcher = DaemonRangeFetcher(tm, url, tag=tag)
+    buf = memoryview(bytearray(span[1] - span[0]))
+    got = await fetcher.fetch_into(*span, buf)
+    return fetcher, got, bytes(buf)
+
+
+def local_reads() -> float:
+    return RANGE_READS.labels("local")._value.get()
+
+
+def test_a_span_the_store_covers_is_read_from_the_pinned_parent_as_no_task(
+        run_async, tmp_path):
+    """The whole shard is in this store: a span across two of its pieces
+    comes out of it byte for byte what the ranged task gives a host without
+    the shard, with the parent pinned while the read runs and let go after,
+    no task made for it, nothing asked of the origin, and counted."""
+
+    async def run():
+        runner, url, served = await start_range_origin(CONTENT)
+        holder = make_task_manager(tmp_path / "a")
+        other = make_task_manager(tmp_path / "b")
+        try:
+            parent = await pull_whole(holder, url)
+            assert parent.metadata.piece_size == 4 * MB
+            pinned = []
+            sound = parent.read_into
+
+            def read_into(offset, length, buf, at=0):
+                pinned.append(parent.pinned)
+                return sound(offset, length, buf, at)
+
+            parent.read_into = read_into
+            before = served["bytes"], local_reads(), len(holder.flight.summary())
+            fetcher, (src, move, read_ms), got = await fetch(holder, url)
+            assert (src, move) == ("local", 0.0) and read_ms >= 0
+            assert got == CONTENT[SPAN[0]:SPAN[1]]
+            assert pinned == [True] and not parent.pinned
+            assert [t.metadata.task_id for t in holder.storage.tasks()] \
+                == [parent.metadata.task_id]
+            assert (served["bytes"], local_reads() - 1,
+                    len(holder.flight.summary())) == before
+            assert fetcher.stats == {"local": 1, "cold": 0, "reuse": 0}
+            # The host without the shard: ONE ranged task under the id
+            # every host names the span by, the same bytes.
+            fetcher, (src, _, _), through_task = await fetch(other, url)
+            assert src == "origin" and through_task == got
+            assert [t.metadata.task_id for t in other.storage.tasks()] \
+                == [ranged_task_id(url, *SPAN)]
+            assert fetcher.stats == {"local": 0, "cold": 1, "reuse": 0}
+        finally:
+            await default_registry().close_all()
+            await runner.cleanup()
+            holder.storage.close()
+            other.storage.close()
+
+    run_async(run())
+
+
+async def parent_absent(tm, url):
+    return SPAN
+
+
+async def parent_of_another_tag(tm, url):
+    await pull_whole(tm, url, tag="another")
+    return SPAN
+
+
+async def partial_parent_missing_a_piece_of_the_span(tm, url):
+    """Piece 0 of three is here: a span inside it is read from it, the span
+    that runs on into piece 1 is not covered."""
+    store = tm.storage.register_task(TaskStoreMetadata(
+        task_id=idgen.parent_task_id_v1(url, tag=TAG), url=url, tag=TAG))
+    store.update_task(content_length=len(CONTENT), piece_size=4 * MB,
+                      total_piece_count=3)
+    store.write_piece(0, CONTENT[:4 * MB])
+    inside = (MB, MB + 5000)
+    _, (src, _, _), got = await fetch(tm, url, inside)
+    assert src == "local" and got == CONTENT[inside[0]:inside[1]]
+    return SPAN
+
+
+async def parent_evicted_between_two_reads(tm, url):
+    parent = await pull_whole(tm, url)
+    _, (src, _, _), _ = await fetch(tm, url)
+    assert src == "local"
+    tm.storage.delete_task(parent.metadata.task_id)
+    return SPAN
+
+
+async def parent_cut_short_under_its_metadata(tm, url):
+    """``read_into`` raises StorageError (EOF inside the span): the read
+    says no, the task's own import fails the same way and falls to the
+    origin, as it did before the short cut."""
+    parent = await pull_whole(tm, url)
+    os.truncate(parent.data_path, SPAN[0] + 100)
+    return SPAN
+
+
+@pytest.mark.parametrize("arrange", [
+    parent_absent, parent_of_another_tag,
+    partial_parent_missing_a_piece_of_the_span,
+    parent_evicted_between_two_reads, parent_cut_short_under_its_metadata],
+    ids=lambda f: f.__name__)
+def test_the_gate_says_no_and_the_ranged_task_runs(run_async, tmp_path,
+                                                   monkeypatch, arrange):
+    """Where this store cannot give the span, the span is ONE ranged task
+    under the unchanged id, its bytes exact; a parent that cannot be read is
+    warned of once, however many spans ask."""
+    warned = []
+    monkeypatch.setattr(
+        task_manager_module.log, "warning",
+        lambda msg, **kw: warned.append(msg))
+
+    async def run():
+        runner, url, served = await start_range_origin(CONTENT)
+        tm = make_task_manager(tmp_path / "host")
+        try:
+            span = await arrange(tm, url)
+            before = served["bytes"], local_reads()
+            held = {t.metadata.task_id for t in tm.storage.tasks()}
+            fetcher, (src, _, _), got = await fetch(tm, url, span)
+            assert src == "origin" and got == CONTENT[span[0]:span[1]]
+            assert fetcher.stats == {"local": 0, "cold": 1, "reuse": 0}
+            assert {t.metadata.task_id for t in tm.storage.tasks()} - held \
+                == {ranged_task_id(url, *span)}
+            # The span and the probe's byte, not the shard.
+            assert 0 <= served["bytes"] - before[0] - (span[1] - span[0]) <= 8
+            assert local_reads() == before[1]
+            again = (span[0] + 16, span[1])
+            _, (src, _, _), got = await fetch(tm, url, again)
+            assert src == "origin" and got == CONTENT[again[0]:again[1]]
+        finally:
+            await default_registry().close_all()
+            await runner.cleanup()
+            tm.storage.close()
+
+    run_async(run())
+    unreadable = [m for m in warned if m.startswith("local range read failed")]
+    assert len(unreadable) == (
+        arrange is parent_cut_short_under_its_metadata)
+
+
+def test_under_a_scheduler_a_host_that_holds_the_shard_asks_nobody(
+        run_async, tmp_path):
+    """Origin, scheduler, seed and a peer that pulled the shard whole: a
+    sample's span on the peer makes no register (the scheduler's tables stand
+    still), no task in the seed and none in the peer; the same span under a
+    tag the peer holds no shard of is still one ranged task from the seed,
+    under the unchanged id."""
+    import tests.test_p2p_e2e as e2e
+
+    async def run():
+        runner, url, served = await start_range_origin(CONTENT)
+        sched = await e2e.start_scheduler()
+        daemons = []
+        try:
+            daemons.append(seed := await e2e.start_daemon(
+                tmp_path, "seed", sched.port(), seed=True))
+            daemons.append(peer := await e2e.start_daemon(
+                tmp_path, "peer", sched.port()))
+            await pull_whole(peer.task_manager, url)
+
+            def tables():
+                return (len(sched.service.tasks.all()),
+                        len(sched.service.peers.all()),
+                        len(seed.storage.tasks()), len(peer.storage.tasks()),
+                        served["bytes"])
+
+            before = tables()
+            fetcher, (src, _, _), got = await fetch(peer.task_manager, url)
+            assert src == "local" and got == CONTENT[SPAN[0]:SPAN[1]]
+            assert tables() == before
+            assert fetcher.stats == {"local": 1, "cold": 0, "reuse": 0}
+
+            fetcher, (src, _, _), got = await fetch(peer.task_manager, url,
+                                                    tag="not-held")
+            assert src == "peer" and got == CONTENT[SPAN[0]:SPAN[1]]
+            assert fetcher.stats == {"local": 0, "cold": 1, "reuse": 0}
+            task_id = ranged_task_id(url, *SPAN, tag="not-held")
+            assert sched.service.tasks.load(task_id) is not None
+            for d in daemons:
+                assert d.storage.find_completed_task(task_id) is not None
+            after = tables()
+            assert (after[0] - before[0], after[2] - before[2],
+                    after[3] - before[3]) == (1, 1, 1)
+            assert after[1] - before[1] == 2      # the peer's and the seed's
+        finally:
+            for d in daemons:
+                await d.stop()
+            await sched.stop()
+            await runner.cleanup()
+
+    run_async(run(), timeout=120)
 
 
 @pytest.mark.slow

@@ -1,11 +1,11 @@
 """The dataset plane on an embedded daemon against the plain reference beside
 this file (``feed_reference.py``): ``PodShardedLoader.over_daemon`` on a real
 TaskManager (shards named by URL, indexed by streaming each once through a
-whole-file task, every sample a ranged task that imports its span from that
-store) feeding ``DeviceFeed(force_hbm=True)`` (the one ``HBMSink``, on the CPU
-backend), held to the reference's keys, order and every byte, the short last
-batch included, for one host and for each host of two; and what the plane
-stamps on its flight ring while it does so.
+whole-file task, every sample a read of its span out of that store, no task
+made for it) feeding ``DeviceFeed(force_hbm=True)`` (the one ``HBMSink``, on
+the CPU backend), held to the reference's keys, order and every byte, the
+short last batch included, for one host and for each host of two; and what
+the plane stamps on its flight ring while it does so.
 """
 
 from __future__ import annotations
@@ -20,6 +20,7 @@ import pytest
 
 from dragonfly2_tpu.dataset import LoaderOptions, PodShardedLoader
 from dragonfly2_tpu.dataset.device_feed import DeviceFeed
+from dragonfly2_tpu.dataset.shard_reader import RANGE_READS
 from dragonfly2_tpu.pkg import flight
 from dragonfly2_tpu.pkg.testing import start_range_origin
 from dragonfly2_tpu.source.client import default_registry
@@ -62,7 +63,8 @@ def landed(tmp_path_factory):
 
     shards = [make_shard(s) for s in range(SHARDS)]
     out = types.SimpleNamespace(shards=shards, runs={}, events={},
-                                origin_bytes={}, tasks={})
+                                origin_bytes={}, tasks={}, reads={})
+    out.local_reads_before = RANGE_READS.labels("local")._value.get()
 
     async def run():
         origins = [await start_range_origin(content) for content, _ in shards]
@@ -99,6 +101,8 @@ def landed(tmp_path_factory):
                         stats["bytes"] for _, _, stats in origins) - served
                     out.tasks[hosts, host] = [
                         t.metadata.content_length for t in storage.tasks()]
+                    out.reads[hosts, host] = [
+                        r.fetcher.stats for r in loader.readers]
                     out.plan = loader.plan(0) if hosts == 1 else out.plan
                     storage.close()
         finally:
@@ -163,24 +167,33 @@ def test_the_loaders_plan_names_the_references_samples(landed):
 
 
 @pytest.mark.parametrize("hosts, host", HOSTS)
-def test_a_shard_is_pulled_once_and_a_sample_is_one_local_ranged_task(
+def test_a_shard_is_pulled_once_and_a_sample_is_a_read_of_this_store(
         landed, hosts, host):
     """prepare() streamed each shard whole through one task; after it the
-    origin served nothing more: every sample's span was imported from this
-    host's store, one ranged task a sample (its three members coalesce)."""
+    origin served nothing more and no task was made: the only tasks in the
+    store are the shards', every sample's one span (its three members
+    coalesce) was read out of this host's store, and counted so."""
     assert landed.origin_bytes[hosts, host] == 0
-    whole = sorted(len(content) for content, _ in landed.shards)
-    sizes = sorted(landed.tasks[hosts, host])
-    samples = len(sizes) - SHARDS
-    assert samples == sum(len(k) for k, _ in landed.runs[hosts, host])
-    assert sizes[-SHARDS:] == whole and max(sizes[:samples]) < 4096
+    assert sorted(landed.tasks[hosts, host]) == sorted(
+        len(content) for content, _ in landed.shards)
+    samples = sum(len(k) for k, _ in landed.runs[hosts, host])
     reads = [e for e in landed.events[hosts, host] if e[0] == "feed_sample"]
     assert len(reads) == samples
     assert sorted(piece for _, piece, _, _ in reads) == list(range(samples))
     for _, _, aux, note in reads:
         fields = dict(part.split("=") for part in note.split())
-        assert fields["src"] == "local" and fields["tasks"] == "1"
-        assert 0 <= float(fields["move"]) + float(fields["read"]) <= aux
+        assert fields["src"] == "local" and fields["tasks"] == "0"
+        assert float(fields["move"]) == 0 <= float(fields["read"]) <= aux
+        assert 0 < int(fields["bytes"]) < 4096
+    stats = landed.reads[hosts, host]
+    assert sum(s["local"] for s in stats) == samples
+    assert all(s["cold"] == s["reuse"] == 0 for s in stats)
+
+
+def test_the_counter_of_span_reads_gains_local_by_every_sample(landed):
+    samples = sum(len(k) for run in landed.runs.values() for k, _ in run)
+    assert RANGE_READS.labels("local")._value.get() \
+        - landed.local_reads_before >= samples
 
 
 @pytest.mark.parametrize("hosts, host", HOSTS)
