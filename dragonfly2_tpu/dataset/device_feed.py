@@ -9,6 +9,21 @@ against host checksums (the same verify-on-land contract as the
 materializes as a ``(batch, record_bytes)`` uint8 device array in one
 fused assembly dispatch.
 
+A feed has ONE landing geometry for its whole life: ``batch_size`` rows of
+``record_bytes`` rounded up to words, staged ``min(batch_size, 64)`` rows a
+stack. Every batch's sink is built for that, whatever the batch holds, so
+the assembly and the record view are the programs the first batch
+compiled. The epoch's short last batch of ``n`` records lands them in slots
+``0..n-1`` and the slots ``n..batch_size-1`` as empty records (all-zero
+rows, the host checksum of nothing; ``verify()`` covers them too), and its
+array is the first ``n`` rows of the full view, cut on the device by a
+plain XLA slice: the one program an epoch's end may still compile, and a
+small one. A record is handed to the sink at its own length and padded
+where it is written, in its row of the reused staging stack (one copy of
+the record, one zero fill of the row's rest, a checksum of the record's own
+words: zeros add nothing to sum32 / xor32); the NumPy path pads where it
+builds its array, so both give rows that are zero beyond the record.
+
 On a CPU-only JAX backend (``JAX_PLATFORMS=cpu``) — or with no usable
 jax at all — the feed degrades to plain NumPy batches (``force_hbm=True``
 keeps the sink path for tests and CPU-backend verification). A device
@@ -22,8 +37,11 @@ Spans, on the ring handed in as ``flight`` (the loader's,
 ``PodShardedLoader.flight``; pkg/flight.py says what each carries):
 ``feed_wait`` a batch, the consumer side's wait for its samples;
 ``feed_batch`` a batch, first record staged -> as_record_batch
-dispatched; and the batch's HBMSink stamps its own ``sink_*`` steps there
-with ``batch=<k>`` leading the note.
+dispatched, its note naming the records (``n=``) beside the rows that were
+landed and put (``rows=``, ``put=``: the geometry's); and the batch's
+HBMSink stamps its own ``sink_*`` steps there with ``batch=<k>`` leading the
+note. ``dataset_device_short_batches_total`` counts the device batches with
+``n < rows``: one an epoch whose samples are no multiple of ``batch_size``.
 """
 
 from __future__ import annotations
@@ -44,6 +62,11 @@ DEVICE_FALLBACKS = metrics.counter(
     "dataset_device_fallbacks_total",
     "Device feeds that fell to NumPy batches after the device path failed, "
     "by the exception that ended it", ("cause",))
+DEVICE_SHORT_BATCHES = metrics.counter(
+    "dataset_device_short_batches_total",
+    "Device batches of fewer records than the feed's landing geometry has "
+    "rows (an epoch's last): landed through the full batch's programs, the "
+    "rest of the rows empty, and cut to their records on the device")
 
 
 class DeviceFeedError(Exception):
@@ -79,7 +102,10 @@ class DeviceFeed:
     ``record_bytes``: every record must be exactly this long, unless
     ``pad=True`` (shorter records are zero-padded; longer ones always
     raise — silent truncation would corrupt training data). The final
-    short batch is yielded unless ``drop_last``.
+    short batch is yielded unless ``drop_last``. On the device path it
+    costs a full batch's landing (``batch_size`` rows put, the rows past its
+    records empty) and one slice program for its row count: no assembly or
+    view compile, those are the full batch's (the module docstring).
     """
 
     def __init__(self, ext: str, record_bytes: int, batch_size: int, *,
@@ -109,45 +135,55 @@ class DeviceFeed:
             raise DeviceFeedError(
                 f"sample {sample.get('__key__')!r}: {self.ext} is "
                 f"{len(data)}B > record_bytes={self.record_bytes}")
-        if len(data) < self.record_bytes:
-            if not self.pad:
-                raise DeviceFeedError(
-                    f"sample {sample.get('__key__')!r}: {self.ext} is "
-                    f"{len(data)}B != record_bytes={self.record_bytes} "
-                    "(pass pad=True to zero-pad)")
-            data = data + b"\0" * (self.record_bytes - len(data))
+        if len(data) < self.record_bytes and not self.pad:
+            raise DeviceFeedError(
+                f"sample {sample.get('__key__')!r}: {self.ext} is "
+                f"{len(data)}B != record_bytes={self.record_bytes} "
+                "(pass pad=True to zero-pad)")
+        # At its own length: the row it lands in is where it is padded.
         return data
 
     def _land_hbm(self, records: list[bytes]) -> "tuple[object, str]":
+        import jax
+
         from dragonfly2_tpu.ops.hbm_sink import HBMSink
 
+        # The feed's one geometry, whatever this batch holds.
+        rows, n = self.batch_size, len(records)
         padded = self.record_bytes + ((-self.record_bytes) % 4)
         ring, lead = self.flight, f"batch={self.batch_no}"
         sink = HBMSink(
-            padded * len(records), padded, device=self.device,
-            batch_pieces=min(len(records), 64),
+            padded * rows, padded, device=self.device,
+            batch_pieces=min(rows, 64),
             stamp=None if ring is None else (
                 lambda code, piece, ms, note="": ring.record(
                     code, piece, ms, f"{lead} {note}" if note else lead)))
         t0 = time.perf_counter()
         for i, rec in enumerate(records):
             sink.land_piece(i, rec)
+        for i in range(n, rows):
+            sink.land_piece(i, b"")     # an empty record: an all-zero row
         sink.flush()
         t1 = time.perf_counter()
-        sink.verify()   # on-device checksums vs host values
+        sink.verify()   # on-device checksums vs host values, every slot
         t2 = time.perf_counter()
-        arr = sink.as_record_batch(len(records), self.record_bytes)
+        arr = sink.as_record_batch(rows, self.record_bytes)
+        if n < rows:
+            arr = jax.lax.slice_in_dim(arr, 0, n)
+            DEVICE_SHORT_BATCHES.inc()
         t3 = time.perf_counter()
-        DATASET_BYTES.labels("device").inc(padded * len(records))
-        return arr, (f"put={padded * len(records)} "
+        DATASET_BYTES.labels("device").inc(padded * rows)
+        return arr, (f"put={padded * rows} "
                      f"stage={(t1 - t0) * 1e3:.3f} "
                      f"verify={(t2 - t1) * 1e3:.3f} view={(t3 - t2) * 1e3:.3f}")
 
     def _land_numpy(self, records: list[bytes]):
         import numpy as np
 
-        return np.frombuffer(b"".join(records), dtype=np.uint8).reshape(
-            len(records), self.record_bytes)
+        arr = np.zeros((len(records), self.record_bytes), np.uint8)
+        for row, rec in zip(arr, records):
+            row[:len(rec)] = np.frombuffer(rec, np.uint8)
+        return arr
 
     def _fall_back(self, e: Exception) -> None:
         """The device path failed: host batches from here on, the input
@@ -181,6 +217,7 @@ class DeviceFeed:
                 flightlib.EV_FEED_BATCH, self.batch_no,
                 (time.perf_counter() - t0) * 1000.0,
                 f"path={path} n={len(records)} "
+                f"rows={self.batch_size if path == 'hbm' else len(records)} "
                 f"payload={payload} {steps}")
         self.batch_no += 1
         return DeviceBatch(keys=keys, array=arr, on_device=path == "hbm",
@@ -204,7 +241,7 @@ class DeviceFeed:
             keys.append(sample.get("__key__", ""))
             shards.append(sample.get("__shard__", ""))
             records.append(self._record(sample))
-            payload += len(sample[self.ext])
+            payload += len(records[-1])
             if len(records) == self.batch_size:
                 self._stamp_wait(waited, len(records))
                 yield self._land(keys, shards, records, payload)

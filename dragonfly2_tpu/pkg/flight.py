@@ -178,10 +178,14 @@ EV_FEED_SAMPLE = 53
 EV_FEED_WAIT = 54
 # feed_batch: a batch's landing, first record staged -> last flushed ->
 # verified on the device -> as_record_batch dispatched (piece = batch; note =
-# "path=<hbm|numpy> n=<records> payload=<bytes> put=<bytes> stage=<ms>
-# verify=<ms> view=<ms>"). The feed's HBMSink stamps its sink_stage,
-# sink_checksum, sink_put, sink_assemble and sink_compile on the same ring
-# with "batch=<k>" leading their note.
+# "path=<hbm|numpy> n=<records> rows=<rows> payload=<bytes> put=<bytes>
+# stage=<ms> verify=<ms> view=<ms>": ``rows`` the rows landed, on the device
+# path the feed's one geometry (batch_size, the epoch's short last batch
+# too: its rows past ``n`` are empty records and ``view`` holds its slice),
+# ``put`` those rows' padded bytes; on the NumPy path ``n`` and 0). The
+# feed's HBMSink stamps its sink_stage, sink_checksum (one a row, the empty
+# ones too), sink_put, sink_assemble and sink_compile on the same ring with
+# "batch=<k>" leading their note.
 EV_FEED_BATCH = 55
 
 EVENT_NAMES = {

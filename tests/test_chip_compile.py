@@ -420,6 +420,20 @@ def test_record_batch_view(one_chip):
     assert out >= records * (piece - 3) and temp <= 1.05 * out
 
 
+@pytest.mark.parametrize("n", [4, 60, 204])
+def test_a_short_batch_is_a_slice_of_the_full_view(one_chip, n):
+    """The one program an epoch's end may compile (DeviceFeed._land_hbm): the
+    first ``n`` rows of the feed cell's (256, 256 KiB) batch, whatever ``n``
+    is a multiple of, with no temporary."""
+    import jax
+    import jax.numpy as jnp
+
+    arg, out, temp = _memory(
+        lambda batch: jax.lax.slice_in_dim(batch, 0, n),
+        _spec((256, 256 * 1024), jnp.uint8, one_chip))
+    assert n * 256 * 1024 <= out <= arg and temp == 0
+
+
 @pytest.mark.parametrize("piece_mib", [32, 4])
 def test_hot_swap_gate_reads_bytes_in_place(one_chip, piece_mib):
     import jax.numpy as jnp

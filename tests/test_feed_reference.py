@@ -211,18 +211,21 @@ def test_the_ring_carries_every_batch_and_its_sinks_steps(landed, hosts,
             batches, sizes, reference(landed, hosts, host)):
         fields = dict(part.split("=") for part in note.split())
         assert fields["path"] == "hbm" and int(fields["n"]) == n
-        assert int(fields["put"]) == padded * n
+        # What was landed and put is the feed's one geometry, BATCH rows,
+        # the short last batch's too: its rows past n are empty records.
+        assert int(fields["rows"]) == BATCH
+        assert int(fields["put"]) == padded * BATCH
         steps = [float(fields[s]) for s in ("stage", "verify", "view")]
         assert all(s >= 0 for s in steps) and sum(steps) <= aux + 1e-6
         # The batch's own sink stamped its steps under the batch's number:
-        # a stage and a checksum a record, a put a stack, one assembly.
+        # a stage and a checksum a row, a put a stack, one assembly.
         mine = [e for e in events if e[3].split(" ")[0] == f"batch={k}"]
         names = [e[0] for e in mine]
-        assert names.count("sink_checksum") == n
-        assert names.count("sink_put") == -(-n // 64)
+        assert names.count("sink_checksum") == BATCH
+        assert names.count("sink_put") == -(-BATCH // 64)
         assert names.count("sink_assemble") == 1
         assert sorted(p for name, p, _, _ in mine
-                      if name == "sink_checksum") == list(range(n))
+                      if name == "sink_checksum") == list(range(BATCH))
 
 
 def test_payload_in_the_ring_is_the_records_bytes_before_padding(landed):
