@@ -17,6 +17,7 @@ No reference analog: Dragonfly2's dfget terminates at the filesystem
 
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 
@@ -829,62 +830,109 @@ class HotSwapResult:
     task_id: str
     content_length: int
     generation: int
-    buffer: object                  # uint8 device array (np on fallback)
+    buffer: object                  # uint32 device words, whole pieces
+                                    # (a uint8 np array on fallback)
     tensors: dict
     on_device: bool
     flipped: bool
     reused_device_bytes: int        # HBM->HBM copied from the live buffer
-    staged_bytes: int               # host->device staged (fetched chunks)
+    staged_bytes: int               # host->device staged from the landing
     stats: dict                     # delta resolver accounting (may be {})
 
 
-def _read_store_span(store, start: int, length: int) -> bytes:
-    """Pooled read of [start, start+length) of a completed store."""
+def _host_piece_checksums(store) -> dict[int, tuple[int, int]]:
+    """checksum_numpy over every piece of the landed disk copy — the
+    host side of the hot-swap verify gate. Every piece is read into ONE
+    pooled buffer: a fresh 32 MiB ``bytes`` a piece (``read_piece``) pays
+    its page faults again or not by the allocator's state, 0.9 to 5.9 s
+    for a shard's 55 pieces on the chip's host (PERF.md section 6,
+    PR 49)."""
+    from dragonfly2_tpu.ops.checksum import checksum_numpy
     from dragonfly2_tpu.storage.local_store import (
         acquire_read_buffer,
         release_read_buffer,
     )
 
-    buf = acquire_read_buffer(length)
-    try:
-        with store:
-            store.read_into(start, length, buf)
-        return bytes(buf[:length])
-    finally:
-        release_read_buffer(buf)
-
-
-def _host_piece_checksums(store) -> dict[int, tuple[int, int]]:
-    """checksum_numpy over every piece of the landed disk copy — the
-    host side of the hot-swap verify gate."""
-    from dragonfly2_tpu.ops.checksum import checksum_numpy
-
     out: dict[int, tuple[int, int]] = {}
     with store:
-        for rec in store.get_pieces():
-            out[rec.num] = checksum_numpy(store.read_piece(rec.num))
+        pieces = store.get_pieces()
+        buf = acquire_read_buffer(
+            max((rec.size for rec in pieces), default=0) + 3)
+        try:
+            for rec in pieces:
+                padded = rec.size + (-rec.size) % 4
+                store.read_into(rec.offset, rec.size, buf)
+                buf[rec.size:padded] = bytes(padded - rec.size)
+                out[rec.num] = checksum_numpy(buf[:padded])
+        finally:
+            release_read_buffer(buf)
     return out
 
 
-def _device_parts(new_m, base_m, store) -> tuple[list, int, int]:
-    """The assemble plan for the spare buffer: reused chunks as live-
-    buffer slices, fetched chunks as host bytes read from the VERIFIED
-    disk landing (never the wire). Returns (parts, reused, staged)."""
+def _swap_runs(new_m, base_m) -> list:
+    """The new version's content as runs ``[dst, src, length, reused]`` in
+    offset order, for ``hbm_sink.plan_swap``: reused chunks that follow one
+    another in the new version AND in the base are one run (versions that
+    replace tensors in place: a dozen runs, not 1,400 chunks), and so are
+    fetched chunks that follow one another."""
     from dragonfly2_tpu.delta.resolver import plan_delta
 
-    plan = plan_delta(new_m, base_m)
-    base_of = {c.offset: b for c, b in plan.reused}
-    parts: list = []
-    reused = staged = 0
+    base_of = {c.offset: b.offset for c, b in
+               plan_delta(new_m, base_m).reused}
+    runs: list = []
     for c in new_m.chunks:
-        b = base_of.get(c.offset)
-        if b is not None:
-            parts.append(("r", b.offset, b.length))
-            reused += c.length
+        src = base_of.get(c.offset)
+        last = runs[-1] if runs else None
+        if (last is not None and last[3] == (src is not None)
+                and (src is None or last[1] + last[2] == src)):
+            last[2] += c.length
         else:
-            parts.append(("f", _read_store_span(store, c.offset, c.length)))
-            staged += c.length
-    return parts, reused, staged
+            runs.append([c.offset, c.offset if src is None else src,
+                         c.length, src is not None])
+    return runs
+
+
+def _swap_on_device(store, live, plan, names, shardings, tf):
+    """The device half of a hot-swap, on a thread of its own: the words no
+    run holds staged from the verified landing, the new word buffer
+    assembled beside the live one, EVERY piece of it checked on the device
+    against the host's sums of the landing (the flip gate: a mismatch
+    raises ValueError), then the typed tensors cut from it and ready.
+    Each step is a span on the delta task's flight."""
+    import jax
+
+    from dragonfly2_tpu.ops import hbm_sink
+    from dragonfly2_tpu.ops import safetensors as st
+
+    meta = store.metadata
+    device = (next(iter(live.devices())) if live is not None
+              else jax.local_devices()[0])
+    span = hbm_sink.span
+    hbm_sink.watch_compiles()
+    with store:
+        with span(tf.record, flightlib.EV_SWAP_STAGE,
+                  len(plan.slabs)) as step:
+            slabs = hbm_sink.stage_swap(plan, store.read_into, device)
+            step.note = str(meta.content_length - plan.reused_bytes)
+        count, _ = hbm_sink.compiled()
+        with span(tf.record, flightlib.EV_SWAP_ASSEMBLE,
+                  bool(plan.live_segs) + len(slabs)):
+            words = hbm_sink.assemble_swap_words(live, plan, slabs, device)
+            del slabs
+        with span(tf.record, flightlib.EV_SWAP_VERIFY,
+                  meta.total_piece_count):
+            hbm_sink.verify_words_against_host(
+                words, meta.piece_size, _host_piece_checksums(store))
+        # The swap's two programs (the copy, the checksums) are one for a
+        # geometry: a compile here is a geometry's first swap.
+        hbm_sink.SWAP_ASSEMBLIES.labels(
+            "compiled" if hbm_sink.compiled()[0] > count else "cached").inc()
+    with span(tf.record, flightlib.EV_SWAP_VIEWS) as step:
+        tensors = st.load_from_words(words, meta.content_length,
+                                     names=names, shardings=shardings)
+        jax.block_until_ready(list(tensors.values()))
+        step.piece = len(tensors)
+    return words, tensors
 
 
 async def download_delta(daemon, url: str, *, base, hot=None,
@@ -900,32 +948,45 @@ async def download_delta(daemon, url: str, *, base, hot=None,
     any, comes from ``hot``). ``hot``: an ops.hbm_sink.DoubleBuffer;
     when given, the verified new generation is installed with one atomic
     flip, so a reader thread iterating ``hot.snapshot()`` only ever sees
-    complete old-or-new tensor sets.
+    complete old-or-new tensor sets. The live buffer is the generation's
+    uint32 words (``DeviceResult.as_words()``, a HotSwapResult's
+    ``buffer``); anything else is no source, and the new generation is
+    staged whole.
 
     The wire side rides the delta plane (TaskManager.start_delta_task):
     only changed chunks cross DCN, and the patched disk landing is
-    digest-verified and served to peers. The device side then copies
-    reused chunks HBM->HBM out of the live buffer, stages only fetched
-    chunks from the disk landing, and verifies the assembled buffer
-    on-device against the disk copy's piece checksums BEFORE the flip.
+    digest-verified and served to peers. The device side then asks the
+    sink manager's admission as every client pull does, copies the reused
+    runs HBM->HBM out of the live words, stages only what no run holds
+    from the disk landing (``hbm_sink.plan_swap``: one compiled program a
+    geometry, whichever tensors a version changed), and verifies EVERY
+    piece of the assembled buffer on-device against the disk copy's piece
+    checksums BEFORE the flip; the tensors are cut from the new words as a
+    landing's are (``ops/safetensors.load_from_words``) and are ready when
+    it flips.
     """
     import asyncio
 
     import numpy as np
 
     from dragonfly2_tpu.daemon.peer.task_manager import FileTaskRequest
+    from dragonfly2_tpu.delta.manifest import manifest_from_store
     from dragonfly2_tpu.delta.resolver import fetch_manifest
     from dragonfly2_tpu.ops import hbm_sink
     from dragonfly2_tpu.ops import safetensors as st
 
+    called = time.perf_counter()
     tm = daemon.task_manager
     base_task_id = base if isinstance(base, str) else base.task_id
-    live_u8 = None
+    live = None
     if hot is not None and hot.generation > 0:
-        live_u8 = hot.buffer()
+        live = hot.buffer()
     elif not isinstance(base, str):
-        live_u8 = (base.buffer if isinstance(base, HotSwapResult)
-                   else base.as_bytes_array())
+        live = (base.buffer if isinstance(base, HotSwapResult)
+                else base.as_words())
+    if isinstance(live, np.ndarray) or str(getattr(live, "dtype", "")) \
+            != "uint32":
+        live = None
 
     req = FileTaskRequest(
         url=url, output="",
@@ -943,89 +1004,86 @@ async def download_delta(daemon, url: str, *, base, hot=None,
     if store is None:
         raise DfError(Code.UnknownError, "delta task has no store")
     total = store.metadata.content_length
+    tf = tm.flight.task(final.task_id)
 
-    # Device plan: chunk-mapped when the live buffer + both manifests
-    # are at hand, whole-buffer staging otherwise.
-    parts = None
-    reused = staged = 0
-    if live_u8 is not None:
-        new_m = await fetch_manifest(tm, final.task_id)
-        base_store = tm.storage.find_completed_task(base_task_id)
-        base_m = (await fetch_manifest(tm, base_task_id)
-                  if base_store is not None else None)
-        if base_m is None and base_store is not None and new_m is not None:
-            from dragonfly2_tpu.delta.manifest import manifest_from_store
-
-            base_m = await asyncio.to_thread(
-                manifest_from_store, base_store, base_store.metadata.url,
-                new_m.params)
-        if new_m is not None and base_m is not None \
-                and base_m.params == new_m.params:
-            parts, reused, staged = await asyncio.to_thread(
-                _device_parts, new_m, base_m, store)
-    if parts is None:
-        parts = [("f", await asyncio.to_thread(
-            _read_store_span, store, 0, total))]
-        staged = total
-
-    on_device = True
-    try:
-        u8 = hbm_sink.assemble_delta_u8(live_u8, parts)
-    except Exception as e:
-        # Device trouble (OOM, runtime errors) degrades to a host
-        # buffer over the verified disk landing — the device_feed
-        # discipline: the pipeline must outlive a sink hiccup.
-        log.warning("delta device assembly failed; numpy fallback",
-                    task=final.task_id[:16], error=str(e)[:200])
-        u8 = np.frombuffer(await asyncio.to_thread(
-            _read_store_span, store, 0, total), dtype=np.uint8)
-        on_device = False
-        reused, staged = 0, total
-    if on_device:
-        # The flip gate: a verify MISMATCH is corruption, never a
-        # fallback — handing back a bad buffer would defeat
-        # verify-on-land exactly like the device sink path.
-        checks = await asyncio.to_thread(_host_piece_checksums, store)
-        piece_size = store.metadata.piece_size
-        if store.metadata.total_piece_count <= 1:
-            piece_size = (total + ((-total) % 4)) or 4
+    asked = time.perf_counter()
+    admission = (tm.device_sinks.admit() if tm.device_sinks is not None
+                 else contextlib.nullcontext())
+    async with admission:
+        planning = time.perf_counter()
+        tf.record(flightlib.EV_ADMIT_WAIT, -1, (planning - asked) * 1000.0)
+        # Device plan: the reused runs out of the live words when they and
+        # both manifests are at hand, whole-buffer staging otherwise.
+        runs, how = [[0, 0, total, False]], "whole"
+        if live is not None:
+            new_m = await fetch_manifest(tm, final.task_id)
+            base_store = tm.storage.find_completed_task(base_task_id)
+            base_m = (await fetch_manifest(tm, base_task_id)
+                      if base_store is not None else None)
+            built = (base_m is None and base_store is not None
+                     and new_m is not None)
+            if built:
+                base_m = await asyncio.to_thread(
+                    manifest_from_store, base_store,
+                    base_store.metadata.url, new_m.params)
+            if new_m is not None and base_m is not None \
+                    and base_m.params == new_m.params:
+                runs = _swap_runs(new_m, base_m)
+                how = "built" if built else "fetched"
+        on_device = True
         try:
-            await asyncio.to_thread(
-                hbm_sink.verify_u8_against_host, u8, piece_size, checks)
-        except ValueError as e:
+            plan = hbm_sink.plan_swap(
+                runs, total, max(1, store.metadata.total_piece_count)
+                * (store.metadata.piece_size // 4),
+                0 if live is None else int(live.shape[0]))
+            tf.record(flightlib.EV_SWAP_PLAN, plan.runs,
+                      (time.perf_counter() - planning) * 1000.0, how)
+            words, tensors = await asyncio.to_thread(
+                _swap_on_device, store, live, plan, names, shardings, tf)
+            reused, staged = plan.reused_bytes, total - plan.reused_bytes
+        except (st.SafetensorsError, DfError):
+            raise
+        except hbm_sink.SwapVerifyError as e:
+            # The flip gate: a verify MISMATCH is corruption, never a
+            # fallback — handing back a bad buffer would defeat
+            # verify-on-land exactly like the device sink path. The old
+            # generation stays live.
+            hbm_sink.SWAP_RESULTS.labels("refused").inc()
             raise DfError(Code.ClientPieceDownloadFail,
                           f"hot-swap verify failed: {e}")
+        except Exception as e:
+            # Device trouble (OOM, runtime errors) degrades to a host
+            # buffer over the verified disk landing — the device_feed
+            # discipline: the pipeline must outlive a sink hiccup.
+            log.warning("delta device assembly failed; numpy fallback",
+                        task=final.task_id[:16], error=str(e)[:200])
+            on_device = False
+            reused, staged = 0, total
 
-    head = np.asarray(u8[:min(total, 8)]).tobytes()
-    if len(head) < 8:
-        raise st.SafetensorsError("content shorter than the length prefix")
-    n = int.from_bytes(head, "little")
-    if 8 + n > total:
-        raise st.SafetensorsError("header length exceeds content")
-    header_dict, data_start = st.parse_header(
-        np.asarray(u8[:8 + n]).tobytes())
-    if on_device:
-        tensors = st.tensor_views(u8, header_dict, data_start, names)
-        if shardings:
-            unknown = [k for k in shardings if k not in tensors]
-            if unknown:
-                raise st.SafetensorsError(
-                    f"shardings reference tensors not loaded: {unknown}")
-            import jax
-
-            for k, sharding in shardings.items():
-                tensors[k] = jax.device_put(tensors[k], sharding)
-    else:
-        tensors = _numpy_views(u8, header_dict, data_start, names)
+    if not on_device:
+        words = np.empty((total,), np.uint8)
+        with store:
+            await asyncio.to_thread(store.read_into, 0, total,
+                                    memoryview(words))
+        header_dict, data_start = st.parse_header(bytes(
+            words[:8 + int.from_bytes(bytes(words[:8]), "little")]))
+        tensors = _numpy_views(words, header_dict, data_start, names)
 
     generation = 1
     flipped = False
     if hot is not None:
-        generation = hot.flip(u8, tensors)
+        generation = hot.flip(words, tensors)
         flipped = True
+    tf.record(flightlib.EV_SWAP_FLIP, generation,
+              (time.perf_counter() - called) * 1000.0,
+              "" if on_device else "fallback")
+    hbm_sink.SWAP_RESULTS.labels(
+        "flipped" if on_device else "fallback").inc()
+    hbm_sink.SWAP_BYTES.labels("hbm_reused").inc(reused)
+    hbm_sink.SWAP_BYTES.labels("staged").inc(staged)
     return HotSwapResult(
         task_id=final.task_id, content_length=total, generation=generation,
-        buffer=u8, tensors=tensors, on_device=on_device, flipped=flipped,
+        buffer=words, tensors=tensors, on_device=on_device, flipped=flipped,
         reused_device_bytes=reused, staged_bytes=staged,
         stats=dict(tm.delta_stats.get(final.task_id, {})))
 

@@ -71,10 +71,16 @@ class DaemonRangeFetcher:
 
     def __init__(self, task_manager, url: str, *, tag: str = "",
                  application: str = "", header: dict | None = None,
-                 pod_broadcast: bool = False):
+                 pod_broadcast: bool = False, digest: str = ""):
         self.tm = task_manager
         self.url = url
         self.tag = tag
+        # The whole object's digest where whoever pulled it whole named it
+        # by one: part of the parent's identity, so a host that holds the
+        # object under it (this one, a seed that was preheated) serves the
+        # span out of its store. A slice is never verified against it
+        # (``LocalTaskStore.completion_digest_applies``).
+        self.digest = digest
         # Extra task-identity fields for consumers whose spans must dedup
         # with other surfaces carrying them (the delta plane threads the
         # original request's application/header through so every host
@@ -105,7 +111,7 @@ class DaemonRangeFetcher:
 
         rng = Range.normalize_header(f"{start}-{end - 1}")
         req = FileTaskRequest(url=self.url, output="",
-                              meta=UrlMeta(tag=self.tag,
+                              meta=UrlMeta(digest=self.digest, tag=self.tag,
                                            application=self.application,
                                            header=dict(self.header),
                                            range=rng),

@@ -280,27 +280,32 @@ def _range_fetcher(tm, req):
     """Ranged-task fetcher for the delta spans: the dataset plane's
     DaemonRangeFetcher, parameterized so span task ids agree across
     every host running the same delta (tag/application/header ride
-    along; the whole-content digest is deliberately dropped — it cannot
-    name a slice)."""
+    along, and so does the whole-content digest: it verifies no slice,
+    but it is part of the new version's identity, and a host that holds
+    that version whole under it, the seed that landed it first, serves a
+    span out of its store and not from the origin again)."""
     from dragonfly2_tpu.dataset.shard_reader import DaemonRangeFetcher
 
     return DaemonRangeFetcher(
         tm, req.url, tag=req.meta.tag, application=req.meta.application,
-        header=dict(req.meta.header), pod_broadcast=req.pod_broadcast)
+        header=dict(req.meta.header), pod_broadcast=req.pod_broadcast,
+        digest=req.meta.digest)
 
 
 async def _resolve_manifests(tm, req, task_id: str, base_store, *,
                              params: CDCParams | None):
-    """(new_manifest, base_manifest) or None when the delta path is not
-    viable (no published manifest for the new version)."""
+    """(new_manifest, base_manifest, whether the base's was built here) or
+    None when the delta path is not viable (no published manifest for the
+    new version)."""
     new_m = await fetch_manifest(tm, task_id)
     if new_m is None:
         return None
     want = new_m.params
     base_id = base_store.metadata.task_id
     base_m = await fetch_manifest(tm, base_id)
-    if (base_m is None or base_m.params != want
-            or base_m.content_length != base_store.metadata.content_length):
+    built = (base_m is None or base_m.params != want
+             or base_m.content_length != base_store.metadata.content_length)
+    if built:
         base_m = await asyncio.to_thread(
             manifest_from_store, base_store, base_store.metadata.url, want)
         # Publish the freshly-built base manifest (best effort): the
@@ -313,7 +318,7 @@ async def _resolve_manifests(tm, req, task_id: str, base_store, *,
                         base=base_id[:16], error=describe(e))
     if params is not None and want != params:
         log.info("delta using published chunk params", task=task_id[:16])
-    return new_m, base_m
+    return new_m, base_m, built
 
 
 async def run_delta_task(tm, req, base_task_id: str, *,
@@ -360,6 +365,7 @@ async def run_delta_task(tm, req, base_task_id: str, *,
         async for p in _fallback(publish=True):
             yield p
         return
+    planning = time.perf_counter()
     manifests = await _resolve_manifests(tm, req, task_id, base_store,
                                          params=params)
     if manifests is None:
@@ -368,8 +374,14 @@ async def run_delta_task(tm, req, base_task_id: str, *,
         async for p in _fallback(publish=True):
             yield p
         return
-    new_m, base_m = manifests
+    new_m, base_m, built = manifests
     plan = plan_delta(new_m, base_m)
+    # The flight begins here, with the task: the manifests' ms lie before
+    # its first event, as an admission wait does.
+    tm.flight.task(task_id).record(
+        flightlib.EV_SWAP_PLAN, new_m.num_chunks,
+        (time.perf_counter() - planning) * 1000.0,
+        "built" if built else "fetched")
     if plan.reused_bytes == 0:
         log.info("zero chunk overlap with base; full download",
                  task=task_id[:16], base=base_task_id[:16])

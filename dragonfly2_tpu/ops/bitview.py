@@ -1,7 +1,7 @@
 """Typed views of landed bytes that the TPU compiler accepts at real sizes.
 
-The landed content is a flat uint32 word buffer (ops/hbm_sink.py) or, on
-the hot-swap path, a flat uint8 buffer. Reinterpreting either as another
+The landed content is a flat uint32 word buffer (a sink's, a hot-swapped
+generation's) or a caller's flat uint8 buffer. Reinterpreting either as another
 width with ``bitcast_convert_type`` gives the array a minor dimension of
 2 or 4, which the TPU's tiled layout pads to 128 lanes: 32-128x the
 tensor, refused above a few hundred MiB. A view is cut in one of two

@@ -936,98 +936,264 @@ def _chip_checksums_jit(words, *, mesh, axis_name: str, piece_words: int):
 # Double-buffer hot-swap (checkpoint-delta plane, delta/)
 #
 # A serving process keeps the LIVE checkpoint generation on device while
-# the next one assembles in a spare buffer: reused delta chunks are
-# device-to-device slices of the live buffer (they never leave HBM, let
-# alone re-cross DCN), fetched chunks are host-staged once, and the
-# verified result replaces the live generation with ONE atomic reference
-# swap — a reader always sees a complete (generation, buffer, tensors)
-# triple, never a mix.
+# the next one assembles beside it: the words of reused runs are copied
+# HBM -> HBM out of the live generation's word buffer (they never leave
+# HBM, let alone re-cross DCN), the rest is staged once from the verified
+# landing in the host's store, every piece of the result is checksummed on
+# the device against the host's sums, and only then does the result replace
+# the live generation with ONE atomic reference swap: a reader always sees
+# a complete (generation, words, tensors) triple, never a mix.
+#
+# The assembly is ONE program a geometry (``_swap_copy_jit``): the buffers'
+# lengths and the block are its shape, and what is copied from where is an
+# int32 operand (``segs``), so no version's set of changed tensors compiles
+# anything (a slice a chunk and one concatenate of them all was a program a
+# version: some 1,400 operands at the benchmark's shard). Buffers are
+# uint32 words, as every landing's are, seen as rows of 128: a step copies
+# ``block`` rows from the source to the new buffer under a word mask, so a
+# run may begin and end at any word. The new buffer is donated from call to
+# call: the live generation first, then each staging slab.
 # ---------------------------------------------------------------------- #
 
-def assemble_delta_u8(live_u8, parts):
-    """Assemble the next generation's uint8 content buffer.
+_SWAP_BLOCK_ROWS = 1024     # rows of 128 words a step copies: 512 KiB
+_SWAP_SLAB_ROWS = 65536     # a staging slab: 32 MiB of pooled host memory
 
-    ``parts`` is the new content in offset order, each element either
-    ``("r", src_offset, length)`` — a device-side slice of ``live_u8``
-    (a reused chunk at its OLD offset) — or ``("f", bytes)`` — a fetched
-    chunk's host bytes, staged to device here. One concatenate
-    materializes the buffer; reused bytes move HBM→HBM only."""
+SWAP_BYTES = metrics.counter(
+    "device_swap_bytes_total",
+    "Content bytes of hot-swapped generations by how they reached the new "
+    "buffer: copied HBM -> HBM out of the live generation (hbm_reused), or "
+    "read from the verified landing in the store and put (staged); the two "
+    "sum to the content", ("how",))
+SWAP_RESULTS = metrics.counter(
+    "device_swap_total",
+    "Hot-swaps of the client API by how they ended: the verified generation "
+    "installed (flipped; returned unflipped where the caller holds no "
+    "DoubleBuffer), refused by the verify gate with the old generation "
+    "still live (refused), or handed back as a host buffer after the device "
+    "path failed (fallback)", ("result",))
+SWAP_ASSEMBLIES = metrics.counter(
+    "device_swap_assemblies_total",
+    "Hot-swap assemblies by whether any of their programs (the copy, the "
+    "checksums) was compiled for the swap: a geometry's first (compiled) or "
+    "not (cached)", ("how",))
+
+
+def _pow2_floor(n: int) -> int:
+    return 1 << (max(1, n).bit_length() - 1)
+
+
+def _pow2_ceil(n: int) -> int:
+    return 1 << (max(1, n) - 1).bit_length()
+
+
+class SwapPlan:
+    """What ``plan_swap`` made of a delta's runs: the segments that copy
+    the live generation's words, and the slabs that bring the rest."""
+
+    __slots__ = ("block", "out_rows", "slab_rows", "live_segs", "slabs",
+                 "reused_bytes", "runs")
+
+    def __init__(self, block: int, out_rows: int, slab_rows: int):
+        self.block = block
+        self.out_rows = out_rows
+        self.slab_rows = slab_rows
+        self.live_segs: list = []      # (out row, src row, lo, hi)
+        # Each slab: (reads, segs); a read is (word in the slab, byte of
+        # the content, bytes).
+        self.slabs: list = []
+        self.reused_bytes = 0
+        self.runs = 0
+
+
+def plan_swap(runs, total: int, padded_words: int,
+              live_words: int = 0) -> SwapPlan:
+    """The next generation's buffer (``padded_words`` uint32 words holding
+    ``total`` content bytes) as copies of whole words: ``runs`` is the new
+    content in offset order, ``(dst, src, length, reused)`` in bytes, a
+    reused run lying at ``src`` of the live generation's ``live_words``
+    words. A word is copied out of the live buffer where it lies wholly
+    inside ONE reused run whose ``src - dst`` is whole rows (a multiple of
+    512 bytes: versions that replace tensors in place have 0) and the copy
+    stays inside both buffers; every other word of the content (a run's
+    first and last partial word, a run shifted by less than a row, all of
+    it where nothing is live) is read from the verified landing in the
+    store. Pure arithmetic: no device, no store."""
+    lanes = _LANES
+    out_rows = -(-padded_words // lanes)
+    live_rows = live_words // lanes if live_words % lanes == 0 else 0
+    block = _pow2_floor(min(_SWAP_BLOCK_ROWS, out_rows,
+                            live_rows or out_rows))
+    plan = SwapPlan(block, out_rows, max(4 * block, min(
+        _SWAP_SLAB_ROWS, _pow2_ceil(out_rows))))
+    span = block * lanes
+    content_words = -(-total // 4)
+    staged: list = []       # [a, b) words of the new buffer, in order
+    at = 0
+    for dst, src, length, reused in runs:
+        a, b = -(-dst // 4), min((dst + length) // 4, total // 4)
+        dw, rem = divmod(src - dst, 4 * lanes)
+        dw *= lanes
+        if not (reused and live_rows and rem == 0 and a < b
+                and 0 <= a + dw and b + dw <= live_words):
+            continue
+        drow = dw // lanes
+        low = max(0, -drow)
+        high = min(out_rows - block, live_rows - block - drow)
+        segs = []
+        x = a
+        while x < b:
+            row = min(x // lanes, high)
+            if row < low:
+                break
+            y = min(b, (row + block) * lanes)
+            segs.append((row, row + drow, x - row * lanes, y - row * lanes))
+            x = y
+        if x < b:
+            continue        # no block of the live buffer holds it: staged
+        plan.runs += 1
+        if a > at:
+            staged.append((at, a))
+        at = b
+        plan.live_segs += segs
+        plan.reused_bytes += 4 * (b - a)
+    if content_words > at:
+        staged.append((at, content_words))
+    # The staged words packed into slabs: a piece lies at the lane it has
+    # in the new buffer, a block clear of either end, so that a step's
+    # window of the slab never leaves it.
+    slab_words = plan.slab_rows * lanes
+    reads: list = []
     segs = []
-    for part in parts:
-        if part[0] == "r":
-            _, src, length = part
-            segs.append(live_u8[src:src + length])
-        else:
-            staged = np.frombuffer(part[1], dtype=np.uint8)
-            # Beside the live generation, not on the default device.
-            segs.append(jnp.asarray(staged) if live_u8 is None
-                        else jax.device_put(staged, live_u8.sharding))
-    if not segs:
-        return jnp.zeros((0,), jnp.uint8)
-    return jnp.concatenate(segs) if len(segs) > 1 else segs[0]
+    q = span
+    for a, b in staged:
+        while a < b:
+            q += (a - q) % lanes
+            take = min(b - a, slab_words - span - q)
+            if take <= 0:
+                plan.slabs.append((reads, segs))
+                reads, segs, q = [], [], span
+                continue
+            reads.append((q, 4 * a, min(4 * (a + take), total) - 4 * a))
+            x = a
+            while x < a + take:
+                row = min(x // lanes, out_rows - block)
+                y = min(a + take, (row + block) * lanes)
+                segs.append((row, row + (q - a) // lanes, x - row * lanes,
+                             y - row * lanes))
+                x = y
+            q += take
+            a += take
+    if reads:
+        plan.slabs.append((reads, segs))
+    return plan
 
 
-def _byte_lane_checksums(rows):
-    """(sum32, xor32) as int32 scalars over a (n, 4k) uint8 block read
-    as little-endian words: byte lane k of every word folds on its own
-    and is shifted into place, so no word array is ever formed."""
-    total = jnp.int32(0)
-    fold = jnp.int32(0)
-    for k in range(4):
-        lane = rows[:, k::4].astype(jnp.int32)
-        total = total + (jnp.sum(lane, dtype=jnp.int32) << (8 * k))
-        fold = fold | (jax.lax.reduce(
-            lane, jnp.int32(0), jax.lax.bitwise_xor, (0, 1)) << (8 * k))
-    return total, fold
+def _seg_table(segs, src, block: int) -> "tuple[np.ndarray, np.int32]":
+    """A plan's segments as the operand of ``_swap_copy_jit``: int32 rows
+    padded to a power of two (the table's shape is part of the program, the
+    count a scalar beside it), at least twice the blocks ``src`` holds: a
+    source copied whole with a run's end in every other block still takes
+    the table that a dozen runs do."""
+    table = np.zeros((_pow2_ceil(max(len(segs), 2 * src.shape[0]
+                                     // (block * _LANES))), 4), np.int32)
+    if segs:
+        table[:len(segs)] = segs
+    return table, np.int32(len(segs))
 
 
-@functools.partial(jax.jit, static_argnames=("piece_size",))
-def _u8_checksums_jit(u8, piece_size: int):
-    """Per-piece (sum32[n], xor32[n]) of a flat uint8 buffer, one piece
-    per loop iteration: temporaries of the order of a piece. (Packing the
-    bytes into words first pads a (n, 4) array to 128 lanes, 32x the
-    buffer.)"""
-    full, tail = divmod(u8.shape[0], piece_size)
-    row = 512               # four 128-lane rows of words, where it divides
-    while piece_size % row:
-        row //= 2
-    sums, xors = [], []
-    if full:
-        def one(i):
-            piece = jax.lax.dynamic_slice(u8, (i * piece_size,),
-                                          (piece_size,))
-            return _byte_lane_checksums(piece.reshape(-1, row))
+@functools.partial(jax.jit, static_argnames=("block",), donate_argnums=(0,))
+def _swap_copy_jit(out, src, segs, count, *, block: int):
+    """``count`` segments of ``src`` copied into ``out``, which is donated:
+    segment ``(o, s, lo, hi)`` puts words ``lo..hi`` of the ``block`` rows
+    from row ``s`` of ``src`` over the same words of the rows from ``o`` of
+    ``out``. Both are flat uint32 buffers of whole rows of 128 words."""
+    rows = out.reshape(-1, _LANES)
+    src = src.reshape(-1, _LANES)
+    at = (jax.lax.broadcasted_iota(jnp.int32, (block, _LANES), 0) * _LANES
+          + jax.lax.broadcasted_iota(jnp.int32, (block, _LANES), 1))
 
-        s, x = jax.lax.map(one, jnp.arange(full, dtype=jnp.int32))
-        sums.append(s)
-        xors.append(x)
-    if tail:
-        rest = jnp.pad(u8[full * piece_size:], (0, (-tail) % row))
-        s, x = _byte_lane_checksums(rest.reshape(-1, row))
-        sums.append(s[None])
-        xors.append(x[None])
-    return (jax.lax.bitcast_convert_type(jnp.concatenate(sums), jnp.uint32),
-            jax.lax.bitcast_convert_type(jnp.concatenate(xors), jnp.uint32))
+    def place(k, rows):
+        o, s, lo, hi = segs[k, 0], segs[k, 1], segs[k, 2], segs[k, 3]
+        new = jax.lax.dynamic_slice(src, (s, 0), (block, _LANES))
+        old = jax.lax.dynamic_slice(rows, (o, 0), (block, _LANES))
+        return jax.lax.dynamic_update_slice(
+            rows, jnp.where((at >= lo) & (at < hi), new, old), (o, 0))
+
+    return jax.lax.fori_loop(0, count, place, rows).reshape(-1)
 
 
-def verify_u8_against_host(u8, piece_size: int,
-                           host_checksums: "dict[int, tuple[int, int]]") -> None:
+def stage_swap(plan: SwapPlan, read_into, device) -> list:
+    """The plan's slabs on ``device``: each filled from the store
+    (``read_into(start, length, buffer)``) in pooled host memory, put, and
+    given back once the runtime has read it."""
+    slabs = []
+    nbytes = plan.slab_rows * _LANES * 4
+    for reads, _ in plan.slabs:
+        view = _STAGING.acquire(nbytes)
+        try:
+            for q, start, length in reads:
+                read_into(start, length, view[4 * q:4 * q + length])
+                view[4 * q + length:4 * q + length + (-length) % 4] = \
+                    bytes((-length) % 4)
+            slabs.append(jax.block_until_ready(
+                _put(np.frombuffer(view, np.uint32), device)))
+        finally:
+            _give_back(view)
+    return slabs
+
+
+def assemble_swap_words(live, plan: SwapPlan, slabs: list, device):
+    """The next generation's word buffer: zeros, then the live generation's
+    reused words, then each slab's, one dispatch each of the one program."""
+    out = jnp.zeros((plan.out_rows * _LANES,), jnp.uint32, device=device)
+    if plan.live_segs:
+        out = _swap_copy_jit(
+            out, live, *_seg_table(plan.live_segs, live, plan.block),
+            block=plan.block)
+    for slab, (_, segs) in zip(slabs, plan.slabs):
+        out = _swap_copy_jit(out, slab, *_seg_table(segs, slab, plan.block),
+                             block=plan.block)
+    return jax.block_until_ready(out)
+
+
+@functools.partial(jax.jit, static_argnames=("piece_words",))
+def _words_checksums_jit(words, *, piece_words: int):
+    """Per-piece (sum32[n], xor32[n]) of a flat word buffer of whole
+    pieces, one piece per loop iteration: temporaries of the order of a
+    piece, and no bound but the device's memory."""
+    def one(i):
+        piece = jax.lax.dynamic_slice(words, (i * piece_words,),
+                                      (piece_words,))
+        s, x = _chunk_checksums_xla(piece, piece_words)
+        return s[0], x[0]
+
+    return jax.lax.map(one, jnp.arange(words.shape[0] // piece_words,
+                                       dtype=jnp.int32))
+
+
+class SwapVerifyError(ValueError):
+    """The new generation's words differ from the verified landing."""
+
+
+def verify_words_against_host(words, piece_size: int,
+                              host_checksums: "dict[int, tuple[int, int]]") -> None:
     """On-device verification gate for a hot-swap flip: per-piece
-    (sum32, xor32) of the device buffer compared against host-side values
-    (checksum_numpy over the disk copy's pieces). Raises ValueError
-    naming the first mismatching piece; the flip must not happen."""
+    (sum32, xor32) of the device's word buffer (whole pieces, zeros past
+    the content) compared against host-side values (checksum_numpy over
+    the disk copy's pieces). Raises SwapVerifyError (a ValueError) naming
+    the first mismatching piece; the flip must not happen."""
     if piece_size % 4:
         raise ValueError(f"piece size {piece_size} not 4-byte aligned")
-    bitview.check_u8_indexable(u8)
-    if u8.shape[0] == 0:
-        sums = xors = np.zeros((1,), np.uint32)
-    else:
-        sums, xors = (np.asarray(c)
-                      for c in _u8_checksums_jit(u8, piece_size))
+    piece_words = piece_size // 4
+    if words.shape[0] % piece_words:
+        raise ValueError(f"{words.shape[0]} words are no whole pieces of "
+                         f"{piece_words}")
+    sums, xors = (np.asarray(c) for c in _words_checksums_jit(
+        words, piece_words=piece_words))
     for num, (want_s, want_x) in sorted(host_checksums.items()):
         have = (int(sums[num]), int(xors[num]))
         if have != (want_s, want_x):
-            raise ValueError(
+            raise SwapVerifyError(
                 f"piece {num} corrupt in spare buffer: "
                 f"sum {have[0]:#x}!={want_s:#x} "
                 f"xor {have[1]:#x}!={want_x:#x}")
@@ -1054,7 +1220,7 @@ class DoubleBuffer:
         return self._state[0]
 
     def snapshot(self) -> tuple:
-        """(generation, buffer_u8, tensors) — one consistent triple."""
+        """(generation, words, tensors) — one consistent triple."""
         return self._state
 
     def tensors(self) -> dict:
@@ -1064,8 +1230,10 @@ class DoubleBuffer:
         return self._state[1]
 
     def flip(self, buffer, tensors: dict) -> int:
-        """Install the next generation. Callers flip ONLY verified
-        buffers (verify_u8_against_host / HBMSink.verify)."""
+        """Install the next generation: its uint32 word buffer (whole
+        pieces, as ``HBMSink.as_words``) and the tensors cut from it.
+        Callers flip ONLY verified buffers (verify_words_against_host /
+        HBMSink.verify)."""
         gen = self._state[0] + 1
         self._state = (gen, buffer, dict(tensors))
         return gen

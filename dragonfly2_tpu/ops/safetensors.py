@@ -4,8 +4,8 @@ The north-star payload is a sharded safetensors checkpoint. Once the P2P
 fabric lands the file in HBM (ops/hbm_sink.py), this module turns it into
 named tensors WITHOUT a host round trip: the 8-byte header length and the
 JSON header are fetched to host (tiny), and each tensor is cut from the
-device-resident buffer — the sink's uint32 words, or a uint8 buffer on the
-hot-swap path — by ops/bitview.py, whose forms the TPU compiler accepts
+device-resident buffer — the sink's uint32 words, as a hot-swapped
+generation's are, or a caller's uint8 buffer — by ops/bitview.py, whose forms the TPU compiler accepts
 at checkpoint-shard sizes, the tensors of one dtype and shape by one
 dispatch.
 
@@ -194,8 +194,15 @@ def load_from_sink(sink, *, names: list[str] | None = None,
     maps tensor name → jax.sharding.Sharding; matching tensors are
     device_put to their sharding (device-to-device over ICI on a slice),
     the rest stay on the sink's device."""
-    words = sink.as_words()
-    total = sink.content_length
+    return load_from_words(sink.as_words(), sink.content_length,
+                           names=names, shardings=shardings)
+
+
+def load_from_words(words, total: int, *, names: list[str] | None = None,
+                    shardings: dict | None = None) -> dict[str, jax.Array]:
+    """Named tensors from ``total`` content bytes in a flat uint32 device
+    buffer (a sink's ``as_words()``, a hot-swapped generation's words):
+    what ``load_from_sink`` does with a sink's."""
     # Header prefix to host: 8 bytes, then exactly the header. Two tiny
     # fetches instead of guessing a prefix size.
     n = int.from_bytes(bitview.host_bytes(words, 0, min(8, total)),

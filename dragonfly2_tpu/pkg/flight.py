@@ -187,6 +187,30 @@ EV_FEED_WAIT = 54
 # ones too), sink_put, sink_assemble and sink_compile on the same ring with
 # "batch=<k>" leading their note.
 EV_FEED_BATCH = 55
+# A hot-swap of the client API (client/device.py ``download_delta``), on the
+# delta task's flight: each span ONE event at its end with aux = its ms, as
+# the sink_* spans. swap_plan is stamped twice where the delta proper ran:
+# by the resolver as the task starts (both manifests fetched over the fabric,
+# or the base's built from its store: note = "fetched" | "built"; piece =
+# chunks of the new version) and by the device half (the manifests read
+# again from this store, ``plan_delta``, ``plan_swap``; piece = runs copied
+# out of the live generation, note as above, "whole" where nothing is
+# reused). Then, in order: swap_stage (the words no run holds read from the
+# verified landing into slabs and put; piece = slabs, note = bytes),
+# swap_assemble (the copy programs dispatched -> the new words ready; piece
+# = dispatches, note = "compiled" where a program was), swap_verify (the
+# host's sums of every piece of the landing, the device's of every piece of
+# the new words, the comparison; piece = pieces), swap_views (the typed
+# tensors cut from the new words; piece = tensors), swap_flip (a point: the
+# generation installed, or handed back where the caller holds no
+# DoubleBuffer; piece = generation, aux = ms since download_delta was
+# called, note = "fallback" where the generation is a host buffer).
+EV_SWAP_PLAN = 56
+EV_SWAP_STAGE = 57
+EV_SWAP_ASSEMBLE = 58
+EV_SWAP_VERIFY = 59
+EV_SWAP_VIEWS = 60
+EV_SWAP_FLIP = 61
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -220,6 +244,9 @@ EVENT_NAMES = {
     EV_SINK_TAIL: "sink_tail", EV_RANGE_IMPORT: "range_import",
     EV_FEED_SAMPLE: "feed_sample", EV_FEED_WAIT: "feed_wait",
     EV_FEED_BATCH: "feed_batch",
+    EV_SWAP_PLAN: "swap_plan", EV_SWAP_STAGE: "swap_stage",
+    EV_SWAP_ASSEMBLE: "swap_assemble", EV_SWAP_VERIFY: "swap_verify",
+    EV_SWAP_VIEWS: "swap_views", EV_SWAP_FLIP: "swap_flip",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING
@@ -235,7 +262,9 @@ _SINK_STEPS = (EV_SINK_LAND, EV_SINK_READ, EV_SINK_CHECKSUM, EV_SINK_STAGE,
                EV_SINK_FINALIZE, EV_SINK_TAIL, EV_SINK_REPLICATE,
                EV_SINK_VERIFY_CHIPS, EV_SINK_WAIT)
 # The client API's steps, summed into the report's ``client`` block.
-_CLIENT_STEPS = (EV_ADMIT_WAIT, EV_SHARD_PLAN, EV_SHARD_VIEWS)
+_CLIENT_STEPS = (EV_ADMIT_WAIT, EV_SHARD_PLAN, EV_SHARD_VIEWS, EV_SWAP_PLAN,
+                 EV_SWAP_STAGE, EV_SWAP_ASSEMBLE, EV_SWAP_VERIFY,
+                 EV_SWAP_VIEWS, EV_SWAP_FLIP)
 # Chip-to-chip work of a landing: booked under ``ici`` beside the
 # intra-slice piece transfers.
 _ICI_STEPS = (EV_SINK_REPLICATE, EV_SINK_VERIFY_CHIPS)
@@ -817,7 +846,8 @@ def render_waterfall(report: dict) -> str:
     if client:
         lines.append("client api, ms (admit_wait: queued for a sink slot "
                      "before the task; shard_*: the sharded pull this task "
-                     "heads): " + " ".join(
+                     "heads; swap_*: the hot-swap this delta task feeds): "
+                     + " ".join(
                          f"{k[:-3]}={v:.1f}" for k, v in client.items()))
     parent = report.get("parent") or {}
     parts = []

@@ -143,7 +143,7 @@ async def main() -> int:
         result = await device_lib.download_to_device(
             pod, f"{base_url}/v1", digest=sha1)
         hot = DoubleBuffer()
-        hot.flip(result.as_bytes_array(), result.load_safetensors())
+        hot.flip(result.as_words(), result.load_safetensors())
         step = int(np.asarray(hot.tensors()["step"])[0])
         print(f"serving generation {hot.generation} "
               f"(checkpoint step {step}, {len(v1)} bytes in HBM)")

@@ -5,7 +5,8 @@ tiny twin (``test_cells_rehearsal.py`` has the sound runs): the script
 alters what lands where the program's own verification cannot see it, runs
 the whole cell, and the last line's ``correct`` must be false, with no
 operation failed: only the benchmark's comparison with the generator
-objected.
+objected. One break is the program's own to catch (the hot-swap's gate over
+a corrupt live generation): that run ends with the one swap failed.
 """
 
 import pytest
@@ -29,6 +30,12 @@ CONTROLS = [
     ("control_feed.py", ("--break", "flip"), "tiny-feed-records"),
     ("control_feed.py", ("--break", "swap"), "tiny-feed-records"),
     ("control_feed.py", ("--break", "numpy"), "tiny-feed-records"),
+    # The hot-swap: a base chunk corrupt on disk is fetched again and
+    # counted (the reference never has it); the wrong version served; a
+    # torn snapshot among the reader's notes.
+    ("control_swap.py", ("--break", "store"), "tiny-shard-swap"),
+    ("control_swap.py", ("--break", "version"), "tiny-shard-swap"),
+    ("control_swap.py", ("--break", "torn"), "tiny-shard-swap"),
 ]
 
 
@@ -37,3 +44,15 @@ CONTROLS = [
 def test_a_broken_landing_comes_out_incorrect(script, how, cell):
     line = last_line(whole_run("tests/" + script, cell, *how), cell)
     assert line["correct"] is False, line
+
+
+def test_a_corrupt_live_generation_is_refused_by_the_swaps_own_gate():
+    """The one break that the program itself must catch: a bit of the live
+    generation's words differs inside a run the swap copies HBM -> HBM. The
+    gate refuses the flip, so the operation fails, the old generation stays
+    live, and the run comes out incorrect with that one failure."""
+    proc = whole_run("tests/control_swap.py", "tiny-shard-swap",
+                     "--break", "live")
+    line = last_line(proc, "tiny-shard-swap", failed=1)
+    assert line["correct"] is False, line
+    assert "hot-swap verify failed: piece 0 corrupt" in proc.stdout

@@ -33,6 +33,7 @@ CELLS = {
     "tiny-shard-cold-fanout": ("manifest-fanout.json", 1),
     "tiny-host-reland-ep4": ("manifest-global.json", 4),
     "tiny-feed-records": ("manifest-feed.json", 1),
+    "tiny-shard-swap": ("manifest-swap.json", 1),
 }
 
 # Every process of a run inherits its environment from the run, so a
@@ -88,13 +89,14 @@ def whole_run(script: str, cell: str, *extra: str):
     return proc
 
 
-def last_line(proc, cell: str) -> dict:
+def last_line(proc, cell: str, failed: int = 0) -> dict:
     """What every whole run of a tiny cell ends with, sound or broken: exit
-    0 and the JSON as the last line, no operation failed, nothing of a CPU
+    0 and the JSON as the last line, no operation failed (but where the
+    break is one that the program itself must refuse), nothing of a CPU
     run under a device metric's name."""
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["failed"] == 0 and line["attempted"] >= 1, line
+    assert line["failed"] == failed and line["attempted"] >= 1, line
     assert line["metrics"] == {} and line["rehearsal"] is True, line
     assert line["device"] == {"platform": "cpu", "kind": "cpu",
                               "count": CELLS[cell][1],

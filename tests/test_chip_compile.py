@@ -396,13 +396,12 @@ def test_byte_buffers_stop_at_2_gib():
     import jax.numpy as jnp
 
     from dragonfly2_tpu.ops import bitview
-    from dragonfly2_tpu.ops.hbm_sink import verify_u8_against_host
 
     big = jax.ShapeDtypeStruct((1 << 31,), jnp.uint8)
     with pytest.raises(ValueError, match="2 GiB"):
         bitview.typed_view(big, 1 << 20, jnp.bfloat16, (4, 4))
     with pytest.raises(ValueError, match="2 GiB"):
-        verify_u8_against_host(big, 4 * MiB, {})
+        bitview.check_u8_indexable(big)
 
 
 def test_record_batch_view(one_chip):
@@ -435,15 +434,53 @@ def test_a_short_batch_is_a_slice_of_the_full_view(one_chip, n):
 
 
 @pytest.mark.parametrize("piece_mib", [32, 4])
-def test_hot_swap_gate_reads_bytes_in_place(one_chip, piece_mib):
+def test_hot_swap_gate_reads_words_in_place(one_chip, piece_mib):
     import jax.numpy as jnp
 
-    from dragonfly2_tpu.ops.hbm_sink import _u8_checksums_jit
+    from dragonfly2_tpu.ops.hbm_sink import _words_checksums_jit
 
     arg, out, temp = _memory(
-        functools.partial(_u8_checksums_jit, piece_size=piece_mib * MiB),
-        _spec((CONTENT - 3,), jnp.uint8, one_chip))
+        functools.partial(_words_checksums_jit,
+                          piece_words=piece_mib * MiB // 4),
+        _spec((PIECES // 4,), jnp.uint32, one_chip))
     assert temp <= 2 * piece_mib * MiB
+
+
+def test_hot_swap_gate_has_no_2_gib_bound(one_chip):
+    """The gate of the word buffer takes ``moonlight-ep4-*``'s 4.68 GB file
+    (150 pieces of 32 MiB), which the byte gate refused."""
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops.hbm_sink import _words_checksums_jit
+
+    arg, out, temp = _memory(
+        functools.partial(_words_checksums_jit, piece_words=8 * MiB),
+        _spec((150 * 8 * MiB,), jnp.uint32, one_chip))
+    assert arg == 150 * 32 * MiB and temp <= 64 * MiB
+
+
+@pytest.mark.parametrize("source", ["live", "slab"])
+def test_hot_swap_assembly_writes_the_new_words_in_place(one_chip, source):
+    """The swap's copy program at the benchmark's shard: the new buffer is
+    donated and comes back as it lies (no second content), the source (the
+    live generation's words, a 32 MiB staging slab) is read where it is,
+    and a step's block is the only temporary."""
+    import jax
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops import hbm_sink
+
+    words = PIECES // 4
+    src = words if source == "live" else hbm_sink._SWAP_SLAB_ROWS * 128
+    m = jax.jit(functools.partial(hbm_sink._swap_copy_jit,
+                                  block=hbm_sink._SWAP_BLOCK_ROWS),
+                donate_argnums=(0,)).lower(
+        _spec((words,), jnp.uint32, one_chip),
+        _spec((src,), jnp.uint32, one_chip),
+        _spec((4096, 4), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip)).compile().memory_analysis()
+    assert m.alias_size_in_bytes == m.output_size_in_bytes == PIECES
+    assert m.temp_size_in_bytes <= 4 * MiB
 
 
 def _mesh_words(topo, n_words: int):

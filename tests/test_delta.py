@@ -311,9 +311,11 @@ class TestDoubleBuffer:
             tensors = {f"t{i}": np.full((16,), version, np.float32)
                        for i in range(4)}
             content = _make_safetensors(tensors)
-            u8 = jnp.asarray(np.frombuffer(content, np.uint8))
+            words = jnp.asarray(np.frombuffer(
+                content + bytes(-len(content) % 4), "<u4"))
             header, ds = st.parse_header(content)
-            return u8, st.tensor_views(u8, header, ds)
+            return words, st.tensor_views(words, header, ds,
+                                          total=len(content))
 
         hot = DoubleBuffer()
         hot.flip(*gen_views(1.0))
@@ -341,31 +343,46 @@ class TestDoubleBuffer:
         assert hot.generation == 11
 
     def test_assemble_and_verify(self):
+        import jax
         import jax.numpy as jnp
 
         from dragonfly2_tpu.ops.checksum import checksum_numpy
         from dragonfly2_tpu.ops.hbm_sink import (
-            assemble_delta_u8,
-            verify_u8_against_host,
+            assemble_swap_words,
+            plan_swap,
+            stage_swap,
+            verify_words_against_host,
         )
 
         old = os.urandom(4096)
         fetched = os.urandom(512)
-        live = jnp.asarray(np.frombuffer(old, np.uint8))
-        # New layout: old[1024:2048] + fetched + old[0:1024]
-        parts = [("r", 1024, 1024), ("f", fetched), ("r", 0, 1024)]
-        u8 = assemble_delta_u8(live, parts)
-        want = old[1024:2048] + fetched + old[:1024]
-        assert bytes(np.asarray(u8)) == want
+        live = jnp.asarray(np.frombuffer(old, "<u4"))
+        # New layout: old[0:1024] + fetched + old[1536:4096], the runs cut
+        # inside a word on either side of the fetched one.
+        want = old[:1022] + fetched + old[1534:]
+        runs = [[0, 0, 1022, True], [1022, 1022, 512, False],
+                [1534, 1534, 2562, True]]
+        plan = plan_swap(runs, len(want), 1024, live.shape[0])
+        # Whole words of the reused runs never leave the device; the words
+        # the runs' edges cut are staged with the fetched ones.
+        assert plan.runs == 2 and plan.reused_bytes == 1020 + 2560
+        device = jax.devices()[0]
+
+        def read_into(start, length, buf):
+            buf[:length] = want[start:start + length]
+
+        words = assemble_swap_words(
+            live, plan, stage_swap(plan, read_into, device), device)
+        assert np.asarray(words).tobytes() == want
         checks = {0: checksum_numpy(want[:2048]),
                   1: checksum_numpy(want[2048:])}
-        verify_u8_against_host(u8, 2048, checks)
+        verify_words_against_host(words, 2048, checks)
         # A flipped byte must be caught, naming the piece.
         corrupt = bytearray(want)
         corrupt[100] ^= 0xFF
-        bad = jnp.asarray(np.frombuffer(bytes(corrupt), np.uint8))
+        bad = jnp.asarray(np.frombuffer(bytes(corrupt), "<u4"))
         with pytest.raises(ValueError, match="piece 0"):
-            verify_u8_against_host(bad, 2048, checks)
+            verify_words_against_host(bad, 2048, checks)
 
 
 # ------------------------------------------------------------------ #
@@ -646,8 +663,7 @@ def test_download_delta_device_hotswap_e2e(run_async, tmp_path):
             result = await device_lib.download_to_device(
                 pod, f"{base_url}/v1", digest=sha1)
             hot = DoubleBuffer()
-            hot.flip(result.as_bytes_array(),
-                     result.load_safetensors())
+            hot.flip(result.as_words(), result.load_safetensors())
             assert hot.generation == 1
             np.testing.assert_array_equal(
                 np.asarray(hot.tensors()["bias"]), tensors_v1["bias"])

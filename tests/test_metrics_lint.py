@@ -58,7 +58,7 @@ METRIC_MODULES = (
 # The documented component vocabulary (docs/OBSERVABILITY.md "Metric
 # families"). Adding a component means documenting it there first.
 COMPONENTS = ("bufpool", "chaos", "dataset", "delta", "device_sharded",
-              "device_sink", "device_views", "fleet", "manager",
+              "device_sink", "device_swap", "device_views", "fleet", "manager",
               "objectstorage", "peer", "proxy", "qos", "runtime", "scheduler",
               "storage", "store", "tracing", "upload")
 
