@@ -28,6 +28,7 @@ import asyncio
 import hashlib
 import os
 import time
+from contextlib import aclosing
 from dataclasses import dataclass, field
 
 from dragonfly2_tpu.delta.chunker import CDCParams, Chunk
@@ -42,6 +43,7 @@ from dragonfly2_tpu.pkg import flight as flightlib
 from dragonfly2_tpu.pkg.errors import Code, DfError, StorageError, describe
 from dragonfly2_tpu.pkg.piece import compute_piece_count, compute_piece_size
 from dragonfly2_tpu.storage.local_store import (
+    LocalTaskStore,
     acquire_read_buffer,
     release_read_buffer,
 )
@@ -60,6 +62,20 @@ DELTA_CHUNKS = metrics.counter(
     "Delta-task chunks by resolution (corrupt_base = base copy failed "
     "its digest during the copy and was transparently re-fetched)",
     ("result",))
+DELTA_PIECES = metrics.counter(
+    "peer_delta_pieces_total",
+    "Delta-task target pieces by how they landed (built = a piece job read, "
+    "verified and wrote the piece; resumed = the store already had it and "
+    "the job was skipped whole)", ("how",))
+
+# Piece jobs of one landing in flight at once, each on a worker thread of
+# the loop's default executor. The smallest number on the flat of
+# benchmarks/delta_probe.py on the chip's host (13 vCPUs; 55 pieces of
+# 32 MiB, 8.4 % fetched; a landing request -> done, median of 3): 1 in
+# flight 3.86 s, 2 1.61, 4 1.42, 6 1.42, 8 1.40. From 4 up the landing is
+# as long as the whole-object sha256 that follows its pieces on one thread
+# (PERF.md section 5, "The delta landing, alone").
+_JOBS_IN_FLIGHT = 4
 
 # URL scheme of fabric-published manifests: task id of the manifest task
 # is a pure function of the CONTENT task id, so every host resolves the
@@ -231,7 +247,7 @@ def _write_file(path: str, data: bytes) -> None:
 
 class _SpanFetches:
     """Concurrent ranged-task pulls of the fetch spans, bounded, with
-    per-span buffers released after the last consuming chunk."""
+    per-span buffers released after the last consuming piece job."""
 
     def __init__(self, fetcher, spans: list[tuple[int, int]],
                  consumers: dict[tuple[int, int], int],
@@ -245,13 +261,15 @@ class _SpanFetches:
 
     async def _pull(self, span: tuple[int, int]) -> memoryview:
         s, e = span
-        buf = acquire_read_buffer(e - s)
-        try:
-            async with self._sem:
+        async with self._sem:
+            # Inside the gate: a fresh pooled buffer is zero-filled on the
+            # loop's thread, and the spans still waiting need none yet.
+            buf = acquire_read_buffer(e - s)
+            try:
                 await self.fetcher.fetch_into(s, e, buf[:e - s])
-        except BaseException:
-            release_read_buffer(buf)
-            raise
+            except BaseException:
+                release_read_buffer(buf)
+                raise
         self._bufs[span] = buf
         return buf
 
@@ -389,9 +407,13 @@ async def run_delta_task(tm, req, base_task_id: str, *,
             yield p
         return
 
-    async for p in _run_delta(tm, req, task_id, base_store, new_m, plan,
-                              fetch_concurrency):
-        yield p
+    # Closed with this generator, inner first: a client that goes away
+    # between two frames must find the landing's jobs ended before the
+    # stores are unpinned, not whenever the collector reaches them.
+    async with aclosing(_run_delta(tm, req, task_id, base_store, new_m, plan,
+                                   fetch_concurrency)) as landing:
+        async for p in landing:
+            yield p
 
 
 async def _run_delta(tm, req, task_id: str, base_store,
@@ -412,7 +434,6 @@ async def _run_delta(tm, req, task_id: str, base_store,
     tm._running[task_id] = run
     store.pin()
     base_store.pin()
-    fetches: _SpanFetches | None = None
     stats = {"reused_bytes": 0, "fetched_bytes": 0, "chunks_reused": 0,
              "chunks_fetched": 0, "corrupt_base": 0,
              "chunks_total": new_m.num_chunks,
@@ -422,24 +443,19 @@ async def _run_delta(tm, req, task_id: str, base_store,
              chunks=new_m.num_chunks, reuse_frac=round(
                  plan.reused_bytes / max(1, new_m.content_length), 4))
     try:
-        tf = tm.flight.task(task_id)
-        fetcher = _range_fetcher(tm, req)
-        spans = plan.fetch_spans()
-        consumers: dict[tuple[int, int], int] = {}
-        span_of: dict[int, tuple[int, int]] = {}
-        si = 0
-        for c in plan.fetched:
-            while si < len(spans) and spans[si][1] <= c.offset:
-                si += 1
-            span_of[c.offset] = spans[si]
-            consumers[spans[si]] = consumers.get(spans[si], 0) + 1
-        fetches = _SpanFetches(fetcher, spans, consumers,
-                               concurrency=fetch_concurrency)
-
-        async for p in _assemble(tm, req, store, base_store, new_m, plan,
-                                 fetches, span_of, fetcher, stats, tf,
-                                 peer_id):
-            yield p
+        if LocalTaskStore.completion_digest_applies(
+                req.meta.digest, req.range is not None):
+            # The pieces are this host's own copies: no parent map can
+            # certify them, so the completion re-hash is certain. Let it
+            # follow the pieces as they commit, as a back-to-source pull's
+            # does (storage _PrefixHasher).
+            store.start_prefix_hasher(req.meta.digest)
+        async with aclosing(_assemble(
+                tm, req, store, base_store, new_m, plan,
+                _range_fetcher(tm, req), fetch_concurrency, stats,
+                tm.flight.task(task_id), peer_id)) as pieces:
+            async for p in pieces:
+                yield p
     except DfError as e:
         await _fail(tm, req, store, run, task_id, peer_id, e)
         yield _failed_progress(task_id, peer_id, run.error)
@@ -451,8 +467,6 @@ async def _run_delta(tm, req, task_id: str, base_store,
         yield _failed_progress(task_id, peer_id, run.error)
         return
     finally:
-        if fetches is not None:
-            await fetches.close()
         base_store.unpin()
         store.unpin()
         if run.error is None and not store.metadata.done:
@@ -484,13 +498,196 @@ def _failed_progress(task_id: str, peer_id: str, err: DfError):
                             peer_id=peer_id, error=err.to_wire())
 
 
+@dataclass
+class _PieceJob:
+    """One target piece of a delta landing: the new manifest's chunks that
+    overlap it, each with where its bytes come from. The job's buffer spans
+    ``[lo, hi)``, the piece widened to the boundaries of the chunks that
+    straddle its edges (at most ``piece_size + 2 * params.max_size``), so
+    every chunk lies in it whole and is verified in the bytes that are
+    written. A chunk is booked by the job in which it starts."""
+
+    num: int
+    start: int
+    end: int
+    lo: int
+    hi: int
+    reused: list[tuple[Chunk, Chunk]] = field(default_factory=list)  # (new, base)
+    fetched: list[tuple[Chunk, tuple[int, int]]] = field(
+        default_factory=list)                                 # (new, span)
+
+    def spans(self) -> list[tuple[int, int]]:
+        """The fetched spans the job reads, each once, in offset order."""
+        return list(dict.fromkeys(span for _, span in self.fetched))
+
+    def owns(self, c: Chunk) -> bool:
+        return self.start <= c.offset < self.end
+
+
+def _piece_jobs(new_m: DeltaManifest, plan: DeltaPlan,
+                piece_size: int) -> list[_PieceJob]:
+    """Cut the new manifest's chunks (which tile the content in offset
+    order) into one job a target piece, before any byte moves."""
+    base_of = {c.offset: b for c, b in plan.reused}
+    spans = plan.fetch_spans()
+    span_of: dict[int, tuple[int, int]] = {}
+    si = 0
+    for c in plan.fetched:
+        while spans[si][1] <= c.offset:
+            si += 1
+        span_of[c.offset] = spans[si]
+    chunks = new_m.chunks
+    total = new_m.content_length
+    jobs: list[_PieceJob] = []
+    first = 0
+    for num in range(compute_piece_count(total, piece_size)):
+        start = num * piece_size
+        end = min(start + piece_size, total)
+        while chunks[first].end <= start:
+            first += 1
+        job = _PieceJob(num, start, end, lo=chunks[first].offset, hi=end)
+        i = first
+        while i < len(chunks) and chunks[i].offset < end:
+            c = chunks[i]
+            i += 1
+            job.hi = max(end, c.end)
+            b = base_of.get(c.offset)
+            if b is None:
+                job.fetched.append((c, span_of[c.offset]))
+            else:
+                job.reused.append((c, b))
+        jobs.append(job)
+    return jobs
+
+
+def _sha256_hex(view) -> str:
+    return hashlib.sha256(view).hexdigest()
+
+
+def _build_piece(job: _PieceJob, buf: memoryview, views: dict, base_store,
+                 store) -> tuple[list[tuple[Chunk, Chunk]], float, float]:
+    """A piece job's thread half, whole: the fetched slices copied to their
+    places in ``buf``, every reused chunk read there straight from the base
+    store and sha256-ed where it lies, and, when every digest held, the
+    piece's own slice of ``buf`` written. Returns the reused chunks that
+    failed (the coroutine re-fetches them and writes the piece itself) and
+    the clock readings around the reads and digests. Touches nothing of the
+    event loop's."""
+    lo = job.lo
+    for c, span in job.fetched:
+        at = c.offset - span[0]
+        buf[c.offset - lo:c.end - lo] = views[span][at:at + c.length]
+    bad = []
+    t0 = time.perf_counter()
+    for c, b in job.reused:
+        at = c.offset - lo
+        try:
+            base_store.read_into(b.offset, b.length, buf, at=at)
+            ok = _sha256_hex(buf[at:at + c.length]) == c.sha256
+        except (StorageError, OSError) as e:
+            log.warning("base chunk read failed; re-fetching",
+                        base_offset=b.offset, error=str(e)[:200])
+            ok = False
+        if not ok:
+            bad.append((c, b))
+    t1 = time.perf_counter()
+    if not bad:
+        store.write_piece(job.num, buf[job.start - lo:job.end - lo])
+    return bad, t0, t1
+
+
+async def _in_thread(fn, *args):
+    """``asyncio.to_thread`` that a cancellation does not leave behind: the
+    thread works in a pooled buffer and on pinned stores, all the caller's
+    to release, so a cancelled caller waits for it to return first."""
+    work = asyncio.ensure_future(asyncio.to_thread(fn, *args))
+    try:
+        return await asyncio.shield(work)
+    except asyncio.CancelledError:
+        await asyncio.gather(work, return_exceptions=True)
+        raise
+
+
+async def _land_piece(job: _PieceJob, bufs: list, widest: int, store,
+                      base_store, fetches: _SpanFetches, fetcher,
+                      stats: dict, tf) -> None:
+    """One piece job: wait for the fetched spans the piece needs, build the
+    piece on a worker thread, repair what the thread handed back, book the
+    chunks that start in the piece."""
+    spans = job.spans()
+    mine = [c for c, _ in job.fetched if job.owns(c)]
+    buf = None
+    try:
+        views = {}
+        if spans:
+            t0 = time.perf_counter()
+            for span in spans:
+                views[span] = await fetches.view(span)
+            tf.record(flightlib.EV_DELTA_FETCH, job.num,
+                      (time.perf_counter() - t0) * 1000.0,
+                      str(sum(c.length for c in mine)))
+        # A buffer a finished job put back, or a fresh one (zero-filled on
+        # this thread, beside the jobs already on theirs).
+        buf = bufs.pop() if bufs else acquire_read_buffer(widest)
+        bad, t0, t1 = await _in_thread(_build_piece, job, buf, views,
+                                       base_store, store)
+        failed = {c.offset for c, _ in bad}
+        reused = [c for c, _ in job.reused
+                  if job.owns(c) and c.offset not in failed]
+        if len(bad) < len(job.reused):
+            # The thread's own clock readings, stamped from here: the ring
+            # is not written from worker threads.
+            tf.record_at(t1, flightlib.EV_DELTA_REUSE, job.num,
+                         (t1 - t0) * 1000.0,
+                         str(sum(c.length for c in reused)))
+        for c, b in bad:
+            # Corrupt (or unreadable) base chunk: the digest gate caught it
+            # before any byte of it was written. Re-fetch THIS chunk as its
+            # own ranged task into its place and verify it again.
+            log.warning("base chunk digest mismatch; re-fetching",
+                        new_offset=c.offset, base_offset=b.offset,
+                        length=c.length)
+            t0 = time.perf_counter()
+            view = buf[c.offset - job.lo:c.end - job.lo]
+            await fetcher.fetch_into(c.offset, c.end, view)
+            if await _in_thread(_sha256_hex, view) != c.sha256:
+                raise DfError(Code.ClientPieceDownloadFail,
+                              f"delta chunk at {c.offset} failed its "
+                              f"manifest digest even after re-fetch")
+            tf.record(flightlib.EV_DELTA_FETCH, job.num,
+                      (time.perf_counter() - t0) * 1000.0, str(c.length))
+        if bad:
+            await _in_thread(store.write_piece, job.num,
+                             buf[job.start - job.lo:job.end - job.lo])
+    finally:
+        if buf is not None:
+            bufs.append(buf)
+        for span in spans:
+            fetches.consumed(span)
+    DELTA_PIECES.labels("built").inc()
+    refetched = [c for c, _ in bad if job.owns(c)]
+    stats["corrupt_base"] += len(refetched)
+    DELTA_CHUNKS.labels("corrupt_base").inc(len(refetched))
+    _book(stats, "reused", reused)
+    _book(stats, "fetched", mine + refetched)
+
+
+def _book(stats: dict, kind: str, chunks: list[Chunk]) -> None:
+    nbytes = sum(c.length for c in chunks)
+    stats[kind + "_bytes"] += nbytes
+    stats["chunks_" + kind] += len(chunks)
+    DELTA_BYTES.labels(kind).inc(nbytes)
+    DELTA_CHUNKS.labels(kind).inc(len(chunks))
+
+
 async def _assemble(tm, req, store, base_store, new_m: DeltaManifest,
-                    plan: DeltaPlan, fetches: _SpanFetches,
-                    span_of: dict, fetcher, stats: dict, tf, peer_id: str):
-    """Walk the new manifest in offset order, materializing each chunk
-    (local verified copy or fetched span slice) into piece-structured
-    writes on the target store, then finalize exactly like a downloaded
-    task."""
+                    plan: DeltaPlan, fetcher, fetch_concurrency: int,
+                    stats: dict, tf, peer_id: str):
+    """Land the new version a target piece at a time: ``_JOBS_IN_FLIGHT``
+    piece jobs side by side, admitted in piece order, each built on a
+    worker thread (``_build_piece``); then finalize exactly like a
+    downloaded task. A piece the store already has (a resumed landing) is
+    skipped whole, its chunks booked as the plan has them."""
     from dragonfly2_tpu.daemon.peer.broker import PieceEvent
     from dragonfly2_tpu.daemon.peer.task_manager import FileTaskProgress
 
@@ -499,51 +696,62 @@ async def _assemble(tm, req, store, base_store, new_m: DeltaManifest,
     store.update_task(content_length=total, piece_size=piece_size,
                       total_piece_count=compute_piece_count(
                           total, piece_size))
-    base_of = {c.offset: b for c, b in plan.reused}
-
-    piece_buf = acquire_read_buffer(piece_size)
-    chunk_buf = acquire_read_buffer(new_m.params.max_size)
+    jobs = []
+    for job in _piece_jobs(new_m, plan, piece_size):
+        if store.has_piece(job.num):
+            DELTA_PIECES.labels("resumed").inc()
+            _book(stats, "reused", [c for c, _ in job.reused if job.owns(c)])
+            _book(stats, "fetched", [c for c, _ in job.fetched if job.owns(c)])
+        else:
+            jobs.append(job)
+    # A span is pulled for the jobs that read it; one that only resumed
+    # pieces wanted is not pulled again.
+    consumers: dict[tuple[int, int], int] = {}
+    for job in jobs:
+        for span in job.spans():
+            consumers[span] = consumers.get(span, 0) + 1
+    # The jobs' buffers, one size so any job takes any: at most
+    # ``_JOBS_IN_FLIGHT`` are ever made, each by the first job to want one.
+    widest = max((j.hi - j.lo for j in jobs), default=0)
+    bufs: list[memoryview] = []
+    fetches = _SpanFetches(fetcher, list(consumers), consumers,
+                           concurrency=fetch_concurrency)
+    started: list[asyncio.Future] = []
+    pending: set[asyncio.Future] = set()
     last_progress = 0.0
     try:
-        piece_num = 0
-        piece_fill = 0
-        pos = 0                          # absolute content position
-        for c in new_m.chunks:
-            view = await _chunk_bytes(tm, req, c, base_of, base_store,
-                                      fetches, span_of, fetcher, chunk_buf,
-                                      stats, tf)
-            # Copy the chunk into the piece grid (a chunk can straddle
-            # many pieces and vice versa).
-            off = 0
-            while off < c.length:
-                take = min(c.length - off, piece_size - piece_fill)
-                piece_buf[piece_fill:piece_fill + take] = \
-                    view[off:off + take]
-                piece_fill += take
-                off += take
-                pos += take
-                if piece_fill == piece_size or pos == total:
-                    if not store.has_piece(piece_num):   # resume skip
-                        await asyncio.to_thread(
-                            store.write_piece, piece_num,
-                            piece_buf[:piece_fill])
-                    store.touch()
-                    piece_num += 1
-                    piece_fill = 0
-            if c.offset in span_of:
-                fetches.consumed(span_of[c.offset])
-            now = time.monotonic()
-            if now - last_progress >= 0.1:
-                last_progress = now
-                yield FileTaskProgress(
-                    state="running", task_id=store.metadata.task_id,
-                    peer_id=peer_id, content_length=total,
-                    completed_length=store.downloaded_bytes(),
-                    piece_count=len(store.metadata.pieces),
-                    total_piece_count=store.metadata.total_piece_count)
+        for job in (*jobs, None):       # None: the last jobs drain
+            while pending and (job is None
+                               or len(pending) >= _JOBS_IN_FLIGHT):
+                done, pending = await asyncio.wait(
+                    pending, return_when=asyncio.FIRST_COMPLETED)
+                for t in done:
+                    t.result()
+                store.touch()
+                now = time.monotonic()
+                if now - last_progress >= 0.1:
+                    last_progress = now
+                    yield FileTaskProgress(
+                        state="running", task_id=store.metadata.task_id,
+                        peer_id=peer_id, content_length=total,
+                        completed_length=store.downloaded_bytes(),
+                        piece_count=len(store.metadata.pieces),
+                        total_piece_count=store.metadata.total_piece_count)
+            if job is not None:
+                started.append(asyncio.ensure_future(_land_piece(
+                    job, bufs, widest, store, base_store, fetches, fetcher,
+                    stats, tf)))
+                pending.add(started[-1])
     finally:
-        release_read_buffer(piece_buf)
-        release_read_buffer(chunk_buf)
+        # No job outlives the landing: one still waiting for a span is
+        # cancelled, one in its thread is waited for (``_in_thread``), so
+        # nothing writes into a store the caller is about to unpin.
+        for t in pending:
+            t.cancel()
+        await asyncio.gather(*started, return_exceptions=True)
+        await fetches.close()
+        for buf in bufs:
+            release_read_buffer(buf)
 
     # Exact-accounting invariant before anything is announced.
     booked = stats["reused_bytes"] + stats["fetched_bytes"]
@@ -576,68 +784,3 @@ async def _assemble(tm, req, store, base_store, new_m: DeltaManifest,
              corrupt_base=stats["corrupt_base"])
     yield tm._final_progress(store, task_id, peer_id, device=req.device,
                              device_verified=device_verified)
-
-
-async def _chunk_bytes(tm, req, c: Chunk, base_of: dict, base_store,
-                       fetches: _SpanFetches, span_of: dict, fetcher,
-                       chunk_buf, stats: dict, tf) -> memoryview:
-    """One chunk's verified bytes: local copy from the base (digest
-    checked during the copy; corrupt → transparent ranged re-fetch) or a
-    slice of its fetched span."""
-    b = base_of.get(c.offset)
-    if b is None:
-        t0 = time.perf_counter()
-        span = span_of[c.offset]
-        view = await fetches.view(span)
-        tf.record(flightlib.EV_DELTA_FETCH, -1,
-                  (time.perf_counter() - t0) * 1000.0, str(c.length))
-        stats["fetched_bytes"] += c.length
-        stats["chunks_fetched"] += 1
-        DELTA_BYTES.labels("fetched").inc(c.length)
-        DELTA_CHUNKS.labels("fetched").inc()
-        return view[c.offset - span[0]: c.end - span[0]]
-
-    t0 = time.perf_counter()
-    view = chunk_buf[:c.length]
-    ok = False
-    try:
-        with base_store:
-            await asyncio.to_thread(base_store.read_into, b.offset,
-                                    b.length, view)
-        digest = await asyncio.to_thread(
-            lambda: hashlib.sha256(view).hexdigest())
-        ok = digest == c.sha256
-    except (StorageError, OSError) as e:
-        log.warning("base chunk read failed; re-fetching",
-                    base_offset=b.offset, error=str(e)[:200])
-    if ok:
-        tf.record(flightlib.EV_DELTA_REUSE, -1,
-                  (time.perf_counter() - t0) * 1000.0, str(c.length))
-        stats["reused_bytes"] += c.length
-        stats["chunks_reused"] += 1
-        DELTA_BYTES.labels("reused").inc(c.length)
-        DELTA_CHUNKS.labels("reused").inc()
-        return view
-    # Corrupt (or unreadable) base chunk: the digest gate caught it
-    # during the copy — re-fetch THIS chunk as its own ranged task and
-    # book it as fetched, plus the corrupt_base count.
-    log.warning("base chunk digest mismatch; re-fetching",
-                new_offset=c.offset, base_offset=b.offset,
-                length=c.length)
-    stats["corrupt_base"] += 1
-    DELTA_CHUNKS.labels("corrupt_base").inc()
-    t0 = time.perf_counter()
-    await fetcher.fetch_into(c.offset, c.end, view)
-    digest = await asyncio.to_thread(
-        lambda: hashlib.sha256(view).hexdigest())
-    if digest != c.sha256:
-        raise DfError(Code.ClientPieceDownloadFail,
-                      f"delta chunk at {c.offset} failed its manifest "
-                      f"digest even after re-fetch")
-    tf.record(flightlib.EV_DELTA_FETCH, -1,
-              (time.perf_counter() - t0) * 1000.0, str(c.length))
-    stats["fetched_bytes"] += c.length
-    stats["chunks_fetched"] += 1
-    DELTA_BYTES.labels("fetched").inc(c.length)
-    DELTA_CHUNKS.labels("fetched").inc()
-    return view

@@ -11,6 +11,7 @@ complete old-or-new tensor sets).
 
 from __future__ import annotations
 
+import asyncio
 import hashlib
 import json
 import os
@@ -551,6 +552,335 @@ def test_delta_e2e_reuse_accounting_and_corrupt_base(run_async, tmp_path):
     run_async(body(), timeout=120)
 
 
+# ------------------------------------------------------------------ #
+# The landing as piece jobs (delta/resolver.py _assemble): one
+# TaskManager with no scheduler, version N imported, version N+1 behind an
+# origin, the target's pieces far smaller than a real landing's so a dozen
+# jobs run side by side
+# ------------------------------------------------------------------ #
+
+JOB_PARAMS = CDCParams(mask_bits=12, min_size=2 << 10, max_size=32 << 10)
+JOB_PIECE = 64 << 10
+
+
+def _manifest_of(content: bytes, params: CDCParams) -> DeltaManifest:
+    ch = GearChunker(params)
+    ch.feed(content)
+    ch.finish()
+    return DeltaManifest(name="t", content_length=len(content),
+                         chunks=ch.chunks, params=ch.params)
+
+
+class _JobRig:
+    """Version N landed, version N+1 planned, the target store registered
+    with ``JOB_PIECE`` pieces; ``land`` drives ``_run_delta`` itself."""
+
+    def __init__(self, tm, origin, url, v1, v2, base_store):
+        from dragonfly2_tpu.delta.resolver import plan_delta
+
+        self.tm, self.origin, self.v2 = tm, origin, v2
+        self.base_store = base_store
+        self.new_m = _manifest_of(v2, JOB_PARAMS)
+        self.plan = plan_delta(self.new_m, _manifest_of(v1, JOB_PARAMS))
+        self.req = _file_req(
+            url, "sha256:" + hashlib.sha256(v2).hexdigest())
+        self.task_id = self.req.task_id()
+        from dragonfly2_tpu.storage.local_store import TaskStoreMetadata
+        self.store = tm.storage.register_task(TaskStoreMetadata(
+            task_id=self.task_id, piece_size=JOB_PIECE))
+        self.pieces = -(-len(v2) // JOB_PIECE)
+
+    def landing(self):
+        from dragonfly2_tpu.delta import resolver
+
+        return resolver._run_delta(self.tm, self.req, self.task_id,
+                                   self.base_store, self.new_m, self.plan, 4)
+
+    async def land(self):
+        final = None
+        async for p in self.landing():
+            assert p.state != "failed", p.error
+            final = p
+        assert final is not None and final.state == "done"
+        return self.tm.delta_stats[self.task_id]
+
+    def landed(self) -> bytes:
+        got = bytearray()
+        for rec in self.store.get_pieces():
+            got += self.store.read_piece(rec.num)
+        return bytes(got)
+
+    def events(self, name: str) -> list:
+        from dragonfly2_tpu.pkg import flight as flightlib
+
+        return [e for e in self.tm.flight.get(self.task_id).events()
+                if flightlib.EVENT_NAMES.get(e[1]) == name]
+
+
+def _with_job_rig(run_async, tmp_path, body, v1: bytes, v2: bytes):
+    from dragonfly2_tpu.daemon.peer.piece_manager import PieceManager
+    from dragonfly2_tpu.daemon.peer.task_manager import TaskManager
+    from dragonfly2_tpu.pkg import flight as flightlib
+    from dragonfly2_tpu.source import default_registry
+    from dragonfly2_tpu.storage import StorageManager, StorageOption
+
+    async def run():
+        origin, base_url, _stats = await _two_blob_origin(v1, v2)
+        storage = StorageManager(StorageOption(
+            data_dir=str(tmp_path / "data")))
+        try:
+            tm = TaskManager(storage, PieceManager())
+            tm.flight = flightlib.FlightRecorder()
+            path = tmp_path / "v1.bin"
+            path.write_bytes(v1)
+            base = await tm.import_task(str(path), _file_req("probe://v1"))
+            rig = _JobRig(tm, origin, f"{base_url}/v2", v1, v2,
+                          storage.find_completed_task(base["task_id"]))
+            await body(rig)
+        finally:
+            await default_registry().close_all()
+            storage.close()
+            await origin.cleanup()
+
+    run_async(run(), timeout=120)
+
+
+def _pieces_counted() -> dict:
+    from dragonfly2_tpu.delta import resolver
+
+    return {how: resolver.DELTA_PIECES.labels(how)._value.get()
+            for how in ("built", "resumed")}
+
+
+def _job_versions(n: int = 1 << 20):
+    content = os.urandom(n)
+    return content, scattered_mutation(content, frac=0.02, sites=3)
+
+
+def test_piece_jobs_cut_every_chunk_to_one_owner():
+    """The cut alone: every chunk of the new manifest is owned by exactly
+    one job (the piece it starts in), lies whole inside the buffer span of
+    every job whose piece it overlaps, and no span is wider than the piece
+    and two chunks."""
+    from dragonfly2_tpu.delta.resolver import _piece_jobs, plan_delta
+
+    v1, v2 = _job_versions()
+    new_m = _manifest_of(v2, JOB_PARAMS)
+    plan = plan_delta(new_m, _manifest_of(v1, JOB_PARAMS))
+    jobs = _piece_jobs(new_m, plan, JOB_PIECE)
+    assert [j.num for j in jobs] == list(range(-(-len(v2) // JOB_PIECE)))
+    owners: dict = {}
+    straddlers = 0
+    for j in jobs:
+        chunks = sorted([c for c, _ in j.reused] + [c for c, _ in j.fetched],
+                        key=lambda c: c.offset)
+        # The job's chunks tile its buffer span and cover its piece.
+        assert chunks[0].offset == j.lo <= j.start
+        assert chunks[-1].end == j.hi >= j.end
+        assert all(a.end == b.offset for a, b in zip(chunks, chunks[1:]))
+        assert j.hi - j.lo <= JOB_PIECE + 2 * JOB_PARAMS.max_size
+        for c, span in j.fetched:
+            assert span in plan.fetch_spans() and span[0] <= c.offset \
+                and c.end <= span[1]
+        for c in chunks:
+            straddlers += not j.owns(c)
+            if j.owns(c):
+                assert c.offset not in owners
+                owners[c.offset] = j.num
+    assert sorted(owners) == [c.offset for c in new_m.chunks]
+    assert straddlers > 0
+
+
+def test_delta_jobs_straddling_chunks_booked_once(run_async, tmp_path):
+    """With pieces of 64 KiB and chunks of 2-32 KiB nearly every piece
+    boundary cuts a chunk: each such chunk is written by two jobs and
+    booked by one, both pieces come out right, and the accounting sums to
+    the content exactly."""
+    v1, v2 = _job_versions()
+
+    async def body(rig):
+        before = _pieces_counted()
+        st = await rig.land()
+        assert st["chunks_reused"] + st["chunks_fetched"] \
+            == rig.new_m.num_chunks
+        assert st["reused_bytes"] == rig.plan.reused_bytes
+        assert st["fetched_bytes"] == rig.plan.fetched_bytes
+        assert st["corrupt_base"] == 0
+        assert rig.landed() == v2
+        after = _pieces_counted()
+        assert after["built"] - before["built"] == rig.pieces
+        assert after["resumed"] == before["resumed"]
+        # One delta_reuse span a job that reused anything, stamped with the
+        # job's piece; their notes are the bytes booked.
+        spans = rig.events("delta_reuse")
+        assert 1 <= len(spans) <= rig.pieces
+        assert sum(int(e[4]) for e in spans) == rig.plan.reused_bytes
+        assert sum(int(e[4]) for e in rig.events("delta_fetch")) \
+            == rig.plan.fetched_bytes
+
+    _with_job_rig(run_async, tmp_path, body, v1, v2)
+
+
+def test_delta_jobs_commit_out_of_order_under_the_prefix_hasher(
+        run_async, tmp_path, monkeypatch):
+    """Piece 0 is held back on its thread until later pieces have
+    committed: the whole-object sha256 still comes from the prefix hasher
+    (``verified``'s note ``prefix``: the frontier piece from memory, the
+    others read back), and the landing is byte-equal to the new version."""
+    import time as time_mod
+
+    from dragonfly2_tpu.delta import resolver
+
+    v1, v2 = _job_versions()
+    build = resolver._build_piece
+    order = []
+
+    def held_back(job, buf, views, base_store, store):
+        if job.num == 0:
+            deadline = time_mod.monotonic() + 10.0
+            while (len(store.metadata.pieces) < 3
+                   and time_mod.monotonic() < deadline):
+                time_mod.sleep(0.005)
+        out = build(job, buf, views, base_store, store)
+        order.append(job.num)
+        return out
+
+    monkeypatch.setattr(resolver, "_build_piece", held_back)
+
+    async def body(rig):
+        st = await rig.land()
+        assert order.index(0) >= 3, order
+        assert st["reused_bytes"] + st["fetched_bytes"] == len(v2)
+        (verified,) = rig.events("verified")
+        assert verified[4] == "prefix"
+        (start,) = rig.events("verify_start")
+        assert 0 <= start[2] <= rig.pieces
+        assert rig.store.metadata.digest == rig.req.meta.digest
+        assert rig.store.metadata.done
+        assert rig.landed() == v2
+
+    _with_job_rig(run_async, tmp_path, body, v1, v2)
+
+
+def test_delta_jobs_corrupt_base_chunk_refetched_among_neighbours(
+        run_async, tmp_path):
+    """One base chunk rots on disk under the landed task. The job that
+    meets it hands it back, it is re-fetched as its own ranged task and
+    verified again, counted once (though two jobs may write part of it),
+    and every other reused chunk is still reused."""
+    v1 = os.urandom(1 << 20)
+    v2 = bytearray(v1)
+    v2[900_000:910_000] = os.urandom(10_000)     # far from the rot
+    v2 = bytes(v2)
+
+    async def body(rig):
+        rot = next(b for c, b in rig.plan.reused
+                   if b.offset <= 300_000 < b.end)
+        with open(rig.base_store.data_path, "r+b") as f:
+            f.seek(300_000)
+            f.write(bytes(x ^ 0xFF for x in v1[300_000:300_016]))
+        st = await rig.land()
+        assert st["corrupt_base"] == 1
+        assert st["fetched_bytes"] == rig.plan.fetched_bytes + rot.length
+        assert st["reused_bytes"] == rig.plan.reused_bytes - rot.length
+        assert st["chunks_reused"] == len(rig.plan.reused) - 1
+        assert rig.landed() == v2
+        assert rig.store.metadata.digest == rig.req.meta.digest
+
+    _with_job_rig(run_async, tmp_path, body, v1, v2)
+
+
+def test_delta_jobs_client_gone_then_resumed(run_async, tmp_path,
+                                             monkeypatch):
+    """A client that goes away after its first frame leaves verified pieces,
+    a terminal state for waiters, both stores unpinned and NO job still on
+    a thread; the retry skips the pieces the store has (their jobs are not
+    run) and its accounting still sums to the content."""
+    import threading
+    import time as time_mod
+
+    from dragonfly2_tpu.delta import resolver
+
+    v1, v2 = _job_versions(2 << 20)
+    build = resolver._build_piece
+    lock = threading.Lock()
+    running = [0]
+    built = []
+
+    def slow(job, buf, views, base_store, store):
+        with lock:
+            running[0] += 1
+        try:
+            time_mod.sleep(0.03)
+            return build(job, buf, views, base_store, store)
+        finally:
+            with lock:
+                running[0] -= 1
+                built.append(job.num)
+
+    monkeypatch.setattr(resolver, "_build_piece", slow)
+
+    async def body(rig):
+        landing = rig.landing()
+        async for p in landing:
+            assert p.state == "running" and p.piece_count >= 1
+            break
+        await landing.aclose()
+        # Nothing of the landing is left: no job in its thread, no pin, a
+        # terminal state.
+        assert running[0] == 0
+        assert not rig.store.pinned and not rig.base_store.pinned
+        assert not rig.tm.is_task_running(rig.task_id)
+        assert rig.tm.flight.get(rig.task_id).state == "failed"
+        had = sorted(rig.store.metadata.pieces)
+        assert 1 <= len(had) < rig.pieces
+        assert sorted(built) == had
+        await asyncio.sleep(0.1)
+        assert sorted(rig.store.metadata.pieces) == had   # nobody writes on
+        for num in had:
+            assert rig.store.read_piece(num) \
+                == v2[num * JOB_PIECE:(num + 1) * JOB_PIECE]
+
+        before = _pieces_counted()
+        del built[:]
+        st = await rig.land()
+        after = _pieces_counted()
+        assert sorted(built) == [n for n in range(rig.pieces)
+                                 if n not in had]
+        assert after["resumed"] - before["resumed"] == len(had)
+        assert after["built"] - before["built"] == rig.pieces - len(had)
+        assert st["reused_bytes"] + st["fetched_bytes"] == len(v2)
+        assert st["chunks_reused"] + st["chunks_fetched"] \
+            == rig.new_m.num_chunks
+        assert rig.landed() == v2
+        assert rig.store.metadata.digest == rig.req.meta.digest
+
+    _with_job_rig(run_async, tmp_path, body, v1, v2)
+
+
+def test_flight_analyze_folds_delta_spans_side_by_side():
+    """Piece jobs stamp ``delta_reuse`` spans that lie side by side: the
+    ``store`` phase is their union, never more seconds than the task
+    took, though their ``aux`` sums to several times that."""
+    import time as time_mod
+
+    from dragonfly2_tpu.pkg import flight as flightlib
+
+    tf = flightlib.TaskFlight("side-by-side")
+    time_mod.sleep(0.06)
+    now = time_mod.perf_counter()
+    for k in range(6):
+        tf.record_at(now - 0.001 * k, flightlib.EV_DELTA_REUSE, k, 50.0,
+                     "1")
+    tf.finish("done")
+    report = flightlib.analyze(tf)
+    assert report["event_counts"]["delta_reuse"] == 6
+    wall = report["wall_s"]
+    assert 0.045 <= report["phases"]["store"] <= wall < 6 * 0.050
+    total = sum(report["phases"].values()) + report["other_s"]
+    assert total == pytest.approx(wall, rel=0.01)
+
+
 def test_delta_flight_events_attribute_phases(run_async, tmp_path):
     """The flight recorder books delta local copies as store time and
     span pulls as dcn time; the phase partition stays wall-time-exact
@@ -708,3 +1038,23 @@ def test_example_checkpoint_hotswap_smoke():
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, proc.stderr[-4000:]
     assert "flipped to generation 2" in proc.stdout, proc.stdout
+
+
+def test_delta_probe_rehearsal(tmp_path):
+    """``benchmarks/delta_probe.py`` (the probe ``_JOBS_IN_FLIGHT`` rests
+    on) runs whole at a tiny size: every landing through ``_run_delta``
+    verified by its own digest, a row a number of jobs in flight."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(repo, "benchmarks", "delta_probe.py"),
+         "--pieces", "3", "--piece-bytes", str(4 << 20), "--repeats", "1",
+         "--in-flight", "1,4", "--out", str(tmp_path / "probe.json")],
+        capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    with open(tmp_path / "probe.json") as f:
+        rows = json.load(f)["rows"]
+    assert [r["in_flight"] for r in rows] == [1, 4]
+    assert all(r["how"] == ["prefix"] for r in rows)
