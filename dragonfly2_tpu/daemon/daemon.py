@@ -325,10 +325,11 @@ class Daemon:
         if self.config.clock_offset_s:
             recorder.wall_offset = self.config.clock_offset_s
         if self.config.prof.enabled:
-            # Runtime observatory: always-on sampler + loop-lag probe +
-            # GC observatory (pkg/prof). Slow ticks/pauses stamp typed events into
-            # every running flight; the probe feeds a daemon-side
-            # loop_lag SLO engine at /debug/slo.
+            # Runtime observatory: always-on sampler + the loop's account
+            # + GC observatory (pkg/prof). Holds of the loop and slow
+            # pauses stamp typed events into every running flight, the
+            # account's slices its own ring (runtime:loop:daemon); the
+            # probe feeds a daemon-side loop_lag SLO engine at /debug/slo.
             from dataclasses import replace as _dc_replace
 
             from dragonfly2_tpu.pkg import prof as proflib

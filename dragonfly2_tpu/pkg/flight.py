@@ -88,7 +88,13 @@ EV_TASK_DONE = 23
 EV_TASK_FAILED = 24
 EV_DELTA_REUSE = 25    # delta chunk copied from the local base (aux=cost_ms)
 EV_DELTA_FETCH = 26    # delta chunk pulled as a ranged task (aux=cost_ms)
-EV_LOOP_LAG = 27       # event loop wedged during this task (aux=lag_s)
+# A HOLD of the event loop, 20 ms and more, during this task: ONE event at
+# its end, aux = its SECONDS, piece = the loop thread's cpu ms inside it, note
+# = "held n=<handles> gc=<ms> who=<file:func:line>" (a turn that long: what
+# every other handle waited behind) or "late" (a due timer waited that long
+# while the loop still sat in select). pkg/prof stamps it, on the loop's own
+# ring (below, EV_LOOP_ACCT) and into every running task's.
+EV_LOOP_LAG = 27
 EV_GC_PAUSE = 28       # slow cyclic-GC pause during this task (aux=pause_s)
 # Spans of the device-sink landing thread: ONE event at the span's end,
 # aux = its duration in ms (start = t - aux/1000, as landed/source_landed
@@ -211,6 +217,14 @@ EV_SWAP_ASSEMBLE = 58
 EV_SWAP_VERIFY = 59
 EV_SWAP_VIEWS = 60
 EV_SWAP_FLIP = 61
+# The account the loop's thread keeps of itself (pkg/prof LoopLagProbe), on a
+# ring of its own, ``runtime:loop:<name>``, that is no task's: a SLICE, one
+# event as a turn ends once 5 ms of busy time have gathered since the last
+# (and at every hold's end), aux = those busy ms (select exit -> the next
+# select entry, summed), piece = the thread's cpu us inside them, note =
+# "late=<ms> gc=<ms> it=<iterations> n=<handles>". The same ring holds the
+# loop's ``loop_lag`` holds.
+EV_LOOP_ACCT = 62
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -247,6 +261,7 @@ EVENT_NAMES = {
     EV_SWAP_PLAN: "swap_plan", EV_SWAP_STAGE: "swap_stage",
     EV_SWAP_ASSEMBLE: "swap_assemble", EV_SWAP_VERIFY: "swap_verify",
     EV_SWAP_VIEWS: "swap_views", EV_SWAP_FLIP: "swap_flip",
+    EV_LOOP_ACCT: "loop_acct",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING
@@ -287,6 +302,9 @@ _PRIORITY = {"verify": 7, "store": 6, "hbm": 5, "ici": 4, "dcn": 3,
 # A first byte later than this after the request counts the gap as stall
 # (the parent was connected but silent) instead of transfer time.
 STALL_TTFB_S = 0.25
+# A hold of the loop this long is a wedge (ProfConfig.lag_slow_s' default
+# and the loop_lag SLO's threshold); shorter ones only name their holder.
+WEDGED_S = 0.25
 
 PHASE_SECONDS = metrics.histogram(
     "peer_task_phase_seconds",
@@ -713,6 +731,8 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
     truncated = len(ordered) > max_waterfall
     counts: dict = {}
     runtime: dict = {}
+    holders: dict = {}            # who -> summed seconds of its holds
+    holds = 0
     hbm: dict = {}
     client: dict = {}
     parent: dict = {}
@@ -754,6 +774,13 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
             # and a sharded pull's plan and views span its other tasks.
             client[code] = client.get(code, 0.0) + aux
         elif code in _RUNTIME_EVENTS:
+            if code == EV_LOOP_LAG:
+                who = note.partition("who=")[2]
+                if who:
+                    holds += 1
+                    holders[who] = holders.get(who, 0.0) + aux
+                if aux < WEDGED_S:
+                    continue      # a hold names its holder; a wedge counts
             r = runtime.get(name)
             if r is None:
                 r = runtime[name] = {"count": 0, "max_s": 0.0, "total_s": 0.0}
@@ -764,6 +791,13 @@ def analyze(tf: TaskFlight, *, stall_ttfb_s: float = STALL_TTFB_S,
     for r in runtime.values():
         r["max_s"] = round(r["max_s"], 4)
         r["total_s"] = round(r["total_s"], 4)
+    if holders:
+        # Turns of 20 ms and more inside the task's wall, and what ran in
+        # them by summed seconds, the longest first.
+        runtime["holds"] = holds
+        runtime["holders"] = {
+            who: round(s, 4) for who, s in
+            sorted(holders.items(), key=lambda kv: -kv[1])}
     return {
         "task_id": tf.task_id,
         "state": tf.state,
@@ -806,6 +840,10 @@ def runtime_advisory(report: dict) -> str:
     wedged loop or a GC storm names its culprit."""
     rt = report.get("runtime") or {}
     parts = []
+    for who, seconds in (rt.get("holders") or {}).items():
+        parts.append(f"event loop held {rt['holds']}x, {seconds:.2f} s by "
+                     f"{who}")
+        break
     ll = rt.get("loop_lag")
     if ll:
         parts.append(f"event loop wedged {ll['count']}x "
@@ -1019,6 +1057,9 @@ class FlightRecorder:
         # ring is read for a report.
         self.feeders: list = []
         self._tasks: "OrderedDict[str, TaskFlight]" = OrderedDict()
+        # Rings that are no task's (a loop's account, ``runtime:loop:*``):
+        # outside the index, so no eviction reaches them.
+        self._rings: "dict[str, TaskFlight]" = {}
         self._lock = threading.Lock()
 
     def sync(self) -> None:
@@ -1042,6 +1083,21 @@ class FlightRecorder:
                     wall_offset=self.wall_offset)
         return tf
 
+    def ring(self, name: str, capacity: int = 0) -> TaskFlight:
+        """Get-or-create ``name``'s ring OUTSIDE the index of tasks: it
+        lives as long as the recorder, is read like any flight (``get``,
+        ``/debug/flight/<name>``) and is never stamped by
+        ``stamp_running``."""
+        tf = self._rings.get(name)
+        if tf is None:
+            with self._lock:
+                tf = self._rings.get(name)
+                if tf is None:
+                    tf = self._rings[name] = TaskFlight(
+                        name, capacity or self.capacity,
+                        wall_offset=self.wall_offset)
+        return tf
+
     def _evict_one(self) -> None:
         for tid, tf in self._tasks.items():
             if tf.state != "running":
@@ -1050,23 +1106,26 @@ class FlightRecorder:
         self._tasks.popitem(last=False)
 
     def get(self, task_id: str) -> "TaskFlight | None":
-        return self._tasks.get(task_id)
+        return self._tasks.get(task_id) or self._rings.get(task_id)
 
-    def stamp_running(self, code: int, aux: float = 0.0,
-                      note: str = "") -> None:
+    def stamp_running(self, code: int, aux: float = 0.0, note: str = "",
+                      piece: int = -1, end_pc: "float | None" = None) -> None:
         """Record one event into EVERY running flight — how pkg/prof
-        stamps runtime interference (a wedged loop, a slow GC pause)
-        into the task windows it overlapped. Bounded by max_tasks."""
+        stamps runtime interference (a hold of the loop, a slow GC pause)
+        into the task windows it overlapped, as an event that ended at
+        ``end_pc`` (now). Bounded by max_tasks."""
+        if end_pc is None:
+            end_pc = time.perf_counter()
         for tf in list(self._tasks.values()):
             if tf.state == "running":
-                tf.record(code, -1, aux, note)
+                tf.record_at(end_pc, code, piece, aux, note)
 
     def summary(self) -> list:
         return [{"task_id": tf.task_id, "state": tf.state,
                  "wall_s": round(tf.wall_s(), 3),
                  "events": tf.events_total,
                  "events_dropped": tf.events_dropped}
-                for tf in self._tasks.values()]
+                for tf in (*self._tasks.values(), *self._rings.values())]
 
     def finish_task(self, task_id: str, state: str,
                     note: str = "") -> "TaskFlight | None":

@@ -21,14 +21,24 @@ Three instruments, one ``RuntimeObservatory``:
     which is why every long-lived thread in this tree carries a ``df-``
     prefix (tier-1 guard in tests/test_prof.py): dispatcher, upload,
     io-ring, chunker and sampler work separate cleanly in one glance.
-  * ``LoopLagProbe`` — a scheduled heartbeat per asyncio loop; the delta
-    between the intended and actual wake is the loop's lag. Samples land
-    in a preallocated ring + bounded histogram; ticks above ``slow_s``
-    are stamped into every RUNNING task flight as typed events
-    (EV_LOOP_LAG), so ``dfget --explain``'s stall phase can say *the
-    loop was wedged*, not just *nothing happened*. The ring also backs
-    the ``loop_lag`` SLO (pkg/slo kind="probe"): wedged wall-seconds
-    over observed wall-seconds.
+  * ``LoopLagProbe`` — the loop's thread keeps an account, from inside
+    its own iteration: ``arm()`` wraps the running loop's
+    ``selector.select``, and an iteration is ``select`` exit → the next
+    ``select`` entry, the ready handles of one turn. Two clock readings
+    a turn, and one of the thread's CPU time where a slice is stamped,
+    give, exactly and cumulatively, what ran the thread (``busy``), what
+    it cost on a core (``cpu``: busy − cpu is a turn held off a core, by a
+    blocking call, the GIL, a page fault), when it was due and did not run
+    (``late``)
+    and what the cyclic GC took of it (``gc``). Every 5 ms of busy time
+    is one ``loop_acct`` slice, and every turn or late wake of 20 ms and
+    more one ``loop_lag`` hold that names its holder (``who``: the frame
+    the sampler met on that thread inside the hold), on a ring of the
+    probe's own (``runtime:loop:<name>``); holds are also stamped into
+    every RUNNING task flight, so ``dfget --explain`` can say *who held
+    the loop*, not just *nothing happened*. The holds also back the
+    ``loop_lag`` SLO (pkg/slo kind="probe"): wedged wall-seconds over
+    observed wall-seconds.
   * ``GCObservatory`` — ``gc.callbacks`` pause histograms per
     generation + collection counters; pauses above ``gc_slow_s`` stamp
     EV_GC_PAUSE the same way. ``/proc/self`` gauges (RSS, open fds,
@@ -49,12 +59,13 @@ from __future__ import annotations
 import asyncio
 import gc
 import os
+import sys
 import threading
 import time
-import sys
+from collections import deque
 from dataclasses import dataclass
 
-from dragonfly2_tpu.pkg import dflog, metrics
+from dragonfly2_tpu.pkg import dflog, flight as flightlib, metrics
 
 log = dflog.get("prof")
 
@@ -70,15 +81,34 @@ TRUNCATED_TOTAL = metrics.counter(
 
 LAG_SECONDS = metrics.histogram(
     "runtime_loop_lag_seconds",
-    "Asyncio event-loop heartbeat lag (actual wake minus intended wake); "
-    "the loop-wedge detector behind the loop_lag SLO",
+    "How long a due handle of the asyncio loop waited: each turn of 1 ms "
+    "and more (what every other handle waited behind) and each late wake "
+    "(a due timer, the loop still in select); behind the loop_lag SLO",
     buckets=(0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
              5.0))
 
 SLOW_TICKS_TOTAL = metrics.counter(
     "runtime_loop_slow_ticks_total",
-    "Heartbeat ticks whose lag crossed the slow-tick threshold (each one "
-    "is also stamped into every running task flight as a typed event)")
+    "Turns and late wakes of the loop of lag_slow_s and more: the wedges "
+    "that the loop_lag SLO and a task's autopsy count")
+
+LOOP_BUSY_SECONDS = metrics.counter(
+    "runtime_loop_busy_seconds_total",
+    "Wall seconds the loop's thread spent running ready handles (select "
+    "exit to the next select entry), by loop; updated a slice at a time",
+    ("loop",))
+
+LOOP_CPU_SECONDS = metrics.counter(
+    "runtime_loop_cpu_seconds_total",
+    "The loop thread's own CPU seconds inside those turns: busy less cpu "
+    "is a turn held off a core (a blocking call, the GIL, a page fault)",
+    ("loop",))
+
+LOOP_LATE_SECONDS = metrics.counter(
+    "runtime_loop_late_seconds_total",
+    "Seconds a due timer waited while the loop still sat in select: the "
+    "loop due and not running (no GIL, no core), by loop",
+    ("loop",))
 
 GC_PAUSE_SECONDS = metrics.histogram(
     "runtime_gc_pause_seconds",
@@ -122,16 +152,28 @@ class ProfConfig:
     hz: float = 19.0              # sampler passes per second
     max_nodes: int = 8192         # trie node hard cap (then truncation)
     max_depth: int = 48           # frames folded per stack
-    lag_interval_s: float = 0.25  # heartbeat period per probed loop
-    lag_slow_s: float = 0.25      # slow-tick threshold -> flight events
+    lag_slow_s: float = flightlib.WEDGED_S    # a hold this long is a wedge
     gc_slow_s: float = 0.05       # GC pause threshold -> flight events
-    lag_ring: int = 4096          # lag samples retained for the SLO probe
+    lag_ring: int = 4096          # holds retained for the SLO probe
 
 
 # Internal fixed bucket edges for the JSON-served lag/GC histograms
 # (preallocated count arrays; the Prometheus families use their own).
 _LAG_EDGES = (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
               5.0)
+# The account's three steps, in ns. A turn or a late wake from _NOTE_NS up
+# is a wait worth booking (note_lag); 5 ms of busy time are one loop_acct
+# slice, so a saturated loop stamps 200 a second and an idle one none; a
+# turn or a late wake of _HOLD_NS and more is a hold, one loop_lag event
+# that names its holder: under the feed's 23 ms landing, under a tenth of
+# the shortest operation the benchmark times, at most 50 a second.
+_NOTE_NS = 1_000_000
+_SLICE_NS = 5_000_000
+_HOLD_NS = 20_000_000
+# The probe's own ring: five minutes of a saturated loop's slices.
+_ACCT_RING = 65536
+_PKG_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__))) \
+    + os.sep
 
 
 def proc_stats() -> dict:
@@ -187,6 +229,9 @@ class StackSampler:
         self._stackbuf: list = [None] * max_depth
         self._names: "dict[int, str]" = {}      # ident -> thread name
         self._names_refreshed = 0.0
+        # Armed loop probes by their thread: a pass that meets one of them
+        # inside a hold keeps the thread's frame for it (``who``).
+        self.loops: "dict[int, LoopLagProbe]" = {}
         self._lock = threading.Lock()
         self._stop = threading.Event()
         self._thread: "threading.Thread | None" = None
@@ -233,6 +278,9 @@ class StackSampler:
         for ident, frame in sys._current_frames().items():
             if ident == me:
                 continue
+            probe = self.loops.get(ident)
+            if probe is not None:
+                probe.note_frame(frame)
             n = 0
             while frame is not None and n < self.max_depth:
                 buf[n] = frame.f_code
@@ -327,72 +375,272 @@ class StackSampler:
             total += self._self_counts(child, acc)
         return total
 
-    def top_frames(self, n: int = 5) -> list:
-        """Flat process-wide top self-time frames (bench fallback
-        snapshots want one list, not a per-thread tree)."""
-        acc: "dict[str, int]" = {}
-        with self._lock:
-            for root in self._roots.values():
-                self._self_counts(root, acc)
-        top = sorted(acc.items(), key=lambda kv: -kv[1])[:n]
-        return [{"frame": f, "self": c} for f, c in top]
-
 
 # --------------------------------------------------------------------- #
 # (b) Event-loop lag probe
 # --------------------------------------------------------------------- #
 
 class LoopLagProbe:
-    """One heartbeat task per probed loop. A wedge of W seconds surfaces
-    as ONE tick with ~W lag (the heartbeat self-reschedules), so the SLO
-    probe counts wedged WALL TIME, not tick counts — immune to dilution
-    by the healthy ticks around a stall."""
+    """The account of one loop's thread, kept from inside its iteration.
+
+    ``arm()`` wraps the loop's ``selector.select`` (an attribute of the
+    selector object; ``disarm()`` takes it off again). An iteration is
+    ``select`` exit -> the next ``select`` entry: the ready handles of one
+    turn. Cumulative and exact: ``busy`` (ns running handles), ``late`` (ns
+    a due timer waited while the loop still sat in ``select``: a held loop
+    is busy, a starved one is late), ``gc`` (cyclic collections that ran on
+    this thread), ``iterations``, ``handles``; idle is the rest of the wall
+    time since ``arm()``, by construction.
+
+    ``cpu``, the thread's own CPU ns, is read where a slice is stamped and
+    not every turn: that clock is a system call, microseconds on some
+    machines, and on some it ticks every 10 ms. It is a difference of ONE
+    clock, so right in sum and as coarse as the clock in one slice; a slice's
+    cpu is that of the turns since the last slice and of the ``select`` calls
+    between them, which use none while they sleep.
+
+    ``note_lag`` is the one entry for a wait: the account calls it with each
+    turn and each late wake of 1 ms and more; from 20 ms up the wait is a
+    HOLD, one ``loop_lag`` event on the probe's ring and in every running
+    task flight, and one row of the ring behind ``wedged_seconds``: the SLO
+    probe counts wedged WALL TIME, each hold its full length."""
 
     def __init__(self, obs: "RuntimeObservatory", name: str,
-                 interval_s: float = 0.25, slow_s: float = 0.25,
-                 ring: int = 4096):
+                 slow_s: float = 0.25, ring: int = 4096):
         self.obs = obs
         self.name = name
-        self.interval_s = interval_s
         self.slow_s = slow_s
-        self._ring: list = [None] * ring        # (mono_t, lag_s)
+        self._ring: list = [None] * ring        # (mono_t, lag_s): the holds
         self._cap = ring
         self._n = 0
         self.started_mono = time.monotonic()
+        self.ticks = 0
         self.max_lag_s = 0.0
         self.slow_ticks = 0
+        self.longest: list = []                 # (lag_s, cpu_ms, note) x 5
         self._buckets = [0] * (len(_LAG_EDGES) + 1)
-        self._task: "asyncio.Task | None" = None
+        # The account. Totals up to the last slice; the open slice beside
+        # them (``_s_*``), folded in where a slice is stamped.
+        self.busy_ns = self.cpu_ns = self.late_ns = self.gc_ns = 0
+        self.iterations = self.handles = 0
+        self._s_busy = self._s_late = self._s_gc = 0
+        self._s_it = self._s_n = 0
+        self._armed_ns = 0          # perf_counter_ns at arm()
+        self._in_ns = 0             # the last select's entry ...
+        self._out_ns = 0            # ... and exit: >= _in_ns inside a turn
+        self._cpu_at = 0            # the thread's CPU ns at the last slice
+        self._turn_n = 0            # handles ready at that exit
+        self._frames: "deque" = deque(maxlen=128)   # (exit ns, code, line)
+        self._selector = None
+        self._inner = None
+        self._wrapper = None
+        self._thread = 0
+        self._busy_c = LOOP_BUSY_SECONDS.labels(name)
+        self._cpu_c = LOOP_CPU_SECONDS.labels(name)
+        self._late_c = LOOP_LATE_SECONDS.labels(name)
+
+    # -- lifecycle ---------------------------------------------------------
 
     def arm(self) -> "LoopLagProbe":
-        """Create the heartbeat on the RUNNING loop (call from it)."""
+        """Start the account on the RUNNING loop (call from it). A probe
+        that is armed stays as it is: its wrapper never nests."""
+        if self._wrapper is not None:
+            return self
         loop = asyncio.get_running_loop()
+        selector = getattr(loop, "_selector", None)
         self.started_mono = time.monotonic()
-        self._task = loop.create_task(self._beat(loop))
-        try:
-            self._task.set_name(f"df-prof-loop-{self.name}")
-        except AttributeError:
-            pass
+        if selector is None:
+            log.warning("loop has no selector to account for", loop=self.name)
+            return self
+        self._selector = selector
+        self._inner = selector.select
+        self._wrapper = selector.select = self._account(loop, self._inner)
+        self._thread = threading.get_ident()
+        self.obs.sampler.loops[self._thread] = self
+        self.obs.loop_ring(self.name)   # its clock starts with the account
         return self
 
     def disarm(self) -> None:
-        if self._task is not None:
-            self._task.cancel()
-            self._task = None
+        """Close the open slice and leave ``select`` as ``arm()`` found it.
+        Where another wrapper was laid over this one since, this one stays
+        in the chain and only passes the call on."""
+        if self._wrapper is None:
+            return
+        if threading.get_ident() == self._thread:
+            # Inside a turn of the loop itself: book it up to here, so the
+            # slices sum to the busy time.
+            try:
+                now = time.perf_counter_ns()
+                if self._out_ns >= self._in_ns:
+                    self._s_busy += now - self._out_ns
+                    self._out_ns = now
+                self._slice(now)
+            except Exception:
+                log.error("loop account failed at disarm", exc_info=True,
+                          loop=self.name)
+        self._unwrap()
 
-    async def _beat(self, loop) -> None:
-        interval = self.interval_s
-        while True:
-            t0 = loop.time()
-            await asyncio.sleep(interval)
-            lag = max(0.0, loop.time() - t0 - interval)
-            self.note_lag(lag)
+    def _unwrap(self) -> None:
+        selector, wrapper = self._selector, self._wrapper
+        self._wrapper = self._selector = None
+        if vars(selector).get("select") is wrapper:
+            if getattr(self._inner, "__self__", None) is selector:
+                del selector.select      # the class's own method again
+            else:
+                selector.select = self._inner
+        if self.obs.sampler.loops.get(self._thread) is self:
+            del self.obs.sampler.loops[self._thread]
+        self._inner = None
 
-    def note_lag(self, lag: float) -> None:
-        """One heartbeat observation (the async beat calls this; tests
-        and the DES sim may feed synthetic ticks)."""
-        self._ring[self._n % self._cap] = (time.monotonic(), lag)
-        self._n += 1
+    def _failed(self) -> None:
+        """The account raised: say so once, with the traceback, and take the
+        wrapper off. Never into the loop, and never line after line."""
+        log.error("loop account failed; disarmed", exc_info=True,
+                  loop=self.name)
+        self._unwrap()
+
+    # -- the iteration -----------------------------------------------------
+
+    def _account(self, loop, inner):
+        """``select``'s wrapper: the hot path. Two readings of the clock
+        and arithmetic on this object's own numbers; whatever formats,
+        stamps, counts or asks the system for the thread's CPU time runs in
+        ``_turn_end`` / ``_woke_late``, a turn in hundreds."""
+        pc = time.perf_counter_ns
+        ready = loop._ready
+        # A timer's due time is on loop.time()'s clock, in seconds.
+        to_pc = pc() - int(loop.time() * 1e9)
+        self._armed_ns = self._out_ns = pc()
+        self._cpu_at = time.thread_time_ns()
+        self._turn_n = 1            # the handle that runs arm()
+        probe = self
+
+        def select(timeout=None):
+            if probe._wrapper is None:          # disarmed under a wrapper
+                return inner(timeout)
+            t_in = pc()
+            busy = t_in - probe._out_ns
+            probe._in_ns = t_in
+            probe._s_busy += busy
+            probe._s_it += 1
+            probe._s_n += probe._turn_n
+            if busy >= _NOTE_NS or probe._s_busy >= _SLICE_NS:
+                probe._turn_end(busy, t_in)
+            events = inner(timeout)
+            t_out = pc()
+            n = len(ready) + len(events)
+            scheduled = loop._scheduled
+            if scheduled:
+                due = int(scheduled[0]._when * 1e9) + to_pc
+                if due < t_out:
+                    n += 1
+                    late = t_out - (due if due > t_in else t_in)
+                    probe._s_late += late
+                    if late >= _NOTE_NS:
+                        probe._woke_late(late, t_out)
+            probe._turn_n = n
+            probe._out_ns = t_out
+            return events
+
+        return select
+
+    def _turn_end(self, busy: int, t_in: int) -> None:
+        """A turn of 1 ms and more, or one that filled the slice: off the
+        hot path. Never raises into the loop: an account that fails takes
+        itself off."""
+        try:
+            if busy >= _HOLD_NS:
+                # The hold closes its slice, whose cpu and gc are the
+                # hold's (and those of the few ms of turns before it).
+                note = (f"held n={self._turn_n} gc={self._s_gc / 1e6:.1f} "
+                        f"who={self._who(self._out_ns)}")
+                cpu = self._slice(t_in)
+                self.note_lag(busy / 1e9, note, cpu / 1e6, t_in / 1e9)
+            else:
+                if busy >= _NOTE_NS:
+                    self.note_lag(busy / 1e9)
+                if self._s_busy >= _SLICE_NS:
+                    self._slice(t_in)
+        except Exception:
+            self._failed()
+
+    def _woke_late(self, late: int, t_out: int) -> None:
+        try:
+            self.note_lag(late / 1e9, "late", 0.0, t_out / 1e9)
+            if late >= _HOLD_NS:
+                self._slice(t_out)
+        except Exception:
+            self._failed()
+
+    def _slice(self, end_ns: int) -> int:
+        """Stamp the open slice as ONE ``loop_acct`` event that ends at
+        ``end_ns`` and fold it into the totals; on the loop's own thread,
+        whose CPU time since the last slice is the slice's. Returns those
+        CPU ns."""
+        now_cpu = time.thread_time_ns()
+        cpu, self._cpu_at = now_cpu - self._cpu_at, now_cpu
+        busy, late = self._s_busy, self._s_late
+        gc_ns, it, n = self._s_gc, self._s_it, self._s_n
+        self._s_busy = self._s_late = self._s_gc = 0
+        self._s_it = self._s_n = 0
+        self.busy_ns += busy
+        self.cpu_ns += cpu
+        self.late_ns += late
+        self.gc_ns += gc_ns
+        self.iterations += it
+        self.handles += n
+        self._busy_c.inc(busy / 1e9)
+        self._cpu_c.inc(cpu / 1e9)
+        self._late_c.inc(late / 1e9)
+        ring = self.obs.loop_ring(self.name)
+        if ring is not None and (busy or late):
+            ring.record_at(
+                end_ns / 1e9, flightlib.EV_LOOP_ACCT, cpu // 1000, busy / 1e6,
+                f"late={late / 1e6:.3f} gc={gc_ns / 1e6:.3f} it={it} n={n}")
+        return cpu
+
+    def note_gc(self, pause_ns: int) -> None:
+        """A collection that ran on this loop's thread (GCObservatory)."""
+        self._s_gc += pause_ns
+
+    # -- who held it -------------------------------------------------------
+
+    def note_frame(self, frame) -> None:
+        """A sampler pass met this loop's thread at ``frame``. Inside a
+        turn, keep the innermost frame of this package (else the innermost
+        of any file): the ``who`` of the hold the turn may become."""
+        out = self._out_ns
+        if out < self._in_ns:
+            return                  # in select: nobody holds the loop
+        f = frame
+        while f is not None and not f.f_code.co_filename.startswith(_PKG_DIR):
+            f = f.f_back
+        f = f or frame
+        self._frames.append((out, f.f_code, f.f_lineno))
+
+    def _who(self, out_ns: int) -> str:
+        """The frame most often met inside the turn that began at
+        ``out_ns``, as ``file:func:line``; ``?`` where no pass fell in."""
+        met: dict = {}
+        for out, code, line in list(self._frames):
+            if out == out_ns:
+                met[code, line] = met.get((code, line), 0) + 1
+        if not met:
+            return "?"
+        code, line = max(met, key=met.get)
+        return f"{os.path.basename(code.co_filename)}:{code.co_name}:{line}"
+
+    # -- a wait, booked ----------------------------------------------------
+
+    def note_lag(self, lag: float, note: str = "late", cpu_ms: float = 0.0,
+                 end_pc: "float | None" = None) -> None:
+        """One wait of ``lag`` seconds that a due handle stood: a turn's
+        length (``note`` "held ...": what every other handle waited behind)
+        or a late wake's ("late"). The account calls this from 1 ms up;
+        tests and the DES sim may feed synthetic ones. From 20 ms up it is
+        a hold: ONE ``loop_lag`` event that ended at ``end_pc`` (now) on the
+        probe's ring and in every running task flight."""
+        self.ticks += 1
         i = 0
         for edge in _LAG_EDGES:
             if lag <= edge:
@@ -405,7 +653,13 @@ class LoopLagProbe:
         if lag >= self.slow_s:
             self.slow_ticks += 1
             SLOW_TICKS_TOTAL.inc()
-            self.obs._stamp_flights_loop_lag(lag)
+        if lag * 1e9 < _HOLD_NS:
+            return
+        self._ring[self._n % self._cap] = (time.monotonic(), lag)
+        self._n += 1
+        self.longest = sorted(self.longest + [(lag, cpu_ms, note)],
+                              reverse=True)[:5]
+        self.obs.stamp_hold(self.name, lag, int(cpu_ms), note, end_pc)
 
     # -- SLO feed ----------------------------------------------------------
 
@@ -440,17 +694,36 @@ class LoopLagProbe:
         return min(bad, observed), observed
 
     def summary(self) -> dict:
+        busy, cpu = self.busy_ns + self._s_busy, self.cpu_ns
+        # The account stands as of its last reading: a select's entry or
+        # exit, whichever came last.
+        asof = max(self._in_ns, self._out_ns)
+        if (self._wrapper is not None and self._out_ns >= self._in_ns
+                and threading.get_ident() == self._thread):
+            # Asked from inside a turn: the turn so far is busy time.
+            asof = time.perf_counter_ns()
+            busy += asof - self._out_ns
+            cpu += time.thread_time_ns() - self._cpu_at
         return {
             "name": self.name,
-            "interval_s": self.interval_s,
             "slow_s": self.slow_s,
-            "ticks": self._n,
+            "ticks": self.ticks,
             "max_lag_s": round(self.max_lag_s, 6),
             "slow_ticks": self.slow_ticks,
             "histogram": {
                 "edges_s": list(_LAG_EDGES),
                 "counts": list(self._buckets),
             },
+            # The account: busy + idle is the wall time since arm().
+            "busy_s": busy / 1e9,
+            "idle_s": (asof - self._armed_ns - busy) / 1e9,
+            "cpu_s": cpu / 1e9,     # up to the last slice, or to now
+            "late_s": (self.late_ns + self._s_late) / 1e9,
+            "gc_s": (self.gc_ns + self._s_gc) / 1e9,
+            "iterations": self.iterations + self._s_it,
+            "handles": self.handles + self._s_n,
+            "holds": [{"seconds": round(lag, 6), "cpu_ms": round(cpu_ms, 3),
+                       "note": note} for lag, cpu_ms, note in self.longest],
         }
 
 
@@ -512,6 +785,9 @@ class GCObservatory:
             self.max_pause_s = pause
         self._pause_children[gen].observe(pause)
         self._count_children[gen].inc()
+        probe = self.obs.sampler.loops.get(threading.get_ident())
+        if probe is not None:
+            probe.note_gc(int(pause * 1e9))
         if pause >= self.slow_s:
             self.slow_pauses += 1
             self.obs._stamp_flights_gc(pause)
@@ -534,10 +810,10 @@ class GCObservatory:
 # --------------------------------------------------------------------- #
 
 class RuntimeObservatory:
-    """Sampler + per-loop lag probes + GC observatory behind one handle.
-    ``recorder`` (a pkg/flight.FlightRecorder) is where slow ticks and
-    slow GC pauses land as typed events; roles without a recorder
-    (scheduler) just skip the stamping."""
+    """Sampler + per-loop accounts + GC observatory behind one handle.
+    ``recorder`` (a pkg/flight.FlightRecorder) is where the loops' slices
+    and holds and slow GC pauses land as typed events; a role without a
+    recorder (scheduler) keeps the account and stamps nothing."""
 
     def __init__(self, cfg: "ProfConfig | None" = None, recorder=None):
         self.cfg = cfg or ProfConfig()
@@ -562,31 +838,44 @@ class RuntimeObservatory:
         self.sampler.stop()
 
     def arm_loop(self, name: str = "main") -> LoopLagProbe:
-        """Attach a lag probe to the RUNNING loop (call from it). One
+        """Start an account of the RUNNING loop (call from it). One
         probe per name; re-arming a name replaces the old probe."""
         old = self.probes.get(name)
         if old is not None:
             old.disarm()
-        probe = LoopLagProbe(
-            self, name, self.cfg.lag_interval_s, self.cfg.lag_slow_s,
-            self.cfg.lag_ring)
+        probe = LoopLagProbe(self, name, self.cfg.lag_slow_s,
+                             self.cfg.lag_ring)
         self.probes[name] = probe
         return probe.arm()
 
     # -- flight stamping ---------------------------------------------------
 
-    def _stamp_flights_loop_lag(self, lag: float) -> None:
+    def loop_ring(self, name: str):
+        """The ring of loop ``name``'s account, ``runtime:loop:<name>`` on
+        the recorder (None for a role without one): a flight the recorder
+        holds outside its index of tasks, so no task can evict it."""
         rec = self.recorder
-        if rec is not None:
-            from dragonfly2_tpu.pkg import flight as flightlib
+        if rec is None:
+            return None
+        return rec.ring(f"runtime:loop:{name}", _ACCT_RING)
 
-            rec.stamp_running(flightlib.EV_LOOP_LAG, lag, "loop_lag")
+    def stamp_hold(self, name: str, lag: float, cpu_ms: int, note: str,
+                   end_pc: "float | None" = None) -> None:
+        """The one place a ``loop_lag`` is stamped: on the loop's own ring
+        and in every running task flight, as an event that ended at
+        ``end_pc`` (aux = seconds, piece = cpu ms)."""
+        ring = self.loop_ring(name)
+        if ring is None:
+            return
+        if end_pc is None:
+            end_pc = time.perf_counter()
+        ring.record_at(end_pc, flightlib.EV_LOOP_LAG, cpu_ms, lag, note)
+        self.recorder.stamp_running(flightlib.EV_LOOP_LAG, lag, note,
+                                    cpu_ms, end_pc)
 
     def _stamp_flights_gc(self, pause: float) -> None:
         rec = self.recorder
         if rec is not None:
-            from dragonfly2_tpu.pkg import flight as flightlib
-
             rec.stamp_running(flightlib.EV_GC_PAUSE, pause, "gc_pause")
 
     # -- SLO feed ----------------------------------------------------------

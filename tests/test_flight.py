@@ -14,6 +14,7 @@ import dis
 import gc
 import json
 import math
+import time
 import weakref
 
 import pytest
@@ -88,6 +89,43 @@ class TestRecorderBounds:
         rec.task("done-1").finish("done")
         rec.task("new-1")
         assert "running-1" in rec._tasks and "done-1" not in rec._tasks
+
+    def test_a_ring_that_is_no_tasks_outlives_every_task(self):
+        """A loop's account (``runtime:loop:*``) lies outside the index of
+        tasks: 200 finished and running flights later, with the index at
+        its cap and evicting running ones, it is the same ring, read like
+        any flight and stamped by nobody but its owner."""
+        rec = flight.FlightRecorder(capacity=32, max_tasks=8)
+        ring = rec.ring("runtime:loop:ut", 64)
+        assert rec.ring("runtime:loop:ut") is ring      # get-or-create
+        assert len(ring._ring) == 64
+        ring.record(flight.EV_LOOP_ACCT, 4000, 5.0, "late=0.0 gc=0.0 it=9 n=9")
+        for i in range(200):
+            rec.task(f"churn-{i}")
+            if i % 2:
+                rec.finish_task(f"churn-{i}", "done")
+            rec.stamp_running(flight.EV_GC_PAUSE, 0.06, "gc_pause")
+        assert len(rec._tasks) == 8
+        assert rec.get("churn-0") is None               # evicted, running
+        assert rec.get("runtime:loop:ut") is ring
+        assert "runtime:loop:ut" not in rec._tasks
+        assert [e[1] for e in ring.events()] == [flight.EV_LOOP_ACCT]
+        assert rec.finish_task("runtime:loop:ut", "done") is None
+        assert ring.state == "running"
+        index = {row["task_id"]: row for row in rec.summary()}
+        assert index["runtime:loop:ut"]["events"] == 1
+        assert len(index) == 9
+
+    def test_stamp_running_dates_an_event_that_ended_before_now(self):
+        rec = flight.FlightRecorder(capacity=32, max_tasks=8)
+        tf = rec.task("held")
+        ended = time.perf_counter() - 0.5
+        rec.stamp_running(flight.EV_LOOP_LAG, 0.3, "held n=1 gc=0.0 who=?",
+                          7, ended)
+        rec.stamp_running(flight.EV_GC_PAUSE, 0.06, "gc_pause")
+        (t, code, piece, aux, note), (t_gc, *_rest) = tf.events()
+        assert (code, piece, aux) == (flight.EV_LOOP_LAG, 7, 0.3)
+        assert t == pytest.approx(ended - tf._start_pc) and t < 0 <= t_gc
 
     def test_record_allocates_no_dicts_on_hot_path(self):
         """The always-on contract: one tuple per event, no per-event dict
@@ -592,6 +630,16 @@ class TestDebugEndpoints:
                     assert "phase breakdown:" in text
                     async with sess.get(f"{base}/debug/flight/absent") as r:
                         assert r.status == 404
+                    # A loop's account is served by its name, like a task.
+                    rec.ring("runtime:loop:daemon").record(
+                        flight.EV_LOOP_LAG, 3, 0.05,
+                        "held n=1 gc=0.0 who=device_feed.py:_land:207")
+                    async with sess.get(
+                            f"{base}/debug/flight/runtime:loop:daemon"
+                            "?raw=1") as r:
+                        assert r.status == 200
+                        own = await r.json()
+                    assert [e[1] for e in own["events"]] == ["loop_lag"]
                     async with sess.get(f"{base}/debug/pod/dbg-task") as r:
                         assert r.status == 200
                         pod = await r.json()

@@ -163,19 +163,28 @@ class TestStackSampler:
 
 
 # --------------------------------------------------------------------- #
-# Loop-lag probe: ring, histogram, wedged-seconds SLO feed
+# The loop's account: what ran it, what held it and who; the holds' ring,
+# histogram and wedged-seconds SLO feed
 # --------------------------------------------------------------------- #
+
+def _acct_events(rec, name, code):
+    return [e for e in rec.get(f"runtime:loop:{name}").events()
+            if e[1] == code]
+
+
+def _fields(note: str) -> dict:
+    return dict(part.split("=", 1) for part in note.split() if "=" in part)
+
 
 class TestLoopLagProbe:
     def _probe(self, **kw) -> LoopLagProbe:
         obs = RuntimeObservatory(ProfConfig(enabled=False))
-        kw.setdefault("interval_s", 0.05)
         kw.setdefault("slow_s", 0.25)
         return LoopLagProbe(obs, "ut", **kw)
 
     def test_note_lag_feeds_ring_histogram_and_max(self):
         p = self._probe()
-        for lag in (0.001, 0.02, 0.3):
+        for lag in (0.001, 0.03, 0.3):
             p.note_lag(lag)
         s = p.summary()
         assert s["ticks"] == 3
@@ -184,11 +193,15 @@ class TestLoopLagProbe:
         assert sum(s["histogram"]["counts"]) == 3
         assert len(s["histogram"]["counts"]) == \
             len(s["histogram"]["edges_s"]) + 1
+        # A wait is booked from 1 ms up; from 20 ms up it is a hold, and
+        # only a hold is a row of the ring behind the SLO.
+        assert p._n == 2
+        assert [h["seconds"] for h in s["holds"]] == [0.3, 0.03]
 
     def test_wedged_seconds_counts_wall_time_not_ticks(self):
-        """A single 1.5 s wedge among hundreds of healthy ticks must
+        """A single 1.5 s wedge among hundreds of short turns must
         dominate the probe output — wedged TIME over observed TIME, so
-        healthy ticks cannot dilute a stall (the reason this SLI is a
+        healthy turns cannot dilute a stall (the reason this SLI is a
         probe, not a completion ratio)."""
         p = self._probe()
         p.started_mono = time.monotonic() - 5.0     # ran ~5 s already
@@ -214,19 +227,25 @@ class TestLoopLagProbe:
         assert total == pytest.approx(10.0)
 
     def test_armed_probe_measures_a_real_wedge(self, run_async):
+        """The account needs no tick to come round: the turn that held the
+        loop is booked as it ends, one wait of its own length."""
         async def body():
             obs = RuntimeObservatory(ProfConfig(
-                enabled=False, lag_interval_s=0.02, lag_slow_s=0.15))
+                enabled=False, lag_slow_s=0.15))
             p = obs.arm_loop("ut-wedge")
             try:
-                await asyncio.sleep(0.08)
+                await asyncio.sleep(0.02)
                 time.sleep(0.3)             # wedge the loop
-                await asyncio.sleep(0.08)   # let the heartbeat observe it
+                await asyncio.sleep(0)      # the turn ends: it is booked
             finally:
                 p.disarm()
             s = p.summary()
-            assert s["max_lag_s"] >= 0.2, s
+            assert s["max_lag_s"] >= 0.3, s
             assert s["slow_ticks"] >= 1, s
+            assert s["busy_s"] >= 0.3, s
+            # A role without a recorder keeps the account, stamps nothing.
+            assert obs.loop_ring("ut-wedge") is None
+            assert s["holds"][0]["note"].startswith("held n=1 "), s
 
         run_async(body(), timeout=30)
 
@@ -236,8 +255,9 @@ class TestLoopLagProbe:
         rec.task("t-done")
         rec.finish_task("t-done", "done")
         obs = RuntimeObservatory(ProfConfig(enabled=False), recorder=rec)
-        p = LoopLagProbe(obs, "ut", interval_s=0.05, slow_s=0.25)
+        p = LoopLagProbe(obs, "ut", slow_s=0.25)
         p.note_lag(0.8)
+        p.note_lag(0.01)                    # a wait, no hold: no event
         running = rec.get("t-run")
         evs = [e for e in running.events() if e[1] == flight.EV_LOOP_LAG]
         assert len(evs) == 1
@@ -245,6 +265,246 @@ class TestLoopLagProbe:
         done = rec.get("t-done")
         assert not [e for e in done.events()
                     if e[1] == flight.EV_LOOP_LAG]
+        # The same hold, once, on the loop's own ring.
+        own = _acct_events(rec, "ut", flight.EV_LOOP_LAG)
+        assert [e[3] for e in own] == [pytest.approx(0.8)]
+
+    def test_a_hold_names_its_holder(self, run_async):
+        """A ``time.sleep`` inside a coroutine is ONE ``held`` hold as long
+        as the sleep, of one handle, off the core, and the sampler's pass
+        that fell inside it (driven by hand) names the coroutine."""
+        rec = flight.FlightRecorder(max_tasks=8)
+        obs = RuntimeObservatory(ProfConfig(enabled=False), recorder=rec)
+        rec.task("t-run")
+        entered = threading.Event()
+        sampled: list = []
+
+        def one_pass():
+            assert entered.wait(timeout=30)
+            with obs.sampler._lock:
+                obs.sampler._sample_once()
+            sampled.append(True)
+
+        async def the_holder():
+            entered.set()
+            while not sampled:              # the pass falls inside the turn
+                time.sleep(0.001)
+            time.sleep(0.05)
+
+        async def body():
+            sampler = threading.Thread(target=one_pass, daemon=True,
+                                       name="df-ut-one-pass")
+            sampler.start()
+            p = obs.arm_loop("ut-hold")
+            try:
+                await asyncio.sleep(0.02)   # woken by ONE due timer
+                await the_holder()
+                await asyncio.sleep(0)
+            finally:
+                p.disarm()
+                sampler.join(timeout=30)
+            assert not sampler.is_alive()
+            return p
+
+        p = run_async(body(), timeout=60)
+        mine = [e for e in _acct_events(rec, "ut-hold", flight.EV_LOOP_LAG)
+                if "the_holder" in e[4]]
+        assert len(mine) == 1, _acct_events(rec, "ut-hold",
+                                            flight.EV_LOOP_LAG)
+        _t, _code, cpu_ms, seconds, note = mine[0]
+        assert seconds >= 0.05
+        assert note.startswith("held n=1 gc=")
+        who = _fields(note)["who"].split(":")
+        assert who[:2] == ["test_prof.py", "the_holder"] and int(who[2]) > 0
+        assert cpu_ms / 1000.0 < seconds / 2     # it slept: off the core
+        # The running task's flight names the same hold, and the probe's
+        # longest holds list it.
+        stamped = [e for e in rec.get("t-run").events()
+                   if e[1] == flight.EV_LOOP_LAG and e[4] == note]
+        assert len(stamped) == 1 and stamped[0][3] == seconds
+        assert note in [h["note"] for h in p.summary()["holds"]]
+        # A hold ends a slice: the slice that holds it is at least as long.
+        slices = _acct_events(rec, "ut-hold", flight.EV_LOOP_ACCT)
+        assert any(e[3] >= seconds * 1000.0 and e[0] == mine[0][0]
+                   for e in slices)
+
+    def test_busy_and_idle_are_the_wall_time_since_arm(self, run_async):
+        async def body():
+            obs = RuntimeObservatory(ProfConfig(enabled=False))
+            before_arm = time.perf_counter_ns()
+            p = obs.arm_loop("ut-wall")
+            after_arm = time.perf_counter_ns()
+            for _ in range(50):
+                await asyncio.sleep(0)
+            await asyncio.sleep(0.03)
+            time.sleep(0.01)
+            live = p.summary()              # asked from inside a turn
+            before_disarm = time.perf_counter_ns()
+            p.disarm()
+            after_disarm = time.perf_counter_ns()
+            return (p, p.summary(), live, before_disarm - after_arm,
+                    after_disarm - before_arm)
+
+        p, s, live, inner_ns, outer_ns = run_async(body(), timeout=30)
+        wall_ns = (s["busy_s"] + s["idle_s"]) * 1e9
+        assert inner_ns - 1 <= wall_ns <= outer_ns + 1
+        assert s["idle_s"] >= 0.029 and s["busy_s"] >= 0.01
+        assert 0 < s["cpu_s"] <= s["busy_s"]
+        assert s["iterations"] >= 51 and s["handles"] >= 51
+        # The live reading is of the same account, a moment earlier.
+        assert live["busy_s"] <= s["busy_s"]
+        assert live["busy_s"] + live["idle_s"] <= wall_ns / 1e9
+        # Once disarmed the account stands still.
+        assert p.summary() == s
+
+    def test_slices_sum_to_the_cumulative_busy_time(self, run_async):
+        rec = flight.FlightRecorder(max_tasks=8)
+        obs = RuntimeObservatory(ProfConfig(enabled=False), recorder=rec)
+        busy_c = proflib.LOOP_BUSY_SECONDS.labels("ut-slices")
+        busy_before = busy_c._value.get()
+
+        async def body():
+            p = obs.arm_loop("ut-slices")
+            for _ in range(8):
+                time.sleep(0.003)           # turns of 3 ms: 5 ms a slice
+                await asyncio.sleep(0)
+            await asyncio.sleep(0.01)
+            p.disarm()
+            return p
+
+        p = run_async(body(), timeout=30)
+        slices = _acct_events(rec, "ut-slices", flight.EV_LOOP_ACCT)
+        assert len(slices) >= 4             # 24 ms of turns, 5 ms a slice
+        assert sum(e[3] for e in slices) == pytest.approx(
+            p.busy_ns / 1e6, rel=1e-9)
+        assert p.busy_ns >= 24_000_000
+        assert sum(e[2] for e in slices) <= p.cpu_ns // 1000
+        notes = [_fields(e[4]) for e in slices]
+        assert sum(int(f["it"]) for f in notes) == p.iterations
+        assert sum(int(f["n"]) for f in notes) == p.handles
+        assert sum(float(f["late"]) for f in notes) == pytest.approx(
+            p.late_ns / 1e6, abs=0.001 * len(notes))
+        assert busy_c._value.get() - busy_before == pytest.approx(
+            p.busy_ns / 1e9)
+
+    def test_a_collection_on_the_loops_thread_shows_in_gc(self, run_async):
+        rec = flight.FlightRecorder(max_tasks=8)
+        obs = RuntimeObservatory(ProfConfig(enabled=False), recorder=rec)
+
+        async def body():
+            obs.gc.arm()
+            p = obs.arm_loop("ut-gc")
+            try:
+                await asyncio.sleep(0)
+                junk = []
+                for _ in range(1000):
+                    cycle = [junk]
+                    cycle.append(cycle)
+                    junk.append(cycle)
+                del junk, cycle
+                gc.collect()
+                await asyncio.sleep(0)
+            finally:
+                p.disarm()
+                obs.gc.disarm()
+            return p
+
+        p = run_async(body(), timeout=30)
+        assert p.gc_ns > 0
+        assert p.gc_ns <= p.busy_ns         # it ran inside the turns
+        assert p.summary()["gc_s"] == p.gc_ns / 1e9
+        slices = _acct_events(rec, "ut-gc", flight.EV_LOOP_ACCT)
+        assert sum(float(_fields(e[4])["gc"]) for e in slices) > 0
+
+    def test_a_late_wake_is_a_hold_of_its_own_kind(self, run_async):
+        """A due timer that waits while the loop still sits in ``select``
+        (here: a selector that comes back 30 ms late) is ``late``, not
+        busy: the loop was due and did not run."""
+        rec = flight.FlightRecorder(max_tasks=8)
+        obs = RuntimeObservatory(ProfConfig(enabled=False), recorder=rec)
+
+        async def body():
+            selector = asyncio.get_running_loop()._selector
+            real = selector.select
+
+            def oversleeps(timeout=None):
+                events = real(timeout)
+                if timeout:
+                    time.sleep(0.03)
+                return events
+
+            selector.select = oversleeps
+            try:
+                p = obs.arm_loop("ut-late")
+                await asyncio.sleep(0.01)
+                p.disarm()
+                # An attribute that was there before arm() is there again.
+                assert vars(selector)["select"] is oversleeps
+            finally:
+                del selector.select
+            return p
+
+        p = run_async(body(), timeout=30)
+        assert p.late_ns >= 30_000_000
+        late = [e for e in _acct_events(rec, "ut-late", flight.EV_LOOP_LAG)
+                if e[4] == "late"]
+        assert len(late) >= 1 and late[0][3] >= 0.03
+        assert p.busy_ns < p.late_ns        # nobody held the loop
+
+    def test_an_account_that_raises_takes_itself_off(self, run_async):
+        """Whatever the account does wrong it does once: no exception
+        reaches the loop, no line a turn reaches the log, ``select`` is the
+        selector's own again and the loop turns on."""
+        async def body():
+            obs = RuntimeObservatory(ProfConfig(enabled=False))
+            selector = asyncio.get_running_loop()._selector
+            p = obs.arm_loop("ut-raises")
+            calls = []
+
+            def broken(end_ns):
+                calls.append(end_ns)
+                raise ValueError("a negative increment, say")
+
+            p._slice = broken
+            time.sleep(0.006)               # a turn that fills a slice
+            await asyncio.sleep(0)
+            assert len(calls) == 1
+            assert "select" not in vars(selector)
+            assert threading.get_ident() not in obs.sampler.loops
+            time.sleep(0.006)
+            await asyncio.sleep(0.001)
+            assert len(calls) == 1
+            p.disarm()                      # nothing left to do, and no raise
+
+        run_async(body(), timeout=30)
+
+    def test_disarm_leaves_select_as_it_found_it(self, run_async):
+        async def body():
+            obs = RuntimeObservatory(ProfConfig(enabled=False))
+            selector = asyncio.get_running_loop()._selector
+            assert "select" not in vars(selector)
+            p = obs.arm_loop("ut-arm")
+            wrapper = vars(selector)["select"]
+            assert p.arm() is p             # armed: a second arm is none
+            assert vars(selector)["select"] is wrapper
+            assert wrapper.__closure__ is not None
+            assert obs.sampler.loops[threading.get_ident()] is p
+            # Re-arming the NAME replaces the probe and does not nest: the
+            # new wrapper's inner select is the selector's own method.
+            q = obs.arm_loop("ut-arm")
+            assert q is not p and q._inner.__self__ is selector
+            assert obs.sampler.loops[threading.get_ident()] is q
+            await asyncio.sleep(0)
+            q.disarm()
+            assert "select" not in vars(selector)
+            assert threading.get_ident() not in obs.sampler.loops
+            q.disarm()                      # idempotent
+            await asyncio.sleep(0.001)      # the loop turns on, unwrapped
+            iterations = q.summary()["iterations"]
+            await asyncio.sleep(0.001)
+            assert q.summary()["iterations"] == iterations
+
+        run_async(body(), timeout=30)
 
 
 # --------------------------------------------------------------------- #
@@ -346,7 +606,7 @@ class TestLoopLagSLO:
         from dragonfly2_tpu.pkg import slo as slolib
 
         obs = RuntimeObservatory(ProfConfig(enabled=False))
-        p = LoopLagProbe(obs, "ut", interval_s=0.05, slow_s=0.25)
+        p = LoopLagProbe(obs, "ut", slow_s=0.25)
         obs.probes["ut"] = p
         p.started_mono = time.monotonic() - 5.0
         p.note_lag(1.5)                     # 1.5 s wedge in ~5 s observed
@@ -474,8 +734,15 @@ class TestFlightRuntimeIntegration:
     def _report_with_runtime(self):
         tf = flight.TaskFlight("rt-task")
         tf.record(flight.EV_REGISTER)
-        tf.record(flight.EV_LOOP_LAG, -1, 0.7, "loop_lag")
-        tf.record(flight.EV_LOOP_LAG, -1, 0.3, "loop_lag")
+        tf.record(flight.EV_LOOP_LAG, 12, 0.7,
+                  "held n=1 gc=0.0 who=local_store.py:save_metadata:88")
+        tf.record(flight.EV_LOOP_LAG, 0, 0.3, "late")
+        # Holds under the wedge's 0.25 s name their holder and count as
+        # no wedge.
+        tf.record(flight.EV_LOOP_LAG, 20, 0.023,
+                  "held n=3 gc=0.0 who=device_feed.py:_land:207")
+        tf.record(flight.EV_LOOP_LAG, 21, 0.024,
+                  "held n=2 gc=1.5 who=device_feed.py:_land:207")
         tf.record(flight.EV_GC_PAUSE, -1, 0.12, "gc_pause")
         tf.finish("done", "")
         return flight.analyze(tf)
@@ -487,15 +754,35 @@ class TestFlightRuntimeIntegration:
         assert rt["loop_lag"]["max_s"] == pytest.approx(0.7)
         assert rt["loop_lag"]["total_s"] == pytest.approx(1.0)
         assert rt["gc_pause"]["count"] == 1
+        # Every held hold by who ran in it, the longest sum first.
+        assert rt["holds"] == 3
+        assert list(rt["holders"].items()) == [
+            ("local_store.py:save_metadata:88", pytest.approx(0.7)),
+            ("device_feed.py:_land:207", pytest.approx(0.047))]
 
     def test_advisory_renders_in_waterfall(self):
         rep = self._report_with_runtime()
         advisory = flight.runtime_advisory(rep)
+        assert ("event loop held 3x, 0.70 s by "
+                "local_store.py:save_metadata:88") in advisory
         assert "event loop wedged 2x" in advisory
         assert "gc paused 1x" in advisory
         assert "/debug/prof" in advisory
         text = flight.render_waterfall(rep)
         assert advisory in text
+
+    def test_short_holds_alone_still_name_their_holder(self):
+        tf = flight.TaskFlight("rt-short")
+        tf.record(flight.EV_REGISTER)
+        for _ in range(3):
+            tf.record(flight.EV_LOOP_LAG, 22, 0.1,
+                      "held n=1 gc=0.0 who=device_feed.py:_land:207")
+        tf.finish("done", "")
+        rep = flight.analyze(tf)
+        assert "loop_lag" not in rep["runtime"]     # no wedge among them
+        assert flight.runtime_advisory(rep) == (
+            "runtime interference: event loop held 3x, 0.30 s by "
+            "device_feed.py:_land:207 during this task — see /debug/prof")
 
     def test_quiet_runtime_prints_no_advisory(self):
         tf = flight.TaskFlight("quiet")
@@ -627,8 +914,7 @@ class TestRuntimeObservatoryE2E:
         rec = flight.recorder()
 
         async def body():
-            cfg = ProfConfig(hz=100, lag_interval_s=0.02, lag_slow_s=0.2,
-                             gc_slow_s=0.0)
+            cfg = ProfConfig(hz=100, lag_slow_s=0.2, gc_slow_s=0.0)
             # The install below must create the singleton (first cfg
             # wins): a leaked observatory from another test would run
             # this drill with the wrong thresholds.
@@ -686,7 +972,7 @@ class TestRuntimeObservatoryE2E:
                     junk.append(cycle)
                     gc.collect(0)
                 time.sleep(0.45)            # wedge: blocks loop + pieces
-                await asyncio.sleep(0.1)    # heartbeat observes the wedge
+                await asyncio.sleep(0)      # the turn ends: it is booked
                 await asyncio.wait_for(run, timeout=60)
                 assert store.is_complete()
                 rec.finish_task(task_id, "done")
@@ -697,7 +983,7 @@ class TestRuntimeObservatoryE2E:
                 # finish the broadcast (burn = 100 * wedged/observed;
                 # observed stays well under the 25 s break-even).
                 time.sleep(1.0)
-                await asyncio.sleep(0.1)    # heartbeat observes it
+                await asyncio.sleep(0)      # booked as its turn ends
 
                 # Give the 100 Hz sampler a beat to catch the burner.
                 deadline = time.monotonic() + 5.0
@@ -762,6 +1048,18 @@ class TestRuntimeObservatoryE2E:
                 assert "runtime interference" in text
                 assert "event loop wedged" in text
                 assert "/debug/prof" in text
+                # ...and names who held the loop: the 100 Hz sampler met
+                # this very coroutine inside the 0.45 s wedge.
+                assert any(who.startswith("test_prof.py:body:")
+                           for who in rt["holders"]), rt
+                assert "event loop held" in text and " s by " in text
+                # The loop's own ring kept the holds and the slices, and
+                # the runtime report the account.
+                assert loop_sum["busy_s"] >= 1.45, loop_sum
+                assert loop_sum["holds"][0]["seconds"] >= 1.0, loop_sum
+                own = rec.get("runtime:loop:daemon")
+                assert sum(e[3] for e in own.events()
+                           if e[1] == flight.EV_LOOP_ACCT) >= 1450.0
             finally:
                 burn_stop.set()
                 burner.join(timeout=5)
