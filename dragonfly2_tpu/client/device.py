@@ -840,33 +840,42 @@ class HotSwapResult:
     stats: dict                     # delta resolver accounting (may be {})
 
 
-def _host_piece_checksums(store) -> dict[int, tuple[int, int]]:
-    """checksum_numpy over every piece of the landed disk copy — the
-    host side of the hot-swap verify gate. Every piece is read into ONE
-    pooled buffer: a fresh 32 MiB ``bytes`` a piece (``read_piece``) pays
-    its page faults again or not by the allocator's state, 0.9 to 5.9 s
-    for a shard's 55 pieces on the chip's host (PERF.md section 6,
-    PR 49)."""
+def _host_piece_checksums(store) -> tuple[dict[int, tuple[int, int]], int]:
+    """checksum_numpy of every piece of the verified landing, the host side
+    of the hot-swap verify gate, and how many of them were carried. A piece
+    a delta landing's job committed carries the pair the job took of the
+    bytes it wrote (``LocalTaskStore.word_sums``) and is not read again;
+    every other piece (a store read back from disk, a piece a resumed
+    landing found there, a version pulled whole) is walked here: read into
+    ONE pooled buffer (a fresh 32 MiB ``bytes`` a piece, ``read_piece``,
+    pays its page faults again or not by the allocator's state, 0.9 to
+    5.9 s for a shard's 55 pieces on the chip's host: PERF.md section 6,
+    PR 49) and summed on this thread. With every pair carried nothing is
+    read and no buffer taken."""
+    from dragonfly2_tpu.ops import hbm_sink
     from dragonfly2_tpu.ops.checksum import checksum_numpy
     from dragonfly2_tpu.storage.local_store import (
         acquire_read_buffer,
         release_read_buffer,
     )
 
-    out: dict[int, tuple[int, int]] = {}
     with store:
         pieces = store.get_pieces()
-        buf = acquire_read_buffer(
-            max((rec.size for rec in pieces), default=0) + 3)
-        try:
-            for rec in pieces:
-                padded = rec.size + (-rec.size) % 4
-                store.read_into(rec.offset, rec.size, buf)
-                buf[rec.size:padded] = bytes(padded - rec.size)
-                out[rec.num] = checksum_numpy(buf[:padded])
-        finally:
-            release_read_buffer(buf)
-    return out
+        sums = store.word_sums()
+        out = {rec.num: sums[rec.num] for rec in pieces if rec.num in sums}
+        walked = [rec for rec in pieces if rec.num not in out]
+        if walked:
+            buf = acquire_read_buffer(max(rec.size for rec in walked))
+            try:
+                for rec in walked:
+                    store.read_into(rec.offset, rec.size, buf)
+                    out[rec.num] = checksum_numpy(buf[:rec.size])
+            finally:
+                release_read_buffer(buf)
+    carried = len(pieces) - len(walked)
+    hbm_sink.SWAP_HOST_SUMS.labels("carried").inc(carried)
+    hbm_sink.SWAP_HOST_SUMS.labels("walked").inc(len(walked))
+    return out, carried
 
 
 def _swap_runs(new_m, base_m) -> list:
@@ -897,8 +906,10 @@ def _swap_on_device(store, live, plan, names, shardings, tf):
     run holds staged from the verified landing, the new word buffer
     assembled beside the live one, EVERY piece of it checked on the device
     against the host's sums of the landing (the flip gate: a mismatch
-    raises ValueError), then the typed tensors cut from it and ready.
-    Each step is a span on the delta task's flight."""
+    raises ValueError; the sums are the ones the landing's piece jobs
+    carried, or ``_host_piece_checksums``'s walk), then the typed tensors
+    cut from it and ready. Each step is a span on the delta task's flight.
+    Returns the words, the tensors and how many pieces' sums were carried."""
     import jax
 
     from dragonfly2_tpu.ops import hbm_sink
@@ -920,9 +931,10 @@ def _swap_on_device(store, live, plan, names, shardings, tf):
             words = hbm_sink.assemble_swap_words(live, plan, slabs, device)
             del slabs
         with span(tf.record, flightlib.EV_SWAP_VERIFY,
-                  meta.total_piece_count):
-            hbm_sink.verify_words_against_host(
-                words, meta.piece_size, _host_piece_checksums(store))
+                  meta.total_piece_count) as step:
+            sums, carried = _host_piece_checksums(store)
+            step.note = str(carried)
+            hbm_sink.verify_words_against_host(words, meta.piece_size, sums)
         # The swap's two programs (the copy, the checksums) are one for a
         # geometry: a compile here is a geometry's first swap.
         hbm_sink.SWAP_ASSEMBLIES.labels(
@@ -932,7 +944,7 @@ def _swap_on_device(store, live, plan, names, shardings, tf):
                                      names=names, shardings=shardings)
         jax.block_until_ready(list(tensors.values()))
         step.piece = len(tensors)
-    return words, tensors
+    return words, tensors, carried
 
 
 async def download_delta(daemon, url: str, *, base, hot=None,
@@ -960,10 +972,14 @@ async def download_delta(daemon, url: str, *, base, hot=None,
     runs HBM->HBM out of the live words, stages only what no run holds
     from the disk landing (``hbm_sink.plan_swap``: one compiled program a
     geometry, whichever tensors a version changed), and verifies EVERY
-    piece of the assembled buffer on-device against the disk copy's piece
-    checksums BEFORE the flip; the tensors are cut from the new words as a
-    landing's are (``ops/safetensors.load_from_words``) and are ready when
-    it flips.
+    piece of the assembled buffer on-device against the host's piece
+    checksums of the verified landing BEFORE the flip. Those are the sums
+    the landing's piece jobs took of the bytes they committed, read only
+    now that the task is done and its whole-object digest verified; a piece
+    that carries none is read back from the store and summed
+    (``_host_piece_checksums``; ``stats["host_sums_carried"]`` counts the
+    carried). The tensors are cut from the new words as a landing's are
+    (``ops/safetensors.load_from_words``) and are ready when it flips.
     """
     import asyncio
 
@@ -1031,6 +1047,7 @@ async def download_delta(daemon, url: str, *, base, hot=None,
                 runs = _swap_runs(new_m, base_m)
                 how = "built" if built else "fetched"
         on_device = True
+        stats = dict(tm.delta_stats.get(final.task_id, {}))
         try:
             plan = hbm_sink.plan_swap(
                 runs, total, max(1, store.metadata.total_piece_count)
@@ -1038,8 +1055,9 @@ async def download_delta(daemon, url: str, *, base, hot=None,
                 0 if live is None else int(live.shape[0]))
             tf.record(flightlib.EV_SWAP_PLAN, plan.runs,
                       (time.perf_counter() - planning) * 1000.0, how)
-            words, tensors = await asyncio.to_thread(
+            words, tensors, carried = await asyncio.to_thread(
                 _swap_on_device, store, live, plan, names, shardings, tf)
+            stats["host_sums_carried"] = carried
             reused, staged = plan.reused_bytes, total - plan.reused_bytes
         except (st.SafetensorsError, DfError):
             raise
@@ -1084,8 +1102,7 @@ async def download_delta(daemon, url: str, *, base, hot=None,
     return HotSwapResult(
         task_id=final.task_id, content_length=total, generation=generation,
         buffer=words, tensors=tensors, on_device=on_device, flipped=flipped,
-        reused_device_bytes=reused, staged_bytes=staged,
-        stats=dict(tm.delta_stats.get(final.task_id, {})))
+        reused_device_bytes=reused, staged_bytes=staged, stats=stats)
 
 
 _NP_DTYPES = {

@@ -42,6 +42,7 @@ from dragonfly2_tpu.pkg import dflog, metrics
 from dragonfly2_tpu.pkg import flight as flightlib
 from dragonfly2_tpu.pkg.errors import Code, DfError, StorageError, describe
 from dragonfly2_tpu.pkg.piece import compute_piece_count, compute_piece_size
+from dragonfly2_tpu.pkg.wordsum import checksum_numpy
 from dragonfly2_tpu.storage.local_store import (
     LocalTaskStore,
     acquire_read_buffer,
@@ -564,15 +565,26 @@ def _sha256_hex(view) -> str:
     return hashlib.sha256(view).hexdigest()
 
 
+def _commit_piece(job: _PieceJob, buf: memoryview, store) -> None:
+    """Write the piece's own slice of ``buf``, every chunk of which has held
+    its digest, with the (sum32, xor32) of exactly that slice, taken here by
+    the thread that commits it: a hot-swap's flip gate compares them with
+    the device's sums and reads the landing no second time
+    (``client.device._host_piece_checksums``). What ``buf`` holds past the
+    slice is a straddling chunk's, the next piece's to sum."""
+    piece = buf[job.start - job.lo:job.end - job.lo]
+    store.write_piece(job.num, piece, word_sums=checksum_numpy(piece))
+
+
 def _build_piece(job: _PieceJob, buf: memoryview, views: dict, base_store,
                  store) -> tuple[list[tuple[Chunk, Chunk]], float, float]:
     """A piece job's thread half, whole: the fetched slices copied to their
     places in ``buf``, every reused chunk read there straight from the base
     store and sha256-ed where it lies, and, when every digest held, the
-    piece's own slice of ``buf`` written. Returns the reused chunks that
-    failed (the coroutine re-fetches them and writes the piece itself) and
-    the clock readings around the reads and digests. Touches nothing of the
-    event loop's."""
+    piece's own slice of ``buf`` summed and written (``_commit_piece``).
+    Returns the reused chunks that failed (the coroutine re-fetches them and
+    commits the piece itself) and the clock readings around the reads and
+    digests. Touches nothing of the event loop's."""
     lo = job.lo
     for c, span in job.fetched:
         at = c.offset - span[0]
@@ -592,7 +604,7 @@ def _build_piece(job: _PieceJob, buf: memoryview, views: dict, base_store,
             bad.append((c, b))
     t1 = time.perf_counter()
     if not bad:
-        store.write_piece(job.num, buf[job.start - lo:job.end - lo])
+        _commit_piece(job, buf, store)
     return bad, t0, t1
 
 
@@ -657,8 +669,7 @@ async def _land_piece(job: _PieceJob, bufs: list, widest: int, store,
             tf.record(flightlib.EV_DELTA_FETCH, job.num,
                       (time.perf_counter() - t0) * 1000.0, str(c.length))
         if bad:
-            await _in_thread(store.write_piece, job.num,
-                             buf[job.start - job.lo:job.end - job.lo])
+            await _in_thread(_commit_piece, job, buf, store)
     finally:
         if buf is not None:
             bufs.append(buf)

@@ -19,26 +19,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-
-def _pad_to_words(data: bytes) -> np.ndarray:
-    pad = (-len(data)) % 4
-    if pad:
-        data = data + b"\x00" * pad
-    return np.frombuffer(data, dtype="<u4")
-
-
-def checksum_numpy(data: bytes) -> tuple[int, int]:
-    """Host-side reference: (sum32, xor32). The sum is taken at the word's
-    own width: a uint32 reduction wraps mod 2^32, which IS sum32's
-    definition, and it runs at the xor's speed, where a sum widened to
-    uint64 goes through numpy's buffered cast at a third of it (PERF.md
-    section 5, "The passes, alone")."""
-    words = _pad_to_words(data)
-    s = int(np.add.reduce(words, dtype=np.uint32))
-    x = int(np.bitwise_xor.reduce(words))
-    return s, x
+# The host's half, on numpy alone, for the processes that import no jax.
+from dragonfly2_tpu.pkg.wordsum import checksum_numpy  # noqa: F401
 
 
 @functools.partial(jax.jit, static_argnames=("piece_words",))

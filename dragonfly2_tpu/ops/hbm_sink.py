@@ -964,6 +964,12 @@ SWAP_BYTES = metrics.counter(
     "buffer: copied HBM -> HBM out of the live generation (hbm_reused), or "
     "read from the verified landing in the store and put (staged); the two "
     "sum to the content", ("how",))
+SWAP_HOST_SUMS = metrics.counter(
+    "device_swap_host_sums_total",
+    "Pieces of hot-swapped landings by where the flip gate's host sums came "
+    "from: carried from the commit by the delta job that wrote the piece "
+    "(carried), or read back from the store and summed before the gate "
+    "(walked)", ("how",))
 SWAP_RESULTS = metrics.counter(
     "device_swap_total",
     "Hot-swaps of the client API by how they ended: the verified generation "
@@ -1179,9 +1185,11 @@ def verify_words_against_host(words, piece_size: int,
                               host_checksums: "dict[int, tuple[int, int]]") -> None:
     """On-device verification gate for a hot-swap flip: per-piece
     (sum32, xor32) of the device's word buffer (whole pieces, zeros past
-    the content) compared against host-side values (checksum_numpy over
-    the disk copy's pieces). Raises SwapVerifyError (a ValueError) naming
-    the first mismatching piece; the flip must not happen."""
+    the content) compared against host-side values (checksum_numpy of the
+    verified landing's pieces, wherever taken: by the delta job that
+    committed a piece or by a walk of the store; none is trusted over the
+    device's). Raises SwapVerifyError (a ValueError) naming the first
+    mismatching piece; the flip must not happen."""
     if piece_size % 4:
         raise ValueError(f"piece size {piece_size} not 4-byte aligned")
     piece_words = piece_size // 4

@@ -496,6 +496,14 @@ class LocalTaskStore:
         # passed — seeds validate the full digest before done). The skip
         # compares verified-against values to THIS map, piece by piece.
         self.certified_digests: "dict[int, str] | None" = None
+        # num -> (sum32, xor32) of the piece's bytes (ops/checksum.py's
+        # definition), for the pieces whose committing writer took them
+        # from the bytes it wrote (a delta landing's piece jobs): the host
+        # side of a hot-swap's flip gate without a second read of the
+        # landing. In memory only and tied to the bytes: set by the commit
+        # that wrote them, dropped when the piece is re-recorded and when
+        # the task is invalidated; a store read back from disk has none.
+        self._word_sums: dict[int, tuple[int, int]] = {}
         # Optional StorageObserver (see storage/manager.py): notified on
         # piece commits and geometry updates so external indexes (the
         # native upload server's serving registry) stay current. Called
@@ -574,6 +582,8 @@ class LocalTaskStore:
         if ph is not None:
             self._prefix_hasher = None
             ph.stop()
+        with self._meta_lock:
+            self._word_sums.clear()
         self.close()
         shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -635,7 +645,8 @@ class LocalTaskStore:
     # -- piece IO ----------------------------------------------------------
 
     def write_piece(self, num: int, data, expected_digest: str = "",
-                    cost_ms: int = 0, algorithm: str = "") -> PieceRecord:
+                    cost_ms: int = 0, algorithm: str = "",
+                    word_sums: "tuple[int, int] | None" = None) -> PieceRecord:
         """Write piece ``num`` (``data`` is any bytes-like — pooled read
         buffers land without a bytes() copy). Verifies the per-piece digest
         before the write lands (reference local_storage.go:102-196 hashes
@@ -643,7 +654,10 @@ class LocalTaskStore:
         with ``algorithm`` (default: preferred_piece_algorithm — hardware
         crc32c fused into the write when the native library is present).
         Receive paths that hold the body as wire chunks use
-        ``write_piece_chunks`` instead (digest fused into the write)."""
+        ``write_piece_chunks`` instead (digest fused into the write).
+        ``word_sums``: the (sum32, xor32) the caller took of exactly
+        ``data``, kept with the piece by the commit (``word_sums()``); no
+        sum is taken here, so a writer that brings none pays nothing."""
         m = self.metadata
         if m.piece_size <= 0:
             raise StorageError("piece size not set")
@@ -696,7 +710,8 @@ class LocalTaskStore:
                 written += os.pwrite(fd, mv[written:], offset + written)
         rec = PieceRecord(num=num, offset=offset, size=len(data),
                           digest=digest_str, cost_ms=cost_ms)
-        return self._commit_piece_record(rec, feed_chunks=(data,))
+        return self._commit_piece_record(rec, feed_chunks=(data,),
+                                         word_sums=word_sums)
 
     def _pwritev_chunks(self, fd: int, chunks: list, offset: int,
                         num: int) -> None:
@@ -914,8 +929,8 @@ class LocalTaskStore:
         on completion. See ``certifies`` for the provenance argument."""
         return self.certifies(self.certified_digests)
 
-    def _commit_piece_record(self, rec: PieceRecord,
-                             feed_chunks=None) -> PieceRecord:
+    def _commit_piece_record(self, rec: PieceRecord, feed_chunks=None,
+                             word_sums=None) -> PieceRecord:
         """The single metadata-commit point for all write paths (in-memory
         write_piece/write_piece_chunks and native-transport record_piece):
         record under the lock, then persist the piece map in batches so a
@@ -924,11 +939,17 @@ class LocalTaskStore:
         bytes when the writer still holds them — the prefix hasher
         advances from memory instead of re-reading landed bytes (fed
         after the lock, in this worker thread, while the buffers are
-        still owned by the caller)."""
+        still owned by the caller). ``word_sums`` are the committed
+        bytes' own (``write_piece``); a piece re-recorded without any loses
+        the pair its old bytes carried."""
         with self._meta_lock:
             existing = self.metadata.pieces.get(rec.num)
             self.metadata.pieces[rec.num] = rec
             self.touch()
+            if word_sums is not None:
+                self._word_sums[rec.num] = word_sums
+            elif existing is not None:
+                self._word_sums.pop(rec.num, None)
             if existing is None:
                 self._unsaved_pieces += 1
             ph = self._prefix_hasher
@@ -1044,6 +1065,13 @@ class LocalTaskStore:
     def has_piece(self, num: int) -> bool:
         return num in self.metadata.pieces
 
+    def word_sums(self) -> dict[int, tuple[int, int]]:
+        """num -> (sum32, xor32) of the pieces whose committing writer
+        brought the pair (``write_piece``'s ``word_sums``); the others are
+        the reader's to sum."""
+        with self._meta_lock:
+            return dict(self._word_sums)
+
     @property
     def data_path(self) -> str:
         """Path of the on-disk data file (upload server sendfile source)."""
@@ -1079,6 +1107,8 @@ class LocalTaskStore:
         if ph is not None:
             self._prefix_hasher = None
             ph.stop()
+        with self._meta_lock:
+            self._word_sums.clear()
         self.metadata.invalid = True
         self.save_metadata()
 
