@@ -27,6 +27,8 @@ from dragonfly2_tpu.proto.common import UrlMeta
 
 log = dflog.get("client.device")
 
+CACHE_SCHEME = "dfcache://"
+
 SHARDED_TASKS = metrics.counter(
     "device_sharded_tasks_total",
     "Spans of sharded pulls (download_sharded) by how they were served: "
@@ -185,6 +187,12 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
     the SAME range). Ranged landings verify by the per-piece digest chain
     only; a whole-content ``digest`` cannot apply to a slice.
 
+    A ``dfcache://<cache_id>`` URL (what ``save_from_device`` stores) is
+    pulled P2P-only: the request never goes back to a source, because there
+    is none, and where no host holds the entry it fails with the
+    scheduler's code. A range of it is cut by a host that holds it whole
+    (the scheduler's ``_trigger_range_holder``).
+
     A pod-wide pull (every host of a slice asking for the same object at
     once) is, on this path, plain P2P: each host registers for the task, the
     scheduler hands it up to ``candidate_parent_limit`` parents, slice-mates
@@ -213,6 +221,10 @@ async def download_to_device(daemon, url: str, *, digest: str = "",
         meta=UrlMeta(digest=digest, tag=tag, application=application,
                      header=header or {}, range=rng),
         device="tpu", sink_device=device,
+        # A cache entry has no origin: its pull is P2P-only by construction
+        # (this is every device entry point's one task request, the header
+        # fetch and the ranged tasks of download_sharded among them).
+        disable_back_source=url.startswith(CACHE_SCHEME),
     )
     if rng:
         req.range = Range.parse_http(rng)
@@ -814,6 +826,140 @@ async def download_global(daemon, url: str,
     tf.record(flightlib.EV_SHARD_VIEWS, len(out),
               (time.perf_counter() - viewing) * 1000.0)
     return ShardedTensors(out, tasks)
+
+
+# ------------------------------------------------------------------ #
+# The way out: a checkpoint leaves HBM through the fabric
+# ------------------------------------------------------------------ #
+
+@dataclass
+class SaveResult:
+    """An acknowledged save: ``holders`` are the ids of the hosts that hold
+    a verified copy, this host's first."""
+
+    task_id: str
+    cache_id: str
+    digest: str             # sha256:<hex> of the stored file
+    content_length: int
+    pieces: int
+    holders: list[str]
+
+
+class Save:
+    """A save whose snapshot is taken: the caller's tensors are its own
+    again (it may overwrite or donate them), and the rest runs behind it.
+    ``stall_s`` is what the call took."""
+
+    def __init__(self, task_id: str, cache_id: str, content_length: int,
+                 stall_s: float, running):
+        self.task_id = task_id
+        self.cache_id = cache_id
+        self.content_length = content_length
+        self.stall_s = stall_s
+        self._running = running
+
+    async def acked(self) -> SaveResult:
+        """Returns when ``replicas`` hosts, this one among them, hold a copy
+        whose pieces and sha256 verified; raises DfError where the save
+        failed or the replicas could not be made in time (the task is then
+        reported ``Failed`` to the scheduler)."""
+        import asyncio
+
+        return await asyncio.shield(self._running)
+
+
+async def save_from_device(daemon, tensors: dict, cache_id: str, *,
+                           tag: str = "", application: str = "",
+                           replicas: int = 2, metadata: dict | None = None,
+                           ack_timeout: float = 120.0) -> Save:
+    """Save ``tensors`` (name -> jax.Array, all on one local device) into
+    the fabric as ONE safetensors file, the persistent cache task
+    ``dfcache://<cache_id>``, replicated to ``replicas`` hosts. Any host
+    resumes it with ``download_to_device`` / ``download_sharded`` of that
+    URL, from the replicas alone.
+
+    Returns a ``Save`` as soon as the snapshot is taken, which is the
+    caller's stall (ByteCheckpoint's "checkpoint stall"): the writer's
+    header is laid out (``ops/safetensors.plan_file``), the file's words
+    are built on the device from the typed tensors and checksummed a piece
+    there (``ops/hbm_source.snapshot``). Behind the caller, until
+    ``Save.acked()``: the words come to the host a group of pieces at a
+    time, each piece is committed to this daemon's store on a worker
+    thread with its digest and its host (sum, xor) held equal to the
+    device's (a mismatch fails the save: what is stored is what was in
+    HBM), the whole-content sha256 follows the pieces on a thread of its
+    own (``PieceManager.import_pieces``); the task is registered with the
+    scheduler as a persistent cache task, and ``Finished`` is answered only
+    when ``replicas`` hosts hold a verified copy
+    (``TaskManager.import_source``; the scheduler's ``_replica_order`` says
+    which host is asked). No file is written outside the store.
+
+    ``dfcache import --persistent`` is the other form of the same task: it
+    reads a file and is answered BEFORE replication."""
+    import asyncio
+
+    from dragonfly2_tpu.daemon.peer.task_manager import FileTaskRequest
+    from dragonfly2_tpu.ops import hbm_sink, hbm_source
+    from dragonfly2_tpu.ops import safetensors as st
+    from dragonfly2_tpu.pkg.piece import compute_piece_size
+
+    called = time.perf_counter()
+    tm = daemon.task_manager
+    if not cache_id:
+        raise DfError(Code.BadRequest, "cache_id required")
+    req = FileTaskRequest(
+        url=CACHE_SCHEME + cache_id, output="",
+        meta=UrlMeta(tag=tag, application=application))
+    task_id = req.task_id()
+    tf = tm.flight.task(task_id)
+    try:
+        head, layout, total = st.plan_file(
+            {name: (x.dtype, x.shape) for name, x in tensors.items()},
+            metadata)
+        piece_size = compute_piece_size(total)
+        with hbm_sink.span(tf.record, flightlib.EV_SAVE_PACK,
+                           len(tensors)) as step:
+            snap = await asyncio.to_thread(
+                hbm_source.snapshot, tensors, head, layout, total, piece_size)
+            step.note = str(total)
+    except (st.SafetensorsError, hbm_source.SaveError) as e:
+        hbm_source.SAVE_FAILURES.labels("error").inc()
+        raise DfError(Code.BadRequest, f"save_from_device: {e}")
+    stall = time.perf_counter() - called
+    tf.record(flightlib.EV_SAVE_SNAPSHOT, snap.pieces, stall * 1000.0,
+              str(total))
+    hbm_source.SAVE_SECONDS.labels("snapshot").inc(stall)
+
+    async def run() -> SaveResult:
+        try:
+            result = await tm.import_source(
+                snap, req, replica_count=replicas,
+                wait_replicas_s=ack_timeout, stamp=tf.record)
+        except BaseException as e:
+            # By the code of who refused: the importer's piece gate, the
+            # scheduler's awaited Finished.
+            reason = {Code.ClientPieceDownloadFail: "mismatch",
+                      Code.SchedError: "replica"}.get(
+                          getattr(e, "code", None), "error")
+            hbm_source.SAVE_FAILURES.labels(reason).inc()
+            tm.flight.finish_task(task_id, "failed", note=str(e)[:200])
+            raise
+        finally:
+            snap.release()
+        hbm_source.SAVE_BYTES.labels("stored").inc(total)
+        hbm_source.SAVE_SECONDS.labels("ack").inc(
+            time.perf_counter() - called)
+        tm.flight.finish_task(task_id, "done")
+        return SaveResult(task_id=task_id, cache_id=cache_id,
+                          digest=result["digest"], content_length=total,
+                          pieces=snap.pieces, holders=result["holders"])
+
+    running = asyncio.ensure_future(run())
+    # A save nobody awaits still reports its failure.
+    running.add_done_callback(
+        lambda f: f.cancelled() or f.exception() is None or log.warning(
+            "save failed", task=task_id[:16], error=str(f.exception())[:200]))
+    return Save(task_id, cache_id, total, stall, running)
 
 
 # ------------------------------------------------------------------ #

@@ -215,7 +215,8 @@ class Daemon:
             meta=meta,
             flight=self.task_manager.flight.task(task_id),
             quarantine=self.task_manager.quarantine,
-            is_seed=is_seed or self.config.seed_peer,
+            is_seed=is_seed or (self.config.seed_peer
+                                and not getattr(request, "as_peer", False)),
             piece_parallelism=self.config.download.parent_concurrency,
             report_batch=self.config.download.report_batch,
             limiter=limiter if limiter is not None else self.task_manager.limiter,

@@ -24,14 +24,21 @@ from dragonfly2_tpu.pkg import dflog
 from dragonfly2_tpu.pkg import digest as pkgdigest
 from dragonfly2_tpu.pkg import flight as flightlib
 from dragonfly2_tpu.pkg import retry as retrylib
-from dragonfly2_tpu.pkg.errors import Code, SourceError
+from dragonfly2_tpu.pkg.errors import Code, DfError, SourceError
 from dragonfly2_tpu.pkg.piece import Range, compute_piece_count, compute_piece_size
 from dragonfly2_tpu.pkg.ratelimit import Limiter
+from dragonfly2_tpu.pkg.wordsum import checksum_numpy
 from dragonfly2_tpu.source import Request as SourceRequest
 from dragonfly2_tpu.source import get_client
 from dragonfly2_tpu.storage.local_store import LocalTaskStore, PieceRecord, _native
 
 log = dflog.get("peer.piece_manager")
+
+# An import from buffers (``import_pieces``): pieces a fetched group holds,
+# and groups fetched and not yet committed (the host's memory: that many
+# groups and the one being fetched).
+_GROUP_PIECES = 4
+_GROUPS_IN_FLIGHT = 2
 
 # piece arrival callback: fired after each piece lands in storage, with the
 # record and the store (conductor reports to scheduler + notifies subscribers)
@@ -574,6 +581,118 @@ class PieceManager:
                                             self._limiter, t0)
         finally:
             release_read_buffer(buf)
+
+    # -- import from buffers (client/device.py save_from_device) -----------
+
+    async def import_pieces(self, store: LocalTaskStore, source,
+                            stamp=None) -> str:
+        """Import content that lies in memory and not in a file: ``source``
+        has ``content_length``, ``piece_size``, ``fetch(first, count)`` (a
+        blocking call, run on a thread: the bytes of pieces [first, first +
+        count) as one buffer, the last piece cut to the content) and
+        ``sums``, piece -> the (sum32, xor32) the producer took of it, or
+        None; where it has ``prefetch(first, count)`` the next group is
+        asked for while this one is waited for. Returns the content's
+        ``sha256:`` digest.
+
+        A group is fetched on one thread, one group after the other, at most
+        ``_GROUPS_IN_FLIGHT`` fetched and not yet committed. Each of its
+        pieces is committed on a worker thread of its own: ``checksum_numpy``
+        of exactly the bytes handed to ``write_piece``, held equal to
+        ``source.sums`` (a mismatch fails the import: what is stored is what
+        the producer held), the piece digest fused into the write, the pair
+        kept with the piece (``word_sums``). The whole-content sha256 follows
+        the pieces on ONE thread of its own, in piece order, over the same
+        memory the workers write from: nothing is read back. ``stamp(code,
+        piece, ms, note)`` gets save_d2h, save_commit and save_digest."""
+        import hashlib
+        import queue
+        import threading
+        from concurrent.futures import Future
+
+        length, piece_size = source.content_length, source.piece_size
+        total = compute_piece_count(length, piece_size)
+        store.update_task(content_length=length, piece_size=piece_size,
+                          total_piece_count=total)
+        stamp = stamp or (lambda *a: None)
+        hasher = hashlib.sha256()
+        hashing: "queue.SimpleQueue" = queue.SimpleQueue()
+
+        def digest_loop() -> None:
+            while True:
+                item = hashing.get()
+                if item is None:
+                    return
+                first, views, done = item
+                t0 = time.perf_counter()
+                try:
+                    for view in views:
+                        hasher.update(view)
+                    stamp(flightlib.EV_SAVE_DIGEST, first,
+                          (time.perf_counter() - t0) * 1000.0,
+                          str(sum(len(v) for v in views)))
+                    done.set_result(None)
+                except BaseException as e:  # noqa: BLE001 - the job raises it
+                    done.set_exception(e)
+
+        def commit(num: int, view) -> None:
+            t0 = time.perf_counter()
+            sums = checksum_numpy(view)
+            want = source.sums.get(num) if source.sums else None
+            if want is not None and tuple(want) != sums:
+                raise DfError(
+                    Code.ClientPieceDownloadFail,
+                    f"piece {num}: the host's (sum, xor) {sums} differ from "
+                    f"the producer's {tuple(want)}")
+            store.write_piece(num, view, word_sums=sums)
+            stamp(flightlib.EV_SAVE_COMMIT, num,
+                  (time.perf_counter() - t0) * 1000.0, str(len(view)))
+
+        async def commit_group(first: int, buf) -> None:
+            views = [buf[i:i + piece_size]
+                     for i in range(0, len(buf), piece_size)]
+            hashed: Future = Future()
+            hashing.put((first, views, hashed))
+            try:
+                await asyncio.gather(*(
+                    asyncio.to_thread(commit, first + i, view)
+                    for i, view in enumerate(views)))
+                await asyncio.wrap_future(hashed)
+            finally:
+                in_flight.release()
+
+        groups = [(first, min(_GROUP_PIECES, total - first))
+                  for first in range(0, total, _GROUP_PIECES)]
+        in_flight = asyncio.Semaphore(_GROUPS_IN_FLIGHT)
+        thread = threading.Thread(
+            target=digest_loop, daemon=True,
+            name=f"df-save-digest-{store.metadata.task_id[:12]}")
+        thread.start()
+        jobs: list = []
+        try:
+            for i, (first, count) in enumerate(groups):
+                await in_flight.acquire()
+                failed = [j for j in jobs if j.done() and j.exception()]
+                if failed:
+                    raise failed[0].exception()
+                t0 = time.perf_counter()
+                if i + 1 < len(groups) and hasattr(source, "prefetch"):
+                    source.prefetch(*groups[i + 1])
+                buf = await asyncio.to_thread(source.fetch, first, count)
+                stamp(flightlib.EV_SAVE_D2H, first,
+                      (time.perf_counter() - t0) * 1000.0, str(len(buf)))
+                jobs.append(asyncio.ensure_future(commit_group(first, buf)))
+                del buf
+            await asyncio.gather(*jobs)
+        except BaseException:
+            for job in jobs:
+                job.cancel()
+            await asyncio.gather(*jobs, return_exceptions=True)
+            raise
+        finally:
+            hashing.put(None)
+        await asyncio.to_thread(thread.join)
+        return "sha256:" + hasher.hexdigest()
 
     # -- whole-content digest ----------------------------------------------
 

@@ -64,6 +64,10 @@ class FileTaskRequest:
     # scheduler stripes the DCN pull across same-slice hosts (1/S of the
     # bytes each; the rest fills intra-slice).
     pod_broadcast: bool = False
+    # A triggered replication (persistent cache): this daemon pulls from
+    # peers as a plain peer even where it is configured as a seed peer, whose
+    # every other task registers as a seed and is sent back to source.
+    as_peer: bool = False
 
     def task_id(self) -> str:
         return idgen.task_id_v1(
@@ -405,7 +409,48 @@ class TaskManager:
         piece_manager.go:662 ImportFile + dfcache Import). With
         ``persistent``, the scheduler records it as a persistent cache task
         and replicates it to ``replica_count`` hosts (reference
-        UploadPersistentCacheTask* family, service_v2.go:1726-1895)."""
+        UploadPersistentCacheTask* family, service_v2.go:1726-1895): the
+        scheduler answers ``Finished`` at once and replicates behind it."""
+        async def fill(store) -> None:
+            await self.piece_manager.import_file(store, path)
+            if req.meta.digest:
+                # Whole-content hash: off the loop (hashlib releases
+                # the GIL; inline it stalls every active transfer).
+                await asyncio.to_thread(
+                    store.validate_digest, req.meta.digest)
+                store.metadata.digest = req.meta.digest
+
+        return await self._import(fill, req, persistent=persistent,
+                                  replica_count=replica_count, ttl=ttl)
+
+    async def import_source(self, source, req: "FileTaskRequest", *,
+                            replica_count: int = 2, ttl: float = 0.0,
+                            wait_replicas_s: float = 120.0,
+                            stamp=None) -> dict:
+        """Import content that lies in memory (``source``:
+        ``PieceManager.import_pieces``'s) as a persistent cache task, and
+        return only when ``replica_count`` hosts, this one among them, hold
+        a copy whose pieces and sha256 verified: ``Finished`` carries the
+        digest this import took and is answered by the scheduler once the
+        replicas are made and each holder's daemon says so (``holders`` in
+        the result), or refused after ``wait_replicas_s``; the task is then
+        reported ``Failed``. An entry of the same id that this host already
+        holds is refused: a save never answers with another save's bytes."""
+        if self.storage.find_completed_task(req.task_id()) is not None:
+            raise DfError(Code.BadRequest,
+                          f"{req.url} is already held by this host")
+
+        async def fill(store) -> None:
+            store.metadata.digest = await self.piece_manager.import_pieces(
+                store, source, stamp)
+
+        return await self._import(
+            fill, req, persistent=True, replica_count=replica_count, ttl=ttl,
+            wait_replicas_s=wait_replicas_s, stamp=stamp)
+
+    async def _import(self, fill, req: "FileTaskRequest", *,
+                      persistent: bool, replica_count: int, ttl: float,
+                      wait_replicas_s: float = 0.0, stamp=None) -> dict:
         task_id = req.task_id()
         peer_id = req.peer_id or idgen.peer_id_v1(self.host_ip)
         if persistent:
@@ -416,40 +461,54 @@ class TaskManager:
                  "replica_count": replica_count, "ttl": ttl,
                  "digest": req.meta.digest})
         try:
-            result = await self._import_local(path, req, task_id, peer_id)
+            result = await self._import_local(fill, req, task_id, peer_id)
+            if persistent:
+                sent = time.perf_counter()
+                reply = await self._persistent_call(
+                    "Scheduler.UploadPersistentCacheTaskFinished", task_id,
+                    peer_id,
+                    {"content_length": result["content_length"],
+                     "piece_size": result.get("piece_size", 0),
+                     "total_piece_count": result.get("total_piece_count", -1),
+                     "digest": result["digest"],
+                     "wait_replicas_s": float(wait_replicas_s)},
+                    timeout=10.0 + wait_replicas_s)
+                result["holders"] = list((reply or {}).get("holders") or [])
+                if wait_replicas_s and stamp is not None:
+                    stamp(flightlib.EV_SAVE_REPLICATED,
+                          len(result["holders"]),
+                          (time.perf_counter() - sent) * 1000.0,
+                          ",".join(result["holders"]))
         except BaseException:
             if persistent:
                 try:
                     # Best-effort: a scheduler/network error here must not
-                    # mask the real import failure.
+                    # mask the real import failure. An import that asked
+                    # for verified replicas and got none leaves no task
+                    # that reads as durable.
                     await self._persistent_call(
                         "Scheduler.UploadPersistentCacheTaskFailed",
-                        task_id, peer_id, {})
+                        task_id, peer_id,
+                        {"unreplicated": bool(wait_replicas_s)})
                 except Exception as notify_err:
                     log.warning("persistent-failed notify failed",
                                 error=str(notify_err))
             raise
-        if persistent:
-            await self._persistent_call(
-                "Scheduler.UploadPersistentCacheTaskFinished", task_id, peer_id,
-                {"content_length": result["content_length"],
-                 "piece_size": result.get("piece_size", 0),
-                 "total_piece_count": result.get("total_piece_count", -1)})
         return result
 
     async def _persistent_call(self, method: str, task_id: str, peer_id: str,
-                               extra: dict) -> None:
+                               extra: dict, timeout: float = 10.0):
         if self.scheduler_client is None:
             raise DfError(Code.BadRequest,
                           "persistent import needs a scheduler connection")
         host_info = self.host_wire() if self.host_wire is not None else {}
         host_info.pop("telemetry", None)
-        await self.scheduler_client.unary(
+        return await self.scheduler_client.unary(
             task_id, method,
             {"task_id": task_id, "peer_id": peer_id,
-             "host": host_info, **extra})
+             "host": host_info, **extra}, timeout=timeout)
 
-    async def _import_local(self, path: str, req: "FileTaskRequest",
+    async def _import_local(self, fill, req: "FileTaskRequest",
                             task_id: str, peer_id: str) -> dict:
         existing = self.storage.find_completed_task(task_id)
         if existing is None:
@@ -458,13 +517,7 @@ class TaskManager:
                 tag=req.meta.tag, application=req.meta.application))
             with store:
                 try:
-                    await self.piece_manager.import_file(store, path)
-                    if req.meta.digest:
-                        # Whole-content hash: off the loop (hashlib releases
-                        # the GIL; inline it stalls every active transfer).
-                        await asyncio.to_thread(
-                            store.validate_digest, req.meta.digest)
-                        store.metadata.digest = req.meta.digest
+                    await fill(store)
                     store.mark_done()
                     self._pex_announce(task_id)
                 except BaseException:
@@ -480,7 +533,8 @@ class TaskManager:
                 "pieces": len(store.metadata.pieces),
                 "piece_size": store.metadata.piece_size,
                 "total_piece_count": store.metadata.total_piece_count,
-                "content_length": store.metadata.content_length}
+                "content_length": store.metadata.content_length,
+                "digest": store.metadata.digest}
 
     async def _announce_local_task(self, store, task_id: str, peer_id: str) -> None:
         """Tell the scheduler this host holds the complete task so it can be
@@ -729,7 +783,8 @@ class TaskManager:
                               disable_back_source=bool(
                                   spec.get("disable_back_source")),
                               device=spec.get("device", ""),
-                              pod_broadcast=bool(spec.get("pod_broadcast")))
+                              pod_broadcast=bool(spec.get("pod_broadcast")),
+                              as_peer=not is_seed)
         if meta.range:
             req.range = Range.parse_http(meta.range)
         task_id = spec.get("task_id") or req.task_id()

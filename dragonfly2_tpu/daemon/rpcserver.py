@@ -178,6 +178,9 @@ class DaemonRpcServer:
         if store is not None and store.pinned:
             return {"ok": False, "reason": "task store in use"}
         self.task_manager.storage.delete_task(task_id)
+        if self.task_manager.device_sinks is not None:
+            # A resident sink of the task goes with its store entry.
+            self.task_manager.device_sinks.discard(task_id)
         if self.task_manager.pex is not None:
             self.task_manager.pex.remove_task(task_id)
         return {"ok": True}

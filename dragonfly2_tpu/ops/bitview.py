@@ -136,6 +136,12 @@ VIEWS_TENSORS = metrics.counter(
     "shared a program)",
     ("form",))
 _TENSORS = {form: VIEWS_TENSORS.labels(form) for form in ("rows", "flat")}
+VIEWS_BYTES = metrics.counter(
+    "device_views_bytes_total",
+    "Bytes of the typed views those programs returned, by the form that cut "
+    "them: a checkpoint of 2-byte weights is cut by the rows kernel, a "
+    "training state's 4-byte tensors by the flat loops", ("form",))
+_BYTES = {form: VIEWS_BYTES.labels(form) for form in ("rows", "flat")}
 
 
 def _count(shape) -> int:
@@ -490,6 +496,7 @@ def typed_views(buffer, byte_offsets, dtype, shape) -> list:
                 # Traced into a caller's program it is no dispatch.
                 VIEWS_DISPATCHES.inc()
                 _TENSORS[form].inc(len(chunk))
+                _BYTES[form].inc(len(chunk) * _count(shape) * dtype.itemsize)
             for i, view in zip(chunk, views):
                 out[i] = view
     return out

@@ -57,6 +57,49 @@ def parse_header(head: bytes) -> tuple[dict, int]:
     return header, 8 + n
 
 
+_FILE_DTYPES = {np.dtype(v).name: k for k, v in _DTYPES.items()}
+
+
+def plan_file(specs: dict, metadata: dict | None = None) -> tuple[bytes, dict, int]:
+    """The writer's header for ONE safetensors file of the named tensors:
+    ``specs`` maps a name to ``(dtype, shape)``. Returns (the file's first
+    bytes: length prefix and header, name -> (byte offset in the file,
+    byte length) in the file's order, the file's length).
+
+    The writer's choices, which a reader need not know and a second writer
+    must repeat to give the same bytes: the tensors lie by item size, the
+    widest first, then by name, one behind the other without a gap (the
+    format allows none), so every tensor starts on a multiple of its item
+    size; the header is ``json.dumps`` with the separators ``(",", ":")``,
+    ``__metadata__`` first where there is any, then the tensors in the
+    file's order, padded with spaces so that the data starts on a multiple
+    of 8."""
+    entries = []
+    for name, (dtype, shape) in specs.items():
+        dt = np.dtype(dtype)
+        code = _FILE_DTYPES.get(dt.name)
+        if code is None or name == "__metadata__":
+            raise SafetensorsError(f"{name}: cannot write dtype {dt.name!r}")
+        shape = [int(d) for d in shape]
+        entries.append((-dt.itemsize, name, code,
+                        shape, math.prod(shape) * dt.itemsize))
+    entries.sort(key=lambda e: e[:2])
+    header: dict = {}
+    if metadata:
+        header["__metadata__"] = {str(k): str(v) for k, v in metadata.items()}
+    at = 0
+    for _, name, code, shape, nbytes in entries:
+        header[name] = {"dtype": code, "shape": shape,
+                        "data_offsets": [at, at + nbytes]}
+        at += nbytes
+    text = json.dumps(header, separators=(",", ":")).encode()
+    text += b" " * (-(8 + len(text)) % 8)
+    head = len(text).to_bytes(8, "little") + text
+    layout = {name: (len(head) + header[name]["data_offsets"][0], nbytes)
+              for _, name, _, _, nbytes in entries}
+    return head, layout, len(head) + at
+
+
 def header_metadata(header: dict) -> dict[str, str]:
     """The checkpoint's ``__metadata__`` entry as a plain dict ({} when
     absent). The format allows free-form string-to-string metadata

@@ -225,6 +225,26 @@ EV_SWAP_FLIP = 61
 # "late=<ms> gc=<ms> it=<iterations> n=<handles>". The same ring holds the
 # loop's ``loop_lag`` holds.
 EV_LOOP_ACCT = 62
+# A save of the client API (client/device.py ``save_from_device``), on the
+# persistent cache task's flight: each span ONE event at its end with aux =
+# its ms, as the sink_* spans. In order: save_pack (the programs that place
+# the tensors' bytes in the file's words and checksum them, dispatched ->
+# the sums on the host; piece = tensors, note = the file's bytes),
+# save_snapshot (the call -> the handle returned: the caller's stall, the
+# layout and save_pack inside it; piece = pieces, note = bytes); then behind
+# the caller save_d2h (a group of pieces copied to the host; piece = the
+# group's first, note = bytes), save_commit (one a piece, on a worker
+# thread: its host sums held equal to the device's, its digest, its write;
+# piece = num, note = bytes), save_digest (the digest thread's sha256 over
+# a group; piece = the group's first, note = bytes), save_replicated
+# (``Finished`` sent -> the scheduler's answer that ``replicas`` hosts hold
+# a verified copy; piece = holders, note = their host ids).
+EV_SAVE_SNAPSHOT = 63
+EV_SAVE_PACK = 64
+EV_SAVE_D2H = 65
+EV_SAVE_COMMIT = 66
+EV_SAVE_DIGEST = 67
+EV_SAVE_REPLICATED = 68
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -262,6 +282,9 @@ EVENT_NAMES = {
     EV_SWAP_ASSEMBLE: "swap_assemble", EV_SWAP_VERIFY: "swap_verify",
     EV_SWAP_VIEWS: "swap_views", EV_SWAP_FLIP: "swap_flip",
     EV_LOOP_ACCT: "loop_acct",
+    EV_SAVE_SNAPSHOT: "save_snapshot", EV_SAVE_PACK: "save_pack",
+    EV_SAVE_D2H: "save_d2h", EV_SAVE_COMMIT: "save_commit",
+    EV_SAVE_DIGEST: "save_digest", EV_SAVE_REPLICATED: "save_replicated",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING
@@ -279,7 +302,9 @@ _SINK_STEPS = (EV_SINK_LAND, EV_SINK_READ, EV_SINK_CHECKSUM, EV_SINK_STAGE,
 # The client API's steps, summed into the report's ``client`` block.
 _CLIENT_STEPS = (EV_ADMIT_WAIT, EV_SHARD_PLAN, EV_SHARD_VIEWS, EV_SWAP_PLAN,
                  EV_SWAP_STAGE, EV_SWAP_ASSEMBLE, EV_SWAP_VERIFY,
-                 EV_SWAP_VIEWS, EV_SWAP_FLIP)
+                 EV_SWAP_VIEWS, EV_SWAP_FLIP, EV_SAVE_SNAPSHOT, EV_SAVE_PACK,
+                 EV_SAVE_D2H, EV_SAVE_COMMIT, EV_SAVE_DIGEST,
+                 EV_SAVE_REPLICATED)
 # Chip-to-chip work of a landing: booked under ``ici`` beside the
 # intra-slice piece transfers.
 _ICI_STEPS = (EV_SINK_REPLICATE, EV_SINK_VERIFY_CHIPS)
@@ -884,7 +909,8 @@ def render_waterfall(report: dict) -> str:
     if client:
         lines.append("client api, ms (admit_wait: queued for a sink slot "
                      "before the task; shard_*: the sharded pull this task "
-                     "heads; swap_*: the hot-swap this delta task feeds): "
+                     "heads; swap_*: the hot-swap this delta task feeds; "
+                     "save_*: the save_from_device this task stores): "
                      + " ".join(
                          f"{k[:-3]}={v:.1f}" for k, v in client.items()))
     parent = report.get("parent") or {}

@@ -224,12 +224,21 @@ UNARY: dict[str, Msg] = {
         url=F(str), tag=F(str), application=F(str), piece_size=F(int),
         content_length=F(int), total_piece_count=F(int),
         replica_count=F(int), ttl=F(float), digest=F(str)),
+    # ``digest``: the content's digest as the uploader took it, given to
+    # the replicas to verify against. ``wait_replicas_s`` > 0 is the awaited
+    # form: the answer comes once ``replica_count`` hosts hold a verified
+    # copy and names them (``holders``), or is an error after that long;
+    # 0 answers at once and replicates behind the answer.
     "Scheduler.UploadPersistentCacheTaskFinished": Msg(
         "UploadPersistentCacheTaskFinished",
         **_PERSISTENT_COMMON,
-        content_length=F(int), piece_size=F(int), total_piece_count=F(int)),
+        content_length=F(int), piece_size=F(int), total_piece_count=F(int),
+        digest=F(str), wait_replicas_s=F(float)),
+    # ``unreplicated``: the uploader asked for verified replicas and got no
+    # answer: the task is kept as failed, whatever copies exist.
     "Scheduler.UploadPersistentCacheTaskFailed": Msg(
-        "UploadPersistentCacheTaskFailed", **_PERSISTENT_COMMON),
+        "UploadPersistentCacheTaskFailed", **_PERSISTENT_COMMON,
+        unreplicated=F(bool)),
     "Scheduler.StatPersistentCacheTask": Msg(
         "StatPersistentCacheTask", task_id=F(str, required=True)),
     "Scheduler.ListPersistentCacheTasks": Msg("ListPersistentCacheTasks"),

@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import asyncio
 
-from dragonfly2_tpu.pkg import aio, dflog
+from dragonfly2_tpu.pkg import aio, dflog, idgen
 from dragonfly2_tpu.pkg import cluster as clusterlib
 from dragonfly2_tpu.pkg import fleet as fleetlib
 from dragonfly2_tpu.pkg import flight as flightlib
@@ -90,6 +90,15 @@ INGEST_BATCH_PIECES = metrics.histogram(
     "coalesces under load (1 = idle single-piece latency path)",
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 1024))
 
+# URLs that name no origin: their content exists on the hosts that hold it
+# and nowhere else, so no peer of theirs is ever sent back to source.
+ORIGINLESS = "dfcache://"
+
+PERSISTENT_REPLICAS_VERIFIED = metrics.counter(
+    "scheduler_persistent_replicas_verified_total",
+    "Holders of a persistent cache task whose daemon answered an awaited "
+    "Finished's Peer.StatTask with the task done under the digest and "
+    "length on record")
 STATE_REBUILT_COUNT = metrics.counter(
     "scheduler_state_rebuilt_peers_total",
     "Peers whose Task/Peer state this scheduler rebuilt without having "
@@ -883,6 +892,8 @@ class SchedulerService:
     async def _maybe_trigger_seed(self, task: Task, requesting_peer: Peer) -> bool:
         """Pick the least-loaded live seed host and trigger a seed download.
         Returns True if a seed is (already) seeding this task."""
+        if task.url.startswith(ORIGINLESS):
+            return await self._trigger_range_holder(task, requesting_peer)
         if not self.config.seed_peer_enabled:
             return False
         # Already seeding?
@@ -912,6 +923,47 @@ class SchedulerService:
             self._mark_task_running(task)
             log.info("triggered seed download", task=task.id[:16], seed=seed_host.id)
         return ok
+
+    async def _trigger_range_holder(self, task: Task,
+                                    requesting_peer: Peer) -> bool:
+        """A task of a URL without an origin (``dfcache://``) has no seed
+        peer to send to a source: nothing is triggered for the whole entry,
+        whose holders are its parents or there are none. A RANGE of it can
+        be made by any host that holds the entry whole: that host is told
+        to seed the ranged task, which it cuts from its own store
+        (``range_import``: the seed's back-to-source with the origin off
+        the table) and then serves, as a seed peer serves a range of a URL.
+        Holders are the entry's persistent replicas and its finished
+        peers, another host than the asker's first."""
+        if not task.range_header:
+            return False
+        if self._seed_active(task):
+            return True
+        whole = idgen.parent_task_id_v1(
+            task.url, digest=task.digest, tag=task.tag,
+            application=task.application,
+            filters="&".join(task.filtered_query_params))
+        holders = {p["host_id"]: self._persistent_host(p["host_id"])
+                   for p in self.persistent.peers_of(whole, "succeeded")}
+        parent = self.tasks.load(whole)
+        for p in (parent.peers() if parent is not None else ()):
+            if p.fsm.current == PeerState.SUCCEEDED:
+                holders.setdefault(p.host.id, p.host)
+        hosts = sorted((h for h in holders.values()
+                        if h is not None and h.port > 0),
+                       key=lambda h: (h.id == requesting_peer.host.id, h.id))
+        for host in hosts:
+            if await self.seed_clients.trigger_download_task(host, {
+                    "task_id": task.id, "url": task.url, "tag": task.tag,
+                    "application": task.application, "digest": task.digest,
+                    "filters": task.filtered_query_params,
+                    "range": task.range_header, "seed": True,
+                    "disable_back_source": True}):
+                self._mark_task_running(task)
+                log.info("triggered range seed on a holder",
+                         task=task.id[:16], holder=host.id)
+                return True
+        return False
 
     # -- piece reports (reference :1291-1455) ------------------------------
 
@@ -1426,7 +1478,10 @@ class SchedulerService:
                                                     ctx: RpcContext) -> dict:
         """Uploader finished; record the first replica and fan replication
         triggers until replica_count is met (reference :1791 Finished +
-        the replica scheduling the Redis resource drives)."""
+        the replica scheduling the Redis resource drives). With
+        ``wait_replicas_s`` the answer is the awaited form
+        (``_await_replicas``): it comes when ``replica_count`` hosts hold a
+        verified copy, and names them."""
         from dragonfly2_tpu.scheduler.resource import persistentcache as pc
 
         task_id = body.get("task_id", "")
@@ -1438,26 +1493,79 @@ class SchedulerService:
             content_length=body.get("content_length", task["content_length"]),
             piece_size=body.get("piece_size", task["piece_size"]),
             total_piece_count=body.get("total_piece_count",
-                                       task["total_piece_count"]))
+                                       task["total_piece_count"]),
+            digest=body.get("digest") or task["digest"])
         h = body.get("host") or {}
         host_id = h.get("id") or h.get("hostname", "unknown")
         self.persistent.upsert_peer(body.get("peer_id", ""), task_id, host_id,
                                     state=pc.STATE_SUCCEEDED)
+        wait_s = float(body.get("wait_replicas_s") or 0.0)
+        if wait_s > 0:
+            return {"ok": True,
+                    "holders": await self._await_replicas(task_id, wait_s)}
         # Replication runs in the background: N trigger RPCs (10s timeout
         # each, possibly against dead hosts) must not stall — or fail — the
         # uploader's Finished ack.
         aio.spawn(self._ensure_replicas(task_id))
         return {"ok": True}
 
+    async def _await_replicas(self, task_id: str, wait_s: float) -> list[str]:
+        """The awaited ``Finished``: replicate now, and return the ids of
+        ``replica_count`` hosts that hold a verified copy, the uploader's
+        first. A holder is counted when its peer reported the task finished
+        AND its daemon answers ``Peer.StatTask`` with the task done under
+        the digest and length on record: the daemon marks a task done only
+        behind its pieces' digests and the whole-content check, so the
+        uploader's own copy is asked the same way. No host to replicate to,
+        or no such answer within ``wait_s``, marks the task failed and
+        raises: an acknowledgement promises the copies."""
+        from dragonfly2_tpu.scheduler.resource import persistentcache as pc
+
+        loop = asyncio.get_running_loop()
+        deadline = loop.time() + wait_s
+        task = self.persistent.get_task(task_id)
+        missing = task["replica_count"] - self.persistent.replica_count(task_id)
+        fired = await self._ensure_replicas(task_id)
+        verified: list[str] = []
+        why = (f"{fired} of {missing} replications could be triggered"
+               if fired < missing else "")
+        while not why:
+            for p in self.persistent.peers_of(task_id, pc.STATE_SUCCEEDED):
+                host = self._persistent_host(p["host_id"])
+                if p["host_id"] in verified or host is None:
+                    continue
+                stat = await self.seed_clients.stat_task(host, task_id)
+                if (stat and stat.get("done")
+                        and stat.get("digest") == task["digest"]
+                        and stat.get("content_length")
+                        == task["content_length"]):
+                    verified.append(p["host_id"])
+                    PERSISTENT_REPLICAS_VERIFIED.inc()
+            if len(verified) >= task["replica_count"]:
+                return verified
+            if loop.time() >= deadline:
+                why = (f"{len(verified)} of {task['replica_count']} verified "
+                       f"copies after {wait_s:.0f}s")
+                break
+            await asyncio.sleep(0.02)
+        self.persistent.upsert_task(task_id, state=pc.STATE_FAILED)
+        raise DfError(Code.SchedError,
+                      f"persistent task {task_id[:16]} not replicated: {why}")
+
     async def upload_persistent_cache_task_failed(self, body: dict,
                                                   ctx: RpcContext) -> dict:
         """Upload failed: drop the half-registered task (reference :1855) —
         but a failed RE-import of a task with live replicas must not erase
-        the healthy replica bookkeeping."""
+        the healthy replica bookkeeping. ``unreplicated``: the uploader
+        asked for verified replicas and was refused; the task stays on
+        record as failed (its holders still listed, for a delete to reach
+        them) and is no task a top-up restores."""
         from dragonfly2_tpu.scheduler.resource import persistentcache as pc
 
         task_id = body.get("task_id", "")
-        if self.persistent.replica_count(task_id) > 0:
+        if body.get("unreplicated") and self.persistent.get_task(task_id):
+            self.persistent.upsert_task(task_id, state=pc.STATE_FAILED)
+        elif self.persistent.replica_count(task_id) > 0:
             self.persistent.upsert_task(task_id, state=pc.STATE_SUCCEEDED)
             self.persistent.delete_peer_if_not_succeeded(
                 body.get("peer_id", ""))
@@ -1505,9 +1613,25 @@ class SchedulerService:
         return Host(row["host_id"], hostname=row["hostname"], ip=row["ip"],
                     port=row["port"], upload_port=row["upload_port"])
 
+    def _replica_order(self, candidates: list, holders: list) -> list:
+        """Which host gets the next replica: a rule, not a draw. A copy is
+        for the day its holders are gone, and a preempted slice takes every
+        host of it: hosts outside the slices of the hosts that hold the task
+        come before their slice-mates (``tpu_slice``, the label the slice
+        rule of ``scheduling`` reads; a host without one is in no slice), a
+        seed peer comes only where there is no other host (its store is the
+        cluster's cache of origin content, and evicts), then the host with
+        the fewest peers, then the lower host id."""
+        slices = {h.tpu_slice for h in holders if h is not None
+                  and h.tpu_slice}
+        return sorted(candidates, key=lambda h: (
+            h.is_seed(), bool(h.tpu_slice) and h.tpu_slice in slices,
+            len(h.peer_ids), h.id))
+
     async def _ensure_replicas(self, task_id: str) -> int:
         """Fan download triggers to hosts without a replica until the
-        desired count is met. Returns the number of triggers fired."""
+        desired count is met, in ``_replica_order``. Returns the number of
+        triggers fired."""
         task = self.persistent.get_task(task_id)
         if task is None or task["state"] != "succeeded":
             return 0
@@ -1515,9 +1639,9 @@ class SchedulerService:
         want = task["replica_count"] - len(have)
         if want <= 0:
             return 0
-        candidates = [h for h in self.hosts.all()
-                      if h.port > 0 and h.id not in have]
-        candidates.sort(key=lambda h: len(h.peer_ids))
+        candidates = self._replica_order(
+            [h for h in self.hosts.all() if h.port > 0 and h.id not in have],
+            [self.hosts.load(host_id) for host_id in have])
         spec = {
             "task_id": task_id, "url": task["url"], "tag": task["tag"],
             "application": task["application"],

@@ -9,6 +9,8 @@ objected. One break is the program's own to catch (the hot-swap's gate over
 a corrupt live generation): that run ends with the one swap failed.
 """
 
+import json
+
 import pytest
 
 from test_cells_rehearsal import last_line, whole_run
@@ -36,6 +38,9 @@ CONTROLS = [
     ("control_swap.py", ("--break", "store"), "tiny-shard-swap"),
     ("control_swap.py", ("--break", "version"), "tiny-shard-swap"),
     ("control_swap.py", ("--break", "torn"), "tiny-shard-swap"),
+    # The save and resume: a bit of the replica's stored file differs when
+    # the benchmark hashes it.
+    ("control_save.py", ("--break", "flip"), "tiny-ckpt-save-resume"),
 ]
 
 
@@ -56,3 +61,16 @@ def test_a_corrupt_live_generation_is_refused_by_the_swaps_own_gate():
     line = last_line(proc, "tiny-shard-swap", failed=1)
     assert line["correct"] is False, line
     assert "hot-swap verify failed: piece 0 corrupt" in proc.stdout
+
+
+def test_a_resume_with_no_holder_left_fails_and_is_counted():
+    """The second host's copy deleted with the saver's, before the resume:
+    the P2P-only pull has no source to go back to, so every operation of the
+    window fails with the scheduler's word for it and is counted."""
+    proc = whole_run("tests/control_save.py", "tiny-ckpt-save-resume",
+                     "--break", "replica")
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert proc.returncode == 0 and line["failed"] >= 1, proc.stderr[-2000:]
+    assert line["correct"] is False, line
+    assert "host 1's copy deleted too" in proc.stdout
+    assert "back-to-source" not in proc.stdout

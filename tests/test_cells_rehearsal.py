@@ -34,6 +34,7 @@ CELLS = {
     "tiny-host-reland-ep4": ("manifest-global.json", 4),
     "tiny-feed-records": ("manifest-feed.json", 1),
     "tiny-shard-swap": ("manifest-swap.json", 1),
+    "tiny-ckpt-save-resume": ("manifest-save.json", 1),
 }
 
 # Every process of a run inherits its environment from the run, so a

@@ -537,3 +537,52 @@ def test_typed_view_of_words_that_lie_on_every_chip(topo, dtype, shape, form):
     nbytes = 2 * int(np.prod(shape))
     assert out == nbytes
     assert temp <= (2.05 * nbytes + MiB if form == "flat" else MiB)
+
+
+# A rank's training state as save_from_device packs it: 71 pieces of 32 MiB.
+SAVE_WORDS = 71 * 32 * MiB // 4
+SAVE_GROUPS = [
+    ("float32", EXPERT, 16, 0, False),    # the optimizer's: a bitcast
+    ("bfloat16", EXPERT, 16, 0, False),   # the weights': two items a word
+    ("bfloat16", DOWN, 16, 0, False),
+    ("bfloat16", (2047,), 1, 2, True),    # begins and ends inside a word
+    ("uint8", (1000, 3), 1, 1, True),
+]
+
+
+@pytest.mark.parametrize("dtype,shape,members,lead,merge", SAVE_GROUPS)
+def test_save_pack_places_a_group_in_the_donated_words(one_chip, dtype, shape,
+                                                       members, lead, merge):
+    """The way out's pack program (ops/hbm_source.py): a group of tensors
+    placed in the file's words, which are donated and come back the same
+    memory, with temporaries of the order of a tensor and never of the
+    buffer."""
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops.hbm_source import _save_pack_jit
+
+    m = _save_pack_jit.lower(
+        _spec((SAVE_WORDS,), jnp.uint32, one_chip),
+        _spec((members,), jnp.int32, one_chip),
+        tuple(_spec(shape, dtype, one_chip) for _ in range(members)),
+        lead=lead, merge=merge).compile().memory_analysis()
+    assert m.alias_size_in_bytes == m.output_size_in_bytes == 4 * SAVE_WORDS
+    assert m.temp_size_in_bytes <= 2 * int(np.prod(shape)) * 4 + MiB
+
+
+def test_save_pack_sums_and_group_slice_need_no_temporary(one_chip):
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.ops.hbm_source import (
+        _save_group_jit,
+        _save_pack_sums_jit,
+    )
+
+    words = _spec((SAVE_WORDS,), jnp.uint32, one_chip)
+    piece_words = 32 * MiB // 4
+    m = _save_pack_sums_jit.lower(
+        words, piece_words=piece_words).compile().memory_analysis()
+    assert m.output_size_in_bytes <= 4096 and m.temp_size_in_bytes <= 4 * MiB
+    m = _save_group_jit.lower(words, _spec((), jnp.int32, one_chip),
+                              size=4 * piece_words).compile().memory_analysis()
+    assert m.output_size_in_bytes == 128 * MiB and m.temp_size_in_bytes == 0
