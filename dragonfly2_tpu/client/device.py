@@ -889,10 +889,14 @@ async def save_from_device(daemon, tensors: dict, cache_id: str, *,
     device's (a mismatch fails the save: what is stored is what was in
     HBM), the whole-content sha256 follows the pieces on a thread of its
     own (``PieceManager.import_pieces``); the task is registered with the
-    scheduler as a persistent cache task, and ``Finished`` is answered only
-    when ``replicas`` hosts hold a verified copy
-    (``TaskManager.import_source``; the scheduler's ``_replica_order`` says
-    which host is asked). No file is written outside the store.
+    scheduler as a persistent cache task with its geometry, so the host the
+    scheduler's ``_replica_order`` names is asked to pull its replica as the
+    save starts and is served each piece from its commit on, and
+    ``Finished``, which waits only for that pull's tail, is answered only
+    when ``replicas`` hosts hold a verified copy: each piece held against
+    this host's piece digest, the replica's own sha256 of what it stored
+    against the one this host took (``TaskManager.import_source``). No file
+    is written outside the store.
 
     ``dfcache import --persistent`` is the other form of the same task: it
     reads a file and is answered BEFORE replication."""

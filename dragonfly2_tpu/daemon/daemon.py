@@ -203,6 +203,7 @@ class Daemon:
             "tenant": request.meta.tenant,
             "range": request.meta.range,
             "pod_broadcast": getattr(request, "pod_broadcast", False),
+            "digest_from_parent": getattr(request, "digest_from_parent", ""),
         }
         return PeerTaskConductor(
             task_id=task_id,

@@ -22,6 +22,10 @@ class PieceEvent:
     # piece_num → "algo:encoded" — children verify against the parent's
     # advertised digest (reference commonv1 PieceInfo.piece_md5).
     digests: dict[int, str] = field(default_factory=dict)
+    # With ``done``, from a producer that took the whole-content digest of
+    # what it published itself (an import out of memory): a child that was
+    # started before that digest existed holds its own hash against it.
+    content_digest: str = ""
 
 
 @dataclass

@@ -100,6 +100,19 @@ ANNOUNCE_BYTES = metrics.counter(
 MAX_RESCHEDULES = 8
 
 
+def piece_report(rec, parent_id: str) -> dict:
+    """A landed piece as ``piece_finished`` / ``pieces_finished`` carry it to
+    the scheduler; ``parent_id`` "" for a piece this peer produced itself."""
+    return {
+        "piece_num": rec.num,
+        "range_start": rec.offset,
+        "range_size": rec.size,
+        "digest": rec.digest,
+        "download_cost_ms": rec.cost_ms,
+        "dst_peer_id": parent_id,
+    }
+
+
 class PeerTaskConductor:
     def __init__(
         self,
@@ -223,6 +236,9 @@ class PeerTaskConductor:
         self._open_body: dict | None = None
         self._announce_lock = asyncio.Lock()
         self._announce_done = False
+        # The whole-content digest a parent's done carried, for a task
+        # that was told to take it from there (``meta["digest_from_parent"]``).
+        self.content_digest = ""
         self._stream_reconnects = 0
         # Ring-rebuild re-homing: set when dynconfig moved this task's
         # ownership to a different live member — the next successful
@@ -500,6 +516,10 @@ class PeerTaskConductor:
             own_slice=self.own_slice)
         self.synchronizer.sync_parents(schedule_msg.get("parents") or [])
         self._apply_stripe(schedule_msg.get("stripe"))
+        # Where the digest to hold the content against comes with a parent's
+        # done: hash behind the pieces from the first one on, so that only
+        # the tail is left to hash when it comes ("" starts nothing).
+        self.store.start_prefix_hasher(self._digest_from_parent())
         # Resume support: pieces already on disk need no re-download.
         self.dispatcher.mark_known_downloaded(self.store.metadata.pieces.keys())
 
@@ -531,6 +551,7 @@ class PeerTaskConductor:
             # keeps running — a corrupt early finisher can't mask an
             # honest parent whose done is still in flight.
             await self._await_certification()
+            await self._await_parent_digest()
             await self._safe_send({
                 "type": "download_finished",
                 "content_length": self.store.metadata.content_length,
@@ -602,6 +623,50 @@ class PeerTaskConductor:
         if self.store.apply_certification(maps):
             how = "certified"
         return how, tried + len(maps)
+
+    def _digest_from_parent(self) -> str:
+        """The algorithm of the whole-content digest this task takes from a
+        parent's done, or "" (it was given the digest, or has none)."""
+        return ("" if self.meta.get("digest")
+                else self.meta.get("digest_from_parent", ""))
+
+    async def _await_parent_digest(self) -> None:
+        """A replica that was started before the content's digest existed:
+        every piece has landed, each held against its parent's piece digest;
+        the value to hold this store's own hash against rides the done of a
+        parent that hashed what it produced (``content_digest``). Waits for
+        it while a parent's done can still come; none means the task fails,
+        since it is marked done only behind that comparison
+        (``TaskManager._finalize_content_digest``)."""
+        algorithm = self._digest_from_parent()
+        if not algorithm:
+            return
+        disp = self.dispatcher
+        loop = asyncio.get_running_loop()
+        t0 = loop.time()
+        deadline = t0 + max(60.0, self.store.metadata.content_length
+                            / (50 << 20))
+        while True:
+            disp.certified_event.clear()
+            self.content_digest = next(
+                (d for pid, d in disp.content_digests.items()
+                 if pid in disp.done_parents
+                 and d.startswith(algorithm + ":")), "")
+            if self.content_digest or not disp.pending_certifiers():
+                break
+            try:
+                await asyncio.wait_for(disp.certified_event.wait(),
+                                       deadline - loop.time())
+            except asyncio.TimeoutError:
+                break
+        self.flight.record(flightlib.EV_CERT_WAIT, len(disp.done_parents),
+                           (loop.time() - t0) * 1000.0,
+                           "parent_digest" if self.content_digest
+                           else "no_parent_digest")
+        if not self.content_digest:
+            raise DfError(Code.ClientPieceDownloadFail,
+                          f"no parent's done carried the content's "
+                          f"{algorithm} digest")
 
     @staticmethod
     def _cert_wait_bound(content_length: int) -> float:
@@ -1119,14 +1184,7 @@ class PeerTaskConductor:
     _REPORT_FLUSH_S = 0.05
 
     async def _report_piece(self, rec, parent_id: str) -> None:
-        report = {
-            "piece_num": rec.num,
-            "range_start": rec.offset,
-            "range_size": rec.size,
-            "digest": rec.digest,
-            "download_cost_ms": rec.cost_ms,
-            "dst_peer_id": parent_id,
-        }
+        report = piece_report(rec, parent_id)
         # Per-phase timings ride the report so the scheduler can attribute
         # stragglers per host (flight.PodAggregator, /debug/pod/<task>).
         timings = self.flight.piece_report_timings(rec.num)

@@ -83,6 +83,9 @@ class PieceDispatcher:
         # not a merged view, drives the re-hash-skip decision).
         self.parent_digests: dict[str, dict[int, str]] = {}
         self.done_parents: set[str] = set()
+        # The whole-content digest a parent's done carried, where it
+        # carried one (a producer that hashed what it published).
+        self.content_digests: dict[str, str] = {}
         # Incremental ready-tracking: O(1) amortized per assignment instead
         # of rescanning all pieces (a 100 GiB task is ~25k pieces).
         self._needed: set[int] = set()
@@ -201,11 +204,14 @@ class PieceDispatcher:
                 if p.blocked
                 or (q is not None and q.is_quarantined(parent_key(p)))]
 
-    def note_parent_done(self, peer_id: str) -> None:
+    def note_parent_done(self, peer_id: str, content_digest: str = "") -> None:
         """The sync stream saw done=True from this parent: its completion
         gate passed (seed: full-digest validation; intermediate peer: its
-        own certified chain)."""
+        own certified chain). ``content_digest``: the whole-content digest
+        that done carried, if any."""
         self.done_parents.add(peer_id)
+        if content_digest:
+            self.content_digests[peer_id] = content_digest
         if self.flight is not None:
             p = self.parents.get(peer_id)
             self.flight.record(flightlib.EV_PARENT_DONE,

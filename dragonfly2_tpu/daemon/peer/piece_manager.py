@@ -585,7 +585,8 @@ class PieceManager:
     # -- import from buffers (client/device.py save_from_device) -----------
 
     async def import_pieces(self, store: LocalTaskStore, source,
-                            stamp=None) -> str:
+                            stamp=None,
+                            on_piece: PieceCallback | None = None) -> str:
         """Import content that lies in memory and not in a file: ``source``
         has ``content_length``, ``piece_size``, ``fetch(first, count)`` (a
         blocking call, run on a thread: the bytes of pieces [first, first +
@@ -604,7 +605,10 @@ class PieceManager:
         kept with the piece (``word_sums``). The whole-content sha256 follows
         the pieces on ONE thread of its own, in piece order, over the same
         memory the workers write from: nothing is read back. ``stamp(code,
-        piece, ms, note)`` gets save_d2h, save_commit and save_digest."""
+        piece, ms, note)`` gets save_d2h, save_commit and save_digest;
+        ``on_piece(store, rec)`` is awaited on the loop with each piece as
+        its commit returns, as ``download_source``'s is: the piece can be
+        served from then on."""
         import hashlib
         import queue
         import threading
@@ -635,7 +639,7 @@ class PieceManager:
                 except BaseException as e:  # noqa: BLE001 - the job raises it
                     done.set_exception(e)
 
-        def commit(num: int, view) -> None:
+        def commit(num: int, view):
             t0 = time.perf_counter()
             sums = checksum_numpy(view)
             want = source.sums.get(num) if source.sums else None
@@ -644,9 +648,15 @@ class PieceManager:
                     Code.ClientPieceDownloadFail,
                     f"piece {num}: the host's (sum, xor) {sums} differ from "
                     f"the producer's {tuple(want)}")
-            store.write_piece(num, view, word_sums=sums)
+            rec = store.write_piece(num, view, word_sums=sums)
             stamp(flightlib.EV_SAVE_COMMIT, num,
                   (time.perf_counter() - t0) * 1000.0, str(len(view)))
+            return rec
+
+        async def commit_piece(num: int, view) -> None:
+            rec = await asyncio.to_thread(commit, num, view)
+            if on_piece is not None:
+                await on_piece(store, rec)
 
         async def commit_group(first: int, buf) -> None:
             views = [buf[i:i + piece_size]
@@ -655,7 +665,7 @@ class PieceManager:
             hashing.put((first, views, hashed))
             try:
                 await asyncio.gather(*(
-                    asyncio.to_thread(commit, first + i, view)
+                    commit_piece(first + i, view)
                     for i, view in enumerate(views)))
                 await asyncio.wrap_future(hashed)
             finally:

@@ -130,7 +130,8 @@ class PieceTaskSynchronizer:
                     # The parent passed its completion gate (seed: full
                     # digest validated) — its digest map can certify the
                     # child's re-hash-skip decision (provenance-checked).
-                    self.dispatcher.note_parent_done(parent_peer_id)
+                    self.dispatcher.note_parent_done(
+                        parent_peer_id, msg.get("content_digest") or "")
                     done = True
                     break
             if not done:

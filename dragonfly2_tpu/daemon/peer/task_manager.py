@@ -13,6 +13,7 @@ from dataclasses import dataclass, field
 from typing import AsyncIterator
 
 from dragonfly2_tpu.daemon.peer.broker import PieceBroker, PieceEvent
+from dragonfly2_tpu.daemon.peer.conductor import piece_report
 from dragonfly2_tpu.daemon.peer.piece_manager import PieceManager
 from dragonfly2_tpu.pkg import aio, dflog, idgen, metrics
 from dragonfly2_tpu.pkg import flight as flightlib
@@ -68,6 +69,13 @@ class FileTaskRequest:
     # peers as a plain peer even where it is configured as a seed peer, whose
     # every other task registers as a seed and is sent back to source.
     as_peer: bool = False
+    # A replication triggered while the content is still being imported on
+    # its producer: no digest exists yet, and the algorithm named here says
+    # that the value comes with a parent's done. The pull hashes what it
+    # stores from its first piece on and is marked done only once that hash
+    # equals the value (``_finalize_content_digest``, as with a digest given
+    # at the start).
+    digest_from_parent: str = ""
 
     def task_id(self) -> str:
         return idgen.task_id_v1(
@@ -161,6 +169,67 @@ class _RunningTask:
         self.store = store
         self.done = asyncio.Event()
         self.error: DfError | None = None
+
+
+class _ProducerReports:
+    """What a peer that produces a task's bytes itself tells the scheduler
+    on its announce stream, for an import, which has no conductor: each
+    committed piece as ``piece_finished``, then ``download_finished`` or
+    ``download_failed``. It never registers (the scheduler entered the peer
+    when the import told it ``Started``) and nothing is awaited back. Best
+    effort: the children learn the pieces from this host's sync stream, so
+    a report that cannot be sent is logged once and the import goes on."""
+
+    def __init__(self, stream):
+        self._stream = stream
+
+    @classmethod
+    async def open(cls, scheduler_client, host_wire, task_id: str,
+                   peer_id: str, req) -> "_ProducerReports | None":
+        host_info = host_wire() if host_wire is not None else {}
+        if not host_info:
+            return None
+        host_info.pop("telemetry", None)
+        try:
+            return cls(await scheduler_client.open_announce_stream({
+                "host": host_info, "peer_id": peer_id, "task_id": task_id,
+                "url": req.url, "tag": req.meta.tag,
+                "application": req.meta.application,
+                "digest": req.meta.digest}))
+        except DfError as e:
+            log.warning("producer's announce stream not opened",
+                        task_id=task_id[:16], error=str(e))
+            return None
+
+    async def _send(self, msg: dict) -> None:
+        if self._stream is None:
+            return
+        try:
+            await self._stream.send(msg)
+        except DfError as e:
+            log.warning("producer's report not sent", error=str(e))
+            self._stream = None
+
+    async def piece(self, rec) -> None:
+        await self._send({"type": "piece_finished",
+                          "piece": piece_report(rec, "")})
+
+    async def end(self, store) -> None:
+        """``store``: the completed store, or None for a failed import."""
+        if store is None:
+            await self._send({"type": "download_failed"})
+        else:
+            m = store.metadata
+            await self._send({"type": "download_finished",
+                              "content_length": m.content_length,
+                              "piece_size": m.piece_size,
+                              "total_piece_count": m.total_piece_count})
+        stream, self._stream = self._stream, None
+        if stream is not None:
+            try:
+                await stream.close()
+            except DfError:
+                pass
 
 
 class TaskManager:
@@ -280,6 +349,15 @@ class TaskManager:
                         self.locality_bytes.clear()
                     self.locality_bytes[task_id] = dict(
                         getattr(conductor, "locality_bytes", {}) or {})
+                if req.digest_from_parent and not req.meta.digest:
+                    # What the completion decision holds the store against.
+                    # No value (the scheduler answered with another way than
+                    # a pull from parents) leaves nothing to compare with.
+                    if not conductor.content_digest:
+                        raise DfError(
+                            Code.ClientPieceDownloadFail,
+                            "no parent's done carried the content's digest")
+                    req.meta.digest = conductor.content_digest
                 return conductor.from_p2p
             if self.pex is not None:
                 # Schedulerless P2P: gossip told us who holds this task.
@@ -411,8 +489,8 @@ class TaskManager:
         and replicates it to ``replica_count`` hosts (reference
         UploadPersistentCacheTask* family, service_v2.go:1726-1895): the
         scheduler answers ``Finished`` at once and replicates behind it."""
-        async def fill(store) -> None:
-            await self.piece_manager.import_file(store, path)
+        async def fill(store, on_piece) -> None:
+            await self.piece_manager.import_file(store, path, on_piece)
             if req.meta.digest:
                 # Whole-content hash: off the loop (hashlib releases
                 # the GIL; inline it stalls every active transfer).
@@ -430,39 +508,66 @@ class TaskManager:
         """Import content that lies in memory (``source``:
         ``PieceManager.import_pieces``'s) as a persistent cache task, and
         return only when ``replica_count`` hosts, this one among them, hold
-        a copy whose pieces and sha256 verified: ``Finished`` carries the
-        digest this import took and is answered by the scheduler once the
-        replicas are made and each holder's daemon says so (``holders`` in
-        the result), or refused after ``wait_replicas_s``; the task is then
-        reported ``Failed``. An entry of the same id that this host already
-        holds is refused: a save never answers with another save's bytes."""
+        a copy whose pieces and sha256 verified. ``Started`` carries the
+        task's geometry, which is known before the first byte is fetched:
+        the scheduler enters this host as the peer that produces the task
+        and asks the replicas' hosts to pull then, and each piece is served
+        to them from its commit on (``_import_local``). ``Finished`` carries
+        the digest this import took and is answered by the scheduler once
+        the replicas are made and each holder's daemon says so (``holders``
+        in the result), or refused after ``wait_replicas_s``; the task is
+        then reported ``Failed``. An entry of the same id that this host
+        already holds is refused: a save never answers with another save's
+        bytes."""
         if self.storage.find_completed_task(req.task_id()) is not None:
             raise DfError(Code.BadRequest,
                           f"{req.url} is already held by this host")
 
-        async def fill(store) -> None:
+        async def fill(store, on_piece) -> None:
             store.metadata.digest = await self.piece_manager.import_pieces(
-                store, source, stamp)
+                store, source, stamp, on_piece)
 
         return await self._import(
             fill, req, persistent=True, replica_count=replica_count, ttl=ttl,
-            wait_replicas_s=wait_replicas_s, stamp=stamp)
+            wait_replicas_s=wait_replicas_s, stamp=stamp,
+            geometry={"content_length": source.content_length,
+                      "piece_size": source.piece_size,
+                      "total_piece_count": compute_piece_count(
+                          source.content_length, source.piece_size)})
 
     async def _import(self, fill, req: "FileTaskRequest", *,
                       persistent: bool, replica_count: int, ttl: float,
-                      wait_replicas_s: float = 0.0, stamp=None) -> dict:
+                      wait_replicas_s: float = 0.0, stamp=None,
+                      geometry: "dict | None" = None) -> dict:
         task_id = req.task_id()
         peer_id = req.peer_id or idgen.peer_id_v1(self.host_ip)
-        if persistent:
+        # perf_counter as ``Started`` was answered; None until then.
+        answered: "float | None" = None
+
+        async def started() -> "_ProducerReports | None":
+            nonlocal answered
+            if not persistent:
+                return None
             await self._persistent_call(
                 "Scheduler.UploadPersistentCacheTaskStarted", task_id, peer_id,
                 {"url": req.url, "tag": req.meta.tag,
                  "application": req.meta.application,
                  "replica_count": replica_count, "ttl": ttl,
-                 "digest": req.meta.digest})
+                 "digest": req.meta.digest, **(geometry or {})})
+            answered = time.perf_counter()
+            if geometry is None:
+                return None
+            # The scheduler holds this host as the task's producing peer
+            # now: its pieces are reported as any such peer's are.
+            return await _ProducerReports.open(
+                self.scheduler_client, self.host_wire, task_id, peer_id, req)
+
         try:
-            result = await self._import_local(fill, req, task_id, peer_id)
+            result = await self._import_local(fill, req, task_id, peer_id,
+                                              started)
             if persistent:
+                if geometry is not None and stamp is not None:
+                    self._stamp_replica_ahead(result, answered, stamp)
                 sent = time.perf_counter()
                 reply = await self._persistent_call(
                     "Scheduler.UploadPersistentCacheTaskFinished", task_id,
@@ -480,7 +585,7 @@ class TaskManager:
                           (time.perf_counter() - sent) * 1000.0,
                           ",".join(result["holders"]))
         except BaseException:
-            if persistent:
+            if answered is not None:
                 try:
                     # Best-effort: a scheduler/network error here must not
                     # mask the real import failure. An import that asked
@@ -496,6 +601,33 @@ class TaskManager:
             raise
         return result
 
+    def _stamp_replica_ahead(self, result: dict, answered: float,
+                             stamp) -> None:
+        """``save_replica_ahead``, as ``Finished`` is about to be sent: the
+        pieces of the task that this host's upload side has already served
+        to other hosts (the bytes of the ``upload_serve`` events on the
+        task's flight, the native server's sends drained first, as a share
+        of the content times its piece count: a run of pieces is one send),
+        and the ms from ``Started`` answered to the first send's start (0.0
+        with none)."""
+        self.flight.sync()
+        tf = self.flight.get(result["task_id"])
+        sent, first = 0, None
+        for t, code, _, aux, note in (tf.events() if tf is not None else ()):
+            if code == flightlib.EV_UPLOAD_SERVE:
+                sent += flightlib.parse_serve_note(note)[0]
+                began = t - aux / 1000.0
+                first = began if first is None else min(first, began)
+        total = max(result["total_piece_count"], 0)
+        pieces = min(total, round(total * sent
+                                  / max(result["content_length"], 1)))
+        first_ms = 0.0
+        if first is not None:
+            # The flight's clock is perf_counter since its start.
+            start_pc = time.perf_counter() - tf.wall_s()
+            first_ms = max(0.0, (first - (answered - start_pc)) * 1000.0)
+        stamp(flightlib.EV_SAVE_REPLICA_AHEAD, pieces, first_ms, str(sent))
+
     async def _persistent_call(self, method: str, task_id: str, peer_id: str,
                                extra: dict, timeout: float = 10.0):
         if self.scheduler_client is None:
@@ -509,25 +641,64 @@ class TaskManager:
              "host": host_info, **extra}, timeout=timeout)
 
     async def _import_local(self, fill, req: "FileTaskRequest",
-                            task_id: str, peer_id: str) -> dict:
+                            task_id: str, peer_id: str, started) -> dict:
+        """Fill a new store of this host through ``fill(store, on_piece)``
+        and announce it complete. While it is filled the task is RUNNING
+        here (``is_task_running``) as any download is: each committed piece
+        is published to the broker with its digest, so a child's
+        ``Peer.SyncPieceTasks`` is served the store's snapshot and then the
+        pieces as they commit, and the end publishes ``done`` with the
+        content's digest, or ``failed``. ``started()`` tells the scheduler
+        once the store exists (whom it then sends here finds the task), and
+        returns where each piece is reported to it, or None."""
         existing = self.storage.find_completed_task(task_id)
         if existing is None:
             store = self.storage.register_task(TaskStoreMetadata(
                 task_id=task_id, peer_id=peer_id, url=req.url,
                 tag=req.meta.tag, application=req.meta.application))
-            with store:
-                try:
-                    await fill(store)
-                    store.mark_done()
-                    self._pex_announce(task_id)
-                except BaseException:
-                    # A half-imported store must not be resumed by a retry:
-                    # stale piece records would outlive a changed source file
-                    # (start_file_task applies the same rule).
-                    store.mark_invalid()
-                    raise
+            run = self._running[task_id] = _RunningTask(store)
+            reports = None
+
+            async def on_piece(st, rec) -> None:
+                m = st.metadata
+                self.broker.publish(task_id, PieceEvent(
+                    [rec.num], m.total_piece_count, m.content_length,
+                    m.piece_size, digests={rec.num: rec.digest}))
+                if reports is not None:
+                    await reports.piece(rec)
+
+            try:
+                with store:
+                    try:
+                        reports = await started()
+                        await fill(store, on_piece)
+                        store.mark_done()
+                        self._pex_announce(task_id)
+                    except BaseException as e:
+                        # A half-imported store must not be resumed by a
+                        # retry: stale piece records would outlive a changed
+                        # source file (start_file_task applies the same
+                        # rule). A child's stream ends as a failed parent's.
+                        store.mark_invalid()
+                        run.error = e if isinstance(e, DfError) else DfError(
+                            Code.UnknownError, describe(e))
+                        self.broker.publish(task_id,
+                                            PieceEvent([], failed=True))
+                        if reports is not None:
+                            await reports.end(None)
+                        raise
+            finally:
+                run.done.set()
+                self._running.pop(task_id, None)
+            m = store.metadata
+            self.broker.publish(task_id, PieceEvent(
+                [], m.total_piece_count, m.content_length, m.piece_size,
+                done=True, content_digest=m.digest))
+            if reports is not None:
+                await reports.end(store)
         else:
             store = existing
+            await started()
         await self._announce_local_task(store, task_id, peer_id)
         return {"task_id": task_id, "peer_id": peer_id,
                 "pieces": len(store.metadata.pieces),
@@ -784,7 +955,9 @@ class TaskManager:
                                   spec.get("disable_back_source")),
                               device=spec.get("device", ""),
                               pod_broadcast=bool(spec.get("pod_broadcast")),
-                              as_peer=not is_seed)
+                              as_peer=not is_seed,
+                              digest_from_parent=spec.get(
+                                  "digest_from_parent", ""))
         if meta.range:
             req.range = Range.parse_http(meta.range)
         task_id = spec.get("task_id") or req.task_id()

@@ -228,10 +228,13 @@ class DaemonRpcServer:
 
     def _piece_snapshot(self, task_id: str) -> dict | None:
         store = self.task_manager.storage.try_get(task_id)
-        if store is None:
+        if store is None or store.metadata.invalid:
+            # What a failed task left is no parent's store: a child that
+            # comes after the failure is told so at once, as one that was
+            # there is by the stream's end.
             return None
         m = store.metadata
-        return {
+        snapshot = {
             "pieces": sorted(m.pieces.keys()),
             "total_piece_count": m.total_piece_count,
             "content_length": m.content_length,
@@ -239,6 +242,11 @@ class DaemonRpcServer:
             "done": m.done,
             "digests": {n: p.digest for n, p in m.pieces.items() if p.digest},
         }
+        if m.done and m.digest:
+            # What a live ``done`` of this store carried (PieceEvent's
+            # ``content_digest``), for the child that comes after it.
+            snapshot["content_digest"] = m.digest
+        return snapshot
 
     async def _sync_piece_tasks(self, stream: ServerStream, ctx: RpcContext) -> None:
         """Serve piece availability to a child peer, pushing updates as
@@ -284,14 +292,17 @@ class DaemonRpcServer:
                 if event.failed:
                     raise DfError(Code.ClientPieceDownloadFail,
                                   "parent download failed")
-                await stream.send(with_spans({
+                msg = {
                     "pieces": event.piece_nums,
                     "total_piece_count": event.total_piece_count,
                     "content_length": event.content_length,
                     "piece_size": event.piece_size,
                     "done": event.done,
                     "digests": event.digests,
-                }))
+                }
+                if event.content_digest:
+                    msg["content_digest"] = event.content_digest
+                await stream.send(with_spans(msg))
                 if event.done:
                     return
         finally:

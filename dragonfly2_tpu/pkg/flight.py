@@ -238,13 +238,19 @@ EV_LOOP_ACCT = 62
 # piece = num, note = bytes), save_digest (the digest thread's sha256 over
 # a group; piece = the group's first, note = bytes), save_replicated
 # (``Finished`` sent -> the scheduler's answer that ``replicas`` hosts hold
-# a verified copy; piece = holders, note = their host ids).
+# a verified copy; piece = holders, note = their host ids). With replicas
+# asked at ``Started`` (the import carried its geometry) that span is the
+# tail only, and ONE point says so as ``Finished`` is sent:
+# save_replica_ahead (piece = the pieces of the task this host's upload side
+# has served to other hosts by then, aux = ms from ``Started`` answered to
+# the first such send's start, note = the bytes sent).
 EV_SAVE_SNAPSHOT = 63
 EV_SAVE_PACK = 64
 EV_SAVE_D2H = 65
 EV_SAVE_COMMIT = 66
 EV_SAVE_DIGEST = 67
 EV_SAVE_REPLICATED = 68
+EV_SAVE_REPLICA_AHEAD = 69
 
 EVENT_NAMES = {
     EV_REGISTER: "register", EV_SCHEDULED: "scheduled",
@@ -285,6 +291,7 @@ EVENT_NAMES = {
     EV_SAVE_SNAPSHOT: "save_snapshot", EV_SAVE_PACK: "save_pack",
     EV_SAVE_D2H: "save_d2h", EV_SAVE_COMMIT: "save_commit",
     EV_SAVE_DIGEST: "save_digest", EV_SAVE_REPLICATED: "save_replicated",
+    EV_SAVE_REPLICA_AHEAD: "save_replica_ahead",
 }
 
 # Runtime-interference events (pkg/prof stamps them into every RUNNING

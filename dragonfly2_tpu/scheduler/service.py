@@ -22,6 +22,7 @@ import asyncio
 
 from dragonfly2_tpu.pkg import aio, dflog, idgen
 from dragonfly2_tpu.pkg import cluster as clusterlib
+from dragonfly2_tpu.pkg import digest as pkgdigest
 from dragonfly2_tpu.pkg import fleet as fleetlib
 from dragonfly2_tpu.pkg import flight as flightlib
 from dragonfly2_tpu.pkg import podlens as podlenslib
@@ -99,6 +100,13 @@ PERSISTENT_REPLICAS_VERIFIED = metrics.counter(
     "Holders of a persistent cache task whose daemon answered an awaited "
     "Finished's Peer.StatTask with the task done under the digest and "
     "length on record")
+PERSISTENT_REPLICAS_TRIGGERED = metrics.counter(
+    "scheduler_persistent_replicas_triggered_total",
+    "Hosts told to pull a replica of a persistent cache task, by when: "
+    "started (the upload came with its geometry and the replica is pulled "
+    "while the uploader imports) or finished (after the import, a top-up "
+    "after a host left, a GC repair)",
+    ("at",))
 STATE_REBUILT_COUNT = metrics.counter(
     "scheduler_state_rebuilt_peers_total",
     "Peers whose Task/Peer state this scheduler rebuilt without having "
@@ -127,6 +135,12 @@ class SchedulerService:
         )
 
         self.persistent = PersistentCacheResource(self.config.persistent_cache_db)
+        # Replication begun at ``Started``: task id -> the hosts asked for a
+        # replica that have not failed it since, and the triggers still in
+        # flight. ``Finished`` awaits the second and asks only for what the
+        # first leaves missing.
+        self._replicas_asked: dict[str, set[str]] = {}
+        self._replicating: dict[str, asyncio.Task] = {}
         # Pod-level flight aggregation: per-host phase attribution from
         # piece-report timings + quarantine correlation, served at
         # /debug/pod/<task_id> (scheduler/server wires it into the
@@ -1327,6 +1341,9 @@ class SchedulerService:
         self._note_shipped_flight(msg, task, peer)
         self.pod_flight.note_fanout(task.id, "failed", peer.host.id)
         self._fail_peer(peer)
+        # A replica asked for at ``Started`` that failed is asked for again
+        # by ``Finished``'s top-up.
+        self._replicas_asked.get(task.id, set()).discard(peer.host.id)
         # Task fails only when nothing is still making progress.
         still_running = any(
             not p.is_done() and p.id != peer.id for p in task.peers()
@@ -1449,7 +1466,12 @@ class SchedulerService:
     async def upload_persistent_cache_task_started(self, body: dict,
                                                    ctx: RpcContext) -> dict:
         """An uploader begins importing a persistent cache task
-        (reference :1726 UploadPersistentCacheTaskStarted)."""
+        (reference :1726 UploadPersistentCacheTaskStarted). Where the body
+        carries the task's geometry (``content_length``, ``piece_size``,
+        ``total_piece_count``: an import out of memory knows them before its
+        first byte) the uploader becomes a parent now and, with
+        ``replica_count`` > 1, the replicas are asked for now; without it
+        (an import of a file) nothing happens before ``Finished``."""
         from dragonfly2_tpu.scheduler.resource import persistentcache as pc
 
         task_id = body.get("task_id", "")
@@ -1472,7 +1494,36 @@ class SchedulerService:
             state=pc.STATE_UPLOADING)
         self.persistent.upsert_peer(body.get("peer_id", ""), task_id, host_id,
                                     state=pc.STATE_UPLOADING)
+        if (body.get("content_length", -1) >= 0
+                and body.get("piece_size", 0) > 0
+                and body.get("total_piece_count", -1) >= 0):
+            # The upload came with its geometry: the uploader serves each
+            # piece from its commit on, so it is a parent from now, and the
+            # replicas are pulled beside the import. The answer does not
+            # wait for the triggers (an unreachable host's takes seconds).
+            self._enter_producer(body)
+            if int(body.get("replica_count", 1)) > 1:
+                self._replicating[task_id] = aio.spawn(
+                    self._ensure_replicas(task_id, at="started"))
         return {"ok": True}
+
+    def _enter_producer(self, body: dict) -> None:
+        """The uploader of a persistent cache task, in the task resource as
+        a peer that produces the task's bytes itself: the state a
+        back-to-source peer has, which ``_is_candidate`` hands out before
+        its first piece. Its pieces and its end come on its announce stream,
+        ``AnnounceTask`` after the import gives the completed form."""
+        _, task, peer = self._resolve(body)
+        task.update_lengths(body["content_length"], body["piece_size"],
+                            body["total_piece_count"])
+        if peer.fsm.can("register_normal"):
+            peer.fsm.event("register_normal")
+        self._mark_task_running(task)
+        # The state alone: no origin is spent, so neither the task's
+        # back-to-source budget nor the fleet's count of them moves.
+        if peer.fsm.can("download_back_to_source"):
+            peer.fsm.event("download_back_to_source")
+            task.notify_parents_changed()
 
     async def upload_persistent_cache_task_finished(self, body: dict,
                                                     ctx: RpcContext) -> dict:
@@ -1524,7 +1575,15 @@ class SchedulerService:
         loop = asyncio.get_running_loop()
         deadline = loop.time() + wait_s
         task = self.persistent.get_task(task_id)
-        missing = task["replica_count"] - self.persistent.replica_count(task_id)
+        early = self._replicating.pop(task_id, None)
+        if early is not None:
+            # The triggers fired at ``Started``: whom they reached is not
+            # asked twice, whom they did not is asked now.
+            await asyncio.wait([early])
+        missing = task["replica_count"] - len(
+            {p["host_id"] for p in self.persistent.peers_of(
+                task_id, pc.STATE_SUCCEEDED)}
+            | self._replicas_asked.get(task_id, set()))
         fired = await self._ensure_replicas(task_id)
         verified: list[str] = []
         why = (f"{fired} of {missing} replications could be triggered"
@@ -1542,6 +1601,7 @@ class SchedulerService:
                     verified.append(p["host_id"])
                     PERSISTENT_REPLICAS_VERIFIED.inc()
             if len(verified) >= task["replica_count"]:
+                self._replicas_asked.pop(task_id, None)
                 return verified
             if loop.time() >= deadline:
                 why = (f"{len(verified)} of {task['replica_count']} verified "
@@ -1563,6 +1623,26 @@ class SchedulerService:
         from dragonfly2_tpu.scheduler.resource import persistentcache as pc
 
         task_id = body.get("task_id", "")
+        early = self._replicating.pop(task_id, None)
+        if early is not None:
+            await asyncio.wait([early])
+        triggered = self._replicas_asked.pop(task_id, set())
+        peer = self.peers.load(body.get("peer_id", ""))
+        if peer is not None and peer.task.id == task_id:
+            # No parent any more, whatever its announce stream still said;
+            # and a host asked at ``Started`` whose pull has failed since is
+            # no longer among the asked, but is a peer of the task.
+            self._fail_peer(peer)
+            triggered |= {p.host.id for p in peer.task.peers()
+                          if p.host.id != peer.host.id}
+        # A replica begun beside the import goes with it; one that is
+        # complete and on record stays, as it always has. A host whose pull
+        # still runs refuses the delete: that pull fails with its parent and
+        # invalidates its own store.
+        triggered -= {p["host_id"] for p in self.persistent.peers_of(
+            task_id, pc.STATE_SUCCEEDED)}
+        if triggered:
+            aio.spawn(self._delete_on_hosts(task_id, triggered))
         if body.get("unreplicated") and self.persistent.get_task(task_id):
             self.persistent.upsert_task(task_id, state=pc.STATE_FAILED)
         elif self.persistent.replica_count(task_id) > 0:
@@ -1599,7 +1679,18 @@ class SchedulerService:
             (deleted if ok else failed).append(p["host_id"])
         self.persistent.delete_task(task_id)
         self.tasks.delete(task_id)
+        self._replicas_asked.pop(task_id, None)
         return {"ok": not failed, "deleted": deleted, "failed": failed}
+
+    async def _delete_on_hosts(self, task_id: str, host_ids) -> None:
+        """``Peer.DeleteTask`` on each host, asked again for a few seconds
+        where the daemon still answers that the task runs."""
+        for host_id in sorted(host_ids):
+            host = self._persistent_host(host_id)
+            for _ in range(25 if host is not None else 0):
+                if await self.seed_clients.delete_task(host, task_id):
+                    break
+                await asyncio.sleep(0.2)
 
     def _persistent_host(self, host_id: str):
         """Address a persistent host via the live resource if announced,
@@ -1628,14 +1719,24 @@ class SchedulerService:
             h.is_seed(), bool(h.tpu_slice) and h.tpu_slice in slices,
             len(h.peer_ids), h.id))
 
-    async def _ensure_replicas(self, task_id: str) -> int:
+    async def _ensure_replicas(self, task_id: str,
+                               at: str = "finished") -> int:
         """Fan download triggers to hosts without a replica until the
         desired count is met, in ``_replica_order``. Returns the number of
-        triggers fired."""
+        triggers fired. ``at="started"``: the task is still being uploaded
+        (``upload_persistent_cache_task_started``); the hosts asked are kept
+        in ``_replicas_asked`` and count as having one from then on, and
+        the spec, which can carry no digest yet, says under which algorithm
+        the uploader's done will bring it (``digest_from_parent``): the
+        replica holds its own hash of what it stored against that."""
         task = self.persistent.get_task(task_id)
-        if task is None or task["state"] != "succeeded":
+        if task is None or task["state"] != (
+                "uploading" if at == "started" else "succeeded"):
             return 0
-        have = {p["host_id"] for p in self.persistent.peers_of(task_id)}
+        asked = self._replicas_asked.setdefault(task_id, set()) \
+            if at == "started" else self._replicas_asked.get(task_id, set())
+        have = {p["host_id"] for p in self.persistent.peers_of(task_id)} \
+            | asked
         want = task["replica_count"] - len(have)
         if want <= 0:
             return 0
@@ -1649,12 +1750,18 @@ class SchedulerService:
             # Replicas PULL from peers; dfcache:// has no origin.
             "seed": False, "disable_back_source": True,
         }
+        if at == "started" and not task["digest"]:
+            spec["digest_from_parent"] = pkgdigest.ALGORITHM_SHA256
         fired = 0
         for host in candidates[:want]:
+            asked.add(host.id)
             if await self.seed_clients.trigger_download_task(host, spec):
                 fired += 1
+                PERSISTENT_REPLICAS_TRIGGERED.labels(at).inc()
                 log.info("replication triggered", task=task_id[:16],
-                         host=host.id)
+                         host=host.id, at=at)
+            else:
+                asked.discard(host.id)
         return fired
 
     async def _fetch_direct_piece(self, task: Task, peer: Peer) -> None:
@@ -1813,6 +1920,13 @@ class SchedulerService:
                     and self.persistent.replica_count(task["task_id"])
                     < task["replica_count"]):
                 aio.spawn(self._ensure_replicas(task["task_id"]))
+        for task_id in {*self._replicas_asked, *self._replicating}:
+            # Kept from ``Started`` to the awaited ``Finished``'s answer;
+            # an upload that never came back is forgotten here.
+            task = self.persistent.get_task(task_id)
+            if task is None or task["state"] != "uploading":
+                self._replicas_asked.pop(task_id, None)
+                self._replicating.pop(task_id, None)
         return {
             "peers": len(self.peers.gc()),
             "tasks": len(self.tasks.gc()),

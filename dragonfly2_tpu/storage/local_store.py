@@ -851,11 +851,15 @@ class LocalTaskStore:
         ``validate_digest`` at completion is (near-)free. Idempotent;
         silently a no-op for unknown algorithms. Callers gate on
         ``completion_digest_applies`` — only tasks that will actually run
-        the completion digest decision should pay for this."""
+        the completion digest decision should pay for this. A bare
+        algorithm name (``sha256``) starts it where the value is not known
+        yet and will come with a parent's done: ``validate_digest`` is then
+        given the value to hold the hash against."""
         if self._prefix_hasher is not None or not expected_digest:
             return
         try:
-            algorithm = pkgdigest.parse(expected_digest).algorithm
+            algorithm = (pkgdigest.parse(expected_digest).algorithm
+                         if ":" in expected_digest else expected_digest)
             # The hasher opens its own O_RDONLY fd immediately; make sure
             # the data file exists even before the first piece write.
             self._ensure_fd()

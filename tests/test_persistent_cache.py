@@ -305,3 +305,166 @@ def test_gc_repairs_under_replication(run_async):
         assert fired == []
 
     run_async(run())
+
+
+# -- replication begun at Started (the scheduler alone) ----------------------
+
+GEOMETRY = {"content_length": 3 * 4096 - 7, "piece_size": 4096,
+            "total_piece_count": 3}
+UPLOADS = {
+    # case: (what Started carries beside the task, replicas asked at Started,
+    #        replicas asked at Finished)
+    "no geometry, two replicas": ({"replica_count": 2}, 0, 1),
+    "geometry, one replica": ({"replica_count": 1, **GEOMETRY}, 0, 0),
+    "geometry, two replicas": ({"replica_count": 2, **GEOMETRY}, 1, 0),
+}
+
+
+def _alone():
+    """A scheduler service, hosts a (slice s), b (slice t) and c (slice s)
+    announced, its triggers recorded and answered ok."""
+    from dragonfly2_tpu.scheduler.resource import Host
+    from dragonfly2_tpu.scheduler.service import SchedulerService
+
+    svc = SchedulerService()
+    for i, (name, tpu_slice) in enumerate(
+            (("a", "s"), ("b", "t"), ("c", "s"))):
+        svc.hosts.store(Host(name, ip=f"10.0.0.{i + 1}", port=8000 + i,
+                             upload_port=9000 + i, tpu_slice=tpu_slice))
+    fired = []
+
+    async def trigger(host, spec):
+        fired.append((host.id, dict(spec)))
+        return True
+
+    svc.seed_clients.trigger_download_task = trigger
+    return svc, fired
+
+
+def _upload(extra: dict) -> dict:
+    return {"task_id": "t-up", "peer_id": "p-a", "url": "dfcache://up",
+            "tag": "", "application": "", "digest": "",
+            "host": {"id": "a", "ip": "10.0.0.1", "port": 8000,
+                     "upload_port": 9000, "tpu_slice": "s"}, **extra}
+
+
+def _triggered(at: str) -> float:
+    from dragonfly2_tpu.scheduler import service
+
+    return service.PERSISTENT_REPLICAS_TRIGGERED.labels(at)._value.get()
+
+
+@pytest.mark.parametrize("case", list(UPLOADS))
+def test_replicas_are_asked_at_started_only_for_what_started_carries(
+        run_async, case):
+    """``Started`` with the task's geometry and more than one replica fires
+    the triggers then, once; without either it fires none before
+    ``Finished``, as it always has."""
+    from dragonfly2_tpu.scheduler.resource.peer import PeerState
+
+    extra, at_started, at_finished = UPLOADS[case]
+
+    async def run():
+        svc, fired = _alone()
+        before = {at: _triggered(at) for at in ("started", "finished")}
+        await svc.upload_persistent_cache_task_started(_upload(extra), None)
+        await asyncio.sleep(0.05)   # the triggers run behind the answer
+        assert len(fired) == at_started
+        peer = svc.peers.load("p-a")
+        if "piece_size" in extra:
+            # The uploader is a parent from now, pieceless: a child of
+            # another host is handed it.
+            assert peer.fsm.current == PeerState.BACK_TO_SOURCE
+            assert (peer.task.content_length, peer.task.total_piece_count) \
+                == (GEOMETRY["content_length"], 3)
+            assert peer.task.back_to_source_peers == set()
+        else:
+            assert peer is None
+        if at_started:
+            (host, spec), = fired
+            # The host of the other slice, told where the digest comes from.
+            assert host == "b" and spec["digest"] == ""
+            assert spec["digest_from_parent"] == "sha256"
+            assert (spec["seed"], spec["disable_back_source"]) == (False, True)
+            assert svc._replicas_asked["t-up"] == {"b"}
+        fired.clear()
+        await svc.upload_persistent_cache_task_finished(
+            _upload({"digest": "sha256:" + "ab" * 32, **GEOMETRY}), None)
+        await asyncio.sleep(0.05)
+        assert len(fired) == at_finished
+        if at_finished:
+            (host, spec), = fired
+            assert host == "b" and spec["digest"] == "sha256:" + "ab" * 32
+            assert "digest_from_parent" not in spec
+        assert _triggered("started") - before["started"] == at_started
+        assert _triggered("finished") - before["finished"] == at_finished
+
+    run_async(run())
+
+
+@pytest.mark.parametrize("early", [False, True])
+def test_the_top_up_after_a_host_left_is_what_it_was(run_async, early):
+    """``leave_host`` restores the count elsewhere with the spec a replica
+    made after the import gets, whether or not the task's first replica was
+    asked for at ``Started``."""
+    async def run():
+        svc, fired = _alone()
+        extra = {"replica_count": 2, **(GEOMETRY if early else {})}
+        await svc.upload_persistent_cache_task_started(_upload(extra), None)
+        await asyncio.sleep(0.05)
+        await svc.upload_persistent_cache_task_finished(
+            _upload({"digest": "sha256:" + "cd" * 32, **GEOMETRY}), None)
+        await asyncio.sleep(0.05)
+        assert [host for host, _ in fired] == ["b"]
+        svc.persistent.upsert_peer("p-b", "t-up", "b", state=STATE_SUCCEEDED)
+        svc.gc()    # an answered upload's asked hosts are forgotten
+        assert "t-up" not in svc._replicas_asked
+        fired.clear()
+        await svc.leave_host({"id": "b"}, None)
+        await asyncio.sleep(0.05)
+        (host, spec), = fired
+        assert host == "c" and spec["digest"] == "sha256:" + "cd" * 32
+        assert "digest_from_parent" not in spec
+
+    run_async(run())
+
+
+def test_a_replica_that_failed_before_finished_is_asked_for_again(run_async):
+    """A host asked at ``Started`` whose pull failed is no longer counted:
+    ``Finished`` asks once more; and a failed upload deletes the task on the
+    hosts it had asked."""
+    async def run():
+        svc, fired = _alone()
+        deleted = []
+
+        async def delete(host, task_id):
+            deleted.append(host.id)
+            return True
+
+        svc.seed_clients.delete_task = delete
+        await svc.upload_persistent_cache_task_started(
+            _upload({"replica_count": 2, **GEOMETRY}), None)
+        await asyncio.sleep(0.05)
+        assert [host for host, _ in fired] == ["b"]
+        # b's pull registers and fails.
+        _, task, replica = svc._resolve(
+            {"task_id": "t-up", "peer_id": "p-b",
+             "host": {"id": "b", "ip": "10.0.0.2", "port": 8001}})
+        replica.fsm.event("register_normal")
+        svc._handle_download_failed({}, task, replica)
+        assert svc._replicas_asked["t-up"] == set()
+        await svc.upload_persistent_cache_task_finished(
+            _upload({"digest": "sha256:" + "ef" * 32, **GEOMETRY}), None)
+        await asyncio.sleep(0.05)
+        assert [host for host, _ in fired] == ["b", "b"]
+        assert fired[1][1]["digest"] == "sha256:" + "ef" * 32
+        # The upload is reported failed: b, a peer of the task, is told to
+        # delete it; the uploader's host is not (its daemon did that).
+        await svc.upload_persistent_cache_task_failed(
+            _upload({"unreplicated": True}), None)
+        await asyncio.sleep(0.05)
+        assert deleted == ["b"]
+        assert svc.persistent.get_task("t-up")["state"] == "failed"
+        assert "t-up" not in svc._replicas_asked
+
+    run_async(run())
